@@ -83,7 +83,7 @@ def test_observe_phase_respects_probe_gate():
 # -- fused producer (dp + shard_params + int8) -------------------------------
 
 def _run_fused(anatomy: bool, seed: int = 31):
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.models.mnist_fc import build_fused
     from znicz_tpu.parallel.mesh import data_parallel_mesh
 
@@ -94,7 +94,7 @@ def _run_fused(anatomy: bool, seed: int = 31):
                     shard_params=True, anatomy=anatomy,
                     quantized_collectives={"mode": "int8",
                                            "error_feedback": True})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = [h["metric_validation"] for h in w.decision.metrics_history]
     w.stop()
@@ -164,7 +164,7 @@ def test_anatomy_numerics_track_fused_path():
 
 
 def test_anatomy_rejects_accumulation():
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.models.mnist_fc import build_fused
     from znicz_tpu.parallel.mesh import data_parallel_mesh
 
@@ -174,7 +174,7 @@ def test_anatomy_rejects_accumulation():
                     mesh=data_parallel_mesh(2), anatomy=True,
                     accumulate_steps=2)
     with pytest.raises(ValueError, match="accumulate"):
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
     w.stop()
 
 

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.memory import Array
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.standard_workflow import StandardWorkflow
@@ -65,7 +65,7 @@ def test_lr_adjust_in_training_loop():
     w.decision.links_to.remove(w.repeater)
     adj.link_from(w.decision)
     w.repeater.link_from(adj)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     # epochs 1 and 2 end with an adjustment (iterations 0, 1); the walk
     # stops at end_point on epoch 3's completion before the adjuster fires
@@ -76,7 +76,7 @@ def test_mean_disp_normalizer_backends():
     rng = np.random.default_rng(0)
     x = (rng.normal(size=(6, 4, 4, 2)) * 3 + 1).astype(np.float32)
     outs = []
-    for device in (NumpyDevice(), TPUDevice()):
+    for device in (NumpyDevice(), XLADevice()):
         w = Workflow(name="t")
         u = MeanDispNormalizer(w)
         u.input = Array(x.copy())
@@ -92,7 +92,7 @@ def test_mean_disp_normalizer_backends():
 def test_cutter_and_gd():
     rng = np.random.default_rng(1)
     x = rng.normal(size=(2, 8, 8, 3)).astype(np.float32)
-    for device in (NumpyDevice(), TPUDevice()):
+    for device in (NumpyDevice(), XLADevice()):
         w = Workflow(name="t")
         cut = Cutter(w, offset=(2, 1), size=(4, 5))
         cut.input = Array(x.copy())
@@ -118,7 +118,7 @@ def test_resizable_all2all():
     w = Workflow(name="t")
     u = ResizableAll2All(w, output_sample_shape=5)
     u.input = Array(x)
-    u.initialize(device=TPUDevice())
+    u.initialize(device=XLADevice())
     u.run()
     w_before = u.weights.map_read().copy()
     y_before = u.output.map_read().copy()
@@ -164,7 +164,7 @@ def test_nn_rollback_restores_and_cuts_lr():
         loader_config={"n_classes": 3, "sample_shape": (6,), "n_train": 60,
                        "n_valid": 30, "minibatch_size": 10},
         decision_config={"max_epochs": 2})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     rb = NNRollback(w, lr_cut=0.5, fail_iterations=1)
     rb.link_workflow_state(w)
@@ -245,7 +245,7 @@ def test_publisher_markdown_and_html(tmp_path):
 
     prng.seed_all(3)
     w = wine.build(max_epochs=2, n_train=60, n_valid=30, minibatch_size=10)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     md = Publisher(backend="markdown",
                    directory=str(tmp_path)).publish(w)
@@ -273,7 +273,7 @@ def test_cli_publish_flag(tmp_path, monkeypatch):
             main()
         """))
     monkeypatch.chdir(tmp_path)
-    rc = cli_main([str(wf), "--publish", "markdown", "-d", "tpu",
+    rc = cli_main([str(wf), "--publish", "markdown", "-d", "auto",
                    "--random-seed", "4"])
     assert rc == 0
     assert (tmp_path / "winedemo_report.md").exists() or \

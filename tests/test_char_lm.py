@@ -13,7 +13,7 @@ pytestmark = pytest.mark.slow
 import numpy as np
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.loader.base import TEST, TRAIN, VALID
 from znicz_tpu.models import char_lm
 
@@ -63,7 +63,7 @@ def test_char_lm_trains_and_stops(tmp_path):
     w = char_lm.build(max_epochs=4, seq_len=32, minibatch_size=16,
                       n_layers=2, d=32, heads=2,
                       data_dir=str(tmp_path / "corp"))
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     h = w.decision.metrics_history
     assert len(h) == 4
@@ -84,14 +84,14 @@ def test_char_lm_snapshot_roundtrip(tmp_path):
     prng.seed_all(11)
     w = char_lm.build(max_epochs=2, seq_len=32, minibatch_size=16,
                       data_dir=str(tmp_path / "corp"))
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     state = w.step.state_dict()
 
     prng.seed_all(99)    # different init — restore must overwrite it
     w2 = char_lm.build(max_epochs=2, seq_len=32, minibatch_size=16,
                        data_dir=str(tmp_path / "corp"))
-    w2.initialize(device=TPUDevice())
+    w2.initialize(device=XLADevice())
     w2.step.load_state_dict(state)
     tokens = jax.numpy.asarray(
         np.arange(16 * 32, dtype=np.int32).reshape(16, 32)
@@ -115,7 +115,7 @@ def test_char_lm_sharded_mesh(tmp_path):
                       n_layers=2, d=32, heads=4,
                       mesh=make_mesh({"data": 2, "seq": 2, "model": 2}),
                       data_dir=str(tmp_path / "corp"))
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     h = w.decision.metrics_history
     assert h[-1]["metric_validation"] < h[0]["metric_validation"], h
@@ -142,12 +142,12 @@ def test_char_lm_snapshotter_resume_bit_exact(tmp_path):
             if with_snap else None)
 
     w = fresh(4, True)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     full_hist = w.decision.metrics_history
 
     w2 = fresh(4, False)
-    w2.initialize(device=TPUDevice())
+    w2.initialize(device=XLADevice())
     meta = restore_state(w2, str(tmp_path / "snaps" / "lm_2.npz"))
     assert meta["loader"]["epoch_number"] == 2
     w2.run()
@@ -168,7 +168,7 @@ def test_char_lm_loss_chunks_trains(tmp_path):
     w = char_lm.build(max_epochs=3, seq_len=32, minibatch_size=16,
                       n_layers=2, d=32, heads=2,
                       data_dir=str(tmp_path / "corp"), loss_chunks=4)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     h = w.decision.metrics_history
     assert h[-1]["metric_validation"] < \
@@ -183,7 +183,7 @@ def test_char_lm_moe_trains(tmp_path):
                       n_layers=2, d=32, heads=2,
                       data_dir=str(tmp_path / "corp"), n_experts=4,
                       moe_aux_weight=0.01, moe_top_k=2)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     h = w.decision.metrics_history
     assert h[-1]["metric_validation"] < \

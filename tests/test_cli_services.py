@@ -10,7 +10,7 @@ import pytest
 
 from znicz_tpu.__main__ import main as cli_main
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.config import Tune, root, set_by_path
 from znicz_tpu.launcher import Launcher
 from znicz_tpu.models import wine
@@ -46,7 +46,7 @@ WINE_WORKFLOW = textwrap.dedent("""
 
 def test_launcher_load_main_contract():
     prng.seed_all(3)
-    launcher = Launcher(device=TPUDevice())
+    launcher = Launcher(device=XLADevice())
     wine.run(lambda b, **kw: launcher.load(b, max_epochs=3, n_train=60,
                                            n_valid=30, minibatch_size=10,
                                            **kw),
@@ -61,13 +61,13 @@ def test_launcher_snapshot_resume(tmp_path):
                    snapshotter_config={"directory": str(tmp_path),
                                        "prefix": "w", "only_improved": False,
                                        "keep_all": True})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     snap = tmp_path / "w_2.npz"
     assert snap.exists()
 
     prng.seed_all(3)
-    launcher = Launcher(device=TPUDevice(), snapshot=str(snap))
+    launcher = Launcher(device=XLADevice(), snapshot=str(snap))
     launcher.load(wine.build, max_epochs=4, n_train=60, n_valid=30,
                   minibatch_size=10)
     launcher.main()
@@ -81,7 +81,7 @@ def test_cli_end_to_end(tmp_path):
     cfg = tmp_path / "wine_config.py"
     cfg.write_text("root.wine.max_epochs = 2\n")
     result_file = tmp_path / "result.json"
-    rc = cli_main([str(wf), str(cfg), "--random-seed", "5", "-d", "tpu",
+    rc = cli_main([str(wf), str(cfg), "--random-seed", "5", "-d", "auto",
                    "-o", f"root.wine.result_file={result_file}"])
     assert rc == 0
     result = json.loads(result_file.read_text())
@@ -105,7 +105,7 @@ def test_cli_optimize(tmp_path, capsys):
             main()
         """))
     set_by_path(root, "wine_opt.lr", Tune(0.3, 0.01, 1.0))
-    rc = cli_main([str(wf), "--optimize", "2", "-d", "tpu"])
+    rc = cli_main([str(wf), "--optimize", "2", "-d", "auto"])
     assert rc == 0
     assert "'_evaluator': 'vmapped'" in capsys.readouterr().out
     del root.wine_opt
@@ -126,7 +126,7 @@ def test_cli_optimize_structural_tune_falls_back(tmp_path, capsys):
             main()
         """))
     set_by_path(root, "wine_hidden.hidden", Tune(8, 4, 16))
-    rc = cli_main([str(wf), "--optimize", "1", "-d", "tpu"])
+    rc = cli_main([str(wf), "--optimize", "1", "-d", "auto"])
     assert rc == 0
     assert "'_evaluator': 'sequential'" in capsys.readouterr().out
     del root.wine_hidden
@@ -205,7 +205,7 @@ def _staged_fc_step(n_steps=6, batch=40):
     prng.seed_all(51)
     w = build_fused(max_epochs=1, layers=(32,), minibatch_size=batch,
                     n_train=240, n_valid=80, mesh=data_parallel_mesh(4))
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     rng = np.random.default_rng(2)
     xs = jnp.asarray(rng.normal(size=(n_steps, batch, 28, 28)),
                      jnp.float32)
@@ -313,7 +313,7 @@ def test_ga_with_vmapped_evaluator_converges_to_good_lr():
 def test_ensemble_committee(tmp_path):
     ens = Ensemble(wine.build, n_members=3, base_seed=50, max_epochs=3,
                    n_train=60, n_valid=30, minibatch_size=10)
-    ens.train(TPUDevice())
+    ens.train(XLADevice())
     report = ens.test_classification()
     assert report["n"] == 30
     # the committee must not be worse than the worst member
@@ -331,7 +331,7 @@ def test_cli_ensemble_train(tmp_path, monkeypatch):
     wf = tmp_path / "wine_ens.py"
     wf.write_text(WINE_WORKFLOW)
     monkeypatch.chdir(tmp_path)
-    rc = cli_main([str(wf), "--ensemble-train", "3", "-d", "tpu",
+    rc = cli_main([str(wf), "--ensemble-train", "3", "-d", "auto",
                    "--random-seed", "7"])
     assert rc == 0
     out = json.loads((tmp_path / "ensemble_wine.json").read_text())
@@ -345,8 +345,8 @@ def test_cli_ensemble_train_rejects_bad_usage(tmp_path, monkeypatch):
     wf = tmp_path / "wine_ens2.py"
     wf.write_text(WINE_WORKFLOW)
     monkeypatch.chdir(tmp_path)
-    assert cli_main([str(wf), "--ensemble-train", "0", "-d", "tpu"]) == 2
-    assert cli_main([str(wf), "--ensemble-train", "2", "-d", "tpu",
+    assert cli_main([str(wf), "--ensemble-train", "0", "-d", "auto"]) == 2
+    assert cli_main([str(wf), "--ensemble-train", "2", "-d", "auto",
                      "--publish", "markdown"]) == 2
 
 
@@ -406,7 +406,7 @@ def test_site_config_layering(tmp_path, monkeypatch):
     result_file = tmp_path / "result.json"
     monkeypatch.setenv("ZNICZ_TPU_SITE_CONFIG", str(site))
     try:
-        rc = cli_main([str(wf), str(cfg), "--random-seed", "5", "-d", "tpu",
+        rc = cli_main([str(wf), str(cfg), "--random-seed", "5", "-d", "auto",
                        "-o", f"root.wine.result_file={result_file}"])
         assert rc == 0
         assert json.loads(result_file.read_text())["epochs"] == 2

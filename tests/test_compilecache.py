@@ -34,6 +34,7 @@ def cc(monkeypatch):
     of the suite keeps whatever cache policy it booted with."""
     prev_cfg = {k: getattr(jax.config, k) for k in _CACHE_KEYS}
     prev_state = (compilecache._configured, compilecache._active_dir)
+    monkeypatch.delenv(compilecache.JAX_ENV_VAR, raising=False)
     monkeypatch.delenv(compilecache.ENV_VAR, raising=False)
     monkeypatch.delenv(compilecache.ENV_MIN_S, raising=False)
     compilecache._reset_for_tests()
@@ -60,19 +61,44 @@ def _fresh_fn(salt: float):
 
 # -- persistent cache --------------------------------------------------------
 
-def test_env_layer_wins_and_creates_dir(cc, monkeypatch, tmp_path):
-    target = tmp_path / "envcache"
-    monkeypatch.setenv(compilecache.ENV_VAR, str(target))
-    assert cc.configure() == str(target)
-    assert target.is_dir()
-    assert cc.active_dir() == str(target)
-    assert jax.config.jax_compilation_cache_dir == str(target)
+def test_placed_dir_is_the_dir_and_is_never_replaced(cc, monkeypatch,
+                                                     tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR set: that is the directory, it
+    outranks an explicit argument and the off switch, and nothing this
+    module does leaves another value in jax's config."""
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv(compilecache.JAX_ENV_VAR, placed)
+    monkeypatch.setenv(compilecache.ENV_VAR, "off")
+    seen = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            seen.append(value)
+        real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    assert cc.configure() == placed
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert cc.configure(cache_dir=str(tmp_path / "other"),
+                        force=True) == placed
+    assert cc.ensure() == placed
+    with cc.suspended():
+        pass
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert cc.active_dir() == placed
+    # the only other value ever written is suspended()'s transient ""
+    assert set(seen) <= {placed, ""}
+    _fresh_fn(0.619)(jnp.ones((2, 4), jnp.float32))
+    assert any(f.endswith("-cache") for f in os.listdir(placed))
 
 
-def test_explicit_arg_wins_over_env(cc, monkeypatch, tmp_path):
-    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path / "envcache"))
+def test_explicit_arg_wins_over_off_switch(cc, monkeypatch, tmp_path):
+    monkeypatch.setenv(compilecache.ENV_VAR, "off")
     explicit = tmp_path / "explicit"
     assert cc.configure(cache_dir=str(explicit)) == str(explicit)
+    assert explicit.is_dir()
+    assert jax.config.jax_compilation_cache_dir == str(explicit)
 
 
 def test_env_off_disables(cc, monkeypatch):
@@ -83,24 +109,37 @@ def test_env_off_disables(cc, monkeypatch):
     assert cc.ensure() is None
 
 
-def test_config_tree_layer(cc, tmp_path):
-    from znicz_tpu.core.config import root
+def test_default_dir_is_the_checkouts_and_stable_across_processes(cc):
+    """Unset: ``<checkout>/.data/cache/jax``, the same string in every
+    process — the path is part of what two processes must agree on to
+    hit, so nothing in it may vary (no pid, no timestamp, no tempfile
+    name, no home directory)."""
+    import subprocess
+    import sys
+    import tempfile
 
-    prev = root.common.engine.get("compile_cache_dir", None)
-    root.common.engine.compile_cache_dir = str(tmp_path / "cfgcache")
-    try:
-        assert cc.configure() == str(tmp_path / "cfgcache")
-    finally:
-        root.common.engine.compile_cache_dir = prev
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".data", "cache", "jax")
+    assert compilecache._resolve_dir(None) == want
+    env = {k: v for k, v in os.environ.items()
+           if k not in (compilecache.JAX_ENV_VAR, compilecache.ENV_VAR)}
+    code = ("from znicz_tpu import compilecache as c; "
+            "print(c._resolve_dir(None))")
+    seen = {subprocess.run([sys.executable, "-c", code], cwd=cwd, env=dict(
+        env, PYTHONPATH=checkout), capture_output=True, text=True,
+        check=True, timeout=60).stdout.strip()
+        for cwd in (checkout, tempfile.gettempdir())}
+    assert seen == {want}
+    assert not want.startswith(tempfile.gettempdir())
+    assert os.path.expanduser("~/.cache") not in want
 
 
 def test_ensure_is_idempotent(cc, monkeypatch, tmp_path):
-    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path / "e"))
-    first = cc.ensure()
+    first = cc.configure(cache_dir=str(tmp_path / "e"))
     assert first == str(tmp_path / "e")
     # a second ensure() (every Workflow.run calls it) is a no-op even
     # if the env changes mid-process — the decision was made
-    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path / "other"))
+    monkeypatch.setenv(compilecache.ENV_VAR, "off")
     assert cc.ensure() == first
 
 
@@ -115,10 +154,10 @@ def test_min_compile_time_change_applies_without_force(cc, tmp_path):
 
 def test_malformed_min_s_env_degrades_to_zero(cc, monkeypatch, tmp_path,
                                               caplog):
-    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path / "m"))
     monkeypatch.setenv(compilecache.ENV_MIN_S, "1s")
     with caplog.at_level(logging.WARNING, "znicz_tpu.compilecache"):
-        assert cc.configure() == str(tmp_path / "m")
+        assert cc.configure(cache_dir=str(tmp_path / "m")) == \
+            str(tmp_path / "m")
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     assert any("is not a number" in r.message for r in caplog.records)
 
@@ -209,7 +248,7 @@ def test_corrupt_cache_entries_never_crash(cc, tmp_path):
 def test_engine_boot_triggers_ensure(cc, monkeypatch, tmp_path):
     from znicz_tpu.serve import BatchEngine
 
-    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path / "boot"))
+    monkeypatch.setenv(compilecache.JAX_ENV_VAR, str(tmp_path / "boot"))
     assert not compilecache._configured
     BatchEngine(lambda x: x, max_batch=2, input_shape=(2,))
     assert compilecache.active_dir() == str(tmp_path / "boot")
@@ -222,7 +261,7 @@ def tiny_pkg(tmp_path_factory):
     """One trained-and-exported forward package shared by the AOT
     tests (each test copies it before mutating)."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.standard_workflow import StandardWorkflow
     from znicz_tpu.utils.export import export_forward
 
@@ -235,7 +274,7 @@ def tiny_pkg(tmp_path_factory):
         loader_config={"n_classes": 3, "sample_shape": (6,), "n_train": 60,
                        "n_valid": 0, "minibatch_size": 20},
         decision_config={"max_epochs": 1})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     pkg = str(tmp_path_factory.mktemp("aot") / "tiny.npz")
     export_forward(w, pkg)

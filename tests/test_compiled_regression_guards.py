@@ -1,6 +1,6 @@
 """Structural (jaxpr-level) regression guards for the compiled-mode bug
 classes the first on-chip Pallas parity sweep exposed (2026-07-31 01:01
-UTC, docs/BENCH_LOG.md) — defects invisible to interpret-mode parity
+UTC; ROADMAP Queue 1 item 3) — defects invisible to interpret-mode parity
 because they live in Mosaic lowering or MXU default-precision semantics,
 not in the math.  These tests pin the *structural property each fix
 relies on*, so a refactor cannot silently reintroduce the bug class

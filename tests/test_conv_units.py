@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.memory import Array
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.units.activation import (ForwardTanh, BackwardTanh,
@@ -41,7 +41,7 @@ def test_conv_backend_parity(cls):
     x = rng.normal(size=(2, 8, 8, 3)).astype(np.float32)
     kw = dict(n_kernels=5, kx=3, ky=3, sliding=(2, 2), padding=(1, 1, 1, 1))
     u_np = run_unit(cls, NumpyDevice(), x, **kw)
-    u_x = run_unit(cls, TPUDevice(), x, **kw)
+    u_x = run_unit(cls, XLADevice(), x, **kw)
     np.testing.assert_array_equal(u_np.weights.map_read(),
                                   u_x.weights.map_read())
     np.testing.assert_allclose(u_x.output.map_read(), u_np.output.map_read(),
@@ -87,7 +87,7 @@ def test_gd_conv_backend_parity(fwd_cls, gd_cls):
     rng = np.random.default_rng(1)          # same err stream for both
     gd_np = build(NumpyDevice())
     rng = np.random.default_rng(1)
-    gd_x = build(TPUDevice())
+    gd_x = build(XLADevice())
     for attr in ("err_input", "weights", "bias", "gradient_weights",
                  "gradient_bias"):
         np.testing.assert_allclose(
@@ -107,7 +107,7 @@ def test_pooling_backend_parity(cls):
     rng = np.random.default_rng(2)
     x = rng.normal(size=(2, 7, 7, 3)).astype(np.float32)
     u_np = run_unit(cls, NumpyDevice(), x, kx=2, ky=2)
-    u_x = run_unit(cls, TPUDevice(), x, kx=2, ky=2)
+    u_x = run_unit(cls, XLADevice(), x, kx=2, ky=2)
     np.testing.assert_allclose(u_x.output.map_read(), u_np.output.map_read(),
                                rtol=1e-5, atol=1e-6)
     if hasattr(u_np, "input_offset"):
@@ -118,7 +118,7 @@ def test_pooling_backend_parity(cls):
 def test_max_pooling_gd_scatter():
     rng = np.random.default_rng(3)
     x = rng.normal(size=(2, 6, 6, 2)).astype(np.float32)
-    for device in (NumpyDevice(), TPUDevice()):
+    for device in (NumpyDevice(), XLADevice()):
         w = Workflow(name="t")
         fwd = MaxPooling(w, kx=2, ky=2)
         fwd.input = Array(x)
@@ -164,7 +164,7 @@ def test_stochastic_pooling_seed_reproducible():
     fwd = StochasticPooling(w, kx=2, ky=2)
     fwd.input = Array(x)
     fwd.forward_mode = True
-    fwd.initialize(device=TPUDevice())
+    fwd.initialize(device=XLADevice())
     fwd.run()
     fwd_np = StochasticPooling(Workflow(name="t2"), kx=2, ky=2)
     fwd_np.input = Array(x)
@@ -179,10 +179,10 @@ def test_lrn_units_backend_parity():
     rng = np.random.default_rng(6)
     x = rng.normal(size=(2, 4, 4, 8)).astype(np.float32)
     u_np = run_unit(LRNormalizerForward, NumpyDevice(), x)
-    u_x = run_unit(LRNormalizerForward, TPUDevice(), x)
+    u_x = run_unit(LRNormalizerForward, XLADevice(), x)
     np.testing.assert_allclose(u_x.output.map_read(), u_np.output.map_read(),
                                rtol=1e-5, atol=1e-6)
-    for device in (NumpyDevice(), TPUDevice()):
+    for device in (NumpyDevice(), XLADevice()):
         w = Workflow(name="t")
         fwd = LRNormalizerForward(w)
         fwd.input = Array(x)
@@ -230,7 +230,7 @@ def test_activation_units_parity_and_numeric(fwd_cls, bwd_cls):
     rng = np.random.default_rng(8)
     x = rng.normal(size=(3, 8)).astype(np.float32) * 2.0
     u_np = run_unit(fwd_cls, NumpyDevice(), x)
-    u_x = run_unit(fwd_cls, TPUDevice(), x)
+    u_x = run_unit(fwd_cls, XLADevice(), x)
     np.testing.assert_allclose(u_x.output.map_read(), u_np.output.map_read(),
                                rtol=1e-5, atol=1e-6)
     # backward vs central difference on the numpy path

@@ -6,7 +6,7 @@ import pickle
 import numpy as np
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.config import Config, Tune, fix_config, root, walk_tunes
 from znicz_tpu.core.memory import Array, roundup
 from znicz_tpu.core.mutable import Bool
@@ -70,7 +70,7 @@ def test_array_map_semantics_numpy_device():
 
 
 def test_array_device_roundtrip():
-    dev = TPUDevice()  # CPU jax device under the test platform
+    dev = XLADevice()  # CPU jax device under the test platform
     arr = Array(np.ones((4, 4), dtype=np.float32))
     arr.initialize(dev)
     dv = arr.devmem
@@ -84,7 +84,7 @@ def test_array_device_roundtrip():
 
 
 def test_array_pickle_drops_device():
-    dev = TPUDevice()
+    dev = XLADevice()
     arr = Array(np.full((2, 2), 5.0, np.float32))
     arr.initialize(dev)
     arr.set_devmem(arr.devmem + 1)
@@ -236,7 +236,7 @@ def test_metrics_jsonl_sink(tmp_path):
     import json
 
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.models import wine
 
@@ -246,7 +246,7 @@ def test_metrics_jsonl_sink(tmp_path):
         prng.seed_all(3)
         w = wine.build(max_epochs=3, n_train=60, n_valid=30,
                        minibatch_size=10)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
     finally:
         del root.common.metrics_file

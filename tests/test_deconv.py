@@ -8,7 +8,7 @@ import pytest
 import jax.numpy as jnp
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.memory import Array
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.ops import conv as conv_ops, deconv as deconv_ops
@@ -78,7 +78,7 @@ def test_deconv_backward_numeric():
                                        (up - down) / (2 * eps), rtol=1e-6)
 
 
-@pytest.mark.parametrize("device_cls", [NumpyDevice, TPUDevice])
+@pytest.mark.parametrize("device_cls", [NumpyDevice, XLADevice])
 def test_deconv_unit_standalone_and_gd(device_cls):
     prng.seed_all(5)
     rng = np.random.default_rng(3)
@@ -139,7 +139,7 @@ def test_conv_autoencoder_workflow(fused):
         loader_config={"sample_shape": (8, 8, 1), "identity": True,
                        "n_train": 128, "n_valid": 64, "minibatch_size": 32},
         decision_config={"max_epochs": 5}, fused=fused)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     dec = w.decision
     assert bool(dec.complete)

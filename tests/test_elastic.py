@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.launcher import (CoordinatorUnreachable, multihost,
                                 wait_for_coordinator)
 from znicz_tpu.observe import probe
@@ -202,7 +202,7 @@ def build_local(max_epochs, snap_dir, verify_timeout=0.3, seed=77):
         snapshotter_config={"directory": str(snap_dir), "prefix": "t",
                             "only_improved": False, "keep_all": True,
                             "verify_timeout": verify_timeout})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     return w
 
 

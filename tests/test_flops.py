@@ -2,6 +2,7 @@
 against hand-computed GEMM/conv counts."""
 
 import numpy as np
+import pytest
 
 from znicz_tpu.core import prng
 from znicz_tpu.core.backends import NumpyDevice
@@ -46,9 +47,24 @@ def test_conv_forward_flops():
     assert flops.forward_flops(conv, 4) == expect
 
 
-def test_mfu_uses_peak_table():
+def test_mfu_uses_peak_table(monkeypatch):
+    import jax
+
     w = _fc_workflow()
-    m = flops.mfu(1000.0, w.forwards, 32, gen="v5e")
     step = flops.train_step_flops(w.forwards, 32)
-    assert m == (1000.0 / 32) * step / 197e12
-    assert flops.mfu(1000.0, w.forwards, 32, gen="unknown-gen") is None
+    monkeypatch.delenv(flops.PEAK_FLOPS_ENV, raising=False)
+    # the CPU has no peak the table could know: no MFU, silently
+    assert flops.mfu(1000.0, w.forwards, 32) is None
+
+    class Chip:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"     # what the v5e reports (PR 21)
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    assert flops.mfu(1000.0, w.forwards, 32) == \
+        (1000.0 / 32) * step / 197e12
+    # an accelerator the table does not list is an error, not a missing
+    # metric
+    Chip.device_kind = "TPU v9"
+    with pytest.raises(ValueError, match="TPU v9"):
+        flops.peak_flops()

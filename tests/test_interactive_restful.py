@@ -8,7 +8,7 @@ import urllib.request
 import numpy as np
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.loader.base import TRAIN
 from znicz_tpu.loader.interactive import InteractiveLoader
@@ -90,7 +90,7 @@ def test_interactive_online_training_learns(tmp_path):
     labels = rng.integers(0, 3, 96).astype(np.int32)
     data = centers[labels] + rng.normal(0, 0.3, (96, 6)).astype(np.float32)
     w.loader.feed(data, labels)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = w.decision.metrics_history
     assert hist[-1]["metric_train"] < hist[0]["metric_train"]
@@ -109,7 +109,7 @@ def _train_tiny_exported(tmp_path):
         loader_config={"n_classes": 3, "sample_shape": (6,), "n_train": 60,
                        "n_valid": 0, "minibatch_size": 20},
         decision_config={"max_epochs": 1})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     pkg = str(tmp_path / "srv.npz")
     export_forward(w, pkg)
@@ -153,6 +153,6 @@ def test_prediction_server_serves_exported_model(tmp_path):
 def test_device_benchmark_reports_throughput():
     from znicz_tpu.core.accelerated_units import DeviceBenchmark
 
-    result = DeviceBenchmark(size=128, reps=2).run(device=TPUDevice())
+    result = DeviceBenchmark(size=128, reps=2).run(device=XLADevice())
     assert result["gflops"] > 0
     assert result["size"] == 128

@@ -5,7 +5,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.memory import Array
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.models import kohonen as kohonen_model, rbm as rbm_model
@@ -42,7 +42,7 @@ def test_kohonen_trainer_backend_parity():
     rng = np.random.default_rng(0)
     x = rng.normal(size=(16, 3)).astype(np.float32)
     outs = []
-    for device in (NumpyDevice(), TPUDevice()):
+    for device in (NumpyDevice(), XLADevice()):
         prng.seed_all(9)
         w = Workflow(name="t")
         tr = KohonenTrainer(w, shape=(3, 3))
@@ -78,7 +78,7 @@ def test_kohonen_forward_hits_accumulate():
 def test_kohonen_demo_workflow_organizes():
     prng.seed_all(23)
     w = kohonen_model.build(max_epochs=6, shape=(6, 6), n_train=400)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     dec = w.decision
     assert bool(dec.complete)
@@ -96,7 +96,7 @@ def test_kohonen_demo_workflow_organizes():
 def test_rbm_workflow_reconstruction_improves():
     prng.seed_all(11)
     w = rbm_model.build(max_epochs=6)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     dec = w.decision
     assert bool(dec.complete)
@@ -108,7 +108,7 @@ def test_kohonen_scan_epoch_matches_eager():
     """Epoch-scan mode (one compiled dispatch per class pass) trains to
     the same weights and reports the same |ΔW| trajectory as the
     per-minibatch path — same seed, same data, same step order."""
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.models.kohonen import build
 
@@ -119,7 +119,7 @@ def test_kohonen_scan_epoch_matches_eager():
         try:
             w = build(max_epochs=4, shape=(6, 6), minibatch_size=40,
                       n_train=200, sample_shape=(3,), min_delta=0.0)
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
         finally:
             root.common.engine.scan_epoch = False
@@ -140,7 +140,7 @@ def test_kohonen_scan_epoch_matches_eager():
 def test_kohonen_scan_min_delta_still_stops():
     """The Decision's |ΔW| convergence stop keeps working in scan mode
     (the pre-pass weight snapshot keeps the metric honest)."""
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.models.kohonen import build
 
@@ -150,7 +150,7 @@ def test_kohonen_scan_min_delta_still_stops():
         w = build(max_epochs=50, shape=(4, 4), minibatch_size=50,
                   n_train=100, sample_shape=(2,), alpha=0.05,
                   radius_decay=0.5, min_delta=0.2)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
     finally:
         root.common.engine.scan_epoch = False
@@ -165,7 +165,7 @@ def test_kohonen_scan_midpass_falls_back_to_eager():
     """A class pass entered mid-way (restored loader state after resume)
     must still train: the scan guard only fires at offset 0, so the
     remainder of the pass goes through the per-minibatch path."""
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.models.kohonen import build
 
@@ -174,7 +174,7 @@ def test_kohonen_scan_midpass_falls_back_to_eager():
     try:
         w = build(max_epochs=3, shape=(4, 4), minibatch_size=25,
                   n_train=100, sample_shape=(2,), min_delta=0.0)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         assert w.trainer._scan_fn is not None
         # simulate a resume that landed mid-pass: advance the loader two
         # minibatches without letting the trainer see them

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.loader import mnist as mnist_mod
 from znicz_tpu.loader.base import VALID, TRAIN
@@ -93,7 +93,7 @@ def make_image_loader(cls, d, seed=44, **kw):
     w = Workflow(name="i")
     loader = cls(w, data_dir=d, sample_shape=(12, 10, 3),
                  valid_fraction=0.2, minibatch_size=8, **kw)
-    loader.initialize(device=TPUDevice())
+    loader.initialize(device=XLADevice())
     return loader
 
 
@@ -197,7 +197,7 @@ def test_mnist_workflow_snapshot_roundtrip(tmp_path):
     prng.seed_all(31)
     w = mnist_conv.build(max_epochs=1, n_train=200, n_valid=100,
                          minibatch_size=50)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     arrays, meta = collect_state(w)
     path = str(tmp_path / "m.npz")
@@ -206,7 +206,7 @@ def test_mnist_workflow_snapshot_roundtrip(tmp_path):
     prng.seed_all(9)
     w2 = mnist_conv.build(max_epochs=1, n_train=200, n_valid=100,
                           minibatch_size=50)
-    w2.initialize(device=TPUDevice())
+    w2.initialize(device=XLADevice())
     restore_state(w2, path)
     assert w2.loader.normalizer.vmin == w.loader.normalizer.vmin
     np.testing.assert_array_equal(w2.forwards[0].weights.map_read(),
@@ -262,7 +262,7 @@ def test_alexnet_file_image_epoch(tmp_path):
                       input_size=32, loader_name="file_image",
                       loader_config={"data_dir": d, "valid_fraction": 0.25,
                                      "fit_samples": 16})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = w.decision.metrics_history
     assert len(hist) == 1
@@ -282,7 +282,7 @@ def test_augmentation_mirror_and_crop(png_tree):
             Workflow(name=f"aug{seed}{mb_class}"), data_dir=d,
             sample_shape=(12, 10, 3), valid_fraction=0.25,
             minibatch_size=8, mirror=True, crop=(8, 8))
-        loader.initialize(device=TPUDevice())
+        loader.initialize(device=XLADevice())
         # serve until we reach the requested class
         for _ in range(100):
             loader.run()
@@ -336,7 +336,7 @@ def test_augmenting_full_batch_loader_trains_unpinned(png_tree):
                        "valid_fraction": 0.25, "minibatch_size": 10,
                        "mirror": True, "crop": (10, 8)},
         decision_config={"max_epochs": 6}, fused=True)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     assert w.loader.augmenting
     assert w.step._dataset_dev is None              # pinning skipped
     w.run()
@@ -364,7 +364,7 @@ def test_ensemble_over_augmenting_loader(png_tree):
                            "mirror": True, "crop": (10, 8)},
             decision_config={"max_epochs": 3}, fused=True)
 
-    ens = Ensemble(build, n_members=2, base_seed=50).train(TPUDevice())
+    ens = Ensemble(build, n_members=2, base_seed=50).train(XLADevice())
     result = ens.test_classification()
     # shapes lined up (served geometry) and the committee scored
     assert result["n"] == ens.members[0].loader.class_lengths[1]
@@ -387,7 +387,7 @@ def test_alexnet_augment_recipe(tmp_path):
                       loader_config={"data_dir": d, "augment": True,
                                      "valid_fraction": 0.25,
                                      "fit_samples": 8})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     assert w.loader.sample_shape == (61, 61, 3)       # decode size
     assert w.loader.crop == (32, 32) and w.loader.mirror
     assert w.loader.served_shape == (32, 32, 3)
@@ -424,7 +424,7 @@ def test_scan_epoch_falls_back_for_augmenting_loader(png_tree):
                            "valid_fraction": 0.25, "minibatch_size": 10,
                            "mirror": True, "crop": (10, 8)},
             decision_config={"max_epochs": 3}, fused=True)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         assert w.step._dataset_dev is None       # no pin, no scan fns
         assert not w.step._scan_idx_fns
         w.run()
@@ -461,13 +461,13 @@ def test_augmented_training_resume_is_bit_exact(tmp_path):
     snap_dir = tmp_path / "snaps"
     w_full = build({"directory": str(snap_dir), "prefix": "a",
                     "only_improved": False, "keep_all": True})
-    w_full.initialize(device=TPUDevice())
+    w_full.initialize(device=XLADevice())
     w_full.run()
     full_hist = w_full.decision.metrics_history
     assert len(full_hist) == 4
 
     w_res = build()
-    w_res.initialize(device=TPUDevice())
+    w_res.initialize(device=XLADevice())
     meta = restore_state(w_res, str(snap_dir / "a_2.npz"))
     assert meta["loader"]["epoch_number"] == 2
     w_res.run()

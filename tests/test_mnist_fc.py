@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.mutable import Bool
 from znicz_tpu.core.plumbing import Repeater
 from znicz_tpu.loader.base import TRAIN
@@ -85,7 +85,7 @@ def run_workflow(device, seed=123, max_epochs=4):
     return w
 
 
-@pytest.mark.parametrize("device_cls", [NumpyDevice, TPUDevice])
+@pytest.mark.parametrize("device_cls", [NumpyDevice, XLADevice])
 def test_fc_workflow_converges(device_cls):
     w = run_workflow(device_cls())
     dec = w.decision
@@ -99,8 +99,8 @@ def test_fc_workflow_converges(device_cls):
 
 
 def test_fc_workflow_deterministic():
-    h1 = run_workflow(TPUDevice(), seed=7, max_epochs=2)
-    h2 = run_workflow(TPUDevice(), seed=7, max_epochs=2)
+    h1 = run_workflow(XLADevice(), seed=7, max_epochs=2)
+    h2 = run_workflow(XLADevice(), seed=7, max_epochs=2)
     assert h1.decision.metrics_history == h2.decision.metrics_history
     np.testing.assert_array_equal(h1.forwards[0].weights.map_read(),
                                   h2.forwards[0].weights.map_read())
@@ -110,7 +110,7 @@ def test_fc_workflow_backends_agree():
     """numpy oracle vs XLA backend: same seed, same epoch error counts
     (float32 GEMM on CPU-XLA matches numpy within integer-count tolerance)."""
     h_np = run_workflow(NumpyDevice(), seed=11, max_epochs=2)
-    h_x = run_workflow(TPUDevice(), seed=11, max_epochs=2)
+    h_x = run_workflow(XLADevice(), seed=11, max_epochs=2)
     for m_np, m_x in zip(h_np.decision.metrics_history,
                          h_x.decision.metrics_history):
         assert abs(m_np["metric_validation"] - m_x["metric_validation"]) <= 2
@@ -184,7 +184,7 @@ def test_class_weights_fused_matches_eager():
         return w
 
     we = one_step(False, NumpyDevice())
-    wf = one_step(True, TPUDevice())
+    wf = one_step(True, XLADevice())
     for i, (fe, ff) in enumerate(zip(we.forwards, wf.forwards)):
         np.testing.assert_allclose(
             ff.weights.map_read(), fe.weights.map_read(),
@@ -199,7 +199,7 @@ def test_class_weights_fused_matches_eager():
         loss_function="softmax",
         loader_name="synthetic_classifier", loader_config=loader_cfg,
         decision_config={"max_epochs": 1}, fused=True)
-    w0.initialize(device=TPUDevice())
+    w0.initialize(device=XLADevice())
     w0.loader.run()
     w0.step.run()
     w0.step.sync_to_units()
@@ -264,7 +264,7 @@ def test_fused_confusion_matrix_matches_eager():
         return w
 
     we = run(False, NumpyDevice())
-    wf = run(True, TPUDevice())
+    wf = run(True, XLADevice())
     for cls in (VALID, TRAIN):
         me = we.decision.confusion_matrixes[cls]
         mf = wf.decision.confusion_matrixes[cls]
@@ -296,7 +296,7 @@ def test_fused_confusion_matrix_survives_midpass_flush():
         name="flush", layers=layers, loss_function="softmax",
         loader_name="synthetic_classifier", loader_config=dict(cfg),
         decision_config={"max_epochs": 1}, fused=True)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     # run the pass by hand, flushing after every minibatch
     while True:
         w.loader.run()

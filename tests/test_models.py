@@ -14,7 +14,7 @@ import time
 import numpy as np
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.models import (alexnet, autoencoder, cifar_conv, mnist_conv,
                               wine)
 
@@ -22,7 +22,7 @@ from znicz_tpu.models import (alexnet, autoencoder, cifar_conv, mnist_conv,
 def _train(build, seed=31, **kw):
     prng.seed_all(seed)
     w = build(**kw)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     assert bool(w.decision.complete)
     return w.decision.metrics_history
@@ -98,7 +98,7 @@ def test_run_load_main_shape():
                              minibatch_size=10, **kw)
 
     def main():
-        built["w"].initialize(device=TPUDevice())
+        built["w"].initialize(device=XLADevice())
         built["w"].run()
 
     wine.run(load, main)
@@ -112,7 +112,7 @@ def test_approximator_sample():
 
     prng.seed_all(31)
     w = approximator.build(max_epochs=5)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     np.testing.assert_allclose(
         [h["metric_validation"] for h in w.decision.metrics_history],
@@ -128,7 +128,7 @@ def test_approximator_nearest_target_classification():
     from znicz_tpu.core.backends import NumpyDevice
     from znicz_tpu.models import approximator
 
-    for device_cls in (NumpyDevice, TPUDevice):
+    for device_cls in (NumpyDevice, XLADevice):
         prng.seed_all(31)
         w = approximator.build(max_epochs=5, prototypes=5, fused=False)
         w.initialize(device=device_cls())
@@ -142,7 +142,7 @@ def test_approximator_nearest_target_classification():
 
     prng.seed_all(31)
     wf = approximator.build(max_epochs=5, prototypes=5)   # fused default
-    wf.initialize(device=TPUDevice())
+    wf.initialize(device=XLADevice())
     wf.run()
     np.testing.assert_allclose(
         [h["metric_validation"] for h in wf.decision.metrics_history],
@@ -162,7 +162,7 @@ def test_fused_nearest_target_skipped_for_noisy_targets():
 
     prng.seed_all(31)
     w = approximator.build(max_epochs=1, prototypes=5)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     # sabotage one stored target AFTER load: recovery assumption broken
     w.loader.original_targets.map_write()[0, 0] += 0.25
     assert not w.step._nt_recovery_valid()
@@ -171,7 +171,7 @@ def test_fused_nearest_target_skipped_for_noisy_targets():
 
     prng.seed_all(31)
     w2 = approximator.build(max_epochs=1, prototypes=5)
-    w2.initialize(device=TPUDevice())
+    w2.initialize(device=XLADevice())
     assert w2.step._nt_recovery_valid()   # pristine loader: proven exact
 
 
@@ -183,7 +183,7 @@ def test_tv_channels_sample():
 
     prng.seed_all(31)
     w = tv_channels.build(max_epochs=6)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     assert _validation(w.decision.metrics_history) == \
         [176, 178, 82, 37, 0, 0], w.decision.metrics_history
@@ -213,7 +213,7 @@ def test_image_ae_sample():
 
     prng.seed_all(31)
     w = image_ae.build(max_epochs=6)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     np.testing.assert_allclose(
         [h["metric_validation"] for h in w.decision.metrics_history],
@@ -240,7 +240,7 @@ def test_deep_autoencoder_sample():
     w = autoencoder.build_deep(max_epochs=3, minibatch_size=16,
                                sample_shape=(16, 16, 3),
                                n_kernels=(8, 16), n_train=64)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = w.decision.metrics_history
     assert w.forwards[-1].output.shape[1:] == (16, 16, 3)
@@ -264,7 +264,7 @@ def test_mnist_conv_bf16_convergence_pin():
     w = mnist_conv.build(max_epochs=12, minibatch_size=100, n_train=2000,
                          n_valid=500)
     w.step.compute_dtype = jnp.bfloat16
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     val = [int(h["metric_validation"]) for h in w.decision.metrics_history]
     # f32 pin for the same seed/config: [451, 443, 411, 315, 228, 128]

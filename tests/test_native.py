@@ -90,12 +90,12 @@ def _export_trained(build, tmp_path, name, **kw):
     import os
 
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.utils.export import export_forward
 
     prng.seed_all(7)
     w = build(**kw)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     return export_forward(w, os.path.join(str(tmp_path), name))
 

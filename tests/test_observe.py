@@ -18,7 +18,7 @@ import pytest
 
 from znicz_tpu import observe
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.logger import EVENT_LOGGER, configure, event_log
 from znicz_tpu.observe import probe
 from znicz_tpu.observe.registry import Registry
@@ -44,7 +44,7 @@ def run_workflow(max_epochs=2, seed=77, name="ObserveTest"):
         name=name, layers=LAYERS, loss_function="softmax",
         loader_name="synthetic_classifier", loader_config=LOADER,
         decision_config={"max_epochs": max_epochs})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     return w
 

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.standard_workflow import StandardWorkflow
 
 
@@ -44,7 +44,7 @@ def test_fused_adam_matches_optax():
 
     lr, wd = 0.01, 0.001
     w = build_adam(max_epochs=5, lr=lr, wd=wd)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     step = w.step
     # capture the (only) minibatch the workflow will train on — via the
     # HBM-pinned dataset + indices (serve_indices_only mode leaves
@@ -96,7 +96,7 @@ def test_adam_learns_faster_than_tiny_sgd():
     """Sanity: adam with its adaptive step actually trains (errors drop
     to ~0 on separable synthetic clusters)."""
     w = build_adam(max_epochs=12, lr=0.02)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = [h["metric_train"] for h in w.decision.metrics_history]
     assert hist[-1] <= hist[0] * 0.5, hist
@@ -115,13 +115,13 @@ def test_adam_snapshot_resume_bit_exact(tmp_path):
 
     # uninterrupted: 6 epochs
     w_full = build_adam(max_epochs=6, seed=99)
-    w_full.initialize(device=TPUDevice())
+    w_full.initialize(device=XLADevice())
     w_full.run()
     want = final_weights(w_full)
 
     # interrupted at 3, resumed to 6
     w_a = build_adam(max_epochs=3, seed=99)
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     arrays, meta = collect_state(w_a)
     snap = str(tmp_path / "adam.npz")
@@ -130,7 +130,7 @@ def test_adam_snapshot_resume_bit_exact(tmp_path):
     # same seed: the synthetic DATASET is generated at build time from
     # the prng (snapshots restore streams + shuffle order, not data)
     w_b = build_adam(max_epochs=6, seed=99)
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     restore_state(w_b, snap)
     # the snapshot was taken after w_a COMPLETED (max_epochs reached);
     # extending the run means lifting both the epoch cap and the stored
@@ -160,7 +160,7 @@ def test_cross_optimizer_resume_rejected(tmp_path):
         write_snapshot
 
     w_a = build_adam(max_epochs=1, seed=42)
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     arrays, meta = collect_state(w_a)
     assert meta["optimizer"] == "adam"
@@ -177,7 +177,7 @@ def test_cross_optimizer_resume_rejected(tmp_path):
         loader_config={"n_classes": 4, "sample_shape": (6,), "n_train": 40,
                        "n_valid": 0, "minibatch_size": 40},
         decision_config={"max_epochs": 1})
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     with pytest.raises(ValueError, match="snapshot optimizer"):
         restore_state(w_b, snap)
 
@@ -194,7 +194,7 @@ def test_adam_rejects_l1():
                        "n_valid": 0, "minibatch_size": 40},
         decision_config={"max_epochs": 1}, optimizer="adam")
     with pytest.raises(ValueError, match="l1_vs_l2 is SGD-only"):
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
 
 
 def test_shard_update_matches_replicated(cpu_devices):
@@ -213,7 +213,7 @@ def test_shard_update_matches_replicated(cpu_devices):
                             n_train=160, n_valid=64,
                             mesh=data_parallel_mesh(8),
                             optimizer=opt, shard_update=mode)
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
             w.step.sync_to_units()
             weights[mode] = {
@@ -250,14 +250,14 @@ def test_shard_update_adam_snapshot_roundtrip(tmp_path, cpu_devices):
                            optimizer="adam", shard_update=True)
 
     w_full = build(4)
-    w_full.initialize(device=TPUDevice())
+    w_full.initialize(device=XLADevice())
     w_full.run()
     w_full.step.sync_to_units()
     want = [np.asarray(f.weights.map_read()).copy()
             for f in w_full.forwards]
 
     w_a = build(2)
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     arrays, meta = collect_state(w_a)
     # state arrays carry the PARAM shape, not the shard layout
@@ -267,7 +267,7 @@ def test_shard_update_adam_snapshot_roundtrip(tmp_path, cpu_devices):
     write_snapshot(snap, arrays, meta)
 
     w_b = build(4)
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     restore_state(w_b, snap)
     w_b.decision.max_epochs = 4
     w_b.decision.complete.set(False)
@@ -297,7 +297,7 @@ def test_shard_update_snapshot_restores_across_layouts(tmp_path,
 
     # sharded over 8 devices, interrupted at 2 epochs
     w_a = build(2, 8, True)
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     arrays, meta = collect_state(w_a)
     snap = str(tmp_path / "x.npz")
@@ -305,7 +305,7 @@ def test_shard_update_snapshot_restores_across_layouts(tmp_path,
 
     # oracle: continue the SAME layout to 4 epochs
     w_o = build(4, 8, True)
-    w_o.initialize(device=TPUDevice())
+    w_o.initialize(device=XLADevice())
     w_o.run()
     w_o.step.sync_to_units()
     want = [np.asarray(f.weights.map_read()).copy()
@@ -313,7 +313,7 @@ def test_shard_update_snapshot_restores_across_layouts(tmp_path,
 
     # resume REPLICATED on a 2-device mesh from the sharded snapshot
     w_b = build(4, 2, False)
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     restore_state(w_b, snap)
     w_b.decision.max_epochs = 4
     w_b.decision.complete.set(False)
@@ -351,7 +351,7 @@ def test_clip_norm_matches_manual_oracle():
     results = {}
     for clip in (0.5, 1e9):
         w = build(clip)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         step = w.step
         w.loader.run()
         idx = np.maximum(np.asarray(w.loader.minibatch_indices.mem), 0)
@@ -448,7 +448,7 @@ def test_accumulation_matches_big_minibatch(optimizer):
     weights = {}
     for minibatch, accumulate in ((64, 1), (16, 4)):
         w = _accum_build(minibatch, accumulate, optimizer)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
         w.step.sync_to_units()
         weights[(minibatch, accumulate)] = [
@@ -463,7 +463,7 @@ def test_accumulation_ragged_tail_applies_at_epoch_end():
     """A train pass shorter than accumulate_steps still applies its
     gradients at the pass boundary (no leak into the next epoch)."""
     w = _accum_build(16, 4, n_train=48, max_epochs=4)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     assert w.step._grad_acc is None
     hist = [h["metric_train"] for h in w.decision.metrics_history]
@@ -498,7 +498,7 @@ def test_accumulation_composes_with_shard_update(cpu_devices):
                         n_train=64, n_valid=0,
                         mesh=data_parallel_mesh(8), optimizer="adam",
                         shard_update=shard, accumulate_steps=2)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
         w.step.sync_to_units()
         assert w.step._grad_acc is None
@@ -530,7 +530,7 @@ def test_pallas_kernels_compose_with_accumulation(cpu_devices):
                             n_train=64, n_valid=0,
                             mesh=data_parallel_mesh(8), optimizer="adam",
                             accumulate_steps=2)
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
             w.step.sync_to_units()
             return [np.asarray(f.weights.map_read()).copy()
@@ -557,7 +557,7 @@ def test_ema_matches_manual_average():
     prng.seed_all(61)
     w = build_fused(max_epochs=1, layers=(16,), minibatch_size=20,
                     n_train=100, n_valid=0, ema_decay=d)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     assert all("ew" in leaf for leaf in w.step._params)
 
     manual = [np.asarray(jax.device_get(leaf["w"]))
@@ -598,7 +598,7 @@ def test_ema_snapshots_and_restores():
             decision_config={"max_epochs": 1}, ema_decay=0.9)
 
     w = build(5)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     ema = w.step.ema_params()
     arrays, meta = collect_state(w)
@@ -608,7 +608,7 @@ def test_ema_snapshots_and_restores():
         path = os.path.join(tmp, "s.npz")
         write_snapshot(path, arrays, meta)
         w2 = build(6)
-        w2.initialize(device=TPUDevice())
+        w2.initialize(device=XLADevice())
         restore_state(w2, path)
     ema2 = w2.step.ema_params()
     for a, b in zip(ema, ema2):
@@ -644,7 +644,7 @@ def test_ema_snapshots_and_restores():
                            "minibatch_size": 20},
             decision_config={"max_epochs": 1})
         prng.seed_all(8)
-        w3.initialize(device=TPUDevice())
+        w3.initialize(device=XLADevice())
         with pytest.raises(ValueError, match="EMA weight mirrors"):
             restore_state(w3, path)
 
@@ -666,7 +666,7 @@ def test_export_forward_with_ema_weights(tmp_path):
                        "n_train": 90, "n_valid": 0,
                        "minibatch_size": 30},
         decision_config={"max_epochs": 2}, ema_decay=0.7)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
 
     raw_path = export_forward(w, str(tmp_path / "raw.npz"))
@@ -697,7 +697,7 @@ def test_export_forward_with_ema_weights(tmp_path):
                        "n_train": 30, "n_valid": 0,
                        "minibatch_size": 10},
         decision_config={"max_epochs": 1})
-    w2.initialize(device=TPUDevice())
+    w2.initialize(device=XLADevice())
     w2.run()
     with pytest.raises(ValueError, match="ema_decay"):
         export_forward(w2, str(tmp_path / "x.npz"), use_ema=True)
@@ -739,7 +739,7 @@ def test_everything_on_composition(tmp_path, cpu_devices):
             ema_decay=0.9)
 
     w = build(77)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = [h["metric_validation"] for h in w.decision.metrics_history]
     assert len(hist) == 2 and all(np.isfinite(hist))
@@ -750,7 +750,7 @@ def test_everything_on_composition(tmp_path, cpu_devices):
     snap = str(tmp_path / "allon.npz")
     write_snapshot(snap, arrays, meta)
     w2 = build(78)
-    w2.initialize(device=TPUDevice())
+    w2.initialize(device=XLADevice())
     restore_state(w2, snap)
     for a, b in zip(ema, w2.step.ema_params()):
         np.testing.assert_array_equal(a["w"], b["w"])
@@ -789,7 +789,7 @@ def test_state_dtype_bf16_tracks_f32():
     runs = {}
     for sd in (None, "bfloat16"):
         w = build_sgd_momentum(max_epochs=6, seed=91, state_dtype=sd)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         want = jnp.bfloat16 if sd else jnp.float32
         assert w.step._params[0]["vw"].dtype == want
         w.run()
@@ -816,13 +816,13 @@ def test_state_dtype_snapshot_resume_bit_exact(tmp_path):
 
     w_full = build_sgd_momentum(max_epochs=6, seed=17,
                                 state_dtype="bfloat16")
-    w_full.initialize(device=TPUDevice())
+    w_full.initialize(device=XLADevice())
     w_full.run()
     want = final_weights(w_full)
 
     w_a = build_sgd_momentum(max_epochs=3, seed=17,
                              state_dtype="bfloat16")
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     arrays, meta = collect_state(w_a)
     snap = str(tmp_path / "sgdstate.npz")
@@ -830,7 +830,7 @@ def test_state_dtype_snapshot_resume_bit_exact(tmp_path):
 
     w_b = build_sgd_momentum(max_epochs=6, seed=17,
                              state_dtype="bfloat16")
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     restore_state(w_b, snap)
     w_b.decision.max_epochs = 6
     w_b.decision.complete.set(False)
@@ -862,7 +862,7 @@ def test_state_dtype_shard_update_scan(cpu_devices):
                         optimizer="sgd", shard_update=mode,
                         optimizer_config={"state_dtype": "bfloat16"})
         w.step.scan_epoch = True
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         assert w.step._params[0]["vw"].dtype == jnp.bfloat16, \
             "narrowing undone by the sharded placement"
         w.run()

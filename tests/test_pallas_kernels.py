@@ -73,7 +73,7 @@ def test_pallas_sgd_in_fused_workflow():
     """End-to-end: the fused training step with the Pallas SGD backend
     reproduces the default XLA-fused run bit-for-bit."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.models import wine
 
@@ -81,7 +81,7 @@ def test_pallas_sgd_in_fused_workflow():
         prng.seed_all(17)
         w = wine.build(max_epochs=2, n_train=60, n_valid=30,
                        minibatch_size=10)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
         w.stop()
         return w
@@ -190,11 +190,9 @@ def test_pallas_stochastic_pool_prng_branch_plumbing():
     patch, valid, _ = pool_ops.patches(np, x, 2, 2, 2, 2, pad_value=0.0)
     n, oh, ow, K, c = patch.shape
     vtile = np.broadcast_to(valid.reshape(1, oh * ow, K), (n, oh * ow, K))
-    from znicz_tpu.utils.pallas_hw import tpu_interpret_params
+    from jax.experimental.pallas import tpu as pltpu
 
-    interp = tpu_interpret_params()
-    if interp is None:
-        pytest.skip("no TPU-emulating pallas interpreter in this jax")
+    interp = pltpu.InterpretParams()
     y, tap = stochastic_pool(
         jnp.asarray(patch.reshape(n * oh * ow, K, c)),
         jnp.asarray(vtile.reshape(n * oh * ow, K)), seed=3,
@@ -226,7 +224,7 @@ def test_pallas_conv_unit_selection():
     """root.common.engine.pallas routes Conv.xla_run through the im2col
     kernel with identical outputs."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.core.workflow import Workflow
     from znicz_tpu.units.conv import Conv
@@ -240,7 +238,7 @@ def test_pallas_conv_unit_selection():
         conv.input = Array()
         conv.input.mem = np.random.default_rng(5).normal(
             size=(4, 9, 9, 3)).astype(np.float32)
-        conv.initialize(device=TPUDevice())
+        conv.initialize(device=XLADevice())
         conv.xla_run()
         return np.asarray(conv.output.map_read())
 
@@ -258,14 +256,14 @@ def test_pallas_conv_unit_selection():
 def test_pallas_kohonen_trainer_selection():
     """SOM demo trains identically through the fused Pallas step."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.models import kohonen as km
 
     def run_once():
         prng.seed_all(21)
         w = km.build(max_epochs=2, shape=(5, 5), n_train=200)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
         return np.asarray(w.trainer.weights.map_read())
 
@@ -284,7 +282,7 @@ def test_pallas_stochastic_pooling_unit_selection():
     """The stochastic pooling unit's Pallas path emits values from the
     right windows with offsets consistent with the emitted values."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.core.memory import Array
     from znicz_tpu.core.workflow import Workflow
@@ -300,7 +298,7 @@ def test_pallas_stochastic_pooling_unit_selection():
         x = np.random.default_rng(6).normal(
             size=(3, 6, 6, 4)).astype(np.float32)
         unit.input.mem = x
-        unit.initialize(device=TPUDevice())
+        unit.initialize(device=XLADevice())
         unit.xla_run()
     finally:
         root.common.engine.pallas = False
@@ -391,7 +389,7 @@ def test_pallas_adam_workflow_matches_xla():
     adam kernel (interpret mode) and matches the XLA path's training."""
     from znicz_tpu.core.config import root
     from znicz_tpu.core import prng as prng_mod
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.standard_workflow import StandardWorkflow
 
     def run(pallas: bool):
@@ -409,7 +407,7 @@ def test_pallas_adam_workflow_matches_xla():
                                "n_train": 30, "n_valid": 0,
                                "minibatch_size": 30},
                 decision_config={"max_epochs": 3}, optimizer="adam")
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
             w.step.sync_to_units()
             return np.asarray(w.forwards[0].weights.map_read()).copy()
@@ -489,7 +487,7 @@ def test_pallas_gd_conv_unit_selection():
     tanh activation correction) through the hand-written backward with
     identical training effect."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.core.memory import Array
     from znicz_tpu.core.workflow import Workflow
@@ -503,7 +501,7 @@ def test_pallas_gd_conv_unit_selection():
         fwd = ConvTanh(w, n_kernels=6, kx=3, ky=3, sliding=(2, 2),
                        padding=(1, 1, 1, 1))
         fwd.input = Array(rng.normal(size=(3, 8, 8, 2)).astype(np.float32))
-        fwd.initialize(device=TPUDevice())
+        fwd.initialize(device=XLADevice())
         fwd.run()
         gd = GDTanhConv(w, learning_rate=0.1, weights_decay=0.01,
                         gradient_moment=0.9)
@@ -511,7 +509,7 @@ def test_pallas_gd_conv_unit_selection():
         gd.err_output = Array(rng.normal(size=fwd.output.shape)
                               .astype(np.float32))
         gd.batch_size = 3
-        gd.initialize(device=TPUDevice())
+        gd.initialize(device=XLADevice())
         gd.run()
         return {a: np.asarray(getattr(gd, a).map_read()).copy()
                 for a in ("err_input", "weights", "bias",
@@ -534,7 +532,7 @@ def test_pallas_deconv_unit_selection():
     """root.common.engine.pallas routes Deconv + GDDeconv through the
     hand-written transposed-conv pair with identical results."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.core.memory import Array
     from znicz_tpu.core.workflow import Workflow
@@ -548,14 +546,14 @@ def test_pallas_deconv_unit_selection():
         fwd = Deconv(w, n_kernels=6, kx=3, ky=3, n_channels=2,
                      sliding=(2, 2), padding=(1, 1, 1, 1))
         fwd.input = Array(rng.normal(size=(2, 4, 4, 6)).astype(np.float32))
-        fwd.initialize(device=TPUDevice())
+        fwd.initialize(device=XLADevice())
         fwd.run()
         gd = GDDeconv(w, learning_rate=0.1, gradient_moment=0.9)
         gd.link_from_forward(fwd)
         gd.err_output = Array(rng.normal(size=fwd.output.shape)
                               .astype(np.float32))
         gd.batch_size = 2
-        gd.initialize(device=TPUDevice())
+        gd.initialize(device=XLADevice())
         gd.run()
         return {"out": np.asarray(fwd.output.map_read()).copy(),
                 "err_input": np.asarray(gd.err_input.map_read()).copy(),
@@ -573,30 +571,6 @@ def test_pallas_deconv_unit_selection():
     for attr, want in base.items():
         np.testing.assert_allclose(pallas[attr], want, rtol=1e-4,
                                    atol=1e-5, err_msg=attr)
-
-
-def test_pallas_hw_parity_sweep_interpret():
-    """The compiled-mode hardware sweep (bench.py::bench_pallas_parity)
-    must cover every kernel family and pass fully under the interpreter —
-    so a chip-window run can only fail for hardware/lowering reasons."""
-    from znicz_tpu.utils.pallas_hw import run_parity, tpu_interpret_params
-
-    res = run_parity(interpret=True)
-    assert set(res) == {"sgd", "adam", "dropout", "lrn", "fc_gemm",
-                        "conv_fwd", "conv_bwd", "deconv",
-                        "stochastic_pool", "kohonen", "flash_attention",
-                        "conv_fwd_bf16", "flash_attention_bf16",
-                        "sgd_bf16state"}
-    skipped = {k for k, v in res.items() if v.startswith("skipped:")}
-    if tpu_interpret_params() is None:
-        # pre-InterpretParams jax: exactly the in-kernel-PRNG pair may
-        # skip under the interpreter (they still run compiled on chip)
-        assert skipped <= {"dropout", "stochastic_pool"}, res
-    else:
-        assert not skipped, res
-    bad = {k: v for k, v in res.items()
-           if v != "ok" and k not in skipped}
-    assert not bad, bad
 
 
 # -- round-4 parity tail 2: the blocked FC GEMM (matrix_multiplication) ------
@@ -637,7 +611,7 @@ def test_pallas_fc_unit_selection():
     """engine.pallas routes All2AllTanh + GDTanh through the blocked
     GEMM kernels with identical training effect."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.core.memory import Array
     from znicz_tpu.core.workflow import Workflow
@@ -650,7 +624,7 @@ def test_pallas_fc_unit_selection():
         w = Workflow(name="fc")
         fwd = All2AllTanh(w, output_sample_shape=24)
         fwd.input = Array(rng.normal(size=(16, 33)).astype(np.float32))
-        fwd.initialize(device=TPUDevice())
+        fwd.initialize(device=XLADevice())
         fwd.run()
         gd = GDTanh(w, learning_rate=0.1, weights_decay=0.01,
                     gradient_moment=0.9)
@@ -658,7 +632,7 @@ def test_pallas_fc_unit_selection():
         gd.err_output = Array(rng.normal(size=fwd.output.shape)
                               .astype(np.float32))
         gd.batch_size = 16
-        gd.initialize(device=TPUDevice())
+        gd.initialize(device=XLADevice())
         gd.run()
         return {a: np.asarray(getattr(gd, a).map_read()).copy()
                 for a in ("err_input", "weights", "bias",
@@ -682,7 +656,7 @@ def test_pallas_gd_override_cleared_on_numpy_reinit():
     re-initialized onto the numpy backend, must run the numpy oracle —
     not the stale Pallas closure (GradientDescentBase.numpy_init)."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+    from znicz_tpu.core.backends import NumpyDevice, XLADevice
     from znicz_tpu.core.config import root
     from znicz_tpu.core.memory import Array
     from znicz_tpu.core.workflow import Workflow
@@ -697,14 +671,14 @@ def test_pallas_gd_override_cleared_on_numpy_reinit():
     root.common.engine.pallas = True
     root.common.engine.pallas_interpret = True
     try:
-        fwd.initialize(device=TPUDevice())
+        fwd.initialize(device=XLADevice())
         fwd.run()
         gd = GDTanh(w, learning_rate=0.1)
         gd.link_from_forward(fwd)
         gd.err_output = Array(rng.normal(size=fwd.output.shape)
                               .astype(np.float32))
         gd.batch_size = 4
-        gd.initialize(device=TPUDevice())
+        gd.initialize(device=XLADevice())
         gd.run()
         assert "_backward" in gd.__dict__      # override installed
     finally:

@@ -15,7 +15,7 @@ import pytest
 import jax
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.models.mnist_fc import build_eager, build_fused
 from znicz_tpu.parallel.mesh import data_parallel_mesh, make_mesh
 
@@ -36,7 +36,7 @@ def test_fused_step_matches_eager_units():
 
     prng.seed_all(77)
     wf = build_fused(max_epochs=1, n_valid=0, n_train=200, minibatch_size=50)
-    wf.initialize(device=TPUDevice())
+    wf.initialize(device=XLADevice())
     wf.loader.run()
     wf.step.run()
     wf.step.sync_to_units()
@@ -58,7 +58,7 @@ def test_fused_step_matches_eager_units():
 def run_fused(seed, mesh, max_epochs=3):
     prng.seed_all(seed)
     w = build_fused(max_epochs=max_epochs, mesh=mesh)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     w.step.sync_to_units()
     return w
@@ -112,7 +112,7 @@ def test_train_steps_scan_matches_sequential(cpu_devices):
         prng.seed_all(23)
         w = build_fused(max_epochs=1, n_valid=0, n_train=240,
                         minibatch_size=40, mesh=mesh)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         return w
 
     rng = np.random.default_rng(3)
@@ -205,10 +205,10 @@ def test_scan_epoch_refuses_per_minibatch_lr_schedule(cpu_devices):
     try:
         w = build(by_epoch=False)
         with pytest.raises(ValueError, match="by_epoch=False.*coarsen"):
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
         # by_epoch=True is pass-granular already: must initialize fine
         w_ok = build(by_epoch=True)
-        w_ok.initialize(device=TPUDevice())
+        w_ok.initialize(device=XLADevice())
         assert w_ok.step._scan_idx_fns
     finally:
         root.common.engine.scan_epoch = False
@@ -229,7 +229,7 @@ def test_scan_epoch_single_minibatch_classes(cpu_devices):
             w = build_fused(max_epochs=3, n_train=160, n_valid=80,
                             minibatch_size=160,
                             mesh=data_parallel_mesh(4))
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
             w.step.sync_to_units()
         finally:
@@ -256,7 +256,7 @@ def test_scan_epoch_midpass_entry_falls_back(cpu_devices):
     try:
         w = build_fused(max_epochs=1, n_train=200, n_valid=0,
                         minibatch_size=40, mesh=data_parallel_mesh(4))
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
     finally:
         root.common.engine.scan_epoch = False
     loader, step = w.loader, w.step
@@ -296,7 +296,7 @@ def test_scan_epoch_mse_workflow(cpu_devices):
             w = autoencoder.build(max_epochs=3, n_train=200, n_valid=64,
                                   minibatch_size=40, sample_shape=(12, 12, 1),
                                   mesh=data_parallel_mesh(4))
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
         finally:
             root.common.engine.scan_epoch = False
@@ -312,7 +312,7 @@ def test_lr_schedule_no_recompile(cpu_devices):
     steps must not retrigger compilation."""
     prng.seed_all(5)
     w = build_fused(max_epochs=1, mesh=data_parallel_mesh(8))
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.loader.run()
     while int(w.loader.minibatch_class) != 2:
         w.loader.run()
@@ -339,7 +339,7 @@ def test_fused_step_bf16_compute_tracks_f32():
         w = build(max_epochs=2, minibatch_size=50, n_train=200, n_valid=50,
                   loader_name="synthetic_image")
         w.step.compute_dtype = cdt
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
         losses[name] = [h["metric_train"] for h in
                         w.decision.metrics_history]

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.config import root
 from znicz_tpu.loader.synthetic import SyntheticClassifierLoader
 from znicz_tpu.pipeline import (BatchPrefetcher, PrefetcherStopped,
@@ -47,7 +47,7 @@ def build(max_epochs, snap_dir=None, seed=77, depth=None):
         decision_config={"max_epochs": max_epochs},
         snapshotter_config=cfg,
         pipeline_config={"depth": depth} if depth else None)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     return w
 
 

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.resilience import faults
 from znicz_tpu.resilience.retry import AttemptTimeout, RetryPolicy
 from znicz_tpu.resilience.supervisor import (SupervisorExhausted,
@@ -48,7 +48,7 @@ def build(max_epochs, snap_dir=None, seed=77, health=None, fused=True,
         decision_config={"max_epochs": max_epochs},
         snapshotter_config=cfg, health_config=health, fused=fused,
         defer_metrics=defer_metrics)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     return w
 
 

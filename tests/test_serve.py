@@ -540,7 +540,7 @@ def test_web_status_reports_serving_metrics():
 
 def _export_tiny_package(tmp_path):
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.standard_workflow import StandardWorkflow
     from znicz_tpu.utils.export import export_forward
 
@@ -553,7 +553,7 @@ def _export_tiny_package(tmp_path):
         loader_config={"n_classes": 3, "sample_shape": (6,), "n_train": 60,
                        "n_valid": 0, "minibatch_size": 20},
         decision_config={"max_epochs": 1})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     pkg = str(tmp_path / "srv_cli.npz")
     export_forward(w, pkg)

@@ -8,7 +8,7 @@ import urllib.request
 import numpy as np
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.memory import Array
 from znicz_tpu.models import kohonen as kohonen_model, wine
 from znicz_tpu.plotting import (AccumulatingPlotter, Histogram, ImagePlotter,
@@ -26,7 +26,7 @@ def _trained_wine(seed=3, **kw):
     prng.seed_all(seed)
     w = wine.build(max_epochs=3, n_train=60, n_valid=30, minibatch_size=10,
                    **kw)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     w.stop()
     return w
@@ -74,7 +74,7 @@ def test_tile_filters_shapes():
 def test_kohonen_plotters(tmp_path):
     prng.seed_all(23)
     w = kohonen_model.build(max_epochs=2, shape=(4, 4), n_train=200)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     w.forward.batch_size = 50
     w.forward.input = w.loader.minibatch_data
@@ -157,14 +157,14 @@ def test_export_and_forge_roundtrip(tmp_path):
 def test_forge_upload_fetch_roundtrip(tmp_path):
     import numpy as np
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.models import wine
     from znicz_tpu.utils.export import ExportedForward
     from znicz_tpu.utils.forge import ForgeRegistry
 
     prng.seed_all(3)
     w = wine.build(max_epochs=2, n_train=60, n_valid=30, minibatch_size=10)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     w.stop()
 
@@ -214,12 +214,12 @@ def test_forge_detects_corruption(tmp_path):
 
 def test_launcher_profile_trace(tmp_path):
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.launcher import Launcher
     from znicz_tpu.models import wine
 
     prng.seed_all(3)
-    launcher = Launcher(device=TPUDevice(),
+    launcher = Launcher(device=XLADevice(),
                         profile_dir=str(tmp_path / "trace"))
     launcher.load(wine.build, max_epochs=1, n_train=60, n_valid=30,
                   minibatch_size=10)
@@ -304,7 +304,7 @@ def test_launcher_serves_manhole():
     from znicz_tpu.models import wine
 
     prng.seed_all(3)
-    launcher = Launcher(device=TPUDevice(), manhole_path="")
+    launcher = Launcher(device=XLADevice(), manhole_path="")
     launcher.load(wine.build, max_epochs=1, n_train=60, n_valid=30,
                   minibatch_size=10)
 

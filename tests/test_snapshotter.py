@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.snapshotter import collect_state, restore_state, write_snapshot
 from znicz_tpu.standard_workflow import StandardWorkflow
 
@@ -34,7 +34,7 @@ def build(max_epochs, snap_dir=None, fused=True, seed=77, **snap_kw):
         loader_name="synthetic_classifier", loader_config=LOADER,
         decision_config={"max_epochs": max_epochs},
         snapshotter_config=cfg, fused=fused)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     return w
 
 
@@ -100,7 +100,7 @@ def test_elastic_resume_across_mesh_sizes(tmp_path, cpu_devices, from_dev,
         loader_name="synthetic_classifier", loader_config=LOADER,
         decision_config={"max_epochs": 4}, fused=True,
         mesh=data_parallel_mesh(1))
-    w_full.initialize(device=TPUDevice())
+    w_full.initialize(device=XLADevice())
     w_full.run()
     full_hist = w_full.decision.metrics_history
 
@@ -114,7 +114,7 @@ def test_elastic_resume_across_mesh_sizes(tmp_path, cpu_devices, from_dev,
         snapshotter_config={"directory": str(tmp_path), "prefix": "e",
                             "only_improved": False, "keep_all": True},
         fused=True, mesh=data_parallel_mesh(from_dev))
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     snap = tmp_path / "e_2.npz"
     assert snap.exists()
@@ -127,7 +127,7 @@ def test_elastic_resume_across_mesh_sizes(tmp_path, cpu_devices, from_dev,
         loader_name="synthetic_classifier", loader_config=LOADER,
         decision_config={"max_epochs": 4}, fused=True,
         mesh=data_parallel_mesh(to_dev))
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     restore_state(w_b, str(snap))
     w_b.run()
     resumed = w_b.decision.metrics_history
@@ -148,7 +148,7 @@ def test_snapshot_kohonen_workflow(tmp_path):
 
     prng.seed_all(23)
     w = kohonen_model.build(max_epochs=2, shape=(6, 6), n_train=200)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     arrays, meta = collect_state(w)
     assert "forward.0.weights" in arrays
@@ -158,7 +158,7 @@ def test_snapshot_kohonen_workflow(tmp_path):
 
     prng.seed_all(9)
     w2 = kohonen_model.build(max_epochs=2, shape=(6, 6), n_train=200)
-    w2.initialize(device=TPUDevice())
+    w2.initialize(device=XLADevice())
     restore_state(w2, path)
     np.testing.assert_array_equal(w2.trainer.weights.map_read(),
                                   arrays["forward.0.weights"])

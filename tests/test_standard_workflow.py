@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.loader.base import get_loader
 from znicz_tpu.standard_workflow import StandardWorkflow
 
@@ -33,7 +33,7 @@ def build_conv(fused, max_epochs=3, seed=21):
         name="ConvStd", layers=CONV_LAYERS, loss_function="softmax",
         loader_name="synthetic_image", loader_config=IMAGE_LOADER,
         decision_config={"max_epochs": max_epochs}, fused=fused)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     return w
 
@@ -80,7 +80,7 @@ def test_mse_standard_workflow(fused):
         loader_config={"sample_shape": (16,), "target_shape": (4,),
                        "n_train": 256, "n_valid": 64, "minibatch_size": 32},
         decision_config={"max_epochs": 3}, fused=fused)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     dec = w.decision
     assert bool(dec.complete)
@@ -103,7 +103,7 @@ def test_flat_shorthand_and_registry():
         loader_name="synthetic_classifier",
         loader_config={"minibatch_size": 20, "n_train": 100, "n_valid": 0},
         decision_config={"max_epochs": 1})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     assert bool(w.decision.complete)
     assert w.forwards[0].output_sample_shape == (16,)

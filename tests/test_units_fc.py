@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.core.memory import Array
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.units.all2all import (All2All, All2AllSoftmax, All2AllTanh,
@@ -31,7 +31,7 @@ def test_forward_backend_parity(cls):
     rng = np.random.default_rng(5)
     x = rng.normal(size=(8, 12)).astype(np.float32)
     u_np = make_forward(cls, NumpyDevice(), x, output_sample_shape=7)
-    u_xla = make_forward(cls, TPUDevice(), x, output_sample_shape=7)
+    u_xla = make_forward(cls, XLADevice(), x, output_sample_shape=7)
     np.testing.assert_allclose(u_xla.output.map_read(),
                                u_np.output.map_read(), rtol=1e-5, atol=1e-5)
     # same seed => identical weight init across backends
@@ -69,7 +69,7 @@ def test_gd_backend_parity(fwd_cls, gd_cls):
     err = rng.normal(size=(6, 4)).astype(np.float32)
     kwargs = dict(learning_rate=0.1, weights_decay=0.01, gradient_moment=0.9)
     _, gd_np = make_gd_pair(fwd_cls, gd_cls, NumpyDevice(), x, err, **kwargs)
-    _, gd_xla = make_gd_pair(fwd_cls, gd_cls, TPUDevice(), x, err, **kwargs)
+    _, gd_xla = make_gd_pair(fwd_cls, gd_cls, XLADevice(), x, err, **kwargs)
     for attr in ("err_input", "weights", "bias", "gradient_weights",
                  "gradient_bias"):
         np.testing.assert_allclose(

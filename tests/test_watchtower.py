@@ -19,7 +19,7 @@ import pytest
 
 from znicz_tpu import observe
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.logger import JsonlHandler
 from znicz_tpu.observe import flight, probe, watchtower
 from znicz_tpu.observe.registry import REGISTRY, Registry, \
@@ -56,7 +56,7 @@ def build(max_epochs, snap_dir=None, seed=77, tower=None):
         loader_name="synthetic_classifier", loader_config=LOADER,
         decision_config={"max_epochs": max_epochs},
         snapshotter_config=cfg)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     if tower is not None:
         tower.attach(w)
     return w

@@ -16,7 +16,7 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import NumpyDevice, XLADevice
 from znicz_tpu.standard_workflow import StandardWorkflow
 
 SETTINGS = dict(max_examples=8, deadline=None, derandomize=True)
@@ -109,7 +109,7 @@ def test_fused_matches_eager_for_random_stacks(case):
         # counter-based) — exact update parity does not apply; instead
         # assert BOTH shapes actually trained: finite params that moved
         # from their init, captured AFTER initialize, BEFORE the step
-        for fused, device in ((True, TPUDevice()), (False, NumpyDevice())):
+        for fused, device in ((True, XLADevice()), (False, NumpyDevice())):
             w = _build(stack, seed, fused)
             w.initialize(device=device)
             init = [f.weights.map_read().copy() for f in w.forwards
@@ -123,7 +123,7 @@ def test_fused_matches_eager_for_random_stacks(case):
                 assert np.isfinite(t).all(), fused
         return
     we = _one_step(stack, seed, False, NumpyDevice())
-    wf = _one_step(stack, seed, True, TPUDevice())
+    wf = _one_step(stack, seed, True, XLADevice())
     checked = 0
     for i, (fe, ff) in enumerate(zip(we.forwards, wf.forwards)):
         if not fe.weights:
@@ -153,13 +153,13 @@ def test_random_stacks_snapshot_roundtrip(case):
                                        write_snapshot)
 
     stack, seed = case
-    w = _one_step(stack, seed, True, TPUDevice())
+    w = _one_step(stack, seed, True, XLADevice())
     arrays, meta = collect_state(w)
     with tempfile.TemporaryDirectory() as d:
         path = os.path.join(d, "s.npz")
         write_snapshot(path, arrays, meta)
         # fresh build, DIFFERENT seed: restore must overwrite everything
-        w2 = _one_step(stack, seed + 1, True, TPUDevice())
+        w2 = _one_step(stack, seed + 1, True, XLADevice())
         restore_state(w2, path)
         w2.step.sync_to_units()
     for i, (fa, fb) in enumerate(zip(w.forwards, w2.forwards)):
@@ -219,7 +219,7 @@ def test_ae_fused_matches_eager_for_random_geometry(case):
         return _run_one_minibatch(w, fused)
 
     we = one_step(False, NumpyDevice())
-    wf = one_step(True, TPUDevice())
+    wf = one_step(True, XLADevice())
     for i, (fe, ff) in enumerate(zip(we.forwards, wf.forwards)):
         np.testing.assert_allclose(
             ff.weights.map_read(), fe.weights.map_read(),
@@ -242,7 +242,7 @@ def test_ae_fused_matches_eager_for_random_geometry(case):
     with tempfile.TemporaryDirectory() as d:
         path = os.path.join(d, "s.npz")
         write_snapshot(path, arrays, meta)
-        w2 = one_step(True, TPUDevice())
+        w2 = one_step(True, XLADevice())
         restore_state(w2, path)
         w2.step.sync_to_units()
     for fa, fb in zip(wf.forwards, w2.forwards):
@@ -273,7 +273,7 @@ def test_pallas_engine_matches_xla_for_random_stacks(case):
         root.common.engine.pallas_interpret = pallas
         try:
             w = _build(stack, seed, fused=False)
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             return _run_one_minibatch(w, fused=False)
         finally:
             root.common.engine.pallas = False
@@ -337,7 +337,7 @@ def test_quantized_collectives_across_flag_combos(case):
             optimizer_config=({"state_dtype": state_dtype}
                               if state_dtype else None),
             ema_decay=ema_decay, quantized_collectives=qc, **flags)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
         return [h["metric_validation"]
                 for h in w.decision.metrics_history]

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.config import root
 from znicz_tpu.models.mnist_fc import build_fused
 from znicz_tpu.observe import registry
@@ -55,7 +55,7 @@ def test_shard_params_matches_replicated(cpu_devices):
                             minibatch_size=32, n_train=160, n_valid=64,
                             mesh=data_parallel_mesh(8), optimizer=opt,
                             **LAYOUTS[layout])
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
             w.step.sync_to_units()
             runs[layout] = {
@@ -90,7 +90,7 @@ def test_cross_layout_snapshot_matrix(tmp_path, cpu_devices):
     # one oracle serves every same-mesh cell: the three layouts are
     # bit-identical (pinned above)
     w_o = _build(4, 8, "replicated")
-    w_o.initialize(device=TPUDevice())
+    w_o.initialize(device=XLADevice())
     w_o.run()
     want = _weights(w_o)
     want_hist = [h["metric_train"] for h in w_o.decision.metrics_history]
@@ -102,7 +102,7 @@ def test_cross_layout_snapshot_matrix(tmp_path, cpu_devices):
               ("shard_params", "shard_params")]
     for src, dst in matrix:
         w_a = _build(2, 8, src)
-        w_a.initialize(device=TPUDevice())
+        w_a.initialize(device=XLADevice())
         w_a.run()
         arrays, meta = collect_state(w_a)
         # state arrays always carry the PARAM shape, never the layout
@@ -112,7 +112,7 @@ def test_cross_layout_snapshot_matrix(tmp_path, cpu_devices):
         write_snapshot(snap, arrays, meta)
 
         w_b = _build(4, 8, dst)
-        w_b.initialize(device=TPUDevice())
+        w_b.initialize(device=XLADevice())
         restore_state(w_b, snap)
         w_b.decision.max_epochs = 4
         w_b.decision.complete.set(False)
@@ -136,7 +136,7 @@ def test_cross_layout_elastic_resume_other_world_size(tmp_path,
     for src, n_src, dst, n_dst in (("shard_params", 8, "replicated", 2),
                                    ("replicated", 2, "shard_params", 8)):
         w_a = _build(2, n_src, src)
-        w_a.initialize(device=TPUDevice())
+        w_a.initialize(device=XLADevice())
         w_a.run()
         arrays, meta = collect_state(w_a)
         snap = str(tmp_path / f"ws_{src}_{dst}.npz")
@@ -144,12 +144,12 @@ def test_cross_layout_elastic_resume_other_world_size(tmp_path,
 
         # oracle: continue at the SOURCE world size and layout
         w_o = _build(4, n_src, src)
-        w_o.initialize(device=TPUDevice())
+        w_o.initialize(device=XLADevice())
         w_o.run()
         want = _weights(w_o)
 
         w_b = _build(4, n_dst, dst)
-        w_b.initialize(device=TPUDevice())
+        w_b.initialize(device=XLADevice())
         restore_state(w_b, snap)
         w_b.decision.max_epochs = 4
         w_b.decision.complete.set(False)
@@ -169,7 +169,7 @@ def test_shard_params_memory_gauges(cpu_devices):
     totals = {}
     for layout in ("replicated", "shard_params"):
         w = _build(1, n, layout)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         totals[layout] = (_gauge("znicz_zero_param_bytes") +
                           _gauge("znicz_zero_opt_state_bytes"))
         if layout != "shard_params":
@@ -196,7 +196,7 @@ def test_shard_params_zero_retrace(cpu_devices):
     w = build_fused(max_epochs=3, layers=(16,), minibatch_size=16,
                     n_train=64, n_valid=32, mesh=data_parallel_mesh(8),
                     optimizer="adam", shard_params=True)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     # the small synthetic dataset rides the HBM-pinned index-fed path
     train_fn = w.step._train_fn_idx or w.step._train_fn
@@ -218,7 +218,7 @@ def test_shard_params_composes_with_accumulation_and_ema(cpu_devices):
                         mesh=data_parallel_mesh(4), optimizer="sgd",
                         accumulate_steps=2, ema_decay=0.9,
                         **LAYOUTS[layout])
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.run()
         runs[layout] = {
             "hist": [h["metric_validation"]
@@ -249,7 +249,7 @@ def test_snapshot_d2h_batched(cpu_devices, monkeypatch):
                         n_train=32, n_valid=0,
                         mesh=data_parallel_mesh(4), optimizer="adam",
                         shard_params=True, ema_decay=0.9)
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         w.loader.run()
         w.step.run()
         real = jax_mod.device_get
@@ -324,7 +324,7 @@ def test_shard_params_via_psum_fallback_matches(cpu_devices):
         root.common.engine.zero_gather_via_psum = via
         try:
             w = _build(2, 4, "shard_params", seed=23)
-            w.initialize(device=TPUDevice())
+            w.initialize(device=XLADevice())
             w.run()
             hists[via] = ([h["metric_train"]
                            for h in w.decision.metrics_history],
@@ -353,7 +353,7 @@ def test_shard_params_scan_epoch_and_state_dtype(cpu_devices):
                         optimizer_config={"state_dtype": "bfloat16"},
                         **LAYOUTS[layout])
         w.step.scan_epoch = True
-        w.initialize(device=TPUDevice())
+        w.initialize(device=XLADevice())
         assert w.step._params[0]["vw"].dtype == jnp.bfloat16
         before = _gauge("znicz_zero_gathered_bytes_total")
         w.run()
@@ -458,14 +458,14 @@ def test_ef_residual_snapshot_resume_bit_exact(tmp_path, cpu_devices):
     trajectory at the first post-resume step)."""
     for layout in ("replicated", "shard_params"):
         w_o = _build(4, 8, layout, quantized_collectives=QC)
-        w_o.initialize(device=TPUDevice())
+        w_o.initialize(device=XLADevice())
         w_o.run()
         want = _weights(w_o)
         want_hist = [h["metric_train"]
                      for h in w_o.decision.metrics_history]
 
         w_a = _build(2, 8, layout, quantized_collectives=QC)
-        w_a.initialize(device=TPUDevice())
+        w_a.initialize(device=XLADevice())
         w_a.run()
         arrays, meta = collect_state(w_a)
         # the residual slabs ride the snapshot, one rank row per device
@@ -476,7 +476,7 @@ def test_ef_residual_snapshot_resume_bit_exact(tmp_path, cpu_devices):
         write_snapshot(snap, arrays, meta)
 
         w_b = _build(4, 8, layout, quantized_collectives=QC)
-        w_b.initialize(device=TPUDevice())
+        w_b.initialize(device=XLADevice())
         restore_state(w_b, snap)
         w_b.decision.max_epochs = 4
         w_b.decision.complete.set(False)
@@ -497,14 +497,14 @@ def test_ef_cross_mode_restore_matrix(tmp_path, cpu_devices):
     start at zero and the EF gauge goes live as training continues)."""
     # quantized shard_params -> exact replicated
     w_a = _build(2, 8, "shard_params", quantized_collectives=QC)
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     arrays, meta = collect_state(w_a)
     assert "step.opt.0.rw" in arrays
     snap = str(tmp_path / "qc_to_exact.npz")
     write_snapshot(snap, arrays, meta)
     w_b = _build(4, 8, "replicated")
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     restore_state(w_b, snap)
     w_b.decision.max_epochs = 4
     w_b.decision.complete.set(False)
@@ -514,14 +514,14 @@ def test_ef_cross_mode_restore_matrix(tmp_path, cpu_devices):
 
     # exact replicated -> quantized shard_params
     w_c = _build(2, 8, "replicated")
-    w_c.initialize(device=TPUDevice())
+    w_c.initialize(device=XLADevice())
     w_c.run()
     arrays, meta = collect_state(w_c)
     assert not any(k.endswith(".rw") for k in arrays)
     snap2 = str(tmp_path / "exact_to_qc.npz")
     write_snapshot(snap2, arrays, meta)
     w_d = _build(4, 8, "shard_params", quantized_collectives=QC)
-    w_d.initialize(device=TPUDevice())
+    w_d.initialize(device=XLADevice())
     restore_state(w_d, snap2)
     w_d.decision.max_epochs = 4
     w_d.decision.complete.set(False)
@@ -535,7 +535,7 @@ def test_ef_residual_cross_world_fold(tmp_path, cpu_devices):
     SUM — the only quantity the deferred-error correction depends on —
     onto rank 0, and training continues finite from there."""
     w_a = _build(2, 8, "shard_params", quantized_collectives=QC)
-    w_a.initialize(device=TPUDevice())
+    w_a.initialize(device=XLADevice())
     w_a.run()
     arrays, meta = collect_state(w_a)
     want_sum = arrays["step.opt.0.rw"].sum(axis=0)
@@ -544,7 +544,7 @@ def test_ef_residual_cross_world_fold(tmp_path, cpu_devices):
     write_snapshot(snap, arrays, meta)
 
     w_b = _build(4, 2, "replicated", quantized_collectives=QC)
-    w_b.initialize(device=TPUDevice())
+    w_b.initialize(device=XLADevice())
     restore_state(w_b, snap)
     got = np.asarray(w_b.step._params[0]["rw"])
     assert got.shape[0] == 2

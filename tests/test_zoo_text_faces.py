@@ -7,7 +7,7 @@ import os
 import numpy as np
 
 from znicz_tpu.core import prng
-from znicz_tpu.core.backends import TPUDevice
+from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.loader import text as text_mod
 from znicz_tpu.models import spam, yale_faces
@@ -101,7 +101,7 @@ def test_text_loader_serves_and_restores(tmp_path):
     w = Workflow(name="t")
     loader = text_mod.TextBagOfWordsLoader(
         w, data_dir=d, vocab_size=64, minibatch_size=20)
-    loader.initialize(device=TPUDevice())
+    loader.initialize(device=XLADevice())
     assert loader.class_lengths == [0, 20, 80]
     assert len(loader.vocab) == 64
     assert loader.original_data.shape == (100, 64)
@@ -114,7 +114,7 @@ def test_text_loader_serves_and_restores(tmp_path):
     prng.seed_all(99)                      # restore must not depend on prng
     loader2 = text_mod.TextBagOfWordsLoader(
         Workflow(name="t2"), data_dir=d, vocab_size=64, minibatch_size=20)
-    loader2.initialize(device=TPUDevice())
+    loader2.initialize(device=XLADevice())
     loader2.load_state_dict(state)
     assert loader2.vocab == loader.vocab
     np.testing.assert_allclose(loader2.original_data.mem, served,
@@ -128,7 +128,7 @@ def test_text_loader_serves_and_restores(tmp_path):
 def _train(build, seed=31, **kw):
     prng.seed_all(seed)
     w = build(**kw)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     assert bool(w.decision.complete)
     return w

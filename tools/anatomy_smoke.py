@@ -10,8 +10,11 @@ trip the per-rank straggler rule for exactly the one artificially
 delayed rank in a deterministic-tick fleet fixture.  Also asserts
 ``znicz_goodput_*`` pre-touch materializes every category child at 0.
 
-``ZNICZ_TPU_COMPILE_CACHE=off`` per the box note (the persistent cache
-intermittently segfaults single-process workers here).
+``ZNICZ_TPU_COMPILE_CACHE=off``: a CPU smoke has no use for a persistent
+cache.  (The segfault this pin was once blamed on was never reproduced:
+PR 21 ran ``chip_smoke.py`` twice against one directory on the v5e with
+the cache on, threaded server phase included — 387 hits on the second
+run, no crash.)
 """
 
 import os
@@ -45,7 +48,7 @@ def fail(msg: str) -> None:
 def check_anatomy_run():
     """(b)+(c) on the real fused workflow, (a) asserted at init."""
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.models.mnist_fc import build_fused
     from znicz_tpu.observe import registry
     from znicz_tpu.observe.anatomy import TRAIN_PHASES
@@ -58,7 +61,7 @@ def check_anatomy_run():
                     shard_params=True, anatomy=True,
                     quantized_collectives={"mode": "int8",
                                            "error_feedback": True})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
 
     # (a) pre-touch: every anatomy child of the fused plane must exist
     # at init, BEFORE any step ran, so fleet delta rules see a baseline

@@ -37,7 +37,7 @@ def fail(msg: str) -> "None":
 
 def build_package(tmp: str) -> str:
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.standard_workflow import StandardWorkflow
     from znicz_tpu.utils.export import attach_aot, export_forward
 
@@ -50,7 +50,7 @@ def build_package(tmp: str) -> str:
         loader_config={"n_classes": 3, "sample_shape": (6,), "n_train": 60,
                        "n_valid": 0, "minibatch_size": 20},
         decision_config={"max_epochs": 1})
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     pkg = os.path.join(tmp, "aot_smoke.npz")
     export_forward(w, pkg)
@@ -76,8 +76,11 @@ def main() -> int:
     proc = None
     try:
         # hermetic persistent cache: the smoke must not depend on (or
-        # pollute) the developer's ~/.cache warmth
-        os.environ["ZNICZ_TPU_COMPILE_CACHE"] = os.path.join(tmp, "xla")
+        # pollute) whatever cache directory it was started under, so its
+        # own temporary one REPLACES $JAX_COMPILATION_CACHE_DIR — the
+        # variable that outranks everything else (docs/COMPILE.md)
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(tmp, "xla")
+        os.environ.pop("ZNICZ_TPU_COMPILE_CACHE", None)
         pkg = build_package(tmp)
         port = free_port()
         proc = subprocess.Popen(

@@ -19,7 +19,8 @@ one exported LM package — then, over the wire only:
 - asserts the merged ``/fleet/metrics.prom`` carries the
   ``znicz_router_*`` families beside the workers' rank-labeled series.
 
-jax-on-CPU; the compile cache is pinned off (the PR 9 box note).
+jax-on-CPU; the compile cache is pinned off (a CPU smoke has no use
+for it; the segfault once blamed on it was never reproduced — PR 21).
 Every failure prints a ``fleet_router_smoke:``-prefixed line, exits 1.
 """
 

@@ -15,7 +15,8 @@ asserts end to end over the wire:
 - the fleet watchtower sees the merged view (a trivial rule over
   ``znicz_generate_tokens_total`` summed across ranks evaluates).
 
-jax-on-CPU; the compile cache is pinned off (the PR 9 box note).
+jax-on-CPU; the compile cache is pinned off (a CPU smoke has no use
+for it; the segfault once blamed on it was never reproduced — PR 21).
 Every failure prints a ``fleet_smoke:``-prefixed line and exits 1.
 """
 

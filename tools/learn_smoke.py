@@ -18,7 +18,8 @@ The CLI's JSON verdict is re-asserted here:
 - the router ledger CLOSED (admitted == completed + failed +
   client_gone) with zero broken streams — zero lost requests.
 
-jax-on-CPU; the compile cache is pinned off (the PR 9 box note).
+jax-on-CPU; the compile cache is pinned off (a CPU smoke has no use
+for it; the segfault once blamed on it was never reproduced — PR 21).
 Every failure prints a ``learn_smoke:``-prefixed line, exits 1.
 """
 

@@ -7,8 +7,11 @@ from the ``znicz_qcomm_*`` counters on BOTH collectives (gradient psum
 and ZeRO gather; int8 payload + f32 chunk scales ≈ 3.98x), train to a
 finite history, and publish a nonzero residual norm.
 
-``ZNICZ_TPU_COMPILE_CACHE=off`` per the box note (the persistent cache
-intermittently segfaults single-process workers here).
+``ZNICZ_TPU_COMPILE_CACHE=off``: a CPU smoke has no use for a persistent
+cache.  (The segfault this pin was once blamed on was never reproduced:
+PR 21 ran ``chip_smoke.py`` twice against one directory on the v5e with
+the cache on, threaded server phase included — 387 hits on the second
+run, no crash.)
 """
 
 import os
@@ -37,7 +40,7 @@ def fail(msg: str) -> None:
 
 def run_once(quantized_collectives, shard_params: bool = False):
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.models.mnist_fc import build_fused
     from znicz_tpu.observe import registry
     from znicz_tpu.parallel.mesh import data_parallel_mesh
@@ -48,7 +51,7 @@ def run_once(quantized_collectives, shard_params: bool = False):
                     mesh=data_parallel_mesh(N_DEV), optimizer="adam",
                     shard_params=shard_params,
                     quantized_collectives=quantized_collectives)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = [h["metric_validation"] for h in w.decision.metrics_history]
 

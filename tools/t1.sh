@@ -15,6 +15,12 @@ fi
 # theft, see the bench notes), so the old 870 s cap truncated the run
 # before the summary; 1500 keeps the old ~30% headroom over measured
 set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 1500 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
+# The smokes below pin ZNICZ_TPU_COMPILE_CACHE=off in their own workers:
+# a CPU smoke has no use for a persistent cache.  The segfault those
+# pins were once blamed on did not reproduce: PR 21 ran chip_smoke.py
+# twice against one directory on the v5e with the cache ON, threaded
+# server phase included — 387 hits and 0 misses on the second run, no
+# crash (CHANGES.md).
 # ISSUE 5+6 smoke: the telemetry scrape surfaces must actually serve —
 # boot a WebStatus, hit /metrics + /trace.json + /timeseries.json, and
 # round-trip a flight artifact through `python -m znicz_tpu flight`
@@ -52,8 +58,7 @@ fi
 # ISSUE 12 smoke: speculative decoding exactness — two fresh-process
 # boots from one draft-carrying LM package must stream BYTE-IDENTICAL
 # greedy text with speculation on vs off, and the spec/pages metric
-# families must be live (docs/SERVING.md "Speculative decoding";
-# ZNICZ_TPU_COMPILE_CACHE=off per the box note)
+# families must be live (docs/SERVING.md "Speculative decoding")
 if ! timeout -k 5 300 env JAX_PLATFORMS=cpu python tools/generate_smoke.py --speculative; then
     echo "tools/t1.sh: speculative decoding smoke FAILED (see" \
          "generate_smoke lines above)" >&2
@@ -63,8 +68,7 @@ fi
 # rank env, aggregate their /metrics.prom into one rank-labeled fleet
 # view, assert a fleet rule evaluates over the merged series and the
 # merged Perfetto trace carries request phase spans from both ranks
-# (docs/OBSERVABILITY.md "Fleet telemetry"; ZNICZ_TPU_COMPILE_CACHE=off
-# per the PR 9 box note)
+# (docs/OBSERVABILITY.md "Fleet telemetry")
 if ! timeout -k 5 300 env JAX_PLATFORMS=cpu python tools/fleet_smoke.py; then
     echo "tools/t1.sh: fleet telemetry smoke FAILED (see fleet_smoke" \
          "lines above)" >&2
@@ -75,8 +79,7 @@ fi
 # router under threaded traffic, performs one rolling weight update via
 # POST /rollout, and asserts zero lost requests + fleet convergence on
 # the new fingerprint + steady-state compile delta 0
-# (docs/SERVING.md "Fleet topology"; ZNICZ_TPU_COMPILE_CACHE=off per
-# the PR 9 box note)
+# (docs/SERVING.md "Fleet topology")
 if ! timeout -k 5 400 env JAX_PLATFORMS=cpu python tools/fleet_router_smoke.py; then
     echo "tools/t1.sh: serving-fleet router smoke FAILED (see" \
          "fleet_router_smoke lines above)" >&2
@@ -86,8 +89,7 @@ fi
 # whole loop in fresh processes: 2 serve workers feed the spool, 1
 # supervised trainer consumes it and publishes, the bridge rolls the
 # fleet; asserts an adopted publish + fleet-wide new fingerprint +
-# closed router ledger (docs/LEARNING.md; ZNICZ_TPU_COMPILE_CACHE=off
-# per the PR 9 box note)
+# closed router ledger (docs/LEARNING.md)
 if ! timeout -k 5 700 env JAX_PLATFORMS=cpu python tools/learn_smoke.py; then
     echo "tools/t1.sh: train-while-serve smoke FAILED (see learn_smoke" \
          "lines above)" >&2
@@ -96,8 +98,7 @@ fi
 # ISSUE 15 smoke: ZeRO shard_params — dp(4)+shard_params(adam) on a
 # forced 4-device CPU mesh must read per-chip znicz_zero_* bytes at
 # ~1/4 of the replicated run's with an identical seeded metric history
-# (docs/TUNING.md "ZeRO modes"; ZNICZ_TPU_COMPILE_CACHE=off per the
-# PR 9 box note)
+# (docs/TUNING.md "ZeRO modes")
 if ! timeout -k 5 240 env JAX_PLATFORMS=cpu python tools/zero_smoke.py; then
     echo "tools/t1.sh: ZeRO shard_params smoke FAILED (see zero_smoke" \
          "lines above)" >&2
@@ -107,8 +108,7 @@ fi
 # mesh, mode=off must reproduce the baseline seeded history
 # bit-identically and an int8+error-feedback shard_params run must read
 # ~4x compression from the znicz_qcomm_* counters on both collectives
-# (docs/TUNING.md "Quantized collectives"; ZNICZ_TPU_COMPILE_CACHE=off
-# per the PR 9 box note)
+# (docs/TUNING.md "Quantized collectives")
 if ! timeout -k 5 240 env JAX_PLATFORMS=cpu python tools/qcomm_smoke.py; then
     echo "tools/t1.sh: quantized-collectives smoke FAILED (see" \
          "qcomm_smoke lines above)" >&2
@@ -120,8 +120,7 @@ fi
 # within 10% of the measured step wall, read a nonzero mfu gauge (peak
 # pinned via $ZNICZ_TPU_PEAK_FLOPS), and trip the per-rank straggler
 # rule for exactly one artificially delayed rank
-# (docs/OBSERVABILITY.md "Step anatomy & goodput";
-# ZNICZ_TPU_COMPILE_CACHE=off per the PR 9 box note)
+# (docs/OBSERVABILITY.md "Step anatomy & goodput")
 if ! timeout -k 5 240 env JAX_PLATFORMS=cpu python tools/anatomy_smoke.py; then
     echo "tools/t1.sh: step-anatomy smoke FAILED (see anatomy_smoke" \
          "lines above)" >&2

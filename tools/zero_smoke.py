@@ -6,8 +6,11 @@ on-demand gather traffic, and (c) produce the SAME seeded metric history
 as the replicated run — the memory win with the numerics pinned, end to
 end through the real workflow loop.
 
-``ZNICZ_TPU_COMPILE_CACHE=off`` per the box note (the persistent cache
-intermittently segfaults single-process workers here).
+``ZNICZ_TPU_COMPILE_CACHE=off``: a CPU smoke has no use for a persistent
+cache.  (The segfault this pin was once blamed on was never reproduced:
+PR 21 ran ``chip_smoke.py`` twice against one directory on the v5e with
+the cache on, threaded server phase included — 387 hits on the second
+run, no crash.)
 """
 
 import os
@@ -36,7 +39,7 @@ def fail(msg: str) -> None:
 
 def run_once(shard_params: bool):
     from znicz_tpu.core import prng
-    from znicz_tpu.core.backends import TPUDevice
+    from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.models.mnist_fc import build_fused
     from znicz_tpu.observe import registry
     from znicz_tpu.parallel.mesh import data_parallel_mesh
@@ -46,7 +49,7 @@ def run_once(shard_params: bool):
                     n_train=96, n_valid=32,
                     mesh=data_parallel_mesh(N_DEV), optimizer="adam",
                     shard_params=shard_params)
-    w.initialize(device=TPUDevice())
+    w.initialize(device=XLADevice())
     w.run()
     hist = [h["metric_validation"] for h in w.decision.metrics_history]
 

@@ -23,7 +23,7 @@ designed TPU-first:
   lowerings as the always-available fallback
   (reference: veles.znicz ocl/*.cl + cuda/*.cu).
 
-Blueprint: /root/repo/SURVEY.md.  Targets: /root/repo/BASELINE.md.
+Blueprint: SURVEY.md.  Targets: BASELINE.md.
 """
 
 __version__ = "0.1.0"
@@ -35,10 +35,11 @@ from znicz_tpu.core.mutable import Bool
 from znicz_tpu.core.units import Unit, TrivialUnit
 from znicz_tpu.core.workflow import Workflow
 from znicz_tpu.core.plumbing import Repeater, StartPoint, EndPoint
-from znicz_tpu.core.backends import Device, NumpyDevice, TPUDevice, AutoDevice
+from znicz_tpu.core.backends import (AutoDevice, Device, NumpyDevice,
+                                     TPUDevice, XLADevice)
 
 __all__ = [
     "root", "Config", "prng", "Array", "Bool", "Unit", "TrivialUnit",
     "Workflow", "Repeater", "StartPoint", "EndPoint",
-    "Device", "NumpyDevice", "TPUDevice", "AutoDevice",
+    "Device", "NumpyDevice", "XLADevice", "TPUDevice", "AutoDevice",
 ]

@@ -1,19 +1,22 @@
 """Compile-latency plane (ISSUE 7 tentpole, part 1): the persistent XLA
 compilation cache as a first-class, observable subsystem.
 
-The bench trajectory's weakest signal is compile cost, not step speed:
-r05's flagship fell back to CPU after two TPU compile timeouts, and
-every ``serve`` boot re-JITs all engine buckets from scratch.  JAX ships
-a persistent compilation cache (serialized XLA executables keyed by a
-hash of the HLO + compile options + backend fingerprint); this module
-makes it config-driven, on by default, and assertable:
+Every ``serve`` boot and every training start re-JITs its programs from
+scratch unless something remembers them.  JAX ships a persistent
+compilation cache (serialized XLA executables keyed by a hash of the
+HLO + compile options + backend fingerprint); this module turns it on by
+default, puts it where an operator can place it, and makes it
+assertable:
 
-- :func:`configure` resolves the cache directory from (in precedence
-  order) an explicit argument, ``$ZNICZ_TPU_COMPILE_CACHE``,
-  ``root.common.engine.compile_cache_dir``, and the default
-  ``~/.cache/znicz_tpu/xla`` — so one cluster-shared directory turns
-  every cold compile into a once-per-cluster cost.  ``"off"`` (or an
-  empty string) at any layer disables the cache.
+- :func:`configure` resolves ONE directory.  ``$JAX_COMPILATION_CACHE_DIR``
+  — jax's own variable, the one a driver or a cluster sets from outside —
+  is the directory whenever it is set, and this module then never writes
+  any other value into ``jax_compilation_cache_dir``.  Unset, the
+  directory is ``<checkout>/.data/cache/jax``, computed from the
+  package's own location: a fixed path, because the path is part of
+  what a second process must agree on to hit.
+  ``$ZNICZ_TPU_COMPILE_CACHE=off`` (the test suite's switch) disables
+  the cache, but only where jax's variable is unset.
 - :func:`ensure` is the idempotent boot hook called from
   ``Workflow.run``, ``FusedTrainStep.initialize`` and the serve plane's
   backend load — anywhere compiles are about to happen.  It never
@@ -45,13 +48,20 @@ import sys
 import threading
 from typing import Optional
 
-#: default cache location (ISSUE 7); one directory is safely shared by
-#: concurrent processes — entries are content-hashed and written
-#: atomically by jax
-DEFAULT_DIR = "~/.cache/znicz_tpu/xla"
+from znicz_tpu.core.config import CHECKOUT
 
-#: environment override: a directory path, or ""/"off" to disable
+#: jax's own variable: when set it IS the cache directory, from outside
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the cache lives when nobody placed it; one directory is safely
+#: shared by concurrent processes — entries are content-hashed and
+#: written atomically by jax
+DEFAULT_DIR = os.path.join(CHECKOUT, ".data", "cache", "jax")
+
+#: the off switch (""/"off"/"none"/"0"); honoured only where
+#: $JAX_COMPILATION_CACHE_DIR is unset
 ENV_VAR = "ZNICZ_TPU_COMPILE_CACHE"
+_OFF = ("", "off", "none", "0")
 
 #: environment override for the minimum-compile-seconds threshold
 ENV_MIN_S = "ZNICZ_TPU_COMPILE_CACHE_MIN_S"
@@ -66,19 +76,16 @@ _listener_registered = False
 
 
 def _resolve_dir(explicit: Optional[str]) -> Optional[str]:
-    """Layered resolution; ``None`` means caching is off."""
-    if explicit is None:
-        explicit = os.environ.get(ENV_VAR)
-    if explicit is None:
-        from znicz_tpu.core.config import root
-
-        explicit = root.common.engine.get("compile_cache_dir", None)
-    if explicit is None:
-        explicit = DEFAULT_DIR
-    explicit = str(explicit)
-    if explicit.lower() in ("", "off", "none", "0"):
+    """$JAX_COMPILATION_CACHE_DIR, else the caller's directory, else the
+    off switch, else the checkout's; ``None`` means caching is off."""
+    placed = os.environ.get(JAX_ENV_VAR)
+    if placed:
+        return placed
+    if explicit is not None:
+        return None if str(explicit).lower() in _OFF else str(explicit)
+    if os.environ.get(ENV_VAR, "on").lower() in _OFF:
         return None
-    return os.path.expanduser(explicit)
+    return DEFAULT_DIR
 
 
 def _resolve_min_s(explicit: Optional[float]) -> float:
@@ -131,6 +138,16 @@ def _reset_jax_cache_state() -> None:
                    exc)
 
 
+def _turn_off(jax) -> None:
+    """Stop consulting a previously enabled directory — reached only
+    where $JAX_COMPILATION_CACHE_DIR is unset, the one case in which
+    this module may write an empty directory into the config."""
+    global _configured, _active_dir, _active_min_s
+    jax.config.update("jax_compilation_cache_dir", "")
+    _reset_jax_cache_state()
+    _configured, _active_dir, _active_min_s = True, None, None
+
+
 def configure(cache_dir: Optional[str] = None,
               min_compile_time_s: Optional[float] = None,
               force: bool = False) -> Optional[str]:
@@ -140,7 +157,9 @@ def configure(cache_dir: Optional[str] = None,
     off (explicitly, or because the directory could not be created —
     the degraded path is a warning, never an exception).  Idempotent:
     a second call is a no-op unless ``force`` or the arguments changed
-    the resolution."""
+    the resolution.  ``cache_dir`` is for callers that own a directory
+    (tests, the cold/warm probes); ``$JAX_COMPILATION_CACHE_DIR``
+    outranks it."""
     global _configured, _active_dir, _active_min_s
     with _lock:
         target = _resolve_dir(cache_dir)
@@ -151,33 +170,30 @@ def configure(cache_dir: Optional[str] = None,
         import jax
 
         if target is None:
-            # explicit off: a previously enabled in-process cache must
-            # actually stop being consulted
-            jax.config.update("jax_compilation_cache_dir", "")
-            _reset_jax_cache_state()
-            _configured, _active_dir, _active_min_s = True, None, None
+            _turn_off(jax)
             _log.info("persistent compilation cache disabled")
             return None
-        try:
-            os.makedirs(target, exist_ok=True)
-            probe_path = os.path.join(target, ".znicz_writable")
-            with open(probe_path, "w"):
-                pass
-            os.remove(probe_path)
-        except OSError as exc:
-            # graceful degradation (ISSUE 7 acceptance): every compile
-            # is a logged miss, nothing crashes
-            _log.warning("compile cache dir %r unusable (%s); persistent "
-                         "caching disabled — all compiles will be cold",
-                         target, exc)
-            # actually disable: a previously-enabled directory must stop
-            # being consulted, or stats() lies about the degraded state
-            jax.config.update("jax_compilation_cache_dir", "")
-            _reset_jax_cache_state()
-            _configured, _active_dir, _active_min_s = True, None, None
-            return None
+        if target != os.environ.get(JAX_ENV_VAR):
+            # a directory of our own choosing must prove usable; one
+            # placed from outside is jax's to create and to complain
+            # about, and is never replaced
+            try:
+                os.makedirs(target, exist_ok=True)
+                probe_path = os.path.join(target, ".znicz_writable")
+                with open(probe_path, "w"):
+                    pass
+                os.remove(probe_path)
+            except OSError as exc:
+                # graceful degradation (ISSUE 7 acceptance): every
+                # compile is a logged miss, nothing crashes
+                _log.warning("compile cache dir %r unusable (%s); "
+                             "persistent caching disabled — all compiles "
+                             "will be cold", target, exc)
+                _turn_off(jax)
+                return None
         jax.config.update("jax_enable_compilation_cache", True)
-        jax.config.update("jax_compilation_cache_dir", target)
+        if jax.config.jax_compilation_cache_dir != target:
+            jax.config.update("jax_compilation_cache_dir", target)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           min_s)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

@@ -10,8 +10,15 @@ leaves for the genetic optimizer (reference: veles/genetics/config.py :: Tune).
 
 from __future__ import annotations
 
+import os
 import runpy
 from typing import Any, Iterator
+
+#: the directory that holds the ``znicz_tpu`` package — the checkout.
+#: Run-time products (datasets, snapshots, caches, traces) live under
+#: its ``.data/``, wherever the tree was copied to.
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 class Config:
@@ -161,9 +168,11 @@ root.common.update({
         "precision": "bfloat16",
     },
     "dirs": {
-        "datasets": "/root/repo/.data/datasets",
-        "snapshots": "/root/repo/.data/snapshots",
-        "cache": "/root/repo/.data/cache",
+        "datasets": os.path.join(CHECKOUT, ".data", "datasets"),
+        "snapshots": os.path.join(CHECKOUT, ".data", "snapshots"),
+        "cache": os.path.join(CHECKOUT, ".data", "cache"),
+        "plots": os.path.join(CHECKOUT, ".data", "plots"),
     },
-    "trace": {"enabled": False, "dir": "/root/repo/.data/trace"},
+    "trace": {"enabled": False,
+              "dir": os.path.join(CHECKOUT, ".data", "trace")},
 })

@@ -22,7 +22,7 @@ import numpy as np
 
 import jax
 
-from znicz_tpu.core.backends import Device, NumpyDevice, TPUDevice
+from znicz_tpu.core.backends import Device, NumpyDevice, XLADevice
 
 
 def roundup(n: int, quantum: int) -> int:
@@ -171,7 +171,7 @@ class Array:
 
     def unmap(self) -> None:
         if self._host_dirty and self._mem is not None and isinstance(
-                self._device, TPUDevice):
+                self._device, XLADevice):
             self._devmem = self._device.put(self._mem)
             self._host_dirty = False
 

@@ -2,70 +2,78 @@
 AdamW): 2-D view, row tiling under a VMEM budget, SMEM hyperparameter
 pack, vma-aware out specs, and in-place aliasing.
 
-Returns ``None`` when no tile fits VMEM (pathologically wide rows) — the
-caller falls back to its jnp implementation, which XLA fuses well enough
-that correctness never depends on the Pallas path."""
+Returns ``None`` when no legal tile fits VMEM (a flat-sharded ZeRO leaf
+is one long row) — the caller falls back to its jnp implementation, and
+:func:`tiled_update` names the leaf shape in a warning so the hand-over
+is never silent."""
 
 from __future__ import annotations
+
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: conservative VMEM working-set budget (bytes) for in+out tiles
+_log = logging.getLogger("znicz_tpu.ops.pallas")
+
+#: VMEM working-set budget (bytes) for the in+out tiles, DOUBLE-buffered
+#: as the Pallas pipeline allocates them — under Mosaic's 16 MiB default
+#: scoped-VMEM limit on v5e
 VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def out_struct(shape, dtype, like):
     """ShapeDtypeStruct inheriting ``like``'s varying-mesh-axes: under
     shard_map with vma checking, pallas_call outputs must declare which
-    mesh axes they vary over — same set as the operands.  Degrades to a
-    plain struct on pre-vma jax.  THE one copy of this policy (used by
-    the optimizer kernels here and the flash-attention kernel)."""
-    typeof = getattr(jax, "typeof", None)    # vma-era jax only
-    vma = getattr(typeof(like), "vma", None) if typeof else None
-    if vma is not None:
-        # an EMPTY frozenset means replicated — still required under
-        # check_vma; only a missing attribute (pre-vma jax) may omit it
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    mesh axes they vary over — same set as the operands (an EMPTY
+    frozenset means replicated and is still required).  THE one copy of
+    this policy (used by the optimizer kernels here and the
+    flash-attention kernel)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _pick_tile(rows: int, cols: int, n_buffers: int,
-               min_tile: int = 1) -> int:
+               min_tile: int = 8) -> int:
     """Largest workable row tile: whole-array when it fits (one grid
     step), else the biggest power-of-two divisor of ``rows`` that fits,
-    else 0 (= no tile fits; caller must fall back).  ``min_tile`` guards
-    Mosaic's sublane tiling: 16-bit refs need (16, 128)-divisible blocks
-    unless the block spans the whole array."""
+    else 0 (= no tile fits; caller must fall back).  ``min_tile`` is
+    Mosaic's sublane tiling: a block that does not span the whole array
+    needs (8, 128)-divisible rows for 32-bit refs, (16, 128) for
+    16-bit."""
     def fits(t: int) -> bool:
-        return t * cols * 4 * n_buffers <= VMEM_BUDGET
+        return 2 * t * cols * 4 * n_buffers <= VMEM_BUDGET
 
     if fits(rows):
         return rows
-    for t in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+    for t in (512, 256, 128, 64, 32, 16, 8):
         if t >= min_tile and rows % t == 0 and fits(t):
             return t
     return 0
 
 
 def tiled_update(kernel, hyper_scalars, arrays, aliases: dict,
-                 n_out: int, *, interpret: bool = False):
+                 n_out: int, *, name: str, interpret: bool = False):
     """Run ``kernel(h_ref, *in_refs, *out_refs)`` tiled over same-shaped
     ``arrays`` (first array defines shape/dtype).  ``aliases`` maps
     operand index (1-based: 0 is the SMEM hyper pack) -> output index for
     in-place updates.  Returns a tuple of ``n_out`` arrays reshaped to
-    the input shape, or ``None`` if no tile fits VMEM."""
+    the input shape, or ``None`` if no tile fits VMEM — after a warning
+    that names ``name`` and the leaf shape (once per trace)."""
     orig_shape = arrays[0].shape
     a2 = [a.reshape(-1, orig_shape[-1]) if a.ndim != 2 else a
           for a in arrays]
     rows, cols = a2[0].shape
     # 16-bit buffers (narrow optimizer state) tile at (16, 128) sublanes
     min_tile = 16 if any(jnp.dtype(a.dtype).itemsize < 4 for a in a2) \
-        else 1
+        else 8
     tile = _pick_tile(rows, cols, len(arrays) + n_out, min_tile)
     if tile == 0:
+        _log.warning(
+            "engine.pallas: no VMEM tile fits the fused %s update of a "
+            "%s leaf (2-D view %dx%d); this leaf takes the XLA reference "
+            "update", name, tuple(orig_shape), rows, cols)
         return None
     hyper = jnp.stack([jnp.asarray(h, jnp.float32)
                        for h in hyper_scalars])
@@ -86,6 +94,7 @@ def tiled_update(kernel, hyper_scalars, arrays, aliases: dict,
         out_specs=(spec,) * n_out,
         out_shape=outs,
         input_output_aliases=dict(aliases),
+        name=f"fused_{name}_update",
         interpret=interpret,
     )(hyper, *a2)
     if n_out == 1:

@@ -6,7 +6,8 @@ SGD-update" parity deliverable, extended to the AdamW path).
 Weights/grad/moments stream HBM -> VMEM tile by tile; hyperparameters
 (including the post-increment step count ``t``) ride SMEM as scalars;
 outputs alias the weight/moment inputs (true in-place update).  Shapes
-whose rows cannot tile into VMEM fall back to the jnp implementation."""
+whose rows cannot tile into VMEM take the jnp implementation, with a
+warning naming the leaf (_elementwise.tiled_update)."""
 
 from __future__ import annotations
 
@@ -17,9 +18,9 @@ from znicz_tpu.ops.pallas._elementwise import tiled_update
 
 
 def _kernel(h_ref, w_ref, g_ref, m_ref, v_ref, w_out, m_out, v_out):
-    # bias corrections c1 = 1-b1^t, c2 = 1-b2^t are computed OUTSIDE the
-    # kernel: a scalar pow on SMEM operands crashes the Mosaic scalar
-    # core's compiler (observed on-chip as a remote_compile HTTP 500)
+    # bias corrections c1 = 1-b1^t, c2 = 1-b2^t arrive precomputed: they
+    # are per-step scalars, and a pow on SMEM operands is not something
+    # the scalar core should be asked to lower
     lr, wd, b1, b2, eps, c1, c2, bs = (
         h_ref[0], h_ref[1], h_ref[2], h_ref[3], h_ref[4], h_ref[5],
         h_ref[6], h_ref[7])
@@ -48,7 +49,7 @@ def fused_adam_update(w, grad, m, v, t, learning_rate, weight_decay,
         [learning_rate, weight_decay, beta1, beta2, eps, c1, c2,
          batch_size],
         (w, grad, m, v), aliases={1: 0, 3: 1, 4: 2}, n_out=3,
-        interpret=interpret)
+        name="adam", interpret=interpret)
     if result is None:
         return adam_ops.update(jnp, w, grad, m, v, t, learning_rate,
                                weight_decay, beta1, beta2, eps,

@@ -35,6 +35,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: the kernels' names in the lowered program and in device traces — what
+#: chip_smoke.py looks for to prove the step compiled them
+FWD_KERNEL_NAME = "flash_attention_fwd"
+BWD_KERNEL_NAME = "flash_attention_bwd"
+
+
 def _mask_scores(s, causal: bool, iq: int, block_q: int):
     """Apply the causal mask to one q block's score rows ``(bq, t)``."""
     if not causal:
@@ -147,6 +153,7 @@ def _call_fwd(q, k, v, causal, interpret):
             _out_struct((bh, t, dh), q.dtype, q),
             _out_struct((bh, t, 1), jnp.float32, q),
         ],
+        name=FWD_KERNEL_NAME,
         interpret=interpret,
     )(q, k, v)
 
@@ -195,6 +202,7 @@ def _flash_bwd(causal, interpret, res, do, dlse=None):
             _out_struct((bh, t, dh), jnp.float32, q),
             _out_struct((bh, t, dh), jnp.float32, q),
         ],
+        name=BWD_KERNEL_NAME,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
@@ -228,17 +236,28 @@ def _flash_lse_bwd(causal, interpret, res, cts):
 flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def supported(t: int, dh: int) -> bool:
-    """Shapes this kernel handles: q-blockable time axis, lane-sized head
-    dim, and a VMEM budget that must cover the BACKWARD kernel (the one
-    actually run under value_and_grad): full K/V plus f32 dk/dv
-    accumulator blocks plus the three (block_q, t) f32 score temporaries
-    (p, dp, ds)."""
+def unsupported_reason(t: int, dh: int) -> str | None:
+    """Why this kernel cannot take the shape, or ``None`` when it can:
+    q-blockable time axis, lane-sized head dim, and a VMEM budget that
+    must cover the BACKWARD kernel (the one actually run under
+    value_and_grad): full K/V plus f32 dk/dv accumulator blocks plus the
+    three (block_q, t) f32 score temporaries (p, dp, ds)."""
     bq = _pick_block_q(t)
-    if bq == 0 or dh % 64 != 0:
-        return False
+    if bq == 0:
+        return f"t={t} is not a multiple of the 128-row q block"
+    if dh % 64 != 0:
+        return f"head_dim={dh} is not a multiple of 64"
     vmem = 4 * t * dh * 4 + 3 * bq * t * 4
-    return vmem <= 10 * 1024 * 1024
+    if vmem > 10 * 1024 * 1024:
+        return (f"full K/V at t={t}, head_dim={dh} needs {vmem >> 20} MiB "
+                f"of VMEM in the backward kernel (budget 10 MiB); shard "
+                f"the seq axis")
+    return None
+
+
+def supported(t: int, dh: int) -> bool:
+    """Shapes this kernel handles (see :func:`unsupported_reason`)."""
+    return unsupported_reason(t, dh) is None
 
 
 def flash_attention(q, k, v, causal: bool = False, *,
@@ -247,12 +266,12 @@ def flash_attention(q, k, v, causal: bool = False, *,
     contract as ops.attention.attention (``softmax(q·kᵀ/√dh)·v``),
     differentiable via the flash backward kernels."""
     b, t, h, dh = q.shape
-    if not supported(t, dh):
+    why = unsupported_reason(t, dh)
+    if why:
         raise ValueError(
-            f"flash_attention needs t divisible by a 128/256/512 q-block, "
-            f"dh a multiple of 64, and K/V within the VMEM budget; got "
-            f"t={t}, dh={dh} — gate call sites on "
-            f"ops.pallas.attention.supported() or use the dense path")
+            f"flash_attention cannot take this shape: {why} — gate call "
+            f"sites on ops.pallas.attention.supported() or use the dense "
+            f"path")
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, -1)  # noqa: E731
     o = _flash(fold(q), fold(k), fold(v), causal, interpret)
     return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)

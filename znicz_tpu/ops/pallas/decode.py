@@ -7,14 +7,16 @@ slot has written: memory-bound, gather-heavy, and the only attention
 shape the generative plane dispatches in steady state.  The XLA path
 (``PagedKVDecoder._paged_attend``) first materializes the gathered
 ``(B, T_view, H, Dh)`` K/V copies in HBM and then reads them again for
-the scores; this kernel fuses the two — the grid walks
-``(slot, head, page)`` and each step DMAs ONE arena page straight into
-VMEM via the page table (a *scalar-prefetch* operand: block index maps
-read it before the kernel body runs, the Pallas paged-attention idiom),
-scoring it against the resident query with an f32 online-softmax
-accumulator (m/l/acc scratch, carried across the sequential page axis
-— the same recipe ``ring_attention`` and the contiguous decoder use, so
-numerics agree with the jnp reference to f32 rounding).
+the scores; this kernel fuses the two — the grid walks ``(slot, page)``
+and each step DMAs ONE arena page, every head of it, straight into VMEM
+via the page table (a *scalar-prefetch* operand: block index maps read
+it before the kernel body runs, the Pallas paged-attention idiom),
+scoring it against the slot's resident query rows with an f32
+online-softmax accumulator (m/l/acc scratch, carried across the
+sequential page axis — the same recipe ``ring_attention`` and the
+contiguous decoder use: f32 statistics, probabilities rounded to the
+value dtype before they meet V, so numerics agree with the jnp
+reference to rounding).
 
 Masking: key row ``r`` (global position ``p·page + r``) participates
 iff ``p·page + r < length`` for the slot — rows past the slot's write
@@ -27,9 +29,9 @@ Interpret-mode fallback: like every kernel in this package the
 ``interpret=True`` flag runs the identical kernel on the Pallas
 interpreter, so the CPU test suite executes the real kernel logic
 (tests/test_paged.py pins it against the jnp reference within the
-established 2e-5 band).  Compiled TPU dispatch wants lane-sized heads —
-gate call sites on :func:`supported` (or pass interpret) exactly like
-``ops.pallas.attention``.
+established 2e-5 band).  Compiled TPU dispatch is proven at lane-sized
+heads — gate call sites on :func:`supported` (or pass interpret) exactly
+like ``ops.pallas.attention``.
 """
 
 from __future__ import annotations
@@ -44,11 +46,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: the kernel's name in the lowered program and in device traces
+KERNEL_NAME = "paged_flash_decode"
+
+
 def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, page: int, sm_scale: float):
+            m_ref, l_ref, acc_ref, *, page: int, scale: float):
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    n_pages = pl.num_programs(2)
+    p = pl.program_id(1)
+    n_pages = pl.num_programs(1)
 
     @pl.when(p == 0)
     def _init():
@@ -56,32 +62,37 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                 # (1, Dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (page, Dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32
-                            ) * sm_scale             # (1, page)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1) + p * page
+    # every head of the slot rides one grid step in the arena's own
+    # (page, H, Dh) layout: heads on sublanes, head_dim on lanes, page
+    # rows on the major axis.  One query row is VPU work (a (1, Dh) MXU
+    # operand would waste the array), and reductions over the major axis
+    # are plain vreg adds — no relayout anywhere.
+    q = q_ref[0].astype(jnp.float32)                 # (H, Dh)
+    k = k_ref[0].astype(jnp.float32)                 # (page, H, Dh)
+    v = v_ref[0].astype(jnp.float32)
+    s = (k * q[None]).sum(axis=-1, keepdims=True) / scale   # (page, H, 1)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + p * page
     s = jnp.where(kpos >= len_ref[b], jnp.float32(-1e30), s)
-    m_prev = m_ref[...]                              # (1, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    m_prev = m_ref[...]                              # (H, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=0))
     alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.exp(s - m_new)                        # (1, page)
-    l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        pexp, v, preferred_element_type=jnp.float32)
+    pexp = jnp.exp(s - m_new[None])                  # (page, H, 1)
+    l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=0)
+    # p meets V at V's dtype (bf16 on the chip), accumulated in f32 —
+    # the rounding KVDecoder._attend applies
+    pv = pexp.astype(v_ref.dtype).astype(jnp.float32) * v
+    acc_ref[...] = acc_ref[...] * alpha + pv.sum(axis=0)
     m_ref[...] = m_new
 
     @pl.when(p == n_pages - 1)
     def _emit():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...])[0]
+        o_ref[0] = acc_ref[...] / l_ref[...]
 
 
 def supported(page: int, head_dim: int) -> bool:
-    """Shapes the COMPILED kernel tiles cleanly: sublane-sized pages and
-    lane-sized head dims.  Interpret mode has no such constraint — the
-    paged decoder picks interpret automatically off-TPU."""
+    """Shapes the COMPILED kernel is proven on: sublane-multiple pages
+    and lane-sized head dims.  Interpret mode has no such constraint —
+    the paged decoder picks interpret automatically off-TPU."""
     return page % 8 == 0 and head_dim % 128 == 0
 
 
@@ -104,34 +115,34 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
             f"head_dim % 128 == 0; got page={page}, head_dim={Dh} — "
             f"gate call sites on ops.pallas.decode.supported() or run "
             f"interpret")
-    kern = partial(_kernel, page=page,
-                   sm_scale=1.0 / float(np.sqrt(Dh)))
+    kern = partial(_kernel, page=page, scale=float(np.sqrt(Dh)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, P),
+        grid=(B, P),
+        # every block's last two dims are the arrays' own (H, Dh), the
+        # one block shape Mosaic takes at any head count
         in_specs=[
-            pl.BlockSpec((1, 1, Dh),
-                         lambda b, h, p, pt, ln: (b, h, 0)),
+            pl.BlockSpec((1, H, Dh), lambda b, p, pt, ln: (b, 0, 0)),
             # THE paged gather: the block index rides the prefetched
             # page table, so each grid step DMAs exactly the page the
             # slot mapped at view position p
-            pl.BlockSpec((1, page, 1, Dh),
-                         lambda b, h, p, pt, ln: (pt[b, p], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, Dh),
-                         lambda b, h, p, pt, ln: (pt[b, p], 0, h, 0)),
+            pl.BlockSpec((1, page, H, Dh),
+                         lambda b, p, pt, ln: (pt[b, p], 0, 0, 0)),
+            pl.BlockSpec((1, page, H, Dh),
+                         lambda b, p, pt, ln: (pt[b, p], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, Dh),
-                               lambda b, h, p, pt, ln: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, H, Dh), lambda b, p, pt, ln: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),         # running max
-            pltpu.VMEM((1, 1), jnp.float32),         # running denom
-            pltpu.VMEM((1, Dh), jnp.float32),        # o accumulator
+            pltpu.VMEM((H, 1), jnp.float32),         # running max
+            pltpu.VMEM((H, 1), jnp.float32),         # running denom
+            pltpu.VMEM((H, Dh), jnp.float32),        # o accumulator
         ],
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), jnp.float32),
+        name=KERNEL_NAME,
         interpret=interpret,
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q, k_pages, v_pages)

@@ -29,7 +29,7 @@ def _kernel(s_ref, x_ref, w_ref, c_ref, wout_ref, idx_ref):
     # d2 against its row min EXACTLY, and default-precision MXU bf16
     # passes flip winners vs the f32 oracle (measured on chip: 40% of
     # weight elements diverged). The SOM step is dispatch-latency-bound
-    # (docs/BENCH_LOG.md roofline), so the extra passes are free.
+    # (a 16 KB weight table), so the extra passes are free.
     hi = jax.lax.Precision.HIGHEST
     x2 = (x * x).sum(axis=1, keepdims=True)          # (B, 1)
     w2 = (w * w).sum(axis=1)                         # (N,)

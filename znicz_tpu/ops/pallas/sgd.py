@@ -6,7 +6,8 @@ launch, gradient_descent.{cl,cu} — SURVEY.md §3.2).
 Weights/grad/velocity stream HBM -> VMEM tile by tile; hyperparameters
 ride SMEM as scalars; outputs alias the weight/velocity inputs (true
 in-place update, no extra HBM traffic).  Shapes whose rows cannot tile
-into VMEM fall back to the jnp implementation."""
+into VMEM take the jnp implementation, with a warning naming the leaf
+(_elementwise.tiled_update)."""
 
 from __future__ import annotations
 
@@ -40,7 +41,7 @@ def fused_sgd_update(w, grad, vel, learning_rate, weights_decay, l1_vs_l2,
         [learning_rate, weights_decay, l1_vs_l2, gradient_moment,
          batch_size],
         (w, grad, vel), aliases={1: 0, 3: 1}, n_out=2,
-        interpret=interpret)
+        name="sgd", interpret=interpret)
     if result is None:
         # ops.sgd.update preserves vel's storage dtype itself
         return sgd_ops.update(jnp, w, grad, vel, learning_rate,

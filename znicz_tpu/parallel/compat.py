@@ -1,21 +1,17 @@
-"""shard_map compatibility shim — one import site for every user.
+"""``shard_map`` with the replication checker off — one import site for
+every user.
 
-Two portability problems are solved here:
+The static replication checker (``check_vma``) cannot infer replication
+through this codebase's psum-composed update functions, and rejects
+out_specs that are in fact correct (the documented escape hatch in the
+error message itself is to disable the check).  The real correctness
+guard is the test suite's numeric parity coverage:
+sharded-vs-replicated equality, mesh-size invariance, and the
+snapshot/resume bit-exactness pins all fail loudly if a P() output ever
+stops being replicated.
 
-- the symbol moved (``jax.experimental.shard_map.shard_map`` ->
-  ``jax.shard_map``);
-- the static replication checker (``check_rep``, renamed ``check_vma``)
-  cannot infer replication through this codebase's psum-composed
-  update functions on the jax versions in the container image, and
-  rejects out_specs that are in fact correct (the documented escape
-  hatch in the error message itself is to disable the check).  The
-  real correctness guard is the test suite's numeric parity coverage:
-  sharded-vs-replicated equality, mesh-size invariance, and the
-  snapshot/resume bit-exactness pins all fail loudly if a P() output
-  ever stops being replicated.
-
-Callers may still pass ``check_rep=``/``check_vma=`` explicitly; an
-explicit keyword overrides the relaxed default.
+Callers may still pass ``check_vma=`` explicitly; an explicit keyword
+overrides the relaxed default.
 
 This module also hosts :func:`quantized_psum` — the ONE opt-in seam
 through which the explicit gradient psums (parallel/step.py's fused
@@ -28,22 +24,10 @@ build that never imported the codec.
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 
-try:                               # jax >= 0.8
-    from jax import shard_map as _shard_map
-except ImportError:                # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_params = inspect.signature(_shard_map).parameters
-if "check_vma" in _params:
-    shard_map = functools.partial(_shard_map, check_vma=False)
-elif "check_rep" in _params:
-    shard_map = functools.partial(_shard_map, check_rep=False)
-else:                              # no checker flag on this version
-    shard_map = _shard_map
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def quantized_psum(tree, axis_name, codec=None, residuals=None):

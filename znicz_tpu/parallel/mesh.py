@@ -26,16 +26,8 @@ from jax.sharding import Mesh
 
 def varying(x, axis_name):
     """Mark ``x`` as varying over ``axis_name`` (shard_map vma typing for
-    scan carries); pcast on current jax, pvary fallback on older, and a
-    no-op on pre-vma jax (no pcast/pvary): there shard_map has no
-    varying-ness type system to satisfy — and the compat shim
-    (parallel/compat.py) runs with the replication checker disabled, so
-    no marking is needed or possible."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis_name)
-    return x
+    scan carries)."""
+    return lax.pcast(x, axis_name, to="varying")
 
 
 def make_mesh(axis_sizes: dict[str, int],

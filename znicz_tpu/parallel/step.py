@@ -26,7 +26,7 @@ key likewise lives on device and is split inside the compiled step, so the
 hot loop ships no host scalars at all.
 
 Mixed precision: when the device reports a bfloat16 ``compute_dtype``
-(TPUDevice on real TPU), activations and matmul/conv inputs run bf16 while
+(an XLADevice on a TPU), activations and matmul/conv inputs run bf16 while
 master params, gradient accumulation, loss and the SGD update stay f32 —
 the standard MXU recipe.  On CPU (tests) compute stays f32, so tier-1/2
 numerics are unchanged.
@@ -1170,6 +1170,12 @@ class FusedTrainStep(Unit):
             # belongs to process 0 — a default mesh must be addressable
             # from THIS rank (the elastic fleet's standalone-SPMD path)
             self.mesh = Mesh(np.array(jax.local_devices()[:1]), ("data",))
+            if jax.local_device_count() > 1:
+                # there is no CLI flag for a mesh: say what a bare
+                # `python -m znicz_tpu model.py` leaves unused
+                self.info(f"no mesh given: using 1 of "
+                          f"{jax.local_device_count()} local devices "
+                          f"(pass mesh= to the workflow to use more)")
         n_data = self.mesh.shape["data"]
         if self.loader is not None and \
                 self.loader.max_minibatch_size % n_data != 0:

@@ -20,9 +20,6 @@ import numpy as np
 from znicz_tpu.core.config import root
 from znicz_tpu.core.units import Unit
 
-root.common.dirs.plots = getattr(root.common.dirs, "plots", None) or \
-    "/root/repo/.data/plots"
-
 
 def _agg_pyplot():
     import matplotlib

@@ -68,6 +68,7 @@ CLI: ``python -m znicz_tpu elastic --workers N --snap-dir D
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import logging
 import os
@@ -303,6 +304,54 @@ class GoodputLedger:
                 else 0.0}
 
 
+class ChipBusy(RuntimeError):
+    """A second worker process was asked to share this host's TPU."""
+
+
+@functools.lru_cache(maxsize=None)
+def _probe_backend(jax_platforms: Optional[str]) -> tuple:
+    """``(platform, local device count)`` as a worker would see them,
+    asked of a short-lived child: the supervisor itself must not
+    initialize a backend, because a process that has holds the chip and
+    every worker it spawns then fails."""
+    env = dict(os.environ)
+    env.pop("JAX_PLATFORMS", None)
+    if jax_platforms is not None:
+        env["JAX_PLATFORMS"] = jax_platforms
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.local_devices(); "
+         "print(d[0].platform, len(d))"],
+        env=env, capture_output=True, text=True, timeout=120)
+    if proc.returncode != 0:
+        tail = " | ".join((proc.stderr or "").strip().splitlines()[-3:])
+        raise RuntimeError(f"cannot initialize a jax backend for the "
+                           f"workers (rc={proc.returncode}): {tail}")
+    platform, count = proc.stdout.split()[-2:]
+    return platform, int(count)
+
+
+def tpu_chips(env: Optional[Mapping[str, str]] = None) -> int:
+    """How many local TPU chips a worker started with ``env`` would
+    claim — all of them, there being no per-rank chip assignment — or 0
+    when its platform is not the TPU.  A platform pinned to the CPU
+    (every test and smoke) is read off the environment; anything else
+    costs one probe process per supervisor."""
+    wanted = (os.environ if env is None else env).get("JAX_PLATFORMS")
+    if wanted is not None and wanted.split(",")[0].strip() == "cpu":
+        return 0
+    platform, count = _probe_backend(wanted)
+    return count if platform == "tpu" else 0
+
+
+#: live workers this process spawned onto the TPU (at most one, see
+#: :func:`spawn_worker`); module state because the rule spans every
+#: supervisor in the process — ``learn`` runs a serving pool and a
+#: trainer side by side
+_tpu_workers: list = []
+_tpu_lock = threading.Lock()
+
+
 def spawn_worker(argv: Sequence[str], *, rank: int, log_path: str,
                  env: Optional[Mapping[str, str]] = None,
                  heartbeat_path: str = "",
@@ -311,11 +360,41 @@ def spawn_worker(argv: Sequence[str], *, rank: int, log_path: str,
     stdout+stderr piped into the :class:`WorkerProcess` log pump, text
     mode, line buffered.  ``heartbeat_path`` may be "" for workers whose
     liveness is probed another way (the serving fleet probes HTTP
-    ``/livez`` instead of heartbeat files)."""
-    proc = subprocess.Popen(
-        list(argv), env=dict(env) if env is not None else None,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        bufsize=1)
+    ``/livez`` instead of heartbeat files).
+
+    One process for each chip: a worker on the TPU claims every local
+    chip — on v5e hosts with one chip and with four, a second process
+    dies within seconds on "The TPU is already in use by process with
+    pid N" (measured, PR 21) — so while one lives a second spawn raises
+    :class:`ChipBusy` instead of starting a process that cannot run
+    (``fleet --workers 2``, a rolling update's surge worker, ``elastic
+    --workers 2`` and ``learn``'s trainer beside its serving worker all
+    end here).  There is no chip scheduler; workers pinned to the CPU
+    are not counted."""
+    def popen() -> subprocess.Popen:
+        return subprocess.Popen(
+            list(argv), env=dict(env) if env is not None else None,
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+            bufsize=1)
+
+    chips = tpu_chips(env)
+    if not chips:
+        proc = popen()
+    else:
+        with _tpu_lock:     # pools spawn from several threads
+            _tpu_workers[:] = [p for p in _tpu_workers
+                               if p.poll() is None]
+            if _tpu_workers:
+                raise ChipBusy(
+                    f"worker {rank} ({log_tree}) needs the TPU, and "
+                    f"worker pid {_tpu_workers[0].pid} already holds all "
+                    f"{chips} local chip(s): a chip belongs to one "
+                    f"process at a time and workers are not assigned "
+                    f"chips by rank. Run one worker per host (one "
+                    f"process can drive every chip), or pin the workers "
+                    f"to the CPU with JAX_PLATFORMS=cpu")
+            proc = popen()
+            _tpu_workers.append(proc)
     return WorkerProcess(rank, proc, heartbeat_path, log_path,
                          log_tree=log_tree)
 

@@ -155,7 +155,9 @@ class PagedKVDecoder(KVDecoder):
     the contiguous layout; production sets it smaller and banks on the
     long tail).  ``use_pallas=True`` routes single-query decode
     attention through the Pallas flash-decode kernel (interpret mode on
-    CPU) — OFF by default so the oracle pin rides one code path.
+    CPU; on a TPU a geometry the kernel cannot compile is a
+    ``ValueError`` here) — OFF by default so the oracle pin rides one
+    code path.
     """
 
     paged = True
@@ -193,15 +195,14 @@ class PagedKVDecoder(KVDecoder):
             from znicz_tpu.ops.pallas import decode as _pdk
 
             if not _pdk.supported(self.page, self.head_dim):
-                # decide at CONSTRUCTION, not mid-request: compiled
-                # Mosaic wants sublane pages / lane-sized heads —
-                # anything else serves the jnp path with one warning
-                self.warning(
-                    f"pallas decode disabled: page={self.page}, "
-                    f"head_dim={self.head_dim} not compilable "
-                    f"(need page % 8 == 0, head_dim % 128 == 0); "
-                    f"serving the jnp gather path")
-                self.use_pallas = False
+                # decide at CONSTRUCTION, not mid-request — and refuse:
+                # the caller asked for the kernel, so serving the jnp
+                # path instead would answer a different question
+                raise ValueError(
+                    f"pallas decode cannot compile page={self.page}, "
+                    f"head_dim={self.head_dim} (need page % 8 == 0, "
+                    f"head_dim % 128 == 0); drop --pallas-decode or "
+                    f"change --page-size")
         dt = self._cast_policy()
         shape = (self.n_layers, self.arena_pages, self.page, self.heads,
                  self.head_dim)

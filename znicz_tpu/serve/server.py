@@ -579,7 +579,8 @@ def build_generate_parser() -> argparse.ArgumentParser:
     p.add_argument("--pallas-decode", action="store_true",
                    help="route single-query decode attention through "
                         "the Pallas flash-decode kernel (interpret "
-                        "mode off-TPU)")
+                        "mode off-TPU; on a TPU a page size or head "
+                        "dim it cannot compile is an error)")
     p.add_argument("--no-warmup", action="store_true",
                    help="skip pre-compiling the cache buckets")
     p.add_argument("--feedback-spool", default=None, metavar="DIR",
@@ -657,11 +658,17 @@ def generate_main(argv) -> int:
         from znicz_tpu.serve.paged import PagedKVDecoder, truncate_draft
         from znicz_tpu.utils.export import load_lm_draft
 
-        decoder = PagedKVDecoder(
-            params, heads=meta["heads"], max_len=args.max_len,
-            batch=args.slots, page=args.page_size,
-            arena_pages=args.arena_pages or None,
-            use_pallas=args.pallas_decode)
+        try:
+            decoder = PagedKVDecoder(
+                params, heads=meta["heads"], max_len=args.max_len,
+                batch=args.slots, page=args.page_size,
+                arena_pages=args.arena_pages or None,
+                use_pallas=args.pallas_decode)
+        except ValueError as exc:
+            # e.g. --pallas-decode with a geometry the kernel cannot
+            # compile on this TPU: an error, never the jnp path
+            print(f"generate: {exc}")
+            return 2
         if args.speculative:
             if args.spec_k < 1:
                 print(f"generate: --spec-k must be >= 1, got "

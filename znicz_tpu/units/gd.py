@@ -101,6 +101,11 @@ class GradientDescent(GradientDescentBase):
 
             self._backward = pallas_backward
         else:
+            if bool(root.common.engine.get("pallas", False)):
+                # asked for the kernels and not getting one: say so
+                self.warning(f"engine.pallas: no fused Pallas backward "
+                             f"for activation {self.ACTIVATION!r}; "
+                             f"{self.name} uses the XLA path")
             # drop a stale instance override from a previous initialize
             # under engine.pallas — the flag must toggle both ways
             self.__dict__.pop("_backward", None)

@@ -66,7 +66,7 @@ def _epoch_scan(dataset, w, coords, idxs, ms, alpha, radius, *,
     closure) so jit's in-process cache carries across workflow builds:
     a warm-up build genuinely warms the timed build (the closure version
     re-traced per build, and on hardware the re-trace + persistent-cache
-    reload dominated the whole measured SOM run — docs/BENCH_LOG.md)."""
+    reload dominated the whole measured SOM run)."""
     def body(wc, inp):
         idx, m = inp
         new_w, _ = _som_batch_step(dataset[idx], wc, coords, alpha,

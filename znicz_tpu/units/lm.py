@@ -102,7 +102,12 @@ class TransformerLMStep(AcceleratedUnit):
             raise ValueError("TransformerLMStep needs loader=")
         self.vocab_size = int(self.loader.vocab_size)
         if self.mesh is None:
-            self.mesh = make_mesh({"data": 1, "seq": 1, "model": 1})
+            self.mesh = make_mesh({"data": 1, "seq": 1, "model": 1},
+                                  jax.local_devices())
+            if jax.local_device_count() > 1:
+                self.info(f"no mesh given: using 1 of "
+                          f"{jax.local_device_count()} local devices "
+                          f"(pass mesh= to the workflow to use more)")
         if self._params is None:
             self._params = tfm.init_params(
                 prng.get(), self.n_layers, self.d, self.heads, self.ff,

@@ -5,10 +5,11 @@ means it runs on the target chip at least once; a lowering failure is a
 FAIL, never a silent fallback).
 
 ``run_parity(interpret=False)`` returns ``{family: "ok" | "FAIL: ..."}``.
-bench.py emits the dict as the ``pallas_hw_parity`` line on real TPU;
-with ``interpret=True`` the same sweep doubles as a CPU smoke test of the
-harness itself (tests/test_pallas_kernels.py pins the per-kernel math —
-this module only cares that the compiled kernel agrees with the oracle).
+``chip_smoke.py`` runs it compiled on the chip and fails on anything but
+``ok``; with ``interpret=True`` the same sweep doubles as a CPU smoke test
+of the harness itself (tests/test_pallas_kernels.py pins the per-kernel
+math — this module only cares that the compiled kernel agrees with the
+oracle).
 
 Shapes are TPU-native (lane-aligned 128 channels, 8-row tiles) so the
 sweep exercises the real Mosaic tiling, not degenerate padding paths.
@@ -19,39 +20,18 @@ from __future__ import annotations
 import numpy as np
 
 
-class SkipKernel(Exception):
-    """A kernel that cannot run in THIS environment (not a failure):
-    e.g. PRNG-drawing kernels under a jax whose pallas has no
-    TPU-emulating interpreter.  Never raised in compiled mode."""
-
-
-def tpu_interpret_params():
-    """The TPU-emulating pallas interpreter params (needed off-chip for
-    kernels that draw in-kernel PRNG bits — plain ``interpret=True`` has
-    no ``prng_seed`` rule).  The class name moved across jax versions;
-    returns None when this jax has none (jax <= 0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    for name in ("InterpretParams", "TPUInterpretParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls()
-    return None
-
-
 def _check(name, fn, results):
     try:
         fn()
         results[name] = "ok"
-    except SkipKernel as exc:
-        results[name] = f"skipped: {exc}"[:200]
     except Exception as exc:  # noqa: BLE001 — a sweep must finish
-        results[name] = f"FAIL: {exc!r}"[:200]
+        results[name] = f"FAIL: {exc!r}"[:400]
 
 
 def run_parity(interpret: bool = False) -> dict:
     import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
 
     from znicz_tpu.ops import (adam as adam_ops, attention as att,
                                conv as conv_ops, deconv as deconv_ops,
@@ -88,18 +68,10 @@ def run_parity(interpret: bool = False) -> dict:
                                        rtol=1e-5, atol=1e-6)
 
     # kernels that draw in-kernel PRNG bits need the TPU-emulating
-    # interpreter off-chip (plain interpret=True has no prng_seed rule);
-    # on a jax without one they SKIP in interpret mode (still run
-    # compiled on hardware, where interpret=False)
-    prng_interp = tpu_interpret_params() if interpret else False
-
-    def _need_prng_interp():
-        if interpret and prng_interp is None:
-            raise SkipKernel("no TPU-emulating pallas interpreter in "
-                             "this jax (pre-InterpretParams)")
+    # interpreter off-chip (plain interpret=True has no prng_seed rule)
+    prng_interp = pltpu.InterpretParams() if interpret else False
 
     def dropout():
-        _need_prng_interp()
         x = jnp.asarray(rng.normal(size=(256, 256)), jnp.float32)
         ratio = 0.4
         y, mask = pk.dropout_forward(x, seed=7, ratio=ratio,
@@ -177,7 +149,6 @@ def run_parity(interpret: bool = False) -> dict:
                                        rtol=1e-4, atol=1e-3)
 
     def stochastic_pool():
-        _need_prng_interp()
         x = rng.normal(size=(4, 16, 16, 128)).astype(np.float32)
         patch, valid, _ = pool_ops.patches(np, x, 2, 2, 2, 2,
                                            pad_value=0.0)
@@ -270,6 +241,29 @@ def run_parity(interpret: bool = False) -> dict:
         flash_attention(dtype=jnp.bfloat16, rtol=5e-2, atol=5e-2,
                         grad_rtol=1e-1, grad_atol=5e-1)
 
+    def paged_decode():
+        # the serve plane's kernel (--pallas-decode): f32 and the bf16
+        # arena the chip actually holds, page ids shuffled so the
+        # scalar-prefetched gather is exercised
+        from znicz_tpu.ops.pallas import decode as pdk
+        B, H, Dh, page, n_pages, P = 8, 4, 128, 16, 40, 4
+        pt = jnp.asarray(rng.integers(0, n_pages, size=(B, P)), jnp.int32)
+        lengths = jnp.asarray(rng.integers(1, P * page + 1, size=(B,)),
+                              jnp.int32)
+        for dtype, tol in ((jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)):
+            q = jnp.asarray(rng.normal(size=(B, H, Dh)), dtype)
+            k = jnp.asarray(rng.normal(size=(n_pages, page, H, Dh)), dtype)
+            v = jnp.asarray(rng.normal(size=(n_pages, page, H, Dh)), dtype)
+            o = pdk.paged_flash_decode(q, k, v, pt, lengths,
+                                       interpret=interpret)
+            # the kernel's products are exact f32 on the VPU; hold the
+            # einsum oracle to the same (the MXU's default is bf16
+            # passes)
+            with jax.default_matmul_precision("highest"):
+                want = pdk.reference(q, k, v, pt, lengths)
+            np.testing.assert_allclose(np.asarray(o), np.asarray(want),
+                                       rtol=tol, atol=tol)
+
     def sgd_bf16state():
         # narrow optimizer state: velocity stored bf16, f32 math in-tile
         w = jnp.asarray(rng.normal(size=(256, 256)), jnp.float32)
@@ -297,6 +291,7 @@ def run_parity(interpret: bool = False) -> dict:
                      ("flash_attention", flash_attention),
                      ("conv_fwd_bf16", conv_fwd_bf16),
                      ("flash_attention_bf16", flash_attention_bf16),
+                     ("paged_decode", paged_decode),
                      ("sgd_bf16state", sgd_bf16state)):
         _check(name, fn, results)
     return results
