@@ -1,18 +1,17 @@
-"""Step-time anatomy (ISSUE 20): the StepAnatomy accountant, the
-split-dispatch fused/transformer producers' numerics parity, phase-sum
-vs step-wall reconciliation, MFU gauge wiring, the per-rank straggler
-rule, goodput note plumbing, and the bench perf-regression sentinel
-(synthetic 20% cliff flagged; the real recorded r04->r05 pair passes).
+"""Step-time anatomy (ISSUE 20): the StepAnatomy accountant, MFU gauge
+wiring, the per-rank straggler rule, goodput note plumbing, and the
+bench perf-regression sentinel (synthetic 20% cliff flagged; the real
+recorded r04->r05 pair passes).  The split-dispatch producers went with
+ISSUE 24 (the train planes feed ``znicz_anatomy_step_seconds`` from the
+dispatch cadence; tests/test_observe.py).
 """
 
 import importlib.util
 import json
 import os
 
-import numpy as np
 import pytest
 
-from znicz_tpu.core import prng
 from znicz_tpu.observe import probe, registry
 from znicz_tpu.observe.anatomy import TRAIN_PHASES, StepAnatomy
 
@@ -78,152 +77,6 @@ def test_observe_phase_respects_probe_gate():
     probe.anatomy_phase("gated", "stage", 0.5)
     assert _flat()['znicz_anatomy_phase_seconds_count'
                    '{plane="gated",phase="stage"}'] == before + 1.0
-
-
-# -- fused producer (dp + shard_params + int8) -------------------------------
-
-def _run_fused(anatomy: bool, seed: int = 31):
-    from znicz_tpu.core.backends import XLADevice
-    from znicz_tpu.models.mnist_fc import build_fused
-    from znicz_tpu.parallel.mesh import data_parallel_mesh
-
-    prng.seed_all(seed)
-    w = build_fused(max_epochs=2, layers=(32,), minibatch_size=16,
-                    n_train=96, n_valid=32,
-                    mesh=data_parallel_mesh(4), optimizer="adam",
-                    shard_params=True, anatomy=anatomy,
-                    quantized_collectives={"mode": "int8",
-                                           "error_feedback": True})
-    w.initialize(device=XLADevice())
-    w.run()
-    hist = [h["metric_validation"] for h in w.decision.metrics_history]
-    w.stop()
-    return hist
-
-
-def test_anatomy_phase_sum_matches_step_wall(monkeypatch):
-    """ISSUE 20 acceptance: on the forced multi-device CPU mesh a
-    dp+shard_params+int8 anatomy run attributes per-phase seconds
-    summing to within 10% of the measured step wall, counts its steps,
-    and reads a nonzero MFU against the pinned nominal peak."""
-    monkeypatch.setenv("ZNICZ_TPU_PEAK_FLOPS", "1e12")
-    base = _flat()
-    base_phase = {k: v for k, v in base.items() if k.startswith(
-        'znicz_anatomy_phase_seconds_sum{plane="fused"')}
-    base_step = base.get(
-        'znicz_anatomy_step_seconds_sum{plane="fused"}', 0.0)
-    base_steps = base.get('znicz_anatomy_steps_total{plane="fused"}',
-                          0.0)
-    hist = _run_fused(anatomy=True)
-    assert len(hist) == 2
-    flat = _flat()
-    phase_sum = sum(
-        v - base_phase.get(k, 0.0) for k, v in flat.items()
-        if k.startswith('znicz_anatomy_phase_seconds_sum{plane="fused"'))
-    step_sum = flat['znicz_anatomy_step_seconds_sum{plane="fused"}'] \
-        - base_step
-    steps = flat['znicz_anatomy_steps_total{plane="fused"}'] - base_steps
-    assert steps == 12                   # 2 epochs x 96/16 minibatches
-    assert step_sum > 0.0
-    assert abs(phase_sum - step_sum) <= 0.10 * step_sum, \
-        (phase_sum, step_sum)
-    # every train phase genuinely charged (shard_params => zero_gather,
-    # int8 => the quantized collective dispatch)
-    for phase in TRAIN_PHASES:
-        assert flat['znicz_anatomy_phase_seconds_count'
-                    f'{{plane="fused",phase="{phase}"}}'] >= steps
-    assert flat['znicz_anatomy_mfu{plane="fused"}'] > 0.0
-    # the families are live on the scrape surface and rank-label into
-    # the fleet-merged view
-    prom = registry.REGISTRY.render_prometheus()
-    assert "znicz_anatomy_mfu" in prom
-    assert "znicz_goodput_productive_seconds_total" in prom
-    from znicz_tpu.observe import federation as fed
-    agg = fed.FleetAggregator(min_refresh_s=0.0)
-    agg.add_source(3, registry.REGISTRY.render_prometheus)
-    try:
-        merged = agg.snapshot_flat(skip_zero=False)
-        assert any(k.startswith("znicz_anatomy_step_seconds_sum")
-                   and 'rank="3"' in k for k in merged)
-    finally:
-        agg.close()
-
-
-def test_anatomy_numerics_track_fused_path():
-    """The split-dispatch programs compute the same training run as the
-    fused single-program path to float tolerance (XLA fuses and
-    reassociates differently across the program cuts, so bit-exactness
-    is NOT the contract — closeness is)."""
-    hist_fused = _run_fused(anatomy=False)
-    hist_anat = _run_fused(anatomy=True)
-    assert len(hist_anat) == len(hist_fused)
-    # validation error percent per epoch: identical up to at most one
-    # boundary sample flipping on ~1e-7 loss differences
-    np.testing.assert_allclose(hist_anat, hist_fused,
-                               atol=100.0 / 32 + 1e-9)
-
-
-def test_anatomy_rejects_accumulation():
-    from znicz_tpu.core.backends import XLADevice
-    from znicz_tpu.models.mnist_fc import build_fused
-    from znicz_tpu.parallel.mesh import data_parallel_mesh
-
-    prng.seed_all(5)
-    w = build_fused(max_epochs=1, layers=(16,), minibatch_size=16,
-                    n_train=64, n_valid=16,
-                    mesh=data_parallel_mesh(2), anatomy=True,
-                    accumulate_steps=2)
-    with pytest.raises(ValueError, match="accumulate"):
-        w.initialize(device=XLADevice())
-    w.stop()
-
-
-# -- transformer producer ----------------------------------------------------
-
-def test_transformer_anatomy_loss_parity(cpu_devices, monkeypatch):
-    """The transformer anatomy step applies the TRUE batch-mean
-    gradient (local grads + one explicit psum, the quantized-collectives
-    semantics — see the make_train_step docstring), so its reference is
-    a SINGLE-SHARD full-batch run, which it must match to float
-    tolerance — NOT the multi-shard exact path, whose AD-transposed
-    per-replica grads follow a different (documented) trajectory.  All
-    four phases and the MFU gauge populate."""
-    import jax
-    from znicz_tpu.parallel import transformer as tfm
-    from znicz_tpu.parallel.mesh import make_mesh
-
-    monkeypatch.setenv("ZNICZ_TPU_PEAK_FLOPS", "1e12")
-    prng.seed_all(7)
-    gen = prng.get()
-    n_layers, d, heads, ff, vocab = 1, 16, 2, 32, 11
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
-    rng = np.random.default_rng(1)
-    tokens = rng.integers(0, vocab, (4, 8)).astype(np.int32)
-    labels = ((tokens + 1) % vocab).astype(np.int32)
-    meshes = {
-        "plain": make_mesh({"data": 1, "seq": 1, "model": 1}),
-        "anatomy": make_mesh({"data": 2, "seq": 1, "model": 1}),
-    }
-
-    losses = {}
-    for name, anatomy in (("plain", False), ("anatomy", True)):
-        step, _ = tfm.make_train_step(meshes[name], n_layers, d, heads,
-                                      ff, vocab, lr=0.1, anatomy=anatomy)
-        p = {k: (v if not isinstance(v, list) else
-                 [dict(b) for b in v]) for k, v in params.items()}
-        run = []
-        for _ in range(5):
-            p, loss = step(p, tokens, labels)
-            run.append(float(jax.device_get(loss)))
-        losses[name] = run
-    np.testing.assert_allclose(losses["anatomy"], losses["plain"],
-                               rtol=2e-4)
-    assert losses["anatomy"][-1] < losses["anatomy"][0]
-    flat = _flat()
-    for phase in ("grad", "collective", "update"):
-        assert flat['znicz_anatomy_phase_seconds_count'
-                    f'{{plane="transformer",phase="{phase}"}}'] >= 5
-    assert flat['znicz_anatomy_mfu{plane="transformer"}'] > 0.0
 
 
 # -- goodput plumbing --------------------------------------------------------

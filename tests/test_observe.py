@@ -7,11 +7,16 @@ plane's contract with training: instrumentation disabled reduces the
 walk to the bare loop with bit-exact metric histories, and the ring
 buffer stays bounded under a 10k-step soak."""
 
+import importlib.util
 import json
 import logging
 import math
+import os
 import re
+import subprocess
+import sys
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -21,6 +26,7 @@ from znicz_tpu.core import prng
 from znicz_tpu.core.backends import XLADevice
 from znicz_tpu.core.logger import EVENT_LOGGER, configure, event_log
 from znicz_tpu.observe import probe
+from znicz_tpu.observe import trace as trace_mod
 from znicz_tpu.observe.registry import Registry
 from znicz_tpu.observe.trace import Tracer
 from znicz_tpu.resilience import faults
@@ -559,3 +565,402 @@ def test_cli_trace_subcommand_usage():
     from znicz_tpu.__main__ import main
     assert main(["trace"]) == 2
     assert main(["trace", "out.json"]) == 2
+
+
+# -- ISSUE 24: one tracer, two sinks; named scopes; the readers ---------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONV_LAYERS = [
+    {"type": "conv_str", "->": {"n_kernels": 4, "kx": 3, "ky": 3},
+     "<-": {"learning_rate": 0.01}},
+    {"type": "norm", "->": {"alpha": 1e-4, "beta": 0.75, "k": 2.0, "n": 3}},
+    {"type": "max_pooling", "->": {"kx": 2, "ky": 2, "sliding": (2, 2)}},
+    {"type": "dropout", "->": {"dropout_ratio": 0.5}},
+    {"type": "all2all_tanh", "->": {"output_sample_shape": 8},
+     "<-": {"learning_rate": 0.01}},
+    {"type": "activation_tanh"},
+    {"type": "softmax", "->": {"output_sample_shape": 6},
+     "<-": {"learning_rate": 0.01}},
+]
+CONV_SCOPES = ["conv.00_", "norm.01_", "pool.02_", "dropout.03_", "fc.04_",
+               "act.05_", "fc.06_"]
+
+
+@pytest.fixture(scope="module")
+def conv_workflow():
+    """A tiny fused step with one unit of every scope group, run once."""
+    prng.seed_all(5)
+    w = StandardWorkflow(
+        name="ScopeTest", layers=CONV_LAYERS, loss_function="softmax",
+        loader_name="synthetic_classifier",
+        loader_config={**LOADER, "sample_shape": (10, 10, 1)},
+        decision_config={"max_epochs": 1})
+    w.initialize(device=XLADevice())
+    w.run()
+    return w
+
+
+def _reader(name):
+    """A reader of the benchmark, imported by path (the tier-1 command
+    does not collect benchmark/tests)."""
+    bench = os.path.join(REPO, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    spec = importlib.util.spec_from_file_location(
+        f"_reader_{name}", os.path.join(bench, "readers", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_live_span_lies_in_the_profilers_host_plane(tmp_path):
+    """Under ``jax.profiler.start_trace`` a span is in /host:CPU with the
+    ring's name, its args, and the ring's duration within 5 %."""
+    import glob
+
+    import jax
+
+    tracer = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.span("obs.live", unit="u1"):
+            time.sleep(0.05)
+        with tracer.timed("obs.timed", {"unit": "u2"}) as sp:
+            time.sleep(0.02)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    host = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("obs."):
+                        host[ev.name] = (ev.duration_ns, dict(ev.stats))
+    ring = {e[1]: e for e in tracer._events}
+    assert set(host) == {"obs.live", "obs.timed"} == tracer.live_names
+    for name, (dur_ns, stats) in host.items():
+        assert dur_ns / 1e3 == pytest.approx(ring[name][3], rel=0.05)
+        assert stats["unit"] == ring[name][5]["unit"]
+    assert sp.dt * 1e6 == ring["obs.timed"][3]        # the same two reads
+
+
+def test_disabled_tracer_annotates_nothing_and_imports_no_jax():
+    """``observe/trace.py`` alone (no package import) in a fresh process:
+    spans work, enabled or not, and jax is never imported for them."""
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('t', "
+        f"{os.path.join(REPO, 'znicz_tpu', 'observe', 'trace.py')!r})\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(m)\n"
+        "off = m.Tracer(enabled=False)\n"
+        "assert off.span('a') is m._NOOP\n"
+        "on = m.Tracer()\n"
+        "with on.span('b', k=1): pass\n"
+        "with on.timed('c') as sp: pass\n"
+        "assert m._annotation('b', None) is None\n"
+        "assert [e[1] for e in on._events] == ['b', 'c'] and sp.dt >= 0\n"
+        "assert 'jax' not in sys.modules\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr
+    # in this process jax is loaded: a disabled tracer still makes none
+    made = []
+    real = trace_mod._annotation
+    trace_mod._annotation = lambda *a: made.append(a) or real(*a)
+    try:
+        off = Tracer(enabled=False)
+        with off.span("x"):
+            pass
+        with off.timed("y") as sp:
+            pass
+    finally:
+        trace_mod._annotation = real
+    assert made == [] and len(off) == 0 and sp.dt >= 0.0
+
+
+def test_timed_span_track_override_and_error_mark():
+    tracer = Tracer()
+    with pytest.raises(KeyError):
+        with tracer.timed("obs.err", {"unit": "u"}, tid=4242):
+            raise KeyError("boom")
+    ph, name, _, _, tid, args = tracer._events[-1]
+    assert (ph, name, tid) == ("X", "obs.err", 4242)
+    assert args == {"unit": "u", "error": True}
+
+
+def test_workflow_step_error_span_lands_when_a_unit_raises():
+    from znicz_tpu.core.units import Unit
+
+    w = run_workflow(max_epochs=1, name="ObserveErr")
+
+    class Boom(Unit):
+        def run(self):
+            raise RuntimeError("unit failed")
+
+    boom = Boom(w, name="Boom")
+    boom.link_from(w.start_point)
+    w.initialize(device=XLADevice())
+    w.decision.complete.set(False)
+    observe.TRACER.clear()
+    with pytest.raises(RuntimeError, match="unit failed"):
+        w.run()
+    spans = [e for e in observe.TRACER.export_dict()["traceEvents"]
+             if e["name"] == "workflow.step"]
+    assert spans[-1]["args"] == {"unit": "Boom", "error": True}
+    assert all("error" not in e["args"] for e in spans[:-1])
+
+
+def test_fused_step_spans_and_step_cadence_by_default(conv_workflow):
+    """No switch: a default run leaves ``train.dispatch`` and
+    ``train.metrics_read`` spans and observations in
+    ``znicz_anatomy_step_seconds{plane="fused"}``."""
+    before = observe.REGISTRY.snapshot_flat(skip_zero=False).get(
+        'znicz_anatomy_step_seconds_count{plane="fused"}', 0.0)
+    observe.TRACER.clear()
+    w = run_workflow(max_epochs=1, name="ObserveCadence")
+    names = [e["name"] for e in observe.TRACER.export_dict()["traceEvents"]]
+    n_dispatch = names.count("train.dispatch")
+    assert n_dispatch == 9                # 6 train + 3 validation batches
+    assert names.count("train.metrics_read") == 2    # one a class pass
+    after = observe.REGISTRY.snapshot_flat(skip_zero=False)[
+        'znicz_anatomy_step_seconds_count{plane="fused"}']
+    assert after - before == n_dispatch - 1
+    assert {"train.dispatch", "train.metrics_read",
+            "workflow.step"} <= observe.TRACER.live_names
+    assert w.step.loss >= 0.0
+
+
+def test_lowered_fused_step_holds_every_scope_once_per_layer(conv_workflow):
+    st = conv_workflow.step
+    fn = st._train_fn_idx
+    text = fn.lower(*fn._abstract[0]).as_text(debug_info=True)
+    unit_scopes = sorted(n for n in probe._scope_names if "." in n and
+                         n.split(".")[0] in ("conv", "fc", "norm", "pool",
+                                             "dropout", "act") and
+                         n.endswith(tuple(f.name for f in st.forwards)))
+    assert [s[:len(p)] for s, p in zip(unit_scopes, sorted(CONV_SCOPES))] \
+        == sorted(CONV_SCOPES)
+    for scope in unit_scopes:
+        assert f"jvp({scope})/" in text, scope
+        if not scope.startswith(("dropout", "act")):
+            assert f"transpose(jvp({scope}))/" in text, scope
+    for scope in ("gather_batch", "update", "grad_reduce"):
+        assert f"/{scope}/" in text, scope
+    assert "jvp(loss)/" in text and "transpose(jvp(loss))/" in text
+    assert "zero_gather" not in text      # no shard_params here
+
+
+def test_scope_map_covers_the_program_and_is_built_only_when_called(
+        conv_workflow):
+    from znicz_tpu import compilecache
+
+    calls = []
+    real = probe.parse_scopes
+    probe.parse_scopes = lambda *a, **k: calls.append(1) or real(*a, **k)
+    try:
+        stats = compilecache.stats()
+        run_workflow(max_epochs=1, name="ObserveLazy")
+        assert calls == []               # a run never builds the map
+        assert compilecache.stats()["misses"] >= stats["misses"]
+        scopes = probe.scope_map()
+    finally:
+        probe.parse_scopes = real
+    assert calls
+    train = scopes["jit__local_train_idx"]
+    fn = conv_workflow.step._train_fn_idx
+    text = fn.lower(*fn._abstract[0]).compile().as_text()
+    names = {m.group(2) for m in map(probe._INSTRUCTION.match,
+                                     text.splitlines())
+             if m and m.group(3) not in probe.TRIVIAL_OPCODES}
+    assert names and names <= set(train)
+    found = {c.rstrip(")").rsplit("(", 1)[-1] for c in train.values()}
+    assert {"gather_batch", "loss", "update"} <= found
+    assert all(any(f.startswith(p) for f in found) for p in CONV_SCOPES)
+    unscoped = [n for n, c in train.items() if not c]
+    assert len(unscoped) <= 0.05 * len(train), unscoped[:10]
+
+
+def test_scope_map_survives_a_cache_entry_from_an_unscoped_build(
+        tmp_path, monkeypatch):
+    """The persistent cache's key leaves metadata out, so a scoped step
+    may run an executable that an unscoped build of the same program put
+    there (as the parent commit's did on the chip): the map must still
+    come from this build's own annotation."""
+    import contextlib
+    import gc
+
+    from znicz_tpu import compilecache
+
+    prev = compilecache.active_dir()
+    compilecache.configure(cache_dir=str(tmp_path), min_compile_time_s=0.0,
+                           force=True)
+    try:
+        monkeypatch.setattr(probe, "scope",
+                            lambda name: contextlib.nullcontext())
+        run_workflow(max_epochs=1, name="ScopeStaleA")
+        monkeypatch.undo()
+        gc.collect()
+        hits = compilecache.stats()["hits"]
+        w = run_workflow(max_epochs=1, name="ScopeStaleB")
+        assert compilecache.stats()["hits"] > hits      # A's executable
+        fn = w.step._train_fn_idx
+        memo = fn.lower(*fn._abstract[0]).compile().as_text()
+        assert "fc.00_" not in memo          # what runs has A's metadata
+        train = probe.scope_map()["jit__local_train_idx"]
+    finally:
+        compilecache.configure(cache_dir=prev, force=True)
+    found = {c.rstrip(")").rsplit("(", 1)[-1] for c in train.values()}
+    assert {"gather_batch", "loss", "update"} <= found
+    assert any(f.startswith("fc.00_") for f in found)
+    assert sum(1 for c in train.values() if not c) <= 0.05 * len(train)
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(f)/jit(main)/conv.00_c/conv_general_dilated", "conv.00_c"),
+    ("jit(f)/jvp(conv.00_c)/inner/mul", "jvp(conv.00_c)"),
+    ("jit(f)/transpose(jvp(conv.00_c))/mul", "transpose(jvp(conv.00_c))"),
+    ("jit(f)/while/body/update/sub", "update"),
+    ("jit(f)/jit(_threefry_split)/xor", ""),
+])
+def test_scope_of_keeps_the_component_as_it_stands(op_name, scope):
+    assert probe.scope_of(op_name, {"conv.00_c", "update"}) == scope
+
+
+def test_parse_scopes_fusion_root_and_bare_copies_inherit():
+    hlo = """HloModule jit_step, is_scheduled=true
+
+%fused_computation (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  ROOT %mul.1 = f32[8]{0} multiply(%p0, %p0), metadata={op_name="jit(step)/jvp(fc.00_a)/mul"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %copy.1 = f32[8]{0:T(8)} copy(%a)
+  %bitcast.2 = f32[8]{0} bitcast(%copy.1)
+  %fusion.3 = f32[8]{0} fusion(%bitcast.2), kind=kLoop, calls=%fused_computation
+  %copy.4 = f32[8]{0} copy(%fusion.3)
+  %add.5 = f32[8]{0} add(%fusion.3, %fusion.3), metadata={op_name="jit(step)/jit(other)/add"}
+  ROOT %sub.6 = f32[8]{0} subtract(%copy.4, %add.5), metadata={op_name="jit(step)/update/sub"}
+}
+"""
+    module, scopes = probe.parse_scopes(hlo, {"fc.00_a", "update"})
+    assert module == "jit_step"
+    assert scopes == {"mul.1": "jvp(fc.00_a)", "copy.1": "jvp(fc.00_a)",
+                      "fusion.3": "jvp(fc.00_a)", "copy.4": "update",
+                      "add.5": "update", "sub.6": "update"}
+
+
+def test_transformer_step_scopes():
+    import jax
+    import numpy as np
+
+    from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"data": 1, "seq": 1, "model": 1}, jax.devices()[:1])
+    step, _ = tfm.make_train_step(mesh, 2, 16, 2, 32, 11, lr=0.1)
+    params = tfm.init_params(np.random.default_rng(0), 2, 16, 2, 32, 11)
+    tok = np.zeros((2, 8), np.int32)
+    text = step.lower(params, tok, tok).as_text(debug_info=True)
+    for scope in ("embed", "block0.attn", "block0.mlp", "block1.attn",
+                  "block1.mlp", "ce"):
+        assert f"jvp({scope})/" in text, scope
+        assert f"transpose(jvp({scope}))/" in text, scope
+    assert "/update/" in text
+
+
+def test_every_pallas_call_is_named():
+    import ast
+
+    unnamed = []
+    root = os.path.join(REPO, "znicz_tpu", "ops", "pallas")
+    for fname in sorted(os.listdir(root)):
+        if not fname.endswith(".py"):
+            continue
+        tree = ast.parse(open(os.path.join(root, fname)).read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and \
+                    isinstance(node.func, ast.Attribute) and \
+                    node.func.attr == "pallas_call" and \
+                    not any(k.arg == "name" for k in node.keywords):
+                unnamed.append(f"{fname}:{node.lineno}")
+    assert unnamed == []
+
+
+# the readers, on hand-made traces (times in ns)
+
+def test_reader_scope_seconds_by_hand():
+    sd = _reader("scope_device")
+    scopes = {"jit_step": {"fusion.1": "jvp(conv.00_c)",
+                           "copy.2": "transpose(jvp(conv.00_c))",
+                           "while.3": "update", "fusion.4": "update",
+                           "pad.5": "jvp(fc.01_f)", "add.9": ""}}
+    modules = [(0, 1000, "jit_step"), (2000, 2100, "jit_add")]
+    ops = [(0, 100, "fusion.1", "fusion"),
+           (100, 250, "copy.2", "copy"),
+           (300, 700, "while.3", "while"),
+           (350, 450, "fusion.4", "fusion"),     # inside the while
+           (700, 760, "pad.5", "pad"),
+           (800, 830, "add.9", "add"),           # no scope in the map
+           (2000, 2100, "fusion.1", "fusion")]   # another program
+    got = sd.scope_seconds(ops, modules, scopes)
+    ns = {k: round(v * 1e9) for k, v in got.items()}
+    assert ns == {("conv.00_c", "fwd", "other"): 100,
+                  ("conv.00_c", "bwd", "copy"): 150,
+                  ("update", "fwd", "other"): 400,
+                  ("fc.01_f", "fwd", "pad"): 60,
+                  (sd.UNSCOPED, "fwd", "other"): 130}
+    assert sum(ns.values()) == 100 + 150 + 400 + 60 + 30 + 100   # the union
+    rows = {r[0]: r[1:] for r in sd.table(got, steps=2)}
+    assert rows["conv.00_c"] == pytest.approx(
+        (50e-6, 0, 0, 75e-6, 75e-6, 0))
+    assert [sd.group_of(s) for s in ("conv.00_c", "update", "loss")] == \
+        ["conv", "update", "loss"]
+
+
+def test_reader_gap_owners_by_hand():
+    ps = _reader("program_spans")
+    ops = [(0, 1000), (31000, 32000), (52000, 53000), (53001, 54000)]
+    spans = [(500, 31500, "workflow.step"),          # covers gap 1 fully
+             (900, 30300, "train.metrics_read"),     # most of it: inner
+             (40000, 47000, "train.dispatch")]       # 7 of gap 2's 20 us
+    # the runtime's np.asarray span over gap 1 is not a program span: the
+    # reader filters by name before it calls gap_owners
+    owners, innermost, total = ps.gap_owners(ops, spans)
+    # the read ends before the gap does, so the enclosing delivery has
+    # the longer overlap and owns the whole gap (as on the chip, PR 24)
+    assert owners == {"workflow.step": 30000.0, "train.dispatch": 20000.0}
+    assert total == 50000.0                              # 1 ns gap skipped
+    assert innermost == {"train.metrics_read": 29300.0,
+                         "workflow.step": 700.0,
+                         "train.dispatch": 7000.0, ps.NO_SPAN: 13000.0}
+    # both cover the gap fully: a tie, and the shorter (inner) span wins
+    tie = [spans[0], (900, 31200, "train.metrics_read")]
+    owners, innermost, total = ps.gap_owners(ops, tie)
+    assert owners == {"train.metrics_read": 30000.0, ps.NO_SPAN: 20000.0}
+    assert innermost == {"train.metrics_read": 30000.0,
+                         ps.NO_SPAN: 20000.0}
+
+
+def test_reader_step_host_and_sink_agreement_by_hand():
+    ps = _reader("program_spans")
+    step = {"name": "workflow.step", "args": {"unit": "FusedStep"}}
+    ring = [{**step, "ts": 0.0, "dur": 300.0},
+            {"name": "train.metrics_read", "ts": 100.0, "dur": 150.0},
+            {**step, "ts": 1000.0, "dur": 200.0},
+            {"name": "workflow.step", "args": {"unit": "Loader"},
+             "ts": 1300.0, "dur": 900.0},
+            {"name": "train.metrics_read", "ts": 5000.0, "dur": 50.0}]
+    assert ps.step_host_ms(ring, "FusedStep", "train.metrics_read") == \
+        pytest.approx((300 + 200 - 150) / 2 / 1e3)
+    assert ps.step_host_ms(ring, "Nobody", "train.metrics_read") is None
+    # ring origin at unix 10 s; the profile ran from 10.0005 s for 1 ms
+    window = (10.0005e9, 10.0015e9)
+    host = [(500e3, 700e3, "workflow.step"),       # the ring's ts=1000 span
+            (800e3, 1700e3, "workflow.step")]      # open at the stop
+    rows = ps.sink_agreement(ring, host, {"workflow.step"}, 10.0, window)
+    assert rows == [("workflow.step", 1, pytest.approx(200e-6), 1,
+                     pytest.approx(200e-6))]

@@ -114,18 +114,6 @@ if ! timeout -k 5 240 env JAX_PLATFORMS=cpu python tools/qcomm_smoke.py; then
          "qcomm_smoke lines above)" >&2
     [ $rc -eq 0 ] && rc=1
 fi
-# ISSUE 20 smoke: step anatomy — on a forced 4-device CPU mesh a
-# dp(4)+shard_params+int8 anatomy run must pre-touch every
-# znicz_anatomy_* child at init, attribute per-phase seconds summing to
-# within 10% of the measured step wall, read a nonzero mfu gauge (peak
-# pinned via $ZNICZ_TPU_PEAK_FLOPS), and trip the per-rank straggler
-# rule for exactly one artificially delayed rank
-# (docs/OBSERVABILITY.md "Step anatomy & goodput")
-if ! timeout -k 5 240 env JAX_PLATFORMS=cpu python tools/anatomy_smoke.py; then
-    echo "tools/t1.sh: step-anatomy smoke FAILED (see anatomy_smoke" \
-         "lines above)" >&2
-    [ $rc -eq 0 ] && rc=1
-fi
 # ISSUE 9 smoke: elastic kill-and-resume — 2 CPU worker processes, the
 # snapshot writer SIGKILL'd at a seeded step, fleet resumes at world
 # size 1; asserts completion + >= 1 flight artifact + resumes counter

@@ -136,7 +136,7 @@ class Workflow(Unit):
             run_t0 = time.perf_counter()
             signals_before = self.signals_dispatched
             span_args: dict[str, dict] = {}   # unit -> reusable trace
-            perf = time.perf_counter          # args (no per-signal dict)
+            timed = TRACER.timed              # args (no per-signal dict)
         self.end_point.reached = False
         # clear fired-marks left by an early-terminated previous walk so join
         # units cannot fire on stale signals
@@ -149,8 +149,16 @@ class Workflow(Unit):
             while queue:
                 source, target = queue.popleft()
                 if observed:
-                    t0 = perf()
-                    try:
+                    tname = target.name
+                    a = span_args.get(tname)
+                    if a is None:
+                        a = span_args[tname] = {"unit": tname}
+                    # one live span per delivery: ring + profiler host
+                    # plane; a CRASHING delivery still lands on the
+                    # timeline, error-marked by the span's exit -- a
+                    # flight artifact's post-mortem window needs the
+                    # step that died, not just the ones before it
+                    with timed("workflow.step", a) as step_span:
                         # chaos hook: the resilience plane injects
                         # crashes/hangs here (site "workflow.step") so
                         # fault tests drive this real loop; with no plan
@@ -164,22 +172,8 @@ class Workflow(Unit):
                         fault_hook("elastic.worker")
                         self.signals_dispatched += 1
                         target._signal(source, queue)
-                    except BaseException:
-                        # the CRASHING delivery still lands on the
-                        # timeline, error-marked — a flight artifact's
-                        # post-mortem window needs the step that died,
-                        # not just the ones before it
-                        TRACER.complete("workflow.step", t0, perf() - t0,
-                                        {"unit": target.name,
-                                         "error": True})
-                        raise
-                    dt = perf() - t0
-                    probe.signal_dispatched(dt)
-                    tname = target.name
-                    a = span_args.get(tname)
-                    if a is None:
-                        a = span_args[tname] = {"unit": tname}
-                    TRACER.complete("workflow.step", t0, dt, a)
+                    # the histogram reads the span's own two clock reads
+                    probe.signal_dispatched(step_span.dt)
                     # recompile poll rides a stride: polling every
                     # watched program per signal has no business on the
                     # per-signal budget (<2%, metrics_overhead bench); a

@@ -107,7 +107,7 @@ def build_fused(max_epochs=4, layers=(64,), lr=0.05, moment=0.9,
                 optimizer_config=None, shard_update=False,
                 shard_params=False, accumulate_steps=1, ema_decay=None,
                 quantized_collectives=None,
-                pipeline_depth=None, anatomy=None) -> NNWorkflow:
+                pipeline_depth=None) -> NNWorkflow:
     """TPU-native shape: Repeater -> Loader -> FusedTrainStep -> Decision."""
     w = NNWorkflow(name="MnistFC-fused")
     w.repeater = Repeater(w)
@@ -121,7 +121,7 @@ def build_fused(max_epochs=4, layers=(64,), lr=0.05, moment=0.9,
         shard_params=shard_params,
         accumulate_steps=accumulate_steps, ema_decay=ema_decay,
         quantized_collectives=quantized_collectives,
-        anatomy=anatomy, name="FusedStep")
+        name="FusedStep")
     dec = w.decision = DecisionGD(w, max_epochs=max_epochs)
 
     w.repeater.link_from(w.start_point)

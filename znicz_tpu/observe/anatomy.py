@@ -122,6 +122,29 @@ def observe_phase(plane: str, phase: str, dt_s: float,
     _trace.TRACER.complete(f"anatomy.{plane}.{phase}", start, dt_s)
 
 
+class StepCadence:
+    """The train planes' producer of ``znicz_anatomy_step_seconds
+    {plane}``: the wall between consecutive dispatches of one step unit,
+    on by default.  What the chip does inside a step is read from the
+    profiler (named scopes, docs/OBSERVABILITY.md); this is the host-side
+    cadence the fleet watchtower's straggler rule compares across ranks.
+    :meth:`tick` takes the dispatch span's own start stamp."""
+
+    __slots__ = ("_step_child", "_steps", "_last")
+
+    def __init__(self, plane: str) -> None:
+        pretouch(plane, ())
+        self._step_child = _STEP_SECONDS.labels(plane=plane)
+        self._steps = _STEPS.labels(plane=plane)
+        self._last: Optional[float] = None
+
+    def tick(self, now: float) -> None:
+        last, self._last = self._last, now
+        if last is not None and _probe_enabled():
+            self._step_child.observe(now - last)
+            self._steps.inc()
+
+
 class StepAnatomy:
     """Cursor-based accountant for one producer plane.
 

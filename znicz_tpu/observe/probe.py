@@ -39,6 +39,8 @@ path and how determinism tests pin "instrumentation off == seed path".
 
 from __future__ import annotations
 
+import functools
+import re
 import time
 import weakref
 from typing import Optional
@@ -240,28 +242,42 @@ def compile_observed(label: str, dt_s: float, **args) -> None:
                            dt_s, fn=label, **args)
 
 
+#: every live :class:`_CompileTimed`, for :func:`scope_map`
+_timed_programs: "weakref.WeakSet" = weakref.WeakSet()
+
+
 class _CompileTimed:
     """Thin wrapper over a jitted callable: the FIRST invocation — the
     trace+compile+run cold path — is timed into ``znicz_compile_seconds
     {fn=label}``; every later call is one attribute check of passthrough.
     ``_cache_size`` delegates so :func:`watch_compiles` keeps polling the
-    real compile cache through the wrapper."""
+    real compile cache through the wrapper.  The first call's argument
+    shapes, dtypes and shardings are remembered (no buffer is kept), so
+    :func:`scope_map` can lower the same program again when asked."""
 
-    __slots__ = ("_fn", "_label", "_cold", "__weakref__")
+    __slots__ = ("_fn", "_label", "_cold", "_abstract", "__weakref__")
 
     def __init__(self, fn, label: str) -> None:
         self._fn = fn
         self._label = label
         self._cold = True
+        self._abstract = None
+        _timed_programs.add(self)
 
     def _cache_size(self) -> int:
         size = getattr(self._fn, "_cache_size", None)
         return int(size()) if size is not None else 0
 
+    def lower(self, *args, **kw):
+        """The wrapped program's ``jit(...).lower``."""
+        return self._fn.lower(*args, **kw)
+
     def __call__(self, *args, **kw):
         if not self._cold:
             return self._fn(*args, **kw)
         self._cold = False
+        if hasattr(self._fn, "lower"):
+            self._abstract = _abstract_call(args, kw)
         t0 = time.perf_counter()
         out = self._fn(*args, **kw)
         compile_observed(self._label, time.perf_counter() - t0)
@@ -275,6 +291,183 @@ def time_compiles(label: str, fn):
     if fn is None:
         return None
     return _CompileTimed(fn, label)
+
+
+# -- named scopes and the scope map (ISSUE 24) -------------------------------
+
+#: scope names the program has opened through :func:`scope`
+_scope_names: set = set()
+_INSTRUCTION = re.compile(
+    r"^\s*(ROOT )?%?([\w.\-]+) = .*?[})\]] ([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"(calls|to_apply|body|condition)=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$")
+#: a compiler option at its default: changes nothing but jit's memo
+_FRESH_COMPILE = {"xla_embed_ir_in_executable": False}
+#: instructions that are no work of their own
+TRIVIAL_OPCODES = frozenset((
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+    "after-all", "partition-id", "replica-id", "iota"))
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``, with the name remembered so that
+    :func:`scope_map` can find it in an operation's ``op_name``.
+    Metadata only: what the program computes does not change."""
+    import jax
+
+    _scope_names.add(name)
+    return jax.named_scope(name)
+
+
+def scoped(name: str):
+    """Decorator: the body of the function runs under :func:`scope`."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kw):
+            with scope(name):
+                return fn(*args, **kw)
+        return wrapper
+    return deco
+
+
+def _abstract_call(args, kw):
+    """Shapes, dtypes and shardings of one call's arguments."""
+    import jax
+
+    def leaf(a):
+        if hasattr(a, "shape") and hasattr(a, "dtype"):
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None))
+        return a
+    return jax.tree.map(leaf, (args, kw))
+
+
+def scope_of(op_name: str, names=None) -> str:
+    """The program's scope in one ``op_name`` path: its outermost
+    component that is a name opened through :func:`scope`, kept as it
+    stands there -- ``conv.00_Conv`` in an eval pass, ``jvp(conv.00_
+    Conv)`` in a differentiated forward, ``transpose(jvp(conv.00_
+    Conv))`` in the backward pass -- or ``""`` under none."""
+    names = _scope_names if names is None else names
+    for part in op_name.split("/"):
+        if part.rstrip(")").rsplit("(", 1)[-1] in names:
+            return part
+    return ""
+
+
+def parse_scopes(hlo_text: str, names=None) -> tuple:
+    """``(module name, {instruction name: scope})`` from one optimised
+    HLO module's text.  An instruction takes, in this order: the scope
+    in its own ``op_name``; that of the root of the computation it calls
+    (a fusion), else of any instruction in it; and, where the compiler
+    gave it no metadata (a layout ``copy``, an async slice or copy, a
+    scalar it moved), the scope of the first instruction that consumes
+    it, else of an operand -- whose layout the copy is -- else of the
+    instruction that calls its computation.  Trivial instructions
+    (:data:`TRIVIAL_OPCODES`) pass scopes on but are left out; ``""`` is
+    what remains under no scope."""
+    module, comp = "", None
+    scope: dict = {}          # instruction -> scope
+    operands: dict = {}       # instruction -> [operand names]
+    opcode_of: dict = {}
+    comp_of: dict = {}        # instruction -> its computation
+    fused: dict = {}          # instruction -> the computation it calls=
+    caller: dict = {}         # computation -> an instruction that calls it
+    roots: dict = {}          # computation -> scope of its root
+    inside: dict = {}         # computation -> first scope found in it
+    for line in hlo_text.splitlines():
+        if line.startswith("HloModule "):
+            module = line.split()[1].rstrip(",")
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        is_root, name, opcode = m.groups()
+        op = _OP_NAME.search(line)
+        sc = scope_of(op.group(1), names) if op else ""
+        if is_root:
+            roots[comp] = sc
+        if sc:
+            inside.setdefault(comp, sc)
+        for key, callee in _CALLED.findall(line):
+            caller.setdefault(callee, name)
+            if key == "calls":
+                fused[name] = callee
+        scope[name], opcode_of[name], comp_of[name] = sc, opcode, comp
+        operands[name] = _OPERAND.findall(line[m.end():])
+    for name, called in fused.items():
+        if not scope[name]:
+            scope[name] = roots.get(called) or inside.get(called, "")
+    users: dict = {}
+    for name, ops in operands.items():
+        for o in ops:
+            if o in scope:
+                users.setdefault(o, []).append(name)
+
+    def neighbour(name):
+        # consumers first (whose layout a bare copy is), then operands,
+        # then the instruction that calls this one's computation
+        for n in (*users.get(name, ()), *operands.get(name, ()),
+                  caller.get(comp_of[name])):
+            if scope.get(n):
+                return scope[n]
+        return ""
+
+    # hand scopes down chains of bare instructions (copy-start ->
+    # copy-done -> bitcast -> the fusion that reads it)
+    for _ in range(8):
+        moved = False
+        for name, sc in scope.items():
+            if not sc:
+                got = neighbour(name)
+                if got:
+                    scope[name], moved = got, True
+        if not moved:
+            break
+    return module, {n: sc for n, sc in scope.items()
+                    if opcode_of[n] not in TRIVIAL_OPCODES}
+
+
+def scope_map() -> dict:
+    """``{module name: {instruction name: scope}}`` for every program
+    wrapped by :func:`time_compiles` that has run: each is lowered again
+    from the remembered shapes of its first call, compiled, and its
+    optimised HLO parsed by :func:`parse_scopes`.
+
+    The compile has to be a fresh one.  The executable that runs may
+    have come out of the persistent cache, whose key leaves metadata
+    out: it is then the same program as whichever commit compiled it
+    first annotated it, with that commit's scopes or none (found on the
+    chip, PERF.md PR 24), and jit hands the same executable back for the
+    same lowering.  So the cache is suspended and a compiler option at
+    its default is passed, which makes jit compile again.  Instruction
+    names do not depend on metadata, so the fresh compile names them as
+    the running executable does.  Built only when called: this is the
+    profile reader's join from a device operation to the program's
+    scope, and costs nothing (one compile per program, after the run)
+    until a reader asks."""
+    from znicz_tpu import compilecache
+
+    out: dict = {}
+    with compilecache.suspended():
+        for prog in list(_timed_programs):
+            if prog._abstract is None:
+                continue
+            args, kw = prog._abstract
+            text = prog.lower(*args, **kw).compile(
+                compiler_options=_FRESH_COMPILE).as_text()
+            module, scopes = parse_scopes(text)
+            known = out.setdefault(module, {})
+            for name, sc in scopes.items():
+                if sc or name not in known:     # two live steps, one name
+                    known[name] = sc
+    return out
 
 
 # -- persistent compilation cache (ISSUE 7) ----------------------------------

@@ -26,12 +26,27 @@ Export is the Chrome trace-event JSON array format: ``X`` (complete)
 events for spans, ``i`` (instant) events for point events, ``M``
 metadata rows naming the process and threads.  Timestamps are
 microseconds on a per-tracer monotonic origin.
+
+**Second sink.**  A live span (:meth:`Tracer.span` / :meth:`Tracer.timed`)
+also holds a ``jax.profiler.TraceAnnotation`` of the same name for its
+lifetime, so while a profiler session runs (``jax.profiler.start_trace``,
+``Launcher.profile_dir``) the span lies in the profiler's ``/host:CPU``
+plane, on the thread that opened it and on the device trace's clock:
+nanoseconds since the session's ``profile_start_time``, which is unix
+time, as is ``origin_unix_ts`` below -- the two sinks meet on the wall
+clock.  With no session the annotation is the runtime's own no-op.
+Spans recorded after the fact (:meth:`Tracer.complete`: another thread's
+timing, the per-request synthetic tracks) cannot be annotated and stay
+ring-only.  ``jax`` is never imported from here: the annotation class is
+taken from ``sys.modules`` if jax is already loaded, so a numpy-only run
+stays jax-free.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -42,27 +57,70 @@ from typing import Optional
 DEFAULT_CAPACITY = 65536
 
 
+_logger = None              # znicz_tpu.core.logger, at first instant()
+_annotation_cls = None      # jax.profiler.TraceAnnotation once jax is loaded
+
+
+def _annotation(name: str, args: Optional[dict]):
+    """A ``TraceAnnotation`` for one live span, or None while jax is not
+    loaded (looked up in ``sys.modules``, never imported: see the module
+    docstring)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        cls = getattr(profiler, "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    return cls(name, **args) if args else cls(name)
+
+
 class _Span:
-    """Reusable-shape active span: records an ``X`` event on exit."""
+    """Active span: one ``X`` ring event on exit and, while it is open, a
+    profiler annotation of the same name.  ``t0`` and ``dt`` are its two
+    ``perf_counter`` reads (start, and seconds to exit), for call sites
+    that feed a histogram from the same reads; a span that ends in an
+    exception lands error-marked."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_tid", "_ann", "t0", "dt")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict],
+                 tid: Optional[int] = None):
         self._tracer = tracer
         self._name = name
         self._args = args
-        self._t0 = 0.0
+        self._tid = tid
+        self._ann = None
+        self.t0 = self.dt = 0.0
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        if self._tracer.enabled:
+            ann = self._ann = _annotation(self._name, self._args)
+            if ann is not None:
+                ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc) -> None:
-        t1 = time.perf_counter()
+    def __exit__(self, exc_type=None, *exc) -> None:
+        self.dt = time.perf_counter() - self.t0
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(exc_type, *exc)
         tracer = self._tracer
+        if not tracer.enabled:
+            return
+        args = self._args
+        if exc_type is not None:
+            args = {**(args or {}), "error": True}
+        tracer.live_names.add(self._name)
         tracer._events.append(
-            ("X", self._name, (self._t0 - tracer._origin) * 1e6,
-             (t1 - self._t0) * 1e6, threading.get_ident(), self._args))
+            ("X", self._name, (self.t0 - tracer._origin) * 1e6,
+             self.dt * 1e6,
+             self._tid if self._tid is not None else threading.get_ident(),
+             args))
 
 
 class _NoopSpan:
@@ -94,6 +152,9 @@ class Tracer:
         # prefetch worker, HTTP threads and the control walk interleave
         # without a lock on the hot path
         self._events: deque = deque(maxlen=self.capacity)
+        #: names recorded by live spans, the ones that also reach the
+        #: profiler's host plane (``complete()`` names stay ring-only)
+        self.live_names: set = set()
 
     # -- recording -----------------------------------------------------------
     def span(self, name: str, **args):
@@ -101,7 +162,18 @@ class Tracer:
         ``with tracer.span("workflow.step", step=n): ...``"""
         if not self.enabled:
             return _NOOP
-        return _Span(self, name, args or None)
+        return self.timed(name, args or None)
+
+    def timed(self, name: str, args: Optional[dict] = None,
+              tid: Optional[int] = None) -> _Span:
+        """A live span that times even while tracing is disabled (it then
+        records and annotates nothing): the hot call sites read ``.t0``
+        / ``.dt`` after the block and feed their histograms from the
+        span's own two clock reads.  ``args`` is a PRE-BUILT (reusable)
+        dict; ``tid`` puts the RING event on a synthetic track as
+        :meth:`complete` does (the annotation stays on the real
+        thread)."""
+        return _Span(self, name, args, tid)
 
     def complete(self, name: str, start: float, duration: float,
                  args: Optional[dict] = None, tid: Optional[int] = None,
@@ -136,8 +208,9 @@ class Tracer:
         # rare point events also land as log records, so a JSONL log
         # sink (core/logger.py configure(jsonl_path=...)) interleaves
         # them with ordinary log lines
-        from znicz_tpu.core import logger as _logger
-
+        global _logger
+        if _logger is None:
+            from znicz_tpu.core import logger as _logger
         _logger.event_log(name, args)
 
     def enable(self) -> None:

@@ -107,5 +107,5 @@ def conv2d_im2col(x, weights, bias, sliding=(1, 1), padding=(0, 0, 0, 0),
         out_specs=pl.BlockSpec((1, oh, ow, cout), lambda i: (i, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, oh, ow, cout), x.dtype),
-        interpret=interpret,
+        name="conv_fwd", interpret=interpret,
     )(xph, weights, bias)

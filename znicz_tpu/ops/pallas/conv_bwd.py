@@ -106,7 +106,7 @@ def _adjoint_call(dp, wf, hp, wp, ky, kx, out_dtype, interpret):
         out_specs=pl.BlockSpec((1, hp, wp, na), lambda i: (i, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, hp, wp, na), out_dtype),
-        interpret=interpret,
+        name="conv_bwd_input", interpret=interpret,
     )(dp, wf)
 
 
@@ -135,7 +135,7 @@ def _grad_call(xpad, e, ky, kx, sy, sx, oh, ow, interpret):
             jax.ShapeDtypeStruct((ky, kx, cin, cout), jnp.float32),
             jax.ShapeDtypeStruct((1, cout), jnp.float32),
         ],
-        interpret=interpret,
+        name="conv_bwd_weights", interpret=interpret,
     )(xph, e)
 
 

@@ -56,12 +56,12 @@ def dropout_forward(x, seed, ratio: float, *, bits=None,
         y, mask = pl.pallas_call(
             _kernel_prng, in_specs=[smem, smem, smem, vmem],
             out_specs=(vmem, vmem), out_shape=out_shape,
-            interpret=interpret,
+            name="dropout", interpret=interpret,
         )(jnp.asarray([seed], jnp.int32), thresh, scale, x2)
     else:
         y, mask = pl.pallas_call(
             _kernel_bits, in_specs=[smem, smem, vmem, vmem],
             out_specs=(vmem, vmem), out_shape=out_shape,
-            interpret=interpret,
+            name="dropout", interpret=interpret,
         )(thresh, scale, bits.reshape(x2.shape), x2)
     return y.reshape(orig_shape), mask.reshape(orig_shape)

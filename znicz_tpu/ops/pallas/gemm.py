@@ -88,7 +88,7 @@ def matmul(x, w, bias=None, activation: str = activations.LINEAR, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        name="fc_fwd", interpret=interpret,
     )(xp_, wp, bp)
     return out[:M, :N]
 
@@ -121,7 +121,7 @@ def _act_backward(y, err, activation: str, *, interpret: bool):
         grid=(Mp // bm,),
         in_specs=[spec, spec], out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), err.dtype),
-        interpret=interpret,
+        name="activation_bwd", interpret=interpret,
     )(yp, ep)
     return out[:M, :N]
 

@@ -75,6 +75,6 @@ def som_step(x, weights, coords, alpha, sigma, batch_size, *,
         out_specs=(vmem, vmem),
         out_shape=(jax.ShapeDtypeStruct(weights.shape, weights.dtype),
                    jax.ShapeDtypeStruct((B, 1), jnp.int32)),
-        interpret=interpret,
+        name="kohonen_train", interpret=interpret,
     )(scal, x, weights, coords)
     return new_w, idx[:, 0]

@@ -67,7 +67,7 @@ def lrn_forward(x, alpha: float, beta: float, k: float, n: int, *,
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
-        interpret=interpret,
+        name="lrn_fwd", interpret=interpret,
     )(x2)
     return y.reshape(x.shape)
 
@@ -82,6 +82,6 @@ def lrn_backward(x, err_output, alpha: float, beta: float, k: float, n: int,
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
-        interpret=interpret,
+        name="lrn_bwd", interpret=interpret,
     )(x2, e2)
     return out.reshape(x.shape)

@@ -84,10 +84,12 @@ def stochastic_pool(patch, valid, seed, use_abs: bool = False, *,
         return pl.pallas_call(
             partial(_kernel_prng, use_abs=use_abs),
             in_specs=[smem, vmem, vmem], out_specs=(vmem, vmem),
-            out_shape=out_shape, interpret=interpret,
+            out_shape=out_shape, name="stochastic_pool",
+            interpret=interpret,
         )(jnp.asarray([seed], jnp.int32), patch, valid3)
     return pl.pallas_call(
         partial(_kernel_bits, use_abs=use_abs),
         in_specs=[vmem, vmem, vmem], out_specs=(vmem, vmem),
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, name="stochastic_pool",
+        interpret=interpret,
     )(patch, valid3, bits)

@@ -43,6 +43,7 @@ Loader/Decision/Snapshotter stay host-side exactly like the reference.
 
 from __future__ import annotations
 
+import re
 from typing import Optional
 
 import numpy as np
@@ -61,10 +62,35 @@ from znicz_tpu.core.config import root
 from znicz_tpu.core.units import Unit
 from znicz_tpu.loader.base import TRAIN
 from znicz_tpu.observe import probe as _probe
+from znicz_tpu.observe.anatomy import StepCadence
+from znicz_tpu.observe.trace import TRACER as _TRACER
 from znicz_tpu.ops import sgd
 from znicz_tpu.resilience.faults import poison_hook
-from znicz_tpu.units.all2all import All2AllSoftmax
+from znicz_tpu.units.all2all import All2All, All2AllSoftmax
+from znicz_tpu.units.conv import Conv
+from znicz_tpu.units.deconv import Deconv
+from znicz_tpu.units.dropout import DropoutForward
 from znicz_tpu.units.evaluator import EvaluatorMSE, EvaluatorSoftmax
+from znicz_tpu.units.normalization import LRNormalizerForward
+from znicz_tpu.units.pooling import Pooling
+
+
+#: forward-unit class -> scope group; anything else (activations,
+#: cutters) is "act"
+_SCOPE_GROUPS = ((Conv, "conv"), (Deconv, "conv"), (All2All, "fc"),
+                 (LRNormalizerForward, "norm"), (Pooling, "pool"),
+                 (DropoutForward, "dropout"))
+
+
+def unit_scope(index: int, unit) -> str:
+    """``<group>.<index>_<unit name>``: the named scope of one forward
+    unit in the compiled step.  The index makes it unique per layer
+    (unit names default to the class name); characters that would break
+    an operation's ``op_name`` path are replaced."""
+    group = next((g for cls, g in _SCOPE_GROUPS if isinstance(unit, cls)),
+                 "act")
+    return f"{group}.{index:02d}_" + re.sub(r"[^A-Za-z0-9_.\-]", "_",
+                                            str(unit.name))
 
 
 def full_batch_arrays(loader, mse: bool):
@@ -112,19 +138,8 @@ class FusedTrainStep(Unit):
                  accumulate_steps: int = 1,
                  ema_decay: Optional[float] = None,
                  quantized_collectives: Optional[dict] = None,
-                 anatomy: Optional[bool] = None,
                  **kwargs) -> None:
         super().__init__(workflow, **kwargs)
-        #: step-anatomy split-dispatch mode (ISSUE 20): the train step
-        #: runs as SEPARATE compiled programs per phase (zero_gather /
-        #: grad / collective / update) with host stamps between them,
-        #: feeding znicz_anatomy_* (observe/anatomy.py).  Numerics match
-        #: the fused path (same loss_fn, same explicit grad psum, same
-        #: apply); the cost is per-phase dispatch latency + a
-        #: materialized full-weight output under shard_params — a
-        #: diagnostic mode, never the perf path.  ``None`` defers to
-        #: ``root.common.engine.step_anatomy`` (False).
-        self.anatomy = anatomy
         #: quantized-collective codec config (ISSUE 18, EQuARX-style):
         #: ``{"mode": "off|bf16|int8", "chunk": N, "error_feedback":
         #: bool}`` — the gradient psum and (under shard_params) the
@@ -249,11 +264,10 @@ class FusedTrainStep(Unit):
         self._qcomm_gather_bytes = None  # (wire, exact) per dispatch
         self._qcomm_grad_counters = None
         self._qcomm_gather_counters = None
-        self._anatomy = None      # StepAnatomy accountant (anatomy mode)
-        self._anat_gather_fn = None   # split programs (anatomy mode)
-        self._anat_grad_fn = None
-        self._anat_collective_fn = None
-        self._anat_update_fn = None
+        #: wall between consecutive dispatches ->
+        #: znicz_anatomy_step_seconds{plane="fused"} (the fleet
+        #: watchtower's straggler rule reads it), always on
+        self._cadence = StepCadence("fused")
         self._acc = None          # device-side metric sums (deferred mode)
         self._conf_seen = None    # confusion sums already folded this pass
         self._nt_valid = None     # nearest-target recovery proven valid?
@@ -672,15 +686,19 @@ class FusedTrainStep(Unit):
         logits_tail = isinstance(self.forwards[last], All2AllSoftmax) and \
             isinstance(self.evaluator, EvaluatorSoftmax)
         for i, (fwd, p) in enumerate(zip(self.forwards, params)):
-            pc = {k: (v.astype(cdt) if k in ("w", "b") else v)
-                  for k, v in p.items()}
-            unit_rng = None
-            if getattr(fwd, "NEEDS_RNG", False) and rng is not None:
-                unit_rng = jax.random.fold_in(rng, i)
-            if i == last and logits_tail:
-                x = fwd.xla_apply_linear(pc, x)
-            else:
-                x = fwd.xla_apply(pc, x, rng=unit_rng, train=train)
+            # one named scope per forward unit (metadata only): the
+            # profiler's operations carry it, the backward pass as
+            # transpose(jvp(<scope>)) -- docs/OBSERVABILITY.md
+            with _probe.scope(unit_scope(i, fwd)):
+                pc = {k: (v.astype(cdt) if k in ("w", "b") else v)
+                      for k, v in p.items()}
+                unit_rng = None
+                if getattr(fwd, "NEEDS_RNG", False) and rng is not None:
+                    unit_rng = jax.random.fold_in(rng, i)
+                if i == last and logits_tail:
+                    x = fwd.xla_apply_linear(pc, x)
+                else:
+                    x = fwd.xla_apply(pc, x, rng=unit_rng, train=train)
         return x, logits_tail
 
     def _nt_recovery_valid(self) -> bool:
@@ -707,6 +725,7 @@ class FusedTrainStep(Unit):
                                    protos[lab]))
         return self._nt_valid
 
+    @_probe.scoped("loss")
     def _loss_and_metrics(self, out, logits_tail, labels, mask):
         """Masked loss-sum + metric sums over the local shard (f32
         regardless of the forward's compute dtype)."""
@@ -783,6 +802,7 @@ class FusedTrainStep(Unit):
                                         metrics["bs"])
         return new_params, key, metrics
 
+    @_probe.scoped("update")
     def _apply_update(self, params, grads, hyper, bs):
         """Apply one optimizer step for summed gradients ``grads`` over
         ``bs`` total samples — shared by the per-minibatch step and the
@@ -931,9 +951,10 @@ class FusedTrainStep(Unit):
                     likes.append(jax.ShapeDtypeStruct(
                         self._param_shape(i, k), leaf[k].dtype))
                     sites.append((i, k))
-        full = zero.gather_chain(shards, likes, rank, n, "data",
-                                 via_psum=self._gather_via_psum,
-                                 codec=self._codec)
+        with _probe.scope("zero_gather"):
+            full = zero.gather_chain(shards, likes, rank, n, "data",
+                                     via_psum=self._gather_via_psum,
+                                     codec=self._codec)
         out = [dict(leaf) for leaf in leaves]
         for (i, k), v in zip(sites, full):
             out[i][k] = v
@@ -980,16 +1001,24 @@ class FusedTrainStep(Unit):
             # local residual view: the (1, *shape) slab's single row
             residuals = [{k: params[i]["r" + k][0] for k in g}
                          for i, g in enumerate(grads)]
-        grads, res_out = quantized_psum(grads, "data", self._codec,
-                                        residuals)
+        with _probe.scope("grad_reduce"):
+            grads, res_out = quantized_psum(grads, "data", self._codec,
+                                            residuals)
         new_res = None if res_out is None else \
             [{"r" + k: v[None] for k, v in leaf.items()}
              for leaf in res_out]
         metrics["bs"] = jax.lax.psum(mask.sum(), "data")
         return key, grads, metrics, new_res
 
+    @staticmethod
+    @_probe.scoped("gather_batch")
+    def _gather_batch(data, labels, idx):
+        """The index gather from the dataset pinned on the device."""
+        return data[idx], labels[idx]
+
     def _local_grads_idx(self, params, key, data, labels, idx, mask):
-        return self._local_grads(params, key, data[idx], labels[idx], mask)
+        return self._local_grads(
+            params, key, *self._gather_batch(data, labels, idx), mask)
 
     def _local_apply(self, params, hyper, grads, bs):
         return self._apply_update(params, grads, hyper, bs)
@@ -1006,140 +1035,13 @@ class FusedTrainStep(Unit):
     # host ships ~4 bytes/sample of indices per step instead of the
     # minibatch itself (reference: FullBatchLoader's ``on_device`` option)
     def _local_train_idx(self, params, key, hyper, data, labels, idx, mask):
-        return self._local_train(params, key, hyper, data[idx],
-                                 labels[idx], mask)
+        return self._local_train(
+            params, key, hyper, *self._gather_batch(data, labels, idx),
+            mask)
 
     def _local_eval_idx(self, params, data, labels, idx, mask):
-        return self._local_eval(params, data[idx], labels[idx], mask)
-
-    # -- step anatomy (ISSUE 20): split-dispatch phase programs --------------
-    def _trainable_specs(self, spec):
-        """Specs pytree matching the trainable (w/b-only) subtree."""
-        return [{k: spec for k in ("w", "b") if k in leaf}
-                for leaf in self._params]
-
-    def _build_anatomy(self) -> None:
-        """Compile the per-phase programs the anatomy mode dispatches
-        sequentially: the SAME bodies as ``_local_train`` — gather, then
-        ``loss_fn``+grad, then the explicit (possibly quantized) psum,
-        then ``_apply_update`` — cut at the phase seams.  The grad
-        program returns per-rank UNREDUCED grads as a stacked
-        ``(n, *shape)`` array via the ``g[None]`` / out_specs
-        ``P("data")`` trick (each rank's slice stays on its device: no
-        data movement at the cut), and the collective program takes the
-        stack back per-rank and runs the identical ``quantized_psum``
-        seam — grads, error-feedback residuals and the update follow
-        exactly the fused program's math (parity to float tolerance:
-        XLA may fuse/reassociate differently across the program cuts,
-        which test_anatomy pins)."""
-        from znicz_tpu.observe.anatomy import StepAnatomy, TRAIN_PHASES
-
-        rep, sh = P(), P("data")
-        pspecs = self.param_specs()
-        t_rep = self._trainable_specs(rep)
-        t_stacked = self._trainable_specs(sh)
-
-        def local_gather(params):
-            trainable = [{k: v for k, v in leaf.items()
-                          if k in ("w", "b")} for leaf in params]
-            return self._gather_full(trainable)
-
-        def local_grad(trainable, key, x, labels, mask):
-            key, sub = jax.random.split(key)
-            rng = jax.random.fold_in(sub, jax.lax.axis_index("data"))
-
-            def loss_fn(ps):
-                out, logits_tail = self._forward_chain(ps, x, train=True,
-                                                       rng=rng)
-                loss, metrics = self._loss_and_metrics(
-                    out, logits_tail, labels, mask)
-                metrics = jax.lax.psum(metrics, "data")
-                return loss, metrics
-
-            (_, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(trainable)
-            stacked = [{k: v[None] for k, v in leaf.items()}
-                       for leaf in grads]
-            metrics["bs"] = jax.lax.psum(mask.sum(), "data")
-            return key, stacked, metrics
-
-        def local_collective(params, stacked):
-            grads = [{k: v[0] for k, v in leaf.items()}
-                     for leaf in stacked]
-            residuals = None
-            if self._ef:
-                residuals = [{k: params[i]["r" + k][0] for k in g}
-                             for i, g in enumerate(grads)]
-            grads, res_out = quantized_psum(grads, "data", self._codec,
-                                            residuals)
-            new_res = None if res_out is None else \
-                [{"r" + k: v[None] for k, v in leaf.items()}
-                 for leaf in res_out]
-            return grads, new_res
-
-        if self.shard_params:
-            gatherf = shard_map(local_gather, mesh=self.mesh,
-                                in_specs=(pspecs,), out_specs=t_rep)
-            self._anat_gather_fn = jax.jit(gatherf)
-        gradf = shard_map(local_grad, mesh=self.mesh,
-                          in_specs=(t_rep, rep, sh, sh, sh),
-                          out_specs=(rep, t_stacked, rep))
-        self._anat_grad_fn = jax.jit(gradf)
-        collf = shard_map(local_collective, mesh=self.mesh,
-                          in_specs=(pspecs, t_stacked),
-                          out_specs=(t_rep, self._res_specs()))
-        self._anat_collective_fn = jax.jit(collf)
-        if self._ef:
-            def local_update(params, hyper, grads, bs, new_res):
-                params = [{**leaf, **nr}
-                          for leaf, nr in zip(params, new_res)]
-                return self._apply_update(params, grads, hyper, bs)
-            updf = shard_map(local_update, mesh=self.mesh,
-                             in_specs=(pspecs, rep, t_rep, rep,
-                                       self._res_specs()),
-                             out_specs=pspecs)
-        else:
-            updf = shard_map(self._local_apply, mesh=self.mesh,
-                             in_specs=(pspecs, rep, t_rep, rep),
-                             out_specs=pspecs)
-        self._anat_update_fn = jax.jit(updf)
-        self._anatomy = StepAnatomy("fused", TRAIN_PHASES)
-        if self.loader is not None:
-            from znicz_tpu.utils import flops as _flops
-            self._anatomy.set_flops(_flops.train_step_flops(
-                self.forwards, int(self.loader.max_minibatch_size)))
-
-    def _run_anatomy_step(self, x, labels, mask):
-        """Anatomy-mode train dispatch: one program per phase, host
-        stamps at the ``block_until_ready`` boundaries.  Returns the
-        metrics pytree the fused program would have returned."""
-        anat = self._anatomy
-        anat.begin()
-        if self.shard_params:
-            trainable = jax.block_until_ready(
-                self._anat_gather_fn(self._params))
-            anat.stamp("zero_gather")
-        else:
-            trainable = [{k: leaf[k] for k in ("w", "b") if k in leaf}
-                         for leaf in self._params]
-        key, stacked, metrics = jax.block_until_ready(
-            self._anat_grad_fn(trainable, self._key, x, labels, mask))
-        anat.stamp("grad")
-        grads, new_res = jax.block_until_ready(
-            self._anat_collective_fn(self._params, stacked))
-        anat.stamp("collective")
-        hyper = self._hyper_device()
-        if self._ef:
-            params = self._anat_update_fn(self._params, hyper, grads,
-                                          metrics["bs"], new_res)
-        else:
-            params = self._anat_update_fn(self._params, hyper, grads,
-                                          metrics["bs"])
-        jax.block_until_ready(params)
-        anat.stamp("update")
-        self._params, self._key = params, key
-        anat.finish()
-        return metrics
+        return self._local_eval(
+            params, *self._gather_batch(data, labels, idx), mask)
 
     # -- lifecycle ----------------------------------------------------------
     def initialize(self, device=None, **kwargs) -> None:
@@ -1222,19 +1124,6 @@ class FusedTrainStep(Unit):
             self._grad_fn = jax.jit(gradf)
             self._apply_fn = jax.jit(
                 applyf, donate_argnums=(0,) if self.donate else ())
-        self.anatomy = bool(
-            self.anatomy if self.anatomy is not None
-            else root.common.engine.get("step_anatomy", False))
-        if self.anatomy:
-            # split-dispatch diagnostics are a per-minibatch mode: the
-            # accumulate/scan paths batch many steps into one dispatch,
-            # which a host-stamped split cannot attribute — refuse
-            # instead of silently accounting garbage
-            if self.accumulate_steps > 1:
-                raise ValueError("anatomy (split-dispatch step "
-                                 "accounting) requires "
-                                 "accumulate_steps == 1")
-            self._build_anatomy()
         self._pin_dataset()
         if self._scan_idx_fns:
             # VERDICT r5 item 6: in epoch-scan mode hyperparams are read
@@ -1270,8 +1159,7 @@ class FusedTrainStep(Unit):
         label = type(self).__name__
         for attr in ("_train_fn", "_eval_fn", "_grad_fn", "_apply_fn",
                      "_train_fn_idx", "_eval_fn_idx", "_grad_fn_idx",
-                     "_scan_fn", "_anat_gather_fn", "_anat_grad_fn",
-                     "_anat_collective_fn", "_anat_update_fn"):
+                     "_scan_fn"):
             fn = getattr(self, attr, None)
             if fn is not None:
                 setattr(self, attr, _probe.time_compiles(label, fn))
@@ -1280,8 +1168,7 @@ class FusedTrainStep(Unit):
         fns = [getattr(self, n, None) for n in
                ("_train_fn", "_eval_fn", "_grad_fn", "_apply_fn",
                 "_train_fn_idx", "_eval_fn_idx", "_grad_fn_idx",
-                "_scan_fn", "_anat_gather_fn", "_anat_grad_fn",
-                "_anat_collective_fn", "_anat_update_fn")] + \
+                "_scan_fn")] + \
             list(self._scan_idx_fns.values())
         _probe.watch_compiles(f"{type(self).__name__}-{id(self):x}",
                               *(f for f in fns if f is not None),
@@ -1296,10 +1183,6 @@ class FusedTrainStep(Unit):
         GiB) and on the loader exposing ``original_data``."""
         self._dataset_dev = None
         self._train_fn_idx = self._eval_fn_idx = None
-        if self.anatomy:
-            # the index-fed/scan fast paths batch work the split cannot
-            # attribute; anatomy keeps the standard per-minibatch path
-            return
         loader = self.loader
         data_arr, labels_arr, _why = full_batch_arrays(
             loader, mse=isinstance(self.evaluator, EvaluatorMSE))
@@ -1350,8 +1233,8 @@ class FusedTrainStep(Unit):
             def body(carry, inp):
                 p, k = carry
                 idx, m = inp
-                p, k, metrics = self._local_train(p, k, hyper, data[idx],
-                                                  labels[idx], m)
+                p, k, metrics = self._local_train(
+                    p, k, hyper, *self._gather_batch(data, labels, idx), m)
                 return (p, k), metrics
             (params, key), mets = jax.lax.scan(
                 body, (params, key), (idxs, ms))
@@ -1360,8 +1243,8 @@ class FusedTrainStep(Unit):
         def local_eval_many(params, data, labels, idxs, ms):
             def body(_, inp):
                 idx, m = inp
-                return None, self._local_eval(params, data[idx],
-                                              labels[idx], m)
+                return None, self._local_eval(
+                    params, *self._gather_batch(data, labels, idx), m)
             _, mets = jax.lax.scan(body, None, (idxs, ms))
             return jax.tree.map(lambda a: a.sum(0), mets)
 
@@ -1482,6 +1365,15 @@ class FusedTrainStep(Unit):
         # (a class pass entered MID-WAY — restored loader state — falls
         # through to the per-minibatch path for the remainder; _acc is
         # NOT a valid in-flight marker because that path sets it too)
+        with _TRACER.timed("train.dispatch") as span:
+            metrics = self._dispatch(loader, staged)
+        self._cadence.tick(span.t0)
+        self._finish_run(loader, metrics)
+
+    def _dispatch(self, loader, staged):
+        """Hand one minibatch to its compiled program (train, grads
+        half-step or eval; index-fed or not) and return the metrics
+        pytree, still on the device."""
         mask = staged["mask"] if staged is not None else \
             loader.minibatch_indices.mem >= 0
         accumulate = self.accumulate_steps > 1
@@ -1492,21 +1384,19 @@ class FusedTrainStep(Unit):
                     np.int32)
             data, labels_all = self._dataset_dev
             if int(loader.minibatch_class) != TRAIN:
-                metrics = self._eval_fn_idx(self._params, data, labels_all,
-                                            idx, mask)
-            elif accumulate:
+                return self._eval_fn_idx(self._params, data, labels_all,
+                                         idx, mask)
+            if accumulate:
                 self._key, grads, metrics, new_res = self._grad_fn_idx(
                     self._params, self._key, data, labels_all, idx, mask)
                 self._fold_residuals(new_res)
                 self._accumulate(grads, metrics, loader)
-                self._note_qcomm_grads()
             else:
                 self._params, self._key, metrics = self._train_fn_idx(
                     self._params, self._key, self._hyper_device(),
                     data, labels_all, idx, mask)
-                self._note_qcomm_grads()
-            self._finish_run(loader, metrics)
-            return
+            self._note_qcomm_grads()
+            return metrics
         if staged is not None:
             x, labels = staged["x"], staged["y"]
         elif isinstance(self.evaluator, EvaluatorMSE):
@@ -1516,22 +1406,18 @@ class FusedTrainStep(Unit):
             x = loader.minibatch_data.mem
             labels = loader.minibatch_labels.mem
         if int(loader.minibatch_class) != TRAIN:
-            metrics = self._eval_fn(self._params, x, labels, mask)
-        elif accumulate:
+            return self._eval_fn(self._params, x, labels, mask)
+        if accumulate:
             self._key, grads, metrics, new_res = self._grad_fn(
                 self._params, self._key, x, labels, mask)
             self._fold_residuals(new_res)
             self._accumulate(grads, metrics, loader)
-            self._note_qcomm_grads()
-        elif self._anatomy is not None:
-            metrics = self._run_anatomy_step(x, labels, mask)
-            self._note_qcomm_grads()
         else:
             self._params, self._key, metrics = self._train_fn(
                 self._params, self._key, self._hyper_device(),
                 x, labels, mask)
-            self._note_qcomm_grads()
-        self._finish_run(loader, metrics)
+        self._note_qcomm_grads()
+        return metrics
 
     def _fold_residuals(self, new_res) -> None:
         """Persist the residual updates returned by a ``_grad_fn``
@@ -1574,20 +1460,22 @@ class FusedTrainStep(Unit):
             from znicz_tpu.loader.base import plan_device_arrays
             idxs, ms = plan_device_arrays(loader.class_plan())
             data, labels = self._dataset_dev
-            if int(loader.minibatch_class) == TRAIN:
-                self._params, self._key, metrics = \
-                    self._scan_idx_fns["train"](
-                        self._params, self._key, self._hyper_device(),
-                        data, labels, idxs, ms)
-                self._note_qcomm_grads(int(idxs.shape[0]))
-            else:
-                metrics = self._scan_idx_fns["eval"](
-                    self._params, data, labels, idxs, ms)
+            with _TRACER.timed("train.dispatch") as span:
+                if int(loader.minibatch_class) == TRAIN:
+                    self._params, self._key, metrics = \
+                        self._scan_idx_fns["train"](
+                            self._params, self._key, self._hyper_device(),
+                            data, labels, idxs, ms)
+                    self._note_qcomm_grads(int(idxs.shape[0]))
+                else:
+                    metrics = self._scan_idx_fns["eval"](
+                        self._params, data, labels, idxs, ms)
+            self._cadence.tick(span.t0)
             self._note_gathered(int(idxs.shape[0]))
             self._acc = metrics
             self._scan_in_flight = True
         if loader.last_minibatch:
-            self._publish(jax.device_get(self._acc), cumulative=True)
+            self._read_metrics(self._acc, cumulative=True)
             self._acc = None
             self._conf_seen = None
             self._scan_in_flight = False
@@ -1609,14 +1497,14 @@ class FusedTrainStep(Unit):
         # rollback paths are exercised against the real fused step
         self._params = poison_hook("step.params", self._params)
         if not self.defer_metrics:
-            self._publish(jax.device_get(metrics))
+            self._read_metrics(metrics)
             return
         # deferred mode: fold into the device-side accumulator (async tiny
         # adds, no host sync) and only fetch at the end of the class pass
         self._acc = metrics if self._acc is None else \
             jax.tree.map(jnp.add, self._acc, metrics)
         if loader.last_minibatch:
-            self._publish(jax.device_get(self._acc), cumulative=True)
+            self._read_metrics(self._acc, cumulative=True)
             self._acc = None
             self._conf_seen = None
         else:
@@ -1626,6 +1514,15 @@ class FusedTrainStep(Unit):
             self.mse = 0.0
             self.loss = 0.0
             self.minibatch_size = 0
+
+    def _read_metrics(self, sums, cumulative: bool = False) -> None:
+        """THE blocking metric read: fetch the device-side sums and
+        publish them.  The ``train.metrics_read`` span is where the host
+        waits for the device to drain (once a class pass in deferred
+        mode), so the profiler can put the chip's idle gap there down to
+        it."""
+        with _TRACER.span("train.metrics_read"):
+            self._publish(jax.device_get(sums), cumulative=cumulative)
 
     def _publish(self, sums, cumulative: bool = False) -> None:
         """Write (host) metric sums into the attrs the Decision reads.
@@ -1661,7 +1558,7 @@ class FusedTrainStep(Unit):
         reset — the class pass keeps accumulating, so a mid-pass flush never
         truncates the Decision's epoch accounting."""
         if self._acc is not None:
-            self._publish(jax.device_get(self._acc), cumulative=True)
+            self._read_metrics(self._acc, cumulative=True)
 
     def stop(self) -> None:
         if self._params is not None:

@@ -30,6 +30,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from znicz_tpu.parallel.compat import quantized_psum, shard_map
 
+from znicz_tpu.observe import probe as _probe
 from znicz_tpu.parallel import qcomm
 from znicz_tpu.parallel.moe import (load_balance_aux, moe_ffn,
                                     router_z_loss)
@@ -260,14 +261,26 @@ def unshard_params_host(params, specs, shapes):
 def _block(x, p, heads_local: int, causal: bool, use_flash: bool = False,
            interpret: bool = False, use_ring_flash: bool = False,
            moe_top_k: int = 1, moe_aux_weight: float = 0.0,
-           moe_zloss_weight: float = 0.0):
+           moe_zloss_weight: float = 0.0, index: int = 0):
     """One transformer block on local shards: ring attention (seq axis)
     with tp-sharded heads, then Megatron MLP (model axis).  With the seq
     axis unsharded, ``use_flash`` swaps the attention core for the Pallas
     flash kernel (ops/pallas/attention.py) — same math, no (t, t) score
     matrix in HBM.  ``interpret`` is captured at step-build time along
     with ``use_flash`` so one config snapshot governs all three
-    flash-related decisions (kernel choice, interpreter, vma mode)."""
+    flash-related decisions (kernel choice, interpreter, vma mode).
+    ``index`` only names the block's two scopes, ``block<index>.attn``
+    and ``block<index>.mlp``."""
+    with _probe.scope(f"block{index}.attn"):
+        x = _block_attn(x, p, heads_local, causal, use_flash, interpret,
+                        use_ring_flash)
+    with _probe.scope(f"block{index}.mlp"):
+        return _block_mlp(x, p, moe_top_k, moe_aux_weight,
+                          moe_zloss_weight)
+
+
+def _block_attn(x, p, heads_local, causal, use_flash, interpret,
+                use_ring_flash):
     h = _layer_norm(x, p["ln1_g"], p["ln1_b"])
     b, t_loc, _ = h.shape
 
@@ -290,7 +303,10 @@ def _block(x, p, heads_local: int, causal: bool, use_flash: bool = False,
     else:
         o = ring_attention(q, k, v, "seq", causal=causal)
     o = o.reshape(b, t_loc, -1)                      # (b, t_loc, d_local)
-    x = x + tp.row_parallel(o, p["wo"], None, "model")
+    return x + tp.row_parallel(o, p["wo"], None, "model")
+
+
+def _block_mlp(x, p, moe_top_k, moe_aux_weight, moe_zloss_weight):
     m = _layer_norm(x, p["ln2_g"], p["ln2_b"])
     if "ew1" in p:
         # expert-parallel MoE FFN over the model axis (the block's FFN
@@ -439,20 +455,21 @@ def _forward_hidden(ps, tokens, heads_local, causal, use_flash,
     the summed MoE regularizer term, and the compute-dtype-cast params
     (so the caller's head matmul uses the same precision policy)."""
     ps = jax.tree.map(lambda w: w.astype(cdt), ps)
-    x = ps["emb"][tokens]                         # (b_l, t_l, d)
+    with _probe.scope("embed"):
+        x = ps["emb"][tokens]                     # (b_l, t_l, d)
     blk = _block
     if remat or remat_policy:
         pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
         blk = jax.checkpoint(
             _block, policy=pol,
             static_argnums=(2, 3, 4, 5, 6, 7,
-                            8, 9))  # type: ignore[assignment]
+                            8, 9, 10))  # type: ignore[assignment]
     # regularizer weights apply inside _block (per-block pre-weighted)
     aux_term = jnp.zeros((), jnp.float32)
-    for p in ps["blocks"]:
+    for i, p in enumerate(ps["blocks"]):
         x, aux = blk(x, p, heads_local, causal, use_flash, interp,
                      use_ring_flash, moe_top_k, moe_aux_weight,
-                     moe_zloss_weight)
+                     moe_zloss_weight, i)
         aux_term = aux_term + aux
     return x, aux_term, ps
 
@@ -488,6 +505,16 @@ def _forward_ce(ps, tokens, labels, mask, heads_local, causal, use_flash,
         remat=remat, use_ring_flash=use_ring_flash,
         moe_aux_weight=moe_aux_weight, moe_top_k=moe_top_k,
         remat_policy=remat_policy, moe_zloss_weight=moe_zloss_weight)
+    return _ce_from_hidden(x, ps["head"], labels, mask, aux_term,
+                           loss_chunks, head_sharded, reduce)
+
+
+@_probe.scoped("ce")
+def _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
+                    head_sharded, reduce):
+    """Head matmul + masked CE over the hidden states, normalised and
+    (``reduce``) summed over the data x seq shards: the tail of
+    :func:`_forward_ce`, under the ``ce`` scope."""
     b_l, t_l = labels.shape
     mvec = mask[:, None].astype(jnp.float32) if mask is not None else None
     # either path yields the LOCAL weighted nll sum; normalization below
@@ -495,12 +522,12 @@ def _forward_ce(ps, tokens, labels, mask, heads_local, causal, use_flash,
     # vocab-sharded head always routes through the chunk helper (its CE
     # needs the collective-reduced softmax; n_chunks=1 when unchunked).
     if head_sharded or (loss_chunks and loss_chunks > 1):
-        fn = _vshard_chunk_nll(ps["head"]) if head_sharded else \
-            _dense_chunk_nll(ps["head"])
+        fn = _vshard_chunk_nll(head) if head_sharded else \
+            _dense_chunk_nll(head)
         n_chunks = loss_chunks if (loss_chunks and loss_chunks > 1) else 1
         nll = _ce_token_nll_sum(x, labels, fn, n_chunks, mvec)
     else:
-        logits = (x @ ps["head"]).astype(jnp.float32)
+        logits = (x @ head).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
         picked = jnp.take_along_axis(logp, labels[..., None],
                                      axis=-1)[..., 0]
@@ -542,8 +569,7 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
                     moe_top_k: int = 1,
                     remat_policy: str | None = None,
                     moe_zloss_weight: float = 0.0,
-                    quantized_collectives: dict | None = None,
-                    anatomy: bool = False):
+                    quantized_collectives: dict | None = None):
     """-> jitted ``step(params, tokens, labels) -> (params, loss)``
     (``masked=True``: ``step(params, tokens, labels, mask)`` with a
     per-row bool mask — padded loader rows train nothing).
@@ -628,18 +654,6 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
     stateless (pure ``(params, batch) -> params``), so there is no
     residual carry; prefer bf16 mode or the fused step for EF-grade
     convergence.  mode=off builds today's program bit for bit.
-
-    ``anatomy=True`` (ISSUE 20) returns a split-dispatch DRIVER instead
-    of one jitted program: separate compiled phases (zero_gather / grad
-    / collective / update) with host stamps between them feeding
-    ``znicz_anatomy_*{plane="transformer"}``.  The reduction follows
-    the quantized-collectives semantics (local loss + one explicit
-    psum — the true batch-mean gradient) even with no codec, because
-    the exact path's AD-transposed grads are per-rank PARTIAL values
-    that cannot cross a program cut; trajectories therefore track the
-    exact path within the band documented above, not bitwise.  A
-    diagnostic mode — per-phase dispatch latency is the price;
-    ``donate`` is ignored (params feed two programs per step).
     """
     if shard_params and shard_update:
         raise ValueError(
@@ -726,35 +740,38 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
             # the data x seq sum) through the quantized-psum seam; the
             # reported loss scalar reduces exactly (telemetry never
             # quantizes)
-            grads, _ = quantized_psum(grads, ("data", "seq"), codec)
+            with _probe.scope("grad_reduce"):
+                grads, _ = quantized_psum(grads, ("data", "seq"), codec)
             loss = lax.psum(loss, ("data", "seq"))
         n_shards = lax.psum(1, "data") * lax.psum(1, "seq")
-        if shard_params:
-            # each replica updates ONLY its slice (grad sliced to match)
-            # and keeps it — no regather; tensor-sharded leaves update
-            # locally as before
-            flat_g = jax.tree.leaves(grads)
-            new_leaves = [
-                flat_p[i] -
-                lr * zero.pad_slice(flat_g[i], rank, n_data) / n_shards
-                if flat_s[i] == P()
-                else flat_full[i] - lr * flat_g[i] / n_shards
-                for i in range(len(flat_p))]
-            new_params = jax.tree.unflatten(treedef, new_leaves)
-        elif shard_update:
-            # PartitionSpec is a tuple subclass (a pytree container), so
-            # align specs to params by flattening with an is_leaf guard
-            flat_w, treedef = jax.tree.flatten(params)
-            flat_g = jax.tree.leaves(grads)
-            flat_s = _spec_leaves(specs)
-            new_leaves = [
-                _sharded_sgd(w, g, n_shards) if s == P()
-                else w - lr * g / n_shards
-                for w, g, s in zip(flat_w, flat_g, flat_s)]
-            new_params = jax.tree.unflatten(treedef, new_leaves)
-        else:
-            new_params = jax.tree.map(
-                lambda w, g: w - lr * g / n_shards, params, grads)
+        with _probe.scope("update"):
+            if shard_params:
+                # each replica updates ONLY its slice (grad sliced to
+                # match) and keeps it — no regather; tensor-sharded
+                # leaves update locally as before
+                flat_g = jax.tree.leaves(grads)
+                new_leaves = [
+                    flat_p[i] -
+                    lr * zero.pad_slice(flat_g[i], rank, n_data) / n_shards
+                    if flat_s[i] == P()
+                    else flat_full[i] - lr * flat_g[i] / n_shards
+                    for i in range(len(flat_p))]
+                new_params = jax.tree.unflatten(treedef, new_leaves)
+            elif shard_update:
+                # PartitionSpec is a tuple subclass (a pytree container),
+                # so align specs to params by flattening with an is_leaf
+                # guard
+                flat_w, treedef = jax.tree.flatten(params)
+                flat_g = jax.tree.leaves(grads)
+                flat_s = _spec_leaves(specs)
+                new_leaves = [
+                    _sharded_sgd(w, g, n_shards) if s == P()
+                    else w - lr * g / n_shards
+                    for w, g, s in zip(flat_w, flat_g, flat_s)]
+                new_params = jax.tree.unflatten(treedef, new_leaves)
+            else:
+                new_params = jax.tree.map(
+                    lambda w, g: w - lr * g / n_shards, params, grads)
         return new_params, loss / n_shards
 
     # replication checking is disabled wholesale by the compat shim
@@ -765,147 +782,11 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
     batch_spec = P("data", "seq")
     in_specs = (step_specs, batch_spec, batch_spec) + \
         ((P("data"),) if masked else ())
-    if not anatomy:
-        step = shard_map(
-            local_step, mesh=mesh, in_specs=in_specs,
-            out_specs=(step_specs, P()))
-        return jax.jit(step, donate_argnums=(0,) if donate else ()), \
-            step_specs
-    return _make_anatomy_step(
-        mesh, specs, step_specs, shapes, batch_spec, masked, lr,
-        shard_params, shard_update, n_data, via_psum, codec,
-        _sharded_sgd,
-        dict(heads_local=heads_local, causal=causal, use_flash=use_flash,
-             interp=interp, cdt=cdt, remat=remat,
-             loss_chunks=loss_chunks, use_ring_flash=use_ring_flash,
-             head_sharded=head_sharded, moe_aux_weight=moe_aux_weight,
-             moe_top_k=moe_top_k, remat_policy=remat_policy,
-             moe_zloss_weight=moe_zloss_weight)), step_specs
-
-
-def _make_anatomy_step(mesh, specs, step_specs, shapes, batch_spec,
-                       masked, lr, shard_params, shard_update, n_data,
-                       via_psum, codec, sharded_sgd, fwd_kw):
-    """Split-dispatch phase programs + host-stamping driver for
-    ``make_train_step(anatomy=True)`` — the same gather / loss_fn /
-    psum / update bodies as ``local_step``, cut at the phase seams.
-    The grad program returns per-rank UNREDUCED grads stacked over the
-    combined ``(data, seq)`` ranks via the ``g[None]`` / out_specs
-    ``P(("data","seq"), ...)`` trick (no data movement at the cut);
-    the collective program takes the stack back per-rank and runs the
-    explicit (possibly quantized) psum."""
-    from znicz_tpu.observe.anatomy import StepAnatomy, TRAIN_PHASES
-
-    is_spec = lambda s: isinstance(s, P)  # noqa: E731
-    stacked_specs = jax.tree.map(lambda s: P(("data", "seq"), *s),
-                                 specs, is_leaf=is_spec)
-
-    def local_gather(params):
-        rank = lax.axis_index("data")
-        flat_p, treedef = jax.tree.flatten(params)
-        flat_s = _spec_leaves(specs)
-        flat_shapes = _shape_leaves(shapes)
-        idx = [i for i, s in enumerate(flat_s) if s == P()]
-        gathered = zero.gather_chain(
-            [flat_p[i] for i in idx],
-            [jax.ShapeDtypeStruct(flat_shapes[i], flat_p[i].dtype)
-             for i in idx],
-            rank, n_data, "data", via_psum=via_psum, codec=codec)
-        flat_full = list(flat_p)
-        for i, g in zip(idx, gathered):
-            flat_full[i] = g
-        return jax.tree.unflatten(treedef, flat_full)
-
-    def local_grad(full_params, tokens, labels, mask=None):
-        def loss_fn(ps):
-            return _forward_ce(ps, tokens, labels, mask,
-                               reduce=False, **fwd_kw)
-
-        loss, grads = jax.value_and_grad(loss_fn)(full_params)
-        n_shards = lax.psum(1, "data") * lax.psum(1, "seq")
-        loss = lax.psum(loss, ("data", "seq")) / n_shards
-        return jax.tree.map(lambda g: g[None], grads), loss
-
-    def local_collective(stacked):
-        grads = jax.tree.map(lambda g: g[0], stacked)
-        grads, _ = quantized_psum(grads, ("data", "seq"), codec)
-        return grads
-
-    def local_update(params, grads):
-        n_shards = lax.psum(1, "data") * lax.psum(1, "seq")
-        if shard_params:
-            rank = lax.axis_index("data")
-            flat_p, treedef = jax.tree.flatten(params)
-            flat_g = jax.tree.leaves(grads)
-            flat_s = _spec_leaves(specs)
-            new_leaves = [
-                flat_p[i] - lr * zero.pad_slice(flat_g[i], rank,
-                                                n_data) / n_shards
-                if flat_s[i] == P()
-                else flat_p[i] - lr * flat_g[i] / n_shards
-                for i in range(len(flat_p))]
-            return jax.tree.unflatten(treedef, new_leaves)
-        if shard_update:
-            flat_w, treedef = jax.tree.flatten(params)
-            flat_g = jax.tree.leaves(grads)
-            flat_s = _spec_leaves(specs)
-            new_leaves = [
-                sharded_sgd(w, g, n_shards) if s == P()
-                else w - lr * g / n_shards
-                for w, g, s in zip(flat_w, flat_g, flat_s)]
-            return jax.tree.unflatten(treedef, new_leaves)
-        return jax.tree.map(lambda w, g: w - lr * g / n_shards,
-                            params, grads)
-
-    gather_fn = None
-    if shard_params:
-        gather_fn = jax.jit(shard_map(
-            local_gather, mesh=mesh, in_specs=(step_specs,),
-            out_specs=specs))
-    grad_in = (specs, batch_spec, batch_spec) + \
-        ((P("data"),) if masked else ())
-    grad_fn = jax.jit(shard_map(
-        local_grad, mesh=mesh, in_specs=grad_in,
-        out_specs=(stacked_specs, P())))
-    coll_fn = jax.jit(shard_map(
-        local_collective, mesh=mesh, in_specs=(stacked_specs,),
-        out_specs=specs))
-    upd_fn = jax.jit(shard_map(
-        local_update, mesh=mesh, in_specs=(step_specs, specs),
-        out_specs=step_specs))
-
-    anat = StepAnatomy("transformer", TRAIN_PHASES)
-    # analytic MFU numerator: ~6 FLOPs per matmul weight per token for
-    # one train step (2 fwd + 4 bwd), embedding lookup excluded — the
-    # standard transformer approximation; tokens.size (the GLOBAL
-    # batch x time) is known at the first call
-    flat_shapes = _shape_leaves(shapes)
-    matmul_params = sum(int(np.prod(s)) for s in flat_shapes
-                        if len(s) >= 2)
-    matmul_params -= int(np.prod(shapes["emb"]))
-    state = {"flops_set": False}
-
-    def step(params, tokens, labels, mask=None):
-        if not state["flops_set"]:
-            anat.set_flops(6.0 * matmul_params * int(tokens.size))
-            state["flops_set"] = True
-        anat.begin()
-        if gather_fn is not None:
-            full = jax.block_until_ready(gather_fn(params))
-            anat.stamp("zero_gather")
-        else:
-            full = params
-        args = (tokens, labels) + ((mask,) if masked else ())
-        stacked, loss = jax.block_until_ready(grad_fn(full, *args))
-        anat.stamp("grad")
-        grads = jax.block_until_ready(coll_fn(stacked))
-        anat.stamp("collective")
-        new_params = jax.block_until_ready(upd_fn(params, grads))
-        anat.stamp("update")
-        anat.finish()
-        return new_params, loss
-
-    return step
+    step = shard_map(
+        local_step, mesh=mesh, in_specs=in_specs,
+        out_specs=(step_specs, P()))
+    return jax.jit(step, donate_argnums=(0,) if donate else ()), \
+        step_specs
 
 
 def make_eval_loss(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,

@@ -230,9 +230,11 @@ class BatchPrefetcher:
                     _M_SERVE.inc(serve_dt)
                 staged = None
                 if self._stager is not None:
-                    t0 = time.perf_counter()
-                    staged, nbytes = self._stager(rec, arrays)
-                    stage_dt = time.perf_counter() - t0
+                    # the worker's device_put: a live span, on this
+                    # thread's row of the profiler's host plane
+                    with _trace.TRACER.timed("pipeline.stage") as span:
+                        staged, nbytes = self._stager(rec, arrays)
+                    t0, stage_dt = span.t0, span.dt
                     self.stats.stage_s += stage_dt
                     self.stats.bytes_staged += int(nbytes)
                     if observed:
@@ -294,17 +296,19 @@ class BatchPrefetcher:
             # window is over, release the parked worker into the new epoch
             self._pending_release = False
             self._barrier_sem.release()
-        t0 = time.perf_counter()
-        while True:
-            if self._stop.is_set():
-                raise PrefetcherStopped("prefetcher was stopped")
-            try:
-                batch = self._queue.get(timeout=0.05)
-                break
-            except queue.Empty:
-                if self._error is not None:
-                    raise self._error
-        stall_dt = time.perf_counter() - t0
+        # the consumer's wait on the ring: a live span, so an idle chip
+        # under it reads as input-bound in the profiler
+        with _trace.TRACER.timed("pipeline.input_wait") as span:
+            while True:
+                if self._stop.is_set():
+                    raise PrefetcherStopped("prefetcher was stopped")
+                try:
+                    batch = self._queue.get(timeout=0.05)
+                    break
+                except queue.Empty:
+                    if self._error is not None:
+                        raise self._error
+        t0, stall_dt = span.t0, span.dt
         self.stats.consumer_starved_s += stall_dt
         self.stats.consumed += 1
         if probe.enabled():

@@ -482,10 +482,17 @@ class ContinuousBatcher(Logger):
                     "done": True})
                 continue
             slot = free[0]
-            t_prefill = time.perf_counter()
+            # prefill phase: a live span round the device call (ring
+            # event on the request's track, annotation on this thread)
+            span = _trace.TRACER.timed(
+                "generate.prefill",
+                {"rid": req.stream.request_id,
+                 "prompt_len": len(req.prompt), "slot": slot},
+                tid=req.track)
             try:
-                logits = self._attach_paged(req, slot) if self._paged \
-                    else self._attach_contiguous(req, slot)
+                with span:
+                    logits = self._attach_paged(req, slot) if self._paged \
+                        else self._attach_contiguous(req, slot)
             except Exception as exc:  # noqa: BLE001 — this request only
                 self.error(f"prefill failed: {exc!r}")
                 # _finish releases any pages already allocated, so a
@@ -494,22 +501,18 @@ class ContinuousBatcher(Logger):
                 self._finish(req, {"error": f"prefill failed: {exc!r}",
                                    "done": True})
                 continue
-            _trace.TRACER.complete(
-                "generate.prefill", t_prefill,
-                time.perf_counter() - t_prefill, tid=req.track,
-                rid=req.stream.request_id, prompt_len=len(req.prompt),
-                slot=slot)
             # anatomy plane (ISSUE 20): prompt attach / KV prefill as a
-            # phase of the serving plane's step taxonomy
-            _probe.anatomy_phase("serve", "prefill",
-                                 time.perf_counter() - t_prefill,
-                                 t0=t_prefill)
+            # phase of the serving plane's step taxonomy, from the
+            # span's own clock reads
+            _probe.anatomy_phase("serve", "prefill", span.dt, t0=span.t0)
             req.pos = len(req.prompt)
             self.slots[slot] = req
-            token = req.sampler.sample(logits)
+            with _trace.TRACER.span("generate.sample"):
+                token = req.sampler.sample(logits)
             req.next_token = token
-            self._emit_token(req, token)
-            self._retire_if_done(req, slot, time.monotonic())
+            with _trace.TRACER.span("generate.emit"):
+                self._emit_token(req, token)
+                self._retire_if_done(req, slot, time.monotonic())
         # (unreachable)
 
     def _attach_contiguous(self, req: _GenRequest, slot: int):
@@ -693,55 +696,60 @@ class ContinuousBatcher(Logger):
             pos[i] = req.pos
             tok[i] = req.next_token
         pt = self._page_table(self.decoder, "pages")
-        t_step = time.perf_counter()
-        if k:
-            proposals, vlogits = self._spec_round(
-                pt, self._page_table(self._draft, "draft_pages"), pos,
-                tok)
-        else:
-            logits = self.decoder.decode_paged(pt, pos, tok)
+        with _trace.TRACER.timed(
+            "generate.decode_step",
+            {"step": self.step_count + 1, "active": len(live),
+             "paged": True, "spec_k": k}) as span:
+            if k:
+                proposals, vlogits = self._spec_round(
+                    pt, self._page_table(self._draft, "draft_pages"), pos,
+                    tok)
+            else:
+                logits = self.decoder.decode_paged(pt, pos, tok)
         self.step_count += 1
-        _trace.TRACER.complete("generate.decode_step", t_step,
-                               time.perf_counter() - t_step,
-                               step=self.step_count, active=len(live),
-                               paged=True, spec_k=k)
         # anatomy plane (ISSUE 20): a speculative round is a "verify"
         # phase (draft proposals + the target's batched judgment); a
         # plain round is one "decode" dispatch
         _probe.anatomy_phase("serve", "verify" if k else "decode",
-                             time.perf_counter() - t_step, t0=t_step)
+                             span.dt, t0=span.t0)
         now = time.monotonic()
-        for i, req in live:
-            if req.stream.cancelled or (req.deadline is not None and
-                                        now > req.deadline):
+        sampled = []
+        with _trace.TRACER.span("generate.sample"):
+            for i, req in live:
+                if req.stream.cancelled or (req.deadline is not None and
+                                            now > req.deadline):
+                    self._retire_if_done(req, i, now)
+                    continue
+                if not k:
+                    emitted = [req.sampler.sample(logits[i])]
+                elif req.greedy:
+                    g = np.argmax(vlogits[i], axis=-1)
+                    a = 0
+                    while a < k and proposals[i, a] == g[a]:
+                        a += 1
+                    # a accepted drafts + the target's own token at the
+                    # first mismatch (or the bonus token when all
+                    # matched): every emitted token IS the target's
+                    # greedy choice, so the stream is token-identical to
+                    # non-speculative decode by construction
+                    emitted = [int(t) for t in proposals[i, :a]] + \
+                        [int(g[a])]
+                    self.metrics.on_spec(a, k - a)
+                else:
+                    # sampled request: position 0 of the verify logits IS
+                    # its exact next-token distribution — one token per
+                    # round, distribution untouched
+                    emitted = [req.sampler.sample(vlogits[i, 0])]
+                sampled.append((i, req, emitted))
+        with _trace.TRACER.span("generate.emit"):
+            for i, req, emitted in sampled:
+                for token in emitted:
+                    req.pos += 1
+                    req.next_token = int(token)
+                    self._emit_token(req, int(token))
+                    if req.emitted >= req.max_new:
+                        break
                 self._retire_if_done(req, i, now)
-                continue
-            if not k:
-                emitted = [req.sampler.sample(logits[i])]
-            elif req.greedy:
-                g = np.argmax(vlogits[i], axis=-1)
-                a = 0
-                while a < k and proposals[i, a] == g[a]:
-                    a += 1
-                # a accepted drafts + the target's own token at the
-                # first mismatch (or the bonus token when all matched):
-                # every emitted token IS the target's greedy choice, so
-                # the stream is token-identical to non-speculative
-                # decode by construction
-                emitted = [int(t) for t in proposals[i, :a]] + [int(g[a])]
-                self.metrics.on_spec(a, k - a)
-            else:
-                # sampled request: position 0 of the verify logits IS
-                # its exact next-token distribution — one token per
-                # round, distribution untouched
-                emitted = [req.sampler.sample(vlogits[i, 0])]
-            for token in emitted:
-                req.pos += 1
-                req.next_token = int(token)
-                self._emit_token(req, int(token))
-                if req.emitted >= req.max_new:
-                    break
-            self._retire_if_done(req, i, now)
 
     def _step(self) -> None:
         """One batched decode step over the occupied slots."""
@@ -760,31 +768,33 @@ class ContinuousBatcher(Logger):
                 pos[i] = req.pos
                 tok[i] = req.next_token
                 active += 1
-        t_step = time.perf_counter()
-        self._kv, logits = self.decoder.decode(self._kv, pos, tok)
-        self.step_count += 1
         # one batched decode-step span per step (worker thread): a
         # request's share of it is bracketed by its first_token_step /
         # finish_step counters
-        _trace.TRACER.complete("generate.decode_step", t_step,
-                               time.perf_counter() - t_step,
-                               step=self.step_count, active=active)
-        _probe.anatomy_phase("serve", "decode",
-                             time.perf_counter() - t_step, t0=t_step)
+        with _trace.TRACER.timed(
+            "generate.decode_step",
+            {"step": self.step_count + 1, "active": active}) as span:
+            self._kv, logits = self.decoder.decode(self._kv, pos, tok)
+        self.step_count += 1
+        _probe.anatomy_phase("serve", "decode", span.dt, t0=span.t0)
         now = time.monotonic()
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            # cancel/deadline between steps: retire without sampling
-            if req.stream.cancelled or (req.deadline is not None and
-                                        now > req.deadline):
+        sampled = []
+        with _trace.TRACER.span("generate.sample"):
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                # cancel/deadline between steps: retire without sampling
+                if req.stream.cancelled or (req.deadline is not None and
+                                            now > req.deadline):
+                    self._retire_if_done(req, i, now)
+                    continue
+                req.pos += 1
+                req.next_token = req.sampler.sample(logits[i])
+                sampled.append((i, req))
+        with _trace.TRACER.span("generate.emit"):
+            for i, req in sampled:
+                self._emit_token(req, req.next_token)
                 self._retire_if_done(req, i, now)
-                continue
-            req.pos += 1
-            token = req.sampler.sample(logits[i])
-            req.next_token = token
-            self._emit_token(req, token)
-            self._retire_if_done(req, i, now)
 
     def _fail_active(self, exc: Exception) -> None:
         """A decode-loop crash poisons every in-flight stream (their
@@ -819,7 +829,9 @@ class ContinuousBatcher(Logger):
                                               "down"))
                 return
             try:
-                self._admit()
+                if self._pending:
+                    with _trace.TRACER.span("generate.admit"):
+                        self._admit()
                 if any(s is not None for s in self.slots):
                     self._step()
             except Exception as exc:  # noqa: BLE001 — the worker must

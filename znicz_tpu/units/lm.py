@@ -23,6 +23,8 @@ import numpy as np
 from znicz_tpu.core import prng
 from znicz_tpu.core.accelerated_units import AcceleratedUnit
 from znicz_tpu.loader.base import TRAIN
+from znicz_tpu.observe.anatomy import StepCadence
+from znicz_tpu.observe.trace import TRACER
 
 
 class TransformerLMStep(AcceleratedUnit):
@@ -42,8 +44,7 @@ class TransformerLMStep(AcceleratedUnit):
                  n_experts: Optional[int] = None,
                  moe_aux_weight: float = 0.0,
                  moe_top_k: int = 1,
-                 moe_zloss_weight: float = 0.0,
-                 anatomy: Optional[bool] = None, **kwargs) -> None:
+                 moe_zloss_weight: float = 0.0, **kwargs) -> None:
         super().__init__(workflow, **kwargs)
         self.loader = loader
         self.n_layers = int(n_layers)
@@ -71,12 +72,9 @@ class TransformerLMStep(AcceleratedUnit):
                 "moe_aux_weight/moe_zloss_weight/moe_top_k have no "
                 "effect without "
                 "n_experts — a dense model would train silently")
-        #: step-anatomy split-dispatch mode (ISSUE 20): the train step
-        #: runs as per-phase programs with host stamps feeding
-        #: znicz_anatomy_*{plane="transformer"} — explicit-psum
-        #: reduction semantics, see make_train_step(anatomy=True).
-        #: None -> root.common.engine.step_anatomy (False).
-        self.anatomy = anatomy
+        #: wall between consecutive dispatches ->
+        #: znicz_anatomy_step_seconds{plane="transformer"}, always on
+        self._cadence = StepCadence("transformer")
         self.vocab_size: Optional[int] = None
         # decision links (DecisionMSE contract)
         self.minibatch_mse = 0.0
@@ -113,11 +111,6 @@ class TransformerLMStep(AcceleratedUnit):
                 prng.get(), self.n_layers, self.d, self.heads, self.ff,
                 self.vocab_size, n_experts=self.n_experts)
         self._params = self._place_params(self._params)
-        from znicz_tpu.core.config import root
-
-        if self.anatomy is None:
-            self.anatomy = bool(root.common.engine.get("step_anatomy",
-                                                       False))
         # masked=True: the loader's padded tail rows (base.py static-shape
         # policy) contribute neither loss nor gradients
         self._step, _ = tfm.make_train_step(
@@ -127,13 +120,19 @@ class TransformerLMStep(AcceleratedUnit):
             n_experts=self.n_experts,
             moe_aux_weight=self.moe_aux_weight,
             moe_top_k=self.moe_top_k,
-            moe_zloss_weight=self.moe_zloss_weight,
-            anatomy=bool(self.anatomy))
+            moe_zloss_weight=self.moe_zloss_weight)
         self._eval = tfm.make_eval_loss(
             self.mesh, self.n_layers, self.d, self.heads, self.ff,
             self.vocab_size, masked=True, loss_chunks=self.loss_chunks,
             head_sharded=self.head_sharded, n_experts=self.n_experts,
             moe_top_k=self.moe_top_k)
+        # cold-compile timing, and the shapes probe.scope_map() lowers
+        # the programs from again (as FusedTrainStep's programs)
+        from znicz_tpu.observe import probe
+
+        label = type(self).__name__
+        self._step = probe.time_compiles(label, self._step)
+        self._eval = probe.time_compiles(label, self._eval)
         #: minibatch placement: batch over data, time over seq
         self._batch_sharding = NamedSharding(self.mesh, P("data", "seq"))
         self._mask_sharding = NamedSharding(self.mesh, P("data"))
@@ -203,26 +202,22 @@ class TransformerLMStep(AcceleratedUnit):
             # pipelined feeding: the prefetch worker already issued the
             # fused tuple put, overlapped with the previous step
             tokens, labels, mask = staged["lm"]
-        elif self.anatomy:
-            import time
-
-            from znicz_tpu.observe import probe
-            t0 = time.perf_counter()
-            tokens, labels, mask = self._stage_batch(
-                loader.minibatch_data.mem, loader.minibatch_labels.mem,
-                count)
-            probe.anatomy_phase("transformer", "stage",
-                                time.perf_counter() - t0, t0=t0)
         else:
-            tokens, labels, mask = self._stage_batch(
-                loader.minibatch_data.mem, loader.minibatch_labels.mem,
-                count)
-        if int(self.loader.minibatch_class) == TRAIN:
-            self._params, loss = self._step(self._params, tokens, labels,
-                                            mask)
-        else:
-            loss = self._eval(self._params, tokens, labels, mask)
-        self.minibatch_mse = float(jax.device_get(loss))
+            with TRACER.span("lm.stage"):
+                tokens, labels, mask = self._stage_batch(
+                    loader.minibatch_data.mem, loader.minibatch_labels.mem,
+                    count)
+        with TRACER.timed("lm.dispatch") as span:
+            if int(self.loader.minibatch_class) == TRAIN:
+                self._params, loss = self._step(self._params, tokens,
+                                                labels, mask)
+            else:
+                loss = self._eval(self._params, tokens, labels, mask)
+        self._cadence.tick(span.t0)
+        # the per-step blocking read: the host waits here for the step
+        # to finish, and the chip then waits for the next dispatch
+        with TRACER.span("lm.loss_read"):
+            self.minibatch_mse = float(jax.device_get(loss))
         self.minibatch_size = count
 
     # -- serving handoff (ISSUE 10) -----------------------------------------
