@@ -43,6 +43,7 @@ Loader/Decision/Snapshotter stay host-side exactly like the reference.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Optional
 
@@ -91,6 +92,25 @@ def unit_scope(index: int, unit) -> str:
                  "act")
     return f"{group}.{index:02d}_" + re.sub(r"[^A-Za-z0-9_.\-]", "_",
                                             str(unit.name))
+
+
+def pinned_row_shape(n_values: int) -> tuple:
+    """The shape one sample takes in the array :meth:`FusedTrainStep.
+    _pin_dataset` pins: its values flat, and where that costs at most an
+    eighth more, padded to whole (8, 128) tiles and split into lanes,
+    ``(k, 128)`` with ``k`` a multiple of 8.
+
+    A TPU lays an array out by what pads least, not row-major: an NHWC
+    array with a batch that is a multiple of 128 lies batch-minor
+    (``f32[2048,227,227,3]{0,2,3,1}``), and so does ``[N, H*W*C]``, so
+    gathering rows of either relays the whole array out first, in every
+    step.  ``[N, k, 128]`` pads nothing either way and stays row-major:
+    the gather then reads its rows and nothing else (v5e compiles, PR
+    26; PERF.md section 6)."""
+    pad = -n_values % (8 * 128)
+    if pad * 8 > n_values:
+        return (n_values,)
+    return ((n_values + pad) // 128, 128)
 
 
 def full_batch_arrays(loader, mse: bool):
@@ -243,6 +263,7 @@ class FusedTrainStep(Unit):
         self._train_fn = None
         self._eval_fn = None
         self._dataset_dev = None  # HBM-pinned (data, labels) full batch
+        self._sample_shape = ()   # of one sample of the pinned data
         self._train_fn_idx = None
         self._eval_fn_idx = None
         self._scan_idx_fns = {}   # "train"/"eval" -> class-pass scan fn
@@ -1010,11 +1031,14 @@ class FusedTrainStep(Unit):
         metrics["bs"] = jax.lax.psum(mask.sum(), "data")
         return key, grads, metrics, new_res
 
-    @staticmethod
     @_probe.scoped("gather_batch")
-    def _gather_batch(data, labels, idx):
-        """The index gather from the dataset pinned on the device."""
-        return data[idx], labels[idx]
+    def _gather_batch(self, data, labels, idx):
+        """The index gather from the dataset pinned on the device: whole
+        rows as :meth:`_pin_dataset` laid them out, back in the sample's
+        shape (and rid of the rows' padding) once gathered."""
+        n, shape = idx.shape[0], self._sample_shape
+        rows = data[idx].reshape(n, -1)[:, :math.prod(shape)]
+        return rows.reshape(n, *shape), labels[idx]
 
     def _local_grads_idx(self, params, key, data, labels, idx, mask):
         return self._local_grads(
@@ -1180,7 +1204,16 @@ class FusedTrainStep(Unit):
         minibatch INDICES — per-step host->device data transfer (the
         dominant cost for image workflows) disappears.  Gated on size
         (``root.common.engine.dataset_on_device_max_bytes``, default 1
-        GiB) and on the loader exposing ``original_data``."""
+        GiB, against the bytes of the pinned data array) and on the
+        loader exposing ``original_data``.
+
+        The data is pinned in the form the step consumes, so that the
+        step's program reads and writes nothing of the dataset's size
+        but the rows it gathers: in ``compute_dtype`` (the step's own
+        cast, which the compiler otherwise hoists above the gather and
+        applies to the whole array in every step) and in rows of
+        :func:`pinned_row_shape`.  Both are done on the device, once.
+        Labels and MSE targets stay as they are."""
         self._dataset_dev = None
         self._train_fn_idx = self._eval_fn_idx = None
         loader = self.loader
@@ -1190,12 +1223,25 @@ class FusedTrainStep(Unit):
             return
         limit = int(root.common.engine.get(
             "dataset_on_device_max_bytes", 1 << 30))
-        data = np.asarray(data_arr.mem, np.float32)
-        if data.nbytes > limit:
+        host = np.asarray(data_arr.mem, np.float32)
+        cdt = jnp.dtype(self.compute_dtype)
+        n, n_values = len(host), math.prod(host.shape[1:])
+        row = pinned_row_shape(n_values)
+        width = math.prod(row)
+        if n * width * cdt.itemsize > limit:
             return
-        self._dataset_dev = (
-            self._put(data),
-            self._put(np.asarray(labels_arr.mem)))
+        self._sample_shape = host.shape[1:]
+        data = self._put(host.reshape(n, n_values))
+        if (data.dtype, data.shape[1:]) != (cdt, row):
+            def as_pinned(a):
+                a = jnp.pad(a.astype(cdt), ((0, 0), (0, width - n_values)))
+                return a.reshape(n, *row)
+            staged, data = data, jax.jit(as_pinned)(data)
+            staged.delete()
+        self._dataset_dev = (data, self._put(np.asarray(labels_arr.mem)))
+        self.info(f"pinned the dataset on the device: {n} rows of "
+                  f"{n_values} values as {data.dtype}{list(data.shape)}, "
+                  f"{data.nbytes} bytes")
         rep, sh = P(), P("data")
         pspecs = self.param_specs()
         train = shard_map(self._local_train_idx, mesh=self.mesh,
