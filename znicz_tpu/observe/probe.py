@@ -39,6 +39,7 @@ path and how determinism tests pin "instrumentation off == seed path".
 
 from __future__ import annotations
 
+import collections
 import functools
 import re
 import time
@@ -244,6 +245,11 @@ def compile_observed(label: str, dt_s: float, **args) -> None:
 
 #: every live :class:`_CompileTimed`, for :func:`scope_map`
 _timed_programs: "weakref.WeakSet" = weakref.WeakSet()
+#: the newest of them, held: a workflow's units form a reference cycle, so
+#: once its owner lets go the programs live until the next cycle
+#: collection, and a scope_map() asked for after the run found them or not
+#: by that chance (one traced run in about seven read no scopes, PR 25)
+_recent_programs: collections.deque = collections.deque(maxlen=32)
 
 
 class _CompileTimed:
@@ -263,6 +269,7 @@ class _CompileTimed:
         self._cold = True
         self._abstract = None
         _timed_programs.add(self)
+        _recent_programs.append(self)
 
     def _cache_size(self) -> int:
         size = getattr(self._fn, "_cache_size", None)

@@ -21,6 +21,14 @@ Backward follows the standard flash recipe in one grid pass: dq per
 q block; dk/dv accumulated across q blocks into a revisited output block
 (Pallas TPU grids execute sequentially, so accumulation over the minor
 grid axis is sound).
+
+Grouped-query attention rides the index maps: with ``group`` query heads
+to each key/value head the folded q is ``(b*h, t, dh)`` and k, v are
+``(b*h/group, t, dh)``; grid row ``i`` reads key/value row ``i // group``
+(the query heads of one group are consecutive rows), so a key/value head
+is fetched once for its whole group, and the backward kernel's dk/dv
+block stays resident over the group's ``group * t/block_q`` consecutive
+steps and sums over them.  ``group == 1`` is the program it always was.
 """
 
 from __future__ import annotations
@@ -73,10 +81,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, *, causal: bool, sm_scale: float,
-                block_q: int):
+                block_q: int, group: int = 1):
     iq = pl.program_id(1)
+    first = iq == 0
+    if group > 1:
+        # dk/dv sum over the group's query heads as well as over q blocks
+        first = first & (pl.program_id(0) % group == 0)
 
-    @pl.when(iq == 0)
+    @pl.when(first)
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
@@ -125,20 +137,34 @@ def _flash(q, k, v, causal: bool, interpret: bool):
 from znicz_tpu.ops.pallas._elementwise import out_struct as _out_struct
 
 
+def _group_of(q, k) -> int:
+    """Query heads to each key/value head of folded ``q`` and ``k``."""
+    if q.shape[0] % k.shape[0]:
+        raise ValueError(f"{q.shape[0]} folded query heads do not divide "
+                         f"over {k.shape[0]} key/value heads")
+    return q.shape[0] // k.shape[0]
+
+
+def _kv_block(t: int, dh: int, group: int):
+    """BlockSpec of one whole key/value head, row ``i // group``."""
+    index = (lambda i, j: (i, 0, 0)) if group == 1 else \
+        (lambda i, j: (i // group, 0, 0))
+    return pl.BlockSpec((1, t, dh), index, memory_space=pltpu.VMEM)
+
+
 def _call_fwd(q, k, v, causal, interpret):
     bh, t, dh = q.shape
     block_q = _pick_block_q(t)
+    group = _group_of(q, k)
     kern = partial(_fwd_kernel, causal=causal,
                    sm_scale=1.0 / float(np.sqrt(dh)), block_q=block_q)
-    blk = lambda shape: pl.BlockSpec(                  # noqa: E731
-        shape, lambda i, j: (i,) + (0,) * (len(shape) - 1),
-        memory_space=pltpu.VMEM)
+    kv = partial(_kv_block, t, dh, group)
     qspec = pl.BlockSpec((1, block_q, dh), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kern,
         grid=(bh, t // block_q),
-        in_specs=[qspec, blk((1, t, dh)), blk((1, t, dh))],
+        in_specs=[qspec, kv(), kv()],
         out_specs=[
             pl.BlockSpec((1, block_q, dh), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
@@ -167,6 +193,7 @@ def _flash_bwd(causal, interpret, res, do, dlse=None):
     q, k, v, o, lse = res
     bh, t, dh = q.shape
     block_q = _pick_block_q(t)
+    group = _group_of(q, k)
     # Δ = rowsum(do ⊙ o) — the lse-side term of the softmax jacobian;
     # shaped (bh, t, 1) like lse for the same Mosaic-tiling reason.
     # When the caller also differentiates through lse (the ring×flash
@@ -179,10 +206,9 @@ def _flash_bwd(causal, interpret, res, do, dlse=None):
     if dlse is not None:
         delta = delta - dlse
     kern = partial(_bwd_kernel, causal=causal,
-                   sm_scale=1.0 / float(np.sqrt(dh)), block_q=block_q)
-    full = lambda shape: pl.BlockSpec(                 # noqa: E731
-        shape, lambda i, j: (i,) + (0,) * (len(shape) - 1),
-        memory_space=pltpu.VMEM)
+                   sm_scale=1.0 / float(np.sqrt(dh)), block_q=block_q,
+                   group=group)
+    kv = partial(_kv_block, t, dh, group)
     qblk3 = lambda: pl.BlockSpec((1, block_q, dh),     # noqa: E731
                                  lambda i, j: (i, j, 0),
                                  memory_space=pltpu.VMEM)
@@ -192,15 +218,14 @@ def _flash_bwd(causal, interpret, res, do, dlse=None):
     dq, dk, dv = pl.pallas_call(
         kern,
         grid=(bh, t // block_q),
-        in_specs=[qblk3(), full((1, t, dh)), full((1, t, dh)),
-                  qblk3(), qblk2(), qblk2()],
+        in_specs=[qblk3(), kv(), kv(), qblk3(), qblk2(), qblk2()],
         # dk/dv revisit the same (bh)-indexed block across the q axis —
         # sequential grid makes the += accumulation exact
-        out_specs=[qblk3(), full((1, t, dh)), full((1, t, dh))],
+        out_specs=[qblk3(), kv(), kv()],
         out_shape=[
             _out_struct((bh, t, dh), q.dtype, q),
-            _out_struct((bh, t, dh), jnp.float32, q),
-            _out_struct((bh, t, dh), jnp.float32, q),
+            _out_struct(k.shape, jnp.float32, q),
+            _out_struct(k.shape, jnp.float32, q),
         ],
         name=BWD_KERNEL_NAME,
         interpret=interpret,
@@ -264,7 +289,9 @@ def flash_attention(q, k, v, causal: bool = False, *,
                     interpret: bool = False):
     """Fused attention over per-head tensors ``(b, t, h, dh)`` — same
     contract as ops.attention.attention (``softmax(q·kᵀ/√dh)·v``),
-    differentiable via the flash backward kernels."""
+    differentiable via the flash backward kernels.  ``k`` and ``v`` may
+    carry fewer heads (``h`` a multiple of theirs): grouped-query
+    attention, query head ``j`` reading key/value head ``j // group``."""
     b, t, h, dh = q.shape
     why = unsupported_reason(t, dh)
     if why:
@@ -272,6 +299,7 @@ def flash_attention(q, k, v, causal: bool = False, *,
             f"flash_attention cannot take this shape: {why} — gate call "
             f"sites on ops.pallas.attention.supported() or use the dense "
             f"path")
-    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, -1)  # noqa: E731
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        b * x.shape[2], t, -1)
     o = _flash(fold(q), fold(k), fold(v), causal, interpret)
     return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)

@@ -1,7 +1,7 @@
 """Expert-parallel mixture-of-experts FFN over the ``expert`` mesh axis
 (TPU-native extension; the reference has no MoE — SURVEY.md §3.4 EP row).
 
-Two regimes (docs/TUNING.md "MoE"):
+Three regimes (docs/TUNING.md "MoE"):
 
 - :func:`moe_ffn` — tokens REPLICATED over the expert axis: dense
   masked compute (each device runs its local experts over all tokens,
@@ -10,17 +10,29 @@ Two regimes (docs/TUNING.md "MoE"):
 - :func:`moe_ffn_dispatch` — tokens SHARDED over the expert axis: the
   all_to_all token-dispatch path (each token computes once, on its
   expert's device; capacity overflow drops, switch semantics).
+- :func:`moe_routed_ffn` — this chip's SHARE of an expert-parallel
+  layer: told which experts it holds, it routes every token over all
+  experts, sorts the (token, choice) pairs by expert, runs grouped
+  products over the held experts' groups and scatters back.  No
+  capacity and no drop; what absent experts would add is left out (their
+  chips add it).  The score function, the selection bias and the
+  expert's form are arguments.  Its all-to-all form over an expert axis
+  is what would retire the two above (ROADMAP.md).
 
 :func:`load_balance_aux` is the shared switch load-balance regularizer.
 """
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from znicz_tpu.observe import probe as _probe
 
 
 def moe_ffn(x, gate_w, w1_local, b1_local, w2_local, b2_local,
@@ -148,3 +160,139 @@ def moe_ffn_dispatch(x, gate_w, w1_local, b1_local, w2_local, b2_local,
     res = back.reshape(n_experts, capacity, d)   # MY tokens' results
     out = jnp.einsum("tec,ecd->td", comb, res)
     return out, gate_probs
+
+
+def route_top_k(scores_in, bias, top_k: int, score: str = "sigmoid",
+                norm_topk: bool = True, scale: float = 1.0):
+    """Router arithmetic of :func:`moe_routed_ffn`, float32: ``scores_in``
+    ``(tokens, E)`` logits -> ``(choice (tokens, k) int32, weight
+    (tokens, k))``.  ``score`` is ``"sigmoid"`` or ``"softmax"`` of the
+    logits; selection is the top k of score plus ``bias`` (``(E,)`` or
+    None; it steers selection only and takes no gradient); the weights
+    are the selected scores themselves, with ``norm_topk`` divided by
+    their sum over ALL k selected (+1e-6), times ``scale``."""
+    logits = scores_in.astype(jnp.float32)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"router score {score!r}: sigmoid or softmax")
+    sel = s if bias is None else \
+        s + lax.stop_gradient(bias.astype(jnp.float32))
+    _, choice = lax.top_k(lax.stop_gradient(sel), top_k)
+    w = jnp.take_along_axis(s, choice, axis=1)
+    if norm_topk:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-6)
+    return choice, w * scale
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_pairs(x, token_of, slot_of, top_k: int):
+    """``x[token_of]``: each token's row once for each of its ``top_k``
+    pairs, in the pairs' sorted order.  ``slot_of`` is the inverse
+    permutation (pair ``t * top_k + j`` lies at ``slot_of[t * top_k +
+    j]``), so the gradient is a gather too: a token's row sums the
+    ``top_k`` rows at its pairs' slots, where AD's own transpose would
+    scatter-add 4 x ``tokens`` rows (2.8 ms against 0.3 at 32,768 x
+    2,048 on a v5e, my chip run, PR 28)."""
+    return x[token_of]
+
+
+def _rows_of_pairs_fwd(x, token_of, slot_of, top_k):
+    return x[token_of], slot_of
+
+
+def _rows_of_pairs_bwd(top_k, slot_of, g):
+    return _sum_of_pairs(g, None, slot_of, top_k), None, None
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_of_pairs(ys, token_of, slot_of, top_k: int):
+    """Each token's sum over its ``top_k`` pairs' rows of ``ys`` (sorted
+    pair order), accumulated in f32: the inverse of
+    :func:`_rows_of_pairs`, a gather both ways."""
+    picked = ys[slot_of].reshape(-1, top_k, ys.shape[-1])
+    return picked.sum(axis=1, dtype=jnp.float32).astype(ys.dtype)
+
+
+def _sum_of_pairs_fwd(ys, token_of, slot_of, top_k):
+    return _sum_of_pairs(ys, token_of, slot_of, top_k), token_of
+
+
+def _sum_of_pairs_bwd(top_k, token_of, g):
+    return g[token_of], None, None
+
+
+_sum_of_pairs.defvjp(_sum_of_pairs_fwd, _sum_of_pairs_bwd)
+
+
+def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
+                   score: str = "sigmoid", norm_topk: bool = True,
+                   scale: float = 1.0, act=jax.nn.silu,
+                   scope: str = "moe"):
+    """One chip's share of a routed expert layer (module docstring).
+
+    ``x`` ``(tokens, d)``; ``gate_w`` ``(d, E)`` over ALL ``E`` experts
+    and ``bias`` ``(E,)`` or None, both float32 (a selection is a
+    discrete choice: the router's product runs at the highest
+    precision); ``w1``, ``w3`` ``(held, d, f)`` and ``w2`` ``(held, f,
+    d)`` are experts ``first .. first + held``, each a gated unit ``w2
+    (act(x w1) * (x w3))``.
+
+    The ``tokens * top_k`` pairs are sorted by expert, pairs of absent
+    experts last: the sorted buffer is the static worst case (every pair
+    held) and the grouped products (``lax.ragged_dot``, on a TPU a
+    kernel that walks only the tiles its group sizes cover) never touch
+    the tail.  Each pair's result is weighted by its router weight,
+    normalised over all ``top_k`` selected experts whether held or not,
+    and summed into its token.
+
+    Returns ``(y (tokens, d), stats)``; ``stats`` holds float32 scalars
+    ``pairs_held`` (pairs routed to held experts) and
+    ``load_max_over_mean`` (the fullest held expert's pairs over the
+    held experts' mean).  The work lies under two scopes of the
+    program, ``<scope>.route`` (scores, top-k, sort, gather, scatter)
+    and ``<scope>.experts`` (the grouped products): siblings by name,
+    since an operation counts for its outermost scope."""
+    tokens, d = x.shape
+    held = w1.shape[0]
+    n_pairs = tokens * top_k
+    with _probe.scope(f"{scope}.route"):
+        logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        choice, weight = route_top_k(logits, bias, top_k, score, norm_topk,
+                                     scale)
+        local = choice.reshape(n_pairs) - first
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)             # absent: the tail
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
+        n_held = sizes.sum()
+        live = (jnp.arange(n_pairs) < n_held)[:, None]
+        token_of = order // top_k
+        slot_of = jnp.zeros(n_pairs, order.dtype).at[order].set(
+            jnp.arange(n_pairs, dtype=order.dtype))
+        # the tail's rows are nobody's, and a grouped product leaves them
+        # as it found them (whatever the memory held, NaN included): each
+        # result is cut to zeros there BEFORE it meets another factor, so
+        # that no gradient is a zero times that
+        xs = jnp.where(
+            live, _rows_of_pairs(x, token_of, slot_of, top_k), 0)
+        ws = weight.reshape(n_pairs)[order]
+    with _probe.scope(f"{scope}.experts"):
+        def grouped(a, w):
+            return jnp.where(live, lax.ragged_dot(a, w, sizes), 0)
+
+        ys = grouped(act(grouped(xs, w1)) * grouped(xs, w3), w2)
+    with _probe.scope(f"{scope}.route"):
+        ys = ys * ws[:, None].astype(ys.dtype)
+        y = _sum_of_pairs(ys, token_of, slot_of, top_k)
+        sizes_f = sizes.astype(jnp.float32)
+        stats = {"pairs_held": n_held.astype(jnp.float32),
+                 "load_max_over_mean":
+                     sizes_f.max() / jnp.maximum(sizes_f.mean(), 1e-9)}
+    return y, stats

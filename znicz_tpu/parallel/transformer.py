@@ -18,8 +18,10 @@ blocks over ``expert`` (znicz_tpu.parallel.{pipeline,moe}).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
+from collections.abc import Mapping
 
 import numpy as np
 
@@ -33,7 +35,7 @@ from znicz_tpu.parallel.compat import quantized_psum, shard_map
 from znicz_tpu.observe import probe as _probe
 from znicz_tpu.parallel import qcomm
 from znicz_tpu.parallel.moe import (load_balance_aux, moe_ffn,
-                                    router_z_loss)
+                                    moe_routed_ffn, router_z_loss)
 from znicz_tpu.parallel.pipeline import pipeline_apply
 from znicz_tpu.parallel.ring_attention import (ring_attention,
                                                ring_flash_attention)
@@ -113,94 +115,329 @@ def _default_compute_dtype(compute_dtype=None):
     return policy(jax.default_backend())
 
 
+# -- the architecture ---------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class Arch:
+    """One decoder stack, written once: what each layer mixes with, what
+    it feeds forward through, and the sizes.  Every function below reads
+    this; nothing else says what a block is.
+
+    ``mixers[i]`` is ``"attention"`` or ``"sconv"`` (a gated short
+    convolution); ``ffns[i]`` is ``"mlp"`` (biased GELU), ``"moe_dense"``
+    (:func:`moe.moe_ffn`: softmax scores, biased GELU experts sharded
+    over ``model``, every held expert computes every token), ``"glu"``
+    (bias-free SwiGLU) or ``"moe_routed"`` (:func:`moe.moe_routed_ffn`:
+    this chip's ``experts_held`` of ``n_experts`` from ``experts_first``,
+    token dispatch, no drop).  ``norm`` is ``"layer"`` (gain and bias)
+    or ``"rms"`` (gain); ``kv_heads < heads`` is grouped-query attention;
+    ``qk_norm`` puts an RMSNorm with its own gain on each head of q and
+    k; ``rope_theta`` rotates them (rotate-half, over the whole head);
+    ``final_norm`` norms the last residual stream and ``tied`` reads the
+    logits against the embedding matrix.
+
+    Built by :func:`gpt_arch` (the block this module always had: the
+    four integers) or :func:`arch_from_config` (a model's own keys)."""
+
+    d: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    ff: int
+    vocab: int
+    mixers: tuple
+    ffns: tuple
+    norm: str = "layer"
+    eps: float = 1e-5
+    qk_norm: bool = False
+    rope_theta: float | None = None
+    conv_taps: int = 0
+    n_experts: int = 0
+    experts_first: int = 0
+    experts_held: int = 0
+    top_k: int = 1
+    moe_ff: int = 0
+    score: str = "softmax"
+    expert_bias: bool = False
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    final_norm: bool = False
+    tied: bool = False
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.mixers)
+
+    def mechanisms(self) -> list:
+        """Names of what this stack has beyond the GPT-shaped block: the
+        words a refusal is made of (a mesh, ``export_lm``, ``serve/``)."""
+        out = []
+        if "sconv" in self.mixers:
+            out.append("gated short convolution")
+        if self.kv_heads != self.heads:
+            out.append("grouped-query attention")
+        if self.qk_norm:
+            out.append("QK-norm")
+        if self.rope_theta is not None:
+            out.append("rotary embedding")
+        if "glu" in self.ffns:
+            out.append("SwiGLU")
+        if "moe_routed" in self.ffns:
+            out.append("routed experts (moe_routed_ffn)")
+        if self.norm != "layer":
+            out.append("RMSNorm")
+        if self.final_norm:
+            out.append("final norm")
+        if self.tied:
+            out.append("tied embedding and head")
+        return out
+
+
+def gpt_arch(n_layers: int, d: int, heads: int, ff: int, vocab: int,
+             n_experts: int | None = None, moe_top_k: int = 1) -> Arch:
+    """The GPT-shaped stack: pre-LayerNorm, as many key/value heads as
+    query heads, no positional encoding, a biased GELU MLP (or, with
+    ``n_experts``, the dense-masked MoE FFN), an untied head."""
+    return Arch(d=int(d), heads=int(heads), kv_heads=int(heads),
+                head_dim=int(d) // int(heads), ff=int(ff), vocab=int(vocab),
+                mixers=("attention",) * int(n_layers),
+                ffns=("moe_dense" if n_experts else "mlp",) * int(n_layers),
+                n_experts=int(n_experts or 0),
+                experts_held=int(n_experts or 0), top_k=int(moe_top_k))
+
+
+_LAYER_TYPES = {"conv": "sconv", "full_attention": "attention"}
+
+
+def arch_from_config(cfg, vocab: int | None = None) -> Arch:
+    """A model's own keys -> :class:`Arch`.  Reads the ``lfm2_moe``
+    family (``layer_types``, ``num_dense_layers``, ``num_experts``,
+    ``num_experts_per_tok``, ``num_key_value_heads``, ``conv_L_cache``,
+    ``rope_parameters``, ``norm_eps``, ...): RMSNorm, gated short
+    convolutions and GQA attention with QK-norm and rotary embedding by
+    ``layer_types``, bias-free SwiGLU in the leading dense layers and
+    sigmoid-routed experts after them, a final norm and a tied head.
+    ``experts_held`` (``{"first", "count"}``; all by default) is this
+    chip's share of the experts; ``vocab`` (the loader's) overrides
+    ``vocab_size``.  Any other ``model_type`` is refused by name."""
+    kind = cfg.get("model_type", "lfm2_moe" if "layer_types" in cfg else None)
+    if kind != "lfm2_moe":
+        raise ValueError(f"model_type {kind!r}: this stack reads lfm2_moe "
+                         f"configurations and the GPT-shaped integers")
+    if cfg.get("conv_bias", False):
+        raise ValueError("conv_bias: the short convolution here has none")
+    types = list(cfg["layer_types"])
+    if int(cfg.get("num_hidden_layers", len(types))) != len(types):
+        raise ValueError(f"num_hidden_layers {cfg['num_hidden_layers']} "
+                         f"against {len(types)} layer_types")
+    unknown = sorted(set(types) - set(_LAYER_TYPES))
+    if unknown:
+        raise ValueError(f"layer_types {unknown}: conv or full_attention")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    n_dense = int(cfg.get("num_dense_layers", 0))
+    n_experts = int(cfg.get("num_experts", 0))
+    held = cfg.get("experts_held") or {"first": 0, "count": n_experts}
+    first, count = int(held["first"]), int(held["count"])
+    if first < 0 or count < 1 or first + count > max(n_experts, 1):
+        raise ValueError(f"experts_held {held} of {n_experts} experts")
+    rope = cfg.get("rope_parameters") or {}
+    return Arch(
+        d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=int(cfg.get("head_dim") or d // heads),
+        ff=int(cfg["intermediate_size"]),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=tuple(_LAYER_TYPES[t] for t in types),
+        ffns=tuple("glu" if i < n_dense or not n_experts else "moe_routed"
+                   for i in range(len(types))),
+        norm="rms", eps=float(cfg.get("norm_eps", 1e-5)), qk_norm=True,
+        rope_theta=float(rope.get("rope_theta", cfg.get("rope_theta", 1e6))),
+        conv_taps=int(cfg.get("conv_L_cache", 3)), n_experts=n_experts,
+        experts_first=first, experts_held=count,
+        top_k=int(cfg.get("num_experts_per_tok", 1)),
+        moe_ff=int(cfg.get("moe_intermediate_size", 0)), score="sigmoid",
+        expert_bias=bool(cfg.get("use_expert_bias", False)),
+        norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        final_norm=True, tied=bool(cfg.get("tie_word_embeddings", True)))
+
+
+def as_arch(arch, d=None, heads=None, ff=None, vocab=None,
+            n_experts=None, moe_top_k: int = 1) -> Arch:
+    """What every factory below takes first: an :class:`Arch`, a model's
+    configuration mapping, or the GPT-shaped block's ``n_layers`` followed
+    by ``d, heads, ff, vocab``."""
+    if isinstance(arch, Arch):
+        return arch
+    if isinstance(arch, Mapping):
+        return arch_from_config(arch, vocab)
+    return gpt_arch(arch, d, heads, ff, vocab, n_experts, moe_top_k)
+
+
+#: a leaf only a layer kind beyond the GPT-shaped block has -> its name
+_LEAF_MECHANISMS = {
+    "w_in": "gated short convolution", "q_g": "QK-norm",
+    "w3": "SwiGLU", "ew3": "routed experts (moe_routed_ffn)",
+}
+
+
+def mechanisms_of_params(params) -> list:
+    """The names (:meth:`Arch.mechanisms`) of what a params pytree holds
+    beyond the GPT-shaped block, read from its leaves: what ``serve/``
+    and ``export_lm`` refuse with."""
+    out = []
+    for blk in params["blocks"]:
+        for leaf, name in _LEAF_MECHANISMS.items():
+            if leaf in blk and name not in out:
+                out.append(name)
+        if "wk" in blk and np.shape(blk["wk"]) != np.shape(blk["wq"]) and \
+                "grouped-query attention" not in out:
+            out.append("grouped-query attention")
+    if "norm_g" in params:
+        out.append("final norm")
+    if "head" not in params:
+        out.append("tied embedding and head")
+    return out
+
+
+def _layer_shapes(arch: Arch, i: int) -> dict:
+    """``{leaf: shape}`` of layer ``i``: the one table the initialiser,
+    the specs and the shapes are read from."""
+    d, hd = arch.d, arch.head_dim
+    bias = arch.norm == "layer"
+    out = {"ln1_g": (d,), "ln2_g": (d,)}
+    if bias:
+        out.update({"ln1_b": (d,), "ln2_b": (d,)})
+    if arch.mixers[i] == "attention":
+        out.update({"wq": (d, arch.heads * hd), "wk": (d, arch.kv_heads * hd),
+                    "wv": (d, arch.kv_heads * hd), "wo": (arch.heads * hd, d)})
+        if arch.qk_norm:
+            out.update({"q_g": (hd,), "k_g": (hd,)})
+    else:
+        out.update({"w_in": (d, 3 * d), "conv_k": (arch.conv_taps, d),
+                    "w_out": (d, d)})
+    ffn = arch.ffns[i]
+    if ffn == "mlp":
+        out.update({"w1": (d, arch.ff), "b1": (arch.ff,),
+                    "w2": (arch.ff, d), "b2": (d,)})
+    elif ffn == "glu":
+        out.update({"w1": (d, arch.ff), "w3": (d, arch.ff),
+                    "w2": (arch.ff, d)})
+    elif ffn == "moe_dense":
+        e = arch.n_experts
+        out.update({"gate": (d, e), "ew1": (e, d, arch.ff),
+                    "eb1": (e, arch.ff), "ew2": (e, arch.ff, d),
+                    "eb2": (e, d)})
+    else:
+        e, f = arch.experts_held, arch.moe_ff
+        out.update({"gate": (d, arch.n_experts), "ew1": (e, d, f),
+                    "ew3": (e, d, f), "ew2": (e, f, d)})
+        if arch.expert_bias:
+            out["ebias"] = (arch.n_experts,)
+    return out
+
+
+#: leaves that start at one (gains), and those that start at zero
+_ONES = ("ln1_g", "ln2_g", "q_g", "k_g", "norm_g")
+_ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias")
+#: how each leaf of the GPT-shaped block lies over the ``model`` axis
+_TP_SPECS = {
+    "wq": P(None, "model"), "wk": P(None, "model"), "wv": P(None, "model"),
+    "wo": P("model", None), "w1": P(None, "model"), "b1": P("model"),
+    "w2": P("model", None), "ew1": P("model", None, None),
+    "eb1": P("model", None), "ew2": P("model", None, None),
+    "eb2": P("model", None),
+}
+
+
 # -- dp x sp x tp flagship --------------------------------------------------
-def init_params(gen, n_layers: int, d: int, heads: int, ff: int,
-                vocab: int, n_experts: int | None = None):
-    """Global (unsharded) parameter pytree from the framework PRNG.
-    ``n_experts`` swaps each block's dense FFN for a top-1 MoE FFN
-    (gate + per-expert w1/b1/w2/b2 stacks, expert-sharded over the
-    ``model`` axis at placement time)."""
+def init_params(gen, arch, d=None, heads=None, ff=None, vocab=None,
+                n_experts: int | None = None):
+    """Global (unsharded) parameter pytree from the framework PRNG, for
+    ``arch`` (:func:`as_arch`: an :class:`Arch`, a configuration mapping,
+    or ``n_layers, d, heads, ff, vocab`` of the GPT-shaped block, where
+    ``n_experts`` swaps each block's dense FFN for the dense-masked MoE
+    FFN: gate + per-expert w1/b1/w2/b2 stacks, expert-sharded over the
+    ``model`` axis at placement time).  Projections are normal
+    ``1/sqrt(fan_in)``, the embedding normal 0.02, gains one, biases
+    zero; the short convolution's taps are normal ``1/sqrt(taps)``."""
+    arch = as_arch(arch, d, heads, ff, vocab, n_experts)
+
     def w(shape, scale=None):
         scale = scale or 1.0 / np.sqrt(shape[-2] if len(shape) > 1
                                        else shape[0])
         return gen.normal(0.0, scale, shape).astype(np.float32)
 
+    def leaf(name, shape):
+        if name in _ONES:
+            return np.ones(shape, np.float32)
+        if name in _ZEROS:
+            return np.zeros(shape, np.float32)
+        if name == "conv_k":
+            return w(shape, 1.0 / np.sqrt(shape[0]))
+        return w(shape)
+
+    if arch.n_layers and set(arch.ffns) <= {"mlp", "moe_dense"} and \
+            set(arch.mixers) == {"attention"} and arch.norm == "layer":
+        # the GPT-shaped block draws in the order it always drew in
+        # (seeded runs and their pins follow the generator's stream)
+        order = ("ln1_g", "ln1_b", "wq", "wk", "wv", "wo", "ln2_g", "ln2_b",
+                 "gate", "ew1", "eb1", "ew2", "eb2", "w1", "b1", "w2", "b2")
+    else:
+        order = None
     blocks = []
-    for _ in range(n_layers):
-        blk = {
-            "ln1_g": np.ones(d, np.float32), "ln1_b": np.zeros(d, np.float32),
-            "wq": w((d, d)), "wk": w((d, d)), "wv": w((d, d)), "wo": w((d, d)),
-            "ln2_g": np.ones(d, np.float32), "ln2_b": np.zeros(d, np.float32),
-        }
-        if n_experts:
-            blk.update({
-                "gate": w((d, n_experts)),
-                "ew1": w((n_experts, d, ff)),
-                "eb1": np.zeros((n_experts, ff), np.float32),
-                "ew2": w((n_experts, ff, d)),
-                "eb2": np.zeros((n_experts, d), np.float32),
-            })
-        else:
-            blk.update({
-                "w1": w((d, ff)), "b1": np.zeros(ff, np.float32),
-                "w2": w((ff, d)), "b2": np.zeros(d, np.float32),
-            })
-        blocks.append(blk)
-    return {"emb": w((vocab, d), 0.02), "head": w((d, vocab)),
-            "blocks": blocks}
+    for i in range(arch.n_layers):
+        shapes = _layer_shapes(arch, i)
+        names = [k for k in order if k in shapes] if order else list(shapes)
+        blocks.append({k: leaf(k, shapes[k]) for k in names})
+    out = {"emb": w((arch.vocab, arch.d), 0.02)}
+    if not arch.tied:
+        out["head"] = w((arch.d, arch.vocab))
+    out["blocks"] = blocks
+    if arch.final_norm:
+        out["norm_g"] = np.ones(arch.d, np.float32)
+    return out
 
 
-def param_specs(n_layers: int, head_sharded: bool = False,
-                moe: bool = False):
+def param_specs(arch, head_sharded: bool = False, moe: bool = False):
     """PartitionSpecs matching init_params: attention qkv column-sharded,
     wo row-sharded, MLP Megatron-sharded over ``model``; the rest
     replicated.  ``head_sharded`` vocab-shards the LM head over
     ``model`` (Megatron parallel cross-entropy — pair with
-    ``make_train_step(head_sharded=True)``).  ``moe`` selects the
-    expert-parallel FFN layout: expert stacks sharded over ``model`` on
-    the expert dim, gate replicated."""
-    blk = {
-        "ln1_g": P(), "ln1_b": P(),
-        "wq": P(None, "model"), "wk": P(None, "model"),
-        "wv": P(None, "model"), "wo": P("model", None),
-        "ln2_g": P(), "ln2_b": P(),
-    }
-    if moe:
-        blk.update({
-            "gate": P(),
-            "ew1": P("model", None, None), "eb1": P("model", None),
-            "ew2": P("model", None, None), "eb2": P("model", None),
-        })
-    else:
-        blk.update({
-            "w1": P(None, "model"), "b1": P("model"),
-            "w2": P("model", None), "b2": P(),
-        })
-    head = P(None, "model") if head_sharded else P()
-    return {"emb": P(), "head": head, "blocks": [dict(blk)] * n_layers}
+    ``make_train_step(head_sharded=True)``).  ``arch`` is an
+    :class:`Arch`, or the GPT-shaped block's ``n_layers`` with ``moe``
+    selecting the expert-parallel FFN layout (expert stacks sharded over
+    ``model`` on the expert dim, gate replicated).  The leaves of the
+    layer kinds that run on no ``model`` axis are replicated."""
+    if not isinstance(arch, Arch):
+        arch = gpt_arch(arch, 1, 1, 1, 1, n_experts=1 if moe else None)
+    gpt = not arch.mechanisms()
+    blocks = [{k: _TP_SPECS.get(k, P()) if gpt else P()
+               for k in _layer_shapes(arch, i)}
+              for i in range(arch.n_layers)]
+    out = {"emb": P()}
+    if not arch.tied:
+        out["head"] = P(None, "model") if head_sharded else P()
+    out["blocks"] = blocks
+    if arch.final_norm:
+        out["norm_g"] = P()
+    return out
 
 
-def param_shapes(n_layers: int, d: int, ff: int, vocab: int,
+def param_shapes(arch, d=None, ff=None, vocab=None,
                  n_experts: int | None = None):
     """Shape pytree mirroring :func:`init_params` — the static ``like``
     information the shard_params gather chain needs (a flat-sharded
-    leaf has lost its original shape)."""
-    blk = {
-        "ln1_g": (d,), "ln1_b": (d,),
-        "wq": (d, d), "wk": (d, d), "wv": (d, d), "wo": (d, d),
-        "ln2_g": (d,), "ln2_b": (d,),
-    }
-    if n_experts:
-        blk.update({
-            "gate": (d, n_experts),
-            "ew1": (n_experts, d, ff), "eb1": (n_experts, ff),
-            "ew2": (n_experts, ff, d), "eb2": (n_experts, d),
-        })
-    else:
-        blk.update({"w1": (d, ff), "b1": (ff,),
-                    "w2": (ff, d), "b2": (d,)})
-    return {"emb": (vocab, d), "head": (d, vocab),
-            "blocks": [dict(blk)] * n_layers}
+    leaf has lost its original shape).  ``arch`` is an :class:`Arch`,
+    or ``n_layers, d, ff, vocab`` of the GPT-shaped block (no shape of
+    which depends on the head count)."""
+    if not isinstance(arch, Arch):
+        arch = gpt_arch(arch, d, 1, ff, vocab, n_experts)
+    out = {"emb": (arch.vocab, arch.d)}
+    if not arch.tied:
+        out["head"] = (arch.d, arch.vocab)
+    out["blocks"] = [_layer_shapes(arch, i) for i in range(arch.n_layers)]
+    if arch.final_norm:
+        out["norm_g"] = (arch.d,)
+    return out
 
 
 def _spec_leaves(specs):
@@ -258,57 +495,133 @@ def unshard_params_host(params, specs, shapes):
     return jax.tree.unflatten(treedef, out)
 
 
-def _block(x, p, heads_local: int, causal: bool, use_flash: bool = False,
-           interpret: bool = False, use_ring_flash: bool = False,
-           moe_top_k: int = 1, moe_aux_weight: float = 0.0,
-           moe_zloss_weight: float = 0.0, index: int = 0):
-    """One transformer block on local shards: ring attention (seq axis)
-    with tp-sharded heads, then Megatron MLP (model axis).  With the seq
-    axis unsharded, ``use_flash`` swaps the attention core for the Pallas
-    flash kernel (ops/pallas/attention.py) — same math, no (t, t) score
-    matrix in HBM.  ``interpret`` is captured at step-build time along
-    with ``use_flash`` so one config snapshot governs all three
-    flash-related decisions (kernel choice, interpreter, vma mode).
-    ``index`` only names the block's two scopes, ``block<index>.attn``
-    and ``block<index>.mlp``."""
-    with _probe.scope(f"block{index}.attn"):
-        x = _block_attn(x, p, heads_local, causal, use_flash, interpret,
-                        use_ring_flash)
+@dataclasses.dataclass(frozen=True)
+class _Run:
+    """What a step build fixes beside the architecture: the local head
+    counts, the attention core, the regularizer weights.  ``use_flash``,
+    ``interpret`` and ``use_ring_flash`` are captured together at
+    step-build time so one config snapshot governs all three
+    flash-related decisions (kernel choice, interpreter, vma mode)."""
+
+    heads_local: int
+    kv_heads_local: int
+    causal: bool = True
+    use_flash: bool = False
+    interpret: bool = False
+    use_ring_flash: bool = False
+    moe_aux_weight: float = 0.0
+    moe_zloss_weight: float = 0.0
+
+
+def _rms_norm(x, g, eps):
+    # the statistic in f32, as _layer_norm's
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
+    return y.astype(x.dtype) * g
+
+
+def _norm(x, p, which: str, arch: Arch):
+    if arch.norm == "rms":
+        return _rms_norm(x, p[which + "_g"], arch.eps)
+    return _layer_norm(x, p[which + "_g"], p[which + "_b"], arch.eps)
+
+
+def _rotate(x, theta: float):
+    """Rotary embedding over the whole head of ``x (b, t, h, dh)``,
+    rotate-half form, positions from 0 (the seq axis is unsharded
+    wherever this runs), in f32."""
+    t, dh = x.shape[1], x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    half = jnp.concatenate([-xf[..., dh // 2:], xf[..., :dh // 2]], axis=-1)
+    return (xf * cos + half * sin).astype(x.dtype)
+
+
+def _block(x, p, arch: Arch, run: _Run, index: int = 0):
+    """Layer ``index`` of ``arch`` on local shards: its mixer, then its
+    feed-forward, each reading a norm of the residual stream and adding
+    to it.  -> ``(x, aux, stats)``: the regularizer term (pre-weighted)
+    and the routed layer's counters.  Scopes: ``block<index>.attn`` or
+    ``.sconv``, then ``block<index>.mlp`` or ``.moe`` (with ``.moe.route``
+    and ``.moe.experts`` beside it)."""
+    if arch.mixers[index] == "attention":
+        with _probe.scope(f"block{index}.attn"):
+            x = _block_attn(x, p, arch, run)
+    else:
+        with _probe.scope(f"block{index}.sconv"):
+            x = _block_sconv(x, p, arch)
+    if arch.ffns[index] == "moe_routed":
+        return _block_routed(x, p, arch, f"block{index}.moe")
     with _probe.scope(f"block{index}.mlp"):
-        return _block_mlp(x, p, moe_top_k, moe_aux_weight,
-                          moe_zloss_weight)
+        x, aux = _block_mlp(x, p, arch, arch.ffns[index], run)
+    return x, aux, {}
 
 
-def _block_attn(x, p, heads_local, causal, use_flash, interpret,
-                use_ring_flash):
-    h = _layer_norm(x, p["ln1_g"], p["ln1_b"])
+def _block_attn(x, p, arch: Arch, run: _Run):
+    """Attention with tp-sharded heads: ring attention over the seq axis;
+    with the seq axis unsharded, ``run.use_flash`` swaps the core for the
+    Pallas flash kernel (ops/pallas/attention.py) — same math, no (t, t)
+    score matrix in HBM.  Fewer key/value heads than query heads go to
+    the flash kernel as they are (its index maps take the group) and to
+    the dense core repeated."""
+    h = _norm(x, p, "ln1", arch)
     b, t_loc, _ = h.shape
 
-    def heads_of(w):
+    def heads_of(w, n):
         y = h @ w                                    # (b, t_loc, d_local)
-        return y.reshape(b, t_loc, heads_local, -1)
+        return y.reshape(b, t_loc, n, -1)
 
-    q, k, v = heads_of(p["wq"]), heads_of(p["wk"]), heads_of(p["wv"])
+    q = heads_of(p["wq"], run.heads_local)
+    k = heads_of(p["wk"], run.kv_heads_local)
+    v = heads_of(p["wv"], run.kv_heads_local)
+    if arch.qk_norm:
+        q = _rms_norm(q, p["q_g"], arch.eps)
+        k = _rms_norm(k, p["k_g"], arch.eps)
+    if arch.rope_theta is not None:
+        q, k = _rotate(q, arch.rope_theta), _rotate(k, arch.rope_theta)
     from znicz_tpu.ops.pallas import attention as pattn
     why = pattn.unsupported_reason(t_loc, q.shape[-1]) \
-        if use_flash or use_ring_flash else None
+        if run.use_flash or run.use_ring_flash else None
     if why:
         _report_flash_refusal(t_loc, q.shape[-1], why)
-    if use_flash and not why:
-        o = pattn.flash_attention(q, k, v, causal=causal,
-                                  interpret=interpret)
-    elif use_ring_flash and not why:
-        o = ring_flash_attention(q, k, v, "seq", causal=causal,
-                                 interpret=interpret)
+    if run.use_flash and not why:
+        o = pattn.flash_attention(q, k, v, causal=run.causal,
+                                  interpret=run.interpret)
     else:
-        o = ring_attention(q, k, v, "seq", causal=causal)
+        group = run.heads_local // run.kv_heads_local
+        if group > 1:
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        if run.use_ring_flash and not why:
+            o = ring_flash_attention(q, k, v, "seq", causal=run.causal,
+                                     interpret=run.interpret)
+        else:
+            o = ring_attention(q, k, v, "seq", causal=run.causal)
     o = o.reshape(b, t_loc, -1)                      # (b, t_loc, d_local)
     return x + tp.row_parallel(o, p["wo"], None, "model")
 
 
-def _block_mlp(x, p, moe_top_k, moe_aux_weight, moe_zloss_weight):
-    m = _layer_norm(x, p["ln2_g"], p["ln2_b"])
-    if "ew1" in p:
+def _block_sconv(x, p, arch: Arch):
+    """Gated short convolution: ``[B, C, X] = split3(u W_in)``; ``z = B *
+    X``; a depthwise causal convolution over time, ``conv_taps`` taps a
+    channel, zeros before the sequence starts (``c_t = sum_j k_j
+    z_{t-taps+1+j}``, accumulated in f32); ``out = (C * c) W_out``."""
+    u = _norm(x, p, "ln1", arch)
+    t = u.shape[1]
+    gate_b, gate_c, xin = jnp.split(u @ p["w_in"], 3, axis=-1)
+    z = (gate_b * xin).astype(jnp.float32)
+    taps = arch.conv_taps
+    zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    kf = p["conv_k"].astype(jnp.float32)
+    c = sum(kf[j] * zp[:, j:j + t] for j in range(taps))
+    return x + (gate_c * c.astype(x.dtype)) @ p["w_out"]
+
+
+def _block_mlp(x, p, arch: Arch, ffn: str, run: _Run):
+    m = _norm(x, p, "ln2", arch)
+    if ffn == "moe_dense":
         # expert-parallel MoE FFN over the model axis (the block's FFN
         # capacity scales with experts instead of Megatron-splitting ff)
         d = m.shape[-1]
@@ -316,40 +629,71 @@ def _block_mlp(x, p, moe_top_k, moe_aux_weight, moe_zloss_weight):
         y2d, probs = moe_ffn(m2d, p["gate"], p["ew1"],
                              p["eb1"], p["ew2"], p["eb2"],
                              jax.nn.gelu, axis_name="model",
-                             top_k=moe_top_k)
+                             top_k=arch.top_k)
         x = x + y2d.reshape(m.shape)
         # regularizers pre-weighted here (weights are static floats), so
         # the accumulator upstream stays a single scalar.  The z-loss's
         # scores GEMM is identical to moe_ffn's internal one — XLA CSEs
         # them under jit
-        aux = moe_aux_weight * load_balance_aux(probs)
-        if moe_zloss_weight:
-            aux = aux + moe_zloss_weight * router_z_loss(m2d @ p["gate"])
+        aux = run.moe_aux_weight * load_balance_aux(probs)
+        if run.moe_zloss_weight:
+            aux = aux + run.moe_zloss_weight * router_z_loss(
+                m2d @ p["gate"])
         return x, aux
+    if ffn == "glu":
+        y = (jax.nn.silu(m @ p["w1"]) * (m @ p["w3"])) @ p["w2"]
+        return x + y, jnp.zeros((), jnp.float32)
     x = x + tp.mlp(m, p["w1"], p["b1"], p["w2"], p["b2"],
                    jax.nn.gelu, "model")
     return x, jnp.zeros((), jnp.float32)
 
 
-def _check_tp(mesh: Mesh, heads: int, d: int, ff: int,
-              vocab_sharded: int | None = None,
-              n_experts: int | None = None) -> int:
+def _block_routed(x, p, arch: Arch, scope: str):
+    """This chip's share of a routed expert layer
+    (:func:`moe.moe_routed_ffn`); the norm and the residual sum lie
+    under ``scope``, the layer's two parts under ``scope.route`` and
+    ``scope.experts``."""
+    with _probe.scope(scope):
+        m = _norm(x, p, "ln2", arch)
+    y, stats = moe_routed_ffn(
+        m.reshape(-1, m.shape[-1]), p["gate"], p.get("ebias"), p["ew1"],
+        p["ew3"], p["ew2"], first=arch.experts_first, top_k=arch.top_k,
+        score=arch.score, norm_topk=arch.norm_topk,
+        scale=arch.routed_scale, scope=scope)
+    with _probe.scope(scope):
+        return x + y.reshape(m.shape), jnp.zeros((), jnp.float32), stats
+
+
+def _check_tp(mesh: Mesh, arch: Arch,
+              vocab_sharded: int | None = None) -> tuple:
+    """-> local ``(heads, key/value heads)`` on this mesh, or a refusal.
+    The layer kinds beyond the GPT-shaped block run where the ``seq``
+    and ``model`` axes are 1, and refuse any other mesh by name."""
     tp_size = mesh.shape["model"]
+    extra = arch.mechanisms()
+    if extra and (tp_size != 1 or mesh.shape.get("seq", 1) != 1):
+        raise ValueError(
+            f"{', '.join(extra)}: no sharding over the seq or model axis "
+            f"is written for these (mesh {dict(mesh.shape)}); run them on "
+            f"a mesh whose seq and model axes are 1")
+    if extra and vocab_sharded is not None:
+        raise ValueError(f"head_sharded with {', '.join(extra)}")
+    heads, d = arch.heads, arch.d
     if heads % tp_size or d % tp_size:
         raise ValueError(f"tp={tp_size} must divide heads={heads} "
                          f"and d={d}")
     # the MoE FFN shards the EXPERT dim, never ff; the dense FFN
     # Megatron-splits ff
-    if n_experts:
-        if n_experts % tp_size:
-            raise ValueError(f"n_experts={n_experts} must divide by "
+    if "moe_dense" in arch.ffns:
+        if arch.n_experts % tp_size:
+            raise ValueError(f"n_experts={arch.n_experts} must divide by "
                              f"tp={tp_size}")
-    elif ff % tp_size:
-        raise ValueError(f"tp={tp_size} must divide ff={ff}")
+    elif arch.ff % tp_size:
+        raise ValueError(f"tp={tp_size} must divide ff={arch.ff}")
     if vocab_sharded is not None and vocab_sharded % tp_size:
         raise ValueError(f"head_sharded needs vocab={vocab_sharded} "
                          f"divisible by tp={tp_size}")
-    return heads // tp_size
+    return heads // tp_size, arch.kv_heads // tp_size
 
 
 def _dense_chunk_nll(head):
@@ -441,20 +785,41 @@ _REMAT_POLICIES = {
 }
 
 
-def _forward_hidden(ps, tokens, heads_local, causal, use_flash,
-                    interp, cdt, remat: bool = False,
-                    use_ring_flash: bool = False,
-                    moe_aux_weight: float = 0.0,
-                    moe_top_k: int = 1,
-                    remat_policy: str | None = None,
-                    moe_zloss_weight: float = 0.0):
+def _cast_params(ps, arch: Arch, cdt):
+    """The forward's view of the params in the compute dtype.  A routed
+    layer's router (``gate``, ``ebias``) stays in the master dtype: it
+    makes a discrete choice, and :func:`moe.moe_routed_ffn` takes its
+    product at the highest precision."""
+    out = jax.tree.map(lambda w: w.astype(cdt), ps)
+    for i, ffn in enumerate(arch.ffns):
+        if ffn == "moe_routed":
+            for k in ("gate", "ebias"):
+                if k in ps["blocks"][i]:
+                    out["blocks"][i][k] = ps["blocks"][i][k]
+    return out
+
+
+def _head_of(ps, arch: Arch):
+    """The ``(d, vocab)`` matrix the logits are read against."""
+    return ps["emb"].T if arch.tied else ps["head"]
+
+
+def _sum_stats(a: dict, b: dict) -> dict:
+    return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in {**a, **b}}
+
+
+def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
+                    remat: bool = False,
+                    remat_policy: str | None = None):
     """Embedding + block stack — the ONE pre-head forward body, shared
     by the CE loss (:func:`_forward_ce`) and the full-pass logits oracle
     (:func:`make_logits_fn`, the generative serving plane's correctness
-    anchor).  Returns ``(x, aux_term, ps_cast)`` — the hidden states,
-    the summed MoE regularizer term, and the compute-dtype-cast params
-    (so the caller's head matmul uses the same precision policy)."""
-    ps = jax.tree.map(lambda w: w.astype(cdt), ps)
+    anchor).  Returns ``(x, aux_term, ps_cast, stats)`` — the hidden
+    states (through the final norm where the stack has one), the summed
+    MoE regularizer term, the compute-dtype-cast params (so the caller's
+    head matmul uses the same precision policy) and the routed layers'
+    counters summed over the layers (``load_max_over_mean`` their mean)."""
+    ps = _cast_params(ps, arch, cdt)
     with _probe.scope("embed"):
         x = ps["emb"][tokens]                     # (b_l, t_l, d)
     blk = _block
@@ -462,33 +827,35 @@ def _forward_hidden(ps, tokens, heads_local, causal, use_flash,
         pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
         blk = jax.checkpoint(
             _block, policy=pol,
-            static_argnums=(2, 3, 4, 5, 6, 7,
-                            8, 9, 10))  # type: ignore[assignment]
+            static_argnums=(2, 3, 4))  # type: ignore[assignment]
     # regularizer weights apply inside _block (per-block pre-weighted)
     aux_term = jnp.zeros((), jnp.float32)
+    stats: dict = {}
     for i, p in enumerate(ps["blocks"]):
-        x, aux = blk(x, p, heads_local, causal, use_flash, interp,
-                     use_ring_flash, moe_top_k, moe_aux_weight,
-                     moe_zloss_weight, i)
+        x, aux, st = blk(x, p, arch, run, i)
         aux_term = aux_term + aux
-    return x, aux_term, ps
+        stats = _sum_stats(stats, st)
+    if "load_max_over_mean" in stats:
+        stats["load_max_over_mean"] = stats["load_max_over_mean"] / \
+            arch.ffns.count("moe_routed")
+    if arch.final_norm:
+        with _probe.scope("ce"):
+            x = _rms_norm(x, ps["norm_g"], arch.eps)
+    return x, aux_term, ps, stats
 
 
-def _forward_ce(ps, tokens, labels, mask, heads_local, causal, use_flash,
-                interp, cdt, remat: bool = False,
+def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
+                remat: bool = False,
                 loss_chunks: int | None = None,
-                use_ring_flash: bool = False,
                 head_sharded: bool = False,
-                moe_aux_weight: float = 0.0,
-                moe_top_k: int = 1,
                 remat_policy: str | None = None,
-                moe_zloss_weight: float = 0.0,
                 reduce: bool = True):
     """The ONE forward + CE-loss body (shared by the train step's loss_fn
-    and the eval pass, so their numerics can never drift).  ``mask`` is a
+    and the eval pass, so their numerics can never drift); -> ``(loss,
+    stats)``, the routed layers' counters beside the loss.  ``mask`` is a
     per-row validity mask or None; masked rows (the loader's padded tail)
     contribute neither loss nor — through AD — gradients, the framework's
-    padding contract (loader/base.py).  ``moe_aux_weight`` scales the
+    padding contract (loader/base.py).  ``run.moe_aux_weight`` scales the
     MoE blocks' summed load-balance aux into the loss (local-mean
     convention, same psum as the CE term; PADDED rows do count toward
     the routing statistics — the aux is a regularizer, not a metric).
@@ -500,13 +867,10 @@ def _forward_ce(ps, tokens, labels, mask, heads_local, causal, use_flash,
     uses it to differentiate a local loss and route the gradient
     reduction through the explicit quantized psum instead of AD's
     psum transpose."""
-    x, aux_term, ps = _forward_hidden(
-        ps, tokens, heads_local, causal, use_flash, interp, cdt,
-        remat=remat, use_ring_flash=use_ring_flash,
-        moe_aux_weight=moe_aux_weight, moe_top_k=moe_top_k,
-        remat_policy=remat_policy, moe_zloss_weight=moe_zloss_weight)
-    return _ce_from_hidden(x, ps["head"], labels, mask, aux_term,
-                           loss_chunks, head_sharded, reduce)
+    x, aux_term, ps, stats = _forward_hidden(
+        ps, tokens, arch, run, cdt, remat=remat, remat_policy=remat_policy)
+    return _ce_from_hidden(x, _head_of(ps, arch), labels, mask, aux_term,
+                           loss_chunks, head_sharded, reduce), stats
 
 
 @_probe.scoped("ce")
@@ -557,8 +921,23 @@ def _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
         jnp.maximum(total, 1.0) + lax.psum(aux_term, ("data", "seq"))
 
 
-def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
-                    vocab: int, lr: float = 0.1, causal: bool = True,
+def _run_of(mesh: Mesh, arch: Arch, causal: bool,
+            vocab_sharded: int | None = None, moe_aux_weight: float = 0.0,
+            moe_zloss_weight: float = 0.0) -> _Run:
+    """The step build's snapshot: local head counts on this mesh (or a
+    refusal) and the attention core the config and the mesh allow."""
+    heads_local, kv_local = _check_tp(mesh, arch, vocab_sharded)
+    from znicz_tpu.core.config import root as root_cfg
+    interp = bool(root_cfg.common.engine.get("pallas_interpret", False))
+    return _Run(heads_local, kv_local, causal,
+                use_flash=_flash_eligible(mesh, interp), interpret=interp,
+                use_ring_flash=_ring_flash_eligible(mesh, interp),
+                moe_aux_weight=float(moe_aux_weight),
+                moe_zloss_weight=float(moe_zloss_weight))
+
+
+def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
+                    vocab=None, lr: float = 0.1, causal: bool = True,
                     compute_dtype=None, shard_update: bool = False,
                     shard_params: bool = False,
                     masked: bool = False, donate: bool = False,
@@ -569,10 +948,18 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
                     moe_top_k: int = 1,
                     remat_policy: str | None = None,
                     moe_zloss_weight: float = 0.0,
-                    quantized_collectives: dict | None = None):
+                    quantized_collectives: dict | None = None,
+                    stats: bool = False):
     """-> jitted ``step(params, tokens, labels) -> (params, loss)``
     (``masked=True``: ``step(params, tokens, labels, mask)`` with a
-    per-row bool mask — padded loader rows train nothing).
+    per-row bool mask — padded loader rows train nothing;
+    ``stats=True``: ``-> (params, loss, stats)`` with the routed expert
+    layers' counters of the step, float32 scalars, an empty dict for a
+    stack that has none).
+
+    ``arch`` says what the stack is (:func:`as_arch`): an :class:`Arch`,
+    a model's configuration mapping, or, as ever, the GPT-shaped block's
+    ``n_layers`` followed by ``d, heads, ff, vocab``.
 
     ``donate=True`` donates the params buffers to the step (the training
     loop's natural contract — the caller rebinds; the old pytree is dead
@@ -660,18 +1047,16 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
             "shard_params subsumes shard_update (replicated leaves "
             "persist sharded and update in place — there is no "
             "regather left to split); pass only one")
-    heads_local = _check_tp(mesh, heads, d, ff,
-                            vocab if head_sharded else None, n_experts)
+    arch = as_arch(arch, d, heads, ff, vocab, n_experts, moe_top_k)
     if remat_policy is not None and remat_policy not in _REMAT_POLICIES:
         raise ValueError(f"remat_policy={remat_policy!r} — choose from "
                          f"{sorted(_REMAT_POLICIES)}")
-    specs = param_specs(n_layers, head_sharded, moe=bool(n_experts))
+    run = _run_of(mesh, arch, causal, arch.vocab if head_sharded else None,
+                  moe_aux_weight, moe_zloss_weight)
+    specs = param_specs(arch, head_sharded)
     cdt = _default_compute_dtype(compute_dtype)
     from znicz_tpu.core.config import root as root_cfg
-    interp = bool(root_cfg.common.engine.get("pallas_interpret", False))
-    use_flash = _flash_eligible(mesh, interp)
-    use_ring_flash = _ring_flash_eligible(mesh, interp)
-    if use_ring_flash and interp:
+    if run.use_ring_flash and run.interpret:
         # eval-only mode: interpret-Pallas needs check_vma=False at
         # seq>1, which corrupts replicated-param gradient reduction
         # (docs/TUNING.md "Ring×flash" §3) — refuse to build a silently
@@ -683,7 +1068,7 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
             "engine.flash_attention=False (dense ring) in interpret "
             "mode, or run compiled on TPU.")
     n_data = mesh.shape["data"]
-    shapes = param_shapes(n_layers, d, ff, vocab, n_experts=n_experts)
+    shapes = param_shapes(arch)
     step_specs = shard_params_specs(specs) if shard_params else specs
     via_psum = bool(root_cfg.common.engine.get("zero_gather_via_psum",
                                                False))
@@ -722,18 +1107,14 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
             full_params = params
 
         def loss_fn(ps):
-            return _forward_ce(ps, tokens, labels, mask, heads_local,
-                               causal, use_flash, interp, cdt,
+            return _forward_ce(ps, tokens, labels, mask, arch, run, cdt,
                                remat=remat, loss_chunks=loss_chunks,
-                               use_ring_flash=use_ring_flash,
                                head_sharded=head_sharded,
-                               moe_aux_weight=moe_aux_weight,
-                               moe_top_k=moe_top_k,
                                remat_policy=remat_policy,
-                               moe_zloss_weight=moe_zloss_weight,
                                reduce=codec is None)
 
-        loss, grads = jax.value_and_grad(loss_fn)(full_params)
+        (loss, counters), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(full_params)
         if codec is not None:
             # quantized mode differentiates the LOCAL loss and reduces
             # every grad leaf (replicated AND tensor-sharded — both need
@@ -772,7 +1153,14 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
             else:
                 new_params = jax.tree.map(
                     lambda w, g: w - lr * g / n_shards, params, grads)
-        return new_params, loss / n_shards
+        if not stats:
+            return new_params, loss / n_shards
+        # the counters are of this shard's tokens: pairs add up over the
+        # shards, a load ratio is averaged
+        counters = {k: lax.psum(v, ("data", "seq")) /
+                    (n_shards if k == "load_max_over_mean" else 1)
+                    for k, v in counters.items()}
+        return new_params, loss / n_shards, counters
 
     # replication checking is disabled wholesale by the compat shim
     # (parallel/compat.py) — it false-positives on these psum-composed
@@ -784,13 +1172,13 @@ def make_train_step(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
         ((P("data"),) if masked else ())
     step = shard_map(
         local_step, mesh=mesh, in_specs=in_specs,
-        out_specs=(step_specs, P()))
+        out_specs=(step_specs, P()) + ((P(),) if stats else ()))
     return jax.jit(step, donate_argnums=(0,) if donate else ()), \
         step_specs
 
 
-def make_eval_loss(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
-                   vocab: int, causal: bool = True, compute_dtype=None,
+def make_eval_loss(mesh: Mesh, arch, d=None, heads=None, ff=None,
+                   vocab=None, causal: bool = True, compute_dtype=None,
                    masked: bool = False, loss_chunks: int | None = None,
                    head_sharded: bool = False,
                    n_experts: int | None = None,
@@ -799,23 +1187,16 @@ def make_eval_loss(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
     the train step's forward + CE loss (the SHARED ``_forward_ce`` body,
     so the numerics cannot drift) with no update: validation/test
     passes."""
-    heads_local = _check_tp(mesh, heads, d, ff,
-                            vocab if head_sharded else None, n_experts)
-    specs = param_specs(n_layers, head_sharded, moe=bool(n_experts))
+    arch = as_arch(arch, d, heads, ff, vocab, n_experts, moe_top_k)
+    run = _run_of(mesh, arch, causal, arch.vocab if head_sharded else None)
+    specs = param_specs(arch, head_sharded)
     cdt = _default_compute_dtype(compute_dtype)
-    from znicz_tpu.core.config import root as root_cfg
-    interp = bool(root_cfg.common.engine.get("pallas_interpret", False))
-    use_flash = _flash_eligible(mesh, interp)
-    use_ring_flash = _ring_flash_eligible(mesh, interp)
 
     def local_eval(params, tokens, labels, mask=None):
         n_shards = lax.psum(1, "data") * lax.psum(1, "seq")
-        return _forward_ce(params, tokens, labels, mask, heads_local,
-                           causal, use_flash, interp, cdt,
+        return _forward_ce(params, tokens, labels, mask, arch, run, cdt,
                            loss_chunks=loss_chunks,
-                           use_ring_flash=use_ring_flash,
-                           head_sharded=head_sharded,
-                           moe_top_k=moe_top_k) / n_shards
+                           head_sharded=head_sharded)[0] / n_shards
 
     batch_spec = P("data", "seq")
     in_specs = (specs, batch_spec, batch_spec) + \
@@ -825,8 +1206,8 @@ def make_eval_loss(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
     return jax.jit(fn)
 
 
-def make_logits_fn(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
-                   vocab: int, causal: bool = True, compute_dtype=None,
+def make_logits_fn(mesh: Mesh, arch, d=None, heads=None, ff=None,
+                   vocab=None, causal: bool = True, compute_dtype=None,
                    n_experts: int | None = None, moe_top_k: int = 1):
     """-> jitted ``logits(params, tokens) -> (b, t, vocab)`` f32 — the
     full forward pass through the SAME ``_forward_hidden`` body the
@@ -840,20 +1221,15 @@ def make_logits_fn(mesh: Mesh, n_layers: int, d: int, heads: int, ff: int,
     The head must be replicated (``head_sharded`` has no logits form —
     the vocab-sharded CE never materializes full-vocab rows by design);
     callers wanting Megatron CE keep using :func:`make_eval_loss`."""
-    heads_local = _check_tp(mesh, heads, d, ff, None, n_experts)
+    arch = as_arch(arch, d, heads, ff, vocab, n_experts, moe_top_k)
+    run = _run_of(mesh, arch, causal)
     cdt = _default_compute_dtype(compute_dtype)
-    from znicz_tpu.core.config import root as root_cfg
-    interp = bool(root_cfg.common.engine.get("pallas_interpret", False))
-    use_flash = _flash_eligible(mesh, interp)
-    use_ring_flash = _ring_flash_eligible(mesh, interp)
 
     def local_logits(params, tokens):
-        x, _aux, ps = _forward_hidden(
-            params, tokens, heads_local, causal, use_flash, interp, cdt,
-            use_ring_flash=use_ring_flash, moe_top_k=moe_top_k)
-        return (x @ ps["head"]).astype(jnp.float32)
+        x, _aux, ps, _stats = _forward_hidden(params, tokens, arch, run, cdt)
+        return (x @ _head_of(ps, arch)).astype(jnp.float32)
 
-    specs = param_specs(n_layers, False, moe=bool(n_experts))
+    specs = param_specs(arch, False)
     batch_spec = P("data", "seq")
     fn = shard_map(local_logits, mesh=mesh,
                    in_specs=(specs, batch_spec),

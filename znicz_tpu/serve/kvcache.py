@@ -111,6 +111,14 @@ class KVDecoder(Logger):
         super().__init__()
         import jax
 
+        from znicz_tpu.parallel.transformer import mechanisms_of_params
+
+        extra = mechanisms_of_params(params)
+        if extra:
+            raise NotImplementedError(
+                f"KV-cache decode serves the GPT-shaped block only; this "
+                f"model has {', '.join(extra)} (no convolution state "
+                f"beside keys and values, no experts when decoding)")
         if any("ew1" in blk for blk in params["blocks"]):
             raise NotImplementedError(
                 "KV-cache decode supports dense FFN blocks only; MoE "

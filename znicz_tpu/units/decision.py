@@ -207,6 +207,11 @@ class DecisionMSE(DecisionBase):
 
     def accumulate(self, cls: int) -> None:
         # evaluator mse is already normalized by its batch; re-weight to sum
+        if not int(self.minibatch_size):
+            # a minibatch that counts no sample adds nothing, and its mse
+            # is not read (TransformerLMStep leaves an unfetched device
+            # array there until the class pass ends)
+            return
         self.epoch_sse[cls] += float(self.minibatch_mse) * \
             int(self.minibatch_size)
         self.epoch_samples[cls] += int(self.minibatch_size)

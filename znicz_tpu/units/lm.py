@@ -27,18 +27,47 @@ from znicz_tpu.observe.anatomy import StepCadence
 from znicz_tpu.observe.trace import TRACER
 
 
+def _fold_pass(acc, loss, mask, stats):
+    """One minibatch into the class pass's device-side sums: the loss
+    weighted by the rows that count (the Decision's own weighting), the
+    rows, the steps, and whatever counters the step gave."""
+    import jax.numpy as jnp
+
+    rows = mask.sum().astype(jnp.float32)
+    new = {"loss_rows": loss * rows, "rows": rows,
+           "steps": jnp.ones((), jnp.float32), **stats}
+    if acc is None:
+        return new
+    return {k: acc[k] + v for k, v in new.items()}
+
+
 class TransformerLMStep(AcceleratedUnit):
     """One train-or-eval step per served (tokens, labels) minibatch.
 
-    Publishes ``minibatch_mse`` (mean CE loss per token — the DecisionMSE
-    contract: a lower-is-better per-sample metric) and mirrors the fused
-    step's donation/dispatch discipline: params live on device, the loss
-    read is the only d2h sync per minibatch.
+    ``arch`` is the architecture as one mapping of the model's own keys
+    (``layer_types``, ``num_dense_layers``, ``num_experts``,
+    ``num_experts_per_tok``, ``num_key_value_heads``, ... and
+    ``experts_held``, this chip's share: ``{"first", "count"}``; see
+    ``parallel.transformer.arch_from_config``); the vocabulary is the
+    loader's.  Without it the unit builds the GPT-shaped block from
+    ``n_layers``, ``d``, ``heads``, ``ff`` (and ``n_experts``).
+
+    Mirrors the fused step's donation/dispatch discipline: params live
+    on the device and are donated to each step, and no step waits for its
+    loss.  The loss stays on the device, is folded into a device-side sum
+    and fetched once a class pass, at ``loader.last_minibatch`` (span
+    ``lm.loss_read``), with the accounting of ``FusedTrainStep``'s
+    deferred mode: ``minibatch_mse`` (mean CE loss per token — the
+    DecisionMSE contract: a lower-is-better per-sample metric) and
+    ``minibatch_size`` then carry the whole pass, and on every earlier
+    minibatch ``minibatch_size`` is 0 and ``minibatch_mse`` is the newest
+    step's loss as a device array, not fetched (``float()`` of it
+    waits for that step).
     """
 
     def __init__(self, workflow=None, loader=None, n_layers: int = 2,
                  d: int = 32, heads: int = 2, ff: Optional[int] = None,
-                 lr: float = 0.1, mesh=None,
+                 lr: float = 0.1, mesh=None, arch=None,
                  loss_chunks: Optional[int] = None,
                  head_sharded: bool = False,
                  n_experts: Optional[int] = None,
@@ -47,6 +76,11 @@ class TransformerLMStep(AcceleratedUnit):
                  moe_zloss_weight: float = 0.0, **kwargs) -> None:
         super().__init__(workflow, **kwargs)
         self.loader = loader
+        #: the model's own keys, or None for the GPT-shaped block below
+        self.arch_config = dict(arch) if arch is not None else None
+        if arch is not None and n_experts is not None:
+            raise ValueError("arch= carries its own experts; n_experts "
+                             "belongs to the GPT-shaped block")
         self.n_layers = int(n_layers)
         self.d = int(d)
         self.heads = int(heads)
@@ -79,9 +113,20 @@ class TransformerLMStep(AcceleratedUnit):
         # decision links (DecisionMSE contract)
         self.minibatch_mse = 0.0
         self.minibatch_size = 0
+        #: the newest dispatch's loss, on the device (never fetched here)
+        self.last_loss = None
+        #: class-pass sums on the device: loss * rows, rows, steps and the
+        #: routed layers' counters; None between passes
+        self._acc = None
+        #: the last finished pass's routed-expert counters (host floats):
+        #: pairs routed to held experts a step, and the fullest held
+        #: expert's load over the mean
+        self.moe_counters: dict = {}
+        self.arch = None
         self._params = None
         self._step = None
         self._eval = None
+        self._fold = None
 
     # -- lifecycle ----------------------------------------------------------
     def numpy_init(self) -> None:
@@ -106,26 +151,22 @@ class TransformerLMStep(AcceleratedUnit):
                 self.info(f"no mesh given: using 1 of "
                           f"{jax.local_device_count()} local devices "
                           f"(pass mesh= to the workflow to use more)")
+        self.arch = self._resolve_arch()
         if self._params is None:
-            self._params = tfm.init_params(
-                prng.get(), self.n_layers, self.d, self.heads, self.ff,
-                self.vocab_size, n_experts=self.n_experts)
+            self._params = tfm.init_params(prng.get(), self.arch)
         self._params = self._place_params(self._params)
         # masked=True: the loader's padded tail rows (base.py static-shape
-        # policy) contribute neither loss nor gradients
+        # policy) contribute neither loss nor gradients.  donate=True: the
+        # step consumes the params it is handed (xla_run rebinds them)
         self._step, _ = tfm.make_train_step(
-            self.mesh, self.n_layers, self.d, self.heads, self.ff,
-            self.vocab_size, lr=self.lr, masked=True,
+            self.mesh, self.arch, lr=self.lr, masked=True, donate=True,
             loss_chunks=self.loss_chunks, head_sharded=self.head_sharded,
-            n_experts=self.n_experts,
             moe_aux_weight=self.moe_aux_weight,
-            moe_top_k=self.moe_top_k,
-            moe_zloss_weight=self.moe_zloss_weight)
+            moe_zloss_weight=self.moe_zloss_weight, stats=True)
         self._eval = tfm.make_eval_loss(
-            self.mesh, self.n_layers, self.d, self.heads, self.ff,
-            self.vocab_size, masked=True, loss_chunks=self.loss_chunks,
-            head_sharded=self.head_sharded, n_experts=self.n_experts,
-            moe_top_k=self.moe_top_k)
+            self.mesh, self.arch, masked=True, loss_chunks=self.loss_chunks,
+            head_sharded=self.head_sharded)
+        self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
         # the programs from again (as FusedTrainStep's programs)
         from znicz_tpu.observe import probe
@@ -138,17 +179,29 @@ class TransformerLMStep(AcceleratedUnit):
         self._mask_sharding = NamedSharding(self.mesh, P("data"))
         #: reused mask row — the hot loop allocates nothing per step
         self._arange = np.arange(self.loader.max_minibatch_size)
+        from znicz_tpu.pipeline.prefetcher import ring_safe_stager
+
+        self._put = ring_safe_stager(lambda t, l, m: jax.device_put(
+            (t, l, m), (self._batch_sharding, self._batch_sharding,
+                        self._mask_sharding)))
+
+    def _resolve_arch(self):
+        """The ``Arch`` this unit runs, at the loader's vocabulary."""
+        from znicz_tpu.parallel import transformer as tfm
+
+        if self.arch_config is not None:
+            return tfm.arch_from_config(self.arch_config, self.vocab_size)
+        return tfm.gpt_arch(self.n_layers, self.d, self.heads, self.ff,
+                            self.vocab_size, self.n_experts, self.moe_top_k)
 
     def _stage_batch(self, tokens, labels, count: int):
         """ONE fused ``device_put``: tokens, labels and the padding mask
         ride a single staged tuple transfer instead of three separate
-        H2D trips (shared by xla_run and the input-pipeline stager)."""
-        import jax
-
-        return jax.device_put(
-            (tokens, labels, self._arange < count),
-            (self._batch_sharding, self._batch_sharding,
-             self._mask_sharding))
+        H2D trips (shared by xla_run and the input-pipeline stager).
+        Detached or fenced (``ring_safe_stager``): no step waits for its
+        loss any more, so the loader refills its host arrays while
+        earlier steps are still queued."""
+        return self._put(tokens, labels, self._arange < count)
 
     def make_stager(self):
         """Producer-side staging for the input pipeline
@@ -156,17 +209,9 @@ class TransformerLMStep(AcceleratedUnit):
         tuple put while the current step computes; ring-slot handoff via
         the shared ring_safe_stager (copy on the aliasing CPU backend,
         H2D fence on accelerators)."""
-        import jax
-
-        from znicz_tpu.pipeline.prefetcher import ring_safe_stager
-
-        safe_put = ring_safe_stager(lambda t, l, m: jax.device_put(
-            (t, l, m), (self._batch_sharding, self._batch_sharding,
-                        self._mask_sharding)))
-
         def stage(rec, arrays):
             tokens, labels = arrays["data"], arrays["labels"]
-            staged = safe_put(tokens, labels, self._arange < rec["size"])
+            staged = self._stage_batch(tokens, labels, rec["size"])
             nbytes = tokens.nbytes + labels.nbytes + \
                 self._arange.size  # one byte per bool mask element
             return {"lm": staged}, nbytes
@@ -180,8 +225,7 @@ class TransformerLMStep(AcceleratedUnit):
 
         from znicz_tpu.parallel import transformer as tfm
 
-        specs = tfm.param_specs(self.n_layers, self.head_sharded,
-                                moe=bool(self.n_experts))
+        specs = tfm.param_specs(self.arch, self.head_sharded)
         return jax.device_put(
             params, jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s), specs,
@@ -209,16 +253,52 @@ class TransformerLMStep(AcceleratedUnit):
                     count)
         with TRACER.timed("lm.dispatch") as span:
             if int(self.loader.minibatch_class) == TRAIN:
-                self._params, loss = self._step(self._params, tokens,
-                                                labels, mask)
+                self._params, loss, stats = self._step(
+                    self._params, tokens, labels, mask)
             else:
-                loss = self._eval(self._params, tokens, labels, mask)
+                loss, stats = self._eval(self._params, tokens, labels,
+                                         mask), {}
+            # no wait: the loss joins the pass's sums on the device (one
+            # tiny program behind the step) and work stays queued
+            self._acc = self._fold(self._acc, loss, mask, stats)
         self._cadence.tick(span.t0)
-        # the per-step blocking read: the host waits here for the step
-        # to finish, and the chip then waits for the next dispatch
+        self.last_loss = loss
+        if not loader.last_minibatch:
+            # minibatches before the last contribute zero to the
+            # Decision's accumulators (FusedTrainStep's deferred mode);
+            # the newest loss stays reachable, unfetched
+            self.minibatch_mse = loss
+            self.minibatch_size = 0
+            return
+        # the one blocking read of the class pass: its totals land here
         with TRACER.span("lm.loss_read"):
-            self.minibatch_mse = float(jax.device_get(loss))
-        self.minibatch_size = count
+            sums = jax.device_get(self._acc)
+        self._acc = None
+        rows, steps = float(sums["rows"]), max(float(sums["steps"]), 1.0)
+        self.minibatch_mse = float(sums["loss_rows"]) / max(rows, 1.0)
+        self.minibatch_size = int(rows)
+        if "pairs_held" in sums:
+            self._publish_moe(float(sums["pairs_held"]) / steps,
+                              float(sums["load_max_over_mean"]) / steps,
+                              float(sums["pairs_held"]))
+
+    def _publish_moe(self, pairs_a_step: float, load_ratio: float,
+                     pairs: float) -> None:
+        """A finished pass's routed-expert counters: the unit's mirror
+        and the process registry (docs/OBSERVABILITY.md)."""
+        from znicz_tpu.observe import registry
+
+        self.moe_counters = {"pairs_held_per_step": pairs_a_step,
+                             "load_max_over_mean": load_ratio}
+        registry.counter(
+            "znicz_lm_moe_pairs_held_total",
+            "(token, choice) pairs routed to experts this chip holds",
+            ("unit",)).labels(unit=self.name).inc(pairs)
+        registry.gauge(
+            "znicz_lm_moe_load_max_over_mean",
+            "fullest held expert's pairs over the held experts' mean, "
+            "averaged over the routed layers and the last class pass",
+            ("unit",)).labels(unit=self.name).set(load_ratio)
 
     # -- serving handoff (ISSUE 10) -----------------------------------------
     def export_lm(self, path: str,
@@ -244,7 +324,13 @@ class TransformerLMStep(AcceleratedUnit):
         if self.n_experts:
             raise ValueError("export_lm cannot package an MoE stack "
                              "(KV-cache decode serves dense FFN only)")
-        params = jax.tree.map(lambda a: np.asarray(jax.device_get(a)),
+        extra = self.arch.mechanisms() if self.arch is not None else []
+        if extra:
+            raise ValueError(
+                f"export_lm cannot package {', '.join(extra)}: serve/ "
+                f"decodes the GPT-shaped block only (no convolution state "
+                f"beside keys and values, no experts when decoding)")
+        params = jax.tree.map(lambda a: np.array(jax.device_get(a)),
                               self._params)
         draft = None
         if draft_layers:
@@ -262,16 +348,12 @@ class TransformerLMStep(AcceleratedUnit):
 
         if self._params is None:
             return {}
+        # a copy: on the CPU device_get hands back a view of the device
+        # buffer, which the next step is donated and overwrites
         return {"params": jax.tree.map(
-            lambda a: np.asarray(jax.device_get(a)), self._params)}
+            lambda a: np.array(jax.device_get(a)), self._params)}
 
-    def load_state_dict(self, state: dict) -> None:
-        if "params" not in state:
-            return
-        params = state["params"]
-        # architecture validation — the generic snapshot restore checks
-        # tree STRUCTURE; shape semantics are this unit's contract:
-        restored_vocab = int(params["emb"].shape[0])
+    def _check_gpt_snapshot(self, params, restored_vocab: int) -> None:
         if len(params["blocks"]) != self.n_layers or \
                 int(params["emb"].shape[1]) != self.d or \
                 tuple(params["head"].shape) != (self.d, restored_vocab):
@@ -289,6 +371,28 @@ class TransformerLMStep(AcceleratedUnit):
             raise ValueError(
                 f"snapshot FFN flavor (n_experts={snap_experts}) does "
                 f"not match this workflow (n_experts={self.n_experts})")
+
+    def load_state_dict(self, state: dict) -> None:
+        if "params" not in state:
+            return
+        params = state["params"]
+        # architecture validation — the generic snapshot restore checks
+        # tree STRUCTURE; shape semantics are this unit's contract:
+        restored_vocab = int(params["emb"].shape[0])
+        if self.arch_config is not None:
+            # every leaf's shape follows from the architecture
+            import jax
+
+            from znicz_tpu.parallel import transformer as tfm
+
+            want = tfm.param_shapes(tfm.arch_from_config(
+                self.arch_config, restored_vocab))
+            if jax.tree.map(lambda a: tuple(np.shape(a)), params) != want:
+                raise ValueError(
+                    "snapshot params do not match this workflow's "
+                    "architecture (leaf names or shapes differ)")
+        else:
+            self._check_gpt_snapshot(params, restored_vocab)
         # vocab must match what the loader SERVES NOW — after a restore
         # the loader has adopted the snapshot vocab (CharSequenceLoader
         # snapshots it), so a mismatch means a genuinely different corpus

@@ -99,6 +99,13 @@ def _lm_arch(params, heads: int, prefix: str = ""):
     pytree — shared by the target and draft halves of a package."""
     vocab, d = (int(s) for s in np.shape(params["emb"]))
     blocks = params["blocks"]
+    from znicz_tpu.parallel.transformer import mechanisms_of_params
+
+    extra = mechanisms_of_params(params)
+    if extra:
+        raise ValueError(
+            f"export_lm packages the GPT-shaped block only; this model "
+            f"has {', '.join(extra)}, which serve/ does not decode")
     if any("ew1" in blk for blk in blocks):
         raise ValueError("export_lm supports dense FFN stacks only "
                          "(KV-cache decode does not serve MoE)")
