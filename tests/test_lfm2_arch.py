@@ -9,6 +9,7 @@ deferred loss read."""
 
 import os
 import sys
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -179,6 +180,112 @@ def test_no_pair_is_dropped_when_every_token_selects_the_same_experts():
     assert float(stats["pairs_held"]) == 0 and not np.asarray(y).any()
 
 
+def _steered_layer(two_held: int, one_held: int, tokens: int = 512):
+    """A layer of 8 experts, top-2, whose router a feature of each token
+    steers: the first ``two_held`` tokens select experts 0 and 1, the
+    next ``one_held`` experts 0 and 5, the rest 4 and 5.  A chip that
+    holds experts 0 and 1 then counts ``2 * two_held + one_held`` pairs,
+    exactly."""
+    dm, p, _ = _layer_inputs()
+    kind = np.full(tokens, 2)
+    kind[:two_held] = 0
+    kind[two_held:two_held + one_held] = 1
+    v = 0.1 * jax.random.normal(jax.random.PRNGKey(9), (tokens, dm["d"]))
+    v = v.at[:, :3].set(jax.nn.one_hot(kind, 3))
+    gate = 0.1 * p["gate"]
+    gate = gate.at[:3].set(8.0 * jnp.asarray(
+        [[1, 1, 0, 0, 0, 0, 0, 0], [1, 0, 0, 0, 0, 1, 0, 0],
+         [0, 0, 0, 0, 1, 1, 0, 0]], jnp.float32) - 4.0)
+    return dm, {**p, "gate": gate, "ebias": jnp.zeros(8)}, v
+
+
+def _share_loss(p, v, first, held, ct, rows_of=None):
+    """Loss, output, counters and the gradients to ``x``, ``gate``,
+    ``ew1``, ``ew3``, ``ew2`` of one share of the layer; ``rows_of``
+    stands in for ``moe.compact_rows`` (the full buffer alone: ``lambda
+    n, h, e: n``)."""
+    def loss(x, gate, w1, w3, w2):
+        y, stats = moe.moe_routed_ffn(x, gate, p["ebias"], w1, w3, w2,
+                                      first=first, top_k=2)
+        return (y * ct).sum(), (y, stats)
+
+    sl = slice(first, first + held)
+    with mock.patch.object(moe, "compact_rows",
+                           rows_of or moe.compact_rows), \
+            jax.default_matmul_precision("highest"):
+        (_, (y, stats)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+            v, p["gate"], p["ew1"][sl], p["ew3"][sl], p["ew2"][sl])
+    return y, stats, grads
+
+
+def test_compact_buffer_gives_the_full_buffers_output_and_gradients():
+    """2 of 8 experts held, 1,024 pairs: the compact buffer (512 rows)
+    carries the layer, and its output and every gradient are the full
+    buffer's."""
+    dm, p, _ = _layer_inputs()
+    v = jax.random.normal(jax.random.PRNGKey(4), (512, dm["d"]))
+    ct = jax.random.normal(jax.random.PRNGKey(5), v.shape)
+    assert moe.compact_rows(1024, 2, 8) == 512
+    y, stats, grads = _share_loss(p, v, 2, 2, ct)
+    y_full, stats_full, grads_full = _share_loss(
+        p, v, 2, 2, ct, rows_of=lambda n, h, e: n)
+    assert float(stats["compact"]) == 1.0
+    assert float(stats_full["compact"]) == 0.0
+    assert 0 < float(stats["pairs_held"]) == float(stats_full["pairs_held"])
+    np.testing.assert_allclose(y, y_full, atol=2e-6)
+    for name, g, want in zip(("x", "gate", "ew1", "ew3", "ew2"), grads,
+                             grads_full):
+        assert np.abs(np.asarray(want)).max() > 1e-3, name
+        np.testing.assert_allclose(g, want, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("one_held,compact", [(-1, 1.0), (0, 1.0), (1, 0.0)],
+                         ids=["just_under", "equal", "just_over"])
+def test_the_buffer_follows_the_count_and_no_pair_is_dropped(one_held,
+                                                             compact):
+    """511, 512 and 513 pairs to the held experts against a compact
+    buffer of 512: compact, compact, full; all three are the reference's
+    layer, and the gradients are the full buffer's."""
+    two_held = 256 if one_held >= 0 else 255
+    dm, p, v = _steered_layer(two_held, abs(one_held))
+    ct = jax.random.normal(jax.random.PRNGKey(6), v.shape)
+    y, stats, grads = _share_loss(p, v, 0, 2, ct)
+    assert float(stats["pairs_held"]) == 512 + one_held
+    assert float(stats["compact"]) == compact
+    ident = lambda a: a                                     # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = ref._sparse_ffn(
+            {**p, "ew1": p["ew1"][:2], "ew3": p["ew3"][:2],
+             "ew2": p["ew2"][:2]}, v, {**dm, "first": 0, "held": 2},
+            ident, ident)
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    _, _, grads_full = _share_loss(p, v, 0, 2, ct,
+                                   rows_of=lambda n, h, e: n)
+    for g, want in zip(grads, grads_full):
+        np.testing.assert_allclose(g, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("held,branches", [(8, 0), (2, 2)],
+                         ids=["all_held", "a_share"])
+def test_a_chip_that_holds_every_expert_traces_no_choice(held, branches):
+    """``held == E``: the compact buffer would be no smaller, so there is
+    one path and no ``cond`` in the program, forward or backward; a
+    share has one in each."""
+    dm, p, _ = _layer_inputs()
+    v = jax.random.normal(jax.random.PRNGKey(4), (512, dm["d"]))
+
+    def loss(x, w1):
+        y, _ = moe.moe_routed_ffn(x, p["gate"], p["ebias"], w1,
+                                  p["ew3"][:held], p["ew2"][:held],
+                                  first=0, top_k=2)
+        return y.sum()
+
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        v, p["ew1"][:held]))
+    assert jaxpr.count(" cond[") == branches
+
+
 @pytest.mark.parametrize("wrong", ["softmax", "no_bias", "no_norm"])
 def test_router_variants_differ_from_the_models(wrong):
     """The three ways to get the router wrong move the layer's output by
@@ -340,7 +447,8 @@ def test_deferred_loss_read_gives_the_old_epoch_losses(tmp_path):
     assert reads == sum(1 for last, _, _ in seen if last) == 9
 
 
-def _arch_workflow(arch_cfg: dict, data_dir: str, max_epochs: int = 2):
+def _arch_workflow(arch_cfg: dict, data_dir: str, max_epochs: int = 2,
+                   seq_len: int = 16, minibatch_size: int = 8):
     """``models/char_lm.py``'s control graph with the step built from a
     model's own keys."""
     from znicz_tpu.core.plumbing import Repeater
@@ -351,8 +459,9 @@ def _arch_workflow(arch_cfg: dict, data_dir: str, max_epochs: int = 2):
 
     w = NNWorkflow(name="ArchLM")
     w.repeater = Repeater(w)
-    w.loader = CharSequenceLoader(w, data_dir=data_dir, seq_len=16,
-                                  minibatch_size=8, valid_fraction=0.1)
+    w.loader = CharSequenceLoader(w, data_dir=data_dir, seq_len=seq_len,
+                                  minibatch_size=minibatch_size,
+                                  valid_fraction=0.1)
     step = w.step = TransformerLMStep(w, loader=w.loader, arch=arch_cfg,
                                       lr=0.05)
     dec = w.decision = DecisionMSE(w, max_epochs=max_epochs)
@@ -370,8 +479,13 @@ def _arch_workflow(arch_cfg: dict, data_dir: str, max_epochs: int = 2):
     return w
 
 
+@pytest.mark.parametrize("seq_len,batch", [(16, 8), (64, 16)],
+                         ids=["one_buffer", "compact_buffer"])
 def test_step_unit_runs_an_architecture_and_publishes_its_counters(
-        tmp_path):
+        tmp_path, seq_len, batch):
+    """At 128 tokens a step a compact buffer would be no smaller than the
+    256 pairs (one path, share 0); at 1,024 tokens 1,536 rows stand for
+    2,048 and carry the layers."""
     from znicz_tpu.core.backends import XLADevice
     from znicz_tpu.observe import registry
 
@@ -380,7 +494,8 @@ def test_step_unit_runs_an_architecture_and_publishes_its_counters(
              if k not in ("router_width", "hyper", "vocab_size")}
     prng.seed_all(5)
     w = _arch_workflow({**model, "num_experts": cfg["router_width"]},
-                       str(tmp_path / "corp"))
+                       str(tmp_path / "corp"), seq_len=seq_len,
+                       minibatch_size=batch)
     w.initialize(device=XLADevice())
     w.run()
     step = w.step
@@ -389,10 +504,17 @@ def test_step_unit_runs_an_architecture_and_publishes_its_counters(
     assert hist[-1]["metric_train"] < hist[0]["metric_validation"]
     assert step.arch.vocab == w.loader.vocab_size
     pairs = step.moe_counters["pairs_held_per_step"]
-    assert 0 < pairs < 2 * 8 * 16 * 2          # two routed layers, top-2
+    assert 0 < pairs < 2 * batch * seq_len * 2  # two routed layers, top-2
     assert step.moe_counters["load_max_over_mean"] >= 1.0
+    share = step.moe_counters["compact_share"]
+    if moe.compact_rows(2 * batch * seq_len, 4, 8) < 2 * batch * seq_len:
+        assert 0.5 <= share <= 1.0
+    else:
+        assert share == 0.0
     fam = registry.REGISTRY.get("znicz_lm_moe_pairs_held_total")
     assert fam is not None and fam.labels(unit=step.name).get() > 0
+    fam = registry.REGISTRY.get("znicz_lm_moe_compact_share")
+    assert fam is not None and fam.labels(unit=step.name).get() == share
     with pytest.raises(ValueError, match="gated short convolution"):
         step.export_lm(str(tmp_path / "pkg.npz"))
     # a snapshot restores into the same architecture and no other

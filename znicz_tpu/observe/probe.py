@@ -329,6 +329,17 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
+def scope_bwd(name: str):
+    """The label AD gives the backward operations of scope ``name``
+    (``transpose(jvp(name))``), for a backward pass written by hand (a
+    ``custom_vjp`` rule): its operations would otherwise carry no scope,
+    or the forward pass's."""
+    import jax
+
+    _scope_names.add(name)
+    return jax.named_scope(f"transpose(jvp({name}))")
+
+
 def scoped(name: str):
     """Decorator: the body of the function runs under :func:`scope`."""
     def deco(fn):

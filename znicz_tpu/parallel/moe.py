@@ -13,9 +13,10 @@ Three regimes (docs/TUNING.md "MoE"):
 - :func:`moe_routed_ffn` — this chip's SHARE of an expert-parallel
   layer: told which experts it holds, it routes every token over all
   experts, sorts the (token, choice) pairs by expert, runs grouped
-  products over the held experts' groups and scatters back.  No
-  capacity and no drop; what absent experts would add is left out (their
-  chips add it).  The score function, the selection bias and the
+  products over the held experts' groups and sums back into tokens,
+  over a buffer sized from the pairs it has counted (the static worst
+  case as the fallback).  No capacity and no drop; what absent experts
+  would add is left out (their chips add it).  The score function, the selection bias and the
   expert's form are arguments.  Its all-to-all form over an expert axis
   is what would retire the two above (ROADMAP.md).
 
@@ -181,7 +182,11 @@ def route_top_k(scores_in, bias, top_k: int, score: str = "sigmoid",
     sel = s if bias is None else \
         s + lax.stop_gradient(bias.astype(jnp.float32))
     _, choice = lax.top_k(lax.stop_gradient(sel), top_k)
-    w = jnp.take_along_axis(s, choice, axis=1)
+    # the selected scores by a compare over the E columns: a gather of
+    # tokens x k scalars and its scatter-add back cost 0.33 + 0.28 ms a
+    # layer at 8,192 tokens (my chip run, PR 29); each sum has one term
+    picked = choice[..., None] == jnp.arange(s.shape[-1])
+    w = jnp.where(picked, s[:, None, :], 0).sum(-1)
     if norm_topk:
         w = w / (w.sum(axis=-1, keepdims=True) + 1e-6)
     return choice, w * scale
@@ -230,6 +235,204 @@ def _sum_of_pairs_bwd(top_k, token_of, g):
 _sum_of_pairs.defvjp(_sum_of_pairs_fwd, _sum_of_pairs_bwd)
 
 
+#: the compact pairs buffer over the mean this share receives
+#: (``n_pairs * held / E``).  A trained model's selection bias balances
+#: the chips to a fraction of a per cent and biases drawn independently
+#: left the fullest share at 1.29 x the mean (26,383-42,307 pairs a step
+#: over four layers of 8,192, my chip runs, PR 28): 1.5 keeps both on
+#: the compact branch, and a load beyond it is computed whole by the
+#: full buffer, slower and never short of a pair.
+_COMPACT_SLACK = 1.5
+#: rows are rounded up to the grouped product's row tile (the v5e's
+#: kernel walks rows by 512: its tile table in the compiled step has
+#: ``rows / 512 + held - 1`` entries, 39 at 12,288 rows and 79 at 32,768)
+_ROW_TILE = 512
+#: counters that are averaged, not summed, over layers and shards
+MEAN_STATS = ("load_max_over_mean", "compact")
+
+
+def compact_rows(n_pairs: int, held: int, n_experts: int) -> int:
+    """Rows of the compact pairs buffer of a share of ``held`` of
+    ``n_experts`` experts: a function of static shapes only.  At
+    ``n_pairs`` (a chip that holds every expert, or a layer too small to
+    gain a tile) there is one buffer and nothing to choose."""
+    rows = int(np.ceil(_COMPACT_SLACK * n_pairs * held / n_experts))
+    return min(-(-rows // _ROW_TILE) * _ROW_TILE, n_pairs)
+
+
+def _grouped(a, w, sizes, live):
+    """``a``'s rows times their group's weights (cast to ``a``'s dtype),
+    cut to zeros on the buffer's tail.  The tail's rows are nobody's, and
+    a grouped product leaves them as it found them (whatever the memory
+    held, NaN included): each result is cut BEFORE it meets another
+    factor, so that no gradient is a zero times that."""
+    return jnp.where(live, lax.ragged_dot(a, w.astype(a.dtype), sizes), 0)
+
+
+def _pairs_full(x, weight, w1, w3, w2, order, slot_of, sizes, top_k: int,
+                act, scope: str):
+    """The pairs stage over the static worst case: all ``tokens *
+    top_k`` sorted pairs, whatever their number (``sizes.sum()``) that
+    held experts receive.  Dispatch and combine are gathers both ways
+    (:func:`_rows_of_pairs`, :func:`_sum_of_pairs`)."""
+    n_pairs = order.shape[0]
+    with _probe.scope(f"{scope}.route"):
+        live = (jnp.arange(n_pairs) < sizes.sum())[:, None]
+        token_of = order // top_k
+        xs = jnp.where(
+            live, _rows_of_pairs(x, token_of, slot_of, top_k), 0)
+        ws = weight[order]
+    with _probe.scope(f"{scope}.experts"):
+        h1, h3 = _grouped(xs, w1, sizes, live), _grouped(xs, w3, sizes, live)
+        ys = _grouped(act(h1) * h3, w2, sizes, live)
+    with _probe.scope(f"{scope}.route"):
+        ys = ys * ws[:, None].astype(ys.dtype)
+        return _sum_of_pairs(ys, token_of, slot_of, top_k)
+
+
+#: the grouped product's gradient to its weights: each group's rows of
+#: the left operand, transposed, times its rows of the result's
+#: cotangent (what AD's own transpose of ``lax.ragged_dot`` builds)
+_TO_WEIGHTS = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+
+def _compact_index(order, sizes, rows: int, top_k: int):
+    """-> ``(order_c, token_c, n_held, live)`` of the first ``rows``
+    sorted pairs: every pair of a held expert is among them when
+    ``sizes.sum() <= rows`` (held pairs sort first)."""
+    order_c, n_held = order[:rows], sizes.sum()
+    return order_c, order_c // top_k, n_held, \
+        (jnp.arange(rows) < n_held)[:, None]
+
+
+def _sum_of_slots(rows_of, slot_of, n_held, top_k: int):
+    """Each token's sum over its live pairs' rows of ``rows_of`` (the
+    pairs' first sorted slots), accumulated in f32.  One gather a
+    choice, token-major, and a dead pair reads row 0: what costs is the
+    rows read at random, and those are the live pairs only (0.38 ms
+    against 1.43 for a scatter-add of the same 12,288 rows and 1.82 for
+    the full buffer's gather, 8,192 tokens x 2,048, my chip run,
+    PR 29)."""
+    slots = slot_of.reshape(-1, top_k)
+    acc = jnp.zeros((slots.shape[0], rows_of.shape[-1]), jnp.float32)
+    for j in range(top_k):
+        held = slots[:, j] < n_held
+        acc = acc + jnp.where(
+            held[:, None], rows_of[jnp.where(held, slots[:, j], 0)], 0)
+    return acc.astype(rows_of.dtype)
+
+
+def _compact_fwd(x, weight, w1, w3, w2, order, slot_of, sizes, rows: int,
+                 top_k: int, act, scope: str):
+    """The pairs stage over the first ``rows`` sorted pairs -> ``(y, (h1,
+    h3))``: the same arithmetic for every live pair as
+    :func:`_pairs_full`, and the two products its backward needs."""
+    with _probe.scope(f"{scope}.route"):
+        order_c, token_c, n_held, live = _compact_index(order, sizes, rows,
+                                                        top_k)
+        xs = jnp.where(live, x[token_c], 0)
+        ws = weight[order_c]
+    with _probe.scope(f"{scope}.experts"):
+        h1, h3 = _grouped(xs, w1, sizes, live), _grouped(xs, w3, sizes, live)
+        ys = _grouped(act(h1) * h3, w2, sizes, live)
+    with _probe.scope(f"{scope}.route"):
+        ys = ys * ws[:, None].astype(ys.dtype)
+        return _sum_of_slots(ys, slot_of, n_held, top_k), (h1, h3)
+
+
+def _compact_bwd(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g,
+                 rows: int, top_k: int, act, scope: str):
+    """:func:`_compact_fwd`'s gradients to ``(x, weight, w1, w3, w2)``
+    from ``h1``, ``h3`` and a second gather of the rows: six grouped
+    products (each the transpose AD itself would take), none computed
+    twice, and nothing kept at the full buffer's size.  The weights'
+    gradients leave their products in the weights' own dtype (float32
+    masters: unrounded)."""
+    def to_rows(g_out, w, like):
+        # :func:`_grouped`'s gradient to its rows, cut like its result
+        w = w.astype(like.dtype)
+        return jnp.where(live, jax.linear_transpose(
+            lambda a: lax.ragged_dot(a, w, sizes), like)(g_out)[0], 0)
+
+    def to_weights(a, g_out, w):
+        return lax.ragged_dot_general(a, g_out, sizes, _TO_WEIGHTS,
+                                      preferred_element_type=w.dtype)
+
+    with _probe.scope_bwd(f"{scope}.route"):
+        order_c, token_c, n_held, live = _compact_index(order, sizes, rows,
+                                                        top_k)
+        xs = jnp.where(live, x[token_c], 0)
+        ws = weight[order_c][:, None]
+        gy = g[token_c]
+    with _probe.scope_bwd(f"{scope}.experts"):
+        hh, hh_vjp = jax.vjp(lambda a, b: act(a) * b, h1, h3)
+        # ys = (hh w2) * ws: the product's own cotangent gives both the
+        # weights' (its rows against hh's) and hh's (times ws)
+        t = to_rows(gy, w2, hh)
+        d_w2 = to_weights(hh * ws.astype(hh.dtype), gy, w2)
+        d_h1, d_h3 = hh_vjp(t * ws.astype(t.dtype))
+        d_w1, d_w3 = to_weights(xs, d_h1, w1), to_weights(xs, d_h3, w3)
+        d_xs = to_rows(d_h1, w1, xs) + to_rows(d_h3, w3, xs)
+    with _probe.scope_bwd(f"{scope}.route"):
+        d_ws = (t.astype(jnp.float32) * hh.astype(jnp.float32)).sum(-1)
+        d_weight = jnp.zeros(weight.shape, weight.dtype).at[order_c].set(
+            d_ws.astype(weight.dtype), unique_indices=True)
+        d_x = _sum_of_slots(d_xs, slot_of, n_held, top_k)
+    return d_x, d_weight, d_w1, d_w3, d_w2
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def _pairs_either(x, weight, w1, w3, w2, order, slot_of, sizes, rows: int,
+                  top_k: int, act, scope: str):
+    """The pairs stage at the size the layer observes: over ``rows``
+    pairs when the held experts' pairs (``sizes.sum()``) fit, over all
+    of them otherwise.  One rule for both passes, so that AD does not
+    make each branch of the choice write the other's residuals as zeros:
+    the compact branch keeps its two products (:func:`_compact_bwd`),
+    the full one keeps nothing and takes :func:`_pairs_full` again in
+    the backward pass."""
+    return _pairs_either_fwd(x, weight, w1, w3, w2, order, slot_of, sizes,
+                             rows, top_k, act, scope)[0]
+
+
+def _pairs_either_fwd(x, weight, w1, w3, w2, order, slot_of, sizes, rows,
+                      top_k, act, scope):
+    args = (x, weight, w1, w3, w2, order, slot_of, sizes)
+
+    def full(*a):
+        blank = jnp.zeros((rows, w1.shape[-1]), x.dtype)
+        return _pairs_full(*a, top_k, act, scope), (blank, blank)
+
+    with _probe.scope(f"{scope}.route"):
+        fits = sizes.sum() <= rows
+    y, kept = lax.cond(
+        fits, lambda *a: _compact_fwd(*a, rows, top_k, act, scope), full,
+        *args)
+    return y, (args, kept)
+
+
+def _pairs_either_bwd(rows, top_k, act, scope, res, g):
+    args, kept = res
+
+    def full(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g):
+        return jax.vjp(
+            lambda *a: _pairs_full(*a, order, slot_of, sizes, top_k, act,
+                                   scope),
+            x, weight, w1, w3, w2)[1](g)
+
+    with _probe.scope_bwd(f"{scope}.route"):
+        fits = args[-1].sum() <= rows           # sizes: as the forward chose
+    grads = lax.cond(
+        fits, lambda *a: _compact_bwd(*a, rows, top_k, act, scope), full,
+        *args, *kept, g)
+    return (*grads, None, None, None)
+
+
+_pairs_either.defvjp(_pairs_either_fwd, _pairs_either_bwd)
+
+
 def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
                    score: str = "sigmoid", norm_topk: bool = True,
                    scale: float = 1.0, act=jax.nn.silu,
@@ -241,26 +444,36 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
     discrete choice: the router's product runs at the highest
     precision); ``w1``, ``w3`` ``(held, d, f)`` and ``w2`` ``(held, f,
     d)`` are experts ``first .. first + held``, each a gated unit ``w2
-    (act(x w1) * (x w3))``.
+    (act(x w1) * (x w3))``, in ``x``'s dtype or as float32 masters: the
+    products run in ``x``'s dtype either way, and masters take their
+    gradients as the products accumulate them, unrounded.
 
     The ``tokens * top_k`` pairs are sorted by expert, pairs of absent
-    experts last: the sorted buffer is the static worst case (every pair
-    held) and the grouped products (``lax.ragged_dot``, on a TPU a
-    kernel that walks only the tiles its group sizes cover) never touch
-    the tail.  Each pair's result is weighted by its router weight,
-    normalised over all ``top_k`` selected experts whether held or not,
-    and summed into its token.
+    experts last, and the pairs stage (gather, three grouped products,
+    the weights, the sum into tokens, and their backward) runs over a
+    buffer sized from what the layer observes: the first
+    :func:`compact_rows` sorted pairs when the held experts' pairs fit
+    there, all ``tokens * top_k`` (the static worst case, every pair
+    held) when they do not, chosen on the device by the count.  No
+    capacity and no drop at any load; a share of all ``E`` experts has
+    the one buffer.  The grouped products (``lax.ragged_dot``, on a TPU
+    a kernel that walks only the tiles its group sizes cover) never
+    touch a buffer's tail.  Each pair's result is weighted by its router
+    weight, normalised over all ``top_k`` selected experts whether held
+    or not, and summed into its token.
 
     Returns ``(y (tokens, d), stats)``; ``stats`` holds float32 scalars
-    ``pairs_held`` (pairs routed to held experts) and
-    ``load_max_over_mean`` (the fullest held expert's pairs over the
-    held experts' mean).  The work lies under two scopes of the
-    program, ``<scope>.route`` (scores, top-k, sort, gather, scatter)
-    and ``<scope>.experts`` (the grouped products): siblings by name,
-    since an operation counts for its outermost scope."""
+    ``pairs_held`` (pairs routed to held experts), ``load_max_over_mean``
+    (the fullest held expert's pairs over the held experts' mean) and
+    ``compact`` (1.0 when the compact buffer carried the layer).  The
+    work lies under two scopes of the program, ``<scope>.route``
+    (scores, top-k, sort, gather, scatter) and ``<scope>.experts`` (the
+    grouped products): siblings by name, since an operation counts for
+    its outermost scope."""
     tokens, d = x.shape
     held = w1.shape[0]
     n_pairs = tokens * top_k
+    rows = compact_rows(n_pairs, held, gate_w.shape[1])
     with _probe.scope(f"{scope}.route"):
         logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
@@ -270,29 +483,19 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
         mine = (local >= 0) & (local < held)
         key = jnp.where(mine, local, held)             # absent: the tail
         order = jnp.argsort(key, stable=True)
-        sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
+        slot_of = jnp.argsort(order)                   # its inverse
+        sizes = (key[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
         n_held = sizes.sum()
-        live = (jnp.arange(n_pairs) < n_held)[:, None]
-        token_of = order // top_k
-        slot_of = jnp.zeros(n_pairs, order.dtype).at[order].set(
-            jnp.arange(n_pairs, dtype=order.dtype))
-        # the tail's rows are nobody's, and a grouped product leaves them
-        # as it found them (whatever the memory held, NaN included): each
-        # result is cut to zeros there BEFORE it meets another factor, so
-        # that no gradient is a zero times that
-        xs = jnp.where(
-            live, _rows_of_pairs(x, token_of, slot_of, top_k), 0)
-        ws = weight.reshape(n_pairs)[order]
-    with _probe.scope(f"{scope}.experts"):
-        def grouped(a, w):
-            return jnp.where(live, lax.ragged_dot(a, w, sizes), 0)
-
-        ys = grouped(act(grouped(xs, w1)) * grouped(xs, w3), w2)
+    stage = (x, weight.reshape(n_pairs), w1, w3, w2, order, slot_of, sizes)
+    if rows < n_pairs:
+        y = _pairs_either(*stage, rows, top_k, act, scope)
+    else:                                   # one buffer, nothing to choose
+        y = _pairs_full(*stage, top_k, act, scope)
     with _probe.scope(f"{scope}.route"):
-        ys = ys * ws[:, None].astype(ys.dtype)
-        y = _sum_of_pairs(ys, token_of, slot_of, top_k)
         sizes_f = sizes.astype(jnp.float32)
         stats = {"pairs_held": n_held.astype(jnp.float32),
                  "load_max_over_mean":
-                     sizes_f.max() / jnp.maximum(sizes_f.mean(), 1e-9)}
+                     sizes_f.max() / jnp.maximum(sizes_f.mean(), 1e-9),
+                 "compact": ((n_held <= rows) & (rows < n_pairs)).astype(
+                     jnp.float32)}
     return y, stats

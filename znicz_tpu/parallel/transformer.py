@@ -34,7 +34,7 @@ from znicz_tpu.parallel.compat import quantized_psum, shard_map
 
 from znicz_tpu.observe import probe as _probe
 from znicz_tpu.parallel import qcomm
-from znicz_tpu.parallel.moe import (load_balance_aux, moe_ffn,
+from znicz_tpu.parallel.moe import (MEAN_STATS, load_balance_aux, moe_ffn,
                                     moe_routed_ffn, router_z_loss)
 from znicz_tpu.parallel.pipeline import pipeline_apply
 from znicz_tpu.parallel.ring_attention import (ring_attention,
@@ -787,13 +787,17 @@ _REMAT_POLICIES = {
 
 def _cast_params(ps, arch: Arch, cdt):
     """The forward's view of the params in the compute dtype.  A routed
-    layer's router (``gate``, ``ebias``) stays in the master dtype: it
-    makes a discrete choice, and :func:`moe.moe_routed_ffn` takes its
-    product at the highest precision."""
+    layer's leaves stay in the master dtype: its router (``gate``,
+    ``ebias``) makes a discrete choice, and :func:`moe.moe_routed_ffn`
+    takes its product at the highest precision; its experts are cast
+    where the layer's pairs stage uses them, on whichever side of its
+    choice of buffer, and take their gradients from there in the master
+    dtype (a cast out here would stand alone on both sides of that
+    choice: 12 ms of the step, my chip run, PR 29)."""
     out = jax.tree.map(lambda w: w.astype(cdt), ps)
     for i, ffn in enumerate(arch.ffns):
         if ffn == "moe_routed":
-            for k in ("gate", "ebias"):
+            for k in ("gate", "ebias", "ew1", "ew3", "ew2"):
                 if k in ps["blocks"][i]:
                     out["blocks"][i][k] = ps["blocks"][i][k]
     return out
@@ -818,7 +822,7 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
     states (through the final norm where the stack has one), the summed
     MoE regularizer term, the compute-dtype-cast params (so the caller's
     head matmul uses the same precision policy) and the routed layers'
-    counters summed over the layers (``load_max_over_mean`` their mean)."""
+    counters summed over the layers (``moe.MEAN_STATS`` their mean)."""
     ps = _cast_params(ps, arch, cdt)
     with _probe.scope("embed"):
         x = ps["emb"][tokens]                     # (b_l, t_l, d)
@@ -835,9 +839,9 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
         x, aux, st = blk(x, p, arch, run, i)
         aux_term = aux_term + aux
         stats = _sum_stats(stats, st)
-    if "load_max_over_mean" in stats:
-        stats["load_max_over_mean"] = stats["load_max_over_mean"] / \
-            arch.ffns.count("moe_routed")
+    for k in MEAN_STATS:
+        if k in stats:
+            stats[k] = stats[k] / arch.ffns.count("moe_routed")
     if arch.final_norm:
         with _probe.scope("ce"):
             x = _rms_norm(x, ps["norm_g"], arch.eps)
@@ -1156,9 +1160,9 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
         if not stats:
             return new_params, loss / n_shards
         # the counters are of this shard's tokens: pairs add up over the
-        # shards, a load ratio is averaged
+        # shards, a load ratio and a share are averaged
         counters = {k: lax.psum(v, ("data", "seq")) /
-                    (n_shards if k == "load_max_over_mean" else 1)
+                    (n_shards if k in MEAN_STATS else 1)
                     for k, v in counters.items()}
         return new_params, loss / n_shards, counters
 

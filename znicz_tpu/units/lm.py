@@ -280,16 +280,18 @@ class TransformerLMStep(AcceleratedUnit):
         if "pairs_held" in sums:
             self._publish_moe(float(sums["pairs_held"]) / steps,
                               float(sums["load_max_over_mean"]) / steps,
+                              float(sums["compact"]) / steps,
                               float(sums["pairs_held"]))
 
     def _publish_moe(self, pairs_a_step: float, load_ratio: float,
-                     pairs: float) -> None:
+                     compact_share: float, pairs: float) -> None:
         """A finished pass's routed-expert counters: the unit's mirror
         and the process registry (docs/OBSERVABILITY.md)."""
         from znicz_tpu.observe import registry
 
         self.moe_counters = {"pairs_held_per_step": pairs_a_step,
-                             "load_max_over_mean": load_ratio}
+                             "load_max_over_mean": load_ratio,
+                             "compact_share": compact_share}
         registry.counter(
             "znicz_lm_moe_pairs_held_total",
             "(token, choice) pairs routed to experts this chip holds",
@@ -299,6 +301,12 @@ class TransformerLMStep(AcceleratedUnit):
             "fullest held expert's pairs over the held experts' mean, "
             "averaged over the routed layers and the last class pass",
             ("unit",)).labels(unit=self.name).set(load_ratio)
+        registry.gauge(
+            "znicz_lm_moe_compact_share",
+            "share of the last class pass's routed layer-steps whose "
+            "held pairs fitted the compact pairs buffer (the rest took "
+            "the full one; none dropped a pair)",
+            ("unit",)).labels(unit=self.name).set(compact_share)
 
     # -- serving handoff (ISSUE 10) -----------------------------------------
     def export_lm(self, path: str,
