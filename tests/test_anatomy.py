@@ -1,21 +1,14 @@
 """Step-time anatomy (ISSUE 20): the StepAnatomy accountant, MFU gauge
-wiring, the per-rank straggler rule, goodput note plumbing, and the
-bench perf-regression sentinel (synthetic 20% cliff flagged; the real
-recorded r04->r05 pair passes).  The split-dispatch producers went with
-ISSUE 24 (the train planes feed ``znicz_anatomy_step_seconds`` from the
-dispatch cadence; tests/test_observe.py).
+wiring, the per-rank straggler rule and goodput note plumbing.  The
+split-dispatch producers went with ISSUE 24 (the train planes feed
+``znicz_anatomy_step_seconds`` from the dispatch cadence;
+tests/test_observe.py).
 """
-
-import importlib.util
-import json
-import os
 
 import pytest
 
 from znicz_tpu.observe import probe, registry
 from znicz_tpu.observe.anatomy import TRAIN_PHASES, StepAnatomy
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _flat(**kw):
@@ -143,70 +136,3 @@ def test_rank_straggler_rule_trips_deterministically():
         assert rules[2].trips == 1            # no re-trip once healthy
     finally:
         agg.close()
-
-
-# -- bench sentinel ----------------------------------------------------------
-
-def _sentinel():
-    spec = importlib.util.spec_from_file_location(
-        "bench_sentinel", os.path.join(REPO, "tools",
-                                       "bench_sentinel.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _round_file(tmp_path, name, value, rc=0,
-                metric="fc_train_samples_per_sec", unit="samples/sec"):
-    doc = {"n": 1, "cmd": "bench", "rc": rc, "parsed": None,
-           "tail": json.dumps({"metric": metric, "value": value,
-                               "unit": unit, "vs_baseline": 1.0})}
-    path = tmp_path / name
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-def test_sentinel_flags_synthetic_regression(tmp_path, capsys):
-    sentinel = _sentinel()
-    old = _round_file(tmp_path, "old.json", 1000.0)
-    new = _round_file(tmp_path, "new.json", 800.0)   # -20% throughput
-    assert sentinel.main([old, new]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "fc_train_samples_per_sec" in out
-    # report-only always exits 0; an improvement or within-band move
-    # never fails
-    assert sentinel.main([old, new, "--report-only"]) == 0
-    better = _round_file(tmp_path, "better.json", 1050.0)
-    assert sentinel.main([old, better]) == 0
-    # a wider band tolerates the same cliff
-    assert sentinel.main([old, new, "--band", "0.25"]) == 0
-
-
-def test_sentinel_orientation_and_one_sided(tmp_path):
-    sentinel = _sentinel()
-    assert sentinel.lower_is_better("serve_latency_p95", "seconds")
-    assert not sentinel.lower_is_better("train_samples_per_sec",
-                                        "samples/sec")
-    # time-like metric regresses UP
-    old = _round_file(tmp_path, "o.json", 1.0, metric="step_seconds",
-                      unit="seconds")
-    new = _round_file(tmp_path, "n.json", 1.3, metric="step_seconds",
-                      unit="seconds")
-    assert sentinel.main([old, new]) == 1
-    # one-sided metrics report but never fail
-    findings = sentinel.compare(
-        {"only_old": {"value": 5.0, "unit": "samples/sec"}},
-        {"only_new": {"value": 7.0, "unit": "samples/sec"}})
-    kinds = {f["metric"]: f["kind"] for f in findings}
-    assert kinds == {"only_old": "dropped", "only_new": "new"}
-
-
-def test_sentinel_passes_real_recorded_rounds():
-    """The recorded BENCH_r04 -> BENCH_r05 pair is an improvement and
-    must pass the default band."""
-    r04 = os.path.join(REPO, "BENCH_r04.json")
-    r05 = os.path.join(REPO, "BENCH_r05.json")
-    if not (os.path.exists(r04) and os.path.exists(r05)):
-        pytest.skip("recorded bench rounds not present")
-    sentinel = _sentinel()
-    assert sentinel.main([r04, r05]) == 0

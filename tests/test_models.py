@@ -252,12 +252,18 @@ def test_deep_autoencoder_sample():
 def test_mnist_conv_bf16_convergence_pin():
     """Tier-2 convergence under the bf16 precision policy (VERDICT r3
     weak #4): the SAME seeded MNIST-conv run as the 2%-test, forced
-    through compute_dtype=bfloat16, with its own exact pinned early
-    trajectory and converged tail — so a precision-policy regression
-    (e.g. an accumulation silently moved to bf16) fails CI as a degraded
-    converged metric, not just a loose "tracks f32" check.  bf16
-    rounding on this platform is deterministic: the pin is exact
-    (captured twice, bit-identical)."""
+    through compute_dtype=bfloat16, with its own pinned early trajectory
+    and converged tail — so a precision-policy regression (e.g. an
+    accumulation silently moved to bf16) fails CI as a degraded
+    converged metric, not just a loose "tracks f32" check.
+
+    A bf16 trajectory is deterministic on one installation and moves in
+    the last digits between jaxlib versions, so the early counts are
+    held to what it can promise across them: the first epoch's count
+    equal (it precedes any rounding that differs), each of the next five
+    within 3 % of the pin, the sequence falling.  Readings:
+    jax 0.4.37 (when the pin was written) [451, 446, 411, 322, 227, 129];
+    jax 0.9.0                              [451, 445, 410, 319, 232, 126]."""
     import jax.numpy as jnp
 
     prng.seed_all(31)
@@ -268,5 +274,8 @@ def test_mnist_conv_bf16_convergence_pin():
     w.run()
     val = [int(h["metric_validation"]) for h in w.decision.metrics_history]
     # f32 pin for the same seed/config: [451, 443, 411, 315, 228, 128]
-    assert val[:6] == [451, 446, 411, 322, 227, 129], val
+    pin = [451, 446, 411, 322, 227, 129]
+    assert val[0] == pin[0], val
+    np.testing.assert_allclose(val[1:6], pin[1:], rtol=0.03, err_msg=str(val))
+    assert all(a > b for a, b in zip(val[:5], val[1:6])), val
     assert val[-1] <= 10, val    # converged: <= 2% of 500

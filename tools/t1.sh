@@ -1,8 +1,9 @@
 #!/bin/bash
-# Tier-1 verify — the ROADMAP.md command verbatim.  Run from anywhere:
+# Tier-1 verify — the driver's pytest command, flag for flag (the
+# `commands` of its test record), then the smoke legs.  Run from anywhere:
 #   bash tools/t1.sh
-# Exit code is pytest's; DOTS_PASSED echoes the passed-test count the
-# driver greps for.
+# Exit code is pytest's unless a smoke fails; DOTS_PASSED echoes the
+# passed-test count.
 cd "$(dirname "$0")/.." || exit 1
 if ! python -c "import pytest" 2>/dev/null; then
     echo "tools/t1.sh: pytest is not importable in this Python" \
@@ -10,11 +11,10 @@ if ! python -c "import pytest" 2>/dev/null; then
          "or activate the right environment" >&2
     exit 2
 fi
-# pytest wall budget: the suite measured 1033s on a CLEAN seed checkout
-# under this box's current contention (457s at PR 15 — same tests, 2x+
-# theft, see the bench notes), so the old 870 s cap truncated the run
-# before the summary; 1500 keeps the old ~30% headroom over measured
-set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 1500 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
+# ALLOW_MULTIPLE_LIBTPU_LOAD=1 lets the six workers describe a TPU
+# topology side by side HERE, where there is no chip; never send this
+# line to the machine with the chip
+set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
 # The smokes below pin ZNICZ_TPU_COMPILE_CACHE=off in their own workers:
 # a CPU smoke has no use for a persistent cache.  The segfault those
 # pins were once blamed on did not reproduce: PR 21 ran chip_smoke.py

@@ -17,8 +17,7 @@ Scrape surfaces: ``WebStatus`` serves ``GET /metrics`` (Prometheus
 text), ``GET /trace.json`` (ring dump) and ``GET /timeseries.json``
 (watchtower delta ring); ``python -m znicz_tpu trace out.json
 workflow.py`` exports a run's timeline; ``python -m znicz_tpu flight
-artifact.json`` pretty-prints a flight; ``bench.py`` attaches
-``registry.snapshot_flat()`` to result lines.  Metric name catalogue:
+artifact.json`` pretty-prints a flight.  Metric name catalogue:
 docs/OBSERVABILITY.md (statically checked by
 tools/check_metric_catalogue.py).
 """

@@ -21,15 +21,15 @@ concerns; re-declaring with a different type or label set is an error.
 
 Everything is stdlib; one registry-wide lock guards both family
 creation and child mutation (hot-path cost: one uncontended lock + one
-float add, ~1 µs — the ``metrics_overhead`` bench scenario pins the
-end-to-end cost at <2 %).  Counters are process-lifetime monotonic,
-exactly like a real Prometheus client: a supervised restart keeps
-counting, which is what makes restart storms visible on a dashboard.
+float add; its share of a step is not measured on the chip).  Counters
+are process-lifetime monotonic, exactly like a real Prometheus client:
+a supervised restart keeps counting, which is what makes restart storms
+visible on a dashboard.
 
 Export surfaces: ``snapshot()`` (structured dict, merged into
 ``WebStatus.snapshot()`` under ``"metrics"``), ``snapshot_flat()``
-(compact ``name{labels} -> number`` dict, attached to bench JSON
-lines), and ``render_prometheus()`` (text exposition served by
+(compact ``name{labels} -> number`` dict, what the watchtower ring
+samples), and ``render_prometheus()`` (text exposition served by
 ``GET /metrics``).
 """
 
@@ -354,10 +354,9 @@ class Registry:
         """Compact ``name{labels} -> number`` dict (histograms contribute
         ``_count`` / ``_sum`` plus estimated ``_p50`` / ``_p95`` /
         ``_p99`` so SLO rules and time series can target latency
-        quantiles directly) — the per-scenario snapshot bench.py
-        attaches to its JSON result lines and the watchtower ring
-        samples.  ``skip_zero`` drops never-touched series so artifact
-        lines stay small.  ``buckets`` additionally emits each
+        quantiles directly) — what the watchtower ring samples.
+        ``skip_zero`` drops never-touched series so a snapshot stays
+        small.  ``buckets`` additionally emits each
         histogram's cumulative ``name_bucket{...,le="..."}`` counts
         (Prometheus convention) — the watchtower samples with it so
         windowed quantiles can be computed over bucket-count deltas

@@ -8,7 +8,7 @@ pads every batch up to a small fixed set of bucket shapes — powers of
 two up to ``max_batch`` — so warmup compiles each bucket exactly once
 and steady-state serving triggers **zero** recompiles.  An explicit
 ``compile_count`` / ``run_count`` pair makes that property assertable
-(tests and the ``serve`` bench check ``compile_count`` stays flat after
+(tests/test_serve.py checks ``compile_count`` stays flat after
 warmup) instead of inferred from wall-clock jitter.
 
 Backends: ``utils.export.ExportedForward`` (jitted JAX), ``native.infer
@@ -185,11 +185,11 @@ class BatchEngine(Logger):
             self.rows_served += n
         if compiled and observe.enabled():
             # shared telemetry plane: a bucket materializing after warmup
-            # is the steady-state-recompile smell the serve bench asserts
-            # against — make it scrapeable and visible on the timeline,
-            # and record how long the cold bucket cost (the compile-
-            # latency baseline, znicz_compile_seconds + compile.cold
-            # span)
+            # is the steady-state-recompile smell tests/test_serve.py
+            # asserts against — make it scrapeable and visible on the
+            # timeline, and record how long the cold bucket cost (the
+            # compile-latency baseline, znicz_compile_seconds +
+            # compile.cold span)
             observe.counter("znicz_serve_engine_compiles_total",
                             "engine buckets compiled").inc()
             observe.instant("serve.compile", bucket=bucket)

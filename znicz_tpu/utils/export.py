@@ -236,7 +236,12 @@ def aot_fingerprint() -> dict:
     """The backend identity an AOT executable is pinned to.  Serialized
     XLA executables embed device-specific code AND jax/xla version-
     specific calling conventions — every field must match at load time
-    or the executable is untrusted (fall back to JIT, never crash)."""
+    or the executable is untrusted (fall back to JIT, never crash).
+
+    An AOT package serves from ONE device, the process's first, whatever
+    ``num_devices`` reads: each bucket is compiled for it and loaded for
+    it (:func:`_aot_load`), so on a four-chip host the other three run
+    none of it."""
     import jaxlib.version
 
     dev = jax.devices()[0]
@@ -244,6 +249,17 @@ def aot_fingerprint() -> dict:
             "jaxlib": jaxlib.version.__version__,
             "platform": dev.platform, "device_kind": dev.device_kind,
             "num_devices": jax.device_count()}
+
+
+def _aot_load(payload, in_tree, out_tree):
+    """Load one bucket's executable for the device it was compiled for:
+    the process's first, where ``jnp.asarray`` puts the package's params.
+    Left to itself ``deserialize_and_load`` loads for every device of
+    the backend, and the first request then asks for that many shards."""
+    from jax.experimental import serialize_executable as _se
+
+    return _se.deserialize_and_load(payload, in_tree, out_tree,
+                                    execution_devices=[jax.devices()[0]])
 
 
 def aot_mismatch_reason(fp: dict) -> str | None:
@@ -328,7 +344,7 @@ def attach_aot(path: str, max_batch: int = 64,
                     "refusing to write an unloadable package")
             # round-trip check BEFORE writing: a payload that cannot
             # load here will never load anywhere
-            _se.deserialize_and_load(payload, want_in, want_out)
+            _aot_load(payload, want_in, want_out)
             payloads[b] = np.frombuffer(payload, dtype=np.uint8)
     aot_meta = {"fingerprint": aot_fingerprint(),
                 "buckets": list(buckets), "max_batch": int(max_batch),
@@ -419,15 +435,13 @@ class ExportedForward:
         failure degrades to the JIT path with one logged reason."""
         import logging
 
-        from jax.experimental import serialize_executable as _se
-
         log = logging.getLogger("znicz_tpu.export")
         reason = aot_mismatch_reason(aot_meta.get("fingerprint") or {})
         if reason is None:
             try:
                 in_tree, out_tree = _aot_treedefs(self._params, 0)
                 self.precompiled_buckets = {
-                    b: _se.deserialize_and_load(p, in_tree, out_tree)
+                    b: _aot_load(p, in_tree, out_tree)
                     for b, p in sorted(payloads.items())}
             except Exception as exc:  # noqa: BLE001 — a corrupt payload
                 self.precompiled_buckets = {}  # must not kill the boot
