@@ -7,7 +7,9 @@ gradient, the whole cut model's first three steps, the shares adding up to
 the uncut layer, no pair dropped, the refusals by name, and the step unit's
 deferred loss read."""
 
+import contextlib
 import os
+import re
 import sys
 from unittest import mock
 
@@ -124,8 +126,8 @@ def test_cut_model_first_three_steps_follow_the_reference():
                                       abs=1e-7), name
 
 
-def _layer_inputs(seed=3, tokens=24):
-    cfg = _cfg(["conv"], 0, experts_held={"first": 0, "count": 8})
+def _layer_inputs(seed=3, tokens=24, **over):
+    cfg = _cfg(["conv"], 0, experts_held={"first": 0, "count": 8}, **over)
     dm = ref.dims(cfg)
     p = ref.init_leaf_group(seed, cfg, 0)
     v = jax.random.normal(jax.random.PRNGKey(seed), (tokens, dm["d"]))
@@ -180,13 +182,13 @@ def test_no_pair_is_dropped_when_every_token_selects_the_same_experts():
     assert float(stats["pairs_held"]) == 0 and not np.asarray(y).any()
 
 
-def _steered_layer(two_held: int, one_held: int, tokens: int = 512):
+def _steered_layer(two_held: int, one_held: int, tokens: int = 512, **over):
     """A layer of 8 experts, top-2, whose router a feature of each token
     steers: the first ``two_held`` tokens select experts 0 and 1, the
     next ``one_held`` experts 0 and 5, the rest 4 and 5.  A chip that
     holds experts 0 and 1 then counts ``2 * two_held + one_held`` pairs,
     exactly."""
-    dm, p, _ = _layer_inputs()
+    dm, p, _ = _layer_inputs(**over)
     kind = np.full(tokens, 2)
     kind[:two_held] = 0
     kind[two_held:two_held + one_held] = 1
@@ -264,6 +266,116 @@ def test_the_buffer_follows_the_count_and_no_pair_is_dropped(one_held,
                                    rows_of=lambda n, h, e: n)
     for g, want in zip(grads, grads_full):
         np.testing.assert_allclose(g, want, atol=2e-5)
+
+
+#: widths the grouped-product kernels accept (multiples of 128 lanes)
+LANES = {"hidden_size": 128, "moe_intermediate_size": 128}
+
+
+@contextlib.contextmanager
+def _pallas_interpret(on: bool):
+    """``engine.pallas_interpret``: set, the Pallas kernels run wherever
+    the program would pick them on a TPU, interpreted."""
+    from znicz_tpu.core.config import root
+
+    prev = root.common.engine.get("pallas_interpret", False)
+    root.common.engine.pallas_interpret = on
+    try:
+        yield
+    finally:
+        root.common.engine.pallas_interpret = prev
+
+
+@pytest.fixture
+def interpreted_kernels():
+    with _pallas_interpret(True):
+        yield
+
+
+def _grouped_forms(p, v):
+    """How often each form of the grouped product stands in the traced
+    layer and its gradients: ``(kernel calls, lax.ragged_dot calls)``."""
+    def loss(x, w1, w3, w2):
+        return moe.moe_routed_ffn(x, p["gate"], p["ebias"], w1, w3, w2,
+                                  first=0, top_k=2)[0].sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+        v, p["ew1"][:2], p["ew3"][:2], p["ew2"][:2]))
+    # a kernel call is a jitted function of ops/pallas/grouped.py by name
+    return len(re.findall(r"name=gmm_(?:rows_t|rows|weights)\b", text)), \
+        text.count("ragged_dot")
+
+
+@pytest.mark.parametrize("one_held,compact", [(-1, 1.0), (0, 1.0), (1, 0.0)],
+                         ids=["just_under", "equal", "just_over"])
+def test_the_kernels_compact_branch_equals_the_full_one(
+        one_held, compact, interpreted_kernels):
+    """The grouped-product kernels in ``lax.ragged_dot``'s place (forced
+    through interpret mode): 511, 512 and 513 pairs against a compact
+    buffer of 512 give the reference's layer, and the output and all
+    five gradients are the full buffer's."""
+    two_held = 256 if one_held >= 0 else 255
+    dm, p, v = _steered_layer(two_held, abs(one_held), **LANES)
+    assert moe._gmm_kernels(512, 128, 128, 2, v.dtype) is True
+    ct = jax.random.normal(jax.random.PRNGKey(6), v.shape)
+    y, stats, grads = _share_loss(p, v, 0, 2, ct)
+    assert float(stats["pairs_held"]) == 512 + one_held
+    assert float(stats["compact"]) == compact
+    ident = lambda a: a                                     # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = ref._sparse_ffn(
+            {**p, "ew1": p["ew1"][:2], "ew3": p["ew3"][:2],
+             "ew2": p["ew2"][:2]}, v, {**dm, "first": 0, "held": 2},
+            ident, ident)
+    np.testing.assert_allclose(y, want, atol=5e-6)
+    y_full, _, grads_full = _share_loss(p, v, 0, 2, ct,
+                                        rows_of=lambda n, h, e: n)
+    np.testing.assert_allclose(y, y_full, atol=5e-6)
+    for name, g, want in zip(("x", "gate", "ew1", "ew3", "ew2"), grads,
+                             grads_full):
+        assert np.abs(np.asarray(want)).max() > 1e-3, name
+        np.testing.assert_allclose(g, want, atol=5e-5, err_msg=name)
+
+
+def test_the_kernels_take_every_grouped_product_of_both_branches(
+        interpreted_kernels):
+    """All nine or none: with the kernels picked, no ``lax.ragged_dot``
+    is left in either branch, forward or backward."""
+    _, p, v = _steered_layer(256, 0, **LANES)
+    kernels, ragged = _grouped_forms(p, v)
+    # compact: 3 forward + 6 by hand; full: 3 forward, 3 again in the
+    # backward pass with 2 rules each
+    assert ragged == 0 and kernels == 9 + 3 + 3 + 6
+
+
+def test_on_the_cpu_the_grouped_products_are_ragged_dot():
+    """No TPU and no ``pallas_interpret``: ``lax.ragged_dot``, at widths
+    the kernels would take too; and widths they refuse fall back under
+    ``pallas_interpret`` as well."""
+    assert jax.default_backend() == "cpu"
+    assert moe._gmm_kernels(512, 128, 128, 2, jnp.float32) is None
+    assert moe._row_tile(512, 128, 128, 2, jnp.float32) == moe._ROW_TILE
+    _, p, v = _steered_layer(256, 0, **LANES)
+    kernels, ragged = _grouped_forms(p, v)
+    assert kernels == 0 and ragged > 0
+    with _pallas_interpret(True):
+        assert moe._gmm_kernels(512, 32, 24, 2, jnp.float32) is None
+        assert moe._row_tile(512, 128, 128, 2, jnp.float32) == 128
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["ragged_dot", "kernels"])
+def test_tile_fill_is_reckoned_with_the_tile_of_the_form_that_ran(kernels):
+    """512 pairs in two groups of 256: one tile of 512 visited twice by
+    ``lax.ragged_dot`` (0.5), four tiles of 128 visited once (1.0)."""
+    _, p, v = _steered_layer(256, 0, **LANES)
+    with _pallas_interpret(kernels):
+        _, stats = moe.moe_routed_ffn(
+            v, p["gate"], p["ebias"], p["ew1"][:2], p["ew3"][:2],
+            p["ew2"][:2], first=0, top_k=2)
+    assert float(stats["pairs_held"]) == 512
+    assert float(stats["tile_fill"]) == (1.0 if kernels else 0.5)
+    assert "tile_fill" in moe.MEAN_STATS
 
 
 @pytest.mark.parametrize("held,branches", [(8, 0), (2, 2)],
@@ -515,6 +627,10 @@ def test_step_unit_runs_an_architecture_and_publishes_its_counters(
     assert fam is not None and fam.labels(unit=step.name).get() > 0
     fam = registry.REGISTRY.get("znicz_lm_moe_compact_share")
     assert fam is not None and fam.labels(unit=step.name).get() == share
+    fill = step.moe_counters["tile_fill"]
+    assert 0.0 < fill <= 1.0
+    fam = registry.REGISTRY.get("znicz_lm_moe_tile_fill")
+    assert fam is not None and fam.labels(unit=step.name).get() == fill
     with pytest.raises(ValueError, match="gated short convolution"):
         step.export_lm(str(tmp_path / "pkg.npz"))
     # a snapshot restores into the same architecture and no other

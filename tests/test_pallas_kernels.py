@@ -710,3 +710,157 @@ def test_fused_sgd_narrow_state():
             np.asarray(v_pl, dtype=np.float32),
             np.asarray(v_ref.astype(jnp.bfloat16), dtype=np.float32),
             rtol=1e-5, atol=1e-6)
+
+
+# -- the grouped products (ops/pallas/grouped.py) -----------------------------
+#: group sizes over a buffer of rows (tile 128): even; an empty group in
+#: the middle and at the end; one group 3.9 x the mean; a boundary on a
+#: tile's edge; 511 / 512 / 513 live rows against 512; nothing live
+GROUPED_CASES = {
+    "even": ([128, 128, 128, 128], 512),
+    "empty_groups": ([100, 0, 300, 50, 0], 512),
+    "one_of_3.9_means": ([499, 20, 20, 20, 190, 19, 1, 255], 1280),
+    "boundary_on_a_tile_edge": ([128, 256, 3, 125], 640),
+    "live_511_of_512": ([0, 511], 512),
+    "live_512_of_512": ([200, 312], 512),
+    "live_513_over_512": ([200, 313], 640),
+    "nothing_live": ([0, 0, 0], 256),
+}
+
+
+def _grouped_operands(case, k=128, n=256):
+    """-> ``(sizes, live, a, g, w)``: float32 operands whose rows past
+    the last live one hold NaN."""
+    sizes, rows = GROUPED_CASES[case]
+    rng = np.random.default_rng(len(case))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    live = (jnp.arange(rows) < sizes.sum())[:, None]
+    a = jnp.where(live, rng.normal(size=(rows, k)).astype(np.float32),
+                  jnp.nan)
+    g = jnp.where(live, rng.normal(size=(rows, n)).astype(np.float32),
+                  jnp.nan)
+    w = jnp.asarray(rng.normal(size=(sizes.shape[0], k, n)), jnp.float32)
+    return sizes, live, a, g, w
+
+
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+@pytest.mark.parametrize("kernel", ["rows", "rows_t", "weights"])
+def test_grouped_product_kernels_match_ragged_dot(kernel, case):
+    """Each kernel against ``lax.ragged_dot`` / ``ragged_dot_general`` in
+    float32; the buffer's tail holds NaN going in and comes out as zeros
+    (and counts for nothing in the weights' gradient)."""
+    import jax
+    from jax import lax
+    from znicz_tpu.ops.pallas import grouped
+    from znicz_tpu.parallel.moe import _TO_WEIGHTS
+
+    sizes, live, a, g, w = _grouped_operands(case)
+    a0, g0 = jnp.where(live, a, 0), jnp.where(live, g, 0)
+    with jax.default_matmul_precision("highest"):
+        if kernel == "rows":
+            got = grouped.gmm_rows(a, w, sizes, interpret=True)
+            want = jnp.where(live, lax.ragged_dot(a0, w, sizes), 0)
+        elif kernel == "rows_t":
+            got = grouped.gmm_rows_t(g, w, sizes, interpret=True)
+            want = jnp.where(live, jax.linear_transpose(
+                lambda x: lax.ragged_dot(x, w, sizes), a0)(g0)[0], 0)
+        else:
+            got = grouped.gmm_weights(a, g, sizes, interpret=True)
+            want = lax.ragged_dot_general(a0, g0, sizes, _TO_WEIGHTS)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=2e-4)
+    if kernel != "weights":
+        assert not np.asarray(got)[int(sizes.sum()):].any()   # the tail
+
+
+@pytest.mark.parametrize("slabs", [1, 2], ids=["whole_block", "two_slabs"])
+def test_grouped_product_is_differentiable_and_splits_wide_weights(
+        slabs, monkeypatch):
+    """``gmm``'s rules are the two other kernels; with a block budget of
+    half a weight the same products run over two slabs."""
+    import jax
+    from jax import lax
+    from znicz_tpu.ops.pallas import grouped
+
+    sizes, live, a, g, w = _grouped_operands("one_of_3.9_means", 256, 512)
+    a = jnp.where(live, a, 0)
+    monkeypatch.setattr(grouped, "_BLOCK_BYTES", 256 * 512 * 4 // slabs)
+    assert grouped._slab(256, 512, 4) == 512 // slabs
+    assert grouped._slab(512, 256, 4) == 256 // slabs
+
+    def loss(f):
+        return lambda a, w: (f(a, w) * jnp.where(live, g, 0)).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(lambda a, w: grouped.gmm(a, w, sizes, True)),
+                       argnums=(0, 1))(a, w)
+        want = jax.grad(loss(lambda a, w: jnp.where(
+            live, lax.ragged_dot(a, w, sizes), 0)), argnums=(0, 1))(a, w)
+    for name, x, y in zip(("rows", "weights"), got, want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5,
+                                   atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("sizes,tile,fill", [
+    ([128, 128, 128, 128], 128, 1.0), ([128, 128, 128, 128], 512, 0.25),
+    ([100, 0, 300, 50], 128, 450 / 768), ([0, 0], 128, 1.0),
+    ([511, 1], 512, 0.5)])
+def test_grouped_tile_fill_counts_a_shared_tile_twice(sizes, tile, fill):
+    from znicz_tpu.ops.pallas import grouped
+
+    got = float(grouped.tile_fill(jnp.asarray(sizes, jnp.int32), tile))
+    assert got == pytest.approx(fill)
+    # and the walk the kernels take has that many visits of live groups
+    rows = -(-max(sum(sizes), 1) // tile) * tile
+    offsets, group, tile_of, out_tile, n = grouped.plan(
+        jnp.asarray(sizes, jnp.int32), rows, tile)
+    visits = int(n[0]) - sum(1 for s in sizes if s == 0)
+    assert sum(sizes) == pytest.approx(fill * tile * max(visits, 1)) or \
+        not sum(sizes)
+    assert list(np.asarray(offsets)) == list(np.cumsum([0] + sizes))
+    assert (np.diff(np.asarray(out_tile)) >= 0).all()
+    assert (np.asarray(tile_of)[:int(n[0])] ==
+            np.asarray(out_tile)[:int(n[0])]).all()
+
+
+@pytest.mark.parametrize("shape,dtype,word", [
+    ((500, 128, 256, 4), jnp.float32, "128-row tile"),
+    ((512, 100, 256, 4), jnp.float32, "128 lanes"),
+    ((512, 128, 256, 4), jnp.float16, "bfloat16 or float32"),
+    ((512, 128, 32768, 4), jnp.bfloat16, "MiB"),
+    ((512, 128, 256, 0), jnp.bfloat16, "no group")])
+def test_grouped_unsupported_reason_names_the_refused_shape(shape, dtype,
+                                                            word):
+    from znicz_tpu.ops.pallas import grouped
+
+    assert grouped.unsupported_reason(12288, 2048, 1536, 16,
+                                      jnp.bfloat16) is None
+    why = grouped.unsupported_reason(*shape, dtype)
+    assert why and word in why
+    rows, k, n, held = shape
+    if held:
+        with pytest.raises(ValueError, match="grouped product"):
+            grouped.gmm_rows(jnp.zeros((rows, k), dtype),
+                             jnp.zeros((held, k, n), dtype),
+                             jnp.zeros((held,), jnp.int32), interpret=True)
+
+
+@pytest.mark.parametrize("kernel", ["ROWS", "ROWS_T", "WEIGHTS"])
+def test_grouped_kernel_names_are_found_by_the_benchmarks_pattern(kernel):
+    """``moe_gmm_roofline`` divides nine products' least time by the time
+    of whatever its pattern finds: all three names match it, or the share
+    is of a part (over 100 %) or of nothing (a traced line without it)."""
+    import importlib.util
+    import os
+
+    from znicz_tpu.ops.pallas import grouped
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "kernels", "moe_gmm.py")
+    spec = importlib.util.spec_from_file_location("_kernels_moe_gmm", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    (call,) = mod.calls_per_step({}, {})
+    name = getattr(grouped, kernel + "_KERNEL_NAME")
+    assert call["pattern"] in name and name.startswith("moe_gmm_")

@@ -25,3 +25,5 @@ from znicz_tpu.ops.pallas.attention import flash_attention  # noqa: F401
 from znicz_tpu.ops.pallas.adam import fused_adam_update  # noqa: F401
 from znicz_tpu.ops.pallas.gemm import (  # noqa: F401
     fc_backward, fc_forward, matmul)
+from znicz_tpu.ops.pallas.grouped import (  # noqa: F401
+    gmm, gmm_rows, gmm_rows_t, gmm_weights)

@@ -26,6 +26,7 @@ Three regimes (docs/TUNING.md "MoE"):
 from __future__ import annotations
 
 import functools
+import logging
 
 import numpy as np
 
@@ -34,6 +35,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from znicz_tpu.observe import probe as _probe
+from znicz_tpu.ops.pallas import grouped as _gmm
+
+_log = logging.getLogger("znicz_tpu.moe")
 
 
 def moe_ffn(x, gate_w, w1_local, b1_local, w2_local, b2_local,
@@ -243,12 +247,13 @@ _sum_of_pairs.defvjp(_sum_of_pairs_fwd, _sum_of_pairs_bwd)
 #: the compact branch, and a load beyond it is computed whole by the
 #: full buffer, slower and never short of a pair.
 _COMPACT_SLACK = 1.5
-#: rows are rounded up to the grouped product's row tile (the v5e's
+#: rows are rounded up to ``lax.ragged_dot``'s row tile (the v5e's
 #: kernel walks rows by 512: its tile table in the compiled step has
-#: ``rows / 512 + held - 1`` entries, 39 at 12,288 rows and 79 at 32,768)
+#: ``rows / 512 + held - 1`` entries, 39 at 12,288 rows and 79 at 32,768),
+#: a multiple of the Pallas kernels' (``ops/pallas/grouped.py::ROW_TILE``)
 _ROW_TILE = 512
 #: counters that are averaged, not summed, over layers and shards
-MEAN_STATS = ("load_max_over_mean", "compact")
+MEAN_STATS = ("load_max_over_mean", "compact", "tile_fill")
 
 
 def compact_rows(n_pairs: int, held: int, n_experts: int) -> int:
@@ -260,13 +265,85 @@ def compact_rows(n_pairs: int, held: int, n_experts: int) -> int:
     return min(-(-rows // _ROW_TILE) * _ROW_TILE, n_pairs)
 
 
+@functools.lru_cache(maxsize=None)
+def _report_kernel_refusal(shape: tuple, why: str) -> None:
+    """The grouped-product kernels were eligible by platform and the
+    shape turned them down: say so, once per shape per process."""
+    _log.warning("grouped-product kernels refused rows=%d k=%d n=%d "
+                 "held=%d: %s; this layer uses lax.ragged_dot", *shape, why)
+
+
+def _gmm_kernels(rows: int, k: int, n: int, held: int, dtype):
+    """THE choice between the two forms of a grouped product of ``rows``
+    rows with ``(held, k, n)`` weights (``k`` and ``n`` in either
+    order), by what can be observed, as the flash kernels are picked
+    (``transformer.py::_flash_eligible``): the Pallas kernels
+    (``ops/pallas/grouped.py``) on a TPU, or interpreted wherever
+    ``root.common.engine.pallas_interpret`` is set, at shapes they
+    accept; ``lax.ragged_dot`` elsewhere.  -> ``None`` for
+    ``lax.ragged_dot``, else the kernels' ``interpret`` argument."""
+    from znicz_tpu.core.config import root
+    interpret = bool(root.common.engine.get("pallas_interpret", False))
+    if not interpret and jax.default_backend() != "tpu":
+        return None
+    why = _gmm.unsupported_reason(rows, k, n, held, dtype)
+    if why:
+        _report_kernel_refusal((rows, k, n, held), why)
+        return None
+    return interpret
+
+
+def _row_tile(rows: int, k: int, n: int, held: int, dtype) -> int:
+    """Rows to a tile of the grouped product :func:`_gmm_kernels` picks."""
+    return _ROW_TILE if _gmm_kernels(rows, k, n, held, dtype) is None \
+        else _gmm.ROW_TILE
+
+
+#: the grouped product's gradient to its weights on the ``lax.ragged_dot``
+#: path: each group's rows of the left operand, transposed, times its
+#: rows of the result's cotangent (what AD's own transpose of
+#: ``lax.ragged_dot`` builds)
+_TO_WEIGHTS = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+
 def _grouped(a, w, sizes, live):
     """``a``'s rows times their group's weights (cast to ``a``'s dtype),
-    cut to zeros on the buffer's tail.  The tail's rows are nobody's, and
-    a grouped product leaves them as it found them (whatever the memory
+    zeros on the buffer's tail.  The tail's rows are nobody's, and
+    ``lax.ragged_dot`` leaves them as it found them (whatever the memory
     held, NaN included): each result is cut BEFORE it meets another
-    factor, so that no gradient is a zero times that."""
-    return jnp.where(live, lax.ragged_dot(a, w.astype(a.dtype), sizes), 0)
+    factor, so that no gradient is a zero times that.  The kernels write
+    the zeros themselves.  Differentiable in both forms (the kernels'
+    rules are the two other kernels)."""
+    interpret = _gmm_kernels(*a.shape, w.shape[2], w.shape[0], a.dtype)
+    if interpret is None:
+        return jnp.where(live, lax.ragged_dot(a, w.astype(a.dtype), sizes),
+                         0)
+    return _gmm.gmm(a, w.astype(a.dtype), sizes, interpret)
+
+
+def _grouped_to_rows(g, w, sizes, live):
+    """:func:`_grouped`'s gradient to its rows, cut like its result: on
+    the kernels' path read from ``w`` as it is stored."""
+    w = w.astype(g.dtype)
+    rows, (held, k, n) = g.shape[0], w.shape
+    interpret = _gmm_kernels(rows, k, n, held, g.dtype)
+    if interpret is None:
+        return jnp.where(live, jax.linear_transpose(
+            lambda a: lax.ragged_dot(a, w, sizes),
+            jax.ShapeDtypeStruct((rows, k), g.dtype))(g)[0], 0)
+    return _gmm.gmm_rows_t(g, w, sizes, interpret=interpret)
+
+
+def _grouped_to_weights(a, g, sizes, dtype):
+    """:func:`_grouped`'s gradient to its weights, in ``dtype`` (float32
+    masters: as the products accumulate it, unrounded)."""
+    interpret = _gmm_kernels(*a.shape, g.shape[1], sizes.shape[0], a.dtype)
+    if interpret is None:
+        return lax.ragged_dot_general(a, g, sizes, _TO_WEIGHTS,
+                                      preferred_element_type=dtype)
+    return _gmm.gmm_weights(a, g, sizes, interpret=interpret).astype(dtype)
 
 
 def _pairs_full(x, weight, w1, w3, w2, order, slot_of, sizes, top_k: int,
@@ -288,14 +365,6 @@ def _pairs_full(x, weight, w1, w3, w2, order, slot_of, sizes, top_k: int,
     with _probe.scope(f"{scope}.route"):
         ys = ys * ws[:, None].astype(ys.dtype)
         return _sum_of_pairs(ys, token_of, slot_of, top_k)
-
-
-#: the grouped product's gradient to its weights: each group's rows of
-#: the left operand, transposed, times its rows of the result's
-#: cotangent (what AD's own transpose of ``lax.ragged_dot`` builds)
-_TO_WEIGHTS = lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(((0,), (0,)), ((), ())),
-    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
 
 
 def _compact_index(order, sizes, rows: int, top_k: int):
@@ -343,22 +412,18 @@ def _compact_fwd(x, weight, w1, w3, w2, order, slot_of, sizes, rows: int,
 
 
 def _compact_bwd(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g,
-                 rows: int, top_k: int, act, scope: str):
+                 rows: int, top_k: int, act, scope: str, dtypes: tuple):
     """:func:`_compact_fwd`'s gradients to ``(x, weight, w1, w3, w2)``
     from ``h1``, ``h3`` and a second gather of the rows: six grouped
     products (each the transpose AD itself would take), none computed
     twice, and nothing kept at the full buffer's size.  The weights'
-    gradients leave their products in the weights' own dtype (float32
-    masters: unrounded)."""
-    def to_rows(g_out, w, like):
-        # :func:`_grouped`'s gradient to its rows, cut like its result
-        w = w.astype(like.dtype)
-        return jnp.where(live, jax.linear_transpose(
-            lambda a: lax.ragged_dot(a, w, sizes), like)(g_out)[0], 0)
+    gradients leave their products in ``dtypes``, their masters' (float32:
+    unrounded)."""
+    def to_rows(g_out, w):
+        return _grouped_to_rows(g_out, w, sizes, live)
 
-    def to_weights(a, g_out, w):
-        return lax.ragged_dot_general(a, g_out, sizes, _TO_WEIGHTS,
-                                      preferred_element_type=w.dtype)
+    def to_weights(a, g_out, dtype):
+        return _grouped_to_weights(a, g_out, sizes, dtype)
 
     with _probe.scope_bwd(f"{scope}.route"):
         order_c, token_c, n_held, live = _compact_index(order, sizes, rows,
@@ -370,11 +435,12 @@ def _compact_bwd(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g,
         hh, hh_vjp = jax.vjp(lambda a, b: act(a) * b, h1, h3)
         # ys = (hh w2) * ws: the product's own cotangent gives both the
         # weights' (its rows against hh's) and hh's (times ws)
-        t = to_rows(gy, w2, hh)
-        d_w2 = to_weights(hh * ws.astype(hh.dtype), gy, w2)
+        t = to_rows(gy, w2)
+        d_w2 = to_weights(hh * ws.astype(hh.dtype), gy, dtypes[2])
         d_h1, d_h3 = hh_vjp(t * ws.astype(t.dtype))
-        d_w1, d_w3 = to_weights(xs, d_h1, w1), to_weights(xs, d_h3, w3)
-        d_xs = to_rows(d_h1, w1, xs) + to_rows(d_h3, w3, xs)
+        d_w1 = to_weights(xs, d_h1, dtypes[0])
+        d_w3 = to_weights(xs, d_h3, dtypes[1])
+        d_xs = to_rows(d_h1, w1) + to_rows(d_h3, w3)
     with _probe.scope_bwd(f"{scope}.route"):
         d_ws = (t.astype(jnp.float32) * hh.astype(jnp.float32)).sum(-1)
         d_weight = jnp.zeros(weight.shape, weight.dtype).at[order_c].set(
@@ -392,14 +458,21 @@ def _pairs_either(x, weight, w1, w3, w2, order, slot_of, sizes, rows: int,
     make each branch of the choice write the other's residuals as zeros:
     the compact branch keeps its two products (:func:`_compact_bwd`),
     the full one keeps nothing and takes :func:`_pairs_full` again in
-    the backward pass."""
+    the backward pass.  The experts' weights are cast to ``x``'s dtype
+    once, before the choice, and both passes of either branch read that
+    cast: a grouped product and its gradient to the rows take a weight
+    in the same layout (ops/pallas/grouped.py), so the backward pass
+    makes no second pass over the masters."""
     return _pairs_either_fwd(x, weight, w1, w3, w2, order, slot_of, sizes,
                              rows, top_k, act, scope)[0]
 
 
 def _pairs_either_fwd(x, weight, w1, w3, w2, order, slot_of, sizes, rows,
                       top_k, act, scope):
-    args = (x, weight, w1, w3, w2, order, slot_of, sizes)
+    masters = (w1, w3, w2)
+    with _probe.scope(f"{scope}.experts"):
+        cast = tuple(w.astype(x.dtype) for w in masters)
+    args = (x, weight, *cast, order, slot_of, sizes)
 
     def full(*a):
         blank = jnp.zeros((rows, w1.shape[-1]), x.dtype)
@@ -410,23 +483,25 @@ def _pairs_either_fwd(x, weight, w1, w3, w2, order, slot_of, sizes, rows,
     y, kept = lax.cond(
         fits, lambda *a: _compact_fwd(*a, rows, top_k, act, scope), full,
         *args)
-    return y, (args, kept)
+    return y, (args, kept, masters)
 
 
 def _pairs_either_bwd(rows, top_k, act, scope, res, g):
-    args, kept = res
+    args, kept, masters = res       # the masters: their gradients' dtypes
+    dtypes = tuple(w.dtype for w in masters)
 
     def full(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g):
-        return jax.vjp(
+        d_x, d_weight, *d_w = jax.vjp(
             lambda *a: _pairs_full(*a, order, slot_of, sizes, top_k, act,
                                    scope),
             x, weight, w1, w3, w2)[1](g)
+        return (d_x, d_weight, *(d.astype(t) for d, t in zip(d_w, dtypes)))
 
     with _probe.scope_bwd(f"{scope}.route"):
         fits = args[-1].sum() <= rows           # sizes: as the forward chose
     grads = lax.cond(
-        fits, lambda *a: _compact_bwd(*a, rows, top_k, act, scope), full,
-        *args, *kept, g)
+        fits, lambda *a: _compact_bwd(*a, rows, top_k, act, scope, dtypes),
+        full, *args, *kept, g)
     return (*grads, None, None, None)
 
 
@@ -456,16 +531,20 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
     there, all ``tokens * top_k`` (the static worst case, every pair
     held) when they do not, chosen on the device by the count.  No
     capacity and no drop at any load; a share of all ``E`` experts has
-    the one buffer.  The grouped products (``lax.ragged_dot``, on a TPU
-    a kernel that walks only the tiles its group sizes cover) never
-    touch a buffer's tail.  Each pair's result is weighted by its router
-    weight, normalised over all ``top_k`` selected experts whether held
-    or not, and summed into its token.
+    the one buffer.  The grouped products (:func:`_gmm_kernels`: on a
+    TPU the Pallas kernels of ``ops/pallas/grouped.py``, elsewhere
+    ``lax.ragged_dot``) walk only the row tiles their group sizes cover
+    and never read a buffer's tail.  Each pair's result is weighted by
+    its router weight, normalised over all ``top_k`` selected experts
+    whether held or not, and summed into its token.
 
     Returns ``(y (tokens, d), stats)``; ``stats`` holds float32 scalars
     ``pairs_held`` (pairs routed to held experts), ``load_max_over_mean``
-    (the fullest held expert's pairs over the held experts' mean) and
-    ``compact`` (1.0 when the compact buffer carried the layer).  The
+    (the fullest held expert's pairs over the held experts' mean),
+    ``compact`` (1.0 when the compact buffer carried the layer) and
+    ``tile_fill`` (held pairs over the row-slots the grouped products
+    visit: a row tile that two groups share is visited twice; reckoned
+    with the row tile of the form that ran).  The
     work lies under two scopes of the program, ``<scope>.route``
     (scores, top-k, sort, gather, scatter) and ``<scope>.experts`` (the
     grouped products): siblings by name, since an operation counts for
@@ -493,9 +572,12 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
         y = _pairs_full(*stage, top_k, act, scope)
     with _probe.scope(f"{scope}.route"):
         sizes_f = sizes.astype(jnp.float32)
+        compact = (n_held <= rows) & (rows < n_pairs)
+        fill_c, fill_f = (_gmm.tile_fill(sizes, _row_tile(
+            r, d, w1.shape[2], held, x.dtype)) for r in (rows, n_pairs))
         stats = {"pairs_held": n_held.astype(jnp.float32),
                  "load_max_over_mean":
                      sizes_f.max() / jnp.maximum(sizes_f.mean(), 1e-9),
-                 "compact": ((n_held <= rows) & (rows < n_pairs)).astype(
-                     jnp.float32)}
+                 "compact": compact.astype(jnp.float32),
+                 "tile_fill": jnp.where(compact, fill_c, fill_f)}
     return y, stats

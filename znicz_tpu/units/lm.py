@@ -281,17 +281,20 @@ class TransformerLMStep(AcceleratedUnit):
             self._publish_moe(float(sums["pairs_held"]) / steps,
                               float(sums["load_max_over_mean"]) / steps,
                               float(sums["compact"]) / steps,
+                              float(sums["tile_fill"]) / steps,
                               float(sums["pairs_held"]))
 
     def _publish_moe(self, pairs_a_step: float, load_ratio: float,
-                     compact_share: float, pairs: float) -> None:
+                     compact_share: float, tile_fill: float,
+                     pairs: float) -> None:
         """A finished pass's routed-expert counters: the unit's mirror
         and the process registry (docs/OBSERVABILITY.md)."""
         from znicz_tpu.observe import registry
 
         self.moe_counters = {"pairs_held_per_step": pairs_a_step,
                              "load_max_over_mean": load_ratio,
-                             "compact_share": compact_share}
+                             "compact_share": compact_share,
+                             "tile_fill": tile_fill}
         registry.counter(
             "znicz_lm_moe_pairs_held_total",
             "(token, choice) pairs routed to experts this chip holds",
@@ -307,6 +310,12 @@ class TransformerLMStep(AcceleratedUnit):
             "held pairs fitted the compact pairs buffer (the rest took "
             "the full one; none dropped a pair)",
             ("unit",)).labels(unit=self.name).set(compact_share)
+        registry.gauge(
+            "znicz_lm_moe_tile_fill",
+            "held pairs over the row-slots the routed layers' grouped "
+            "products visited (a row tile two experts share is visited "
+            "twice), averaged over the layers and the last class pass",
+            ("unit",)).labels(unit=self.name).set(tile_fill)
 
     # -- serving handoff (ISSUE 10) -----------------------------------------
     def export_lm(self, path: str,
