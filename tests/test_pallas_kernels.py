@@ -362,6 +362,122 @@ def test_flash_attention_supported_gate():
     assert not supported(1 << 20, 64)  # VMEM budget
 
 
+@pytest.mark.parametrize("t,dh,causal", [
+    (384, 256, True), (384, 64, True), (640, 64, True), (256, 128, False)],
+    ids=["3x128_dh256", "3x128_dh64", "5x128_dh64", "full_dh128"])
+def test_blocked_flash_attention_matches_dense(t, dh, causal):
+    """The key/value-blocked kernels (interpreted), forward and the three
+    gradients, against dense attention: heads of 256 and 64, a time axis
+    that is 3 and 5 blocks (no power of two times the block), causal (only
+    the tiles under the diagonal are visited) and full."""
+    import jax
+
+    from znicz_tpu.ops import attention as att
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, h = 1, 2
+    ks = jax.random.split(jax.random.PRNGKey(t + dh), 4)
+    q, k, v, ct = (jax.random.normal(kk, (b, t, h, dh)) for kk in ks)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, dh)  # noqa
+
+    def blocked(q, k, v):
+        o = pattn._flash_kvb(fold(q), fold(k), fold(v), causal, True)
+        return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
+
+    def dense(q, k, v):
+        return att.attention(jnp, q, k, v, causal=causal)
+
+    block = pattn._kvb_block(t)
+    assert block == (128 if t % 256 else 256)
+    n = t // block
+    qi, ki, flags = pattn._visits(t, block, causal, False)
+    assert len(qi) == (n * (n + 1) // 2 if causal else n * n)
+    assert not causal or all(ki <= qi)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(blocked(q, k, v), dense(q, k, v),
+                                   atol=2e-5)
+        got = jax.grad(lambda *a: (blocked(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+        want = jax.grad(lambda *a: (dense(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+def test_blocked_flash_attention_repeats_grouped_key_value_heads():
+    """``flash_attention`` at a shape only the blocked form takes, with
+    fewer key/value heads: the heads are repeated for the kernels and the
+    gradients sum back over each group."""
+    import jax
+    from unittest import mock
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, t, kv, group, dh = 1, 256, 1, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (b, t, kv * group, dh))
+    k = jax.random.normal(ks[1], (b, t, kv, dh))
+    v = jax.random.normal(ks[2], (b, t, kv, dh))
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v, causal=True,
+                                   interpret=True) ** 2).sum()
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(loss(pattn.flash_attention), (0, 1, 2))(q, k, v)
+        with mock.patch.object(pattn, "unsupported_reason",
+                               lambda t, dh: "refused for the test"):
+            assert pattn.form_of(t, dh) == ("blocked", None)
+            text = str(jax.make_jaxpr(pattn.flash_attention)(q, k, v))
+            got = jax.grad(loss(pattn.flash_attention), (0, 1, 2))(q, k, v)
+    assert pattn.KVB_FWD_KERNEL_NAME in text
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-4)
+
+
+def test_flash_attention_keeps_the_whole_row_form_wherever_it_accepted():
+    """At every shape the whole-row kernels took (the benchmark's GQA
+    layer: head 64 at 4,096) ``flash_attention`` is the program it was,
+    to the bit: the same two kernels and none of the blocked ones."""
+    import jax
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    assert pattn.form_of(4096, 64) == ("rows", None)
+    assert pattn.unsupported_reason(4096, 64) is None
+    assert pattn.unsupported_reason(4096, 256) is not None
+    assert pattn.blocked_unsupported_reason(4096, 256) is None
+    b, t, h, kv, dh = 1, 256, 4, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = jax.random.normal(ks[0], (b, t, h, dh))
+    k, v = (jax.random.normal(kk, (b, t, kv, dh)) for kk in ks[1:])
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(   # noqa: E731
+        b * x.shape[2], t, dh)
+    grad = jax.grad(lambda *a: pattn.flash_attention(
+        *a, causal=True, interpret=True).sum(), (0, 1, 2))
+    text = str(jax.make_jaxpr(grad)(q, k, v))
+    assert pattn.FWD_KERNEL_NAME in text and pattn.BWD_KERNEL_NAME in text
+    assert "kvb" not in text
+    direct = pattn._flash(fold(q), fold(k), fold(v), True, True)
+    np.testing.assert_array_equal(
+        pattn.flash_attention(q, k, v, causal=True, interpret=True),
+        direct.reshape(b, h, t, dh).transpose(0, 2, 1, 3))
+
+
+@pytest.mark.parametrize("t,dh,word", [
+    (100, 64, "t=100"), (4096, 48, "head_dim=48"),
+    (4096, 1024, "head_dim=1024")])
+def test_blocked_unsupported_reason_names_the_refused_shape(t, dh, word):
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    assert word in pattn.blocked_unsupported_reason(t, dh)
+    assert pattn.form_of(t, dh)[0] is None
+    with pytest.raises(ValueError, match=word):
+        pattn.flash_attention(jnp.zeros((1, t, 1, dh)),
+                              jnp.zeros((1, t, 1, dh)),
+                              jnp.zeros((1, t, 1, dh)))
+
+
 def test_fused_adam_matches_oracle():
     from znicz_tpu.ops import adam as adam_ops
     from znicz_tpu.ops.pallas import fused_adam_update

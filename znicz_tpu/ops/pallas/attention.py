@@ -29,11 +29,20 @@ to each key/value head the folded q is ``(b*h, t, dh)`` and k, v are
 is fetched once for its whole group, and the backward kernel's dk/dv
 block stays resident over the group's ``group * t/block_q`` consecutive
 steps and sums over them.  ``group == 1`` is the program it always was.
+
+A second, KEY/VALUE-BLOCKED form (the ``flash_attention_kvb_*`` kernels,
+below the whole-row ones) takes the shapes whose whole key/value head no
+longer fits: a q block meets one key/value block at a time and keeps the
+running maximum, sum and accumulator in VMEM, so its VMEM is a function of
+the block sizes and the head width only, and under a causal mask the
+blocks wholly above the diagonal are in no kernel's grid: neither fetched
+nor computed.  :func:`form_of` says which form a shape gets; the whole-row
+form keeps every shape it took.
 """
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import numpy as np
 
@@ -261,8 +270,306 @@ def _flash_lse_bwd(causal, interpret, res, cts):
 flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+# -- the key/value-blocked form -----------------------------------------------
+
+#: the blocked kernels' names in the lowered program and in device traces
+KVB_FWD_KERNEL_NAME = "flash_attention_kvb_fwd"
+KVB_DKV_KERNEL_NAME = "flash_attention_kvb_dkv"
+KVB_DQ_KERNEL_NAME = "flash_attention_kvb_dq"
+
+#: rows of a q block and of a key/value block, the largest that divides t
+_KVB_BLOCKS = (512, 256, 128)
+#: scoped VMEM asked of the compiler: the widest head taken (512) holds
+#: six double-buffered bf16 blocks, two f32 accumulators and four f32
+#: score tiles in 11 MiB
+_KVB_VMEM_LIMIT = 32 * 1024 * 1024
+#: a visit's flags: the first and the last of its run (the visits that
+#: share the block the kernel accumulates for), and whether the diagonal
+#: cuts the tile (only then is the mask computed)
+_FIRST, _LAST, _CUT = 1, 2, 4
+_MASKED = -1e30
+
+
+def _kvb_block(t: int) -> int:
+    return next((b for b in _KVB_BLOCKS if t % b == 0), 0)
+
+
+@lru_cache(maxsize=None)
+def _visits(t: int, block: int, causal: bool, by_kv: bool):
+    """The (q block, key/value block) tiles a pass visits, as three static
+    int32 tables ``(q block, kv block, flags)``: under ``causal`` only the
+    tiles with an unmasked entry.  Ordered by q block (the forward pass
+    and dq, which accumulate over key/value blocks) or ``by_kv`` (dk and
+    dv, which accumulate over q blocks); the kernels' grids walk the
+    tables, so a tile that is not listed costs no step and no fetch."""
+    n = t // block
+    pairs = [(i, j) for i in range(n) for j in range(n)
+             if not causal or j <= i]
+    outer = 1 if by_kv else 0
+    pairs.sort(key=lambda ij: (ij[outer], ij[1 - outer]))
+    flags = []
+    for v, (i, j) in enumerate(pairs):
+        first = v == 0 or pairs[v - 1][outer] != pairs[v][outer]
+        last = v + 1 == len(pairs) or pairs[v + 1][outer] != pairs[v][outer]
+        flags.append(_FIRST * first + _LAST * last +
+                     _CUT * (causal and i == j))
+    return (np.asarray([i for i, _ in pairs], np.int32),
+            np.asarray([j for _, j in pairs], np.int32),
+            np.asarray(flags, np.int32))
+
+
+def _nt(a, b):
+    """``a @ b.T`` in float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _either_tile(flags, tile):
+    """Run ``tile(cut)`` once, with the mask only where the diagonal cuts
+    the tile."""
+    cut = (flags & _CUT) != 0
+    pl.when(cut)(partial(tile, True))
+    pl.when(jnp.logical_not(cut))(partial(tile, False))
+
+
+def _cut_scores(s, rows_are_q: bool):
+    """The diagonal tile's mask: q and key/value blocks are as long, so
+    inside the tile position equals index."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0 if rows_are_q else 1)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 if rows_are_q else 0)
+    return jnp.where(kpos > qpos, jnp.float32(_MASKED), s)
+
+
+def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
+                    lse_ref, m_sc, l_sc, acc_sc, *, sm_scale: float):
+    flags = fl_ref[pl.program_id(1)]
+
+    @pl.when((flags & _FIRST) != 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, _MASKED)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def tile(cut: bool):
+        v = v_ref[0]
+        s = _nt(q_ref[0], k_ref[0]) * sm_scale             # (bq, bk)
+        if cut:
+            s = _cut_scores(s, True)
+        m_prev = m_sc[...]
+        # key/value block 0 comes first and every query sees key 0, so
+        # the running maximum is a real score from the first visit on
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
+        acc_sc[...] = alpha * acc_sc[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    _either_tile(flags, tile)
+
+    @pl.when((flags & _LAST) != 0)
+    def _store():
+        l = l_sc[...]
+        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_sc[...] + jnp.log(l)
+
+
+def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *,
+                    sm_scale: float):
+    """dk and dv of one key/value block, summed over the q blocks that see
+    it.  Everything is held TRANSPOSED (key/value rows down, queries
+    across; ``lse`` and ``delta`` arrive as rows), so that all four
+    products are plain or ``a @ b.T`` and no score tile is transposed."""
+    flags = fl_ref[pl.program_id(1)]
+
+    @pl.when((flags & _FIRST) != 0)
+    def _init():
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
+
+    def tile(cut: bool):
+        q, do = q_ref[0], do_ref[0]
+        s = _nt(k_ref[0], q) * sm_scale                    # (bk, bq)
+        if cut:
+            s = _cut_scores(s, False)
+        p = jnp.exp(s - lse_ref[0])
+        dv_sc[...] += jnp.dot(p.astype(do.dtype), do,
+                              preferred_element_type=jnp.float32)
+        ds = p * (_nt(v_ref[0], do) - delta_ref[0]) * sm_scale
+        dk_sc[...] += jnp.dot(ds.astype(q.dtype), q,
+                              preferred_element_type=jnp.float32)
+
+    _either_tile(flags, tile)
+
+    @pl.when((flags & _LAST) != 0)
+    def _store():
+        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _kvb_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dq_ref, dq_sc, *, sm_scale: float):
+    flags = fl_ref[pl.program_id(1)]
+
+    @pl.when((flags & _FIRST) != 0)
+    def _init():
+        dq_sc[...] = jnp.zeros_like(dq_sc)
+
+    def tile(cut: bool):
+        k = k_ref[0]
+        s = _nt(q_ref[0], k) * sm_scale                    # (bq, bk)
+        if cut:
+            s = _cut_scores(s, True)
+        p = jnp.exp(s - lse_ref[0])
+        ds = p * (_nt(do_ref[0], v_ref[0]) - delta_ref[0]) * sm_scale
+        dq_sc[...] += jnp.dot(ds.astype(k.dtype), k,
+                              preferred_element_type=jnp.float32)
+
+    _either_tile(flags, tile)
+
+    @pl.when((flags & _LAST) != 0)
+    def _store():
+        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
+
+
+def _kvb_specs(block: int, dh: int):
+    """BlockSpecs over the visit tables: a q-side block, a key/value-side
+    block, and the float32 row statistics as a column or as a row."""
+    vm = pltpu.VMEM
+    return {
+        "q": pl.BlockSpec((1, block, dh), lambda i, v, qi, ki, fl:
+                          (i, qi[v], 0), memory_space=vm),
+        "kv": pl.BlockSpec((1, block, dh), lambda i, v, qi, ki, fl:
+                           (i, ki[v], 0), memory_space=vm),
+        "col": pl.BlockSpec((1, block, 1), lambda i, v, qi, ki, fl:
+                            (i, qi[v], 0), memory_space=vm),
+        "row": pl.BlockSpec((1, 1, block), lambda i, v, qi, ki, fl:
+                            (i, 0, qi[v]), memory_space=vm),
+    }
+
+
+def _kvb_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_KVB_VMEM_LIMIT)
+
+
+# Jitted, so that a program's layers share one trace and one lowering of
+# each kernel (as ops/pallas/grouped.py's)
+@partial(jax.jit, static_argnames=("causal", "interpret"))
+def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool):
+    bh, t, dh = q.shape
+    block = _kvb_block(t)
+    tables = _visits(t, block, causal, False)
+    spec = _kvb_specs(block, dh)
+    return pl.pallas_call(
+        partial(_kvb_fwd_kernel, sm_scale=1.0 / float(np.sqrt(dh))),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(bh, len(tables[0])),
+            in_specs=[spec["q"], spec["kv"], spec["kv"]],
+            out_specs=[spec["q"], spec["col"]],
+            scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, dh), jnp.float32)]),
+        out_shape=[_out_struct((bh, t, dh), q.dtype, q),
+                   _out_struct((bh, t, 1), jnp.float32, q)],
+        compiler_params=_kvb_params(), name=KVB_FWD_KERNEL_NAME,
+        interpret=interpret,
+    )(*tables, q, k, v)
+
+
+@partial(jax.jit, static_argnames=("causal", "interpret"))
+def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool):
+    bh, t, dh = q.shape
+    block = _kvb_block(t)
+    spec = _kvb_specs(block, dh)
+    sm_scale = 1.0 / float(np.sqrt(dh))
+    delta = (do.astype(jnp.float32) *
+             o.astype(jnp.float32)).sum(-1, keepdims=True)  # (bh, t, 1)
+    by_kv, by_q = (_visits(t, block, causal, flag) for flag in (True, False))
+    dk, dv = pl.pallas_call(
+        partial(_kvb_dkv_kernel, sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(bh, len(by_kv[0])),
+            in_specs=[spec["q"], spec["kv"], spec["kv"], spec["q"],
+                      spec["row"], spec["row"]],
+            out_specs=[spec["kv"], spec["kv"]],
+            scratch_shapes=[pltpu.VMEM((block, dh), jnp.float32),
+                            pltpu.VMEM((block, dh), jnp.float32)]),
+        out_shape=[_out_struct(k.shape, k.dtype, q),
+                   _out_struct(v.shape, v.dtype, q)],
+        compiler_params=_kvb_params(), name=KVB_DKV_KERNEL_NAME,
+        interpret=interpret,
+    )(*by_kv, q, k, v, do, lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
+    dq = pl.pallas_call(
+        partial(_kvb_dq_kernel, sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(bh, len(by_q[0])),
+            in_specs=[spec["q"], spec["kv"], spec["kv"], spec["q"],
+                      spec["col"], spec["col"]],
+            out_specs=spec["q"],
+            scratch_shapes=[pltpu.VMEM((block, dh), jnp.float32)]),
+        out_shape=_out_struct(q.shape, q.dtype, q),
+        compiler_params=_kvb_params(), name=KVB_DQ_KERNEL_NAME,
+        interpret=interpret,
+    )(*by_q, q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_kvb(q, k, v, causal: bool, interpret: bool):
+    return _kvb_call_fwd(q, k, v, causal, interpret)[0]
+
+
+def _flash_kvb_fwd(q, k, v, causal, interpret):
+    o, lse = _kvb_call_fwd(q, k, v, causal, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_kvb_bwd(causal, interpret, res, do):
+    return _kvb_call_bwd(*res, do, causal, interpret)
+
+
+_flash_kvb.defvjp(_flash_kvb_fwd, _flash_kvb_bwd)
+
+
+def blocked_unsupported_reason(t: int, dh: int) -> str | None:
+    """Why the key/value-blocked form cannot take the shape, or ``None``:
+    it needs a time axis its smallest block divides and a head its blocks
+    hold (VMEM is ``block * head_dim``-sized whatever ``t``); key/value
+    heads are as many as query heads (the caller repeats a group's)."""
+    if _kvb_block(t) == 0:
+        return (f"t={t} is not a multiple of the {_KVB_BLOCKS[-1]}-row "
+                f"key/value block")
+    if dh % 64 != 0:
+        return f"head_dim={dh} is not a multiple of 64"
+    if dh > 512:
+        return (f"head_dim={dh}: a {_KVB_BLOCKS[0]}-row block of it passes "
+                f"the blocked kernels' VMEM")
+    return None
+
+
+def form_of(t: int, dh: int) -> tuple[str | None, str | None]:
+    """THE choice of attention kernel for a shape, by the shape alone:
+    ``("rows", None)`` wherever the whole-row form accepts it (every
+    shape it ever took keeps its program), ``("blocked", None)`` where
+    only the key/value-blocked form does, and ``(None, why)``, both
+    refusals in one sentence, where the caller is left with dense
+    attention."""
+    rows = unsupported_reason(t, dh)
+    if rows is None:
+        return "rows", None
+    blocked = blocked_unsupported_reason(t, dh)
+    if blocked is None:
+        return "blocked", None
+    return None, f"{rows}; key/value-blocked: {blocked}"
+
+
 def unsupported_reason(t: int, dh: int) -> str | None:
-    """Why this kernel cannot take the shape, or ``None`` when it can:
+    """Why the whole-row form cannot take the shape (:func:`form_of` then
+    asks the key/value-blocked one), or ``None`` when it can:
     q-blockable time axis, lane-sized head dim, and a VMEM budget that
     must cover the BACKWARD kernel (the one actually run under
     value_and_grad): full K/V plus f32 dk/dv accumulator blocks plus the
@@ -281,7 +588,7 @@ def unsupported_reason(t: int, dh: int) -> str | None:
 
 
 def supported(t: int, dh: int) -> bool:
-    """Shapes this kernel handles (see :func:`unsupported_reason`)."""
+    """Shapes the whole-row form handles (:func:`unsupported_reason`)."""
     return unsupported_reason(t, dh) is None
 
 
@@ -289,17 +596,25 @@ def flash_attention(q, k, v, causal: bool = False, *,
                     interpret: bool = False):
     """Fused attention over per-head tensors ``(b, t, h, dh)`` — same
     contract as ops.attention.attention (``softmax(q·kᵀ/√dh)·v``),
-    differentiable via the flash backward kernels.  ``k`` and ``v`` may
-    carry fewer heads (``h`` a multiple of theirs): grouped-query
-    attention, query head ``j`` reading key/value head ``j // group``."""
+    differentiable via the flash backward kernels, in the form
+    :func:`form_of` gives the shape.  ``k`` and ``v`` may carry fewer
+    heads (``h`` a multiple of theirs): grouped-query attention, query
+    head ``j`` reading key/value head ``j // group`` (the whole-row form
+    through its index maps, the blocked form over repeated heads)."""
     b, t, h, dh = q.shape
-    why = unsupported_reason(t, dh)
-    if why:
+    form, why = form_of(t, dh)
+    if form is None:
         raise ValueError(
             f"flash_attention cannot take this shape: {why} — gate call "
-            f"sites on ops.pallas.attention.supported() or use the dense "
+            f"sites on ops.pallas.attention.form_of() or use the dense "
             f"path")
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
         b * x.shape[2], t, -1)
-    o = _flash(fold(q), fold(k), fold(v), causal, interpret)
+    if form == "rows":
+        o = _flash(fold(q), fold(k), fold(v), causal, interpret)
+    else:
+        group = h // k.shape[2]
+        if group > 1:
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        o = _flash_kvb(fold(q), fold(k), fold(v), causal, interpret)
     return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)

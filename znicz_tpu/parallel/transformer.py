@@ -122,18 +122,30 @@ class Arch:
     it feeds forward through, and the sizes.  Every function below reads
     this; nothing else says what a block is.
 
-    ``mixers[i]`` is ``"attention"`` or ``"sconv"`` (a gated short
-    convolution); ``ffns[i]`` is ``"mlp"`` (biased GELU), ``"moe_dense"``
-    (:func:`moe.moe_ffn`: softmax scores, biased GELU experts sharded
-    over ``model``, every held expert computes every token), ``"glu"``
-    (bias-free SwiGLU) or ``"moe_routed"`` (:func:`moe.moe_routed_ffn`:
-    this chip's ``experts_held`` of ``n_experts`` from ``experts_first``,
-    token dispatch, no drop).  ``norm`` is ``"layer"`` (gain and bias)
-    or ``"rms"`` (gain); ``kv_heads < heads`` is grouped-query attention;
-    ``qk_norm`` puts an RMSNorm with its own gain on each head of q and
-    k; ``rope_theta`` rotates them (rotate-half, over the whole head);
-    ``final_norm`` norms the last residual stream and ``tied`` reads the
-    logits against the embedding matrix.
+    ``mixers[i]`` is ``"attention"``, ``"latent"`` (latent attention,
+    MLA: queries and keys/values through low-rank latents of ``q_lora``
+    and ``kv_lora`` with an RMSNorm on each, a head of ``nope_dim``
+    unrotated and ``rope_dim`` rotated entries whose rotated key is one
+    for all heads; ``head_dim`` is their sum and the value's width) or
+    ``"sconv"`` (a gated short convolution); ``ffns[i]`` is ``"mlp"``
+    (biased GELU), ``"moe_dense"`` (:func:`moe.moe_ffn`: softmax scores,
+    biased GELU experts sharded over ``model``, every held expert
+    computes every token), ``"glu"`` (bias-free SwiGLU) or
+    ``"moe_routed"`` (:func:`moe.moe_routed_ffn`: this chip's
+    ``experts_held`` of ``n_experts`` from ``experts_first``, token
+    dispatch, no drop; with ``shared_ff`` a SwiGLU of that width that
+    every token passes, beside it).  ``norm`` is ``"layer"`` (gain and
+    bias) or ``"rms"`` (gain); ``kv_heads < heads`` is grouped-query
+    attention; ``qk_norm`` puts an RMSNorm with its own gain on each head
+    of q and k; ``rope_theta`` rotates them (rotate-half, over the whole
+    head; ``rope_interleaved``: the pairs are neighbours, ``(2i, 2i +
+    1)``); ``final_norm`` norms the last residual stream and ``tied``
+    reads the logits against the embedding matrix.  ``mtp`` adds one
+    multi-token-prediction module behind the stack (:func:`_mtp_hidden`:
+    a projection of the next token's embedding beside the last state,
+    one more layer of the last layer's kinds, index ``n_layers``, a norm
+    of its own, the model's embedding and head) whose cross-entropy on
+    the second-next token joins the loss ``mtp_weight`` times.
 
     Built by :func:`gpt_arch` (the block this module always had: the
     four integers) or :func:`arch_from_config` (a model's own keys)."""
@@ -162,10 +174,29 @@ class Arch:
     routed_scale: float = 1.0
     final_norm: bool = False
     tied: bool = False
+    q_lora: int = 0
+    kv_lora: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    rope_interleaved: bool = False
+    shared_ff: int = 0
+    mtp: bool = False
+    mtp_weight: float = 0.0
 
     @property
     def n_layers(self) -> int:
         return len(self.mixers)
+
+    def kinds(self, i: int) -> tuple:
+        """``(mixer, ffn)`` of layer ``i``; ``i == n_layers`` is the MTP
+        module's layer, of the last layer's kinds."""
+        i = min(i, self.n_layers - 1)
+        return self.mixers[i], self.ffns[i]
+
+    def routed_layers(self) -> int:
+        """Routed expert layers a step runs, the MTP module's among them."""
+        n = self.ffns.count("moe_routed")
+        return n + (self.mtp and self.kinds(self.n_layers)[1] == "moe_routed")
 
     def mechanisms(self) -> list:
         """Names of what this stack has beyond the GPT-shaped block: the
@@ -173,6 +204,8 @@ class Arch:
         out = []
         if "sconv" in self.mixers:
             out.append("gated short convolution")
+        if "latent" in self.mixers:
+            out.append("latent attention")
         if self.kv_heads != self.heads:
             out.append("grouped-query attention")
         if self.qk_norm:
@@ -183,6 +216,10 @@ class Arch:
             out.append("SwiGLU")
         if "moe_routed" in self.ffns:
             out.append("routed experts (moe_routed_ffn)")
+        if self.shared_ff:
+            out.append("shared expert")
+        if self.mtp:
+            out.append("multi-token prediction")
         if self.norm != "layer":
             out.append("RMSNorm")
         if self.final_norm:
@@ -208,21 +245,21 @@ def gpt_arch(n_layers: int, d: int, heads: int, ff: int, vocab: int,
 _LAYER_TYPES = {"conv": "sconv", "full_attention": "attention"}
 
 
-def arch_from_config(cfg, vocab: int | None = None) -> Arch:
-    """A model's own keys -> :class:`Arch`.  Reads the ``lfm2_moe``
-    family (``layer_types``, ``num_dense_layers``, ``num_experts``,
+def _experts_held(cfg, n_experts: int) -> tuple:
+    held = cfg.get("experts_held") or {"first": 0, "count": n_experts}
+    first, count = int(held["first"]), int(held["count"])
+    if first < 0 or count < 1 or first + count > max(n_experts, 1):
+        raise ValueError(f"experts_held {held} of {n_experts} experts")
+    return first, count
+
+
+def _lfm2_moe_arch(cfg, vocab: int | None) -> Arch:
+    """``lfm2_moe`` (``layer_types``, ``num_dense_layers``, ``num_experts``,
     ``num_experts_per_tok``, ``num_key_value_heads``, ``conv_L_cache``,
     ``rope_parameters``, ``norm_eps``, ...): RMSNorm, gated short
     convolutions and GQA attention with QK-norm and rotary embedding by
     ``layer_types``, bias-free SwiGLU in the leading dense layers and
-    sigmoid-routed experts after them, a final norm and a tied head.
-    ``experts_held`` (``{"first", "count"}``; all by default) is this
-    chip's share of the experts; ``vocab`` (the loader's) overrides
-    ``vocab_size``.  Any other ``model_type`` is refused by name."""
-    kind = cfg.get("model_type", "lfm2_moe" if "layer_types" in cfg else None)
-    if kind != "lfm2_moe":
-        raise ValueError(f"model_type {kind!r}: this stack reads lfm2_moe "
-                         f"configurations and the GPT-shaped integers")
+    sigmoid-routed experts after them, a final norm and a tied head."""
     if cfg.get("conv_bias", False):
         raise ValueError("conv_bias: the short convolution here has none")
     types = list(cfg["layer_types"])
@@ -235,10 +272,7 @@ def arch_from_config(cfg, vocab: int | None = None) -> Arch:
     d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
     n_dense = int(cfg.get("num_dense_layers", 0))
     n_experts = int(cfg.get("num_experts", 0))
-    held = cfg.get("experts_held") or {"first": 0, "count": n_experts}
-    first, count = int(held["first"]), int(held["count"])
-    if first < 0 or count < 1 or first + count > max(n_experts, 1):
-        raise ValueError(f"experts_held {held} of {n_experts} experts")
+    first, count = _experts_held(cfg, n_experts)
     rope = cfg.get("rope_parameters") or {}
     return Arch(
         d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
@@ -260,6 +294,90 @@ def arch_from_config(cfg, vocab: int | None = None) -> Arch:
         final_norm=True, tied=bool(cfg.get("tie_word_embeddings", True)))
 
 
+def _glm4_moe_lite_arch(cfg, vocab: int | None) -> Arch:
+    """``glm4_moe_lite`` (DeepSeek-V3's block: ``q_lora_rank``,
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``, ``first_k_dense_replace``, ``n_routed_experts``,
+    ``n_shared_experts``, ``num_nextn_predict_layers``, ...): RMSNorm,
+    latent attention in every layer, bias-free SwiGLU in the leading
+    dense layers, after them a shared expert beside sigmoid-routed
+    experts selected by score plus ``e_score_correction_bias``
+    (``topk_method`` ``noaux_tc``, one group), a final norm, an untied
+    head and one multi-token-prediction module.  ``n_routed_experts`` is
+    the experts held here where ``router_width`` gives the router's
+    published width; ``mtp_loss_weight`` (0.3) weighs the module's
+    loss."""
+    if cfg.get("attention_bias", False):
+        raise ValueError("attention_bias: the projections here have none")
+    if cfg.get("topk_method", "noaux_tc") != "noaux_tc" or \
+            int(cfg.get("n_group", 1)) != 1 or \
+            int(cfg.get("topk_group", 1)) != 1:
+        raise ValueError("topk_method / n_group / topk_group: noaux_tc "
+                         "over one group of experts is what is written")
+    if cfg.get("rope_scaling") or \
+            float(cfg.get("partial_rotary_factor", 1)) != 1:
+        raise ValueError("rope_scaling / partial_rotary_factor: the rotary "
+                         "part is rotated whole and unscaled")
+    nope, rope = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
+    if int(cfg["v_head_dim"]) != nope + rope:
+        raise ValueError(
+            f"v_head_dim {cfg['v_head_dim']} against a query/key head of "
+            f"{nope + rope}: the attention kernels take one head width")
+    mtp = int(cfg.get("num_nextn_predict_layers", 0))
+    if mtp > 1:
+        raise ValueError(f"num_nextn_predict_layers {mtp}: one module is "
+                         f"written")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    if int(cfg.get("num_key_value_heads", heads)) != heads:
+        raise ValueError("num_key_value_heads: latent attention expands "
+                         "the latent to every head")
+    layers = int(cfg["num_hidden_layers"])
+    n_dense = int(cfg.get("first_k_dense_replace", 0))
+    n_experts = int(cfg.get("router_width", cfg.get("n_routed_experts", 0)))
+    first, count = _experts_held(cfg, n_experts)
+    moe_ff = int(cfg.get("moe_intermediate_size", 0))
+    return Arch(
+        d=d, heads=heads, kv_heads=heads, head_dim=nope + rope,
+        ff=int(cfg["intermediate_size"]),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=("latent",) * layers,
+        ffns=tuple("glu" if i < n_dense or not n_experts else "moe_routed"
+                   for i in range(layers)),
+        norm="rms", eps=float(cfg.get("rms_norm_eps", 1e-5)),
+        rope_theta=float(cfg.get("rope_theta", 1e4)),
+        rope_interleaved=bool(cfg.get("rope_interleave", True)),
+        n_experts=n_experts, experts_first=first, experts_held=count,
+        top_k=int(cfg.get("num_experts_per_tok", 1)), moe_ff=moe_ff,
+        score="sigmoid", expert_bias=True,
+        norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        final_norm=True, tied=bool(cfg.get("tie_word_embeddings", False)),
+        q_lora=int(cfg["q_lora_rank"]), kv_lora=int(cfg["kv_lora_rank"]),
+        nope_dim=nope, rope_dim=rope,
+        shared_ff=int(cfg.get("n_shared_experts", 0)) * moe_ff,
+        mtp=bool(mtp), mtp_weight=float(cfg.get("mtp_loss_weight", 0.3)))
+
+
+#: ``model_type`` -> the reader of that family's keys
+_FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch}
+
+
+def arch_from_config(cfg, vocab: int | None = None) -> Arch:
+    """A model's own keys -> :class:`Arch`, by ``model_type``
+    (:data:`_FAMILIES`: :func:`_lfm2_moe_arch`, also what a mapping with
+    ``layer_types`` and no ``model_type`` is read as, and
+    :func:`_glm4_moe_lite_arch`).  ``experts_held`` (``{"first",
+    "count"}``; all by default) is this chip's share of the experts;
+    ``vocab`` (the loader's) overrides ``vocab_size``.  Any other
+    ``model_type`` is refused by name."""
+    kind = cfg.get("model_type", "lfm2_moe" if "layer_types" in cfg else None)
+    if kind not in _FAMILIES:
+        raise ValueError(
+            f"model_type {kind!r}: this stack reads {', '.join(_FAMILIES)} "
+            f"configurations and the GPT-shaped integers")
+    return _FAMILIES[kind](cfg, vocab)
+
+
 def as_arch(arch, d=None, heads=None, ff=None, vocab=None,
             n_experts=None, moe_top_k: int = 1) -> Arch:
     """What every factory below takes first: an :class:`Arch`, a model's
@@ -276,6 +394,7 @@ def as_arch(arch, d=None, heads=None, ff=None, vocab=None,
 _LEAF_MECHANISMS = {
     "w_in": "gated short convolution", "q_g": "QK-norm",
     "w3": "SwiGLU", "ew3": "routed experts (moe_routed_ffn)",
+    "wkv_a": "latent attention", "sw1": "shared expert",
 }
 
 
@@ -291,6 +410,8 @@ def mechanisms_of_params(params) -> list:
         if "wk" in blk and np.shape(blk["wk"]) != np.shape(blk["wq"]) and \
                 "grouped-query attention" not in out:
             out.append("grouped-query attention")
+    if "mtp" in params:
+        out.append("multi-token prediction")
     if "norm_g" in params:
         out.append("final norm")
     if "head" not in params:
@@ -299,14 +420,24 @@ def mechanisms_of_params(params) -> list:
 
 
 def _layer_shapes(arch: Arch, i: int) -> dict:
-    """``{leaf: shape}`` of layer ``i``: the one table the initialiser,
-    the specs and the shapes are read from."""
+    """``{leaf: shape}`` of layer ``i`` (``n_layers``: the MTP module's):
+    the one table the initialiser, the specs and the shapes are read
+    from."""
     d, hd = arch.d, arch.head_dim
     bias = arch.norm == "layer"
+    mixer, ffn = arch.kinds(i)
     out = {"ln1_g": (d,), "ln2_g": (d,)}
     if bias:
         out.update({"ln1_b": (d,), "ln2_b": (d,)})
-    if arch.mixers[i] == "attention":
+    if mixer == "latent":
+        out.update({
+            "wq_a": (d, arch.q_lora), "q_a_g": (arch.q_lora,),
+            "wq_b": (arch.q_lora, arch.heads * hd),
+            "wkv_a": (d, arch.kv_lora + arch.rope_dim),
+            "kv_a_g": (arch.kv_lora,),
+            "wkv_b": (arch.kv_lora, arch.heads * (arch.nope_dim + hd)),
+            "wo": (arch.heads * hd, d)})
+    elif mixer == "attention":
         out.update({"wq": (d, arch.heads * hd), "wk": (d, arch.kv_heads * hd),
                     "wv": (d, arch.kv_heads * hd), "wo": (arch.heads * hd, d)})
         if arch.qk_norm:
@@ -314,7 +445,6 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
     else:
         out.update({"w_in": (d, 3 * d), "conv_k": (arch.conv_taps, d),
                     "w_out": (d, d)})
-    ffn = arch.ffns[i]
     if ffn == "mlp":
         out.update({"w1": (d, arch.ff), "b1": (arch.ff,),
                     "w2": (arch.ff, d), "b2": (d,)})
@@ -332,11 +462,23 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
                     "ew3": (e, d, f), "ew2": (e, f, d)})
         if arch.expert_bias:
             out["ebias"] = (arch.n_experts,)
+        if arch.shared_ff:
+            out.update({"sw1": (d, arch.shared_ff), "sw3": (d, arch.shared_ff),
+                        "sw2": (arch.shared_ff, d)})
     return out
 
 
+def _mtp_shapes(arch: Arch) -> dict:
+    """``{leaf: shape}`` of the MTP module: the two norms and the
+    projection in front of its layer, the layer, the norm behind it."""
+    d = arch.d
+    return {"enorm_g": (d,), "hnorm_g": (d,), "proj": (2 * d, d),
+            "block": _layer_shapes(arch, arch.n_layers), "norm_g": (d,)}
+
+
 #: leaves that start at one (gains), and those that start at zero
-_ONES = ("ln1_g", "ln2_g", "q_g", "k_g", "norm_g")
+_ONES = ("ln1_g", "ln2_g", "q_g", "k_g", "norm_g", "q_a_g", "kv_a_g",
+         "enorm_g", "hnorm_g")
 _ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias")
 #: how each leaf of the GPT-shaped block lies over the ``model`` axis
 _TP_SPECS = {
@@ -394,7 +536,15 @@ def init_params(gen, arch, d=None, heads=None, ff=None, vocab=None,
     out["blocks"] = blocks
     if arch.final_norm:
         out["norm_g"] = np.ones(arch.d, np.float32)
+    if arch.mtp:
+        out["mtp"] = _map_shapes(leaf, _mtp_shapes(arch))
     return out
+
+
+def _map_shapes(fn, shapes: dict) -> dict:
+    """``fn(leaf name, shape)`` over a nested ``{leaf: shape}`` table."""
+    return {k: _map_shapes(fn, v) if isinstance(v, dict) else fn(k, v)
+            for k, v in shapes.items()}
 
 
 def param_specs(arch, head_sharded: bool = False, moe: bool = False):
@@ -419,6 +569,8 @@ def param_specs(arch, head_sharded: bool = False, moe: bool = False):
     out["blocks"] = blocks
     if arch.final_norm:
         out["norm_g"] = P()
+    if arch.mtp:
+        out["mtp"] = _map_shapes(lambda k, shape: P(), _mtp_shapes(arch))
     return out
 
 
@@ -437,6 +589,8 @@ def param_shapes(arch, d=None, ff=None, vocab=None,
     out["blocks"] = [_layer_shapes(arch, i) for i in range(arch.n_layers)]
     if arch.final_norm:
         out["norm_g"] = (arch.d,)
+    if arch.mtp:
+        out["mtp"] = _mtp_shapes(arch)
     return out
 
 
@@ -526,11 +680,17 @@ def _norm(x, p, which: str, arch: Arch):
     return _layer_norm(x, p[which + "_g"], p[which + "_b"], arch.eps)
 
 
-def _rotate(x, theta: float):
+def _rotate(x, theta: float, interleaved: bool = False):
     """Rotary embedding over the whole head of ``x (b, t, h, dh)``,
     rotate-half form, positions from 0 (the seq axis is unsharded
-    wherever this runs), in f32."""
+    wherever this runs), in f32.  ``interleaved``: the pairs are the
+    neighbours ``(2i, 2i + 1)``; they are first brought to the halves'
+    order (evens, then odds), in which the result stays, as the
+    DeepSeek-V3 family's code leaves it: queries and keys are permuted
+    alike, so their products are those of rotating in place."""
     t, dh = x.shape[1], x.shape[-1]
+    if interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
@@ -541,33 +701,31 @@ def _rotate(x, theta: float):
 
 
 def _block(x, p, arch: Arch, run: _Run, index: int = 0):
-    """Layer ``index`` of ``arch`` on local shards: its mixer, then its
-    feed-forward, each reading a norm of the residual stream and adding
-    to it.  -> ``(x, aux, stats)``: the regularizer term (pre-weighted)
-    and the routed layer's counters.  Scopes: ``block<index>.attn`` or
-    ``.sconv``, then ``block<index>.mlp`` or ``.moe`` (with ``.moe.route``
-    and ``.moe.experts`` beside it)."""
-    if arch.mixers[index] == "attention":
-        with _probe.scope(f"block{index}.attn"):
-            x = _block_attn(x, p, arch, run)
-    else:
+    """Layer ``index`` of ``arch`` on local shards (``n_layers``: the MTP
+    module's): its mixer, then its feed-forward, each reading a norm of
+    the residual stream and adding to it.  -> ``(x, aux, stats)``: the
+    regularizer term (pre-weighted) and the routed layer's counters.
+    Scopes: ``block<index>.attn`` (with ``.attn.latent`` beside it for
+    what latent attention does before the kernel) or ``.sconv``, then
+    ``block<index>.mlp`` or ``.moe`` (with ``.moe.route``,
+    ``.moe.experts`` and ``.moe.shared`` beside it)."""
+    mixer, ffn = arch.kinds(index)
+    if mixer == "sconv":
         with _probe.scope(f"block{index}.sconv"):
             x = _block_sconv(x, p, arch)
-    if arch.ffns[index] == "moe_routed":
+    else:
+        x = _block_attn(x, p, arch, run, f"block{index}.attn")
+    if ffn == "moe_routed":
         return _block_routed(x, p, arch, f"block{index}.moe")
     with _probe.scope(f"block{index}.mlp"):
-        x, aux = _block_mlp(x, p, arch, arch.ffns[index], run)
+        x, aux = _block_mlp(x, p, arch, ffn, run)
     return x, aux, {}
 
 
-def _block_attn(x, p, arch: Arch, run: _Run):
-    """Attention with tp-sharded heads: ring attention over the seq axis;
-    with the seq axis unsharded, ``run.use_flash`` swaps the core for the
-    Pallas flash kernel (ops/pallas/attention.py) — same math, no (t, t)
-    score matrix in HBM.  Fewer key/value heads than query heads go to
-    the flash kernel as they are (its index maps take the group) and to
-    the dense core repeated."""
-    h = _norm(x, p, "ln1", arch)
+def _plain_qkv(h, p, arch: Arch, run: _Run):
+    """Queries, keys and values ``(b, t, heads, head_dim)`` of plain or
+    grouped-query attention: three projections, the optional QK-norm,
+    the optional rotary embedding over the whole head."""
     b, t_loc, _ = h.shape
 
     def heads_of(w, n):
@@ -582,25 +740,76 @@ def _block_attn(x, p, arch: Arch, run: _Run):
         k = _rms_norm(k, p["k_g"], arch.eps)
     if arch.rope_theta is not None:
         q, k = _rotate(q, arch.rope_theta), _rotate(k, arch.rope_theta)
+    return q, k, v
+
+
+def _latent_qkv(h, p, arch: Arch):
+    """Latent attention's queries, keys and values ``(b, t, heads,
+    head_dim)``: ``c_q = RMSNorm(h wq_a)``, ``[q_nope | q_pe] = c_q
+    wq_b`` a head; ``[c_kv | k_pe] = h wkv_a``, ``c_kv = RMSNorm(c_kv)``,
+    ``[k_nope | v] = c_kv wkv_b`` a head; ``q_pe`` and the ONE ``k_pe``
+    all heads share are rotated; ``q = [q_nope | q_pe]``, ``k = [k_nope |
+    k_pe]``."""
+    b, t, _ = h.shape
+    heads, nope, rope = arch.heads, arch.nope_dim, arch.rope_dim
+    c_q = _rms_norm(h @ p["wq_a"], p["q_a_g"], arch.eps)
+    q = (c_q @ p["wq_b"]).reshape(b, t, heads, nope + rope)
+    kv_a = h @ p["wkv_a"]
+    c_kv = _rms_norm(kv_a[..., :arch.kv_lora], p["kv_a_g"], arch.eps)
+    kv = (c_kv @ p["wkv_b"]).reshape(b, t, heads, nope + arch.head_dim)
+    k_pe = kv_a[..., arch.kv_lora:].reshape(b, t, 1, rope)
+    turn = functools.partial(_rotate, theta=arch.rope_theta,
+                             interleaved=arch.rope_interleaved)
+    q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        turn(k_pe), (b, t, heads, rope))], axis=-1)
+    return q, k, kv[..., nope:]
+
+
+def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
+    """Attention with tp-sharded heads: ring attention over the seq axis;
+    with the seq axis unsharded, ``run.use_flash`` swaps the core for a
+    Pallas flash kernel (ops/pallas/attention.py) — same math, no (t, t)
+    score matrix in HBM — in the form the shape gets
+    (``attention.form_of``: whole-row, key/value-blocked, or refused, and
+    then the dense core with one logged line).  Fewer key/value heads
+    than query heads go to the flash kernels as they are and to the dense
+    core repeated.  The norm, the kernel, the output product and the
+    residual sum lie under ``scope``; what latent attention does before
+    the kernel under ``scope.latent``, a sibling by name."""
     from znicz_tpu.ops.pallas import attention as pattn
-    why = pattn.unsupported_reason(t_loc, q.shape[-1]) \
-        if run.use_flash or run.use_ring_flash else None
-    if why:
-        _report_flash_refusal(t_loc, q.shape[-1], why)
-    if run.use_flash and not why:
-        o = pattn.flash_attention(q, k, v, causal=run.causal,
-                                  interpret=run.interpret)
+    with _probe.scope(scope):
+        h = _norm(x, p, "ln1", arch)
+    b, t_loc, _ = h.shape
+    if "wkv_a" in p:
+        with _probe.scope(f"{scope}.latent"):
+            q, k, v = _latent_qkv(h, p, arch)
     else:
-        group = run.heads_local // run.kv_heads_local
-        if group > 1:
-            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-        if run.use_ring_flash and not why:
-            o = ring_flash_attention(q, k, v, "seq", causal=run.causal,
-                                     interpret=run.interpret)
+        with _probe.scope(scope):
+            q, k, v = _plain_qkv(h, p, arch, run)
+    with _probe.scope(scope):
+        dh = q.shape[-1]
+        why = None
+        if run.use_flash:
+            why = pattn.form_of(t_loc, dh)[1]
+        elif run.use_ring_flash:       # the ring merges whole-row blocks
+            why = pattn.unsupported_reason(t_loc, dh)
+        if why:
+            _report_flash_refusal(t_loc, dh, why)
+        if run.use_flash and not why:
+            o = pattn.flash_attention(q, k, v, causal=run.causal,
+                                      interpret=run.interpret)
         else:
-            o = ring_attention(q, k, v, "seq", causal=run.causal)
-    o = o.reshape(b, t_loc, -1)                      # (b, t_loc, d_local)
-    return x + tp.row_parallel(o, p["wo"], None, "model")
+            group = q.shape[2] // k.shape[2]
+            if group > 1:
+                k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+            if run.use_ring_flash and not why:
+                o = ring_flash_attention(q, k, v, "seq", causal=run.causal,
+                                         interpret=run.interpret)
+            else:
+                o = ring_attention(q, k, v, "seq", causal=run.causal)
+        o = o.reshape(b, t_loc, -1)                  # (b, t_loc, d_local)
+        return x + tp.row_parallel(o, p["wo"], None, "model")
 
 
 def _block_sconv(x, p, arch: Arch):
@@ -617,6 +826,11 @@ def _block_sconv(x, p, arch: Arch):
     kf = p["conv_k"].astype(jnp.float32)
     c = sum(kf[j] * zp[:, j:j + t] for j in range(taps))
     return x + (gate_c * c.astype(x.dtype)) @ p["w_out"]
+
+
+def _glu(m, w1, w3, w2):
+    """Bias-free SwiGLU."""
+    return (jax.nn.silu(m @ w1) * (m @ w3)) @ w2
 
 
 def _block_mlp(x, p, arch: Arch, ffn: str, run: _Run):
@@ -641,8 +855,8 @@ def _block_mlp(x, p, arch: Arch, ffn: str, run: _Run):
                 m2d @ p["gate"])
         return x, aux
     if ffn == "glu":
-        y = (jax.nn.silu(m @ p["w1"]) * (m @ p["w3"])) @ p["w2"]
-        return x + y, jnp.zeros((), jnp.float32)
+        return x + _glu(m, p["w1"], p["w3"], p["w2"]), \
+            jnp.zeros((), jnp.float32)
     x = x + tp.mlp(m, p["w1"], p["b1"], p["w2"], p["b2"],
                    jax.nn.gelu, "model")
     return x, jnp.zeros((), jnp.float32)
@@ -652,9 +866,13 @@ def _block_routed(x, p, arch: Arch, scope: str):
     """This chip's share of a routed expert layer
     (:func:`moe.moe_routed_ffn`); the norm and the residual sum lie
     under ``scope``, the layer's two parts under ``scope.route`` and
-    ``scope.experts``."""
+    ``scope.experts``, and the shared expert, which every chip computes
+    alike for every token, under ``scope.shared``."""
     with _probe.scope(scope):
         m = _norm(x, p, "ln2", arch)
+    if "sw1" in p:
+        with _probe.scope(f"{scope}.shared"):
+            x = x + _glu(m, p["sw1"], p["sw3"], p["sw2"])
     y, stats = moe_routed_ffn(
         m.reshape(-1, m.shape[-1]), p["gate"], p.get("ebias"), p["ew1"],
         p["ew3"], p["ew2"], first=arch.experts_first, top_k=arch.top_k,
@@ -795,11 +1013,14 @@ def _cast_params(ps, arch: Arch, cdt):
     dtype (a cast out here would stand alone on both sides of that
     choice: 12 ms of the step, my chip run, PR 29)."""
     out = jax.tree.map(lambda w: w.astype(cdt), ps)
-    for i, ffn in enumerate(arch.ffns):
-        if ffn == "moe_routed":
+    layers = list(zip(ps["blocks"], out["blocks"]))
+    if arch.mtp:
+        layers.append((ps["mtp"]["block"], out["mtp"]["block"]))
+    for master, cast in layers:
+        if "ew3" in master:                # a routed layer's, no other's
             for k in ("gate", "ebias", "ew1", "ew3", "ew2"):
-                if k in ps["blocks"][i]:
-                    out["blocks"][i][k] = ps["blocks"][i][k]
+                if k in master:
+                    cast[k] = master[k]
     return out
 
 
@@ -808,8 +1029,20 @@ def _head_of(ps, arch: Arch):
     return ps["emb"].T if arch.tied else ps["head"]
 
 
+#: the loss's terms a stack with an MTP module reports beside its counters
+_LOSS_TERMS = ("loss_main", "loss_mtp")
+
+
 def _sum_stats(a: dict, b: dict) -> dict:
     return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in {**a, **b}}
+
+
+def _block_fn(remat: bool, remat_policy: str | None):
+    """:func:`_block`, or its checkpointed form."""
+    if not (remat or remat_policy):
+        return _block
+    pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
+    return jax.checkpoint(_block, policy=pol, static_argnums=(2, 3, 4))
 
 
 def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
@@ -822,16 +1055,11 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
     states (through the final norm where the stack has one), the summed
     MoE regularizer term, the compute-dtype-cast params (so the caller's
     head matmul uses the same precision policy) and the routed layers'
-    counters summed over the layers (``moe.MEAN_STATS`` their mean)."""
+    counters summed over the layers."""
     ps = _cast_params(ps, arch, cdt)
     with _probe.scope("embed"):
         x = ps["emb"][tokens]                     # (b_l, t_l, d)
-    blk = _block
-    if remat or remat_policy:
-        pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
-        blk = jax.checkpoint(
-            _block, policy=pol,
-            static_argnums=(2, 3, 4))  # type: ignore[assignment]
+    blk = _block_fn(remat, remat_policy)
     # regularizer weights apply inside _block (per-block pre-weighted)
     aux_term = jnp.zeros((), jnp.float32)
     stats: dict = {}
@@ -839,13 +1067,35 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
         x, aux, st = blk(x, p, arch, run, i)
         aux_term = aux_term + aux
         stats = _sum_stats(stats, st)
-    for k in MEAN_STATS:
-        if k in stats:
-            stats[k] = stats[k] / arch.ffns.count("moe_routed")
     if arch.final_norm:
         with _probe.scope("ce"):
             x = _rms_norm(x, ps["norm_g"], arch.eps)
     return x, aux_term, ps, stats
+
+
+def _mean_stats(stats: dict, arch: Arch) -> dict:
+    """The routed layers' summed counters with ``moe.MEAN_STATS`` turned
+    into their mean over the layers."""
+    return {k: v / arch.routed_layers() if k in MEAN_STATS else v
+            for k, v in stats.items()}
+
+
+def _mtp_hidden(ps, x, nxt, arch: Arch, run: _Run, blk):
+    """The MTP module (depth 1, DeepSeek-V3's form) over the main stack's
+    output ``x`` (through its final norm) and the NEXT tokens ``nxt``
+    (the main loss's labels): ``h' = [RMSNorm_e(Emb(nxt)) | RMSNorm_h(x)]
+    proj``, one more layer (index ``n_layers``, its own weights), its own
+    norm; the caller reads it against the model's head for the
+    second-next token.  -> ``(hidden, aux, stats)``.  Scopes ``mtp.proj``
+    and ``mtp.ce`` around the layer's own."""
+    m = ps["mtp"]
+    with _probe.scope("mtp.proj"):
+        e = _rms_norm(ps["emb"][nxt], m["enorm_g"], arch.eps)
+        h = _rms_norm(x, m["hnorm_g"], arch.eps)
+        y = jnp.concatenate([e, h], axis=-1) @ m["proj"]
+    y, aux, stats = blk(y, m["block"], arch, run, arch.n_layers)
+    with _probe.scope("mtp.ce"):
+        return _rms_norm(y, m["norm_g"], arch.eps), aux, stats
 
 
 def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
@@ -870,21 +1120,44 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
     still reduce exactly inside).  The quantized-collective train step
     uses it to differentiate a local loss and route the gradient
     reduction through the explicit quantized psum instead of AD's
-    psum transpose."""
+    psum transpose.
+
+    With an MTP module (``arch.mtp``) the loss is ``CE(main; next token)
+    + mtp_weight * CE(module; second-next token)``, the second over the
+    positions that have a second-next token, and ``stats`` carries both
+    terms (``loss_main``, ``loss_mtp``, in the loss's own convention)."""
     x, aux_term, ps, stats = _forward_hidden(
         ps, tokens, arch, run, cdt, remat=remat, remat_policy=remat_policy)
-    return _ce_from_hidden(x, _head_of(ps, arch), labels, mask, aux_term,
-                           loss_chunks, head_sharded, reduce), stats
+    head = _head_of(ps, arch)
+    with _probe.scope("ce"):
+        loss = _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
+                               head_sharded, reduce)
+    if arch.mtp:
+        # position i reads token i+1 (its label) and predicts token i+2,
+        # the next position's label; the last position has none
+        y, aux, st = _mtp_hidden(ps, x, labels, arch, run,
+                                 _block_fn(remat, remat_policy))
+        with _probe.scope("mtp.ce"):
+            second = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
+            mtp = _ce_from_hidden(y, head, second, mask, aux, loss_chunks,
+                                  head_sharded, reduce, skip_last=True)
+        stats = {**_sum_stats(stats, st), "loss_main": loss, "loss_mtp": mtp}
+        loss = loss + arch.mtp_weight * mtp
+    return loss, _mean_stats(stats, arch)
 
 
-@_probe.scoped("ce")
 def _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
-                    head_sharded, reduce):
+                    head_sharded, reduce, skip_last: bool = False):
     """Head matmul + masked CE over the hidden states, normalised and
     (``reduce``) summed over the data x seq shards: the tail of
-    :func:`_forward_ce`, under the ``ce`` scope."""
+    :func:`_forward_ce`.  ``skip_last`` leaves each row's last position
+    out of the sum and of the count (the seq axis unsharded)."""
     b_l, t_l = labels.shape
     mvec = mask[:, None].astype(jnp.float32) if mask is not None else None
+    if skip_last:
+        counted = (jnp.arange(t_l) < t_l - 1).astype(jnp.float32)[None, :]
+        mvec = counted if mvec is None else mvec * counted
+        t_l -= 1                      # positions a row counts from here on
     # either path yields the LOCAL weighted nll sum; normalization below
     # is shared so dense and chunked conventions can never drift.  A
     # vocab-sharded head always routes through the chunk helper (its CE
@@ -958,8 +1231,9 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
     (``masked=True``: ``step(params, tokens, labels, mask)`` with a
     per-row bool mask — padded loader rows train nothing;
     ``stats=True``: ``-> (params, loss, stats)`` with the routed expert
-    layers' counters of the step, float32 scalars, an empty dict for a
-    stack that has none).
+    layers' counters of the step and, of a stack with an MTP module, the
+    loss's two terms (``loss_main``, ``loss_mtp``, unweighted), float32
+    scalars, an empty dict for a stack that has none).
 
     ``arch`` says what the stack is (:func:`as_arch`): an :class:`Arch`,
     a model's configuration mapping, or, as ever, the GPT-shaped block's
@@ -1160,10 +1434,16 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
         if not stats:
             return new_params, loss / n_shards
         # the counters are of this shard's tokens: pairs add up over the
-        # shards, a load ratio and a share are averaged
+        # shards, a load ratio and a share are averaged; the loss's terms
+        # are in the loss's convention, reduced as it is
+        terms = {k: counters.pop(k) for k in _LOSS_TERMS if k in counters}
+        if codec is not None:
+            terms = {k: lax.psum(v, ("data", "seq"))
+                     for k, v in terms.items()}
         counters = {k: lax.psum(v, ("data", "seq")) /
                     (n_shards if k in MEAN_STATS else 1)
                     for k, v in counters.items()}
+        counters.update({k: v / n_shards for k, v in terms.items()})
         return new_params, loss / n_shards, counters
 
     # replication checking is disabled wholesale by the compat shim

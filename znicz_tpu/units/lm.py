@@ -45,8 +45,9 @@ class TransformerLMStep(AcceleratedUnit):
     """One train-or-eval step per served (tokens, labels) minibatch.
 
     ``arch`` is the architecture as one mapping of the model's own keys
-    (``layer_types``, ``num_dense_layers``, ``num_experts``,
-    ``num_experts_per_tok``, ``num_key_value_heads``, ... and
+    (``model_type`` and that family's: ``layer_types``,
+    ``num_dense_layers``, ``num_experts``, ... or ``q_lora_rank``,
+    ``n_shared_experts``, ``num_nextn_predict_layers``, ... and
     ``experts_held``, this chip's share: ``{"first", "count"}``; see
     ``parallel.transformer.arch_from_config``); the vocabulary is the
     loader's.  Without it the unit builds the GPT-shaped block from
@@ -122,6 +123,10 @@ class TransformerLMStep(AcceleratedUnit):
         #: pairs routed to held experts a step, and the fullest held
         #: expert's load over the mean
         self.moe_counters: dict = {}
+        #: the last finished training pass's mean loss terms of a stack
+        #: with an MTP module (``last_loss`` and the Decision's metric are
+        #: their weighted total): ``{"main", "mtp"}``, unweighted
+        self.loss_terms: dict = {}
         self.arch = None
         self._params = None
         self._step = None
@@ -283,6 +288,26 @@ class TransformerLMStep(AcceleratedUnit):
                               float(sums["compact"]) / steps,
                               float(sums["tile_fill"]) / steps,
                               float(sums["pairs_held"]))
+        if "loss_main" in sums:
+            self._publish_loss_terms(float(sums["loss_main"]) / steps,
+                                     float(sums["loss_mtp"]) / steps)
+
+    def _publish_loss_terms(self, main: float, mtp: float) -> None:
+        """A finished training pass's two loss terms, each the mean over
+        its steps: the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        self.loss_terms = {"main": main, "mtp": mtp}
+        registry.gauge(
+            "znicz_lm_loss_main",
+            "next-token cross-entropy of the main stack, mean over the "
+            "last training pass's steps", ("unit",)).labels(
+                unit=self.name).set(main)
+        registry.gauge(
+            "znicz_lm_loss_mtp",
+            "second-next-token cross-entropy of the multi-token-prediction "
+            "module, unweighted, mean over the last training pass's steps",
+            ("unit",)).labels(unit=self.name).set(mtp)
 
     def _publish_moe(self, pairs_a_step: float, load_ratio: float,
                      compact_share: float, tile_fill: float,
