@@ -228,6 +228,50 @@ def test_the_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
     np.testing.assert_allclose(share0[0], want0, atol=5e-6)
 
 
+@pytest.mark.parametrize("interleaved", [True, False],
+                         ids=["neighbour_pairs", "halves"])
+def test_queries_rotated_as_whole_rows_are_the_cut_and_concatenated_ones(
+        interleaved):
+    """Where the flash kernels read the layer's layout, ``_latent_qkv``
+    rotates the queries in place with the row kernel
+    (``ops/pallas/rope.py``, interpreted here; the weight's columns
+    permuted for neighbour pairs): q, k, v and the gradients to the input
+    and to every leaf are those of cutting each head at ``nope`` and
+    concatenating."""
+    cfg = _cfg(num_hidden_layers=1, num_attention_heads=2,
+               num_key_value_heads=2, qk_nope_head_dim=96,
+               qk_rope_head_dim=32, v_head_dim=128,
+               rope_interleave=interleaved)
+    arch, dm = _arch(cfg), ref.dims(cfg)
+    assert arch.rope_interleaved == interleaved
+    p = ref.init_leaf_group(9, cfg, "B0")
+    h = jax.random.normal(jax.random.PRNGKey(9), (2, 128, dm["d"]))
+    cts = jax.random.normal(jax.random.PRNGKey(10), (3, 2, 128, 2, 128))
+    cut = tfm._Run(2, 2)
+    rows = tfm._Run(2, 2, use_flash=True, interpret=True)
+
+    def loss(h, p, run):
+        return sum((a * ct).sum() for a, ct in zip(
+            tfm._latent_qkv(h, p, arch, run), cts))
+
+    with mock.patch.object(pattn, "unsupported_reason",
+                           lambda t, dh: "refused for the test"), \
+            jax.default_matmul_precision("highest"):
+        assert tfm._rows_rope(128, arch, rows)
+        assert not tfm._rows_rope(128, arch, cut)
+        text = str(jax.make_jaxpr(lambda h: tfm._latent_qkv(
+            h, p, arch, rows))(h))
+        got = tfm._latent_qkv(h, p, arch, rows)
+        want = tfm._latent_qkv(h, p, arch, cut)
+        g_got = jax.grad(loss, (0, 1))(h, p, rows)
+        g_want = jax.grad(loss, (0, 1))(h, p, cut)
+    assert "rope_tail" in text
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(a, w, atol=2e-5)
+    for a, w in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, w, atol=2e-4, rtol=2e-5)
+
+
 def test_latent_attention_is_a_direct_softmax_with_one_shared_rotary_key():
     """``_latent_qkv`` + the attention core against ``softmax(q k^T /
     sqrt(nope + rope)) v`` written out head by head: the rotary part of the
@@ -239,7 +283,7 @@ def test_latent_attention_is_a_direct_softmax_with_one_shared_rotary_key():
     h = jax.random.normal(jax.random.PRNGKey(9), (2, 12, dm["d"]))
     nope, rope, heads = dm["nope"], dm["rope"], dm["heads"]
     with jax.default_matmul_precision("highest"):
-        q, k, v = tfm._latent_qkv(h, p, arch)
+        q, k, v = tfm._latent_qkv(h, p, arch, tfm._Run(heads, heads))
         assert q.shape == k.shape == v.shape == (2, 12, heads, nope + rope)
         # one rotary key for all heads
         for head in range(1, heads):
@@ -489,3 +533,33 @@ def test_step_unit_trains_the_family_and_publishes_both_loss_terms(tmp_path):
     state["params"]["mtp"].pop("proj")
     with pytest.raises(ValueError, match="architecture"):
         step.load_state_dict(state)
+
+
+def test_step_unit_publishes_the_direct_layout_share(tmp_path):
+    """Heads of 128 through the key/value-blocked kernels (interpreted;
+    the whole-row form refuses as it does at the benchmark's head of 256):
+    every attention layer, the module's among them, reads the layer's own
+    layout, and the unit says so."""
+    from test_lfm2_arch import _arch_workflow, _pallas_interpret
+    from znicz_tpu.core.backends import XLADevice
+    from znicz_tpu.observe import registry
+
+    model = {k: v for k, v in _cfg(
+        num_attention_heads=2, num_key_value_heads=2, qk_nope_head_dim=96,
+        qk_rope_head_dim=32, v_head_dim=128, num_hidden_layers=2).items()
+        if k not in ("hyper", "vocab_size")}
+    prng.seed_all(5)
+    refuse = mock.patch.object(pattn, "unsupported_reason",
+                               lambda t, dh: "refused for the test")
+    with _pallas_interpret(True), refuse:
+        assert pattn.direct_layout(128, 128)
+        w = _arch_workflow(model, str(tmp_path / "corp"), max_epochs=1,
+                           seq_len=128, minibatch_size=2)
+        w.initialize(device=XLADevice())
+        w.run()
+    step = w.step
+    assert np.isfinite(w.decision.metrics_history[-1]["metric_train"])
+    assert step.attn_direct_layout_share == 1.0
+    fam = registry.REGISTRY.get("znicz_lm_attn_direct_layout_share")
+    assert fam is not None and fam.labels(unit=step.name).get() == 1.0
+

@@ -639,3 +639,31 @@ def test_step_unit_runs_an_architecture_and_publishes_its_counters(
     state["params"]["blocks"][0].pop("conv_k")
     with pytest.raises(ValueError, match="architecture"):
         step.load_state_dict(state)
+
+
+def test_step_unit_publishes_that_its_attention_folds(tmp_path):
+    """A head of 64 at 128 positions gets the whole-row flash kernels
+    (interpreted), which read operands folded head-major: the share of
+    attention layers that read the layer's own layout is 0."""
+    from znicz_tpu.core.backends import XLADevice
+    from znicz_tpu.observe import registry
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    cfg = _cfg(["conv", "full_attention"], 1, hidden_size=128,
+               num_attention_heads=2, num_key_value_heads=1)
+    model = {k: v for k, v in cfg.items()
+             if k not in ("router_width", "hyper", "vocab_size")}
+    prng.seed_all(5)
+    assert pattn.form_of(128, 64) == ("rows", None)
+    with _pallas_interpret(True):
+        w = _arch_workflow({**model, "num_experts": cfg["router_width"]},
+                           str(tmp_path / "corp"), max_epochs=1,
+                           seq_len=128, minibatch_size=2)
+        w.initialize(device=XLADevice())
+        w.run()
+    step = w.step
+    assert np.isfinite(w.decision.metrics_history[-1]["metric_train"])
+    assert step.attn_direct_layout_share == 0.0
+    fam = registry.REGISTRY.get("znicz_lm_attn_direct_layout_share")
+    assert fam is not None and fam.labels(unit=step.name).get() == 0.0
+

@@ -435,6 +435,204 @@ def test_blocked_flash_attention_repeats_grouped_key_value_heads():
         np.testing.assert_allclose(g, w, atol=1e-4)
 
 
+def _refuse_rows():
+    """``form_of`` gives a small test shape the blocked form."""
+    from unittest import mock
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    return mock.patch.object(pattn, "unsupported_reason",
+                             lambda t, dh: "refused for the test")
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("dh", [128, 256])
+def test_blocked_direct_layout_is_the_folded_layout_to_the_bit(dh, causal):
+    """The blocked kernels over the layer's own ``(b, t, h, dh)`` (blocks
+    cut by head-indexed maps from ``(b, t, h * dh)``) against the same
+    kernels over operands folded head-major: the output and the three
+    gradients bit for bit, at 2 batches x 3 heads (``i // heads``, ``i %
+    heads`` both move) and a time axis of 3 blocks."""
+    import jax
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, t, h = 2, 384, 3
+    ks = jax.random.split(jax.random.PRNGKey(dh + causal), 4)
+    q, k, v, ct = (jax.random.normal(kk, (b, t, h, dh)).astype(jnp.bfloat16)
+                   for kk in ks)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, dh)  # noqa
+
+    def direct(q, k, v):
+        return pattn._flash_kvb(q, k, v, causal, True)
+
+    def folded(q, k, v):
+        o = pattn._flash_kvb(fold(q), fold(k), fold(v), causal, True)
+        return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
+
+    assert pattn._kvb_block(t) == 128
+    with _refuse_rows():
+        assert pattn.direct_layout(t, dh)
+    np.testing.assert_array_equal(direct(q, k, v), folded(q, k, v))
+    grads = [jax.grad(lambda *a: (f(*a).astype(jnp.float32) *
+                                  ct.astype(jnp.float32)).sum(),
+                      (0, 1, 2))(q, k, v) for f in (direct, folded)]
+    for got, want in zip(*grads):
+        assert got.shape == (b, t, h, dh)
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("dh", [64, 192])
+def test_heads_that_are_no_multiple_of_128_still_fold(dh):
+    """A ``(1, block, 64)`` or ``(1, block, 192)`` block of ``(b, t, h *
+    dh)`` is no legal TPU block: such heads reach the blocked kernels
+    folded head-major, as before."""
+    import jax
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, t, h = 1, 256, 2
+    q = jax.random.normal(jax.random.PRNGKey(dh), (b, t, h, dh))
+    with _refuse_rows():
+        assert pattn.form_of(t, dh) == ("blocked", None)
+        assert not pattn.direct_layout(t, dh)
+        text = str(jax.make_jaxpr(lambda q: pattn.flash_attention(
+            q, q, q, causal=True, interpret=True))(q))
+    assert pattn.KVB_FWD_KERNEL_NAME in text
+    assert f"[{b * h},{t},{dh}]" in text and "transpose" in text
+    # and the whole-row form's shapes are not asked: they fold as ever
+    assert not pattn.direct_layout(2048, 128)
+    assert pattn.direct_layout(4096, 256) and pattn.direct_layout(8192, 128)
+    assert not pattn.direct_layout(8192, 64)
+
+
+def test_blocked_direct_layout_repeats_grouped_key_value_heads():
+    """Fewer key/value heads in the direct layout (repeated on axis 2
+    before the view) equal the dense result over repeated heads, and the
+    gradients sum back over each group."""
+    import jax
+
+    from znicz_tpu.ops import attention as att
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, t, kv, group, dh = 2, 256, 2, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, ct = (jax.random.normal(kk, (b, t, kv * group, dh)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (b, t, kv, dh)) for kk in ks[2:])
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+        return att.attention(jnp, q, k, v, causal=True)
+
+    def flash(q, k, v):
+        return pattn.flash_attention(q, k, v, causal=True, interpret=True)
+
+    with jax.default_matmul_precision("highest"), _refuse_rows():
+        assert pattn.direct_layout(t, dh)
+        np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5)
+        got, want = (jax.grad(lambda *a: (f(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v) for f in (flash, dense))
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+@pytest.mark.parametrize("under_grad", [False, True], ids=["fwd", "grad"])
+def test_direct_layout_program_transposes_no_operand(under_grad):
+    """The jaxpr of ``flash_attention`` at head 256, forward and under
+    ``jax.grad``: no ``transpose`` of a ``(b, t, heads, head_dim)``
+    operand (the one array turned head-major is ``delta``'s float32
+    ``(b, heads, t)``, stacked head by head), and the three kernels under
+    the names ``benchmark/kernels/flash_attention_mla.py`` looks for."""
+    import jax
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, t, h, dh = 2, 256, 3, 256
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, t, h, dh),
+                          jnp.bfloat16)
+
+    def fn(q, k, v):
+        return pattn.flash_attention(q, k, v, causal=True, interpret=True)
+
+    if under_grad:
+        fn = jax.grad(lambda *a, f=fn: f(*a).astype(jnp.float32).sum(),
+                      (0, 1, 2))
+    with _refuse_rows():
+        jaxpr = jax.make_jaxpr(fn)(q, q, q)
+
+    def eqns(j):
+        for e in j.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    names = {e.params["name"] for e in eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"}
+    want = {"flash_attention_kvb_fwd"}
+    if under_grad:
+        want |= {"flash_attention_kvb_dkv", "flash_attention_kvb_dq"}
+    assert names == want
+    assert want <= {pattn.KVB_FWD_KERNEL_NAME, pattn.KVB_DKV_KERNEL_NAME,
+                    pattn.KVB_DQ_KERNEL_NAME}
+    moved = [e for e in eqns(jaxpr.jaxpr) if e.primitive.name == "transpose"]
+    assert all(e.invars[0].aval.ndim < 4 and
+               e.invars[0].aval.size <= b * t * h for e in moved), moved
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("dh,rope", [(256, 64), (128, 32), (128, 128)])
+def test_rope_tail_rotates_each_heads_tail_and_nothing_else(dh, rope, dtype):
+    """The in-place row kernel (interpreted) against cutting every head,
+    rotating its tail in halves order and concatenating: the same array,
+    the columns before the tail untouched to the bit, and the gradient
+    the rotation back."""
+    import jax
+
+    from znicz_tpu.ops.pallas import rope as prope
+    from znicz_tpu.parallel import transformer as tfm
+
+    b, t, h = 2, 48, 3
+    ks = jax.random.split(jax.random.PRNGKey(dh + rope), 2)
+    x, ct = (jax.random.normal(k, (b, t, h * dh)).astype(dtype) for k in ks)
+    cos, sin = tfm._rope_angles(t, rope, 10000.0)
+    assert prope.unsupported_reason(t, dh, rope) is None
+
+    def cut(x):
+        x4 = x.reshape(b, t, h, dh)
+        return jnp.concatenate([x4[..., :dh - rope], tfm._rotate(
+            x4[..., dh - rope:], 10000.0)], axis=-1).reshape(x.shape)
+
+    def rows(x):
+        return prope.rope_tail(x, cos, sin, h, True)
+
+    # (a fused multiply-add here or there: the last bit may differ)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-6
+    np.testing.assert_allclose(rows(x).astype(jnp.float32),
+                               cut(x).astype(jnp.float32), atol=tol)
+    np.testing.assert_array_equal(
+        rows(x).reshape(b, t, h, dh)[..., :dh - rope],
+        x.reshape(b, t, h, dh)[..., :dh - rope])
+    got, want = (jax.grad(lambda x, f=f: (f(x).astype(jnp.float32) *
+                                          ct.astype(jnp.float32)).sum())(x)
+                 for f in (rows, cut))
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               want.astype(jnp.float32),
+                               atol=tol)
+
+
+@pytest.mark.parametrize("t,dh,rope,word", [
+    (4096, 192, 64, "head_dim=192"), (4096, 256, 192, "192 columns"),
+    (4096, 256, 7, "7 columns"), (100, 256, 64, "t=100")])
+def test_rope_tail_unsupported_reason_names_the_refused_shape(t, dh, rope,
+                                                              word):
+    from znicz_tpu.ops.pallas import rope as prope
+
+    assert word in prope.unsupported_reason(t, dh, rope)
+    assert prope.unsupported_reason(4096, 256, 64) is None
+
+
 def test_flash_attention_keeps_the_whole_row_form_wherever_it_accepted():
     """At every shape the whole-row kernels took (the benchmark's GQA
     layer: head 64 at 4,096) ``flash_attention`` is the program it was,

@@ -27,3 +27,4 @@ from znicz_tpu.ops.pallas.gemm import (  # noqa: F401
     fc_backward, fc_forward, matmul)
 from znicz_tpu.ops.pallas.grouped import (  # noqa: F401
     gmm, gmm_rows, gmm_rows_t, gmm_weights)
+from znicz_tpu.ops.pallas.rope import rope_tail  # noqa: F401

@@ -38,6 +38,31 @@ the block sizes and the head width only, and under a causal mask the
 blocks wholly above the diagonal are in no kernel's grid: neither fetched
 nor computed.  :func:`form_of` says which form a shape gets; the whole-row
 form keeps every shape it took.
+
+Layouts.  The whole-row kernels read FOLDED operands: ``(b, t, h, dh)``
+transposed head-major to ``(b*h, t, dh)``, and ``o`` transposed back (a
+pass over each of q, k, v, o, and over their four gradients under AD).
+The blocked kernels read either.  With a head that is a multiple of 128
+(:func:`direct_layout`) they read the layer's own array, viewed as ``(b,
+t, h*dh)`` by a reshape that moves nothing: grid row ``i`` is batch ``i //
+h`` and head ``i % h``, and a ``(1, block, dh)`` block at index ``(i // h,
+q or key/value block, i % h)`` is ``block`` rows of ``dh`` contiguous
+entries, a legal TPU block; ``o``, ``dq``, ``dk``, ``dv`` are written
+through the same maps, so the output product and the projections'
+backward take them as they are and no head-major copy is in the program.
+The float32 rows stay the kernels' own, ``lse`` as ``(b*h, t, 1)``, and
+``delta = sum(do * o, -1)``, reckoned head by head from the layer's
+layout, is the one array still written head-major (``(b, h, t)`` float32).  At 64, 192, 320,
+448 such a block's last axis would be neither a multiple of the 128 lanes
+nor the whole axis, and the blocked kernels are handed folded operands as
+the whole-row ones are.  Kernel bodies, block sizes, visit tables and
+names are one set for both layouts.  The direct layout saves the copies
+only if the caller's arrays ARE row-major ``(b, t, h*dh)`` on the chip:
+XLA keeps them so when they are made whole rows of heads at a time (a
+product's result, a row kernel's) and lays them out time-minor, with a
+copy before the kernels, when a head is cut and concatenated at a column
+that is no multiple of 128 (``parallel/transformer.py::_latent_qkv`` has
+the forms that keep it).
 """
 
 from __future__ import annotations
@@ -434,20 +459,60 @@ def _kvb_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
 
-def _kvb_specs(block: int, dh: int):
+def _kvb_specs(block: int, dh: int, heads: int):
     """BlockSpecs over the visit tables: a q-side block, a key/value-side
-    block, and the float32 row statistics as a column or as a row."""
+    block, and the float32 row statistics as a column or as a row.  Grid
+    row ``i`` is (batch ``i // heads``, head ``i % heads``).  ``heads ==
+    0``: the operands are folded ``(b * h, t, dh)`` and row ``i`` is
+    theirs; else they are the layer's ``(b, t, heads * dh)`` and a block
+    is head ``i % heads``'s ``dh`` columns of batch ``i // heads``'s rows.
+    The statistics are ``(b * h, t, 1)`` or ``(b * h, 1, t)`` either way."""
     vm = pltpu.VMEM
+    if heads:
+        q = lambda i, v, qi, ki, fl: (i // heads, qi[v], i % heads)  # noqa: E731
+        kv = lambda i, v, qi, ki, fl: (i // heads, ki[v], i % heads)  # noqa: E731
+    else:
+        q = lambda i, v, qi, ki, fl: (i, qi[v], 0)          # noqa: E731
+        kv = lambda i, v, qi, ki, fl: (i, ki[v], 0)         # noqa: E731
     return {
-        "q": pl.BlockSpec((1, block, dh), lambda i, v, qi, ki, fl:
-                          (i, qi[v], 0), memory_space=vm),
-        "kv": pl.BlockSpec((1, block, dh), lambda i, v, qi, ki, fl:
-                           (i, ki[v], 0), memory_space=vm),
+        "q": pl.BlockSpec((1, block, dh), q, memory_space=vm),
+        "kv": pl.BlockSpec((1, block, dh), kv, memory_space=vm),
         "col": pl.BlockSpec((1, block, 1), lambda i, v, qi, ki, fl:
                             (i, qi[v], 0), memory_space=vm),
         "row": pl.BlockSpec((1, 1, block), lambda i, v, qi, ki, fl:
                             (i, 0, qi[v]), memory_space=vm),
     }
+
+
+def _kvb_dims(q):
+    """-> ``(b * h, t, dh, heads)`` of an operand in either layout the
+    blocked kernels take: folded ``(b * h, t, dh)`` (``heads`` 0) or the
+    layer's ``(b, t, h, dh)``, which they read as ``(b, t, h * dh)``."""
+    if q.ndim == 3:
+        return (*q.shape, 0)
+    b, t, h, dh = q.shape
+    return b * h, t, dh, h
+
+
+def _kvb_delta(o, do, heads: int):
+    """``sum(do * o)`` over each head's entries, float32 ``(b * h, t)``
+    head-major as the kernels read their rows.  Of the layer's ``(b, t,
+    h * dh)`` it is taken head by head, each a sum over that head's own
+    ``dh`` columns (the slices start at multiples of 128 lanes): the
+    ``(b, h, t)`` result is the one array the direct layout still writes
+    head-major, a 128th of an operand.  A folded operand is one head."""
+    heads = heads or 1
+    dh = o.shape[-1] // heads
+    head = lambda x, i: x[..., i * dh:(i + 1) * dh].astype(  # noqa: E731
+        jnp.float32)
+    return jnp.stack([(head(do, i) * head(o, i)).sum(-1)
+                      for i in range(heads)], axis=1)
+
+
+def _as_rows(x):
+    """The layer's ``(b, t, h, dh)`` as the ``(b, t, h * dh)`` the direct
+    index maps cut (a view: no element moves); a folded operand as it is."""
+    return x.reshape(*x.shape[:2], -1) if x.ndim == 4 else x
 
 
 def _kvb_params():
@@ -460,11 +525,14 @@ def _kvb_params():
 # each kernel (as ops/pallas/grouped.py's)
 @partial(jax.jit, static_argnames=("causal", "interpret"))
 def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool):
-    bh, t, dh = q.shape
+    """-> ``(o, lse)``: ``o`` in the operands' layout (:func:`_kvb_dims`),
+    ``lse`` float32 ``(b * h, t, 1)``."""
+    bh, t, dh, heads = _kvb_dims(q)
     block = _kvb_block(t)
     tables = _visits(t, block, causal, False)
-    spec = _kvb_specs(block, dh)
-    return pl.pallas_call(
+    spec = _kvb_specs(block, dh, heads)
+    q3 = _as_rows(q)
+    o, lse = pl.pallas_call(
         partial(_kvb_fwd_kernel, sm_scale=1.0 / float(np.sqrt(dh))),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(tables[0])),
@@ -473,21 +541,22 @@ def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool):
             scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
                             pltpu.VMEM((block, 1), jnp.float32),
                             pltpu.VMEM((block, dh), jnp.float32)]),
-        out_shape=[_out_struct((bh, t, dh), q.dtype, q),
+        out_shape=[_out_struct(q3.shape, q.dtype, q),
                    _out_struct((bh, t, 1), jnp.float32, q)],
         compiler_params=_kvb_params(), name=KVB_FWD_KERNEL_NAME,
         interpret=interpret,
-    )(*tables, q, k, v)
+    )(*tables, q3, _as_rows(k), _as_rows(v))
+    return o.reshape(q.shape), lse
 
 
 @partial(jax.jit, static_argnames=("causal", "interpret"))
 def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool):
-    bh, t, dh = q.shape
+    bh, t, dh, heads = _kvb_dims(q)
     block = _kvb_block(t)
-    spec = _kvb_specs(block, dh)
+    spec = _kvb_specs(block, dh, heads)
     sm_scale = 1.0 / float(np.sqrt(dh))
-    delta = (do.astype(jnp.float32) *
-             o.astype(jnp.float32)).sum(-1, keepdims=True)  # (bh, t, 1)
+    q3, k3, v3, do3 = (_as_rows(x) for x in (q, k, v, do))
+    delta = _kvb_delta(_as_rows(o), do3, heads)
     by_kv, by_q = (_visits(t, block, causal, flag) for flag in (True, False))
     dk, dv = pl.pallas_call(
         partial(_kvb_dkv_kernel, sm_scale=sm_scale),
@@ -498,11 +567,12 @@ def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool):
             out_specs=[spec["kv"], spec["kv"]],
             scratch_shapes=[pltpu.VMEM((block, dh), jnp.float32),
                             pltpu.VMEM((block, dh), jnp.float32)]),
-        out_shape=[_out_struct(k.shape, k.dtype, q),
-                   _out_struct(v.shape, v.dtype, q)],
+        out_shape=[_out_struct(k3.shape, k.dtype, q),
+                   _out_struct(v3.shape, v.dtype, q)],
         compiler_params=_kvb_params(), name=KVB_DKV_KERNEL_NAME,
         interpret=interpret,
-    )(*by_kv, q, k, v, do, lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
+    )(*by_kv, q3, k3, v3, do3, lse.reshape(bh, 1, t),
+      delta.reshape(bh, 1, t))
     dq = pl.pallas_call(
         partial(_kvb_dq_kernel, sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -511,15 +581,17 @@ def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool):
                       spec["col"], spec["col"]],
             out_specs=spec["q"],
             scratch_shapes=[pltpu.VMEM((block, dh), jnp.float32)]),
-        out_shape=_out_struct(q.shape, q.dtype, q),
+        out_shape=_out_struct(q3.shape, q.dtype, q),
         compiler_params=_kvb_params(), name=KVB_DQ_KERNEL_NAME,
         interpret=interpret,
-    )(*by_q, q, k, v, do, lse, delta)
-    return dq, dk, dv
+    )(*by_q, q3, k3, v3, do3, lse, delta.reshape(bh, t, 1))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_kvb(q, k, v, causal: bool, interpret: bool):
+    """The blocked kernels over operands in either layout
+    (:func:`_kvb_dims`); ``o`` and the three gradients come in theirs."""
     return _kvb_call_fwd(q, k, v, causal, interpret)[0]
 
 
@@ -592,15 +664,27 @@ def supported(t: int, dh: int) -> bool:
     return unsupported_reason(t, dh) is None
 
 
+def direct_layout(t: int, dh: int) -> bool:
+    """Whether the shape's kernels cut their blocks from the layer's own
+    ``(b, t, heads * dh)`` and write ``o`` and the gradients there, by the
+    shape alone: the blocked form (:func:`form_of`) with a head that is a
+    multiple of 128.  A ``(1, block, dh)`` block at index ``(batch, block,
+    head)`` is then a legal TPU block (its last axis a multiple of the 128
+    lanes); at 64, 192, 320, 448 it is neither that nor the whole axis,
+    and the operands are folded head-major as the whole-row form's are."""
+    return form_of(t, dh)[0] == "blocked" and dh % 128 == 0
+
+
 def flash_attention(q, k, v, causal: bool = False, *,
                     interpret: bool = False):
     """Fused attention over per-head tensors ``(b, t, h, dh)`` — same
     contract as ops.attention.attention (``softmax(q·kᵀ/√dh)·v``),
     differentiable via the flash backward kernels, in the form
-    :func:`form_of` gives the shape.  ``k`` and ``v`` may carry fewer
-    heads (``h`` a multiple of theirs): grouped-query attention, query
-    head ``j`` reading key/value head ``j // group`` (the whole-row form
-    through its index maps, the blocked form over repeated heads)."""
+    :func:`form_of` gives the shape and the layout :func:`direct_layout`
+    gives it.  ``k`` and ``v`` may carry fewer heads (``h`` a multiple of
+    theirs): grouped-query attention, query head ``j`` reading key/value
+    head ``j // group`` (the whole-row form through its index maps, the
+    blocked form over repeated heads)."""
     b, t, h, dh = q.shape
     form, why = form_of(t, dh)
     if form is None:
@@ -616,5 +700,7 @@ def flash_attention(q, k, v, causal: bool = False, *,
         group = h // k.shape[2]
         if group > 1:
             k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        if direct_layout(t, dh):
+            return _flash_kvb(q, k, v, causal, interpret)
         o = _flash_kvb(fold(q), fold(k), fold(v), causal, interpret)
     return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)

@@ -57,20 +57,29 @@ _log = logging.getLogger("znicz_tpu.transformer")
 
 
 @functools.lru_cache(maxsize=None)
-def _report_flash_refusal(t: int, dh: int, why: str) -> None:
-    """The flash kernel was eligible by platform and mesh, and the shape
-    turned it down: say so, once per shape per process — the dense
-    ``ring_attention`` path that takes over materializes the score
-    matrix, which is a different program, not a detail."""
-    _log.warning("flash attention refused t=%d head_dim=%d: %s; this "
-                 "step uses dense ring_attention", t, dh, why)
+def _report_flash_choice(t: int, dh: int, why: str | None,
+                         direct: bool) -> None:
+    """What a shape that was eligible for a flash kernel by platform and
+    mesh got, said once per shape per process: a refusal with its reason
+    (the dense ``ring_attention`` path that takes over materializes the
+    score matrix, which is a different program, not a detail), or the
+    layout its kernels read (``attention.direct_layout``: the layer's
+    own, or operands folded head-major around them)."""
+    if why:
+        _log.warning("flash attention refused t=%d head_dim=%d: %s; this "
+                     "step uses dense ring_attention", t, dh, why)
+    else:
+        _log.info("flash attention t=%d head_dim=%d: kernels read %s",
+                  t, dh, "the layer's (batch, t, heads x head_dim) layout"
+                  if direct else "operands folded head-major "
+                  "(batch x heads, t, head_dim): eight transposes a layer")
 
 
 def _flash_eligible(mesh: Mesh, interpret: bool) -> bool:
     """Use the Pallas flash kernel when the seq axis is unsharded (the
     ring handles sharded time) on a TPU; per-shape limits are checked at
     trace time (ops.pallas.attention.unsupported_reason — a refusal is
-    logged, :func:`_report_flash_refusal`).
+    logged, :func:`_report_flash_choice`).
     ``root.common.engine.flash_attention`` (default True) turns it off;
     ``interpret`` (the pallas_interpret flag, captured once at step-build
     time) forces it ON for the Pallas interpreter — but only on a
@@ -680,6 +689,14 @@ def _norm(x, p, which: str, arch: Arch):
     return _layer_norm(x, p[which + "_g"], p[which + "_b"], arch.eps)
 
 
+def _rope_angles(t: int, dh: int, theta: float):
+    """``cos`` and ``sin`` ``(t, dh / 2)`` of the rotary angles of
+    positions 0 .. t-1 over a rotated width ``dh``, float32."""
+    inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
 def _rotate(x, theta: float, interleaved: bool = False):
     """Rotary embedding over the whole head of ``x (b, t, h, dh)``,
     rotate-half form, positions from 0 (the seq axis is unsharded
@@ -691,10 +708,9 @@ def _rotate(x, theta: float, interleaved: bool = False):
     t, dh = x.shape[1], x.shape[-1]
     if interleaved:
         x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-    inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[None, :, None, :]
+    cos, sin = _rope_angles(t, dh, theta)
+    cos = jnp.concatenate([cos] * 2, axis=-1)[None, :, None, :]
+    sin = jnp.concatenate([sin] * 2, axis=-1)[None, :, None, :]
     xf = x.astype(jnp.float32)
     half = jnp.concatenate([-xf[..., dh // 2:], xf[..., :dh // 2]], axis=-1)
     return (xf * cos + half * sin).astype(x.dtype)
@@ -704,22 +720,26 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     """Layer ``index`` of ``arch`` on local shards (``n_layers``: the MTP
     module's): its mixer, then its feed-forward, each reading a norm of
     the residual stream and adding to it.  -> ``(x, aux, stats)``: the
-    regularizer term (pre-weighted) and the routed layer's counters.
+    regularizer term (pre-weighted) and the layer's counters (the routed
+    layer's, and :func:`_block_attn`'s of a layer that ran a flash
+    kernel).
     Scopes: ``block<index>.attn`` (with ``.attn.latent`` beside it for
     what latent attention does before the kernel) or ``.sconv``, then
     ``block<index>.mlp`` or ``.moe`` (with ``.moe.route``,
     ``.moe.experts`` and ``.moe.shared`` beside it)."""
     mixer, ffn = arch.kinds(index)
+    stats: dict = {}
     if mixer == "sconv":
         with _probe.scope(f"block{index}.sconv"):
             x = _block_sconv(x, p, arch)
     else:
-        x = _block_attn(x, p, arch, run, f"block{index}.attn")
+        x, stats = _block_attn(x, p, arch, run, f"block{index}.attn")
     if ffn == "moe_routed":
-        return _block_routed(x, p, arch, f"block{index}.moe")
+        x, aux, routed = _block_routed(x, p, arch, f"block{index}.moe")
+        return x, aux, {**stats, **routed}
     with _probe.scope(f"block{index}.mlp"):
         x, aux = _block_mlp(x, p, arch, ffn, run)
-    return x, aux, {}
+    return x, aux, stats
 
 
 def _plain_qkv(h, p, arch: Arch, run: _Run):
@@ -743,27 +763,79 @@ def _plain_qkv(h, p, arch: Arch, run: _Run):
     return q, k, v
 
 
-def _latent_qkv(h, p, arch: Arch):
+def _rows_rope(t: int, arch: Arch, run: _Run) -> bool:
+    """Whether latent attention's queries are rotated as whole rows of
+    heads by the in-place kernel (``ops/pallas/rope.py``): where the flash
+    kernels read the layer's own layout (``attention.direct_layout``) and
+    the kernel takes the shape.  Elsewhere the head is cut and
+    concatenated, and the flash kernels fold or copy it anyway."""
+    from znicz_tpu.ops.pallas import attention as pattn, rope as prope
+    dh = arch.nope_dim + arch.rope_dim
+    return run.use_flash and pattn.direct_layout(t, dh) and \
+        prope.unsupported_reason(t, dh, arch.rope_dim) is None
+
+
+def _latent_q(c_q, wq_b, arch: Arch, run: _Run):
+    """Latent attention's queries ``(b, t, heads, nope + rope)`` from the
+    normed query latent: ``[q_nope | q_pe] = c_q wq_b`` a head, ``q_pe``
+    rotated.  Where :func:`_rows_rope` says so the product's ``(b, t,
+    heads * head_dim)`` result is rotated in place (the weight's columns
+    permuted first so that a head's rotary pairs lie in halves order, the
+    order :func:`_rotate` leaves them in): no array op cuts a head."""
+    b, t, _ = c_q.shape
+    heads, nope, rope = arch.heads, arch.nope_dim, arch.rope_dim
+    if not _rows_rope(t, arch, run):
+        q = (c_q @ wq_b).reshape(b, t, heads, nope + rope)
+        return jnp.concatenate([q[..., :nope], _rotate(
+            q[..., nope:], arch.rope_theta, arch.rope_interleaved)], axis=-1)
+    from znicz_tpu.ops.pallas import rope as prope
+    if arch.rope_interleaved:
+        w = wq_b.reshape(-1, heads, nope + rope)
+        wq_b = lax.optimization_barrier(jnp.concatenate(
+            [w[..., :nope], w[..., nope::2], w[..., nope + 1::2]],
+            axis=-1).reshape(wq_b.shape))
+    cos, sin = _rope_angles(t, rope, arch.rope_theta)
+    return prope.rope_tail(c_q @ wq_b, cos, sin, heads,
+                           run.interpret).reshape(b, t, heads, nope + rope)
+
+
+def _latent_qkv(h, p, arch: Arch, run: _Run):
     """Latent attention's queries, keys and values ``(b, t, heads,
     head_dim)``: ``c_q = RMSNorm(h wq_a)``, ``[q_nope | q_pe] = c_q
     wq_b`` a head; ``[c_kv | k_pe] = h wkv_a``, ``c_kv = RMSNorm(c_kv)``,
     ``[k_nope | v] = c_kv wkv_b`` a head; ``q_pe`` and the ONE ``k_pe``
     all heads share are rotated; ``q = [q_nope | q_pe]``, ``k = [k_nope |
-    k_pe]``."""
+    k_pe]``.  Each of the three is made whole rows of heads at a time (a
+    product's result, or rotated in place), never cut inside a head and
+    concatenated: XLA then keeps ``(b, t, heads * head_dim)`` row-major,
+    the layout the flash kernels read (``attention.direct_layout``), where
+    a cut at column ``nope`` makes it lay the array out time-minor and
+    copy it for the kernels and back for their gradients."""
     b, t, _ = h.shape
     heads, nope, rope = arch.heads, arch.nope_dim, arch.rope_dim
     c_q = _rms_norm(h @ p["wq_a"], p["q_a_g"], arch.eps)
-    q = (c_q @ p["wq_b"]).reshape(b, t, heads, nope + rope)
+    q = _latent_q(c_q, p["wq_b"], arch, run)
     kv_a = h @ p["wkv_a"]
     c_kv = _rms_norm(kv_a[..., :arch.kv_lora], p["kv_a_g"], arch.eps)
-    kv = (c_kv @ p["wkv_b"]).reshape(b, t, heads, nope + arch.head_dim)
-    k_pe = kv_a[..., arch.kv_lora:].reshape(b, t, 1, rope)
-    turn = functools.partial(_rotate, theta=arch.rope_theta,
-                             interleaved=arch.rope_interleaved)
-    q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
-    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-        turn(k_pe), (b, t, heads, rope))], axis=-1)
-    return q, k, kv[..., nope:]
+    k_pe = _rotate(kv_a[..., arch.kv_lora:].reshape(b, t, 1, rope),
+                   arch.rope_theta, arch.rope_interleaved)
+    # keys and values each come out of a product of their own, whole rows
+    # of ``heads`` heads: a head's ``[k_nope | k_pe]`` is ``[c_kv | k_pe]``
+    # times ``[its k_nope columns | zeros]`` over ``[zeros | identity]``,
+    # so the MXU places the one rotated key in every head (exactly: ones
+    # and zeros) where a concatenation with its broadcast would cut the
+    # head's row at a column that is no multiple of the 128 lanes
+    wkv = p["wkv_b"].reshape(arch.kv_lora, heads, nope + arch.head_dim)
+    place = jnp.pad(jnp.eye(rope, dtype=wkv.dtype), ((0, 0), (nope, 0)))
+    wk = jnp.concatenate([
+        jnp.pad(wkv[..., :nope], ((0, 0), (0, 0), (0, rope))),
+        jnp.broadcast_to(place[:, None], (rope, heads, nope + rope))])
+    wk, wv = lax.optimization_barrier((
+        wk.reshape(arch.kv_lora + rope, -1),
+        wkv[..., nope:].reshape(arch.kv_lora, -1)))
+    k = jnp.concatenate([c_kv, k_pe.reshape(b, t, rope)], axis=-1) @ wk
+    v = c_kv @ wv
+    return q, k.reshape(b, t, heads, -1), v.reshape(b, t, heads, -1)
 
 
 def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
@@ -776,14 +848,18 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
     than query heads go to the flash kernels as they are and to the dense
     core repeated.  The norm, the kernel, the output product and the
     residual sum lie under ``scope``; what latent attention does before
-    the kernel under ``scope.latent``, a sibling by name."""
+    the kernel under ``scope.latent``, a sibling by name.  -> ``(x,
+    stats)``: a layer that ran a flash kernel counts ``attn_flash`` 1 and
+    ``attn_direct`` 1 or 0 (``attention.direct_layout``), constants of
+    the traced step whose sums over layers give the unit its
+    ``znicz_lm_attn_direct_layout_share``."""
     from znicz_tpu.ops.pallas import attention as pattn
     with _probe.scope(scope):
         h = _norm(x, p, "ln1", arch)
     b, t_loc, _ = h.shape
     if "wkv_a" in p:
         with _probe.scope(f"{scope}.latent"):
-            q, k, v = _latent_qkv(h, p, arch)
+            q, k, v = _latent_qkv(h, p, arch, run)
     else:
         with _probe.scope(scope):
             q, k, v = _plain_qkv(h, p, arch, run)
@@ -794,8 +870,12 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
             why = pattn.form_of(t_loc, dh)[1]
         elif run.use_ring_flash:       # the ring merges whole-row blocks
             why = pattn.unsupported_reason(t_loc, dh)
-        if why:
-            _report_flash_refusal(t_loc, dh, why)
+        eligible = run.use_flash or run.use_ring_flash
+        flash = eligible and not why
+        direct = bool(flash and run.use_flash and
+                      pattn.direct_layout(t_loc, dh))
+        if eligible:
+            _report_flash_choice(t_loc, dh, why, direct)
         if run.use_flash and not why:
             o = pattn.flash_attention(q, k, v, causal=run.causal,
                                       interpret=run.interpret)
@@ -809,7 +889,12 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
             else:
                 o = ring_attention(q, k, v, "seq", causal=run.causal)
         o = o.reshape(b, t_loc, -1)                  # (b, t_loc, d_local)
-        return x + tp.row_parallel(o, p["wo"], None, "model")
+        # a layer that ran a flash kernel counts itself, and once more if
+        # its kernels read the layer's layout: known as the step is traced
+        stats = {"attn_flash": jnp.ones((), jnp.float32),
+                 "attn_direct": jnp.full((), float(direct), jnp.float32)} \
+            if flash else {}
+        return x + tp.row_parallel(o, p["wo"], None, "model"), stats
 
 
 def _block_sconv(x, p, arch: Arch):
@@ -1231,9 +1316,11 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
     (``masked=True``: ``step(params, tokens, labels, mask)`` with a
     per-row bool mask — padded loader rows train nothing;
     ``stats=True``: ``-> (params, loss, stats)`` with the routed expert
-    layers' counters of the step and, of a stack with an MTP module, the
-    loss's two terms (``loss_main``, ``loss_mtp``, unweighted), float32
-    scalars, an empty dict for a stack that has none).
+    layers' counters of the step, the count of attention layers that ran
+    a flash kernel and of those whose kernels read the layer's layout
+    (``attn_flash``, ``attn_direct``) and, of a stack with an MTP module,
+    the loss's two terms (``loss_main``, ``loss_mtp``, unweighted),
+    float32 scalars, an empty dict for a stack that has none).
 
     ``arch`` says what the stack is (:func:`as_arch`): an :class:`Arch`,
     a model's configuration mapping, or, as ever, the GPT-shaped block's

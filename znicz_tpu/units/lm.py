@@ -127,6 +127,10 @@ class TransformerLMStep(AcceleratedUnit):
         #: with an MTP module (``last_loss`` and the Decision's metric are
         #: their weighted total): ``{"main", "mtp"}``, unweighted
         self.loss_terms: dict = {}
+        #: of the last finished pass's attention layers that ran a flash
+        #: kernel, the share whose kernels read the layer's layout
+        #: (``ops/pallas/attention.py::direct_layout``); None without one
+        self.attn_direct_layout_share: Optional[float] = None
         self.arch = None
         self._params = None
         self._step = None
@@ -291,6 +295,23 @@ class TransformerLMStep(AcceleratedUnit):
         if "loss_main" in sums:
             self._publish_loss_terms(float(sums["loss_main"]) / steps,
                                      float(sums["loss_mtp"]) / steps)
+        if "attn_flash" in sums:
+            self._publish_attn_layout(float(sums["attn_direct"]) /
+                                      float(sums["attn_flash"]))
+
+    def _publish_attn_layout(self, share: float) -> None:
+        """Of the attention layers that ran a flash kernel, the share whose
+        kernels read the layer's own layout (a constant of the traced
+        step): the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        self.attn_direct_layout_share = share
+        registry.gauge(
+            "znicz_lm_attn_direct_layout_share",
+            "attention layers whose flash kernels read the layer's (batch, "
+            "t, heads x head_dim) layout over the attention layers that ran "
+            "a flash kernel (the rest fold their operands head-major)",
+            ("unit",)).labels(unit=self.name).set(share)
 
     def _publish_loss_terms(self, main: float, mtp: float) -> None:
         """A finished training pass's two loss terms, each the mean over
