@@ -362,25 +362,31 @@ def test_flash_attention_supported_gate():
     assert not supported(1 << 20, 64)  # VMEM budget
 
 
-@pytest.mark.parametrize("t,dh,causal", [
-    (384, 256, True), (384, 64, True), (640, 64, True), (256, 128, False)],
-    ids=["3x128_dh256", "3x128_dh64", "5x128_dh64", "full_dh128"])
-def test_blocked_flash_attention_matches_dense(t, dh, causal):
+@pytest.mark.parametrize("t,dh,causal,h", [
+    (384, 256, True, 2), (384, 64, True, 2), (640, 64, True, 2),
+    (256, 128, False, 2), (384, 128, True, 16)],
+    ids=["3x128_dh256", "3x128_dh64", "5x128_dh64", "full_dh128",
+         "3x128_16_heads_of_128_direct"])
+def test_blocked_flash_attention_matches_dense(t, dh, causal, h):
     """The key/value-blocked kernels (interpreted), forward and the three
     gradients, against dense attention: heads of 256 and 64, a time axis
     that is 3 and 5 blocks (no power of two times the block), causal (only
-    the tiles under the diagonal are visited) and full."""
+    the tiles under the diagonal are visited) and full; and 16 heads of
+    128 read from the layer's own ``(b, t, h, dh)``, the shape a looped
+    dense stack brings (the other cases fold their operands head-major)."""
     import jax
 
     from znicz_tpu.ops import attention as att
     from znicz_tpu.ops.pallas import attention as pattn
 
-    b, h = 1, 2
+    b, direct = 1, h == 16
     ks = jax.random.split(jax.random.PRNGKey(t + dh), 4)
     q, k, v, ct = (jax.random.normal(kk, (b, t, h, dh)) for kk in ks)
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, dh)  # noqa
 
     def blocked(q, k, v):
+        if direct:
+            return pattn._flash_kvb(q, k, v, causal, True)
         o = pattn._flash_kvb(fold(q), fold(k), fold(v), causal, True)
         return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
 

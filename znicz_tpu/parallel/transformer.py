@@ -28,6 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from znicz_tpu.parallel.compat import quantized_psum, shard_map
@@ -155,6 +156,15 @@ class Arch:
     one more layer of the last layer's kinds, index ``n_layers``, a norm
     of its own, the model's embedding and head) whose cross-entropy on
     the second-next token joins the loss ``mtp_weight`` times.
+    ``sandwich`` puts a second norm with its own gain on each sub-layer's
+    OUTPUT, before the residual sum (written for the attention and SwiGLU
+    sub-layers).  ``loop_steps`` runs the whole stack that many times over
+    the same weights, the final norm closing every loop step and its
+    result fed back into layer 0 (:func:`_looped`); a looped stack has an
+    exit gate (``exit_gate``): a biased ``d -> 1`` reads every loop step's
+    output, the gates make a distribution over the loop steps token by
+    token and the loss is the steps' cross-entropies weighted by it, less
+    ``exit_beta`` times its entropy (:func:`_forward_loop_ce`).
 
     Built by :func:`gpt_arch` (the block this module always had: the
     four integers) or :func:`arch_from_config` (a model's own keys)."""
@@ -191,6 +201,29 @@ class Arch:
     shared_ff: int = 0
     mtp: bool = False
     mtp_weight: float = 0.0
+    sandwich: bool = False
+    loop_steps: int = 1
+    exit_beta: float = 0.0
+
+    def __post_init__(self):
+        if self.sandwich and not (set(self.mixers) <= {"attention", "latent"}
+                                  and set(self.ffns) <= {"glu"}):
+            raise ValueError("sandwich: the second norm is written for "
+                             "attention and SwiGLU sub-layers")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps}: at least 1")
+        if self.loop_steps > 1 and (self.mtp or not self.final_norm or
+                                    "moe_routed" in self.ffns):
+            raise ValueError("a looped stack is written with the final "
+                             "norm closing each loop step, no MTP module "
+                             "and no routed experts (their counters are "
+                             "means over layers, not over loop steps)")
+
+    @property
+    def exit_gate(self) -> bool:
+        """A looped stack's loss reads an exit gate; an unlooped stack
+        has none (its one output would take the whole weight)."""
+        return self.loop_steps > 1
 
     @property
     def n_layers(self) -> int:
@@ -229,6 +262,12 @@ class Arch:
             out.append("shared expert")
         if self.mtp:
             out.append("multi-token prediction")
+        if self.loop_steps > 1:
+            out.append("looped stack")
+        if self.exit_gate:
+            out.append("exit gate")
+        if self.sandwich:
+            out.append("sandwich norm")
         if self.norm != "layer":
             out.append("RMSNorm")
         if self.final_norm:
@@ -367,15 +406,58 @@ def _glm4_moe_lite_arch(cfg, vocab: int | None) -> Arch:
         mtp=bool(mtp), mtp_weight=float(cfg.get("mtp_loss_weight", 0.3)))
 
 
+def _ouro_arch(cfg, vocab: int | None) -> Arch:
+    """``ouro`` (a looped LM, arXiv:2510.25741: ``total_ut_steps``,
+    ``layer_types`` all ``full_attention``, ``head_dim``,
+    ``rms_norm_eps``, ``rope_theta``, ...): a dense stack of RMSNorm
+    sandwich-normed layers (plain multi-head or grouped-query attention
+    with rotate-half RoPE over the whole head, no bias, no QK-norm; a
+    bias-free SwiGLU) run ``total_ut_steps`` times over the same weights,
+    the final norm closing every loop step, an exit gate, an untied head.
+    ``exit_entropy_weight`` (0.1) is the loss's ``beta``;
+    ``early_exit_threshold`` is an inference key and is not read."""
+    types = list(cfg.get("layer_types") or
+                 ["full_attention"] * int(cfg["num_hidden_layers"]))
+    if int(cfg.get("num_hidden_layers", len(types))) != len(types):
+        raise ValueError(f"num_hidden_layers {cfg['num_hidden_layers']} "
+                         f"against {len(types)} layer_types")
+    if set(types) != {"full_attention"}:
+        raise ValueError(f"layer_types {sorted(set(types))}: "
+                         f"full_attention in every layer is what is written")
+    if cfg.get("use_sliding_window", False) or cfg.get("sliding_window"):
+        raise ValueError("sliding_window: attention here is causal over "
+                         "the whole sequence")
+    if cfg.get("rope_scaling") or cfg.get("attention_bias", False):
+        raise ValueError("rope_scaling / attention_bias: the rotary "
+                         "embedding is unscaled and the projections have "
+                         "no bias")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {cfg['hidden_act']!r}: SwiGLU")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    steps = int(cfg.get("total_ut_steps", 1))
+    return Arch(
+        d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=int(cfg.get("head_dim") or d // heads),
+        ff=int(cfg["intermediate_size"]),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=("attention",) * len(types), ffns=("glu",) * len(types),
+        norm="rms", eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        rope_theta=float(cfg.get("rope_theta", 1e6)), final_norm=True,
+        tied=bool(cfg.get("tie_word_embeddings", False)), sandwich=True,
+        loop_steps=steps,
+        exit_beta=float(cfg.get("exit_entropy_weight", 0.1)))
+
+
 #: ``model_type`` -> the reader of that family's keys
-_FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch}
+_FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch,
+             "ouro": _ouro_arch}
 
 
 def arch_from_config(cfg, vocab: int | None = None) -> Arch:
     """A model's own keys -> :class:`Arch`, by ``model_type``
     (:data:`_FAMILIES`: :func:`_lfm2_moe_arch`, also what a mapping with
-    ``layer_types`` and no ``model_type`` is read as, and
-    :func:`_glm4_moe_lite_arch`).  ``experts_held`` (``{"first",
+    ``layer_types`` and no ``model_type`` is read as,
+    :func:`_glm4_moe_lite_arch` and :func:`_ouro_arch`).  ``experts_held`` (``{"first",
     "count"}``; all by default) is this chip's share of the experts;
     ``vocab`` (the loader's) overrides ``vocab_size``.  Any other
     ``model_type`` is refused by name."""
@@ -404,6 +486,7 @@ _LEAF_MECHANISMS = {
     "w_in": "gated short convolution", "q_g": "QK-norm",
     "w3": "SwiGLU", "ew3": "routed experts (moe_routed_ffn)",
     "wkv_a": "latent attention", "sw1": "shared expert",
+    "ln1o_g": "sandwich norm",
 }
 
 
@@ -421,6 +504,8 @@ def mechanisms_of_params(params) -> list:
             out.append("grouped-query attention")
     if "mtp" in params:
         out.append("multi-token prediction")
+    if "exit_w" in params:             # only a looped stack carries a gate
+        out += ["looped stack", "exit gate"]
     if "norm_g" in params:
         out.append("final norm")
     if "head" not in params:
@@ -438,6 +523,8 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
     out = {"ln1_g": (d,), "ln2_g": (d,)}
     if bias:
         out.update({"ln1_b": (d,), "ln2_b": (d,)})
+    if arch.sandwich:
+        out.update({"ln1o_g": (d,), "ln2o_g": (d,)})
     if mixer == "latent":
         out.update({
             "wq_a": (d, arch.q_lora), "q_a_g": (arch.q_lora,),
@@ -477,6 +564,19 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
     return out
 
 
+def _tail_shapes(arch: Arch) -> dict:
+    """``{leaf: shape}`` of what the pytree holds behind ``blocks``: the
+    final norm's gain, the MTP module, the exit gate."""
+    out = {}
+    if arch.final_norm:
+        out["norm_g"] = (arch.d,)
+    if arch.mtp:
+        out["mtp"] = _mtp_shapes(arch)
+    if arch.exit_gate:
+        out.update({"exit_w": (arch.d, 1), "exit_b": (1,)})
+    return out
+
+
 def _mtp_shapes(arch: Arch) -> dict:
     """``{leaf: shape}`` of the MTP module: the two norms and the
     projection in front of its layer, the layer, the norm behind it."""
@@ -486,9 +586,9 @@ def _mtp_shapes(arch: Arch) -> dict:
 
 
 #: leaves that start at one (gains), and those that start at zero
-_ONES = ("ln1_g", "ln2_g", "q_g", "k_g", "norm_g", "q_a_g", "kv_a_g",
-         "enorm_g", "hnorm_g")
-_ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias")
+_ONES = ("ln1_g", "ln2_g", "ln1o_g", "ln2o_g", "q_g", "k_g", "norm_g",
+         "q_a_g", "kv_a_g", "enorm_g", "hnorm_g")
+_ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias", "exit_b")
 #: how each leaf of the GPT-shaped block lies over the ``model`` axis
 _TP_SPECS = {
     "wq": P(None, "model"), "wk": P(None, "model"), "wv": P(None, "model"),
@@ -543,10 +643,7 @@ def init_params(gen, arch, d=None, heads=None, ff=None, vocab=None,
     if not arch.tied:
         out["head"] = w((arch.d, arch.vocab))
     out["blocks"] = blocks
-    if arch.final_norm:
-        out["norm_g"] = np.ones(arch.d, np.float32)
-    if arch.mtp:
-        out["mtp"] = _map_shapes(leaf, _mtp_shapes(arch))
+    out.update(_map_shapes(leaf, _tail_shapes(arch)))
     return out
 
 
@@ -576,10 +673,7 @@ def param_specs(arch, head_sharded: bool = False, moe: bool = False):
     if not arch.tied:
         out["head"] = P(None, "model") if head_sharded else P()
     out["blocks"] = blocks
-    if arch.final_norm:
-        out["norm_g"] = P()
-    if arch.mtp:
-        out["mtp"] = _map_shapes(lambda k, shape: P(), _mtp_shapes(arch))
+    out.update(_map_shapes(lambda k, shape: P(), _tail_shapes(arch)))
     return out
 
 
@@ -596,10 +690,7 @@ def param_shapes(arch, d=None, ff=None, vocab=None,
     if not arch.tied:
         out["head"] = (arch.d, arch.vocab)
     out["blocks"] = [_layer_shapes(arch, i) for i in range(arch.n_layers)]
-    if arch.final_norm:
-        out["norm_g"] = (arch.d,)
-    if arch.mtp:
-        out["mtp"] = _mtp_shapes(arch)
+    out.update(_tail_shapes(arch))
     return out
 
 
@@ -689,6 +780,15 @@ def _norm(x, p, which: str, arch: Arch):
     return _layer_norm(x, p[which + "_g"], p[which + "_b"], arch.eps)
 
 
+def _sub_out(y, p, which: str, arch: Arch):
+    """A sub-layer's output on its way to the residual sum: named for the
+    looped stack's recomputation policy (:func:`_block_fn`; a name is no
+    operation), and through the sandwich's second norm where the stack
+    has one."""
+    y = checkpoint_name(y, "sub_out")
+    return _norm(y, p, which, arch) if arch.sandwich else y
+
+
 def _rope_angles(t: int, dh: int, theta: float):
     """``cos`` and ``sin`` ``(t, dh / 2)`` of the rotary angles of
     positions 0 .. t-1 over a rotated width ``dh``, float32."""
@@ -745,7 +845,12 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
 def _plain_qkv(h, p, arch: Arch, run: _Run):
     """Queries, keys and values ``(b, t, heads, head_dim)`` of plain or
     grouped-query attention: three projections, the optional QK-norm,
-    the optional rotary embedding over the whole head."""
+    the optional rotary embedding over the whole head: rotate-half, by the
+    in-place row kernel where :func:`_rows_rope` says so (a head of 128:
+    the whole head is the kernel's tail), else :func:`_rotate`'s f32 chain
+    of array operations.  What no kernel wrote is named ``attn_qkv`` for
+    the looped stack's recomputation policy, which keeps every kernel's
+    output anyway (:func:`_loop_saves`; a name is no operation)."""
     b, t_loc, _ = h.shape
 
     def heads_of(w, n):
@@ -754,25 +859,33 @@ def _plain_qkv(h, p, arch: Arch, run: _Run):
 
     q = heads_of(p["wq"], run.heads_local)
     k = heads_of(p["wk"], run.kv_heads_local)
-    v = heads_of(p["wv"], run.kv_heads_local)
+    v = checkpoint_name(heads_of(p["wv"], run.kv_heads_local), "attn_qkv")
     if arch.qk_norm:
         q = _rms_norm(q, p["q_g"], arch.eps)
         k = _rms_norm(k, p["k_g"], arch.eps)
+    if arch.rope_theta is not None and _rows_rope(t_loc, arch, run):
+        from znicz_tpu.ops.pallas import rope as prope
+        cos, sin = _rope_angles(t_loc, arch.head_dim, arch.rope_theta)
+        return tuple(prope.rope_tail(
+            a.reshape(b, t_loc, -1), cos, sin, a.shape[2],
+            run.interpret).reshape(a.shape) for a in (q, k)) + (v,)
     if arch.rope_theta is not None:
         q, k = _rotate(q, arch.rope_theta), _rotate(k, arch.rope_theta)
-    return q, k, v
+    return checkpoint_name(q, "attn_qkv"), checkpoint_name(k, "attn_qkv"), v
 
 
 def _rows_rope(t: int, arch: Arch, run: _Run) -> bool:
-    """Whether latent attention's queries are rotated as whole rows of
-    heads by the in-place kernel (``ops/pallas/rope.py``): where the flash
-    kernels read the layer's own layout (``attention.direct_layout``) and
-    the kernel takes the shape.  Elsewhere the head is cut and
-    concatenated, and the flash kernels fold or copy it anyway."""
+    """Whether the rotated columns of every head (latent attention's
+    ``rope_dim`` tail; of plain attention the whole head) are rotated as
+    whole rows of heads by the in-place kernel (``ops/pallas/rope.py``):
+    where the flash kernels read the layer's own layout
+    (``attention.direct_layout``) and the kernel takes the shape.
+    Elsewhere the head is cut and concatenated, and the flash kernels fold
+    or copy it anyway."""
     from znicz_tpu.ops.pallas import attention as pattn, rope as prope
-    dh = arch.nope_dim + arch.rope_dim
+    dh = arch.head_dim
     return run.use_flash and pattn.direct_layout(t, dh) and \
-        prope.unsupported_reason(t, dh, arch.rope_dim) is None
+        prope.unsupported_reason(t, dh, arch.rope_dim or dh) is None
 
 
 def _latent_q(c_q, wq_b, arch: Arch, run: _Run):
@@ -894,7 +1007,8 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
         stats = {"attn_flash": jnp.ones((), jnp.float32),
                  "attn_direct": jnp.full((), float(direct), jnp.float32)} \
             if flash else {}
-        return x + tp.row_parallel(o, p["wo"], None, "model"), stats
+        y = tp.row_parallel(o, p["wo"], None, "model")
+        return x + _sub_out(y, p, "ln1o", arch), stats
 
 
 def _block_sconv(x, p, arch: Arch):
@@ -940,8 +1054,8 @@ def _block_mlp(x, p, arch: Arch, ffn: str, run: _Run):
                 m2d @ p["gate"])
         return x, aux
     if ffn == "glu":
-        return x + _glu(m, p["w1"], p["w3"], p["w2"]), \
-            jnp.zeros((), jnp.float32)
+        y = _glu(m, p["w1"], p["w3"], p["w2"])
+        return x + _sub_out(y, p, "ln2o", arch), jnp.zeros((), jnp.float32)
     x = x + tp.mlp(m, p["w1"], p["b1"], p["w2"], p["b2"],
                    jax.nn.gelu, "model")
     return x, jnp.zeros((), jnp.float32)
@@ -999,14 +1113,19 @@ def _check_tp(mesh: Mesh, arch: Arch,
     return heads // tp_size, arch.kv_heads // tp_size
 
 
+def _chunk_token_nll(head, xc, lc):
+    """``-log p[label]`` of each token of a chunk, f32, from
+    replicated-head logits."""
+    logits = (xc @ head).astype(jnp.float32)         # (chunk, vocab)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(logp, lc[:, None], axis=-1)[:, 0]
+
+
 def _dense_chunk_nll(head):
     """-> chunk fn: Σ w·(-log p[label]) from replicated-head logits."""
     @jax.checkpoint
     def chunk_nll(xc, lc, wc):
-        logits = (xc @ head).astype(jnp.float32)     # (chunk, vocab)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        picked = jnp.take_along_axis(logp, lc[:, None], axis=-1)[:, 0]
-        return (-picked * wc).sum()
+        return (_chunk_token_nll(head, xc, lc) * wc).sum()
     return chunk_nll
 
 
@@ -1075,6 +1194,24 @@ def _ce_token_nll_sum(x, labels, chunk_nll, n_chunks, weights):
     return totals.sum()
 
 
+def _ce_token_nll(x, labels, head, n_chunks: int | None):
+    """``-log p[label]`` of every token, ``(b, t)`` f32, against a
+    replicated head, ``n_chunks`` chunks of tokens at a time with the chunk
+    rematerialized as in :func:`_ce_token_nll_sum`: what a loss that weighs
+    each token by something that takes a gradient itself reads (the exit
+    distribution of a looped stack), one head pass for the weighted sum
+    and the plain one."""
+    b, t, d = x.shape
+    n_chunks = n_chunks if n_chunks and n_chunks > 1 else 1
+    if (b * t) % n_chunks:
+        raise ValueError(f"loss_chunks {n_chunks} must divide the step's "
+                         f"{b * t} local tokens here")
+    nll = lax.map(
+        lambda inp: jax.checkpoint(_chunk_token_nll)(head, *inp),
+        (x.reshape(n_chunks, -1, d), labels.reshape(n_chunks, -1)))
+    return nll.reshape(b, t)
+
+
 #: named selective-remat policies for ``jax.checkpoint`` around each
 #: block: "dots" saves matmul outputs and recomputes the cheap
 #: elementwise chain (the usual sweet spot); "dots_no_batch" saves only
@@ -1096,7 +1233,9 @@ def _cast_params(ps, arch: Arch, cdt):
     where the layer's pairs stage uses them, on whichever side of its
     choice of buffer, and take their gradients from there in the master
     dtype (a cast out here would stand alone on both sides of that
-    choice: 12 ms of the step, my chip run, PR 29)."""
+    choice: 12 ms of the step, my chip run, PR 29).  The exit gate of a
+    looped stack stays in the master dtype too.  A looped stack reads this
+    one cast in every loop step."""
     out = jax.tree.map(lambda w: w.astype(cdt), ps)
     layers = list(zip(ps["blocks"], out["blocks"]))
     if arch.mtp:
@@ -1106,6 +1245,8 @@ def _cast_params(ps, arch: Arch, cdt):
             for k in ("gate", "ebias", "ew1", "ew3", "ew2"):
                 if k in master:
                     cast[k] = master[k]
+    if arch.exit_gate:                     # its product is taken in f32
+        out.update({k: ps[k] for k in ("exit_w", "exit_b")})
     return out
 
 
@@ -1114,20 +1255,102 @@ def _head_of(ps, arch: Arch):
     return ps["emb"].T if arch.tied else ps["head"]
 
 
-#: the loss's terms a stack with an MTP module reports beside its counters
-_LOSS_TERMS = ("loss_main", "loss_mtp")
+#: prefixes of the stats that come in the loss's own convention (reduced
+#: and scaled as the loss is): the loss's named terms (``loss_main``,
+#: ``loss_mtp`` of a stack with an MTP module) and a looped stack's means
+#: over tokens (``loop_exit_step_mean``, ``loop_exit_entropy``,
+#: ``loop_loss_step<r>``)
+_TERM_PREFIXES = ("loss_", "loop_")
 
 
 def _sum_stats(a: dict, b: dict) -> dict:
     return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in {**a, **b}}
 
 
-def _block_fn(remat: bool, remat_policy: str | None):
-    """:func:`_block`, or its checkpointed form."""
-    if not (remat or remat_policy):
+_SAVED_NAMES = jax.checkpoint_policies.save_only_these_names(
+    "attn_qkv", "sub_out")
+
+
+def _loop_saves(prim, *_, **params) -> bool:
+    """What a layer application of a looped stack keeps for the backward
+    pass: the rotated queries, keys and values, each sub-layer's output
+    (``attn_qkv``, ``sub_out``: the attention's output product and the
+    SwiGLU's) and whatever a kernel wrote (the flash forward's output and
+    log-sum-exp rows, so no kernel runs twice); with the layer's input
+    that is seven arrays of ``(tokens, d)``.  Recomputed: the four norms,
+    the rotary embedding's f32 chain, the residual sums and the SwiGLU's
+    two wide products with their gated product (three arrays of
+    ``(tokens, ff)``, 12 % of a layer's operations)."""
+    return prim.name == "pallas_call" or _SAVED_NAMES(prim, *_, **params)
+
+
+def _block_fn(remat: bool, remat_policy: str | None, arch: Arch):
+    """:func:`_block`, or its checkpointed form.  A looped stack holds
+    ``loop_steps`` times the activations its weights suggest, so it always
+    recomputes, the cheapest things first (:func:`_loop_saves`), and that
+    is its one recomputation path: the two keywords are refused there."""
+    if arch.loop_steps > 1:
+        if remat or remat_policy:
+            raise ValueError("remat / remat_policy: a looped stack always "
+                             "recomputes by _loop_saves")
+        pol = _loop_saves
+    elif remat or remat_policy:
+        pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
+    else:
         return _block
-    pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
     return jax.checkpoint(_block, policy=pol, static_argnums=(2, 3, 4))
+
+
+def _stack(x, blocks, arch: Arch, run: _Run, blk):
+    """One pass through the layers -> ``(x, aux_term, stats)``: the MoE
+    regularizer term and the layers' counters, each summed over them."""
+    # regularizer weights apply inside _block (per-block pre-weighted)
+    aux_term = jnp.zeros((), jnp.float32)
+    stats: dict = {}
+    for i, p in enumerate(blocks):
+        x, aux, st = blk(x, p, arch, run, i)
+        aux_term = aux_term + aux
+        stats = _sum_stats(stats, st)
+    return x, aux_term, stats
+
+
+def _looped(ps, x, arch: Arch, run: _Run, blk, each=None, carry=()):
+    """The stack run ``arch.loop_steps`` times over the same weights:
+    ``h_r = RMSNorm_f(Stack(h_{r-1}))``, the one final norm closing every
+    loop step and its result fed into the next.  ONE body of ``n_layers``
+    layers under ``lax.scan`` and not ``loop_steps`` copies of them: the
+    program stays the size of the unlooped model's (and so its compile
+    time), every loop step reads the one cast of the weights, and each
+    weight's gradient is summed over its uses in the scan's carry.  The
+    scopes are the same in every loop step, so a scope's time is the sum
+    over them.  ``each(carry, h_r, r) -> (carry, out)`` reads a loop
+    step's output as it is made (``r`` from 0, traced).
+    -> ``(h_last, aux_term, stats, carry, outs stacked over the steps)``."""
+    @jax.checkpoint            # its f32 chain is recomputed, not stacked
+    def close(x, g):
+        with _probe.scope("ce"):
+            return _rms_norm(x, g, arch.eps)
+
+    def body(state, r):
+        x, carry = state
+        x, aux, st = _stack(x, ps["blocks"], arch, run, blk)
+        x = close(x, ps["norm_g"])
+        out = None
+        if each is not None:
+            carry, out = each(carry, x, r)
+        return (x, carry), (aux, st, out)
+
+    (x, carry), (aux, stats, outs) = lax.scan(
+        body, (x, carry), jnp.arange(arch.loop_steps))
+    return x, aux.sum(), {k: v.sum(0) for k, v in stats.items()}, carry, outs
+
+
+def _embedded(ps, tokens, arch: Arch, cdt):
+    """-> ``(the params cast once for the step, the tokens' embeddings
+    (b_l, t_l, d))``."""
+    ps = _cast_params(ps, arch, cdt)
+    with _probe.scope("embed"):
+        return ps, ps["emb"][tokens]
 
 
 def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
@@ -1137,21 +1360,17 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
     by the CE loss (:func:`_forward_ce`) and the full-pass logits oracle
     (:func:`make_logits_fn`, the generative serving plane's correctness
     anchor).  Returns ``(x, aux_term, ps_cast, stats)`` — the hidden
-    states (through the final norm where the stack has one), the summed
-    MoE regularizer term, the compute-dtype-cast params (so the caller's
-    head matmul uses the same precision policy) and the routed layers'
-    counters summed over the layers."""
-    ps = _cast_params(ps, arch, cdt)
-    with _probe.scope("embed"):
-        x = ps["emb"][tokens]                     # (b_l, t_l, d)
-    blk = _block_fn(remat, remat_policy)
-    # regularizer weights apply inside _block (per-block pre-weighted)
-    aux_term = jnp.zeros((), jnp.float32)
-    stats: dict = {}
-    for i, p in enumerate(ps["blocks"]):
-        x, aux, st = blk(x, p, arch, run, i)
-        aux_term = aux_term + aux
-        stats = _sum_stats(stats, st)
+    states (through the final norm where the stack has one; of a looped
+    stack the last loop step's), the summed MoE regularizer term, the
+    compute-dtype-cast params (so the caller's head matmul uses the same
+    precision policy) and the routed layers' counters summed over the
+    layers."""
+    ps, x = _embedded(ps, tokens, arch, cdt)
+    blk = _block_fn(remat, remat_policy, arch)
+    if arch.loop_steps > 1:
+        x, aux_term, stats, _, _ = _looped(ps, x, arch, run, blk)
+        return x, aux_term, ps, stats
+    x, aux_term, stats = _stack(x, ps["blocks"], arch, run, blk)
     if arch.final_norm:
         with _probe.scope("ce"):
             x = _rms_norm(x, ps["norm_g"], arch.eps)
@@ -1210,7 +1429,12 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
     With an MTP module (``arch.mtp``) the loss is ``CE(main; next token)
     + mtp_weight * CE(module; second-next token)``, the second over the
     positions that have a second-next token, and ``stats`` carries both
-    terms (``loss_main``, ``loss_mtp``, in the loss's own convention)."""
+    terms (``loss_main``, ``loss_mtp``, in the loss's own convention).  A
+    looped stack's loss is :func:`_forward_loop_ce`'s."""
+    if arch.loop_steps > 1:
+        return _forward_loop_ce(ps, tokens, labels, mask, arch, run, cdt,
+                                _block_fn(remat, remat_policy, arch),
+                                loss_chunks, reduce)
     x, aux_term, ps, stats = _forward_hidden(
         ps, tokens, arch, run, cdt, remat=remat, remat_policy=remat_policy)
     head = _head_of(ps, arch)
@@ -1221,7 +1445,7 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
         # position i reads token i+1 (its label) and predicts token i+2,
         # the next position's label; the last position has none
         y, aux, st = _mtp_hidden(ps, x, labels, arch, run,
-                                 _block_fn(remat, remat_policy))
+                                 _block_fn(remat, remat_policy, arch))
         with _probe.scope("mtp.ce"):
             second = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
             mtp = _ce_from_hidden(y, head, second, mask, aux, loss_chunks,
@@ -1229,6 +1453,69 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
         stats = {**_sum_stats(stats, st), "loss_main": loss, "loss_mtp": mtp}
         loss = loss + arch.mtp_weight * mtp
     return loss, _mean_stats(stats, arch)
+
+
+def _forward_loop_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
+                     blk, loss_chunks: int | None, reduce: bool):
+    """Forward and loss of a looped stack with an exit gate (Ouro's
+    LoopLM, arXiv:2510.25741), token by token over the ``R = loop_steps``
+    outputs ``h_r`` of :func:`_looped`: ``g_r = h_r w_g + b_g``, ``lam_r =
+    sigmoid(g_r)``, ``S_0 = 1``; for ``r < R``: ``p_r = lam_r S_{r-1}``,
+    ``S_r = S_{r-1} (1 - lam_r)``; ``p_R = S_{R-1}`` (the last gate is
+    read by nothing, the distribution sums to one).  ``L = mean over
+    tokens of [sum_r p_r nll_r - beta H(p)]`` with ``nll_r`` the
+    next-token cross-entropy of ``h_r`` against the one head and ``H(p) =
+    -sum_r p_r log p_r``; gradients flow through ``p`` into the gate and
+    the stack.  The gate's product, the sigmoids, the products ``S_r``,
+    the entropy and the weighting are f32 (scope ``loop.exit``); each loop
+    step's head pass (scope ``ce``) gives the per-token ``nll_r`` once,
+    for the weighted sum and the counter alike.
+    -> ``(loss, stats)``: ``loop_exit_step_mean`` (the mean of ``sum_r r
+    p_r``, 1..R), ``loop_exit_entropy`` (of ``H(p)``, nats) and
+    ``loop_loss_step<r>`` (each loop step's own mean cross-entropy), in
+    the loss's convention, beside the layers' counters."""
+    ps, x = _embedded(ps, tokens, arch, cdt)
+    head = _head_of(ps, arch)
+    steps, beta = arch.loop_steps, jnp.float32(arch.exit_beta)
+    b_l, t_l = labels.shape
+    counted = jnp.ones((b_l, 1), jnp.float32) if mask is None else \
+        mask[:, None].astype(jnp.float32)
+
+    @jax.checkpoint            # the f32 copy of h is recomputed, not stacked
+    def gate(h, w, b):
+        return jnp.einsum("btd,do->bt", h.astype(jnp.float32), w,
+                          precision=lax.Precision.HIGHEST) + b[0]
+
+    def exit_step(carry, h, r):
+        alive, total = carry              # S_{r-1} (b, t); the loss's sum
+        with _probe.scope("loop.exit"):
+            lam = jnp.where(r == steps - 1, 1.0, jax.nn.sigmoid(
+                gate(h, ps["exit_w"], ps["exit_b"])))
+            p = lam * alive
+        with _probe.scope("ce"):
+            nll = _ce_token_nll(h, labels, head, loss_chunks)
+        with _probe.scope("loop.exit"):
+            # 0 at p = 0, and a finite gradient there
+            plogp = p * jnp.log(jnp.maximum(p, 1e-30))
+            total = total + ((p * nll + beta * plogp) * counted).sum()
+            sums = lax.stop_gradient(jnp.stack(
+                [(a * counted).sum() for a in (nll, p, plogp)]))
+        return (alive * (1.0 - lam), total), sums
+
+    _, aux_term, stats, (_, total), sums = _looped(
+        ps, x, arch, run, blk, exit_step,
+        (jnp.ones((b_l, t_l), jnp.float32), jnp.zeros((), jnp.float32)))
+    with _probe.scope("loop.exit"):
+        def mean(s):
+            return _normalised(s, mask, b_l, t_l, 0.0, reduce)
+
+        loss = _normalised(total, mask, b_l, t_l, aux_term, reduce)
+        stats["loop_exit_step_mean"] = mean(
+            (sums[:, 1] * jnp.arange(1, steps + 1, dtype=jnp.float32)).sum())
+        stats["loop_exit_entropy"] = mean(-sums[:, 2].sum())
+        for r in range(steps):
+            stats[f"loop_loss_step{r + 1}"] = mean(sums[r, 0])
+    return loss, stats
 
 
 def _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
@@ -1259,6 +1546,14 @@ def _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
                                      axis=-1)[..., 0]
         nll = -picked.sum() if mvec is None else \
             -(picked * jnp.broadcast_to(mvec, picked.shape)).sum()
+    return _normalised(nll, mask, b_l, t_l, aux_term, reduce)
+
+
+def _normalised(nll, mask, b_l: int, t_l: int, aux_term, reduce: bool):
+    """The LOCAL sum ``nll`` over this shard's counted tokens (``t_l`` a
+    row) -> the mean over all shards' counted tokens in the loss's
+    convention (scaled by the shard count; ``reduce``: summed over the
+    data x seq shards), plus ``aux_term``."""
     if mask is None:
         local = nll / (b_l * t_l) + aux_term
         if not reduce:
@@ -1318,9 +1613,11 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
     ``stats=True``: ``-> (params, loss, stats)`` with the routed expert
     layers' counters of the step, the count of attention layers that ran
     a flash kernel and of those whose kernels read the layer's layout
-    (``attn_flash``, ``attn_direct``) and, of a stack with an MTP module,
-    the loss's two terms (``loss_main``, ``loss_mtp``, unweighted),
-    float32 scalars, an empty dict for a stack that has none).
+    (``attn_flash``, ``attn_direct``), of a stack with an MTP module the
+    loss's two terms (``loss_main``, ``loss_mtp``, unweighted) and of a
+    looped stack its exit distribution's means and each loop step's own
+    cross-entropy (``loop_*``, :func:`_forward_loop_ce`), float32 scalars,
+    an empty dict for a stack that has none).
 
     ``arch`` says what the stack is (:func:`as_arch`): an :class:`Arch`,
     a model's configuration mapping, or, as ever, the GPT-shaped block's
@@ -1334,7 +1631,8 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
     trade (HBM for FLOPs) once t grows past what activations fit;
     ``remat_policy`` ("dots" | "dots_no_batch" | "nothing") selects a
     SELECTIVE checkpoint policy instead of the all-or-nothing default
-    (implies remat when set).
+    (implies remat when set).  A looped stack refuses both: it always
+    recomputes by :func:`_loop_saves`.
     ``loss_chunks=k`` computes the CE loss k token-chunks at a time
     (:func:`_ce_token_nll_sum`) so the ``(tokens, vocab)`` f32 logits
     never materialize — the dominant HBM stream when vocab ≫ d.  Loss
@@ -1523,7 +1821,8 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
         # the counters are of this shard's tokens: pairs add up over the
         # shards, a load ratio and a share are averaged; the loss's terms
         # are in the loss's convention, reduced as it is
-        terms = {k: counters.pop(k) for k in _LOSS_TERMS if k in counters}
+        terms = {k: counters.pop(k) for k in list(counters)
+                 if k.startswith(_TERM_PREFIXES)}
         if codec is not None:
             terms = {k: lax.psum(v, ("data", "seq"))
                      for k, v in terms.items()}

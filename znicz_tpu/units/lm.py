@@ -27,6 +27,38 @@ from znicz_tpu.observe.anatomy import StepCadence
 from znicz_tpu.observe.trace import TRACER
 
 
+def _term_gauges() -> dict:
+    """The registry's gauge of each named term a step reports in the
+    loss's own convention (``parallel/transformer.py``: the stats whose
+    names start ``loss_`` or ``loop_``), by the term's name; a looped
+    stack's ``loop_loss_step<r>`` share one family labelled by ``step``."""
+    from znicz_tpu.observe import registry
+
+    tail = ", mean over the last training pass's steps"
+    return {
+        "loss_main": registry.gauge(
+            "znicz_lm_loss_main",
+            "next-token cross-entropy of the main stack" + tail, ("unit",)),
+        "loss_mtp": registry.gauge(
+            "znicz_lm_loss_mtp",
+            "second-next-token cross-entropy of the multi-token-prediction "
+            "module, unweighted" + tail, ("unit",)),
+        "loop_exit_step_mean": registry.gauge(
+            "znicz_lm_loop_exit_step_mean",
+            "mean over tokens of sum_r r * p_r, the loop step a token is "
+            "expected to leave a looped stack at (1 .. loop steps)" + tail,
+            ("unit",)),
+        "loop_exit_entropy": registry.gauge(
+            "znicz_lm_loop_exit_entropy",
+            "mean over tokens of the exit distribution's entropy in nats "
+            "(at most ln of the loop steps)" + tail, ("unit",)),
+        "loop_loss_step": registry.gauge(
+            "znicz_lm_loop_loss_step",
+            "next-token cross-entropy of one loop step's output alone"
+            + tail, ("unit", "step")),
+    }
+
+
 def _fold_pass(acc, loss, mask, stats):
     """One minibatch into the class pass's device-side sums: the loss
     weighted by the rows that count (the Decision's own weighting), the
@@ -47,8 +79,9 @@ class TransformerLMStep(AcceleratedUnit):
     ``arch`` is the architecture as one mapping of the model's own keys
     (``model_type`` and that family's: ``layer_types``,
     ``num_dense_layers``, ``num_experts``, ... or ``q_lora_rank``,
-    ``n_shared_experts``, ``num_nextn_predict_layers``, ... and
-    ``experts_held``, this chip's share: ``{"first", "count"}``; see
+    ``n_shared_experts``, ``num_nextn_predict_layers``, ... or
+    ``total_ut_steps``, ... and ``experts_held``, this chip's share:
+    ``{"first", "count"}``; see
     ``parallel.transformer.arch_from_config``); the vocabulary is the
     loader's.  Without it the unit builds the GPT-shaped block from
     ``n_layers``, ``d``, ``heads``, ``ff`` (and ``n_experts``).
@@ -127,6 +160,11 @@ class TransformerLMStep(AcceleratedUnit):
         #: with an MTP module (``last_loss`` and the Decision's metric are
         #: their weighted total): ``{"main", "mtp"}``, unweighted
         self.loss_terms: dict = {}
+        #: the last finished training pass's means of a looped stack:
+        #: ``exit_step_mean`` (of ``sum_r r * p_r``), ``exit_entropy`` (of
+        #: ``H(p)``, nats) and ``loss_step<r>``, each loop step's own
+        #: cross-entropy
+        self.loop_counters: dict = {}
         #: of the last finished pass's attention layers that ran a flash
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
@@ -292,9 +330,7 @@ class TransformerLMStep(AcceleratedUnit):
                               float(sums["compact"]) / steps,
                               float(sums["tile_fill"]) / steps,
                               float(sums["pairs_held"]))
-        if "loss_main" in sums:
-            self._publish_loss_terms(float(sums["loss_main"]) / steps,
-                                     float(sums["loss_mtp"]) / steps)
+        self._publish_terms(sums, steps)
         if "attn_flash" in sums:
             self._publish_attn_layout(float(sums["attn_direct"]) /
                                       float(sums["attn_flash"]))
@@ -313,22 +349,27 @@ class TransformerLMStep(AcceleratedUnit):
             "a flash kernel (the rest fold their operands head-major)",
             ("unit",)).labels(unit=self.name).set(share)
 
-    def _publish_loss_terms(self, main: float, mtp: float) -> None:
-        """A finished training pass's two loss terms, each the mean over
-        its steps: the unit's mirror and the process registry."""
-        from znicz_tpu.observe import registry
-
-        self.loss_terms = {"main": main, "mtp": mtp}
-        registry.gauge(
-            "znicz_lm_loss_main",
-            "next-token cross-entropy of the main stack, mean over the "
-            "last training pass's steps", ("unit",)).labels(
-                unit=self.name).set(main)
-        registry.gauge(
-            "znicz_lm_loss_mtp",
-            "second-next-token cross-entropy of the multi-token-prediction "
-            "module, unweighted, mean over the last training pass's steps",
-            ("unit",)).labels(unit=self.name).set(mtp)
+    def _publish_terms(self, sums: dict, steps: float) -> None:
+        """A finished training pass's named terms, each the mean over its
+        steps: the loss's terms of a stack with an MTP module into
+        ``loss_terms``, a looped stack's means into ``loop_counters``, all
+        into the process registry (:func:`_term_gauges`)."""
+        gauges = _term_gauges()
+        loss, loop = {}, {}
+        for name, total in sums.items():
+            value, labels = float(total) / steps, {"unit": self.name}
+            if name in ("loss_main", "loss_mtp"):
+                loss[name.removeprefix("loss_")] = value
+            elif name.startswith("loop_"):
+                loop[name.removeprefix("loop_")] = value
+                if name.startswith("loop_loss_step"):
+                    labels["step"] = name.removeprefix("loop_loss_step")
+                    name = "loop_loss_step"
+            else:
+                continue
+            gauges[name].labels(**labels).set(value)
+        if loss or loop:
+            self.loss_terms, self.loop_counters = loss, loop
 
     def _publish_moe(self, pairs_a_step: float, load_ratio: float,
                      compact_share: float, tile_fill: float,
