@@ -512,11 +512,20 @@ def test_unknown_model_type_is_refused_by_name():
 
 # -- the step unit ------------------------------
 
-#: epoch losses of this seeded run with the loss read after every step
-#: (the parent commit of ISSUE 28): validation, then training
-OLD_EPOCHS = [(3.852034360367731, 1.0002862910210664),
-              (0.8723333572709797, 0.8278742403543786),
-              (0.7837592527829522, 0.7916457109260933)]
+#: epoch losses of this seeded run: validation, then training.  Pinned
+#: with the loss read after every step (the parent commit of ISSUE 28) and
+#: again at PR 35, whose chunked cross-entropy makes f32 gradients that
+#: differ from the checkpointed form's in their last bits (``dlogits`` is
+#: rounded before the loss's cotangent scales it, which is another order
+#: where the cotangent is no power of two: a training pass's last
+#: minibatch here, 3 rows of 16; and XLA sums the bias gradients behind it
+#: in another order): 765 steps carry that to 2.1e-6 in the second epoch
+#: and 2.3e-5 in the third.  Until then: (3.852034360367731,
+#: 1.0002862910210664), (0.8723333572709797, 0.8278742403543786),
+#: (0.7837592527829522, 0.7916457109260933)
+OLD_EPOCHS = [(3.8520348824675774, 1.0002862358980835),
+              (0.8723315645264211, 0.8278743417830027),
+              (0.7837594476493516, 0.791664035712099)]
 
 
 def test_deferred_loss_read_gives_the_old_epoch_losses(tmp_path):

@@ -1121,12 +1121,103 @@ def _chunk_token_nll(head, xc, lc):
     return -jnp.take_along_axis(logp, lc[:, None], axis=-1)[:, 0]
 
 
-def _dense_chunk_nll(head):
-    """-> chunk fn: Σ w·(-log p[label]) from replicated-head logits."""
-    @jax.checkpoint
-    def chunk_nll(xc, lc, wc):
-        return (_chunk_token_nll(head, xc, lc) * wc).sum()
-    return chunk_nll
+def _ce_chunked(x, labels, w, n_chunks: int):
+    """A head pass's operands cut into ``n_chunks`` chunks of tokens; the
+    rows that fill the last chunk weigh 0, so they contribute nothing to
+    the sum or to a gradient."""
+    n_tok, d = x.shape
+    chunk = -(-n_tok // n_chunks)
+    pad = chunk * n_chunks - n_tok
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        labels, w = jnp.pad(labels, (0, pad)), jnp.pad(w, (0, pad))
+    return (x.reshape(n_chunks, chunk, d), labels.reshape(n_chunks, chunk),
+            w.reshape(n_chunks, chunk))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _ce_weighted(x, head, labels, w, n_chunks: int):
+    """The chunked cross-entropy against a replicated head: ``x`` ``(n_tok,
+    d)``, ``head`` ``(d, vocab)``, ``labels`` ``(n_tok,)``, ``w`` ``(n_tok,)``
+    f32 -> ``(sum_i w_i nll_i, nll (n_tok,))``, both f32, ``n_chunks``
+    chunks of tokens at a time so that only one chunk's ``(chunk, vocab)``
+    logits are ever live (whole they are ~2 GB at the bench shape, and the
+    dominant HBM stream of a small-d model).  Per-token numerics are the
+    dense path's (row-wise log_softmax); only the cross-token summation
+    order differs.  ``n_chunks`` need not divide ``n_tok``: the last chunk
+    is filled with rows that weigh 0.
+
+    The sum takes gradients in ``x``, ``head`` AND ``w`` (a looped stack's
+    exit distribution).  ``nll`` is a reading for counters and takes NONE:
+    the backward rule drops its cotangent, so a caller that differentiates
+    through it gets zeros without an error.  Differentiated, the pass
+    makes its gradients where it makes its logits
+    (:func:`_ce_weighted_fwd`): three products with the vocabulary axis a
+    pass, where a checkpointed chunk would run the logits' a second time;
+    the transpose only scales them (:func:`_ce_weighted_bwd`)."""
+    def chunk(inp):
+        xc, lc, wc = inp
+        nll = _chunk_token_nll(head, xc, lc)
+        return (nll * wc).sum(), nll
+
+    totals, nll = lax.map(chunk, _ce_chunked(x, labels, w, n_chunks))
+    return totals.sum(), nll.reshape(-1)[:x.shape[0]]
+
+
+def _ce_weighted_fwd(x, head, labels, w, n_chunks: int):
+    """:func:`_ce_weighted` under differentiation -> its outputs and the
+    residuals ``(dx, dhead, nll)``: per chunk the logits (product 1), the
+    f32 softmax chain as ``log_softmax`` runs it, ``dlogits = w (softmax -
+    onehot)`` cast to the compute dtype (where the transpose of the
+    logits' ``astype`` would cast it), ``dx = dlogits head^T`` (product 2)
+    and ``dhead += x^T dlogits`` (product 3; the running sum in the head's
+    dtype, as a transposed map carries it).  Both gradients are of the
+    sum itself: the backward pass scales them by its cotangent."""
+    def chunk(dhead, inp):
+        xc, lc, wc = inp
+        # log_softmax's chain written out for its parts, and the label's
+        # logit picked BEFORE log(s) is taken off: picked after, as
+        # _chunk_token_nll does (and stays bit for bit the eval pass's old
+        # loss), the step compiled for a v5e writes the whole (chunk,
+        # vocab) f32 logp for the gather to read (PR 35)
+        logits = (xc @ head).astype(jnp.float32)     # (chunk, vocab)
+        shifted = logits - logits.max(-1, keepdims=True)
+        e = jnp.exp(shifted)
+        s = e.sum(-1, keepdims=True)
+        picked = jnp.take_along_axis(shifted, lc[:, None], axis=-1)
+        nll = (jnp.log(s) - picked)[:, 0]
+        hot = lax.broadcasted_iota(jnp.int32, e.shape, 1) == lc[:, None]
+        dl = (e * (wc[:, None] / s) - jnp.where(hot, wc[:, None], 0.0)
+              ).astype(head.dtype)
+        dxc = lax.dot_general(dl, head, (((1,), (1,)), ((), ())))
+        dhead = dhead + lax.dot_general(xc, dl, (((0,), (0,)), ((), ())))
+        return dhead, ((nll * wc).sum(), nll, dxc)
+
+    # the chunks last to first, the order in which a transposed map sums
+    # the head's gradient
+    dhead, (totals, nll, dx) = lax.scan(
+        chunk, jnp.zeros_like(head), _ce_chunked(x, labels, w, n_chunks),
+        reverse=True)
+    n_tok, d = x.shape
+    nll = nll.reshape(-1)[:n_tok]
+    return (totals.sum(), nll), (dx.reshape(-1, d)[:n_tok], dhead, nll)
+
+
+def _ce_weighted_bwd(n_chunks: int, res, cts):
+    """No product and no softmax: the sum is a scalar, so is its cotangent
+    (it carries ``1 / n_tokens``, a loss term's weight), and it scales
+    the forward rule's gradients in f32, cast once; ``nll`` is the
+    gradient with respect to the weights."""
+    dx, dhead, nll = res
+    ct = cts[0]
+
+    def scaled(g):
+        return (ct * g.astype(jnp.float32)).astype(g.dtype)
+
+    return scaled(dx), scaled(dhead), None, ct * nll
+
+
+_ce_weighted.defvjp(_ce_weighted_fwd, _ce_weighted_bwd)
 
 
 def _vshard_chunk_nll(head_local, axis_name: str = "model"):
@@ -1158,58 +1249,41 @@ def _vshard_chunk_nll(head_local, axis_name: str = "model"):
     return chunk_nll
 
 
-def _ce_token_nll_sum(x, labels, chunk_nll, n_chunks, weights):
-    """Σ weights·(-log p[label]) over the local tokens, computed
-    ``n_chunks`` tokens-chunks at a time with the chunk rematerialized:
-    the full ``(tokens, vocab)`` f32 logits tensor — ~2 GB at the bench
-    shape, and the dominant HBM stream of a small-d model — never
-    exists; only one chunk of logits is live (forward AND backward,
-    ``jax.checkpoint`` recomputes it in the transpose).  Per-token
-    numerics are identical to the dense path (row-wise log_softmax);
-    only the cross-token summation order differs."""
-    b, t, d = x.shape
-    n_tok = b * t
-    xf = x.reshape(n_tok, d)
-    lf = labels.reshape(n_tok)
-    wf = jnp.broadcast_to(weights, (b, t)).reshape(n_tok) \
-        if weights is not None else None
-    chunk = -(-n_tok // n_chunks)
-    pad = chunk * n_chunks - n_tok
-    if pad:
-        xf = jnp.pad(xf, ((0, pad), (0, 0)))
-        lf = jnp.pad(lf, (0, pad))
-        # padded rows weigh 0 so they contribute nothing either way
-        wf = jnp.pad(jnp.ones((n_tok,), jnp.float32) if wf is None
-                     else wf, (0, pad))
-    elif wf is None:
-        wf = jnp.ones((n_tok,), jnp.float32)
+def _n_chunks(loss_chunks: int | None) -> int:
+    """``loss_chunks`` as a count of chunks: 1 when unset."""
+    return loss_chunks if loss_chunks and loss_chunks > 1 else 1
 
-    # lax.map (carry-free scan): a scan carry would need its varying-axes
-    # type pinned to whatever mesh axes the enclosing shard_map uses,
-    # which this helper cannot know
+
+def ce_grad_in_forward(arch: Arch, loss_chunks: int | None,
+                       head_sharded: bool) -> bool:
+    """Whether a train step's head passes make their gradients where they
+    make their logits (:func:`_ce_weighted`): a looped stack's always,
+    another's when chunked against a replicated head (an unchunked pass
+    and a vocab-sharded head leave them to AD)."""
+    return arch.loop_steps > 1 or (
+        not head_sharded and _n_chunks(loss_chunks) > 1)
+
+
+def _token_weights(weights, b: int, t: int):
+    """``weights`` (anything that broadcasts to ``(b, t)``, or None for
+    ones) as ``(b * t,)`` f32."""
+    if weights is None:
+        return jnp.ones((b * t,), jnp.float32)
+    return jnp.broadcast_to(weights, (b, t)).reshape(b * t)
+
+
+def _ce_token_nll_sum(x, labels, chunk_nll, n_chunks, weights):
+    """Σ weights·(-log p[label]) over the local tokens against a
+    VOCAB-SHARDED head (:func:`_vshard_chunk_nll`; a replicated head takes
+    :func:`_ce_weighted`), ``n_chunks`` tokens-chunks at a time with the
+    chunk rematerialized: only one chunk of logits is live (forward AND
+    backward, ``jax.checkpoint`` recomputes it in the transpose)."""
+    b, t, d = x.shape
     totals = lax.map(
         lambda inp: chunk_nll(*inp),
-        (xf.reshape(n_chunks, chunk, d), lf.reshape(n_chunks, chunk),
-         wf.reshape(n_chunks, chunk)))
+        _ce_chunked(x.reshape(b * t, d), labels.reshape(b * t),
+                    _token_weights(weights, b, t), n_chunks))
     return totals.sum()
-
-
-def _ce_token_nll(x, labels, head, n_chunks: int | None):
-    """``-log p[label]`` of every token, ``(b, t)`` f32, against a
-    replicated head, ``n_chunks`` chunks of tokens at a time with the chunk
-    rematerialized as in :func:`_ce_token_nll_sum`: what a loss that weighs
-    each token by something that takes a gradient itself reads (the exit
-    distribution of a looped stack), one head pass for the weighted sum
-    and the plain one."""
-    b, t, d = x.shape
-    n_chunks = n_chunks if n_chunks and n_chunks > 1 else 1
-    if (b * t) % n_chunks:
-        raise ValueError(f"loss_chunks {n_chunks} must divide the step's "
-                         f"{b * t} local tokens here")
-    nll = lax.map(
-        lambda inp: jax.checkpoint(_chunk_token_nll)(head, *inp),
-        (x.reshape(n_chunks, -1, d), labels.reshape(n_chunks, -1)))
-    return nll.reshape(b, t)
 
 
 #: named selective-remat policies for ``jax.checkpoint`` around each
@@ -1467,9 +1541,16 @@ def _forward_loop_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
     next-token cross-entropy of ``h_r`` against the one head and ``H(p) =
     -sum_r p_r log p_r``; gradients flow through ``p`` into the gate and
     the stack.  The gate's product, the sigmoids, the products ``S_r``,
-    the entropy and the weighting are f32 (scope ``loop.exit``); each loop
-    step's head pass (scope ``ce``) gives the per-token ``nll_r`` once,
-    for the weighted sum and the counter alike.
+    the entropy and the weighting are f32 (scope ``loop.exit``).  The
+    ``R`` head passes (scope ``ce``) run after the loop as ONE call of
+    :func:`_ce_weighted` over the stacked ``h_r`` with the weights ``p_r``
+    (masked rows 0): differentiated, it makes ``dL/dh_r``, the head's
+    gradient (one running sum over all ``R x loss_chunks`` chunks) and,
+    through the weights, the gate's where it makes the logits, three
+    products a chunk, and its per-token ``nll_r`` serve the counters
+    (readings: no gradient passes through them).  ``loss_chunks`` need not
+    divide a loop step's tokens any more: the call fills its last chunk
+    with rows of weight 0.
     -> ``(loss, stats)``: ``loop_exit_step_mean`` (the mean of ``sum_r r
     p_r``, 1..R), ``loop_exit_entropy`` (of ``H(p)``, nats) and
     ``loop_loss_step<r>`` (each loop step's own mean cross-entropy), in
@@ -1486,26 +1567,33 @@ def _forward_loop_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
         return jnp.einsum("btd,do->bt", h.astype(jnp.float32), w,
                           precision=lax.Precision.HIGHEST) + b[0]
 
-    def exit_step(carry, h, r):
-        alive, total = carry              # S_{r-1} (b, t); the loss's sum
+    def exit_step(alive, h, r):           # alive: S_{r-1} (b, t)
         with _probe.scope("loop.exit"):
             lam = jnp.where(r == steps - 1, 1.0, jax.nn.sigmoid(
                 gate(h, ps["exit_w"], ps["exit_b"])))
-            p = lam * alive
-        with _probe.scope("ce"):
-            nll = _ce_token_nll(h, labels, head, loss_chunks)
-        with _probe.scope("loop.exit"):
-            # 0 at p = 0, and a finite gradient there
-            plogp = p * jnp.log(jnp.maximum(p, 1e-30))
-            total = total + ((p * nll + beta * plogp) * counted).sum()
-            sums = lax.stop_gradient(jnp.stack(
-                [(a * counted).sum() for a in (nll, p, plogp)]))
-        return (alive * (1.0 - lam), total), sums
+            # stacked as the rows the head passes read: stacked (b, t, d),
+            # XLA lays the stack, and with it the whole loop's residual
+            # stream, out time-minor for the gate's reduction
+            return alive * (1.0 - lam), (h.reshape(-1, h.shape[-1]),
+                                         lam * alive)
 
-    _, aux_term, stats, (_, total), sums = _looped(
-        ps, x, arch, run, blk, exit_step,
-        (jnp.ones((b_l, t_l), jnp.float32), jnp.zeros((), jnp.float32)))
+    _, aux_term, stats, _, (hs, p) = _looped(
+        ps, x, arch, run, blk, exit_step, jnp.ones((b_l, t_l), jnp.float32))
+    n_tok = steps * b_l * t_l
+    with _probe.scope("ce"):
+        # inside the loop the scan would stack each pass's residuals (the
+        # head's gradient among them, 201 MB a loop step at 49,152 ids)
+        term, nll = _ce_weighted(
+            hs.reshape(n_tok, -1), head, jnp.tile(labels.reshape(-1), steps),
+            (p * counted).reshape(n_tok), steps * _n_chunks(loss_chunks))
+        nll = nll.reshape(p.shape)
     with _probe.scope("loop.exit"):
+        # 0 at p = 0, and a finite gradient there
+        plogp = p * jnp.log(jnp.maximum(p, 1e-30))
+        total = term + beta * (plogp * counted).sum()
+        sums = lax.stop_gradient(jnp.stack(
+            [(a * counted).sum((1, 2)) for a in (nll, p, plogp)], axis=1))
+
         def mean(s):
             return _normalised(s, mask, b_l, t_l, 0.0, reduce)
 
@@ -1530,15 +1618,18 @@ def _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
         counted = (jnp.arange(t_l) < t_l - 1).astype(jnp.float32)[None, :]
         mvec = counted if mvec is None else mvec * counted
         t_l -= 1                      # positions a row counts from here on
-    # either path yields the LOCAL weighted nll sum; normalization below
+    # every path yields the LOCAL weighted nll sum; normalization below
     # is shared so dense and chunked conventions can never drift.  A
-    # vocab-sharded head always routes through the chunk helper (its CE
+    # vocab-sharded head always routes through its chunk helper (its CE
     # needs the collective-reduced softmax; n_chunks=1 when unchunked).
-    if head_sharded or (loss_chunks and loss_chunks > 1):
-        fn = _vshard_chunk_nll(head) if head_sharded else \
-            _dense_chunk_nll(head)
-        n_chunks = loss_chunks if (loss_chunks and loss_chunks > 1) else 1
-        nll = _ce_token_nll_sum(x, labels, fn, n_chunks, mvec)
+    n_chunks = _n_chunks(loss_chunks)
+    if head_sharded:
+        nll = _ce_token_nll_sum(x, labels, _vshard_chunk_nll(head),
+                                n_chunks, mvec)
+    elif n_chunks > 1:
+        nll, _ = _ce_weighted(
+            x.reshape(-1, x.shape[-1]), head, labels.reshape(-1),
+            _token_weights(mvec, *labels.shape), n_chunks)
     else:
         logits = (x @ head).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
@@ -1634,10 +1725,13 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
     (implies remat when set).  A looped stack refuses both: it always
     recomputes by :func:`_loop_saves`.
     ``loss_chunks=k`` computes the CE loss k token-chunks at a time
-    (:func:`_ce_token_nll_sum`) so the ``(tokens, vocab)`` f32 logits
-    never materialize — the dominant HBM stream when vocab ≫ d.  Loss
-    differs from the dense path only in summation order (~1 ulp); the
-    dense default keeps historical pins bit-stable.
+    (:func:`_ce_weighted`) so the ``(tokens, vocab)`` f32 logits never
+    materialize — the dominant HBM stream when vocab ≫ d — and nothing
+    is recomputed: each chunk's pass makes its logits, ``dlogits`` and
+    both gradient products (three products with the vocabulary axis),
+    and the backward pass scales the results by the loss's cotangent.
+    Loss differs from the dense path only in summation order (~1 ulp);
+    the dense default keeps historical pins bit-stable.
     ``head_sharded=True`` vocab-shards the LM head over ``model`` and
     computes the CE with Megatron parallel cross-entropy
     (:func:`_vshard_chunk_nll`): head memory, the head GEMM, and its

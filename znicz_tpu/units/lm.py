@@ -169,6 +169,11 @@ class TransformerLMStep(AcceleratedUnit):
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
         self.attn_direct_layout_share: Optional[float] = None
+        #: of the train step's head passes, the share that make their
+        #: gradients where they make their logits (all or none:
+        #: ``parallel/transformer.py::ce_grad_in_forward``); None until
+        #: the step is built
+        self.ce_grad_in_forward_share: Optional[float] = None
         self.arch = None
         self._params = None
         self._step = None
@@ -213,6 +218,8 @@ class TransformerLMStep(AcceleratedUnit):
         self._eval = tfm.make_eval_loss(
             self.mesh, self.arch, masked=True, loss_chunks=self.loss_chunks,
             head_sharded=self.head_sharded)
+        self._publish_ce_rule(float(tfm.ce_grad_in_forward(
+            self.arch, self.loss_chunks, self.head_sharded)))
         self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
         # the programs from again (as FusedTrainStep's programs)
@@ -334,6 +341,22 @@ class TransformerLMStep(AcceleratedUnit):
         if "attn_flash" in sums:
             self._publish_attn_layout(float(sums["attn_direct"]) /
                                       float(sums["attn_flash"]))
+
+    def _publish_ce_rule(self, share: float) -> None:
+        """Of the step's head passes, the share that take the chunked
+        cross-entropy's own rule (a constant of the step as it is built):
+        the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        self.ce_grad_in_forward_share = share
+        registry.gauge(
+            "znicz_lm_ce_grad_in_forward_share",
+            "head passes of the train step that made their gradients in "
+            "the pass that made their logits (chunked cross-entropy "
+            "against a replicated head: three products a pass) over all "
+            "its head passes (an unchunked or a vocab-sharded one leaves "
+            "them to AD)",
+            ("unit",)).labels(unit=self.name).set(share)
 
     def _publish_attn_layout(self, share: float) -> None:
         """Of the attention layers that ran a flash kernel, the share whose
