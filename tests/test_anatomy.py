@@ -1,57 +1,17 @@
-"""Step-time anatomy (ISSUE 20): the StepAnatomy accountant, MFU gauge
-wiring, the per-rank straggler rule and goodput note plumbing.  The
-split-dispatch producers went with ISSUE 24 (the train planes feed
-``znicz_anatomy_step_seconds`` from the dispatch cadence;
-tests/test_observe.py).
+"""Step-time anatomy (ISSUE 20): the phase hook's gate, the per-rank
+straggler rule and goodput note plumbing.  The split-dispatch producers
+went with ISSUE 24 (the train planes feed ``znicz_anatomy_step_seconds``
+from the dispatch cadence; tests/test_observe.py), the ``StepAnatomy``
+accountant with ISSUE 37 (the stall watch: tests/test_stall_watch.py).
 """
 
 import pytest
 
 from znicz_tpu.observe import probe, registry
-from znicz_tpu.observe.anatomy import TRAIN_PHASES, StepAnatomy
 
 
 def _flat(**kw):
     return registry.REGISTRY.snapshot_flat(skip_zero=False, **kw)
-
-
-# -- the accountant ----------------------------------------------------------
-
-def test_step_anatomy_stamps_and_pretouch(monkeypatch):
-    """Stamps charge cursor->now per phase (deterministic via injected
-    nows), every child exists at construction, and finish() emits the
-    step counter + MFU from the registered analytic FLOPs."""
-    monkeypatch.setenv("ZNICZ_TPU_PEAK_FLOPS", "1e9")
-    anat = StepAnatomy("anat_unit", TRAIN_PHASES)
-    # pre-touch: all children live at 0 before any step
-    flat = _flat()
-    assert flat['znicz_anatomy_steps_total{plane="anat_unit"}'] == 0.0
-    for phase in TRAIN_PHASES:
-        assert flat['znicz_anatomy_phase_seconds_count'
-                    f'{{plane="anat_unit",phase="{phase}"}}'] == 0.0
-    assert flat['znicz_anatomy_mfu{plane="anat_unit"}'] == 0.0
-
-    anat.set_flops(2e8)                  # with peak 1e9: mfu = 0.2/wall
-    t0 = anat.begin()
-    anat.stamp("zero_gather", now=t0 + 0.10)
-    anat.stamp("grad", now=t0 + 0.60)
-    anat.stamp("collective", now=t0 + 0.75)
-    anat.stamp("update", now=t0 + 0.80)
-    wall = anat.finish()
-    flat = _flat()
-    assert flat['znicz_anatomy_phase_seconds_sum'
-                '{plane="anat_unit",phase="zero_gather"}'] == \
-        pytest.approx(0.10)
-    assert flat['znicz_anatomy_phase_seconds_sum'
-                '{plane="anat_unit",phase="grad"}'] == pytest.approx(0.50)
-    assert flat['znicz_anatomy_phase_seconds_sum'
-                '{plane="anat_unit",phase="collective"}'] == \
-        pytest.approx(0.15)
-    assert flat['znicz_anatomy_steps_total{plane="anat_unit"}'] == 1.0
-    # finish() measures the REAL wall (the injected nows are in its
-    # future, so the measured step is tiny) — the MFU gauge still set
-    assert wall >= 0.0
-    assert flat['znicz_anatomy_mfu{plane="anat_unit"}'] > 0.0
 
 
 def test_observe_phase_respects_probe_gate():

@@ -101,9 +101,62 @@ def _resolve_min_s(explicit: Optional[float]) -> float:
         return 0.0
 
 
+#: the four durations jax 0.9.0 records while it makes a program, and
+#: the phase each feeds (``probe.compile_phase``).  ``backend_compile``
+#: is measured around ``compiler.compile_or_get_cached``, so on a cache
+#: hit it CONTAINS the retrieval that ``cache_load`` reports (read in
+#: ``jax/_src/interpreters/pxla.py`` and ``compiler.py``): a reader
+#: subtracts.  The trace event fires for every nested ``jit`` as well,
+#: cached ones included, each inside its caller's duration.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_PHASE_OF = {
+    _TRACE_EVENT: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
+class _PhaseListener:
+    """jax's duration events into ``probe.compile_phase``, with the
+    nesting taken out of ``trace``: jax announces a trace's start as a
+    scalar event, so a depth per thread tells the outermost trace from
+    the thousands inside it, and what was lowered or compiled while it
+    ran (an eager operation on a constant) comes off its seconds."""
+
+    def __init__(self, sink) -> None:
+        self._sink = sink
+        self._tl = threading.local()
+
+    def on_scalar(self, name: str, value, **kwargs) -> None:
+        if name == _TRACE_EVENT:
+            tl = self._tl
+            depth = getattr(tl, "depth", 0)
+            if not depth:
+                tl.inside = 0.0
+            tl.depth = depth + 1
+
+    def on_duration(self, name: str, dt_s: float, **kwargs) -> None:
+        phase = _PHASE_OF.get(name)
+        if phase is None:
+            return
+        tl = self._tl
+        depth = getattr(tl, "depth", 0)
+        if phase == "trace":
+            if depth > 1:
+                tl.depth = depth - 1
+                return
+            tl.depth = 0
+            dt_s = max(dt_s - getattr(tl, "inside", 0.0), 0.0)
+        elif depth and phase != "cache_load":
+            tl.inside += dt_s
+        self._sink(phase, dt_s, str(kwargs.get("fun_name", "")))
+
+
 def _register_listener() -> None:
-    """Feed jax's cache-hit/miss monitoring events into the registry —
-    once per process, regardless of later reconfiguration."""
+    """Feed jax's cache-hit/miss and compile-duration monitoring events
+    into the registry — once per process, regardless of later
+    reconfiguration and of whether a cache directory is in use."""
     global _listener_registered
     if _listener_registered:
         return
@@ -117,7 +170,10 @@ def _register_listener() -> None:
         elif name == "/jax/compilation_cache/cache_misses":
             probe.compile_cache_event("miss")
 
+    phases = _PhaseListener(probe.compile_phase)
     _monitoring.register_event_listener(_on_event)
+    _monitoring.register_scalar_listener(phases.on_scalar)
+    _monitoring.register_event_duration_secs_listener(phases.on_duration)
     _listener_registered = True
 
 
@@ -169,6 +225,7 @@ def configure(cache_dir: Optional[str] = None,
             return _active_dir
         import jax
 
+        _register_listener()
         if target is None:
             _turn_off(jax)
             _log.info("persistent compilation cache disabled")
@@ -200,7 +257,6 @@ def configure(cache_dir: Optional[str] = None,
         # a corrupt/truncated entry must be a miss, not a crash
         jax.config.update("jax_raise_persistent_cache_errors", False)
         _reset_jax_cache_state()
-        _register_listener()
         _configured, _active_dir, _active_min_s = True, target, min_s
         _log.info("persistent compilation cache at %s "
                   "(min_compile_time_s=%g)", target, min_s)

@@ -28,6 +28,7 @@ import jax
 
 from znicz_tpu.core.config import root
 from znicz_tpu.core.logger import Logger
+from znicz_tpu.observe import probe
 
 
 def resolve_compute_dtype(platform: str, precision: str | None = None):
@@ -40,6 +41,20 @@ def resolve_compute_dtype(platform: str, precision: str | None = None):
     precision = precision or root.common.engine.get("precision", "bfloat16")
     return jnp.bfloat16 if (precision == "bfloat16"
                             and platform != "cpu") else jnp.float32
+
+
+def first_local_device() -> jax.Device:
+    """This process's first local device.  The call that starts jax's
+    client (seconds on a TPU host) runs under ``setup.backend``; a
+    caller that started it already (the benchmark asks for its chips
+    first) pays nothing here and leaves no span."""
+    from jax._src import xla_bridge
+
+    # (a jax without the question counts as not started: bool() is False)
+    if getattr(xla_bridge, "backends_are_initialized", bool)():
+        return jax.local_devices()[0]
+    with probe.setup_phase("backend"):
+        return jax.local_devices()[0]
 
 
 class Device(Logger):
@@ -87,7 +102,7 @@ class XLADevice(Device):
         # jax.devices()[0] is process 0's device — non-addressable from
         # every other rank.  Single-process they are identical.
         self.jax_device = device if device is not None \
-            else jax.local_devices()[0]
+            else first_local_device()
         self.precision = precision or root.common.engine.get("precision", "bfloat16")
         self.platform = self.jax_device.platform
 

@@ -137,6 +137,9 @@ class Workflow(Unit):
             signals_before = self.signals_dispatched
             span_args: dict[str, dict] = {}   # unit -> reusable trace
             timed = TRACER.timed              # args (no per-signal dict)
+            # the train step's span also takes the thread's CPU clock
+            # (cpu_us): its wall counts the runtime's back-pressure
+            step_unit = getattr(self, "step", None)
         self.end_point.reached = False
         # clear fired-marks left by an early-terminated previous walk so join
         # units cannot fire on stale signals
@@ -158,7 +161,8 @@ class Workflow(Unit):
                     # timeline, error-marked by the span's exit -- a
                     # flight artifact's post-mortem window needs the
                     # step that died, not just the ones before it
-                    with timed("workflow.step", a) as step_span:
+                    with timed("workflow.step", a,
+                               cpu=target is step_unit) as step_span:
                         # chaos hook: the resilience plane injects
                         # crashes/hangs here (site "workflow.step") so
                         # fault tests drive this real loop; with no plan

@@ -18,6 +18,7 @@ from typing import Optional
 
 from znicz_tpu.core.backends import AutoDevice, Device
 from znicz_tpu.core.logger import Logger
+from znicz_tpu.observe import probe
 from znicz_tpu.resilience.retry import RetryPolicy
 from znicz_tpu.snapshotter import restore_state
 
@@ -118,7 +119,8 @@ class Launcher(Logger):
     def load(self, builder, **kwargs):
         """Reference ``load`` contract: build the workflow (module-supplied
         builder + kwargs), remember it, return (workflow, from_snapshot)."""
-        self.workflow = builder(**kwargs)
+        with probe.setup_phase("load"):
+            self.workflow = builder(**kwargs)
         return self.workflow, self.snapshot is not None
 
     def main(self, **_ignored):
@@ -127,7 +129,8 @@ class Launcher(Logger):
             raise RuntimeError("load() was not called before main()")
         device = self.device if self.device is not None else AutoDevice()
         self.info(f"initializing {self.workflow.name} on {device!r}")
-        self.workflow.initialize(device=device)
+        with probe.setup_phase("initialize"):
+            self.workflow.initialize(device=device)
         if self.snapshot:
             meta = restore_state(self.workflow, self.snapshot)
             self.info(f"resumed from {self.snapshot} "

@@ -34,8 +34,7 @@ from znicz_tpu.observe.probe import (check_recompiles,
                                      enabled, resilience_event,
                                      set_enabled, staged_bytes,
                                      time_compiles, watch_compiles)
-from znicz_tpu.observe.anatomy import (StepAnatomy, StepCadence,
-                                       observe_phase)
+from znicz_tpu.observe.anatomy import StepCadence, observe_phase
 from znicz_tpu.observe.watchtower import (WATCHTOWER, Rule,
                                           TimeSeriesRing, Watchtower)
 from znicz_tpu.observe import flight
@@ -52,7 +51,7 @@ __all__ = ["REGISTRY", "Registry", "counter", "gauge", "histogram",
            "check_recompiles", "staged_bytes", "resilience_event",
            "compile_observed", "time_compiles",
            "compile_cache_event", "compile_cache_stats",
-           "StepAnatomy", "StepCadence", "observe_phase",
+           "StepCadence", "observe_phase",
            "WATCHTOWER", "Watchtower", "Rule", "TimeSeriesRing",
            "flight", "federation", "FleetAggregator", "MetricsExporter",
            "merge_traces", "next_request_id", "start_metrics_export"]

@@ -30,6 +30,14 @@ hook                  call site
 event``               every persistent compilation-cache consultation
                       lands in ``znicz_compile_cache_{hits,misses}_
                       total`` so warm-vs-cold boot is a counter delta
+``compile_phase``     the same listener, from the compiler's own clock —
+                      trace, lowering, backend compile and the cached
+                      executable's load, each into ``znicz_compile_phase_
+                      seconds_total{phase}`` and a ``compile.<phase>`` span
+``setup_phase``       ``launcher.py`` (``load``, ``initialize``), the step
+                      units (``init_params``, ``place``) and ``core/
+                      backends.py`` (``backend``) — a live ``setup.<phase>``
+                      span and ``znicz_setup_seconds{phase}``
 ====================  =====================================================
 
 All hooks early-out on ``observe.set_enabled(False)`` (one module-global
@@ -40,6 +48,7 @@ path and how determinism tests pin "instrumentation off == seed path".
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import re
 import time
@@ -234,13 +243,89 @@ _COMPILE_SECONDS = _reg.histogram(
 def compile_observed(label: str, dt_s: float, **args) -> None:
     """One cold compile (+ first execution) took ``dt_s`` wall seconds:
     histogram observation plus a ``compile.cold`` complete-span on the
-    trace timeline, so the ROADMAP compile-latency work lands with its
-    baseline already recorded."""
+    trace timeline (and in :data:`SETUP_RING`), so the ROADMAP
+    compile-latency work lands with its baseline already recorded."""
     if not _enabled:
         return
     _COMPILE_SECONDS.labels(fn=label).observe(dt_s)
-    _trace.TRACER.complete("compile.cold", time.perf_counter() - dt_s,
-                           dt_s, fn=label, **args)
+    _setup_event("compile.cold", time.perf_counter() - dt_s, dt_s,
+                 {"fn": label, **args})
+
+
+# -- set-up in its parts (ISSUE 37) -------------------------------------------
+
+#: the set-up events once more, in a ring of their own that nobody clears:
+#: ``setup.<phase>``, ``compile.<phase>`` and ``compile.cold`` with their
+#: stamps, on :data:`trace.TRACER`'s clock.  A caller that clears the main
+#: ring when its measurement begins (the benchmark does) still finds here
+#: WHEN each part of the set-up ran, which a counter cannot say.
+SETUP_RING = _trace.Tracer(capacity=8192, origin=_trace.TRACER._origin)
+
+_COMPILE_PHASE = _reg.counter(
+    "znicz_compile_phase_seconds_total",
+    "seconds jax spent making programs, by the compiler's own clock: "
+    "trace (outermost traces only, less what they compiled inside), "
+    "lower (jaxpr to MLIR), backend_compile (XLA's compile or the "
+    "persistent cache's lookup and load: it contains cache_load) and "
+    "cache_load (the retrieval of a cached executable)",
+    labelnames=("phase",))
+_SETUP_SECONDS = _reg.gauge(
+    "znicz_setup_seconds",
+    "wall seconds of the set-up's phases so far: load (the workflow's "
+    "builder), initialize (Workflow.initialize) and inside it "
+    "init_params (weights drawn on the host), place (parameters, "
+    "optimizer state and a pinned dataset put on the device, fenced) "
+    "and backend (the first jax client start, where the program made it)",
+    labelnames=("phase",))
+
+#: compile-phase events shorter than this stay out of the rings (a trace
+#: visits thousands of cached sub-traces); the counter takes them all
+MIN_PHASE_SPAN_S = 1e-3
+
+
+def _setup_event(name: str, start: float, dt_s: float,
+                 args: Optional[dict] = None) -> None:
+    """One already-timed set-up event into both rings."""
+    _trace.TRACER.complete(name, start, dt_s, args)
+    SETUP_RING.complete(name, start, dt_s, args)
+
+
+def compile_phase(phase: str, dt_s: float, fn: str = "") -> None:
+    """``dt_s`` seconds of one compile phase (``trace`` | ``lower`` |
+    ``backend_compile`` | ``cache_load``), as jax's monitoring reported
+    it to ``compilecache``'s listener: the counter, and a
+    ``compile.<phase>`` span that ends now."""
+    if not _enabled:
+        return
+    _COMPILE_PHASE.labels(phase=phase).inc(dt_s)
+    if dt_s >= MIN_PHASE_SPAN_S:
+        _setup_event(f"compile.{phase}", time.perf_counter() - dt_s, dt_s,
+                     {"fn": fn} if fn else None)
+
+
+def placed(tree):
+    """``tree`` once the device holds it: the fence at the end of a
+    ``setup.place`` span, so that its seconds are the transfer's.  Taken
+    only while the span is (a transfer otherwise overlaps what follows)."""
+    if _enabled:
+        import jax
+
+        jax.block_until_ready(tree)
+    return tree
+
+
+@contextlib.contextmanager
+def setup_phase(phase: str):
+    """A live ``setup.<phase>`` span (ring, the profiler's host plane
+    under ``--profile``, :data:`SETUP_RING`) whose seconds also add to
+    ``znicz_setup_seconds{phase}``."""
+    if not _enabled:
+        yield
+        return
+    with _trace.TRACER.timed(f"setup.{phase}") as span:
+        yield
+    _SETUP_SECONDS.labels(phase=phase).inc(span.dt)
+    SETUP_RING.complete(f"setup.{phase}", span.t0, span.dt)
 
 
 #: every live :class:`_CompileTimed`, for :func:`scope_map`

@@ -84,15 +84,18 @@ class _Span:
     that feed a histogram from the same reads; a span that ends in an
     exception lands error-marked."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_tid", "_ann", "t0", "dt")
+    __slots__ = ("_tracer", "_name", "_args", "_tid", "_ann", "_cpu0",
+                 "t0", "dt")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict],
-                 tid: Optional[int] = None):
+                 tid: Optional[int] = None, cpu: bool = False):
         self._tracer = tracer
         self._name = name
         self._args = args
         self._tid = tid
         self._ann = None
+        #: the thread's CPU clock at entry where ``cpu`` was asked, else None
+        self._cpu0 = 0.0 if cpu else None
         self.t0 = self.dt = 0.0
 
     def __enter__(self) -> "_Span":
@@ -100,11 +103,15 @@ class _Span:
             ann = self._ann = _annotation(self._name, self._args)
             if ann is not None:
                 ann.__enter__()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type=None, *exc) -> None:
         self.dt = time.perf_counter() - self.t0
+        cpu_s = None if self._cpu0 is None else \
+            time.thread_time() - self._cpu0
         ann = self._ann
         if ann is not None:
             self._ann = None
@@ -113,6 +120,8 @@ class _Span:
         if not tracer.enabled:
             return
         args = self._args
+        if cpu_s is not None:
+            args = {**(args or {}), "cpu_us": round(cpu_s * 1e6, 1)}
         if exc_type is not None:
             args = {**(args or {}), "error": True}
         tracer.live_names.add(self._name)
@@ -142,12 +151,15 @@ class Tracer:
     """Bounded ring of trace events; see module docstring."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
-                 enabled: bool = True) -> None:
+                 enabled: bool = True,
+                 origin: Optional[float] = None) -> None:
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
         self.enabled = bool(enabled)
-        self._origin = time.perf_counter()
+        #: the ``perf_counter`` stamp of ``ts == 0`` (another tracer's, for
+        #: a ring that shares its clock)
+        self._origin = time.perf_counter() if origin is None else origin
         # deque appends are atomic under the GIL — spans from the
         # prefetch worker, HTTP threads and the control walk interleave
         # without a lock on the hot path
@@ -165,15 +177,18 @@ class Tracer:
         return self.timed(name, args or None)
 
     def timed(self, name: str, args: Optional[dict] = None,
-              tid: Optional[int] = None) -> _Span:
+              tid: Optional[int] = None, cpu: bool = False) -> _Span:
         """A live span that times even while tracing is disabled (it then
         records and annotates nothing): the hot call sites read ``.t0``
         / ``.dt`` after the block and feed their histograms from the
         span's own two clock reads.  ``args`` is a PRE-BUILT (reusable)
         dict; ``tid`` puts the RING event on a synthetic track as
         :meth:`complete` does (the annotation stays on the real
-        thread)."""
-        return _Span(self, name, args, tid)
+        thread).  ``cpu=True`` adds ``cpu_us`` to the ring event's args:
+        the CPU time of the opening thread inside the span (two
+        ``time.thread_time()`` reads), which a wait on the runtime does
+        not move as it moves the wall."""
+        return _Span(self, name, args, tid, cpu)
 
     def complete(self, name: str, start: float, duration: float,
                  args: Optional[dict] = None, tid: Optional[int] = None,

@@ -141,7 +141,8 @@ class TransformerLMStep(AcceleratedUnit):
                 "effect without "
                 "n_experts — a dense model would train silently")
         #: wall between consecutive dispatches ->
-        #: znicz_anatomy_step_seconds{plane="transformer"}, always on
+        #: znicz_anatomy_step_seconds{plane="transformer"}, and what the
+        #: stall watch looks at; always on
         self._cadence = StepCadence("transformer")
         self.vocab_size: Optional[int] = None
         # decision links (DecisionMSE contract)
@@ -203,10 +204,14 @@ class TransformerLMStep(AcceleratedUnit):
                 self.info(f"no mesh given: using 1 of "
                           f"{jax.local_device_count()} local devices "
                           f"(pass mesh= to the workflow to use more)")
+        from znicz_tpu.observe import probe
+
         self.arch = self._resolve_arch()
         if self._params is None:
-            self._params = tfm.init_params(prng.get(), self.arch)
-        self._params = self._place_params(self._params)
+            with probe.setup_phase("init_params"):
+                self._params = tfm.init_params(prng.get(), self.arch)
+        with probe.setup_phase("place"):
+            self._params = probe.placed(self._place_params(self._params))
         # masked=True: the loader's padded tail rows (base.py static-shape
         # policy) contribute neither loss nor gradients.  donate=True: the
         # step consumes the params it is handed (xla_run rebinds them)
@@ -223,8 +228,6 @@ class TransformerLMStep(AcceleratedUnit):
         self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
         # the programs from again (as FusedTrainStep's programs)
-        from znicz_tpu.observe import probe
-
         label = type(self).__name__
         self._step = probe.time_compiles(label, self._step)
         self._eval = probe.time_compiles(label, self._eval)
@@ -285,6 +288,9 @@ class TransformerLMStep(AcceleratedUnit):
                 lambda s: NamedSharding(self.mesh, s), specs,
                 is_leaf=lambda x: isinstance(x, P)))
 
+    def stop(self) -> None:
+        self._cadence.close()
+
     # -- compute ------------------------------------------------------------
     def numpy_run(self) -> None:
         self.numpy_init()
@@ -315,7 +321,9 @@ class TransformerLMStep(AcceleratedUnit):
             # no wait: the loss joins the pass's sums on the device (one
             # tiny program behind the step) and work stays queued
             self._acc = self._fold(self._acc, loss, mask, stats)
-        self._cadence.tick(span.t0)
+        # the loss is the step's newest output that the next step does
+        # not take by donation: the stall watch asks it is_ready()
+        self._cadence.tick(span.t0, loss)
         self.last_loss = loss
         if not loader.last_minibatch:
             # minibatches before the last contribute zero to the
