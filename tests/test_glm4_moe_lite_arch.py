@@ -562,4 +562,12 @@ def test_step_unit_publishes_the_direct_layout_share(tmp_path):
     assert step.attn_direct_layout_share == 1.0
     fam = registry.REGISTRY.get("znicz_lm_attn_direct_layout_share")
     assert fam is not None and fam.labels(unit=step.name).get() == 1.0
+    # and the rows of the tile each blocked pass ran: the chooser's answer
+    with refuse:
+        want = pattn.kvb_block_rows(128, 128)
+    assert want == {"fwd": 128, "dkv": 128, "dq": 128}
+    assert step.attn_kvb_block_rows == want
+    fam = registry.REGISTRY.get("znicz_lm_attn_kvb_block_rows")
+    assert {name: fam.labels(**{"unit": step.name, "pass": name}).get()
+            for name in want} == want
 

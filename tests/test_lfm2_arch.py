@@ -675,4 +675,9 @@ def test_step_unit_publishes_that_its_attention_folds(tmp_path):
     assert step.attn_direct_layout_share == 0.0
     fam = registry.REGISTRY.get("znicz_lm_attn_direct_layout_share")
     assert fam is not None and fam.labels(unit=step.name).get() == 0.0
+    # the whole-row form runs no key/value-blocked tile in any pass
+    assert step.attn_kvb_block_rows == {"fwd": 0.0, "dkv": 0.0, "dq": 0.0}
+    fam = registry.REGISTRY.get("znicz_lm_attn_kvb_block_rows")
+    assert [fam.labels(**{"unit": step.name, "pass": name}).get()
+            for name in pattn._KVB_PASSES] == [0.0, 0.0, 0.0]
 

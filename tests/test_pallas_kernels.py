@@ -393,7 +393,7 @@ def test_blocked_flash_attention_matches_dense(t, dh, causal, h):
     def dense(q, k, v):
         return att.attention(jnp, q, k, v, causal=causal)
 
-    block = pattn._kvb_block(t)
+    block = pattn._kvb_block(t, dh, "fwd")
     assert block == (128 if t % 256 else 256)
     n = t // block
     qi, ki, flags = pattn._visits(t, block, causal, False)
@@ -406,6 +406,118 @@ def test_blocked_flash_attention_matches_dense(t, dh, causal, h):
             q, k, v)
         want = jax.grad(lambda *a: (dense(*a) * ct).sum(), (0, 1, 2))(
             q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+@pytest.mark.parametrize("pass_", ["fwd", "dkv", "dq"])
+@pytest.mark.parametrize("dh", [64, 128, 256, 512])
+@pytest.mark.parametrize("t", [256, 384, 640, 768, 1024, 3072, 4096])
+def test_blocked_tile_follows_the_time_axis_the_head_and_the_pass(t, dh,
+                                                                  pass_):
+    """The chooser: a block that divides ``t`` and whose working set (the
+    formula beside the limit) is within ``_KVB_VMEM_LIMIT``; a time axis
+    that 1,024 does not divide gets what it got (the largest of 512, 256,
+    128 that divides it: 128 at 384 and 640, 256 at 256 and 768), one that
+    it divides 1,024 rows, the largest that divides and fits (dk/dv at
+    head 512 is the formula's 32 MiB, the limit), in every pass but dk/dv
+    from head 256 on, which the sweep showed no faster there and keeps at
+    512."""
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    block = pattn._kvb_block(t, dh, pass_)
+    assert block and t % block == 0
+    assert pattn._kvb_vmem(pass_, block, dh) <= pattn._KVB_VMEM_LIMIT
+    before = next(b for b in (512, 256, 128) if t % b == 0)
+    kept = pass_ == "dkv" and dh >= 256
+    assert block == (1024 if t % 1024 == 0 and not kept else before)
+    # no larger candidate both divides and fits, but where 512 is kept
+    larger = [b for b in pattn._KVB_BLOCKS if b > block and t % b == 0 and
+              pattn._kvb_vmem(pass_, b, dh) <= pattn._KVB_VMEM_LIMIT]
+    assert larger == ([1024] if kept and t % 1024 == 0 else [])
+    # the formula grows with every argument it has: twice the rows of the
+    # largest tile would not fit in any pass at any head
+    assert pattn._kvb_vmem(pass_, 2048, dh) > pattn._KVB_VMEM_LIMIT
+    assert pattn._kvb_vmem(pass_, block, dh) < \
+        pattn._kvb_vmem(pass_, block, dh + 64)
+
+
+def test_blocked_tile_rows_are_zero_where_the_form_is_not_blocked():
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    assert pattn.kvb_block_rows(4096, 128) == {
+        "fwd": 1024, "dkv": 1024, "dq": 1024}
+    assert pattn.kvb_block_rows(4096, 256) == {
+        "fwd": 1024, "dkv": 512, "dq": 1024}
+    assert pattn.kvb_block_rows(8192 + 512, 64) == {
+        "fwd": 512, "dkv": 512, "dq": 512}
+    # the whole-row form's shapes, and a refused one
+    assert pattn.kvb_block_rows(4096, 64) == {"fwd": 0, "dkv": 0, "dq": 0}
+    assert pattn.kvb_block_rows(4096, 576) == {"fwd": 0, "dkv": 0, "dq": 0}
+
+
+@pytest.mark.parametrize("t,dh,causal,direct,blocks", [
+    (1024, 128, True, True, None), (2048, 128, True, True, None),
+    (2048, 64, True, False, None), (1024, 64, False, False, None),
+    (2048, 256, False, True, None),
+    (2048, 128, True, True, {"fwd": 1024, "dkv": 512, "dq": 256})],
+    ids=["one_cut_tile_direct", "three_tiles_two_cut_direct",
+         "three_tiles_two_cut_folded", "one_tile_full_folded",
+         "four_tiles_full_direct_dh256", "each_pass_its_own_block"])
+def test_blocked_flash_attention_at_1024_row_tiles_matches_dense(
+        t, dh, causal, direct, blocks):
+    """Forward and the three gradients (interpreted) against the dense
+    core at shapes whose tile is 1,024 rows: one tile that the diagonal
+    cuts; three of which two are cut; full attention over one and four
+    (at head 256, where dk/dv runs 16 tiles of 512 beside them); the
+    layer's own layout and the folded one; and the three passes each on a
+    block of its own (the chooser patched)."""
+    import contextlib
+    import jax
+    from unittest import mock
+
+    from znicz_tpu.ops import attention as att
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, h = 1, 3 if blocks else 2
+    ks = jax.random.split(jax.random.PRNGKey(t + dh + causal), 4)
+    q, k, v, ct = (jax.random.normal(kk, (b, t, h, dh)) for kk in ks)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, dh)  # noqa
+
+    def blocked(q, k, v):
+        if direct:
+            return pattn._flash_kvb(q, k, v, causal, True)
+        o = pattn._flash_kvb(fold(q), fold(k), fold(v), causal, True)
+        return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
+
+    def dense(q, k, v):
+        return att.attention(jnp, q, k, v, causal=causal)
+
+    patched = mock.patch.object(
+        pattn, "_kvb_block", lambda t, dh, pass_: blocks[pass_]) \
+        if blocks else contextlib.nullcontext()
+    if not blocks:
+        assert pattn.kvb_block_rows(8192 + t, dh) == {
+            "fwd": 1024, "dkv": 512 if dh == 256 else 1024, "dq": 1024}
+        n = t // 1024
+        flags = pattn._visits(t, 1024, causal, False)[2]
+        assert len(flags) == (n * (n + 1) // 2 if causal else n * n)
+        assert sum((flags & pattn._CUT) != 0) == (n if causal else 0)
+    with patched, jax.default_matmul_precision("highest"):
+        if blocks:                      # this shape is the test's alone
+            text = str(jax.make_jaxpr(jax.grad(
+                lambda *a: blocked(*a).sum(), (0, 1, 2)))(q, k, v))
+            for rows in blocks.values():
+                assert f"f32[{rows},{dh}]" in text     # its accumulator
+        np.testing.assert_allclose(blocked(q, k, v), dense(q, k, v),
+                                   atol=2e-5)
+        got = jax.grad(lambda *a: (blocked(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+        want = jax.grad(lambda *a: (dense(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+    if blocks:                  # no later test meets the patched programs
+        pattn._kvb_call_fwd.clear_cache()
+        pattn._kvb_call_bwd.clear_cache()
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=5e-5)
 
@@ -476,7 +588,7 @@ def test_blocked_direct_layout_is_the_folded_layout_to_the_bit(dh, causal):
         o = pattn._flash_kvb(fold(q), fold(k), fold(v), causal, True)
         return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
 
-    assert pattn._kvb_block(t) == 128
+    assert pattn._kvb_block(t, dh, "dkv") == 128
     with _refuse_rows():
         assert pattn.direct_layout(t, dh)
     np.testing.assert_array_equal(direct(q, k, v), folded(q, k, v))
@@ -670,11 +782,18 @@ def test_flash_attention_keeps_the_whole_row_form_wherever_it_accepted():
 
 @pytest.mark.parametrize("t,dh,word", [
     (100, 64, "t=100"), (4096, 48, "head_dim=48"),
-    (4096, 1024, "head_dim=1024")])
+    (4096, 1024, "head_dim=1024"), (4096, 576, "needs 34 MiB")])
 def test_blocked_unsupported_reason_names_the_refused_shape(t, dh, word):
+    """The refusal of a wide head is the chooser's own formula: head 512
+    is the widest of which every pass holds a 1,024-row block (dk/dv: 32
+    MiB, the limit to the byte), as it was the widest before."""
     from znicz_tpu.ops.pallas import attention as pattn
 
     assert word in pattn.blocked_unsupported_reason(t, dh)
+    assert [dh for dh in range(64, 1088, 64)
+            if pattn.blocked_unsupported_reason(4096, dh) is None] == \
+        list(range(64, 576, 64))
+    assert pattn._kvb_vmem("dkv", 1024, 512) == pattn._KVB_VMEM_LIMIT
     assert pattn.form_of(t, dh)[0] is None
     with pytest.raises(ValueError, match=word):
         pattn.flash_attention(jnp.zeros((1, t, 1, dh)),

@@ -330,6 +330,22 @@ def test_disabled_probe_keeps_no_handle_no_thread_no_span():
     assert not watch.running
 
 
+def test_the_exit_hook_ends_the_thread_with_a_cadence_still_joined():
+    """A process that exits with its cadence open (a worker whose workflow
+    never stopped) must not leave the daemon thread to the finalizing
+    interpreter: the hook ends it within a wake, and none starts after."""
+    watch = StallWatch()
+    cadence = StepCadence("sw_exit", watch=watch)
+    cadence.tick(1.0, Handle())
+    thread = watch._thread
+    assert watch.running and thread.is_alive()
+    watch._halt()                          # what atexit calls
+    assert not thread.is_alive()
+    cadence.close()
+    StepCadence("sw_exit2", watch=watch).tick(2.0, Handle())
+    assert watch._thread is thread         # no new thread after the hook
+
+
 def test_step_units_hand_their_output_and_close_on_stop(monkeypatch):
     """The fused step's dispatch ticks with an output of the step that a
     wake finds ready, and ``workflow.stop`` closes the cadence."""

@@ -54,6 +54,7 @@ the stop lasts (:func:`evidence`) and, when the stop ends, records a
 
 from __future__ import annotations
 
+import atexit
 import json
 import logging
 import os
@@ -317,15 +318,29 @@ class StallWatch:
         self._lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self._due: Optional[float] = None
+        self._halted = threading.Event()
+        if threaded:
+            atexit.register(self._halt)
 
     def add(self, cadence: "StepCadence") -> None:
         with self._lock:
             self._cadences.add(cadence)
-            if self._threaded and self._thread is None:
+            if self._threaded and self._thread is None and \
+                    not self._halted.is_set():
                 self._due = None
                 self._thread = threading.Thread(
                     target=self._loop, name="znicz-stall-watch", daemon=True)
                 self._thread.start()
+
+    def _halt(self) -> None:
+        """End the thread before the interpreter does: a daemon thread
+        that the finalizing interpreter stops inside ``is_ready()`` (C++
+        frames on its stack) aborts the process ("FATAL: exception not
+        rethrown", exit code -6 after a run that had finished)."""
+        self._halted.set()
+        thread = self._thread
+        if thread is not None:
+            thread.join(timeout=2.0)
 
     def _release(self, cadence: "StepCadence", w: _Watched) -> None:
         """Let go of a closed cadence and of the outputs it still held;
@@ -341,8 +356,7 @@ class StallWatch:
         return self._thread is not None
 
     def _loop(self) -> None:
-        while True:
-            time.sleep(WAKE_S)
+        while not self._halted.wait(WAKE_S):
             with self._lock:
                 if not self._cadences:
                     self._thread = None

@@ -302,12 +302,30 @@ KVB_FWD_KERNEL_NAME = "flash_attention_kvb_fwd"
 KVB_DKV_KERNEL_NAME = "flash_attention_kvb_dkv"
 KVB_DQ_KERNEL_NAME = "flash_attention_kvb_dq"
 
-#: rows of a q block and of a key/value block, the largest that divides t
-_KVB_BLOCKS = (512, 256, 128)
-#: scoped VMEM asked of the compiler: the widest head taken (512) holds
-#: six double-buffered bf16 blocks, two f32 accumulators and four f32
-#: score tiles in 11 MiB
+#: rows of a q block and of a key/value block a pass may take, largest
+#: first (:func:`_kvb_block` chooses)
+_KVB_BLOCKS = (1024, 512, 256, 128)
+#: from this head width on the dk/dv pass keeps 512 rows: its four
+#: products a tile keep the MXU busy there, and what a 1,024-row tile
+#: computes and masks on the diagonal (a quarter over what causality
+#: needs, against an eighth) costs more than its fewer visits save
+#: (alone at 2 x 4,096 x 20 x 256: 4.604 ms against 4.638; at heads of 64
+#: and 128 the larger tile wins this pass too, 3.792 against 3.977 and
+#: 1.958 against 2.136; wider heads are not measured)
+_KVB_DKV_MXU_HEAD = 256
+#: scoped VMEM asked of the compiler for each blocked kernel
 _KVB_VMEM_LIMIT = 32 * 1024 * 1024
+#: what a pass holds in VMEM, counted in blocks of ``block`` rows:
+#: double-buffered bf16 operand blocks ``(block, dh)`` (forward q, k, v,
+#: o; dk/dv q, k, v, do, dk, dv; dq q, k, v, do, dq), f32 accumulators
+#: ``(block, dh)``, f32 score tiles ``(block, block)`` (forward ``s``,
+#: ``p``; both backward passes ``s``, ``p``, ``dp``, ``ds``) and f32
+#: statistic columns ``(block, 1)``, each padded to the 128 lanes (forward
+#: ``lse`` twice, ``m``, ``l``; dq ``lse`` and ``delta`` twice; dk/dv
+#: reads them as rows, 32 bytes a position, not counted)
+_KVB_HOLDS = {"fwd": (4, 1, 2, 4), "dkv": (6, 2, 4, 0), "dq": (5, 1, 4, 4)}
+#: the three passes, each a kernel of its own with a tile of its own
+_KVB_PASSES = tuple(_KVB_HOLDS)
 #: a visit's flags: the first and the last of its run (the visits that
 #: share the block the kernel accumulates for), and whether the diagonal
 #: cuts the tile (only then is the mask computed)
@@ -315,8 +333,36 @@ _FIRST, _LAST, _CUT = 1, 2, 4
 _MASKED = -1e30
 
 
-def _kvb_block(t: int) -> int:
-    return next((b for b in _KVB_BLOCKS if t % b == 0), 0)
+def _kvb_vmem(pass_: str, block: int, dh: int) -> int:
+    """Bytes of VMEM a pass's working set takes at ``block`` rows of a
+    head ``dh`` wide (:data:`_KVB_HOLDS`): at 1,024 rows of head 128 the
+    forward pass 12.5 MiB, dk/dv 20 MiB, dq 21 MiB; dk/dv at head 512
+    32 MiB, the whole limit."""
+    operands, accumulators, tiles, columns = _KVB_HOLDS[pass_]
+    return block * (2 * 2 * dh * operands + 4 * dh * accumulators +
+                    4 * block * tiles + 4 * 128 * columns)
+
+
+def _kvb_block(t: int, dh: int, pass_: str) -> int:
+    """Rows of the (square) tile a pass runs, by the time axis, the head
+    width and the pass alone: the largest of :data:`_KVB_BLOCKS` that
+    divides ``t`` and whose working set (:func:`_kvb_vmem`) fits
+    :data:`_KVB_VMEM_LIMIT`; 0 when none does.  The softmax and the cost
+    of a visit, not the MXU, set the pace of every pass but one, so the
+    larger tile is the faster (docs/TUNING.md has the sweep, rectangles
+    and 2,048 rows among it: all slower); the one is dk/dv at a wide
+    head (:data:`_KVB_DKV_MXU_HEAD`), which keeps 512."""
+    most = 512 if pass_ == "dkv" and dh >= _KVB_DKV_MXU_HEAD else \
+        _KVB_BLOCKS[0]
+    return next((b for b in _KVB_BLOCKS if b <= most and t % b == 0 and
+                 _kvb_vmem(pass_, b, dh) <= _KVB_VMEM_LIMIT), 0)
+
+
+def kvb_block_rows(t: int, dh: int) -> dict[str, int]:
+    """``{pass: rows of its tile}`` for the shape, 0 in every pass where
+    the shape's form (:func:`form_of`) is not the key/value-blocked one."""
+    blocked = form_of(t, dh)[0] == "blocked"
+    return {p: _kvb_block(t, dh, p) if blocked else 0 for p in _KVB_PASSES}
 
 
 @lru_cache(maxsize=None)
@@ -528,7 +574,7 @@ def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool):
     """-> ``(o, lse)``: ``o`` in the operands' layout (:func:`_kvb_dims`),
     ``lse`` float32 ``(b * h, t, 1)``."""
     bh, t, dh, heads = _kvb_dims(q)
-    block = _kvb_block(t)
+    block = _kvb_block(t, dh, "fwd")
     tables = _visits(t, block, causal, False)
     spec = _kvb_specs(block, dh, heads)
     q3 = _as_rows(q)
@@ -552,12 +598,12 @@ def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool):
 @partial(jax.jit, static_argnames=("causal", "interpret"))
 def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool):
     bh, t, dh, heads = _kvb_dims(q)
-    block = _kvb_block(t)
-    spec = _kvb_specs(block, dh, heads)
     sm_scale = 1.0 / float(np.sqrt(dh))
     q3, k3, v3, do3 = (_as_rows(x) for x in (q, k, v, do))
     delta = _kvb_delta(_as_rows(o), do3, heads)
-    by_kv, by_q = (_visits(t, block, causal, flag) for flag in (True, False))
+    # each pass on the tile that is its own (:func:`_kvb_block`)
+    block = _kvb_block(t, dh, "dkv")
+    spec, by_kv = _kvb_specs(block, dh, heads), _visits(t, block, causal, True)
     dk, dv = pl.pallas_call(
         partial(_kvb_dkv_kernel, sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -573,6 +619,8 @@ def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool):
         interpret=interpret,
     )(*by_kv, q3, k3, v3, do3, lse.reshape(bh, 1, t),
       delta.reshape(bh, 1, t))
+    block = _kvb_block(t, dh, "dq")
+    spec, by_q = _kvb_specs(block, dh, heads), _visits(t, block, causal, False)
     dq = pl.pallas_call(
         partial(_kvb_dq_kernel, sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -609,17 +657,23 @@ _flash_kvb.defvjp(_flash_kvb_fwd, _flash_kvb_bwd)
 
 def blocked_unsupported_reason(t: int, dh: int) -> str | None:
     """Why the key/value-blocked form cannot take the shape, or ``None``:
-    it needs a time axis its smallest block divides and a head its blocks
-    hold (VMEM is ``block * head_dim``-sized whatever ``t``); key/value
-    heads are as many as query heads (the caller repeats a group's)."""
-    if _kvb_block(t) == 0:
+    it needs a time axis its smallest block divides and a head of which
+    every pass holds its largest block (:func:`_kvb_vmem`, the chooser's
+    formula: VMEM is a function of block, head and pass whatever ``t``;
+    dk/dv at head 512 is the limit to the byte, so 512 is the widest);
+    key/value heads are as many as query heads (the caller repeats a
+    group's)."""
+    if t % _KVB_BLOCKS[-1]:
         return (f"t={t} is not a multiple of the {_KVB_BLOCKS[-1]}-row "
                 f"key/value block")
     if dh % 64 != 0:
         return f"head_dim={dh} is not a multiple of 64"
-    if dh > 512:
-        return (f"head_dim={dh}: a {_KVB_BLOCKS[0]}-row block of it passes "
-                f"the blocked kernels' VMEM")
+    top = _KVB_BLOCKS[0]
+    need = max(_kvb_vmem(p, top, dh) for p in _KVB_PASSES)
+    if need > _KVB_VMEM_LIMIT:
+        return (f"head_dim={dh}: a {top}-row block of it needs "
+                f"{need >> 20} MiB of the blocked kernels' "
+                f"{_KVB_VMEM_LIMIT >> 20} MiB of VMEM")
     return None
 
 

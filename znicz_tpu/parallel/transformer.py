@@ -65,15 +65,23 @@ def _report_flash_choice(t: int, dh: int, why: str | None,
     (the dense ``ring_attention`` path that takes over materializes the
     score matrix, which is a different program, not a detail), or the
     layout its kernels read (``attention.direct_layout``: the layer's
-    own, or operands folded head-major around them)."""
+    own, or operands folded head-major around them) and, of the
+    key/value-blocked form, the rows of each pass's tile
+    (``attention.kvb_block_rows``)."""
     if why:
         _log.warning("flash attention refused t=%d head_dim=%d: %s; this "
                      "step uses dense ring_attention", t, dh, why)
-    else:
-        _log.info("flash attention t=%d head_dim=%d: kernels read %s",
-                  t, dh, "the layer's (batch, t, heads x head_dim) layout"
-                  if direct else "operands folded head-major "
-                  "(batch x heads, t, head_dim): eight transposes a layer")
+        return
+    from znicz_tpu.ops.pallas import attention as pattn
+    layout = "the layer's (batch, t, heads x head_dim) layout" if direct \
+        else ("operands folded head-major (batch x heads, t, head_dim): "
+              "eight transposes a layer")
+    tiles = pattn.kvb_block_rows(t, dh)
+    blocked = "; key/value-blocked, tiles of %s rows" % " / ".join(
+        f"{rows} ({name})" for name, rows in tiles.items()) \
+        if any(tiles.values()) else ""
+    _log.info("flash attention t=%d head_dim=%d: kernels read %s%s",
+              t, dh, layout, blocked)
 
 
 def _flash_eligible(mesh: Mesh, interpret: bool) -> bool:
@@ -1252,6 +1260,21 @@ def _vshard_chunk_nll(head_local, axis_name: str = "model"):
 def _n_chunks(loss_chunks: int | None) -> int:
     """``loss_chunks`` as a count of chunks: 1 when unset."""
     return loss_chunks if loss_chunks and loss_chunks > 1 else 1
+
+
+def attn_kvb_block_rows(mesh: Mesh, arch: Arch, t: int) -> dict:
+    """``{pass: rows}`` of the tile each pass of the key/value-blocked
+    flash kernels runs in a step's attention layers at ``t`` positions
+    (``attention.kvb_block_rows``, by ``t``, the head width and the pass),
+    0 in every pass where they run another form or no flash kernel, or the
+    stack has no attention layer: what :func:`_block_attn` will trace,
+    known from the mesh, the architecture and the sequence length."""
+    from znicz_tpu.ops.pallas import attention as pattn
+    rows = pattn.kvb_block_rows(t, arch.head_dim)
+    if {"attention", "latent"} & set(arch.mixers) and \
+            _run_of(mesh, arch, causal=True).use_flash:
+        return rows
+    return dict.fromkeys(rows, 0)
 
 
 def ce_grad_in_forward(arch: Arch, loss_chunks: int | None,

@@ -170,6 +170,12 @@ class TransformerLMStep(AcceleratedUnit):
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
         self.attn_direct_layout_share: Optional[float] = None
+        #: ``{pass: rows}`` of the tile each pass of the key/value-blocked
+        #: flash kernels runs for the step's attention shape (``fwd``,
+        #: ``dkv``, ``dq``; ``parallel/transformer.py::
+        #: attn_kvb_block_rows``), 0 where the attention layers run
+        #: another form; empty until the step is built
+        self.attn_kvb_block_rows: dict = {}
         #: of the train step's head passes, the share that make their
         #: gradients where they make their logits (all or none:
         #: ``parallel/transformer.py::ce_grad_in_forward``); None until
@@ -225,6 +231,8 @@ class TransformerLMStep(AcceleratedUnit):
             head_sharded=self.head_sharded)
         self._publish_ce_rule(float(tfm.ce_grad_in_forward(
             self.arch, self.loss_chunks, self.head_sharded)))
+        self._publish_attn_tiles(tfm.attn_kvb_block_rows(
+            self.mesh, self.arch, int(self.loader.minibatch_data.shape[1])))
         self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
         # the programs from again (as FusedTrainStep's programs)
@@ -365,6 +373,23 @@ class TransformerLMStep(AcceleratedUnit):
             "its head passes (an unchunked or a vocab-sharded one leaves "
             "them to AD)",
             ("unit",)).labels(unit=self.name).set(share)
+
+    def _publish_attn_tiles(self, rows: dict) -> None:
+        """The rows of the tile each pass of the key/value-blocked flash
+        kernels runs for the step's attention shape (a constant of the
+        step as it is built): the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        self.attn_kvb_block_rows = rows
+        gauge = registry.gauge(
+            "znicz_lm_attn_kvb_block_rows",
+            "rows of the (square) tile each pass of the key/value-blocked "
+            "flash kernels runs for the step's attention shape (forward, "
+            "dk/dv, dq), 0 where its attention layers run the whole-row "
+            "form, no flash kernel, or there are none",
+            ("unit", "pass"))
+        for name, value in rows.items():
+            gauge.labels(**{"unit": self.name, "pass": name}).set(value)
 
     def _publish_attn_layout(self, share: float) -> None:
         """Of the attention layers that ran a flash kernel, the share whose
