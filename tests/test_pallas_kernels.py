@@ -410,6 +410,130 @@ def test_blocked_flash_attention_matches_dense(t, dh, causal, h):
         np.testing.assert_allclose(g, w, atol=5e-5)
 
 
+def _random_selection(key, b, t, density=0.3, dead=()):
+    """int8 ``(b, t, t)``: a random subset of the causal pairs, the
+    diagonal always in it, and the ``(rows, columns)`` slices of ``dead``
+    emptied below the diagonal."""
+    import jax
+
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    sel = (jax.random.uniform(key, (b, t, t)) < density) & causal
+    for rows, cols in dead:
+        sel = sel.at[:, rows, cols].set(False)
+    return (sel | jnp.eye(t, dtype=bool)).astype(jnp.int8)
+
+
+def _dense_selected(q, k, v, sel):
+    import jax
+
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    a = jax.nn.softmax(jnp.where(sel[:, None] != 0, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", a, v)
+
+
+@pytest.mark.parametrize("b,t,h,kv,dh", [
+    (2, 256, 4, 2, 64), (1, 384, 2, 2, 128), (1, 256, 4, 1, 128),
+    (1, 640, 2, 2, 64)],
+    ids=["folded_grouped_dh64", "direct_3x128_dh128",
+         "direct_grouped_dh128", "folded_5x128_dh64"])
+def test_blocked_flash_attention_with_a_selection_matches_dense(b, t, h, kv,
+                                                                dh):
+    """The blocked kernels with a selection operand (interpreted), forward
+    and the three gradients, against dense attention masked by the same
+    selection: folded and the layer's own layout, grouped key/value heads
+    in both (in the layer's layout the group is repeated inside the rule
+    and dk, dv come back a key/value head each), a time axis of 3 and 5
+    blocks, rows whose first tiles hold none of their keys, and a tile
+    below the diagonal that holds no selected pair at all."""
+    import jax
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    ks = jax.random.split(jax.random.PRNGKey(t + dh + kv), 5)
+    q = jax.random.normal(ks[0], (b, t, h, dh))
+    k, v = (jax.random.normal(kk, (b, t, kv, dh)) for kk in ks[1:3])
+    ct = jax.random.normal(ks[3], (b, t, h, dh))
+    # rows from 200 on see nothing of the first 128 keys; rows 128-255 see
+    # nothing of keys 0-127 at all: tile (1, 0) is empty
+    sel = _random_selection(ks[4], b, t, dead=(
+        (slice(200, None), slice(0, 128)), (slice(128, 256), slice(0, 128))))
+    assert pattn.blocked_unsupported_reason(t, dh) is None
+    block = pattn._kvb_block(t, dh, "fwd", True)
+    assert block == (128 if t % 256 else 256)
+
+    def blocked(q, k, v):
+        return pattn.flash_attention(q, k, v, True, interpret=True, sel=sel)
+
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: blocked(*a).sum(), (0, 1, 2)))(q, k, v))
+    for name in pattn.KVB_SEL_KERNEL_NAMES.values():
+        assert name in text
+    assert f"{pattn.KVB_FWD_KERNEL_NAME} " not in text
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(blocked(q, k, v),
+                                   _dense_selected(q, k, v, sel), atol=2e-5)
+        got = jax.grad(lambda *a: (blocked(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+        want = jax.grad(lambda *a: (_dense_selected(*a, sel) * ct).sum(),
+                        (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+def test_a_causal_selection_is_the_causal_kernels_result():
+    """With every causal pair selected the kernels with a selection give
+    what the causal kernels give, forward and backward."""
+    from unittest import mock
+
+    import jax
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    b, t, h, dh = 1, 256, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q, k, v, ct = (jax.random.normal(kk, (b, t, h, dh)) for kk in ks)
+    sel = jnp.tril(jnp.ones((b, t, t), jnp.int8))
+    with mock.patch.object(pattn, "unsupported_reason",
+                           lambda t, dh: "refused for the test"):
+        plain = lambda *a: pattn.flash_attention(            # noqa: E731
+            *a, True, interpret=True)
+        picked = lambda *a: pattn.flash_attention(           # noqa: E731
+            *a, True, interpret=True, sel=sel)
+        np.testing.assert_allclose(picked(q, k, v), plain(q, k, v),
+                                   atol=1e-6)
+        got = jax.grad(lambda *a: (picked(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+        want = jax.grad(lambda *a: (plain(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+def test_a_selection_needs_the_blocked_form_and_a_causal_call():
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    q = jnp.zeros((1, 192, 2, 64))
+    with pytest.raises(ValueError, match="selection.*128-row"):
+        pattn.flash_attention(q, q, q, True, interpret=True,
+                              sel=jnp.ones((1, 192, 192), jnp.int8))
+    q = jnp.zeros((1, 256, 2, 64))
+    with pytest.raises(ValueError, match="causal=True"):
+        pattn.flash_attention(q, q, q, False, interpret=True,
+                              sel=jnp.ones((1, 256, 256), jnp.int8))
+    # the selection's tile is in the working set the tile is chosen by:
+    # 6 bytes an entry, and 1,024 rows still fit at a head of 128
+    for pass_ in ("fwd", "dkv", "dq"):
+        assert pattn._kvb_vmem(pass_, 1024, 128, True) - \
+            pattn._kvb_vmem(pass_, 1024, 128) == 6 * 1024 * 1024
+        assert pattn._kvb_block(16384, 128, pass_, True) == 1024
+    assert pattn.kvb_block_rows(4096, 64) == {"fwd": 0, "dkv": 0, "dq": 0}
+    assert pattn.kvb_block_rows(4096, 64, True) == {
+        "fwd": 1024, "dkv": 1024, "dq": 1024}
+
+
 @pytest.mark.parametrize("pass_", ["fwd", "dkv", "dq"])
 @pytest.mark.parametrize("dh", [64, 128, 256, 512])
 @pytest.mark.parametrize("t", [256, 384, 640, 768, 1024, 3072, 4096])
@@ -494,7 +618,7 @@ def test_blocked_flash_attention_at_1024_row_tiles_matches_dense(
         return att.attention(jnp, q, k, v, causal=causal)
 
     patched = mock.patch.object(
-        pattn, "_kvb_block", lambda t, dh, pass_: blocks[pass_]) \
+        pattn, "_kvb_block", lambda t, dh, pass_, sel=False: blocks[pass_]) \
         if blocks else contextlib.nullcontext()
     if not blocks:
         assert pattn.kvb_block_rows(8192 + t, dh) == {

@@ -63,6 +63,21 @@ product's result, a row kernel's) and lays them out time-minor, with a
 copy before the kernels, when a head is cut and concatenated at a column
 that is no multiple of 128 (``parallel/transformer.py::_latent_qkv`` has
 the forms that keep it).
+
+A SELECTION (``flash_attention(..., sel=)``: int8 ``(b, t, t)``, one entry a
+(query, key) pair, nonzero where the query attends to the key, shared by
+all heads; learned sparse attention's per-query choice of keys) reaches the
+blocked kernels as one more operand: a ``(block, block)`` tile at (query
+block, key/value block) beside q, k and v, in all three passes (the dk/dv
+pass, which holds everything transposed, reads the caller's transposed
+copy).  A masked-out score is ``-1e30`` as the diagonal's are; the selection
+holds the causal cut itself, so the kernels with a selection compute no
+mask of their own, and their visit tables stay the causal ones (a tile
+below the diagonal in which no pair is selected is still visited).  It is a
+static switch: a call without a selection traces the kernels it always
+traced, under the names they always had; with one the three kernels are
+named ``flash_attention_kvb_sel_*``.  Nothing here holds a ``(heads, t,
+t)`` array.
 """
 
 from __future__ import annotations
@@ -301,6 +316,10 @@ flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 KVB_FWD_KERNEL_NAME = "flash_attention_kvb_fwd"
 KVB_DKV_KERNEL_NAME = "flash_attention_kvb_dkv"
 KVB_DQ_KERNEL_NAME = "flash_attention_kvb_dq"
+#: the same three passes with a selection tile among their operands
+KVB_SEL_KERNEL_NAMES = {"fwd": "flash_attention_kvb_sel_fwd",
+                        "dkv": "flash_attention_kvb_sel_dkv",
+                        "dq": "flash_attention_kvb_sel_dq"}
 
 #: rows of a q block and of a key/value block a pass may take, largest
 #: first (:func:`_kvb_block` chooses)
@@ -333,17 +352,20 @@ _FIRST, _LAST, _CUT = 1, 2, 4
 _MASKED = -1e30
 
 
-def _kvb_vmem(pass_: str, block: int, dh: int) -> int:
+def _kvb_vmem(pass_: str, block: int, dh: int, sel: bool = False) -> int:
     """Bytes of VMEM a pass's working set takes at ``block`` rows of a
     head ``dh`` wide (:data:`_KVB_HOLDS`): at 1,024 rows of head 128 the
     forward pass 12.5 MiB, dk/dv 20 MiB, dq 21 MiB; dk/dv at head 512
-    32 MiB, the whole limit."""
+    32 MiB, the whole limit.  ``sel``: with a selection tile among the
+    operands, int8 ``(block, block)`` double-buffered and once more
+    widened for the compare (6 bytes an entry: 6 MiB at 1,024 rows)."""
     operands, accumulators, tiles, columns = _KVB_HOLDS[pass_]
     return block * (2 * 2 * dh * operands + 4 * dh * accumulators +
-                    4 * block * tiles + 4 * 128 * columns)
+                    4 * block * tiles + 4 * 128 * columns +
+                    6 * block * sel)
 
 
-def _kvb_block(t: int, dh: int, pass_: str) -> int:
+def _kvb_block(t: int, dh: int, pass_: str, sel: bool = False) -> int:
     """Rows of the (square) tile a pass runs, by the time axis, the head
     width and the pass alone: the largest of :data:`_KVB_BLOCKS` that
     divides ``t`` and whose working set (:func:`_kvb_vmem`) fits
@@ -355,14 +377,17 @@ def _kvb_block(t: int, dh: int, pass_: str) -> int:
     most = 512 if pass_ == "dkv" and dh >= _KVB_DKV_MXU_HEAD else \
         _KVB_BLOCKS[0]
     return next((b for b in _KVB_BLOCKS if b <= most and t % b == 0 and
-                 _kvb_vmem(pass_, b, dh) <= _KVB_VMEM_LIMIT), 0)
+                 _kvb_vmem(pass_, b, dh, sel) <= _KVB_VMEM_LIMIT), 0)
 
 
-def kvb_block_rows(t: int, dh: int) -> dict[str, int]:
+def kvb_block_rows(t: int, dh: int, sel: bool = False) -> dict[str, int]:
     """``{pass: rows of its tile}`` for the shape, 0 in every pass where
-    the shape's form (:func:`form_of`) is not the key/value-blocked one."""
-    blocked = form_of(t, dh)[0] == "blocked"
-    return {p: _kvb_block(t, dh, p) if blocked else 0 for p in _KVB_PASSES}
+    the shape's form (:func:`form_of`; with a selection always the
+    blocked one, :func:`flash_attention`) is not the key/value-blocked
+    one."""
+    blocked = sel or form_of(t, dh)[0] == "blocked"
+    return {p: _kvb_block(t, dh, p, sel) if blocked else 0
+            for p in _KVB_PASSES}
 
 
 @lru_cache(maxsize=None)
@@ -411,8 +436,26 @@ def _cut_scores(s, rows_are_q: bool):
     return jnp.where(kpos > qpos, jnp.float32(_MASKED), s)
 
 
-def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
-                    lse_ref, m_sc, l_sc, acc_sc, *, sm_scale: float):
+def _selected_scores(s, sel_ref):
+    """The tile's scores where the selection tile is nonzero, ``-1e30``
+    elsewhere (the causal cut is the selection's own)."""
+    return jnp.where(sel_ref[0].astype(jnp.int32) != 0, s,
+                     jnp.float32(_MASKED))
+
+
+def _tile_of(flags, tile, sel_ref):
+    """Run ``tile`` once: with a selection as it stands (the tile masks by
+    its selection operand), else by :func:`_either_tile`."""
+    if sel_ref is None:
+        _either_tile(flags, tile)
+    else:
+        tile(False)
+
+
+def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
+                    sm_scale: float, sel: bool = False):
+    sel_ref = rest[0] if sel else None
+    o_ref, lse_ref, m_sc, l_sc, acc_sc = rest[sel:]
     flags = fl_ref[pl.program_id(1)]
 
     @pl.when((flags & _FIRST) != 0)
@@ -426,9 +469,14 @@ def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
         s = _nt(q_ref[0], k_ref[0]) * sm_scale             # (bq, bk)
         if cut:
             s = _cut_scores(s, True)
+        if sel:
+            s = _selected_scores(s, sel_ref)
         m_prev = m_sc[...]
         # key/value block 0 comes first and every query sees key 0, so
-        # the running maximum is a real score from the first visit on
+        # the running maximum is a real score from the first visit on.
+        # (Under a selection a query's first tiles may hold none of its
+        # keys: p is then 1 everywhere and what l and acc gather is wiped
+        # by alpha = 0 at its first selected key, which every query has.)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -437,7 +485,7 @@ def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
-    _either_tile(flags, tile)
+    _tile_of(flags, tile, sel_ref)
 
     @pl.when((flags & _LAST) != 0)
     def _store():
@@ -447,12 +495,15 @@ def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *,
-                    sm_scale: float):
+                    lse_ref, delta_ref, *rest, sm_scale: float,
+                    sel: bool = False):
     """dk and dv of one key/value block, summed over the q blocks that see
     it.  Everything is held TRANSPOSED (key/value rows down, queries
-    across; ``lse`` and ``delta`` arrive as rows), so that all four
-    products are plain or ``a @ b.T`` and no score tile is transposed."""
+    across; ``lse`` and ``delta`` arrive as rows, a selection as the tile
+    of its transpose), so that all four products are plain or ``a @ b.T``
+    and no score tile is transposed."""
+    sel_ref = rest[0] if sel else None
+    dk_ref, dv_ref, dk_sc, dv_sc = rest[sel:]
     flags = fl_ref[pl.program_id(1)]
 
     @pl.when((flags & _FIRST) != 0)
@@ -465,6 +516,8 @@ def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         s = _nt(k_ref[0], q) * sm_scale                    # (bk, bq)
         if cut:
             s = _cut_scores(s, False)
+        if sel:
+            s = _selected_scores(s, sel_ref)
         p = jnp.exp(s - lse_ref[0])
         dv_sc[...] += jnp.dot(p.astype(do.dtype), do,
                               preferred_element_type=jnp.float32)
@@ -472,7 +525,7 @@ def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         dk_sc[...] += jnp.dot(ds.astype(q.dtype), q,
                               preferred_element_type=jnp.float32)
 
-    _either_tile(flags, tile)
+    _tile_of(flags, tile, sel_ref)
 
     @pl.when((flags & _LAST) != 0)
     def _store():
@@ -481,7 +534,10 @@ def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _kvb_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, dq_sc, *, sm_scale: float):
+                   lse_ref, delta_ref, *rest, sm_scale: float,
+                   sel: bool = False):
+    sel_ref = rest[0] if sel else None
+    dq_ref, dq_sc = rest[sel:]
     flags = fl_ref[pl.program_id(1)]
 
     @pl.when((flags & _FIRST) != 0)
@@ -493,27 +549,38 @@ def _kvb_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         s = _nt(q_ref[0], k) * sm_scale                    # (bq, bk)
         if cut:
             s = _cut_scores(s, True)
+        if sel:
+            s = _selected_scores(s, sel_ref)
         p = jnp.exp(s - lse_ref[0])
         ds = p * (_nt(do_ref[0], v_ref[0]) - delta_ref[0]) * sm_scale
         dq_sc[...] += jnp.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32)
 
-    _either_tile(flags, tile)
+    _tile_of(flags, tile, sel_ref)
 
     @pl.when((flags & _LAST) != 0)
     def _store():
         dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
 
-def _kvb_specs(block: int, dh: int, heads: int):
+def _kvb_specs(block: int, dh: int, heads: int, sel_heads: int = 0):
     """BlockSpecs over the visit tables: a q-side block, a key/value-side
     block, and the float32 row statistics as a column or as a row.  Grid
     row ``i`` is (batch ``i // heads``, head ``i % heads``).  ``heads ==
     0``: the operands are folded ``(b * h, t, dh)`` and row ``i`` is
     theirs; else they are the layer's ``(b, t, heads * dh)`` and a block
     is head ``i % heads``'s ``dh`` columns of batch ``i // heads``'s rows.
-    The statistics are ``(b * h, t, 1)`` or ``(b * h, 1, t)`` either way."""
+    The statistics are ``(b * h, t, 1)`` or ``(b * h, 1, t)`` either way.
+    ``sel_heads`` (the query heads a batch row has; 0: no selection) adds
+    the selection's tile ``(b, t, t)`` at (batch ``i // sel_heads``, q
+    block, key/value block) as ``sel``, and as ``selT`` the tile at (key/
+    value block, q block) of its transpose."""
     vm = pltpu.VMEM
+    tile = {} if not sel_heads else {
+        "sel": pl.BlockSpec((1, block, block), lambda i, v, qi, ki, fl:
+                            (i // sel_heads, qi[v], ki[v]), memory_space=vm),
+        "selT": pl.BlockSpec((1, block, block), lambda i, v, qi, ki, fl:
+                             (i // sel_heads, ki[v], qi[v]), memory_space=vm)}
     if heads:
         q = lambda i, v, qi, ki, fl: (i // heads, qi[v], i % heads)  # noqa: E731
         kv = lambda i, v, qi, ki, fl: (i // heads, ki[v], i % heads)  # noqa: E731
@@ -527,6 +594,7 @@ def _kvb_specs(block: int, dh: int, heads: int):
                             (i, qi[v], 0), memory_space=vm),
         "row": pl.BlockSpec((1, 1, block), lambda i, v, qi, ki, fl:
                             (i, 0, qi[v]), memory_space=vm),
+        **tile,
     }
 
 
@@ -570,69 +638,87 @@ def _kvb_params():
 # Jitted, so that a program's layers share one trace and one lowering of
 # each kernel (as ops/pallas/grouped.py's)
 @partial(jax.jit, static_argnames=("causal", "interpret"))
-def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool):
+def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool, sel=None):
     """-> ``(o, lse)``: ``o`` in the operands' layout (:func:`_kvb_dims`),
-    ``lse`` float32 ``(b * h, t, 1)``."""
+    ``lse`` float32 ``(b * h, t, 1)``.  ``sel``: the selection ``(b, t,
+    t)`` int8, or None."""
     bh, t, dh, heads = _kvb_dims(q)
-    block = _kvb_block(t, dh, "fwd")
+    masked = sel is not None
+    block = _kvb_block(t, dh, "fwd", masked)
     tables = _visits(t, block, causal, False)
-    spec = _kvb_specs(block, dh, heads)
+    spec = _kvb_specs(block, dh, heads, bh // sel.shape[0] if masked else 0)
     q3 = _as_rows(q)
     o, lse = pl.pallas_call(
-        partial(_kvb_fwd_kernel, sm_scale=1.0 / float(np.sqrt(dh))),
+        partial(_kvb_fwd_kernel, sm_scale=1.0 / float(np.sqrt(dh)),
+                sel=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(tables[0])),
-            in_specs=[spec["q"], spec["kv"], spec["kv"]],
+            in_specs=[spec["q"], spec["kv"], spec["kv"]] +
+            ([spec["sel"]] if masked else []),
             out_specs=[spec["q"], spec["col"]],
             scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
                             pltpu.VMEM((block, 1), jnp.float32),
                             pltpu.VMEM((block, dh), jnp.float32)]),
         out_shape=[_out_struct(q3.shape, q.dtype, q),
                    _out_struct((bh, t, 1), jnp.float32, q)],
-        compiler_params=_kvb_params(), name=KVB_FWD_KERNEL_NAME,
+        compiler_params=_kvb_params(),
+        name=KVB_SEL_KERNEL_NAMES["fwd"] if masked else KVB_FWD_KERNEL_NAME,
         interpret=interpret,
-    )(*tables, q3, _as_rows(k), _as_rows(v))
+    )(*tables, q3, _as_rows(k), _as_rows(v), *([sel] if masked else []))
     return o.reshape(q.shape), lse
 
 
 @partial(jax.jit, static_argnames=("causal", "interpret"))
-def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool):
+def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
+                  sel=None):
     bh, t, dh, heads = _kvb_dims(q)
     sm_scale = 1.0 / float(np.sqrt(dh))
+    masked = sel is not None
+    sel_heads = bh // sel.shape[0] if masked else 0
     q3, k3, v3, do3 = (_as_rows(x) for x in (q, k, v, do))
     delta = _kvb_delta(_as_rows(o), do3, heads)
     # each pass on the tile that is its own (:func:`_kvb_block`)
-    block = _kvb_block(t, dh, "dkv")
-    spec, by_kv = _kvb_specs(block, dh, heads), _visits(t, block, causal, True)
+    block = _kvb_block(t, dh, "dkv", masked)
+    spec = _kvb_specs(block, dh, heads, sel_heads)
+    by_kv = _visits(t, block, causal, True)
     dk, dv = pl.pallas_call(
-        partial(_kvb_dkv_kernel, sm_scale=sm_scale),
+        partial(_kvb_dkv_kernel, sm_scale=sm_scale, sel=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(by_kv[0])),
             in_specs=[spec["q"], spec["kv"], spec["kv"], spec["q"],
-                      spec["row"], spec["row"]],
+                      spec["row"], spec["row"]] +
+            ([spec["selT"]] if masked else []),
             out_specs=[spec["kv"], spec["kv"]],
             scratch_shapes=[pltpu.VMEM((block, dh), jnp.float32),
                             pltpu.VMEM((block, dh), jnp.float32)]),
         out_shape=[_out_struct(k3.shape, k.dtype, q),
                    _out_struct(v3.shape, v.dtype, q)],
-        compiler_params=_kvb_params(), name=KVB_DKV_KERNEL_NAME,
+        compiler_params=_kvb_params(),
+        name=KVB_SEL_KERNEL_NAMES["dkv"] if masked else KVB_DKV_KERNEL_NAME,
         interpret=interpret,
     )(*by_kv, q3, k3, v3, do3, lse.reshape(bh, 1, t),
-      delta.reshape(bh, 1, t))
-    block = _kvb_block(t, dh, "dq")
-    spec, by_q = _kvb_specs(block, dh, heads), _visits(t, block, causal, False)
+      delta.reshape(bh, 1, t),
+      # the pass holds its tiles transposed: the one transpose of the
+      # selection, int8, made here and dropped after the pass
+      *([sel.transpose(0, 2, 1)] if masked else []))
+    block = _kvb_block(t, dh, "dq", masked)
+    spec = _kvb_specs(block, dh, heads, sel_heads)
+    by_q = _visits(t, block, causal, False)
     dq = pl.pallas_call(
-        partial(_kvb_dq_kernel, sm_scale=sm_scale),
+        partial(_kvb_dq_kernel, sm_scale=sm_scale, sel=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(by_q[0])),
             in_specs=[spec["q"], spec["kv"], spec["kv"], spec["q"],
-                      spec["col"], spec["col"]],
+                      spec["col"], spec["col"]] +
+            ([spec["sel"]] if masked else []),
             out_specs=spec["q"],
             scratch_shapes=[pltpu.VMEM((block, dh), jnp.float32)]),
         out_shape=_out_struct(q3.shape, q.dtype, q),
-        compiler_params=_kvb_params(), name=KVB_DQ_KERNEL_NAME,
+        compiler_params=_kvb_params(),
+        name=KVB_SEL_KERNEL_NAMES["dq"] if masked else KVB_DQ_KERNEL_NAME,
         interpret=interpret,
-    )(*by_q, q3, k3, v3, do3, lse, delta.reshape(bh, t, 1))
+    )(*by_q, q3, k3, v3, do3, lse, delta.reshape(bh, t, 1),
+      *([sel] if masked else []))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -653,6 +739,47 @@ def _flash_kvb_bwd(causal, interpret, res, do):
 
 
 _flash_kvb.defvjp(_flash_kvb_fwd, _flash_kvb_bwd)
+
+
+def _repeat_group(k, v, heads: int):
+    """``k`` and ``v`` ``(b, t, kv, dh)`` with each key/value head repeated
+    for its group of ``heads / kv`` query heads; folded operands (one row a
+    query head already) as they are."""
+    group = heads // k.shape[2] if k.ndim == 4 else 1
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash_kvb_sel(q, k, v, sel, interpret: bool):
+    """:func:`_flash_kvb` under a selection (causal: the selection's cut
+    is the diagonal's or tighter); ``sel`` takes no gradient.  In the
+    layer's layout ``k`` and ``v`` come with their own (fewer) heads: the
+    group is repeated for the kernels here, in both passes, and what is
+    kept for the backward pass is the unrepeated pair."""
+    return _kvb_call_fwd(q, *_repeat_group(k, v, q.shape[2]), True,
+                         interpret, sel)[0]
+
+
+def _flash_kvb_sel_fwd(q, k, v, sel, interpret):
+    o, lse = _kvb_call_fwd(q, *_repeat_group(k, v, q.shape[2]), True,
+                           interpret, sel)
+    return o, (q, k, v, o, lse, sel)
+
+
+def _flash_kvb_sel_bwd(interpret, res, do):
+    q, k, v, o, lse, sel = res
+    dq, dk, dv = _kvb_call_bwd(q, *_repeat_group(k, v, q.shape[2]), o, lse,
+                               do, True, interpret, sel)
+    if dk.shape != k.shape:          # a group's heads sum into their own
+        b, t, kv, dh = k.shape
+        dk, dv = (g.reshape(b, t, kv, -1, dh).sum(3, dtype=jnp.float32)
+                  .astype(g.dtype) for g in (dk, dv))
+    return dq, dk, dv, None
+
+
+_flash_kvb_sel.defvjp(_flash_kvb_sel_fwd, _flash_kvb_sel_bwd)
 
 
 def blocked_unsupported_reason(t: int, dh: int) -> str | None:
@@ -730,7 +857,7 @@ def direct_layout(t: int, dh: int) -> bool:
 
 
 def flash_attention(q, k, v, causal: bool = False, *,
-                    interpret: bool = False):
+                    interpret: bool = False, sel=None):
     """Fused attention over per-head tensors ``(b, t, h, dh)`` — same
     contract as ops.attention.attention (``softmax(q·kᵀ/√dh)·v``),
     differentiable via the flash backward kernels, in the form
@@ -738,9 +865,22 @@ def flash_attention(q, k, v, causal: bool = False, *,
     gives it.  ``k`` and ``v`` may carry fewer heads (``h`` a multiple of
     theirs): grouped-query attention, query head ``j`` reading key/value
     head ``j // group`` (the whole-row form through its index maps, the
-    blocked form over repeated heads)."""
+    blocked form over repeated heads).  ``sel`` (int8 ``(b, t, t)``,
+    nonzero where a query attends to a key, at most the causal triangle,
+    every query with a key of its own; no gradient) restricts each
+    query's softmax to its selected keys, for all heads alike: always in
+    the blocked form (the whole-row kernels take no selection, so
+    :func:`blocked_unsupported_reason` alone decides), ``causal``
+    required."""
     b, t, h, dh = q.shape
-    form, why = form_of(t, dh)
+    if sel is not None:
+        why = blocked_unsupported_reason(t, dh)
+        if why or not causal:
+            raise ValueError(f"flash_attention with a selection: "
+                             f"{why or 'causal=True is required'}")
+        form = "blocked"
+    else:
+        form, why = form_of(t, dh)
     if form is None:
         raise ValueError(
             f"flash_attention cannot take this shape: {why} — gate call "
@@ -751,10 +891,16 @@ def flash_attention(q, k, v, causal: bool = False, *,
     if form == "rows":
         o = _flash(fold(q), fold(k), fold(v), causal, interpret)
     else:
+        direct = dh % 128 == 0     # as direct_layout, the form known
+        if sel is not None and direct:
+            return _flash_kvb_sel(q, k, v, sel, interpret)
         group = h // k.shape[2]
         if group > 1:
             k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-        if direct_layout(t, dh):
+        if sel is not None:
+            o = _flash_kvb_sel(fold(q), fold(k), fold(v), sel, interpret)
+        elif direct:
             return _flash_kvb(q, k, v, causal, interpret)
-        o = _flash_kvb(fold(q), fold(k), fold(v), causal, interpret)
+        else:
+            o = _flash_kvb(fold(q), fold(k), fold(v), causal, interpret)
     return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)

@@ -34,7 +34,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from znicz_tpu.parallel.compat import quantized_psum, shard_map
 
 from znicz_tpu.observe import probe as _probe
-from znicz_tpu.parallel import qcomm
+from znicz_tpu.parallel import dsa, qcomm
 from znicz_tpu.parallel.moe import (MEAN_STATS, load_balance_aux, moe_ffn,
                                     moe_routed_ffn, router_z_loss)
 from znicz_tpu.parallel.pipeline import pipeline_apply
@@ -173,6 +173,14 @@ class Arch:
     output, the gates make a distribution over the loop steps token by
     token and the loss is the steps' cross-entropies weighted by it, less
     ``exit_beta`` times its entropy (:func:`_forward_loop_ce`).
+    ``index_top_k`` puts an indexer on every attention layer (learned
+    sparse attention, DeepSeek-V3.2-Exp's: ``index_heads`` index query
+    heads of ``index_dim`` on one index key head read a DETACHED copy of
+    the layer's normed input; a query attends to the ``index_top_k`` keys
+    of largest index score, one set for all heads; the alignment term,
+    the KL from the heads' mean attention probabilities to the softmax of
+    the index scores over the selection, joins the loss summed over the
+    layers and trains the indexer alone: ``parallel/dsa.py``).
 
     Built by :func:`gpt_arch` (the block this module always had: the
     four integers) or :func:`arch_from_config` (a model's own keys)."""
@@ -212,6 +220,9 @@ class Arch:
     sandwich: bool = False
     loop_steps: int = 1
     exit_beta: float = 0.0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_top_k: int = 0
 
     def __post_init__(self):
         if self.sandwich and not (set(self.mixers) <= {"attention", "latent"}
@@ -220,6 +231,13 @@ class Arch:
                              "attention and SwiGLU sub-layers")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps {self.loop_steps}: at least 1")
+        if self.index_top_k and (set(self.mixers) != {"attention"} or
+                                 self.loop_steps > 1 or self.mtp or
+                                 self.rope_theta is None):
+            raise ValueError("index_top_k: the indexer is written for an "
+                             "unlooped stack of plain or grouped-query "
+                             "attention layers with a rotary embedding and "
+                             "no MTP module")
         if self.loop_steps > 1 and (self.mtp or not self.final_norm or
                                     "moe_routed" in self.ffns):
             raise ValueError("a looped stack is written with the final "
@@ -262,6 +280,8 @@ class Arch:
             out.append("QK-norm")
         if self.rope_theta is not None:
             out.append("rotary embedding")
+        if self.index_top_k:
+            out.append("learned sparse attention (indexer)")
         if "glu" in self.ffns:
             out.append("SwiGLU")
         if "moe_routed" in self.ffns:
@@ -456,16 +476,84 @@ def _ouro_arch(cfg, vocab: int | None) -> Arch:
         exit_beta=float(cfg.get("exit_entropy_weight", 0.1)))
 
 
+def _keye_vl2_arch(cfg, vocab: int | None) -> Arch:
+    """``KeyeVL2`` (the language model of Keye-VL-2.0: a Qwen3-MoE-shaped
+    decoder, ``num_experts``, ``num_experts_per_tok``,
+    ``moe_intermediate_size``, ``norm_topk_prob``, ``decoder_sparse_step``,
+    ``mlp_only_layers``, with ``sa_config``, a DeepSeek-Sparse-Attention
+    indexer on every attention layer): RMSNorm, grouped-query attention
+    with QK-norm and rotate-half RoPE, ``sa_config.indexer_num_heads``
+    index heads of ``indexer_head_dim`` on one index key head picking
+    ``sa_config.topk`` keys a query, softmax-routed SwiGLU experts in
+    every layer (no shared expert, no dense layer, no bias), a final norm,
+    an untied head.  ``router_width`` gives the router's published width
+    where ``num_experts`` counts the experts held here.
+    ``rope_scaling.mrope_section`` splits the rotary frequencies over
+    three position streams; a step takes text tokens only, whose three
+    streams are one, so the rotation is :func:`_rotate`'s: the sections
+    are checked against the head and otherwise not read.  Refused: a
+    sliding window, ``mlp_only_layers``, a ``decoder_sparse_step`` other
+    than 1, an attention bias, index key heads other than one;
+    ``sa_config``'s chunk sizes change no value and are not read."""
+    if cfg.get("use_sliding_window", False) or cfg.get("sliding_window"):
+        raise ValueError("sliding_window: attention here is over the "
+                         "indexer's selection of the whole sequence")
+    if cfg.get("mlp_only_layers"):
+        raise ValueError(f"mlp_only_layers {cfg['mlp_only_layers']}: every "
+                         f"layer routed is what is written")
+    if int(cfg.get("decoder_sparse_step", 1)) != 1:
+        raise ValueError(f"decoder_sparse_step "
+                         f"{cfg['decoder_sparse_step']}: 1 (every layer "
+                         f"routed) is what is written")
+    if cfg.get("attention_bias", False):
+        raise ValueError("attention_bias: the projections here have none")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {cfg['hidden_act']!r}: SwiGLU")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    hd = int(cfg.get("head_dim") or d // heads)
+    scaling = cfg.get("rope_scaling") or {}
+    if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+        raise ValueError(f"rope_scaling {scaling}: the rotary embedding is "
+                         f"unscaled")
+    sections = scaling.get("mrope_section")
+    if sections is not None and 2 * sum(int(n) for n in sections) != hd:
+        raise ValueError(f"mrope_section {sections} does not sum to half "
+                         f"the head ({hd} / 2)")
+    sa = cfg["sa_config"]
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError(f"sa_config.indexer_num_kv_heads "
+                         f"{sa['indexer_num_kv_heads']}: one index key head "
+                         f"is what is written")
+    layers = int(cfg["num_hidden_layers"])
+    n_experts = int(cfg.get("router_width", cfg["num_experts"]))
+    first, count = _experts_held(cfg, n_experts)
+    return Arch(
+        d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=hd, ff=int(cfg.get("intermediate_size", 0)),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=("attention",) * layers, ffns=("moe_routed",) * layers,
+        norm="rms", eps=float(cfg.get("rms_norm_eps", 1e-6)), qk_norm=True,
+        rope_theta=float(cfg.get("rope_theta", 1e7)), n_experts=n_experts,
+        experts_first=first, experts_held=count,
+        top_k=int(cfg["num_experts_per_tok"]),
+        moe_ff=int(cfg["moe_intermediate_size"]), score="softmax",
+        norm_topk=bool(cfg.get("norm_topk_prob", True)), final_norm=True,
+        tied=bool(cfg.get("tie_word_embeddings", False)),
+        index_heads=int(sa["indexer_num_heads"]),
+        index_dim=int(sa["indexer_head_dim"]), index_top_k=int(sa["topk"]))
+
+
 #: ``model_type`` -> the reader of that family's keys
 _FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch,
-             "ouro": _ouro_arch}
+             "ouro": _ouro_arch, "KeyeVL2": _keye_vl2_arch}
 
 
 def arch_from_config(cfg, vocab: int | None = None) -> Arch:
     """A model's own keys -> :class:`Arch`, by ``model_type``
     (:data:`_FAMILIES`: :func:`_lfm2_moe_arch`, also what a mapping with
     ``layer_types`` and no ``model_type`` is read as,
-    :func:`_glm4_moe_lite_arch` and :func:`_ouro_arch`).  ``experts_held`` (``{"first",
+    :func:`_glm4_moe_lite_arch`, :func:`_ouro_arch` and
+    :func:`_keye_vl2_arch`).  ``experts_held`` (``{"first",
     "count"}``; all by default) is this chip's share of the experts;
     ``vocab`` (the loader's) overrides ``vocab_size``.  Any other
     ``model_type`` is refused by name."""
@@ -495,6 +583,7 @@ _LEAF_MECHANISMS = {
     "w3": "SwiGLU", "ew3": "routed experts (moe_routed_ffn)",
     "wkv_a": "latent attention", "sw1": "shared expert",
     "ln1o_g": "sandwich norm",
+    "wiq": "learned sparse attention (indexer)",
 }
 
 
@@ -546,6 +635,10 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
                     "wv": (d, arch.kv_heads * hd), "wo": (arch.heads * hd, d)})
         if arch.qk_norm:
             out.update({"q_g": (hd,), "k_g": (hd,)})
+        if arch.index_top_k:
+            hi, di = arch.index_heads, arch.index_dim
+            out.update({"wiq": (d, hi * di), "wik": (d, di), "wiw": (d, hi),
+                        "ik_g": (di,), "ik_b": (di,)})
     else:
         out.update({"w_in": (d, 3 * d), "conv_k": (arch.conv_taps, d),
                     "w_out": (d, d)})
@@ -595,8 +688,9 @@ def _mtp_shapes(arch: Arch) -> dict:
 
 #: leaves that start at one (gains), and those that start at zero
 _ONES = ("ln1_g", "ln2_g", "ln1o_g", "ln2o_g", "q_g", "k_g", "norm_g",
-         "q_a_g", "kv_a_g", "enorm_g", "hnorm_g")
-_ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias", "exit_b")
+         "q_a_g", "kv_a_g", "enorm_g", "hnorm_g", "ik_g")
+_ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias", "exit_b",
+          "ik_b")
 #: how each leaf of the GPT-shaped block lies over the ``model`` axis
 _TP_SPECS = {
     "wq": P(None, "model"), "wk": P(None, "model"), "wv": P(None, "model"),
@@ -830,9 +924,10 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     the residual stream and adding to it.  -> ``(x, aux, stats)``: the
     regularizer term (pre-weighted) and the layer's counters (the routed
     layer's, and :func:`_block_attn`'s of a layer that ran a flash
-    kernel).
+    kernel or has an indexer, whose alignment term ``aux`` carries).
     Scopes: ``block<index>.attn`` (with ``.attn.latent`` beside it for
-    what latent attention does before the kernel) or ``.sconv``, then
+    what latent attention does before the kernel, ``.attn.index``,
+    ``.attn.select`` and ``.attn.align`` for an indexer) or ``.sconv``, then
     ``block<index>.mlp`` or ``.moe`` (with ``.moe.route``,
     ``.moe.experts`` and ``.moe.shared`` beside it)."""
     mixer, ffn = arch.kinds(index)
@@ -844,9 +939,13 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
         x, stats = _block_attn(x, p, arch, run, f"block{index}.attn")
     if ffn == "moe_routed":
         x, aux, routed = _block_routed(x, p, arch, f"block{index}.moe")
-        return x, aux, {**stats, **routed}
-    with _probe.scope(f"block{index}.mlp"):
-        x, aux = _block_mlp(x, p, arch, ffn, run)
+        stats = {**stats, **routed}
+    else:
+        with _probe.scope(f"block{index}.mlp"):
+            x, aux = _block_mlp(x, p, arch, ffn, run)
+    if "loss_index" in stats:
+        # an indexer's alignment term joins the loss as a regularizer does
+        aux = aux + stats["loss_index"]
     return x, aux, stats
 
 
@@ -973,7 +1072,11 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
     stats)``: a layer that ran a flash kernel counts ``attn_flash`` 1 and
     ``attn_direct`` 1 or 0 (``attention.direct_layout``), constants of
     the traced step whose sums over layers give the unit its
-    ``znicz_lm_attn_direct_layout_share``."""
+    ``znicz_lm_attn_direct_layout_share``.  A layer with an indexer
+    (``arch.index_top_k``) hands its kernels the selection
+    (:func:`_select_keys`, the three scopes ``scope.index``, ``.select``,
+    ``.align``) and adds ``loss_index`` (the alignment term, a local mean
+    as a regularizer's is) and the selection's counts."""
     from znicz_tpu.ops.pallas import attention as pattn
     with _probe.scope(scope):
         h = _norm(x, p, "ln1", arch)
@@ -984,10 +1087,15 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
     else:
         with _probe.scope(scope):
             q, k, v = _plain_qkv(h, p, arch, run)
+    sel, picked = None, {}
+    if arch.index_top_k:
+        sel, picked = _select_keys(h, q, k, p, arch, run, scope)
     with _probe.scope(scope):
         dh = q.shape[-1]
         why = None
-        if run.use_flash:
+        if run.use_flash and sel is not None:
+            why = pattn.blocked_unsupported_reason(t_loc, dh)
+        elif run.use_flash:
             why = pattn.form_of(t_loc, dh)[1]
         elif run.use_ring_flash:       # the ring merges whole-row blocks
             why = pattn.unsupported_reason(t_loc, dh)
@@ -999,7 +1107,9 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
             _report_flash_choice(t_loc, dh, why, direct)
         if run.use_flash and not why:
             o = pattn.flash_attention(q, k, v, causal=run.causal,
-                                      interpret=run.interpret)
+                                      interpret=run.interpret, sel=sel)
+        elif sel is not None:
+            o = _selected_attention_dense(q, k, v, sel)
         else:
             group = q.shape[2] // k.shape[2]
             if group > 1:
@@ -1016,7 +1126,56 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
                  "attn_direct": jnp.full((), float(direct), jnp.float32)} \
             if flash else {}
         y = tp.row_parallel(o, p["wo"], None, "model")
-        return x + _sub_out(y, p, "ln1o", arch), stats
+        return x + _sub_out(y, p, "ln1o", arch), {**stats, **picked}
+
+
+def _select_keys(h, q, k, p, arch: Arch, run: _Run, scope: str):
+    """A layer's indexer (``parallel/dsa.py``) over a DETACHED copy of the
+    layer's normed input ``h`` and of the attention's own ``q`` and ``k``:
+    ``qI = h wiq`` (``index_heads`` of ``index_dim``), ``kI = LayerNorm(h
+    wik)`` (one head), both rotated over the whole index head with the
+    layer's theta, ``w = h wiw * index_heads^-0.5 * index_dim^-0.5`` in
+    float32.  -> ``(sel int8 (b, t, t), stats)``: the selection the
+    attention kernels take, and ``loss_index`` (the alignment term, which
+    alone reaches the indexer's five leaves and reaches nothing else),
+    ``dsa_selected`` / ``dsa_pairs`` (selected and causal pairs) and
+    ``dsa_live_tiles`` / ``dsa_tiles`` (of the tiles the blocked forward
+    kernel visits, those that hold a selected pair, and all of them)."""
+    from znicz_tpu.ops.pallas import attention as pattn
+    b, t, _ = h.shape
+    hi, di = arch.index_heads, arch.index_dim
+    with _probe.scope(f"{scope}.index"):
+        hd = lax.stop_gradient(h)
+        qi = _rotate((hd @ p["wiq"]).reshape(b, t, hi, di), arch.rope_theta)
+        ki = _rotate(_layer_norm(hd @ p["wik"], p["ik_g"], p["ik_b"],
+                                 arch.eps)[:, :, None], arch.rope_theta)
+        w = (hd @ p["wiw"]).astype(jnp.float32) * np.float32(
+            1.0 / np.sqrt(hi * di))
+    sel, term = dsa.index_select_align(
+        qi, ki[:, :, 0], w, lax.stop_gradient(q), lax.stop_gradient(k),
+        arch.index_top_k, scope)
+    with _probe.scope(f"{scope}.select"):
+        block = pattn.kvb_block_rows(t, q.shape[-1], True)["fwd"] or t
+        live, tiles = dsa.live_tiles(sel, block)
+        stats = {"loss_index": term,
+                 "dsa_selected": (sel != 0).sum(dtype=jnp.float32),
+                 "dsa_pairs": jnp.float32(b * t * (t + 1) // 2),
+                 "dsa_live_tiles": live, "dsa_tiles": tiles}
+    return sel, stats
+
+
+def _selected_attention_dense(q, k, v, sel):
+    """Attention over a selection with the scores materialised ``(b,
+    heads, t, t)``: what a layer with an indexer falls back to where no
+    flash kernel takes it (small shapes off the TPU; the kernels take any
+    ``t`` their block divides)."""
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) / np.sqrt(q.shape[-1])
+    a = jax.nn.softmax(jnp.where(sel[:, None] != 0, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", a.astype(v.dtype), v)
 
 
 def _block_sconv(x, p, arch: Arch):
@@ -1270,7 +1429,7 @@ def attn_kvb_block_rows(mesh: Mesh, arch: Arch, t: int) -> dict:
     stack has no attention layer: what :func:`_block_attn` will trace,
     known from the mesh, the architecture and the sequence length."""
     from znicz_tpu.ops.pallas import attention as pattn
-    rows = pattn.kvb_block_rows(t, arch.head_dim)
+    rows = pattn.kvb_block_rows(t, arch.head_dim, bool(arch.index_top_k))
     if {"attention", "latent"} & set(arch.mixers) and \
             _run_of(mesh, arch, causal=True).use_flash:
         return rows
@@ -1354,7 +1513,8 @@ def _head_of(ps, arch: Arch):
 
 #: prefixes of the stats that come in the loss's own convention (reduced
 #: and scaled as the loss is): the loss's named terms (``loss_main``,
-#: ``loss_mtp`` of a stack with an MTP module) and a looped stack's means
+#: ``loss_mtp`` of a stack with an MTP module, ``loss_index`` of one with
+#: an indexer) and a looped stack's means
 #: over tokens (``loop_exit_step_mean``, ``loop_exit_entropy``,
 #: ``loop_loss_step<r>``)
 _TERM_PREFIXES = ("loss_", "loop_")
@@ -1526,8 +1686,11 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
     With an MTP module (``arch.mtp``) the loss is ``CE(main; next token)
     + mtp_weight * CE(module; second-next token)``, the second over the
     positions that have a second-next token, and ``stats`` carries both
-    terms (``loss_main``, ``loss_mtp``, in the loss's own convention).  A
-    looped stack's loss is :func:`_forward_loop_ce`'s."""
+    terms (``loss_main``, ``loss_mtp``, in the loss's own convention).
+    With an indexer on the attention layers (``arch.index_top_k``) the loss
+    is ``CE + L_I``, the alignment term summed over the layers with weight
+    1, and ``stats`` carries ``loss_index``.  A looped stack's loss is
+    :func:`_forward_loop_ce`'s."""
     if arch.loop_steps > 1:
         return _forward_loop_ce(ps, tokens, labels, mask, arch, run, cdt,
                                 _block_fn(remat, remat_policy, arch),
@@ -1549,6 +1712,12 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
                                   head_sharded, reduce, skip_last=True)
         stats = {**_sum_stats(stats, st), "loss_main": loss, "loss_mtp": mtp}
         loss = loss + arch.mtp_weight * mtp
+    if arch.index_top_k:
+        # summed over the layers and inside ``loss`` already (it came as
+        # the regularizer term does); here as the loss's named term
+        term = stats["loss_index"]
+        stats["loss_index"] = lax.psum(term, ("data", "seq")) if reduce \
+            else term
     return loss, _mean_stats(stats, arch)
 
 

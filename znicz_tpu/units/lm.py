@@ -80,7 +80,8 @@ class TransformerLMStep(AcceleratedUnit):
     (``model_type`` and that family's: ``layer_types``,
     ``num_dense_layers``, ``num_experts``, ... or ``q_lora_rank``,
     ``n_shared_experts``, ``num_nextn_predict_layers``, ... or
-    ``total_ut_steps``, ... and ``experts_held``, this chip's share:
+    ``total_ut_steps``, ... or ``sa_config``, ``num_experts``, ... and
+    ``experts_held``, this chip's share:
     ``{"first", "count"}``; see
     ``parallel.transformer.arch_from_config``); the vocabulary is the
     loader's.  Without it the unit builds the GPT-shaped block from
@@ -166,6 +167,14 @@ class TransformerLMStep(AcceleratedUnit):
         #: ``H(p)``, nats) and ``loss_step<r>``, each loop step's own
         #: cross-entropy
         self.loop_counters: dict = {}
+        #: the last finished training pass's readings of a stack whose
+        #: attention layers carry an indexer (learned sparse attention):
+        #: ``selected_share`` (selected over causal (query, key) pairs),
+        #: ``live_tile_share`` (of the tiles the blocked forward kernel
+        #: visits, those that hold a selected pair), ``index_loss`` (the
+        #: alignment term, summed over the layers) and
+        #: ``index_loss_share`` (its share of the pass's loss)
+        self.dsa_counters: dict = {}
         #: of the last finished pass's attention layers that ran a flash
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
@@ -354,6 +363,8 @@ class TransformerLMStep(AcceleratedUnit):
                               float(sums["tile_fill"]) / steps,
                               float(sums["pairs_held"]))
         self._publish_terms(sums, steps)
+        if "dsa_pairs" in sums:
+            self._publish_dsa(sums, steps, self.minibatch_mse)
         if "attn_flash" in sums:
             self._publish_attn_layout(float(sums["attn_direct"]) /
                                       float(sums["attn_flash"]))
@@ -404,6 +415,39 @@ class TransformerLMStep(AcceleratedUnit):
             "t, heads x head_dim) layout over the attention layers that ran "
             "a flash kernel (the rest fold their operands head-major)",
             ("unit",)).labels(unit=self.name).set(share)
+
+    def _publish_dsa(self, sums: dict, steps: float, loss: float) -> None:
+        """A finished pass's readings of the indexers' selections and of
+        the alignment term: the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        index_loss = float(sums["loss_index"]) / steps
+        self.dsa_counters = {
+            "selected_share":
+                float(sums["dsa_selected"]) / float(sums["dsa_pairs"]),
+            "live_tile_share":
+                float(sums["dsa_live_tiles"]) / float(sums["dsa_tiles"]),
+            "index_loss": index_loss,
+            "index_loss_share": index_loss / loss if loss else 0.0}
+        helps = {
+            "selected_share":
+                "(query, key) pairs the indexers selected over the causal "
+                "pairs, all attention layers of the last class pass",
+            "live_tile_share":
+                "tiles of the blocked forward kernel's visit table that "
+                "hold at least one selected pair over the tiles visited, "
+                "all attention layers of the last class pass",
+            "index_loss":
+                "the indexers' alignment term (KL from the heads' mean "
+                "attention probabilities to the softmax of the index "
+                "scores over the selection), summed over the layers, mean "
+                "over the last class pass's steps",
+            "index_loss_share":
+                "the alignment term over the whole loss (cross-entropy "
+                "plus the term), last class pass"}
+        for key, value in self.dsa_counters.items():
+            registry.gauge(f"znicz_lm_dsa_{key}", helps[key],
+                           ("unit",)).labels(unit=self.name).set(value)
 
     def _publish_terms(self, sums: dict, steps: float) -> None:
         """A finished training pass's named terms, each the mean over its
