@@ -44,6 +44,17 @@ second time: the flash kernels keep none) leaves the MXU in the compute
 dtype, as the head pass's logits do, which halves what a block writes and
 reads back.
 
+The target has two implementations of one algorithm.  Where the step's
+kernels run (a TPU, or interpreted) and the shape fits
+(:func:`align_kernel_refusal`), a Pallas kernel makes it a block at a time
+(``ops/pallas/dsa.py``, :data:`~znicz_tpu.ops.pallas.dsa.ALIGN_KERNEL_NAME`):
+two sweeps over key tiles, the heads' scores float32 in VMEM from the MXU to
+the ``exp``, none in HBM, and no tile above the block's last query visited.
+Everywhere else the blocked ``jax.numpy`` form above.  Both give the same
+``p`` to rounding (the kernel's scores are not rounded to the compute
+dtype); the index scores, the threshold, the selection, the KL and the
+gradients are the same code under either.
+
 Scopes ``<scope>.index`` (the scores, and their gradients'
 ``transpose(jvp(...))``), ``<scope>.select`` (keys, threshold, the
 selection) and ``<scope>.align`` (the attention heads' probabilities, the
@@ -61,6 +72,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from znicz_tpu.observe import probe as _probe
+from znicz_tpu.ops.pallas import dsa as _pdsa
 
 #: query rows a block of the scan holds
 Q_BLOCK = 128
@@ -102,14 +114,56 @@ def _blocks_of(t: int) -> tuple[int, int]:
     return block, groups
 
 
-def _one_block(ki, k, top_k: int, scale, weight, grads: bool, scope: str):
+def align_kernel_refusal(t: int, heads: int, kv: int, dh: int,
+                         interpret: bool) -> str | None:
+    """Why the alignment target of ``t`` positions of ``heads`` heads ``dh``
+    wide on ``kv`` key/value heads is left to the ``jax.numpy`` form, or
+    ``None`` where the kernel makes it (``ops/pallas/dsa.py``): where the
+    step's kernels run at all (a TPU, or ``interpret``: interpreted), whole
+    blocks of :data:`Q_BLOCK` queries, and a shape the kernel's tiles take
+    at every group's key extent (multiples of ``t / groups``)."""
+    if not interpret and jax.default_backend() != "tpu":
+        return (f"the backend is {jax.default_backend()} and the step's "
+                f"kernels are not interpreted")
+    if t % Q_BLOCK:
+        return f"t={t} is not a multiple of the {Q_BLOCK}-row block of queries"
+    block, groups = _blocks_of(t)
+    return _pdsa.unsupported_reason(block, t // groups, heads, kv, dh)
+
+
+def _one_block(ki, k, top_k: int, scale, weight, grads: bool, scope: str,
+               kernel: bool, interpret: bool):
     """The scan body over blocks of queries against the keys ``ki`` ``(tk,
-    di)`` and ``k`` ``(tk, kv, dh)``; the carry is ``dL/dkI``."""
+    di)`` and ``k`` ``(tk, kv, dh)``; the carry is ``dL/dkI``.  ``kernel``:
+    the target by the Pallas kernel, the block's ``q`` then head-major
+    ``(kv, grp * bq, dh)``."""
     tk = ki.shape[0]
     f32 = jnp.float32
 
+    def target_numpy(q, sel):
+        # a key/value head's group of query heads at a time, as one plain
+        # product with the keys along the minor axis, the layout every
+        # softmax over keys has: the one product over all heads
+        # (``qgjd,kgd->gjqk``) gets, at some widths, a layout of XLA's
+        # choosing in which the row statistics cost 27 times the product
+        # (11.7 ms a block of 128 at 8,192 keys against 0.2 at 16,384; my
+        # chip run, PR 39).  The scores leave the product in the compute
+        # dtype (as the head pass's logits do) and are float32 from there on
+        bq, kv, grp, _ = q.shape
+        rows = jnp.repeat(sel, grp, axis=0)                 # (bq*grp, tk)
+        p = jnp.zeros(sel.shape, f32)
+        for g in range(kv):
+            a = jnp.dot(q[:, g].reshape(bq * grp, -1),
+                        k[:, g].T).astype(f32) * scale
+            a = jnp.where(rows, a, -jnp.inf)
+            e = jnp.exp(a - a.max(-1, keepdims=True))
+            p = p + (e / e.sum(-1, keepdims=True)).reshape(
+                bq, grp, -1).sum(1)
+        return p / (kv * grp)                                # (bq, tk)
+
     def body(dki, xs):
-        qi, w, q, pos = xs     # (bq, hi, di) (bq, hi) (bq, kv, grp, dh) (bq,)
+        # (bq, hi, di) (bq, hi) (bq, kv, grp, dh) or head-major (bq,)
+        qi, w, q, pos = xs
         causal = jnp.arange(tk)[None, :] <= pos[:, None]
         with _probe.scope(f"{scope}.index"):
             s = jnp.einsum("qjd,kd->qjk", qi, ki, preferred_element_type=f32)
@@ -118,34 +172,21 @@ def _one_block(ki, k, top_k: int, scale, weight, grads: bool, scope: str):
         with _probe.scope(f"{scope}.select"):
             keys = jnp.where(causal, sortable_keys(idx), jnp.uint32(0))
             sel = (keys >= kth_largest_key(keys, top_k)[:, None]) & causal
+        sel8 = sel.astype(jnp.int8)
         with _probe.scope(f"{scope}.align"):
-            # a key/value head's group of query heads at a time, as one
-            # plain product with the keys along the minor axis, the layout
-            # every softmax over keys has: the one product over all heads
-            # (``qgjd,kgd->gjqk``) gets, at some widths, a layout of XLA's
-            # choosing in which the row statistics cost 27 times the
-            # product (11.7 ms a block of 128 at 8,192 keys against 0.2 at
-            # 16,384; my chip run, PR 39).  The scores leave the product
-            # in the compute dtype (as the head pass's logits do) and are
-            # float32 from there on
-            bq, kv, grp, _ = q.shape
-            rows = jnp.repeat(sel, grp, axis=0)                 # (bq*grp, tk)
-            p = jnp.zeros(sel.shape, f32)
-            for g in range(kv):
-                a = jnp.dot(q[:, g].reshape(bq * grp, -1),
-                            k[:, g].T).astype(f32) * scale
-                a = jnp.where(rows, a, -jnp.inf)
-                e = jnp.exp(a - a.max(-1, keepdims=True))
-                p = p + (e / e.sum(-1, keepdims=True)).reshape(
-                    bq, grp, -1).sum(1)
-            p = p / (kv * grp)                                   # (bq, tk)
+            if kernel:
+                p = _pdsa.align_target(q, k, sel8, pos[-1],
+                                       sm_scale=float(scale),
+                                       interpret=interpret)
+            else:
+                p = target_numpy(q, sel)
             li = jnp.where(sel, idx, -jnp.inf)
             logq = li - jax.nn.logsumexp(li, axis=-1, keepdims=True)
             kl = jnp.where(p > 0, p * (jnp.log(jnp.maximum(p, 1e-37)) -
                                        logq), 0.0).sum()
             # dL/dI: zero outside the selection (exp(-inf), and p is 0)
             d_idx = (jnp.exp(logq) - p) * weight
-        out = (sel.astype(jnp.int8), kl)
+        out = (sel8, kl)
         if not grads:
             return dki, out
         with _probe.scope_bwd(f"{scope}.index"):
@@ -159,7 +200,8 @@ def _one_block(ki, k, top_k: int, scale, weight, grads: bool, scope: str):
     return body
 
 
-def _row(qi, ki, w, q, k, top_k: int, weight, grads: bool, scope: str):
+def _row(qi, ki, w, q, k, top_k: int, weight, grads: bool, scope: str,
+         interpret: bool):
     """One sequence: ``qi (t, hi, di)``, ``ki (t, di)``, ``w (t, hi)``, ``q
     (t, h, dh)``, ``k (t, kv, dh)`` -> ``(sel (t, t) int8, sum of the rows'
     KL, (dqi, dki, dw) or None)``."""
@@ -167,7 +209,12 @@ def _row(qi, ki, w, q, k, top_k: int, weight, grads: bool, scope: str):
     kv = k.shape[1]
     block, groups = _blocks_of(t)
     rows = t // groups
-    q = q.reshape(t, kv, h // kv, dh)
+    kernel = align_kernel_refusal(t, h, kv, dh, interpret) is None
+    q = q.reshape(t // block, block, kv, h // kv, dh)
+    if kernel:
+        # the kernel's rows, head-major a block: one pass over q a layer
+        with _probe.scope(f"{scope}.align"):
+            q = q.transpose(0, 2, 3, 1, 4).reshape(t // block, kv, -1, dh)
     scale = np.float32(1.0 / np.sqrt(dh))
     sels, kl, dqis, dws = [], 0.0, [], []
     dki = jnp.zeros(ki.shape, jnp.float32)
@@ -176,10 +223,11 @@ def _row(qi, ki, w, q, k, top_k: int, weight, grads: bool, scope: str):
         cut = lambda a: a[lo:hi].reshape(rows // block, block,  # noqa: E731
                                          *a.shape[1:])
         body = _one_block(ki[:hi], k[:hi], top_k, scale, weight, grads,
-                          scope)
+                          scope, kernel, interpret)
         dki_g, out = lax.scan(
             body, jnp.zeros((hi, ki.shape[1]), jnp.float32),
-            (cut(qi), cut(w), cut(q), cut(jnp.arange(t, dtype=jnp.int32))))
+            (cut(qi), cut(w), q[lo // block:hi // block],
+             cut(jnp.arange(t, dtype=jnp.int32))))
         with _probe.scope(f"{scope}.select"):
             sels.append(jnp.pad(out[0].reshape(rows, hi),
                                 ((0, 0), (0, t - hi))))
@@ -196,11 +244,12 @@ def _row(qi, ki, w, q, k, top_k: int, weight, grads: bool, scope: str):
                      dki.astype(ki.dtype), jnp.concatenate(dws))
 
 
-def _forward(qi, ki, w, q, k, top_k: int, scope: str, grads: bool):
+def _forward(qi, ki, w, q, k, top_k: int, scope: str, interpret: bool,
+             grads: bool):
     b, t = q.shape[:2]
     weight = np.float32(1.0 / (b * t))
     rows = [_row(qi[i], ki[i], w[i], q[i], k[i], top_k, weight, grads,
-                 scope) for i in range(b)]
+                 scope, interpret) for i in range(b)]
     sel = jnp.stack([r[0] for r in rows])
     loss = sum(r[1] for r in rows) * weight
     if not grads:
@@ -209,8 +258,9 @@ def _forward(qi, ki, w, q, k, top_k: int, scope: str, grads: bool):
                             for i in range(3))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def index_select_align(qi, ki, w, q, k, top_k: int, scope: str = "dsa"):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def index_select_align(qi, ki, w, q, k, top_k: int, scope: str = "dsa",
+                       interpret: bool = False):
     """The selection and the alignment loss of one attention layer.
 
     ``qi`` ``(b, t, hi, di)`` and ``ki`` ``(b, t, di)``: the indexer's
@@ -221,17 +271,20 @@ def index_select_align(qi, ki, w, q, k, top_k: int, scope: str = "dsa"):
     gradient.  -> ``(sel int8 (b, t, t), L_I float32)``: ``sel[b, t, s]``
     is 1 where query ``t`` attends to key ``s``; ``L_I`` is the mean over
     the ``b * t`` tokens of the KL (module docstring), with gradients to
-    ``qi``, ``ki`` and ``w`` only."""
-    sel, loss, _ = _forward(qi, ki, w, q, k, top_k, scope, False)
+    ``qi``, ``ki`` and ``w`` only.  ``interpret``: the step runs its Pallas
+    kernels interpreted, so the target's kernel too
+    (:func:`align_kernel_refusal`)."""
+    sel, loss, _ = _forward(qi, ki, w, q, k, top_k, scope, interpret, False)
     return sel, loss
 
 
-def _isa_fwd(qi, ki, w, q, k, top_k, scope):
-    sel, loss, grads = _forward(qi, ki, w, q, k, top_k, scope, True)
+def _isa_fwd(qi, ki, w, q, k, top_k, scope, interpret):
+    sel, loss, grads = _forward(qi, ki, w, q, k, top_k, scope, interpret,
+                                True)
     return (sel, loss), grads
 
 
-def _isa_bwd(top_k, scope, grads, cts):
+def _isa_bwd(top_k, scope, interpret, grads, cts):
     ct = cts[1]
     with _probe.scope_bwd(f"{scope}.index"):
         return (*((ct * g.astype(jnp.float32)).astype(g.dtype)

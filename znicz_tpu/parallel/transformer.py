@@ -59,7 +59,7 @@ _log = logging.getLogger("znicz_tpu.transformer")
 
 @functools.lru_cache(maxsize=None)
 def _report_flash_choice(t: int, dh: int, why: str | None,
-                         direct: bool) -> None:
+                         direct: bool, align: str | None = None) -> None:
     """What a shape that was eligible for a flash kernel by platform and
     mesh got, said once per shape per process: a refusal with its reason
     (the dense ``ring_attention`` path that takes over materializes the
@@ -67,7 +67,8 @@ def _report_flash_choice(t: int, dh: int, why: str | None,
     layout its kernels read (``attention.direct_layout``: the layer's
     own, or operands folded head-major around them) and, of the
     key/value-blocked form, the rows of each pass's tile
-    (``attention.kvb_block_rows``)."""
+    (``attention.kvb_block_rows``).  ``align``: of a layer with an
+    indexer, what makes its alignment target (:func:`_align_choice`)."""
     if why:
         _log.warning("flash attention refused t=%d head_dim=%d: %s; this "
                      "step uses dense ring_attention", t, dh, why)
@@ -80,8 +81,19 @@ def _report_flash_choice(t: int, dh: int, why: str | None,
     blocked = "; key/value-blocked, tiles of %s rows" % " / ".join(
         f"{rows} ({name})" for name, rows in tiles.items()) \
         if any(tiles.values()) else ""
-    _log.info("flash attention t=%d head_dim=%d: kernels read %s%s",
-              t, dh, layout, blocked)
+    _log.info("flash attention t=%d head_dim=%d: kernels read %s%s%s",
+              t, dh, layout, blocked, f"; {align}" if align else "")
+
+
+def _align_choice(t: int, heads: int, kv: int, dh: int,
+                  interpret: bool) -> str:
+    """What makes a layer's alignment target, in words for the step's one
+    INFO line a shape (``dsa.align_kernel_refusal``)."""
+    from znicz_tpu.ops.pallas import dsa as pdsa
+    why = dsa.align_kernel_refusal(t, heads, kv, dh, interpret)
+    if why:
+        return f"the alignment target by the jax.numpy form ({why})"
+    return f"the alignment target by kernel {pdsa.ALIGN_KERNEL_NAME}"
 
 
 def _flash_eligible(mesh: Mesh, interpret: bool) -> bool:
@@ -1104,7 +1116,10 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
         direct = bool(flash and run.use_flash and
                       pattn.direct_layout(t_loc, dh))
         if eligible:
-            _report_flash_choice(t_loc, dh, why, direct)
+            _report_flash_choice(
+                t_loc, dh, why, direct, None if sel is None else
+                _align_choice(t_loc, q.shape[2], k.shape[2], dh,
+                              run.interpret))
         if run.use_flash and not why:
             o = pattn.flash_attention(q, k, v, causal=run.causal,
                                       interpret=run.interpret, sel=sel)
@@ -1153,7 +1168,7 @@ def _select_keys(h, q, k, p, arch: Arch, run: _Run, scope: str):
             1.0 / np.sqrt(hi * di))
     sel, term = dsa.index_select_align(
         qi, ki[:, :, 0], w, lax.stop_gradient(q), lax.stop_gradient(k),
-        arch.index_top_k, scope)
+        arch.index_top_k, scope, run.interpret)
     with _probe.scope(f"{scope}.select"):
         block = pattn.kvb_block_rows(t, q.shape[-1], True)["fwd"] or t
         live, tiles = dsa.live_tiles(sel, block)
@@ -1434,6 +1449,20 @@ def attn_kvb_block_rows(mesh: Mesh, arch: Arch, t: int) -> dict:
             _run_of(mesh, arch, causal=True).use_flash:
         return rows
     return dict.fromkeys(rows, 0)
+
+
+def dsa_align_kernel_share(mesh: Mesh, arch: Arch, t: int) -> float | None:
+    """Of a step's layers with an indexer at ``t`` positions, the share
+    whose alignment target the kernel makes (``ops/pallas/dsa.py``; all or
+    none: the layers share their shape), None for a stack without an
+    indexer: what :func:`_select_keys` will trace, known from the mesh, the
+    architecture and the sequence length (``dsa.align_kernel_refusal``)."""
+    if not arch.index_top_k or "attention" not in arch.mixers:
+        return None
+    run = _run_of(mesh, arch, causal=True)
+    return float(dsa.align_kernel_refusal(
+        t, run.heads_local, run.kv_heads_local, arch.head_dim,
+        run.interpret) is None)
 
 
 def ce_grad_in_forward(arch: Arch, loss_chunks: int | None,

@@ -185,6 +185,11 @@ class TransformerLMStep(AcceleratedUnit):
         #: attn_kvb_block_rows``), 0 where the attention layers run
         #: another form; empty until the step is built
         self.attn_kvb_block_rows: dict = {}
+        #: of the step's layers with an indexer, the share whose alignment
+        #: target the Pallas kernel makes (all or none: ``parallel/
+        #: transformer.py::dsa_align_kernel_share``); None without an
+        #: indexer, and until the step is built
+        self.dsa_align_kernel_share: Optional[float] = None
         #: of the train step's head passes, the share that make their
         #: gradients where they make their logits (all or none:
         #: ``parallel/transformer.py::ce_grad_in_forward``); None until
@@ -240,8 +245,11 @@ class TransformerLMStep(AcceleratedUnit):
             head_sharded=self.head_sharded)
         self._publish_ce_rule(float(tfm.ce_grad_in_forward(
             self.arch, self.loss_chunks, self.head_sharded)))
+        seq_len = int(self.loader.minibatch_data.shape[1])
         self._publish_attn_tiles(tfm.attn_kvb_block_rows(
-            self.mesh, self.arch, int(self.loader.minibatch_data.shape[1])))
+            self.mesh, self.arch, seq_len))
+        self._publish_dsa_align(tfm.dsa_align_kernel_share(
+            self.mesh, self.arch, seq_len))
         self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
         # the programs from again (as FusedTrainStep's programs)
@@ -402,6 +410,23 @@ class TransformerLMStep(AcceleratedUnit):
         for name, value in rows.items():
             gauge.labels(**{"unit": self.name, "pass": name}).set(value)
 
+    def _publish_dsa_align(self, share: Optional[float]) -> None:
+        """Of the layers with an indexer, the share whose alignment target
+        the kernel makes (a constant of the step as it is built; nothing
+        without an indexer): the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        self.dsa_align_kernel_share = share
+        if share is None:
+            return
+        registry.gauge(
+            "znicz_lm_dsa_align_kernel_share",
+            "layers with an indexer whose alignment target (the heads' mean "
+            "attention probabilities over the selection) the Pallas kernel "
+            "dsa_align_target makes over the layers with an indexer (the "
+            "rest: blocked jax.numpy, the heads' scores through HBM)",
+            ("unit",)).labels(unit=self.name).set(share)
+
     def _publish_attn_layout(self, share: float) -> None:
         """Of the attention layers that ran a flash kernel, the share whose
         kernels read the layer's own layout (a constant of the traced
@@ -429,25 +454,31 @@ class TransformerLMStep(AcceleratedUnit):
                 float(sums["dsa_live_tiles"]) / float(sums["dsa_tiles"]),
             "index_loss": index_loss,
             "index_loss_share": index_loss / loss if loss else 0.0}
-        helps = {
-            "selected_share":
+        # each family by its literal name: tools/check_metric_catalogue.py
+        # reads declarations off the source
+        gauges = {
+            "selected_share": registry.gauge(
+                "znicz_lm_dsa_selected_share",
                 "(query, key) pairs the indexers selected over the causal "
                 "pairs, all attention layers of the last class pass",
-            "live_tile_share":
+                ("unit",)),
+            "live_tile_share": registry.gauge(
+                "znicz_lm_dsa_live_tile_share",
                 "tiles of the blocked forward kernel's visit table that "
                 "hold at least one selected pair over the tiles visited, "
-                "all attention layers of the last class pass",
-            "index_loss":
+                "all attention layers of the last class pass", ("unit",)),
+            "index_loss": registry.gauge(
+                "znicz_lm_dsa_index_loss",
                 "the indexers' alignment term (KL from the heads' mean "
                 "attention probabilities to the softmax of the index "
                 "scores over the selection), summed over the layers, mean "
-                "over the last class pass's steps",
-            "index_loss_share":
+                "over the last class pass's steps", ("unit",)),
+            "index_loss_share": registry.gauge(
+                "znicz_lm_dsa_index_loss_share",
                 "the alignment term over the whole loss (cross-entropy "
-                "plus the term), last class pass"}
+                "plus the term), last class pass", ("unit",))}
         for key, value in self.dsa_counters.items():
-            registry.gauge(f"znicz_lm_dsa_{key}", helps[key],
-                           ("unit",)).labels(unit=self.name).set(value)
+            gauges[key].labels(unit=self.name).set(value)
 
     def _publish_terms(self, sums: dict, steps: float) -> None:
         """A finished training pass's named terms, each the mean over its
