@@ -1,0 +1,208 @@
+"""The Mamba-2 mixer (a state-space layer; Dao & Gu, arXiv:2405.21060), as
+the ``granitemoehybrid`` family's ``mamba`` layers have it, with its scan in
+the chunked form.
+
+The layer, on the normed residual stream ``u (b, t, d)``, with ``H`` heads
+of ``P`` entries, a state of ``N`` entries a head entry and ONE group (``B``
+and ``C`` serve all heads):
+
+    [z | xBC | dt] = u W_in                      d -> H P + (H P + 2 N) + H
+    xBC = silu(conv(xBC) + b)                    depthwise, causal, zeros
+                                                 before the sequence
+    [x | B | C] = xBC                            x as (H, P)
+    dt = softplus(dt + dt_bias),  A = -exp(A_log)             a head
+    h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,  h_0 = 0      (P, N) a head
+    y_t = h_t C_t + D x_t
+    out = RMSNorm(y * silu(z); g) W_out          over all H P, gate first
+
+**The scan** (:func:`ssd`) never walks the positions.  In chunks of ``Q``
+positions, with ``cs`` the running sum of ``dt A`` inside a chunk:
+
+1. inside a chunk the quadratic form: ``scores = C B^T`` once a chunk for
+   all heads, masked by the decays ``L[i, j] = exp(cs_i - cs_j)`` (``j <=
+   i``), times ``dt x``;
+2. a chunk's closing state ``S_c = sum_j exp(cs_last - cs_j) dt_j x_j
+   B_j^T``;
+3. the carry from chunk to chunk, ``h_{c+1} = exp(cs_last) h_c + S_c``
+   (``t / Q`` steps of a ``lax.scan``; the one part that is sequential);
+4. the carried state's contribution to each position, ``exp(cs_i) h_c
+   C_i``.
+
+Products run on the operands' dtype (bfloat16 in a training step) and
+accumulate in float32; the softplus, the log-decays and their running sums,
+every decay factor and the carried state are float32.  Every decay factor
+is an ``exp`` of a sum that is at most 0, so none overflows.  ``Q`` changes
+no value: a row that ``Q`` does not divide is filled with positions of ``dt
+= 0`` (the state passes them unchanged).
+
+**What the backward pass keeps**: the operands and each chunk's opening
+state (``(b, t / Q, H, P, N)`` float32, named ``ssm_state``).  The two
+halves, :func:`_chunk_states` (2, 3) and :func:`_chunk_outputs` (1, 4), are
+each a ``jax.checkpoint``: the ``(H, Q, Q)`` decay and score matrices of
+every chunk (0.5 GB a layer at 8,192 positions) are made again from the
+operands when the gradients are, and never stored.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from znicz_tpu.observe import probe as _probe
+
+#: the words a refusal is made of (``Arch.mechanisms``, ``serve/``, a mesh)
+MECHANISM = "state-space layer (Mamba-2)"
+
+
+def in_width(heads: int, head_dim: int, state: int) -> int:
+    """Columns of ``W_in``: the gate, the convolved ``[x | B | C]``, a step
+    size a head."""
+    return 2 * heads * head_dim + 2 * state + heads
+
+
+def leaf_shapes(d: int, heads: int, head_dim: int, state: int,
+                taps: int) -> dict:
+    """``{leaf: shape}`` of the mixer."""
+    inner = heads * head_dim
+    return {"ssm_in": (d, in_width(heads, head_dim, state)),
+            "ssm_conv_k": (taps, inner + 2 * state),
+            "ssm_conv_b": (inner + 2 * state,), "ssm_dt_b": (heads,),
+            "ssm_a_log": (heads,), "ssm_d": (heads,), "ssm_g": (inner,),
+            "ssm_out": (inner, d)}
+
+
+#: the mixer's leaves that stay in the master dtype in a step's forward:
+#: the step size's bias, the decay rate and the skip enter float32 chains
+F32_LEAVES = ("ssm_dt_b", "ssm_a_log", "ssm_d")
+
+
+def _chunked(a, q: int):
+    """``(b, t, ...) -> (b, t / q, q, ...)``."""
+    return a.reshape(a.shape[0], a.shape[1] // q, q, *a.shape[2:])
+
+
+def _running(dt, a, q: int):
+    """``cs (b, c, H, q)`` float32: the running sum inside each chunk of the
+    log-decays ``dt A`` (each at most 0)."""
+    return jnp.cumsum(_chunked(dt * a, q).transpose(0, 1, 3, 2), axis=-1)
+
+
+@jax.checkpoint
+def _chunk_states(x, dt, a, bm):
+    """Steps 2 and 3 on chunked operands ``x (b, c, q, H, P)``, ``dt (b, c *
+    q, H)`` float32, ``a (H,)``, ``bm (b, c, q, N)`` -> ``(each chunk's
+    opening state (b, c, H, P, N), the state behind the last position
+    (b, H, P, N))``, float32."""
+    q = x.shape[2]
+    cs = _running(dt, a, q)
+    to_end = jnp.exp(cs[..., -1:] - cs).transpose(0, 1, 3, 2)   # (b, c, q, H)
+    xw = (x.astype(jnp.float32) *
+          (_chunked(dt, q) * to_end)[..., None]).astype(x.dtype)
+    closing = jnp.einsum("bcqhp,bcqn->cbhpn", xw, bm,
+                         preferred_element_type=jnp.float32)
+    whole = jnp.exp(cs[..., -1]).transpose(1, 0, 2)             # (c, b, H)
+
+    def carry(h, inp):
+        keep, s = inp
+        return keep[..., None, None] * h + s, h
+
+    last, opening = lax.scan(carry, jnp.zeros_like(closing[0]),
+                             (whole, closing))
+    return opening.transpose(1, 0, 2, 3, 4), last
+
+
+@jax.checkpoint
+def _chunk_outputs(x, dt, a, bm, cm, skip, opening):
+    """Steps 1 and 4 and the skip -> ``y (b, c, q, H, P)`` in ``x``'s
+    dtype."""
+    q = x.shape[2]
+    cs = _running(dt, a, q)
+    scores = jnp.einsum("bcin,bcjn->bcij", cm, bm,
+                        preferred_element_type=jnp.float32)
+    seen = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
+    # masked before the exp: above the diagonal the difference is positive
+    decay = jnp.exp(jnp.where(seen, cs[..., :, None] - cs[..., None, :],
+                              -jnp.inf))                     # (b, c, H, i, j)
+    xf = x.astype(jnp.float32)
+    xdt = (xf * _chunked(dt, q)[..., None]).astype(x.dtype)
+    y = jnp.einsum("bchij,bcjhp->bcihp",
+                   (scores[:, :, None] * decay).astype(x.dtype), xdt,
+                   preferred_element_type=jnp.float32)
+    carried = jnp.einsum("bcin,bchpn->bcihp", cm, opening.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+    y = y + carried * jnp.exp(cs).transpose(0, 1, 3, 2)[..., None]
+    return (y + skip[:, None] * xf).astype(x.dtype)
+
+
+def ssd(x, dt, a, bm, cm, skip, chunk: int):
+    """The scan: ``x (b, t, H, P)``, ``dt (b, t, H)`` float32 (after the
+    softplus), ``a (H,)`` float32 (negative), ``bm``, ``cm`` ``(b, t, N)``,
+    ``skip (H,)`` float32 -> ``(y (b, t, H, P) in x's dtype, the state
+    behind the last position (b, H, P, N) float32)``, in chunks of ``chunk``
+    positions (the whole row where it is shorter)."""
+    b, t = x.shape[:2]
+    q = min(int(chunk), t)
+    fill = -t % q
+    if fill:
+        x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, fill)) +
+                                 ((0, 0),) * (v.ndim - 2))
+                         for v in (x, dt, bm, cm))
+    x, bm, cm = (_chunked(v, q) for v in (x, bm, cm))
+    opening, last = _chunk_states(x, dt, a, bm)
+    opening = checkpoint_name(opening, "ssm_state")
+    y = _chunk_outputs(x, dt, a, bm, cm, skip, opening)
+    return y.reshape(b, t + fill, *y.shape[3:])[:, :t], last
+
+
+def _conv(v, taps, bias):
+    """Depthwise causal convolution over time with zeros before the
+    sequence, ``c_t = sum_j k_j v_{t - taps + 1 + j} + bias``, in float32."""
+    n, t = taps.shape[0], v.shape[1]
+    vp = jnp.pad(v.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
+    kf = taps.astype(jnp.float32)
+    return sum(kf[j] * vp[:, j:j + t] for j in range(n)) + \
+        bias.astype(jnp.float32)
+
+
+def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
+          eps: float, scope: str):
+    """The layer on the normed stream ``u (b, t, d)`` -> ``(out (b, t, d),
+    stats)``.  Scopes: ``scope`` (the two projections, the split, the gate
+    and the gated norm), ``scope.conv`` and ``scope.scan`` (softplus,
+    decays, the four steps, the skip), siblings by name.  ``stats`` (of
+    this layer; a step sums them over its layers): ``ssm_decay``, the mean
+    over positions and heads of ``exp(dt A)``; ``ssm_state_rms``, the RMS
+    of the state behind the last position (a row's, mean over the rows);
+    ``ssm_layers``, 1.  Neither
+    depends on ``chunk``."""
+    b, t, _ = u.shape
+    inner = heads * head_dim
+    with _probe.scope(scope):
+        proj = u @ p["ssm_in"]
+        z, xbc = proj[..., :inner], proj[..., inner:2 * inner + 2 * state]
+        dt = proj[..., 2 * inner + 2 * state:]
+    with _probe.scope(f"{scope}.conv"):
+        xbc = jax.nn.silu(_conv(xbc, p["ssm_conv_k"], p["ssm_conv_b"])
+                          ).astype(u.dtype)
+    with _probe.scope(f"{scope}.scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                             p["ssm_dt_b"].astype(jnp.float32))
+        a = -jnp.exp(p["ssm_a_log"].astype(jnp.float32))
+        y, last = ssd(xbc[..., :inner].reshape(b, t, heads, head_dim), dt, a,
+                      xbc[..., inner:inner + state],
+                      xbc[..., inner + state:],
+                      p["ssm_d"].astype(jnp.float32), chunk)
+        y = checkpoint_name(y, "ssm_y")
+        last = lax.stop_gradient(last)
+        stats = {"ssm_decay": lax.stop_gradient(jnp.exp(dt * a)).mean(),
+                 "ssm_state_rms":
+                     jnp.sqrt((last * last).mean((1, 2, 3))).mean(),
+                 "ssm_layers": jnp.ones((), jnp.float32)}
+    with _probe.scope(scope):
+        gated = y.reshape(b, t, inner).astype(jnp.float32) * \
+            jax.nn.silu(z.astype(jnp.float32))
+        gated = gated * lax.rsqrt((gated * gated).mean(-1, keepdims=True)
+                                  + eps)
+        return (gated.astype(u.dtype) * p["ssm_g"]) @ p["ssm_out"], stats

@@ -34,7 +34,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from znicz_tpu.parallel.compat import quantized_psum, shard_map
 
 from znicz_tpu.observe import probe as _probe
-from znicz_tpu.parallel import dsa, qcomm
+from znicz_tpu.parallel import dsa, qcomm, ssm
 from znicz_tpu.parallel.moe import (MEAN_STATS, load_balance_aux, moe_ffn,
                                     moe_routed_ffn, router_z_loss)
 from znicz_tpu.parallel.pipeline import pipeline_apply
@@ -193,6 +193,18 @@ class Arch:
     the KL from the heads' mean attention probabilities to the softmax of
     the index scores over the selection, joins the loss summed over the
     layers and trains the indexer alone: ``parallel/dsa.py``).
+    A ``"mamba"`` mixer is a state-space layer (Mamba-2, ``parallel/
+    ssm.py``): ``ssm_heads`` heads of ``ssm_head_dim`` with a state of
+    ``ssm_state`` entries a head entry, one group, a depthwise convolution
+    of ``conv_taps`` taps with a bias, scanned in chunks of ``ssm_chunk``
+    positions (a tile: it changes no value).  Four static multipliers (muP's,
+    as the Granite families write them; each emits nothing at its default):
+    ``embed_mult`` on the embeddings entering layer 0, ``residual_mult`` on
+    every sub-layer's output before the residual sum, ``attn_mult`` the
+    attention scores' scale where it is not ``1 / sqrt(head_dim)`` (the
+    kernels keep their own scale; q takes ``attn_mult * sqrt(head_dim)``),
+    ``logits_div`` dividing the logits (the hidden state in front of the
+    head pass takes ``1 / logits_div``).
 
     Built by :func:`gpt_arch` (the block this module always had: the
     four integers) or :func:`arch_from_config` (a model's own keys)."""
@@ -235,8 +247,31 @@ class Arch:
     index_heads: int = 0
     index_dim: int = 0
     index_top_k: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_chunk: int = 256
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    attn_mult: float | None = None
+    logits_div: float = 1.0
 
     def __post_init__(self):
+        if self._scaled() and (
+                self.mtp or self.loop_steps > 1 or self.index_top_k or
+                not set(self.mixers) <= {"attention", "mamba"} or
+                not set(self.ffns) <= {"glu"}):
+            raise ValueError("embed_mult / residual_mult / attn_mult / "
+                             "logits_div: the multipliers are written for "
+                             "an unlooped stack of plain or grouped-query "
+                             "attention, state-space and SwiGLU sub-layers "
+                             "with no indexer and no MTP module")
+        if "mamba" in self.mixers and not (
+                self.ssm_heads > 0 and self.ssm_head_dim > 0 and
+                self.ssm_state > 0 and self.conv_taps > 0 and
+                self.ssm_chunk > 0):
+            raise ValueError("a mamba mixer needs ssm_heads, ssm_head_dim, "
+                             "ssm_state, conv_taps and ssm_chunk")
         if self.sandwich and not (set(self.mixers) <= {"attention", "latent"}
                                   and set(self.ffns) <= {"glu"}):
             raise ValueError("sandwich: the second norm is written for "
@@ -256,6 +291,10 @@ class Arch:
                              "norm closing each loop step, no MTP module "
                              "and no routed experts (their counters are "
                              "means over layers, not over loop steps)")
+
+    def _scaled(self) -> bool:
+        return (self.embed_mult, self.residual_mult, self.attn_mult,
+                self.logits_div) != (1.0, 1.0, None, 1.0)
 
     @property
     def exit_gate(self) -> bool:
@@ -284,6 +323,8 @@ class Arch:
         out = []
         if "sconv" in self.mixers:
             out.append("gated short convolution")
+        if "mamba" in self.mixers:
+            out.append(ssm.MECHANISM)
         if "latent" in self.mixers:
             out.append("latent attention")
         if self.kv_heads != self.heads:
@@ -292,6 +333,9 @@ class Arch:
             out.append("QK-norm")
         if self.rope_theta is not None:
             out.append("rotary embedding")
+        if self._scaled():
+            out.append("static multipliers (embedding, residual, scores, "
+                       "logits)")
         if self.index_top_k:
             out.append("learned sparse attention (indexer)")
         if "glu" in self.ffns:
@@ -330,7 +374,8 @@ def gpt_arch(n_layers: int, d: int, heads: int, ff: int, vocab: int,
                 experts_held=int(n_experts or 0), top_k=int(moe_top_k))
 
 
-_LAYER_TYPES = {"conv": "sconv", "full_attention": "attention"}
+_LAYER_TYPES = {"conv": "sconv", "full_attention": "attention",
+                "attention": "attention", "mamba": "mamba"}
 
 
 def _experts_held(cfg, n_experts: int) -> tuple:
@@ -354,7 +399,7 @@ def _lfm2_moe_arch(cfg, vocab: int | None) -> Arch:
     if int(cfg.get("num_hidden_layers", len(types))) != len(types):
         raise ValueError(f"num_hidden_layers {cfg['num_hidden_layers']} "
                          f"against {len(types)} layer_types")
-    unknown = sorted(set(types) - set(_LAYER_TYPES))
+    unknown = sorted(set(types) - {"conv", "full_attention"})
     if unknown:
         raise ValueError(f"layer_types {unknown}: conv or full_attention")
     d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
@@ -555,17 +600,92 @@ def _keye_vl2_arch(cfg, vocab: int | None) -> Arch:
         index_dim=int(sa["indexer_head_dim"]), index_top_k=int(sa["topk"]))
 
 
+def _granitemoehybrid_arch(cfg, vocab: int | None) -> Arch:
+    """``granitemoehybrid`` (Granite 4.0-H: ``layer_types`` of ``mamba`` and
+    ``attention``, ``mamba_n_heads``, ``mamba_d_head``, ``mamba_d_state``,
+    ``mamba_d_conv``, ``mamba_chunk_size``, ``shared_intermediate_size``,
+    and the four multipliers ``embedding_multiplier``,
+    ``attention_multiplier``, ``residual_multiplier``, ``logits_scaling``):
+    RMSNorm, Mamba-2 state-space layers (``parallel/ssm.py``: one group, a
+    biased convolution, bias-free projections) beside grouped-query
+    attention layers with NO positional encoding and the score scale
+    ``attention_multiplier``, a bias-free SwiGLU of
+    ``shared_intermediate_size`` in every layer (the family's one fused
+    ``input_linear`` is ``w1`` and ``w3`` side by side), a final norm, the
+    head tied or not.  Refused by name: experts (``num_local_experts`` > 0:
+    the family's routed part beside the shared SwiGLU is not written),
+    ``mamba_n_groups`` other than 1, a ``normalization_function`` other
+    than ``rmsnorm``, a ``position_embedding_type`` other than ``nope``, an
+    attention or projection bias, a convolution without its bias, an inner
+    width that is not ``mamba_n_heads x mamba_d_head``, an activation other
+    than silu.  ``intermediate_size`` (the experts') is not read."""
+    if int(cfg.get("num_local_experts") or 0) > 0:
+        raise ValueError(f"num_local_experts {cfg['num_local_experts']}: "
+                         f"routed experts beside the shared SwiGLU are not "
+                         f"written for this family (0 is)")
+    if int(cfg.get("mamba_n_groups", 1)) != 1:
+        raise ValueError(f"mamba_n_groups {cfg['mamba_n_groups']}: one group "
+                         f"(B and C serve all heads) is what is written")
+    if cfg.get("normalization_function", "rmsnorm") != "rmsnorm":
+        raise ValueError(f"normalization_function "
+                         f"{cfg['normalization_function']!r}: rmsnorm")
+    if cfg.get("position_embedding_type", "nope") != "nope":
+        raise ValueError(f"position_embedding_type "
+                         f"{cfg['position_embedding_type']!r}: nope (no "
+                         f"positional encoding) is what is written")
+    if cfg.get("attention_bias", False) or cfg.get("mamba_proj_bias", False):
+        raise ValueError("attention_bias / mamba_proj_bias: the projections "
+                         "here have none")
+    if not cfg.get("mamba_conv_bias", True):
+        raise ValueError("mamba_conv_bias false: the state-space layer's "
+                         "convolution here carries its bias")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {cfg['hidden_act']!r}: SwiGLU")
+    types = list(cfg["layer_types"])
+    if int(cfg.get("num_hidden_layers", len(types))) != len(types):
+        raise ValueError(f"num_hidden_layers {cfg['num_hidden_layers']} "
+                         f"against {len(types)} layer_types")
+    unknown = sorted(set(types) - {"mamba", "attention"})
+    if unknown:
+        raise ValueError(f"layer_types {unknown}: mamba or attention")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    m_heads, m_dim = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
+    if int(cfg.get("mamba_expand", 2)) * d != m_heads * m_dim:
+        raise ValueError(
+            f"mamba_expand {cfg.get('mamba_expand', 2)} x hidden_size {d} "
+            f"against mamba_n_heads {m_heads} x mamba_d_head {m_dim}")
+    return Arch(
+        d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=int(cfg.get("head_dim") or d // heads),
+        ff=int(cfg["shared_intermediate_size"]),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=tuple(_LAYER_TYPES[t] for t in types),
+        ffns=("glu",) * len(types), norm="rms",
+        eps=float(cfg.get("rms_norm_eps", 1e-5)),
+        conv_taps=int(cfg.get("mamba_d_conv", 4)), final_norm=True,
+        tied=bool(cfg.get("tie_word_embeddings", True)),
+        ssm_heads=m_heads, ssm_head_dim=m_dim,
+        ssm_state=int(cfg["mamba_d_state"]),
+        ssm_chunk=int(cfg.get("mamba_chunk_size", 256)),
+        embed_mult=float(cfg.get("embedding_multiplier", 1.0)),
+        residual_mult=float(cfg.get("residual_multiplier", 1.0)),
+        attn_mult=float(cfg["attention_multiplier"])
+        if cfg.get("attention_multiplier") is not None else None,
+        logits_div=float(cfg.get("logits_scaling", 1.0)))
+
+
 #: ``model_type`` -> the reader of that family's keys
 _FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch,
-             "ouro": _ouro_arch, "KeyeVL2": _keye_vl2_arch}
+             "ouro": _ouro_arch, "KeyeVL2": _keye_vl2_arch,
+             "granitemoehybrid": _granitemoehybrid_arch}
 
 
 def arch_from_config(cfg, vocab: int | None = None) -> Arch:
     """A model's own keys -> :class:`Arch`, by ``model_type``
     (:data:`_FAMILIES`: :func:`_lfm2_moe_arch`, also what a mapping with
     ``layer_types`` and no ``model_type`` is read as,
-    :func:`_glm4_moe_lite_arch`, :func:`_ouro_arch` and
-    :func:`_keye_vl2_arch`).  ``experts_held`` (``{"first",
+    :func:`_glm4_moe_lite_arch`, :func:`_ouro_arch`,
+    :func:`_keye_vl2_arch` and :func:`_granitemoehybrid_arch`).  ``experts_held`` (``{"first",
     "count"}``; all by default) is this chip's share of the experts;
     ``vocab`` (the loader's) overrides ``vocab_size``.  Any other
     ``model_type`` is refused by name."""
@@ -596,6 +716,7 @@ _LEAF_MECHANISMS = {
     "wkv_a": "latent attention", "sw1": "shared expert",
     "ln1o_g": "sandwich norm",
     "wiq": "learned sparse attention (indexer)",
+    "ssm_a_log": ssm.MECHANISM,
 }
 
 
@@ -651,6 +772,9 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
             hi, di = arch.index_heads, arch.index_dim
             out.update({"wiq": (d, hi * di), "wik": (d, di), "wiw": (d, hi),
                         "ik_g": (di,), "ik_b": (di,)})
+    elif mixer == "mamba":
+        out.update(ssm.leaf_shapes(d, arch.ssm_heads, arch.ssm_head_dim,
+                                   arch.ssm_state, arch.conv_taps))
     else:
         out.update({"w_in": (d, 3 * d), "conv_k": (arch.conv_taps, d),
                     "w_out": (d, d)})
@@ -700,9 +824,9 @@ def _mtp_shapes(arch: Arch) -> dict:
 
 #: leaves that start at one (gains), and those that start at zero
 _ONES = ("ln1_g", "ln2_g", "ln1o_g", "ln2o_g", "q_g", "k_g", "norm_g",
-         "q_a_g", "kv_a_g", "enorm_g", "hnorm_g", "ik_g")
+         "q_a_g", "kv_a_g", "enorm_g", "hnorm_g", "ik_g", "ssm_g", "ssm_d")
 _ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias", "exit_b",
-          "ik_b")
+          "ik_b", "ssm_conv_b")
 #: how each leaf of the GPT-shaped block lies over the ``model`` axis
 _TP_SPECS = {
     "wq": P(None, "model"), "wk": P(None, "model"), "wv": P(None, "model"),
@@ -723,7 +847,10 @@ def init_params(gen, arch, d=None, heads=None, ff=None, vocab=None,
     FFN: gate + per-expert w1/b1/w2/b2 stacks, expert-sharded over the
     ``model`` axis at placement time).  Projections are normal
     ``1/sqrt(fan_in)``, the embedding normal 0.02, gains one, biases
-    zero; the short convolution's taps are normal ``1/sqrt(taps)``."""
+    zero; a convolution's taps are normal ``1/sqrt(taps)``; a state-space
+    layer's decay rates uniform 1 .. 16 (``ssm_a_log`` their log), its step
+    sizes log-uniform 0.001 .. 0.1 (``ssm_dt_b`` their inverse softplus),
+    its skip one, as Mamba-2 starts them."""
     arch = as_arch(arch, d, heads, ff, vocab, n_experts)
 
     def w(shape, scale=None):
@@ -736,8 +863,13 @@ def init_params(gen, arch, d=None, heads=None, ff=None, vocab=None,
             return np.ones(shape, np.float32)
         if name in _ZEROS:
             return np.zeros(shape, np.float32)
-        if name == "conv_k":
+        if name in ("conv_k", "ssm_conv_k"):
             return w(shape, 1.0 / np.sqrt(shape[0]))
+        if name == "ssm_a_log":
+            return np.log(gen.uniform(1.0, 16.0, shape)).astype(np.float32)
+        if name == "ssm_dt_b":
+            dt = np.exp(gen.uniform(np.log(1e-3), np.log(1e-1), shape))
+            return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
         return w(shape)
 
     if arch.n_layers and set(arch.ffns) <= {"mlp", "moe_dense"} and \
@@ -896,11 +1028,13 @@ def _norm(x, p, which: str, arch: Arch):
 
 def _sub_out(y, p, which: str, arch: Arch):
     """A sub-layer's output on its way to the residual sum: named for the
-    looped stack's recomputation policy (:func:`_block_fn`; a name is no
-    operation), and through the sandwich's second norm where the stack
-    has one."""
+    recomputation policies (:func:`_block_fn`; a name is no operation),
+    through the sandwich's second norm where the stack has one, and times
+    ``arch.residual_mult`` where that is not 1."""
     y = checkpoint_name(y, "sub_out")
-    return _norm(y, p, which, arch) if arch.sandwich else y
+    if arch.sandwich:
+        y = _norm(y, p, which, arch)
+    return y if arch.residual_mult == 1.0 else y * arch.residual_mult
 
 
 def _rope_angles(t: int, dh: int, theta: float):
@@ -939,7 +1073,8 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     kernel or has an indexer, whose alignment term ``aux`` carries).
     Scopes: ``block<index>.attn`` (with ``.attn.latent`` beside it for
     what latent attention does before the kernel, ``.attn.index``,
-    ``.attn.select`` and ``.attn.align`` for an indexer) or ``.sconv``, then
+    ``.attn.select`` and ``.attn.align`` for an indexer), ``.sconv`` or
+    ``.ssm`` (with ``.ssm.conv`` and ``.ssm.scan`` beside it), then
     ``block<index>.mlp`` or ``.moe`` (with ``.moe.route``,
     ``.moe.experts`` and ``.moe.shared`` beside it)."""
     mixer, ffn = arch.kinds(index)
@@ -947,6 +1082,8 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     if mixer == "sconv":
         with _probe.scope(f"block{index}.sconv"):
             x = _block_sconv(x, p, arch)
+    elif mixer == "mamba":
+        x, stats = _block_ssm(x, p, arch, f"block{index}.ssm")
     else:
         x, stats = _block_attn(x, p, arch, run, f"block{index}.attn")
     if ffn == "moe_routed":
@@ -961,10 +1098,23 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     return x, aux, stats
 
 
+def _block_ssm(x, p, arch: Arch, scope: str):
+    """A state-space layer (``ssm.mixer``) on the normed stream; the norm
+    and the residual sum lie under ``scope``.  -> ``(x, stats)``."""
+    with _probe.scope(scope):
+        u = _norm(x, p, "ln1", arch)
+    y, stats = ssm.mixer(u, p, arch.ssm_heads, arch.ssm_head_dim,
+                         arch.ssm_state, arch.ssm_chunk, arch.eps, scope)
+    with _probe.scope(scope):
+        return x + _sub_out(y, p, "ln1o", arch), stats
+
+
 def _plain_qkv(h, p, arch: Arch, run: _Run):
     """Queries, keys and values ``(b, t, heads, head_dim)`` of plain or
-    grouped-query attention: three projections, the optional QK-norm,
-    the optional rotary embedding over the whole head: rotate-half, by the
+    grouped-query attention: three projections, the score scale where it is
+    not the kernels' own (``arch.attn_mult``: q takes ``attn_mult *
+    sqrt(head_dim)``, exact where that is a power of two), the optional
+    QK-norm, the optional rotary embedding over the whole head: rotate-half, by the
     in-place row kernel where :func:`_rows_rope` says so (a head of 128:
     the whole head is the kernel's tail), else :func:`_rotate`'s f32 chain
     of array operations.  What no kernel wrote is named ``attn_qkv`` for
@@ -977,6 +1127,8 @@ def _plain_qkv(h, p, arch: Arch, run: _Run):
         return y.reshape(b, t_loc, n, -1)
 
     q = heads_of(p["wq"], run.heads_local)
+    if arch.attn_mult is not None:
+        q = q * (arch.attn_mult * float(np.sqrt(arch.head_dim)))
     k = heads_of(p["wk"], run.kv_heads_local)
     v = checkpoint_name(heads_of(p["wv"], run.kv_heads_local), "attn_qkv")
     if arch.qk_norm:
@@ -1519,8 +1671,10 @@ def _cast_params(ps, arch: Arch, cdt):
     choice of buffer, and take their gradients from there in the master
     dtype (a cast out here would stand alone on both sides of that
     choice: 12 ms of the step, my chip run, PR 29).  The exit gate of a
-    looped stack stays in the master dtype too.  A looped stack reads this
-    one cast in every loop step."""
+    looped stack stays in the master dtype too, and a state-space layer's
+    step-size bias, decay rates and skip (``ssm.F32_LEAVES``: they enter
+    float32 chains).  A looped stack reads this one cast in every loop
+    step."""
     out = jax.tree.map(lambda w: w.astype(cdt), ps)
     layers = list(zip(ps["blocks"], out["blocks"]))
     if arch.mtp:
@@ -1530,6 +1684,8 @@ def _cast_params(ps, arch: Arch, cdt):
             for k in ("gate", "ebias", "ew1", "ew3", "ew2"):
                 if k in master:
                     cast[k] = master[k]
+        if "ssm_a_log" in master:
+            cast.update({k: master[k] for k in ssm.F32_LEAVES})
     if arch.exit_gate:                     # its product is taken in f32
         out.update({k: ps[k] for k in ("exit_w", "exit_b")})
     return out
@@ -1554,7 +1710,7 @@ def _sum_stats(a: dict, b: dict) -> dict:
 
 
 _SAVED_NAMES = jax.checkpoint_policies.save_only_these_names(
-    "attn_qkv", "sub_out")
+    "attn_qkv", "sub_out", "ssm_y", "ssm_state")
 
 
 def _loop_saves(prim, *_, **params) -> bool:
@@ -1566,7 +1722,15 @@ def _loop_saves(prim, *_, **params) -> bool:
     that is seven arrays of ``(tokens, d)``.  Recomputed: the four norms,
     the rotary embedding's f32 chain, the residual sums and the SwiGLU's
     two wide products with their gated product (three arrays of
-    ``(tokens, ff)``, 12 % of a layer's operations)."""
+    ``(tokens, ff)``, 12 % of a layer's operations).  It is also what a
+    layer of a stack with state-space layers keeps (:func:`_block_fn`): of
+    such a layer the scan's output and each chunk's opening state too
+    (``ssm_y``, ``ssm_state``: the scan's forward pass is not run a second
+    time; its backward pass makes a chunk's decay and score matrices again,
+    ``parallel/ssm.py``), with the wide input projection and its split, the
+    convolution, the gate and the gated norm made again: a layer holds five
+    or six arrays of ``(tokens, d)`` and its chunk states where it would
+    hold ``(tokens, 8.5 d)`` of them."""
     return prim.name == "pallas_call" or _SAVED_NAMES(prim, *_, **params)
 
 
@@ -1574,7 +1738,9 @@ def _block_fn(remat: bool, remat_policy: str | None, arch: Arch):
     """:func:`_block`, or its checkpointed form.  A looped stack holds
     ``loop_steps`` times the activations its weights suggest, so it always
     recomputes, the cheapest things first (:func:`_loop_saves`), and that
-    is its one recomputation path: the two keywords are refused there."""
+    is its one recomputation path: the two keywords are refused there.  A
+    stack with state-space layers recomputes by the same policy unless a
+    keyword says otherwise (a layer's wide arrays are 8.5 ``d`` a token)."""
     if arch.loop_steps > 1:
         if remat or remat_policy:
             raise ValueError("remat / remat_policy: a looped stack always "
@@ -1582,6 +1748,8 @@ def _block_fn(remat: bool, remat_policy: str | None, arch: Arch):
         pol = _loop_saves
     elif remat or remat_policy:
         pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
+    elif "mamba" in arch.mixers:
+        pol = _loop_saves
     else:
         return _block
     return jax.checkpoint(_block, policy=pol, static_argnums=(2, 3, 4))
@@ -1633,10 +1801,11 @@ def _looped(ps, x, arch: Arch, run: _Run, blk, each=None, carry=()):
 
 def _embedded(ps, tokens, arch: Arch, cdt):
     """-> ``(the params cast once for the step, the tokens' embeddings
-    (b_l, t_l, d))``."""
+    (b_l, t_l, d))``, times ``arch.embed_mult`` where that is not 1."""
     ps = _cast_params(ps, arch, cdt)
     with _probe.scope("embed"):
-        return ps, ps["emb"][tokens]
+        x = ps["emb"][tokens]
+        return ps, x if arch.embed_mult == 1.0 else x * arch.embed_mult
 
 
 def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
@@ -1646,8 +1815,9 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
     by the CE loss (:func:`_forward_ce`) and the full-pass logits oracle
     (:func:`make_logits_fn`, the generative serving plane's correctness
     anchor).  Returns ``(x, aux_term, ps_cast, stats)`` — the hidden
-    states (through the final norm where the stack has one; of a looped
-    stack the last loop step's), the summed MoE regularizer term, the
+    states (through the final norm where the stack has one, and divided
+    by ``arch.logits_div``; of a looped stack the last loop step's), the
+    summed MoE regularizer term, the
     compute-dtype-cast params (so the caller's head matmul uses the same
     precision policy) and the routed layers' counters summed over the
     layers."""
@@ -1660,6 +1830,11 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
     if arch.final_norm:
         with _probe.scope("ce"):
             x = _rms_norm(x, ps["norm_g"], arch.eps)
+    if arch.logits_div != 1.0:
+        # the logits divided: the hidden state is, once, in front of the
+        # head pass (exact where the divisor is a power of two)
+        with _probe.scope("ce"):
+            x = x * (1.0 / arch.logits_div)
     return x, aux_term, ps, stats
 
 

@@ -80,7 +80,8 @@ class TransformerLMStep(AcceleratedUnit):
     (``model_type`` and that family's: ``layer_types``,
     ``num_dense_layers``, ``num_experts``, ... or ``q_lora_rank``,
     ``n_shared_experts``, ``num_nextn_predict_layers``, ... or
-    ``total_ut_steps``, ... or ``sa_config``, ``num_experts``, ... and
+    ``total_ut_steps``, ... or ``sa_config``, ``num_experts``, ... or
+    ``mamba_n_heads``, ``embedding_multiplier``, ... and
     ``experts_held``, this chip's share:
     ``{"first", "count"}``; see
     ``parallel.transformer.arch_from_config``); the vocabulary is the
@@ -175,6 +176,12 @@ class TransformerLMStep(AcceleratedUnit):
         #: alignment term, summed over the layers) and
         #: ``index_loss_share`` (its share of the pass's loss)
         self.dsa_counters: dict = {}
+        #: the last finished training pass's readings of a stack with
+        #: state-space layers (``parallel/ssm.py``), means over its steps
+        #: and layers: ``decay_mean`` (of ``exp(dt A)`` over positions and
+        #: heads) and ``final_state_rms`` (RMS of the state behind a row's
+        #: last position); neither depends on the chunk the scan runs in
+        self.ssm_counters: dict = {}
         #: of the last finished pass's attention layers that ran a flash
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
@@ -373,6 +380,8 @@ class TransformerLMStep(AcceleratedUnit):
         self._publish_terms(sums, steps)
         if "dsa_pairs" in sums:
             self._publish_dsa(sums, steps, self.minibatch_mse)
+        if sums.get("ssm_layers"):
+            self._publish_ssm(sums)
         if "attn_flash" in sums:
             self._publish_attn_layout(float(sums["attn_direct"]) /
                                       float(sums["attn_flash"]))
@@ -479,6 +488,31 @@ class TransformerLMStep(AcceleratedUnit):
                 "plus the term), last class pass", ("unit",))}
         for key, value in self.dsa_counters.items():
             gauges[key].labels(unit=self.name).set(value)
+
+    def _publish_ssm(self, sums: dict) -> None:
+        """A finished training pass's readings of the state-space layers
+        (each summed over the pass's steps and layers, as their count is):
+        the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        layers = float(sums["ssm_layers"])
+        self.ssm_counters = {
+            "decay_mean": float(sums["ssm_decay"]) / layers,
+            "final_state_rms": float(sums["ssm_state_rms"]) / layers}
+        registry.gauge(
+            "znicz_lm_ssm_decay_mean",
+            "mean over positions, heads, state-space layers and the last "
+            "training pass's steps of the state's decay a position, "
+            "exp(dt A) (1: the state is kept whole; 0: dropped)",
+            ("unit",)).labels(unit=self.name).set(
+                self.ssm_counters["decay_mean"])
+        registry.gauge(
+            "znicz_lm_ssm_final_state_rms",
+            "RMS of a state-space layer's state behind a row's last "
+            "position, mean over rows, layers and the last training "
+            "pass's steps (a carry that is dropped or shortened moves it)",
+            ("unit",)).labels(unit=self.name).set(
+                self.ssm_counters["final_state_rms"])
 
     def _publish_terms(self, sums: dict, steps: float) -> None:
         """A finished training pass's named terms, each the mean over its
