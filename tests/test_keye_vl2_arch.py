@@ -133,22 +133,26 @@ def test_first_three_steps_follow_the_reference_with_a_selection_that_bites():
         == layers * b
 
 
-@pytest.mark.parametrize("head_dim,align_kernel", [(64, False), (128, True)])
+@pytest.mark.parametrize("head_dim,index_dim,align_kernel", [
+    (64, 8, False), (128, 8, True), (128, 64, True), (64, 16, False)])
 def test_the_step_with_its_kernels_interpreted_follows_the_reference(
-        head_dim, align_kernel):
+        head_dim, index_dim, align_kernel):
     """``engine.pallas_interpret`` puts the blocked flash kernels WITH the
     selection operand into the step (and no other attention kernel), at
     256 positions, two 128-row tiles a side: loss, the alignment term and
     every leaf's gradient stay the reference's.  At a head of 64 the
     alignment target is the ``jax.numpy`` form's (the kernel refuses the
-    width), at 128 the kernel's, whose name then stands in the step."""
+    width), at 128 the kernel's, whose name then stands in the step; the
+    index scores and their gradients are their two kernels' at any index
+    width (the benchmark's 64 among them), beside either."""
     from test_lfm2_arch import _pallas_interpret
     from znicz_tpu.ops.pallas import dsa as pdsa
 
     rope = head_dim // 2
     cfg = _cfg(hidden_size=64, head_dim=head_dim, num_attention_heads=2,
                num_key_value_heads=1, num_hidden_layers=1,
-               sa_config={**TINY["sa_config"], "topk": 48},
+               sa_config={**TINY["sa_config"], "topk": 48,
+                          "indexer_head_dim": index_dim},
                rope_scaling={"mrope_section": [rope // 4, 3 * rope // 8,
                                                3 * rope // 8],
                              "rope_type": "default", "type": "default"})
@@ -156,8 +160,8 @@ def test_the_step_with_its_kernels_interpreted_follows_the_reference(
     want = ref.first_steps(5, cfg, traffic, 1, steps=1)
     with _pallas_interpret(True):
         arch = _arch(cfg)
-        assert tfm.dsa_align_kernel_share(_mesh1(), arch, 256) == float(
-            align_kernel)
+        assert tfm.dsa_kernel_shares(_mesh1(), arch, 256) == {
+            "index": 1.0, "align": float(align_kernel)}
         text = str(jax.make_jaxpr(tfm.make_train_step(
             _mesh1(), arch, compute_dtype=jnp.float32)[0])(
                 ref.init_params(5, cfg), jnp.zeros((1, 256), jnp.int32),
@@ -170,6 +174,8 @@ def test_the_step_with_its_kernels_interpreted_follows_the_reference(
                  pattn.KVB_DQ_KERNEL_NAME + '"'):
         assert name not in text
     assert (pdsa.ALIGN_KERNEL_NAME in text) == align_kernel
+    for name in (pdsa.INDEX_SCORES_KERNEL_NAME, pdsa.INDEX_GRADS_KERNEL_NAME):
+        assert name in text
     assert losses[0] == pytest.approx(want["loss"][0], rel=2e-5)
     assert index[0] == pytest.approx(want["loss_index"][0], rel=2e-5)
     _check_gradients(grads, want, norm_rel=5e-3, diff_rel=1e-2)
@@ -420,9 +426,10 @@ def test_the_unit_publishes_the_selections_counters(tmp_path):
         assert fam is not None and fam.labels(unit=step.name).get() == value
     # 32 positions are no block of queries: the jax.numpy form, and the
     # gauge says so
-    assert step.dsa_align_kernel_share == 0.0
-    fam = registry.REGISTRY.get("znicz_lm_dsa_align_kernel_share")
-    assert fam is not None and fam.labels(unit=step.name).get() == 0.0
+    assert step.dsa_align_kernel_share == step.dsa_index_kernel_share == 0.0
+    for what in ("align", "index"):
+        fam = registry.REGISTRY.get(f"znicz_lm_dsa_{what}_kernel_share")
+        assert fam is not None and fam.labels(unit=step.name).get() == 0.0
     with pytest.raises(ValueError, match="indexer"):
         step.export_lm(str(tmp_path / "pkg.npz"))
     state = step.state_dict()
@@ -432,22 +439,12 @@ def test_the_unit_publishes_the_selections_counters(tmp_path):
         step.load_state_dict(state)
 
 
-@pytest.mark.parametrize("head_dim,seq_len,interpret,share", [
-    (128, 256, True, 1.0),      # the kernel's shape, kernels interpreted
-    (128, 256, False, 0.0),     # the same shape on this backend as it is
-    (64, 256, True, 0.0),       # a head the kernel refuses
-    (128, 96, True, 0.0),       # no whole blocks of queries
-])
-def test_the_unit_publishes_the_alignment_kernels_share(head_dim, seq_len,
-                                                        interpret, share):
-    """``znicz_lm_dsa_align_kernel_share`` and the unit's mirror, set as
-    the step is built from what :func:`dsa.align_kernel_refusal` says of
-    its shape: 1.0 where the kernel makes the target, 0.0 where the shape
-    or the backend leaves it to the ``jax.numpy`` form."""
+def _built_step(head_dim, seq_len, interpret):
+    """A one-layer step unit with an indexer, built (not run) at ``seq_len``
+    positions with the step's kernels interpreted or not."""
     from builders import lm_train_keys
     from test_lfm2_arch import _pallas_interpret
     from znicz_tpu.core.backends import XLADevice
-    from znicz_tpu.observe import registry
 
     rope = head_dim // 2
     cfg = {**_cfg(hidden_size=64, head_dim=head_dim, num_attention_heads=2,
@@ -464,9 +461,48 @@ def test_the_unit_publishes_the_alignment_kernels_share(head_dim, seq_len,
         w = lm_train_keys.build_workflow(rows, cfg, traffic)
         w.step._params = ref.init_params(17, cfg)
         w.initialize(device=XLADevice())
-    assert w.step.dsa_align_kernel_share == share
+    return w.step
+
+
+@pytest.mark.parametrize("head_dim,seq_len,interpret,share", [
+    (128, 256, True, 1.0),      # the kernel's shape, kernels interpreted
+    (128, 256, False, 0.0),     # the same shape on this backend as it is
+    (64, 256, True, 0.0),       # a head the kernel refuses
+    (128, 96, True, 0.0),       # no whole blocks of queries
+])
+def test_the_unit_publishes_the_alignment_kernels_share(head_dim, seq_len,
+                                                        interpret, share):
+    """``znicz_lm_dsa_align_kernel_share`` and the unit's mirror, set as
+    the step is built from what :func:`dsa.align_kernel_refusal` says of
+    its shape: 1.0 where the kernel makes the target, 0.0 where the shape
+    or the backend leaves it to the ``jax.numpy`` form."""
+    from znicz_tpu.observe import registry
+
+    step = _built_step(head_dim, seq_len, interpret)
+    assert step.dsa_align_kernel_share == share
     fam = registry.REGISTRY.get("znicz_lm_dsa_align_kernel_share")
-    assert fam is not None and fam.labels(unit=w.step.name).get() == share
+    assert fam is not None and fam.labels(unit=step.name).get() == share
+
+
+@pytest.mark.parametrize("head_dim,seq_len,interpret,share", [
+    (64, 256, True, 1.0),       # whole blocks of queries, kernels interpreted
+    (64, 256, False, 0.0),      # the same shape on this backend as it is
+    (128, 96, True, 0.0),       # no whole blocks of queries
+])
+def test_the_unit_publishes_the_index_kernels_share(head_dim, seq_len,
+                                                    interpret, share):
+    """``znicz_lm_dsa_index_kernel_share`` and the unit's mirror, set as the
+    step is built from what :func:`dsa.index_kernel_refusal` says of its
+    shape: 1.0 where the two kernels make the index scores and their
+    gradients (whatever the attention's head, which is the alignment
+    kernel's affair), 0.0 where the shape or the backend leaves them to the
+    einsums."""
+    from znicz_tpu.observe import registry
+
+    step = _built_step(head_dim, seq_len, interpret)
+    assert step.dsa_index_kernel_share == share
+    fam = registry.REGISTRY.get("znicz_lm_dsa_index_kernel_share")
+    assert fam is not None and fam.labels(unit=step.name).get() == share
 
 
 def test_serving_refuses_the_indexer_by_name():

@@ -14,10 +14,15 @@ nothing else: the caller hands the indexer a detached input, and the
 selection carries no gradient.
 
 :func:`index_select_align` computes all three a block of queries at a time
-(``lax.scan`` over blocks of :data:`Q_BLOCK` rows), so that the largest
-temporary is a block's scores over the keys, ``(heads, Q_BLOCK, t)``
-float32: 256 MiB at 32 heads and 16,384 positions, whatever ``t`` squared
-is.  What leaves is the selection itself (int8 ``(b, t, t)``, the operand
+(``lax.scan`` over blocks of :data:`Q_BLOCK` rows), so that no temporary
+is a square of ``t``.  Where the kernels below run, nothing a block makes
+in HBM is wider than its ``(Q_BLOCK, t)`` float32 rows (the index scores
+summed over their heads, the ordered keys, the target, ``dL/dI``: 8 MiB
+each at 16,384 positions): no ``(heads, Q_BLOCK, t)`` temporary is left,
+of the index heads or of the attention's.  In the ``jax.numpy`` forms the
+largest is a block's scores over the keys, ``(heads, Q_BLOCK, t)`` float32:
+256 MiB at 32 heads and 16,384 positions.  What leaves is the selection
+itself (int8 ``(b, t, t)``, the operand
 the flash kernels take: ``ops/pallas/attention.py``), the loss and, under
 differentiation, the loss's gradients to ``qI``, ``kI`` and ``w``, made
 in the block that made the scores (a ``custom_vjp`` as the chunked
@@ -44,16 +49,34 @@ second time: the flash kernels keep none) leaves the MXU in the compute
 dtype, as the head pass's logits do, which halves what a block writes and
 reads back.
 
-The target has two implementations of one algorithm.  Where the step's
-kernels run (a TPU, or interpreted) and the shape fits
-(:func:`align_kernel_refusal`), a Pallas kernel makes it a block at a time
-(``ops/pallas/dsa.py``, :data:`~znicz_tpu.ops.pallas.dsa.ALIGN_KERNEL_NAME`):
-two sweeps over key tiles, the heads' scores float32 in VMEM from the MXU to
-the ``exp``, none in HBM, and no tile above the block's last query visited.
-Everywhere else the blocked ``jax.numpy`` form above.  Both give the same
+The target and the index scores each have two implementations of one
+algorithm.  Where the step's kernels run (a TPU, or interpreted) and the
+shape fits (:func:`align_kernel_refusal`, :func:`index_kernel_refusal`:
+one question, :func:`_kernel_refusal`, with each kernel's own reasons), a
+block's work is Pallas kernels (``ops/pallas/dsa.py``) whose scores stay
+float32 in VMEM from the MXU on, none in HBM, and that visit no key tile
+above the block's last query:
+
+- the target, :data:`~znicz_tpu.ops.pallas.dsa.ALIGN_KERNEL_NAME`: two
+  sweeps over key tiles, the attention heads' scores from the MXU to the
+  ``exp``;
+- the index scores, :data:`~znicz_tpu.ops.pallas.dsa.
+  INDEX_SCORES_KERNEL_NAME`: ``qI kI^T`` a few index heads at a time,
+  ``relu``, the weighting and the sum over the heads into the block's
+  ``(Q_BLOCK, tile)`` rows; and their gradients, :data:`~znicz_tpu.ops.
+  pallas.dsa.INDEX_GRADS_KERNEL_NAME`: the same product again in the tile
+  (the forward pass keeps neither ``s`` nor ``relu(s)``), ``g = dL/dI * w *
+  (s > 0)`` rounded to the operands' dtype as the einsums round it, ``g
+  kI`` summed over the tiles, ``qI^T g`` a tile at a time into the scan's
+  carry (keys-minor, as XLA lays the carry out), and ``dL/dw``.  They read
+  a block's ``qI`` as the layer has it, ``(Q_BLOCK, hi x di)``, a head cut
+  from the lanes: no copy of ``qI`` is prepared.
+
+Everywhere else the blocked ``jax.numpy`` forms above.  Both give the same
 ``p`` to rounding (the kernel's scores are not rounded to the compute
-dtype); the index scores, the threshold, the selection, the KL and the
-gradients are the same code under either.
+dtype) and the same index scores and gradients to the order of their
+float32 sums; the threshold, the selection, the KL and ``dL/dI`` are the
+same code under either.
 
 Scopes ``<scope>.index`` (the scores, and their gradients'
 ``transpose(jvp(...))``), ``<scope>.select`` (keys, threshold, the
@@ -114,29 +137,51 @@ def _blocks_of(t: int) -> tuple[int, int]:
     return block, groups
 
 
-def align_kernel_refusal(t: int, heads: int, kv: int, dh: int,
-                         interpret: bool) -> str | None:
-    """Why the alignment target of ``t`` positions of ``heads`` heads ``dh``
-    wide on ``kv`` key/value heads is left to the ``jax.numpy`` form, or
-    ``None`` where the kernel makes it (``ops/pallas/dsa.py``): where the
-    step's kernels run at all (a TPU, or ``interpret``: interpreted), whole
-    blocks of :data:`Q_BLOCK` queries, and a shape the kernel's tiles take
-    at every group's key extent (multiples of ``t / groups``)."""
+def _kernel_refusal(t: int, interpret: bool, of_shape) -> str | None:
+    """Why a pass over ``t`` positions is left to its ``jax.numpy`` form, or
+    ``None`` where its kernel runs (``ops/pallas/dsa.py``): where the step's
+    kernels run at all (a TPU, or ``interpret``: interpreted), whole blocks
+    of :data:`Q_BLOCK` queries, and a shape the kernel's tiles take at every
+    group's key extent (``of_shape(block, keys)``, the kernel's own reason,
+    for extents that are multiples of ``keys = t / groups``)."""
     if not interpret and jax.default_backend() != "tpu":
         return (f"the backend is {jax.default_backend()} and the step's "
                 f"kernels are not interpreted")
     if t % Q_BLOCK:
         return f"t={t} is not a multiple of the {Q_BLOCK}-row block of queries"
     block, groups = _blocks_of(t)
-    return _pdsa.unsupported_reason(block, t // groups, heads, kv, dh)
+    return of_shape(block, t // groups)
+
+
+def align_kernel_refusal(t: int, heads: int, kv: int, dh: int,
+                         interpret: bool) -> str | None:
+    """Why the alignment target of ``t`` positions of ``heads`` heads ``dh``
+    wide on ``kv`` key/value heads is left to the ``jax.numpy`` form, or
+    ``None`` where the kernel makes it (:func:`_kernel_refusal`)."""
+    return _kernel_refusal(t, interpret, lambda block, keys:
+                           _pdsa.unsupported_reason(block, keys, heads, kv,
+                                                    dh))
+
+
+def index_kernel_refusal(t: int, hi: int, di: int,
+                         interpret: bool) -> str | None:
+    """Why the index scores of ``t`` positions of ``hi`` index heads ``di``
+    wide and their three gradients are left to the ``jax.numpy`` einsums,
+    or ``None`` where the two kernels make them (:func:`_kernel_refusal`)."""
+    return _kernel_refusal(t, interpret, lambda block, keys:
+                           _pdsa.index_unsupported_reason(block, keys, hi,
+                                                          di))
 
 
 def _one_block(ki, k, top_k: int, scale, weight, grads: bool, scope: str,
-               kernel: bool, interpret: bool):
+               kernel: bool, index_kernel: bool, interpret: bool):
     """The scan body over blocks of queries against the keys ``ki`` ``(tk,
-    di)`` and ``k`` ``(tk, kv, dh)``; the carry is ``dL/dkI``.  ``kernel``:
+    di)`` and ``k`` ``(tk, kv, dh)``; the carry is ``dL/dkI``, ``(tk, di)``
+    or, with ``index_kernel``, keys-minor ``(di, tk)``.  ``kernel``:
     the target by the Pallas kernel, the block's ``q`` then head-major
-    ``(kv, grp * bq, dh)``."""
+    ``(kv, grp * bq, dh)``; ``index_kernel``: the index scores and their
+    gradients by theirs, the block's ``qi`` (and ``dqi``) then ``(bq, hi *
+    di)``, the heads side by side."""
     tk = ki.shape[0]
     f32 = jnp.float32
 
@@ -162,13 +207,19 @@ def _one_block(ki, k, top_k: int, scale, weight, grads: bool, scope: str,
         return p / (kv * grp)                                # (bq, tk)
 
     def body(dki, xs):
-        # (bq, hi, di) (bq, hi) (bq, kv, grp, dh) or head-major (bq,)
+        # (bq, hi, di) or (bq, hi * di), (bq, hi), (bq, kv, grp, dh) or
+        # head-major, (bq,)
         qi, w, q, pos = xs
         causal = jnp.arange(tk)[None, :] <= pos[:, None]
         with _probe.scope(f"{scope}.index"):
-            s = jnp.einsum("qjd,kd->qjk", qi, ki, preferred_element_type=f32)
-            r = jnp.maximum(s, 0.0)
-            idx = (r * w[:, :, None]).sum(1)                     # (bq, tk)
+            if index_kernel:
+                idx = _pdsa.index_scores(qi, ki, w, pos[-1],
+                                         interpret=interpret)
+            else:
+                s = jnp.einsum("qjd,kd->qjk", qi, ki,
+                               preferred_element_type=f32)
+                r = jnp.maximum(s, 0.0)
+                idx = (r * w[:, :, None]).sum(1)                 # (bq, tk)
         with _probe.scope(f"{scope}.select"):
             keys = jnp.where(causal, sortable_keys(idx), jnp.uint32(0))
             sel = (keys >= kth_largest_key(keys, top_k)[:, None]) & causal
@@ -190,11 +241,18 @@ def _one_block(ki, k, top_k: int, scale, weight, grads: bool, scope: str,
         if not grads:
             return dki, out
         with _probe.scope_bwd(f"{scope}.index"):
-            g = (d_idx[:, None, :] * w[:, :, None] * (s > 0)).astype(qi.dtype)
-            dqi = jnp.einsum("qjk,kd->qjd", g, ki, preferred_element_type=f32)
-            dki = dki + jnp.einsum("qjk,qjd->kd", g, qi,
-                                   preferred_element_type=f32)
-            dw = (d_idx[:, None, :] * r).sum(-1)
+            if index_kernel:
+                dqi, dk_i, dw = _pdsa.index_grads(qi, ki, w, d_idx, pos[-1],
+                                                  interpret=interpret)
+            else:
+                g = (d_idx[:, None, :] * w[:, :, None] *
+                     (s > 0)).astype(qi.dtype)
+                dqi = jnp.einsum("qjk,kd->qjd", g, ki,
+                                 preferred_element_type=f32)
+                dk_i = jnp.einsum("qjk,qjd->kd", g, qi,
+                                  preferred_element_type=f32)
+                dw = (d_idx[:, None, :] * r).sum(-1)
+            dki = dki + dk_i
         return dki, out + (dqi, dw)
 
     return body
@@ -209,12 +267,17 @@ def _row(qi, ki, w, q, k, top_k: int, weight, grads: bool, scope: str,
     kv = k.shape[1]
     block, groups = _blocks_of(t)
     rows = t // groups
+    iheads, di = qi.shape[1:]
     kernel = align_kernel_refusal(t, h, kv, dh, interpret) is None
+    index_kernel = index_kernel_refusal(t, iheads, di, interpret) is None
     q = q.reshape(t // block, block, kv, h // kv, dh)
     if kernel:
         # the kernel's rows, head-major a block: one pass over q a layer
         with _probe.scope(f"{scope}.align"):
             q = q.transpose(0, 2, 3, 1, 4).reshape(t // block, kv, -1, dh)
+    # the index kernels cut a head from the lanes of a block's (bq, hi x di)
+    qi_blocks = qi.reshape(t // block, block, iheads * di) if index_kernel \
+        else qi.reshape(t // block, block, iheads, di)
     scale = np.float32(1.0 / np.sqrt(dh))
     sels, kl, dqis, dws = [], 0.0, [], []
     dki = jnp.zeros(ki.shape, jnp.float32)
@@ -223,24 +286,26 @@ def _row(qi, ki, w, q, k, top_k: int, weight, grads: bool, scope: str,
         cut = lambda a: a[lo:hi].reshape(rows // block, block,  # noqa: E731
                                          *a.shape[1:])
         body = _one_block(ki[:hi], k[:hi], top_k, scale, weight, grads,
-                          scope, kernel, interpret)
+                          scope, kernel, index_kernel, interpret)
         dki_g, out = lax.scan(
-            body, jnp.zeros((hi, ki.shape[1]), jnp.float32),
-            (cut(qi), cut(w), q[lo // block:hi // block],
+            body, jnp.zeros((di, hi) if index_kernel else (hi, di),
+                            jnp.float32),
+            (qi_blocks[lo // block:hi // block], cut(w),
+             q[lo // block:hi // block],
              cut(jnp.arange(t, dtype=jnp.int32))))
         with _probe.scope(f"{scope}.select"):
             sels.append(jnp.pad(out[0].reshape(rows, hi),
                                 ((0, 0), (0, t - hi))))
         kl = kl + out[1].sum()
         if grads:
-            dki = dki.at[:hi].add(dki_g)
-            dqis.append(out[2].reshape(rows, *qi.shape[1:]))
+            dki = dki.at[:hi].add(dki_g.T if index_kernel else dki_g)
+            dqis.append(out[2])
             dws.append(out[3].reshape(rows, w.shape[1]))
     with _probe.scope(f"{scope}.select"):
         sel = jnp.concatenate(sels) if groups > 1 else sels[0]
     if not grads:
         return sel, kl, None
-    return sel, kl, (jnp.concatenate(dqis).astype(qi.dtype),
+    return sel, kl, (jnp.concatenate(dqis).reshape(qi.shape).astype(qi.dtype),
                      dki.astype(ki.dtype), jnp.concatenate(dws))
 
 

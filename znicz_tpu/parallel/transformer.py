@@ -68,7 +68,8 @@ def _report_flash_choice(t: int, dh: int, why: str | None,
     own, or operands folded head-major around them) and, of the
     key/value-blocked form, the rows of each pass's tile
     (``attention.kvb_block_rows``).  ``align``: of a layer with an
-    indexer, what makes its alignment target (:func:`_align_choice`)."""
+    indexer, what makes its index scores and its alignment target
+    (:func:`_dsa_choice`)."""
     if why:
         _log.warning("flash attention refused t=%d head_dim=%d: %s; this "
                      "step uses dense ring_attention", t, dh, why)
@@ -85,15 +86,24 @@ def _report_flash_choice(t: int, dh: int, why: str | None,
               t, dh, layout, blocked, f"; {align}" if align else "")
 
 
-def _align_choice(t: int, heads: int, kv: int, dh: int,
-                  interpret: bool) -> str:
-    """What makes a layer's alignment target, in words for the step's one
-    INFO line a shape (``dsa.align_kernel_refusal``)."""
+def _dsa_choice(t: int, heads: int, kv: int, dh: int, hi: int, di: int,
+                interpret: bool) -> str:
+    """What makes a layer's index scores with their gradients and its
+    alignment target, in words for the step's one INFO line a shape
+    (``dsa.index_kernel_refusal``, ``dsa.align_kernel_refusal``)."""
     from znicz_tpu.ops.pallas import dsa as pdsa
-    why = dsa.align_kernel_refusal(t, heads, kv, dh, interpret)
-    if why:
-        return f"the alignment target by the jax.numpy form ({why})"
-    return f"the alignment target by kernel {pdsa.ALIGN_KERNEL_NAME}"
+    said = []
+    for what, why, names in (
+            ("the index scores and their gradients",
+             dsa.index_kernel_refusal(t, hi, di, interpret),
+             (pdsa.INDEX_SCORES_KERNEL_NAME, pdsa.INDEX_GRADS_KERNEL_NAME)),
+            ("the alignment target",
+             dsa.align_kernel_refusal(t, heads, kv, dh, interpret),
+             (pdsa.ALIGN_KERNEL_NAME,))):
+        said.append(f"{what} by the jax.numpy form ({why})" if why else
+                    f"{what} by kernel{'s' * (len(names) > 1)} "
+                    f"{' and '.join(names)}")
+    return "; ".join(said)
 
 
 def _flash_eligible(mesh: Mesh, interpret: bool) -> bool:
@@ -1270,8 +1280,8 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
         if eligible:
             _report_flash_choice(
                 t_loc, dh, why, direct, None if sel is None else
-                _align_choice(t_loc, q.shape[2], k.shape[2], dh,
-                              run.interpret))
+                _dsa_choice(t_loc, q.shape[2], k.shape[2], dh,
+                            arch.index_heads, arch.index_dim, run.interpret))
         if run.use_flash and not why:
             o = pattn.flash_attention(q, k, v, causal=run.causal,
                                       interpret=run.interpret, sel=sel)
@@ -1603,18 +1613,23 @@ def attn_kvb_block_rows(mesh: Mesh, arch: Arch, t: int) -> dict:
     return dict.fromkeys(rows, 0)
 
 
-def dsa_align_kernel_share(mesh: Mesh, arch: Arch, t: int) -> float | None:
+def dsa_kernel_shares(mesh: Mesh, arch: Arch, t: int) -> dict | None:
     """Of a step's layers with an indexer at ``t`` positions, the share
-    whose alignment target the kernel makes (``ops/pallas/dsa.py``; all or
-    none: the layers share their shape), None for a stack without an
+    whose index scores and their gradients (``"index"``) and whose alignment
+    target (``"align"``) the kernels make (``ops/pallas/dsa.py``; each all
+    or none: the layers share their shape), None for a stack without an
     indexer: what :func:`_select_keys` will trace, known from the mesh, the
-    architecture and the sequence length (``dsa.align_kernel_refusal``)."""
+    architecture and the sequence length (``dsa.index_kernel_refusal``,
+    ``dsa.align_kernel_refusal``)."""
     if not arch.index_top_k or "attention" not in arch.mixers:
         return None
     run = _run_of(mesh, arch, causal=True)
-    return float(dsa.align_kernel_refusal(
-        t, run.heads_local, run.kv_heads_local, arch.head_dim,
-        run.interpret) is None)
+    return {
+        "index": float(dsa.index_kernel_refusal(
+            t, arch.index_heads, arch.index_dim, run.interpret) is None),
+        "align": float(dsa.align_kernel_refusal(
+            t, run.heads_local, run.kv_heads_local, arch.head_dim,
+            run.interpret) is None)}
 
 
 def ce_grad_in_forward(arch: Arch, loss_chunks: int | None,

@@ -193,10 +193,12 @@ class TransformerLMStep(AcceleratedUnit):
         #: another form; empty until the step is built
         self.attn_kvb_block_rows: dict = {}
         #: of the step's layers with an indexer, the share whose alignment
-        #: target the Pallas kernel makes (all or none: ``parallel/
-        #: transformer.py::dsa_align_kernel_share``); None without an
-        #: indexer, and until the step is built
+        #: target, and the share whose index scores and their gradients,
+        #: the Pallas kernels make (each all or none: ``parallel/
+        #: transformer.py::dsa_kernel_shares``); None without an indexer,
+        #: and until the step is built
         self.dsa_align_kernel_share: Optional[float] = None
+        self.dsa_index_kernel_share: Optional[float] = None
         #: of the train step's head passes, the share that make their
         #: gradients where they make their logits (all or none:
         #: ``parallel/transformer.py::ce_grad_in_forward``); None until
@@ -255,7 +257,7 @@ class TransformerLMStep(AcceleratedUnit):
         seq_len = int(self.loader.minibatch_data.shape[1])
         self._publish_attn_tiles(tfm.attn_kvb_block_rows(
             self.mesh, self.arch, seq_len))
-        self._publish_dsa_align(tfm.dsa_align_kernel_share(
+        self._publish_dsa_kernels(tfm.dsa_kernel_shares(
             self.mesh, self.arch, seq_len))
         self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
@@ -419,22 +421,33 @@ class TransformerLMStep(AcceleratedUnit):
         for name, value in rows.items():
             gauge.labels(**{"unit": self.name, "pass": name}).set(value)
 
-    def _publish_dsa_align(self, share: Optional[float]) -> None:
+    def _publish_dsa_kernels(self, shares: Optional[dict]) -> None:
         """Of the layers with an indexer, the share whose alignment target
-        the kernel makes (a constant of the step as it is built; nothing
-        without an indexer): the unit's mirror and the process registry."""
+        and the share whose index scores the kernels make (constants of the
+        step as it is built; nothing without an indexer): the unit's
+        mirrors and the process registry."""
         from znicz_tpu.observe import registry
 
-        self.dsa_align_kernel_share = share
-        if share is None:
+        if shares is None:
+            self.dsa_align_kernel_share = self.dsa_index_kernel_share = None
             return
+        self.dsa_align_kernel_share = shares["align"]
+        self.dsa_index_kernel_share = shares["index"]
         registry.gauge(
             "znicz_lm_dsa_align_kernel_share",
             "layers with an indexer whose alignment target (the heads' mean "
             "attention probabilities over the selection) the Pallas kernel "
             "dsa_align_target makes over the layers with an indexer (the "
             "rest: blocked jax.numpy, the heads' scores through HBM)",
-            ("unit",)).labels(unit=self.name).set(share)
+            ("unit",)).labels(unit=self.name).set(shares["align"])
+        registry.gauge(
+            "znicz_lm_dsa_index_kernel_share",
+            "layers with an indexer whose index scores and their gradients "
+            "to the index queries, keys and weights the Pallas kernels "
+            "dsa_index_scores and dsa_index_grads make over the layers with "
+            "an indexer (the rest: blocked jax.numpy einsums, the index "
+            "heads' scores through HBM)",
+            ("unit",)).labels(unit=self.name).set(shares["index"])
 
     def _publish_attn_layout(self, share: float) -> None:
         """Of the attention layers that ran a flash kernel, the share whose
