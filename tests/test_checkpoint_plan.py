@@ -1,0 +1,70 @@
+"""The checkpoint plan (``parallel/transformer.py::checkpoint_plan``) held
+to the compiler: the two benchmark cells whose layers are checkpointed by
+``_loop_saves`` (``granite4h_micro_train_pp4_t8192``, whose state-space
+stack keeps what a v5e has room for, and ``ouro_train_pp8_t4096``, whose
+looped stack keeps its own list) compiled at their real widths for a TPU
+v5e that is described and not attached, as ``benchmark/tests/
+test_granite4h_rehearsal.py`` and ``test_ouro_rehearsal.py`` do, with the
+plan a v5e's memory limit gives.
+
+Each compile runs in a process of its own (``tests/v5e_step_compile.py``):
+a worker that had loaded the TPU's library would disturb the profiler's
+tests that run in it afterwards.  Skipped where no topology can be
+described."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+GIB = 2 ** 30
+HBM_USABLE = 15.75 * GIB          # what the runtime leaves of 16 GiB
+
+
+def _compiled(config: str, traffic: str) -> dict:
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "TPU_LOG_DIR": "disabled"}
+    env.pop("XLA_FLAGS", None)     # one CPU device is enough there
+    done = subprocess.run(
+        [sys.executable, os.path.join(HERE, "v5e_step_compile.py"), config,
+         traffic, "15.75"], env=env, capture_output=True, text=True,
+        timeout=900)
+    assert done.returncode == 0, done.stderr[-3000:]
+    out = json.loads(done.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(out["skip"])
+    return out
+
+
+@pytest.mark.parametrize("config,traffic,params,kept,tflop", [
+    ("granite_4_0_h_micro", "train_tokens_pp4_t8192", 951_991_232,
+     {"glu_wide": 10 * 2 * 8192 * 8192 * 2, "ssm_in": 9 * 8192 * 8512 * 2,
+      "ssm_conv_sum": 0}, (38.5, 40.0)),
+    ("ouro_2_6b", "train_tokens_pp8_t4096", 509_661_185,
+     {"glu_wide": 0}, (17.5, 18.7)),
+])
+def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
+        config, traffic, params, kept, tflop):
+    """The compiled step's arguments and temporaries against the plan's
+    arithmetic: the footprint reckoned for today's list plus what the plan
+    keeps never stands under the compiler's count by more than the margin
+    (nor over it by more: a formula that counts double would refuse what
+    fits), the whole stays under the rehearsals' line of 0.9 x 15.75 GiB,
+    and the compiler's operation count says the kept products are not made
+    again (47.3 TFLOP with today's list in the granite cell; a looped
+    stack's count, loop bodies counted once, is the parent's)."""
+    out = _compiled(config, traffic)
+    assert out["params"] == params and out["limit"] == int(HBM_USABLE)
+    assert out["plan"] == kept
+    live = out["argument_bytes"] + out["temp_bytes"]
+    reckoned = out["footprint"] + sum(kept.values())
+    print(f"{config}: compiled {live / GIB:.3f} GiB, reckoned "
+          f"{reckoned / GIB:.3f} (footprint {out['footprint'] / GIB:.3f} + "
+          f"kept {sum(kept.values()) / GIB:.3f}), "
+          f"{out['flops'] / 1e12:.3f} TFLOP")
+    assert live < 0.9 * HBM_USABLE, f"{live / GIB:.2f} GiB"
+    assert abs(live - reckoned) <= out["margin"], (live, reckoned)
+    assert reckoned + out["margin"] <= HBM_USABLE
+    assert tflop[0] < out["flops"] / 1e12 < tflop[1]

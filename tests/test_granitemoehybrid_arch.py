@@ -436,6 +436,152 @@ def test_the_stack_recomputes_its_wide_arrays_by_its_own_policy():
     assert not [s for s in shapes if len(s) == 3 and
                 s[-1] in (64, ssm.in_width(8, 8, 16))]
     assert tfm._block_fn(True, "dots", arch) is not blk
+    # with every optional kind kept the wide arrays are residuals: the
+    # input projection, the SwiGLU's two products, the convolution's sum
+    full = tfm._block_fn(False, None, arch, tfm._KEPT_IF_ROOM)
+    _, vjp = jax.vjp(lambda p_, x_: full(x_, p_, arch, run, 0)[0], p, x)
+    wide = [tuple(v.shape) for v in jax.tree.leaves(vjp)
+            if hasattr(v, "shape") and len(v.shape) == 3]
+    assert wide.count((2, 32, 64)) >= 2                     # m w1, m w3
+    assert (2, 32, ssm.in_width(8, 8, 16)) in wide
+    assert (2, 32, 64 + 2 * 16) in wide
+    # a keyword's policy is not the plan's to widen
+    assert tfm._saves(()) is tfm._loop_saves
+    assert tfm._saves(("ssm_in",)) is tfm._saves(("ssm_in",))
+
+
+GIB = 2 ** 30
+
+
+def _cell_arch():
+    """The benchmark cell's architecture (``benchmark/configs/
+    granite_4_0_h_micro.json``): ten layers at the published widths."""
+    import json
+
+    with open(os.path.join(BENCH, "configs",
+                           "granite_4_0_h_micro.json")) as f:
+        cfg = json.load(f)
+    opts = cfg["builders"]["lm_train_keys"]
+    return tfm.arch_from_config({k: cfg[k] for k in opts["model_keys"]}), \
+        opts["loss_chunks"]
+
+
+@pytest.mark.parametrize("tokens,limit_gib,kept", [
+    # the cell on a v5e: the wide products and the input projection, and
+    # the convolution's float32 sum refused (13.9 GiB reckoned with it)
+    (8192, 15.75, ("glu_wide", "ssm_in")),
+    (8192, 15.75 / 2, ()),           # half the memory: today's list
+    (16384, 15.75, ()),              # twice the tokens keep less
+    (4096, 15.75, ("glu_wide", "ssm_in", "ssm_conv_sum")),  # a shorter row
+    (8192, 32.0, ("glu_wide", "ssm_in", "ssm_conv_sum")),
+    (8192, 12.5, ()),                # the first kind refused ends the walk
+    (8192, None, ()),                # no limit reported: a CPU
+])
+def test_the_plan_keeps_what_the_counted_bytes_leave_room_for(tokens,
+                                                              limit_gib, kept):
+    """``checkpoint_plan`` from static shapes and a given memory limit, at
+    the benchmark cell's widths: kinds in their fixed order while their
+    bytes fit the limit less the reckoned footprint and the margin."""
+    arch, chunks = _cell_arch()
+    limit = None if limit_gib is None else int(limit_gib * GIB)
+    plan = tfm.checkpoint_plan(arch, tokens, 2, limit, chunks)
+    assert tuple(plan) == tfm._KEPT_IF_ROOM
+    assert tuple(k for k, v in plan.items() if v) == kept
+    sizes = {"glu_wide": 10 * 2 * tokens * 8192 * 2,
+             "ssm_in": 9 * tokens * 8512 * 2,
+             "ssm_conv_sum": 9 * tokens * 4352 * 4}
+    assert all(plan[k] == sizes[k] for k in kept)
+    if limit is not None:
+        room = limit - tfm.step_footprint(arch, tokens, 2, chunks) - \
+            tfm.PLAN_MARGIN
+        assert sum(plan.values()) <= max(room, 0)
+        refused = [k for k in plan if k not in kept]
+        assert not refused or \
+            sum(plan.values()) + sizes[refused[0]] > room
+
+
+def test_the_plan_is_for_the_policys_stacks_alone():
+    """A stack whose layers are not checkpointed by ``_loop_saves`` has no
+    plan (nothing to name in its INFO line or its gauge), whatever the
+    memory."""
+    plain = dataclasses.replace(_arch(_cfg()),
+                                mixers=("attention",) * 3)
+    assert tfm.checkpoint_plan(plain, 8192, 2, 64 * GIB) == {}
+
+
+def _loss_and_grads(cfg, dtype, seed=5):
+    """The step's own loss (``_forward_ce`` on the one-device mesh) and its
+    gradient to every leaf, not read back through an update."""
+    from jax.sharding import PartitionSpec as P
+    from znicz_tpu.parallel.compat import shard_map
+
+    arch, mesh = _arch(cfg), _mesh1()
+    run = tfm._run_of(mesh, arch, causal=True)
+
+    def local(ps, tok, lab):
+        return tfm._forward_ce(ps, tok, lab, None, arch, run, dtype,
+                               loss_chunks=2)[0]
+
+    rows = P("data", "seq")
+    fn = shard_map(local, mesh=mesh, out_specs=P(),
+                   in_specs=(tfm.param_specs(arch, False), rows, rows))
+    tokens = ref.make_tokens(seed, cfg, TRAFFIC["seq_len"], 0,
+                             TRAFFIC["minibatch_size"])
+    loss, grads = jax.jit(jax.value_and_grad(fn))(
+        jax.tree.map(jnp.asarray, ref.init_params(seed, cfg)),
+        jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:]))
+    return float(loss), _named(cfg, jax.tree.map(np.asarray, grads)), run
+
+
+@pytest.mark.parametrize("dtype,rel", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 5e-2)])
+def test_a_full_plan_moves_no_loss_and_no_gradient(monkeypatch, dtype, rel):
+    """The step with every optional kind kept (a device that reports room
+    for all of it) against the step that keeps today's list (no limit
+    reported): the kept arrays are the forward pass's own, so the loss and
+    every leaf's gradient agree to float32 rounding (in bfloat16 to its
+    rounding: XLA fuses a chain it makes again otherwise than the one it
+    made first)."""
+    cfg = _cfg()
+    got = []
+    for limit in (None, 64 * GIB):
+        monkeypatch.setattr(tfm, "_memory_limit", lambda mesh: limit)
+        assert tuple(k for k, v in tfm.checkpoint_kept_bytes(
+            _mesh1(), _arch(cfg), TRAFFIC["minibatch_size"],
+            TRAFFIC["seq_len"], 2, dtype).items() if v) == \
+            (tfm._KEPT_IF_ROOM if limit else ())
+        loss, grads, run = _loss_and_grads(cfg, dtype)
+        assert run.hbm_limit == limit
+        got.append((loss, grads))
+    (loss0, grads0), (loss1, grads1) = got
+    assert loss1 == pytest.approx(loss0, rel=2e-6 if rel < 1e-3 else 0)
+    assert set(grads0) == set(grads1)
+    for name, g0 in grads0.items():
+        scale = float(np.abs(g0).max())
+        np.testing.assert_allclose(grads1[name], g0, rtol=0,
+                                   atol=rel * scale, err_msg=name)
+
+
+def test_the_plan_is_said_once_with_the_bytes_that_decided_it(caplog,
+                                                              monkeypatch):
+    """The step's INFO line: each kind kept or refused with its bytes, the
+    limit, the footprint and the margin."""
+    import logging
+
+    arch, chunks = _cell_arch()
+    tfm._report_plan.cache_clear()
+    with caplog.at_level(logging.INFO, logger="znicz_tpu.transformer"):
+        kept = tfm._report_plan(arch, 8192, 2, int(15.75 * GIB), chunks)
+        assert tfm._report_plan(arch, 8192, 2, int(15.75 * GIB),
+                                chunks) is kept
+    assert kept == ("glu_wide", "ssm_in")
+    lines = [r.getMessage() for r in caplog.records
+             if "checkpointed layers" in r.getMessage()]
+    assert len(lines) == 1
+    for word in ("glu_wide kept (2.500 GiB)", "ssm_in kept (1.169 GiB)",
+                 "ssm_conv_sum refused (1.195 GiB)", "limit 15.750 GiB",
+                 "footprint", "margin 2.000"):
+        assert word in lines[0], lines[0]
 
 
 # -- (e) refusals by mechanism --------------------------------------------
@@ -491,6 +637,11 @@ def test_the_unit_publishes_the_state_space_counters(tmp_path):
     for key, value in got.items():
         fam = registry.REGISTRY.get(f"znicz_lm_ssm_{key}")
         assert fam is not None and fam.labels(unit=step.name).get() == value
+    # the plan's gauge: a CPU reports no memory limit, every kind refused
+    assert step.checkpoint_kept_bytes == dict.fromkeys(tfm._KEPT_IF_ROOM, 0)
+    fam = registry.REGISTRY.get("znicz_lm_checkpoint_kept_bytes")
+    for name in tfm._KEPT_IF_ROOM:
+        assert fam.labels(unit=step.name, name=name).get() == 0
     with pytest.raises(ValueError, match=MECHANISM):
         step.export_lm(str(tmp_path / "pkg.npz"))
     state = step.state_dict()

@@ -379,6 +379,32 @@ def test_a_looped_stack_has_one_recomputation_path_and_no_routed_layer():
                 jnp.zeros((2, 8), jnp.int32))
 
 
+@pytest.mark.parametrize("limit_gib", [None, 15.75, 64.0, 1024.0])
+def test_a_looped_stack_keeps_nothing_beside_its_list_at_any_limit(
+        limit_gib, monkeypatch):
+    """``checkpoint_plan`` of the benchmark cell's looped stack (and of the
+    tiny one): the SwiGLU's wide products are named and refused whatever
+    memory the device reports, since what a layer application keeps crosses
+    the scan and is stacked; the step's policy is ``_loop_saves`` itself."""
+    import json
+
+    with open(os.path.join(BENCH, "configs", "ouro_2_6b.json")) as f:
+        cfg = json.load(f)
+    opts = cfg["builders"]["lm_train_keys"]
+    cell = tfm.arch_from_config({k: cfg[k] for k in opts["model_keys"]})
+    limit = None if limit_gib is None else int(limit_gib * 2 ** 30)
+    for arch, tokens in ((cell, 8192), (_arch(_cfg()), 32)):
+        assert tfm.checkpoint_plan(arch, tokens, 2, limit,
+                                   opts["loss_chunks"]) == {"glu_wide": 0}
+        assert tfm._report_plan(arch, tokens, 2, limit,
+                                opts["loss_chunks"]) == ()
+    monkeypatch.setattr(tfm, "_memory_limit", lambda mesh: limit)
+    assert tfm._run_of(_mesh1(), cell, causal=True).hbm_limit == limit
+    assert tfm.checkpoint_kept_bytes(_mesh1(), cell, 2, 4096, 8,
+                                     jnp.bfloat16) == {"glu_wide": 0}
+    assert tfm._saves(()) is tfm._loop_saves
+
+
 def test_the_new_kinds_refuse_a_sharded_mesh_by_name(cpu_devices):
     arch = _arch(_cfg())
     for axes in ({"data": 1, "seq": 1, "model": 2},

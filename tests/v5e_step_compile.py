@@ -1,0 +1,89 @@
+"""Compile one benchmark cell's train step for a TPU v5e that is described
+and not attached, in a process of its own (a process that has loaded the
+TPU's library disturbs the profiler's tests that run after it), and print
+one JSON line: the compiled step's memory, the compiler's operation count,
+the checkpoint plan a v5e's memory limit gives and the footprint the plan
+reckoned with.  ``tests/test_checkpoint_plan.py`` runs it.
+
+    python tests/v5e_step_compile.py CONFIG TRAFFIC [LIMIT_GIB]
+"""
+
+import json
+import math
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.mesh import make_mesh
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - any failure means "cannot"
+        return {"skip": f"no v5e:2x2 topology can be described here: {exc}"}
+    jax.config.update("jax_enable_compilation_cache", False)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "traffic",
+                           traffic + ".json")) as f:
+        rows = json.load(f)
+    # the step asks jax.default_backend(), which is the CPU here, and the
+    # described chip reports no memory: a v5e's own answers
+    tfm._flash_eligible = lambda mesh, interp: True
+    limit = int(limit_gib * 2 ** 30)
+    tfm._memory_limit = lambda mesh: limit
+    opts = cfg["builders"]["lm_train_keys"]
+    arch = tfm.arch_from_config({k: cfg[k] for k in opts["model_keys"]})
+    mesh = make_mesh({"data": 1, "seq": 1, "model": 1}, topo.devices[:1])
+    step, _ = tfm.make_train_step(
+        mesh, arch, lr=float(cfg["hyper"]["lr"]), masked=True, donate=True,
+        loss_chunks=opts["loss_chunks"], stats=True,
+        compute_dtype=jnp.bfloat16)
+    rep = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep),
+        tfm.param_shapes(arch), is_leaf=lambda x: isinstance(x, tuple))
+    b, t = int(rows["minibatch_size"]), int(rows["seq_len"])
+    tok = jax.ShapeDtypeStruct((b, t), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data", "seq")))
+    mask = jax.ShapeDtypeStruct((b,), jnp.bool_,
+                                sharding=NamedSharding(mesh, P("data")))
+    compiled = step.lower(params, tok, tok, mask).compile()
+    m = compiled.memory_analysis()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    out = os.environ.get("V5E_STEP_TEXT")
+    if out:
+        with open(out, "w") as f:
+            f.write(compiled.as_text())
+    return {
+        "params": sum(math.prod(s.shape) for s in jax.tree.leaves(params)),
+        "tokens": b * t,
+        "argument_bytes": m.argument_size_in_bytes,
+        "temp_bytes": m.temp_size_in_bytes,
+        "flops": cost.get("flops"), "bytes_accessed":
+            cost.get("bytes accessed"),
+        "limit": limit,
+        "plan": tfm.checkpoint_plan(arch, b * t, 2, limit,
+                                    opts["loss_chunks"]),
+        "footprint": tfm.step_footprint(arch, b * t, 2, opts["loss_chunks"]),
+        "margin": tfm.PLAN_MARGIN}
+
+
+if __name__ == "__main__":
+    args = sys.argv[1:]
+    print(json.dumps(main(args[0], args[1],
+                          *(float(a) for a in args[2:3]))))

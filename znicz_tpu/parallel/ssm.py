@@ -40,7 +40,12 @@ state (``(b, t / Q, H, P, N)`` float32, named ``ssm_state``).  The two
 halves, :func:`_chunk_states` (2, 3) and :func:`_chunk_outputs` (1, 4), are
 each a ``jax.checkpoint``: the ``(H, Q, Q)`` decay and score matrices of
 every chunk (0.5 GB a layer at 8,192 positions) are made again from the
-operands when the gradients are, and never stored.
+operands when the gradients are, and never stored.  The operands themselves
+come from the input projection and the convolution, which the layer's own
+checkpoint (``transformer.py::_block_fn``) makes again unless the device has
+room for them: the projection's result and the convolution's float32 sum are
+named here (``ssm_in``, ``ssm_conv_sum``) for ``transformer.py::
+checkpoint_plan`` to keep or refuse.
 """
 
 from __future__ import annotations
@@ -180,11 +185,12 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
     b, t, _ = u.shape
     inner = heads * head_dim
     with _probe.scope(scope):
-        proj = u @ p["ssm_in"]
+        proj = checkpoint_name(u @ p["ssm_in"], "ssm_in")
         z, xbc = proj[..., :inner], proj[..., inner:2 * inner + 2 * state]
         dt = proj[..., 2 * inner + 2 * state:]
     with _probe.scope(f"{scope}.conv"):
-        xbc = jax.nn.silu(_conv(xbc, p["ssm_conv_k"], p["ssm_conv_b"])
+        xbc = jax.nn.silu(checkpoint_name(
+            _conv(xbc, p["ssm_conv_k"], p["ssm_conv_b"]), "ssm_conv_sum")
                           ).astype(u.dtype)
     with _probe.scope(f"{scope}.scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) +

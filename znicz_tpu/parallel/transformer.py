@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import math
 from collections.abc import Mapping
 
 import numpy as np
@@ -1021,6 +1022,9 @@ class _Run:
     use_ring_flash: bool = False
     moe_aux_weight: float = 0.0
     moe_zloss_weight: float = 0.0
+    #: bytes of device memory the backend reports (:func:`_memory_limit`),
+    #: None where it reports none: what :func:`checkpoint_plan` divides
+    hbm_limit: int | None = None
 
 
 def _rms_norm(x, g, eps):
@@ -1372,8 +1376,11 @@ def _block_sconv(x, p, arch: Arch):
 
 
 def _glu(m, w1, w3, w2):
-    """Bias-free SwiGLU."""
-    return (jax.nn.silu(m @ w1) * (m @ w3)) @ w2
+    """Bias-free SwiGLU.  Its two wide products are named for the
+    recomputation policy (``glu_wide``: kept where :func:`checkpoint_plan`
+    finds room; a name is no operation)."""
+    return (jax.nn.silu(checkpoint_name(m @ w1, "glu_wide")) *
+            checkpoint_name(m @ w3, "glu_wide")) @ w2
 
 
 def _block_mlp(x, p, arch: Arch, ffn: str, run: _Run):
@@ -1613,6 +1620,22 @@ def attn_kvb_block_rows(mesh: Mesh, arch: Arch, t: int) -> dict:
     return dict.fromkeys(rows, 0)
 
 
+def checkpoint_kept_bytes(mesh: Mesh, arch: Arch, batch: int, t: int,
+                          loss_chunks: int | None = None,
+                          compute_dtype=None) -> dict:
+    """:func:`checkpoint_plan` of a train step of ``batch`` rows of ``t``
+    positions on ``mesh``: ``{name: bytes}`` of what its checkpointed
+    layers keep beside their own list (0: refused; empty: no layer is
+    checkpointed by that policy), what :func:`_forward_ce` will trace,
+    known from the mesh, its first device's memory, the architecture and
+    the batch's shape."""
+    tokens = (batch // mesh.shape.get("data", 1)) * \
+        (t // mesh.shape.get("seq", 1))
+    cdt = _default_compute_dtype(compute_dtype)
+    return checkpoint_plan(arch, tokens, jnp.dtype(cdt).itemsize,
+                           _memory_limit(mesh), loss_chunks)
+
+
 def dsa_kernel_shares(mesh: Mesh, arch: Arch, t: int) -> dict | None:
     """Of a step's layers with an indexer at ``t`` positions, the share
     whose index scores and their gradients (``"index"``) and whose alignment
@@ -1724,8 +1747,22 @@ def _sum_stats(a: dict, b: dict) -> dict:
     return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in {**a, **b}}
 
 
-_SAVED_NAMES = jax.checkpoint_policies.save_only_these_names(
-    "attn_qkv", "sub_out", "ssm_y", "ssm_state")
+#: what a checkpointed layer keeps whatever the memory (:func:`_loop_saves`)
+_KEPT_ALWAYS = ("attn_qkv", "sub_out", "ssm_y", "ssm_state")
+
+#: what it keeps beside them where the device has room for all the layers'
+#: (:func:`checkpoint_plan`), in the order of time saved a byte kept: the
+#: SwiGLU's two wide products and a state-space layer's input projection
+#: (a product made again costs about 12 ms a GiB of its result on a v5e),
+#: then the convolution's float32 sum (elementwise: about 10 ms a GiB)
+_KEPT_IF_ROOM = ("glu_wide", "ssm_in", "ssm_conv_sum")
+
+#: bytes :func:`checkpoint_plan` leaves free beside the step's reckoned
+#: footprint and what it keeps: what :func:`step_footprint` may stand under
+#: the compiler's count by, and what else the process holds on the device
+PLAN_MARGIN = 2 * 2 ** 30
+
+_SAVED_NAMES = jax.checkpoint_policies.save_only_these_names(*_KEPT_ALWAYS)
 
 
 def _loop_saves(prim, *_, **params) -> bool:
@@ -1737,34 +1774,228 @@ def _loop_saves(prim, *_, **params) -> bool:
     that is seven arrays of ``(tokens, d)``.  Recomputed: the four norms,
     the rotary embedding's f32 chain, the residual sums and the SwiGLU's
     two wide products with their gated product (three arrays of
-    ``(tokens, ff)``, 12 % of a layer's operations).  It is also what a
-    layer of a stack with state-space layers keeps (:func:`_block_fn`): of
-    such a layer the scan's output and each chunk's opening state too
+    ``(tokens, ff)``, 12 % of a layer's operations).  It is also the least
+    a layer of a stack with state-space layers keeps (:func:`_block_fn`):
+    of such a layer the scan's output and each chunk's opening state too
     (``ssm_y``, ``ssm_state``: the scan's forward pass is not run a second
     time; its backward pass makes a chunk's decay and score matrices again,
     ``parallel/ssm.py``), with the wide input projection and its split, the
     convolution, the gate and the gated norm made again: a layer holds five
     or six arrays of ``(tokens, d)`` and its chunk states where it would
-    hold ``(tokens, 8.5 d)`` of them."""
+    hold ``(tokens, 8.5 d)`` of them.  What such a stack keeps beside this
+    list follows the memory: :func:`checkpoint_plan`, :func:`_saves`."""
     return prim.name == "pallas_call" or _SAVED_NAMES(prim, *_, **params)
 
 
-def _block_fn(remat: bool, remat_policy: str | None, arch: Arch):
+@functools.lru_cache(maxsize=None)
+def _saves(kept: tuple):
+    """:func:`_loop_saves` with the names ``kept`` beside its own (one
+    policy object a set of names, so a layer's trace is found again)."""
+    if not kept:
+        return _loop_saves
+    named = jax.checkpoint_policies.save_only_these_names(
+        *_KEPT_ALWAYS, *kept)
+
+    def saves(prim, *_, **params) -> bool:
+        return prim.name == "pallas_call" or named(prim, *_, **params)
+    return saves
+
+
+def _recomputes_by_policy(arch: Arch) -> bool:
+    """Whether the stack's layers are checkpointed by :func:`_loop_saves`
+    with no keyword asking: a looped stack, a stack with state-space
+    layers (:func:`_block_fn`)."""
+    return arch.loop_steps > 1 or "mamba" in arch.mixers
+
+
+def _n_params(arch: Arch) -> int:
+    return sum(math.prod(s) for s in _shape_leaves(param_shapes(arch)))
+
+
+def step_footprint(arch: Arch, tokens: int, itemsize: int,
+                   loss_chunks: int | None = None) -> int:
+    """Bytes a train step of ``arch`` holds on a device at its fullest,
+    reckoned from static shapes for ``tokens`` local tokens a step and a
+    compute dtype of ``itemsize`` bytes, with every layer checkpointed by
+    :func:`_loop_saves` and nothing kept beside its list: what
+    :func:`checkpoint_plan` takes off the device's memory before it keeps
+    anything more.  The sum of
+
+    - the float32 masters and their cast to the compute dtype;
+    - the gradients that are whole while the layers' backward passes run:
+      the head pass makes the head's (a tied embedding's) float32 gradient
+      first and a looped stack carries its layers' through the scan in the
+      compute dtype; every other leaf's update runs as its gradient lands;
+    - what :func:`_loop_saves` keeps of every layer application (the
+      layer's input, ``sub_out`` twice, q, k, v and the kernel's output and
+      rows of an attention layer, ``ssm_y`` and ``ssm_state`` of a
+      state-space layer), and a looped stack's outputs;
+    - one layer's backward pass at work: six arrays of its widest
+      activation in the compute dtype (a SwiGLU's two products, their
+      gated product and the three gradients), and of a looped stack the
+      layer's kept arrays once more (cut from the scan's stack as copies);
+    - the head pass: one chunk's float32 logits and their gradient in the
+      compute dtype (a looped stack's passes are one call of ``loop_steps``
+      times the chunks), and the gradient to the stack's output it leaves
+      behind, float32 and its copy in the compute dtype, a loop step each
+      (:func:`_ce_weighted` makes it where it makes the logits).
+
+    Held to the two compiled steps the benchmark rehearses
+    (``tests/test_checkpoint_plan.py``, a described v5e's
+    ``memory_analysis()``: arguments and temporaries): it may stand under
+    neither by more than :data:`PLAN_MARGIN`."""
+    d, loops = arch.d, arch.loop_steps
+    act = tokens * itemsize
+    weights = _n_params(arch) * (4 + itemsize)
+    grads = arch.vocab * d * 4
+    kept = working = 0
+    for i in range(arch.n_layers):
+        mixer, ffn = arch.kinds(i)
+        layer = 3 * act * d
+        if mixer == "mamba":
+            inner = arch.ssm_heads * arch.ssm_head_dim
+            chunks = -(-tokens // arch.ssm_chunk)
+            layer += act * inner + chunks * inner * arch.ssm_state * 4
+            wide = ssm.in_width(arch.ssm_heads, arch.ssm_head_dim,
+                                arch.ssm_state)
+        elif mixer in ("attention", "latent"):
+            qo, kv = arch.heads * arch.head_dim, arch.kv_heads * arch.head_dim
+            layer += act * (2 * qo + 2 * kv) + tokens * arch.heads * 4
+            wide = qo
+        else:
+            wide = 3 * d
+        kept += layer
+        if ffn == "glu":
+            wide = max(wide, arch.ff)
+        working = max(working, 6 * act * wide +
+                      (layer if loops > 1 else 0))
+    if loops > 1:
+        grads += itemsize * sum(
+            math.prod(shape) for i in range(arch.n_layers)
+            for shape in _layer_shapes(arch, i).values())
+        kept = loops * (kept + act * d)
+    chunk = -(-tokens // _n_chunks(loss_chunks))
+    head = (chunk * arch.vocab + loops * tokens * d) * (4 + itemsize)
+    return weights + grads + kept + working + head
+
+
+def _kind_bytes(arch: Arch, tokens: int, itemsize: int) -> dict:
+    """``{name: bytes}`` all the layers of ``arch`` hold of each optional
+    kind of :data:`_KEPT_IF_ROOM` when a step of ``tokens`` local tokens
+    keeps it: ``tokens x width x itemsize x layers that have it`` (the
+    convolution's sum is float32 whatever the compute dtype)."""
+    glu = sum(f == "glu" for f in arch.ffns)
+    mamba = sum(m == "mamba" for m in arch.mixers)
+    inner = arch.ssm_heads * arch.ssm_head_dim
+    return {
+        "glu_wide": tokens * 2 * arch.ff * itemsize * glu,
+        "ssm_in": tokens * itemsize * mamba * ssm.in_width(
+            arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state),
+        "ssm_conv_sum": tokens * (inner + 2 * arch.ssm_state) * 4 * mamba}
+
+
+def checkpoint_plan(arch: Arch, tokens: int, itemsize: int,
+                    limit: int | None,
+                    loss_chunks: int | None = None) -> dict:
+    """``{name: bytes}`` of what the checkpointed layers of ``arch`` keep
+    for the backward pass beside :func:`_loop_saves`'s list, for each kind
+    of :data:`_KEPT_IF_ROOM` the stack has: the bytes all its layers hold
+    of a kind that is kept, 0 for one that is refused.
+
+    The kinds are walked in their fixed order of time saved a byte, and a
+    kind is kept while its bytes (:func:`_kind_bytes`, from static shapes)
+    fit what is left of ``limit``, the device's memory as its backend
+    reports it, after :func:`step_footprint`, :data:`PLAN_MARGIN` and the
+    kinds kept before it; the first kind that does not fit ends the walk,
+    so a later, smaller one never takes the room an earlier one was
+    refused.  Kept arrays are the forward pass's own, in its dtype: no
+    value of the step changes, only what its backward pass makes again.
+
+    Nothing is kept where no limit is reported (a CPU: its steps are the
+    ones they were), and nothing by a LOOPED stack at any limit: what a
+    layer application keeps there crosses the scan over the loop steps and
+    is stacked, and the stacking costs what the recomputation does.  In
+    ``ouro_train_pp8_t4096`` (PERF.md section 5; my chip run, PR 35)
+    ``bitcast_dynamic-update-slice_fusion`` takes 53.2 ms a step for seven
+    ``(8,192, 2,048)`` arrays an application: 2.2 ms an application for 235
+    MB, where the SwiGLU's two wide products of 184 MB cost 2.3 ms to make
+    again; and 24 applications of them are 4.12 GiB beside the 11.20 the
+    compiled step counts.  Whoever takes the stacking away (a loop
+    unrolled, a stack written in place) reopens this."""
+    if not _recomputes_by_policy(arch):
+        return {}
+    sizes = {k: v for k, v in _kind_bytes(arch, tokens, itemsize).items()
+             if v}
+    plan = dict.fromkeys(sizes, 0)
+    if limit is None or arch.loop_steps > 1:
+        return plan
+    room = limit - step_footprint(arch, tokens, itemsize, loss_chunks) - \
+        PLAN_MARGIN
+    for name in _KEPT_IF_ROOM:
+        if name not in sizes:
+            continue
+        if sizes[name] > room:
+            break
+        plan[name] = sizes[name]
+        room -= sizes[name]
+    return plan
+
+
+def _memory_limit(mesh: Mesh) -> int | None:
+    """Bytes of device memory a step's programs may use, as the backend of
+    the mesh's first device reports them (``memory_stats()["bytes_limit"]``:
+    15.75 GiB of a v5e's 16), None where it reports none (a CPU, a chip
+    that is described and not attached)."""
+    try:
+        stats = mesh.devices.flat[0].memory_stats()
+    except Exception:  # noqa: BLE001 - a device without the call has none
+        return None
+    return int(stats["bytes_limit"]) if stats and stats.get("bytes_limit") \
+        else None
+
+
+@functools.lru_cache(maxsize=None)
+def _report_plan(arch: Arch, tokens: int, itemsize: int,
+                 limit: int | None, loss_chunks: int | None) -> tuple:
+    """:func:`checkpoint_plan`'s kept names, and what it decided said once
+    per step shape per process."""
+    plan = checkpoint_plan(arch, tokens, itemsize, limit, loss_chunks)
+    if plan:
+        gib = 2.0 ** 30
+        sizes = _kind_bytes(arch, tokens, itemsize)
+        said = ", ".join(f"{name} {'kept' if got else 'refused'} "
+                         f"({sizes[name] / gib:.3f} GiB)"
+                         for name, got in plan.items())
+        if arch.loop_steps > 1:
+            why = "a looped stack stacks what it keeps"
+        elif limit is None:
+            why = "the device reports no memory limit"
+        else:
+            footprint = step_footprint(arch, tokens, itemsize, loss_chunks)
+            why = (f"limit {limit / gib:.3f} GiB, footprint "
+                   f"{footprint / gib:.3f}, margin {PLAN_MARGIN / gib:.3f}")
+        _log.info("checkpointed layers at %d tokens keep beside their own "
+                  "list: %s; %s", tokens, said, why)
+    return tuple(name for name, got in plan.items() if got)
+
+
+def _block_fn(remat: bool, remat_policy: str | None, arch: Arch,
+              kept: tuple = ()):
     """:func:`_block`, or its checkpointed form.  A looped stack holds
     ``loop_steps`` times the activations its weights suggest, so it always
     recomputes, the cheapest things first (:func:`_loop_saves`), and that
     is its one recomputation path: the two keywords are refused there.  A
     stack with state-space layers recomputes by the same policy unless a
-    keyword says otherwise (a layer's wide arrays are 8.5 ``d`` a token)."""
-    if arch.loop_steps > 1:
-        if remat or remat_policy:
+    keyword says otherwise (a layer's wide arrays are 8.5 ``d`` a token),
+    and keeps the names ``kept`` beside the policy's own: what
+    :func:`checkpoint_plan` found room for."""
+    if remat or remat_policy:
+        if arch.loop_steps > 1:
             raise ValueError("remat / remat_policy: a looped stack always "
                              "recomputes by _loop_saves")
-        pol = _loop_saves
-    elif remat or remat_policy:
         pol = _REMAT_POLICIES[remat_policy] if remat_policy else None
-    elif "mamba" in arch.mixers:
-        pol = _loop_saves
+    elif _recomputes_by_policy(arch):
+        pol = _saves(kept)
     else:
         return _block
     return jax.checkpoint(_block, policy=pol, static_argnums=(2, 3, 4))
@@ -1825,7 +2056,7 @@ def _embedded(ps, tokens, arch: Arch, cdt):
 
 def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
                     remat: bool = False,
-                    remat_policy: str | None = None):
+                    remat_policy: str | None = None, kept: tuple = ()):
     """Embedding + block stack — the ONE pre-head forward body, shared
     by the CE loss (:func:`_forward_ce`) and the full-pass logits oracle
     (:func:`make_logits_fn`, the generative serving plane's correctness
@@ -1837,7 +2068,7 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
     precision policy) and the routed layers' counters summed over the
     layers."""
     ps, x = _embedded(ps, tokens, arch, cdt)
-    blk = _block_fn(remat, remat_policy, arch)
+    blk = _block_fn(remat, remat_policy, arch, kept)
     if arch.loop_steps > 1:
         x, aux_term, stats, _, _ = _looped(ps, x, arch, run, blk)
         return x, aux_term, ps, stats
@@ -1910,12 +2141,17 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
     is ``CE + L_I``, the alignment term summed over the layers with weight
     1, and ``stats`` carries ``loss_index``.  A looped stack's loss is
     :func:`_forward_loop_ce`'s."""
+    # a keyword's policy is the caller's: it keeps what that policy says
+    kept = () if remat or remat_policy else _report_plan(
+        arch, tokens.size, jnp.dtype(cdt).itemsize, run.hbm_limit,
+        loss_chunks)
     if arch.loop_steps > 1:
         return _forward_loop_ce(ps, tokens, labels, mask, arch, run, cdt,
-                                _block_fn(remat, remat_policy, arch),
+                                _block_fn(remat, remat_policy, arch, kept),
                                 loss_chunks, reduce)
     x, aux_term, ps, stats = _forward_hidden(
-        ps, tokens, arch, run, cdt, remat=remat, remat_policy=remat_policy)
+        ps, tokens, arch, run, cdt, remat=remat, remat_policy=remat_policy,
+        kept=kept)
     head = _head_of(ps, arch)
     with _probe.scope("ce"):
         loss = _ce_from_hidden(x, head, labels, mask, aux_term, loss_chunks,
@@ -1924,7 +2160,7 @@ def _forward_ce(ps, tokens, labels, mask, arch: Arch, run: _Run, cdt,
         # position i reads token i+1 (its label) and predicts token i+2,
         # the next position's label; the last position has none
         y, aux, st = _mtp_hidden(ps, x, labels, arch, run,
-                                 _block_fn(remat, remat_policy, arch))
+                                 _block_fn(remat, remat_policy, arch, kept))
         with _probe.scope("mtp.ce"):
             second = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
             mtp = _ce_from_hidden(y, head, second, mask, aux, loss_chunks,
@@ -2092,7 +2328,8 @@ def _run_of(mesh: Mesh, arch: Arch, causal: bool,
                 use_flash=_flash_eligible(mesh, interp), interpret=interp,
                 use_ring_flash=_ring_flash_eligible(mesh, interp),
                 moe_aux_weight=float(moe_aux_weight),
-                moe_zloss_weight=float(moe_zloss_weight))
+                moe_zloss_weight=float(moe_zloss_weight),
+                hbm_limit=_memory_limit(mesh))
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

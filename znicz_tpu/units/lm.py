@@ -199,6 +199,12 @@ class TransformerLMStep(AcceleratedUnit):
         #: and until the step is built
         self.dsa_align_kernel_share: Optional[float] = None
         self.dsa_index_kernel_share: Optional[float] = None
+        #: ``{name: bytes}`` of what the step's checkpointed layers keep
+        #: for the backward pass beside their policy's own list, by the
+        #: memory the device reports (``parallel/transformer.py::
+        #: checkpoint_plan``; 0: refused); empty where no layer is
+        #: checkpointed by that policy, and until the step is built
+        self.checkpoint_kept_bytes: dict = {}
         #: of the train step's head passes, the share that make their
         #: gradients where they make their logits (all or none:
         #: ``parallel/transformer.py::ce_grad_in_forward``); None until
@@ -259,6 +265,9 @@ class TransformerLMStep(AcceleratedUnit):
             self.mesh, self.arch, seq_len))
         self._publish_dsa_kernels(tfm.dsa_kernel_shares(
             self.mesh, self.arch, seq_len))
+        self._publish_checkpoint_plan(tfm.checkpoint_kept_bytes(
+            self.mesh, self.arch, int(self.loader.max_minibatch_size),
+            seq_len, self.loss_chunks))
         self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
         # the programs from again (as FusedTrainStep's programs)
@@ -420,6 +429,24 @@ class TransformerLMStep(AcceleratedUnit):
             ("unit", "pass"))
         for name, value in rows.items():
             gauge.labels(**{"unit": self.name, "pass": name}).set(value)
+
+    def _publish_checkpoint_plan(self, plan: dict) -> None:
+        """What the step's checkpointed layers keep beside their policy's
+        own list, in bytes a name (a constant of the step as it is built):
+        the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        self.checkpoint_kept_bytes = plan
+        gauge = registry.gauge(
+            "znicz_lm_checkpoint_kept_bytes",
+            "bytes all the step's checkpointed layers keep of an optional "
+            "kind of activation for the backward pass (the SwiGLU's two "
+            "wide products, a state-space layer's input projection, its "
+            "convolution's float32 sum), 0 for a kind the device's memory "
+            "refused",
+            ("unit", "name"))
+        for name, value in plan.items():
+            gauge.labels(unit=self.name, name=name).set(value)
 
     def _publish_dsa_kernels(self, shares: Optional[dict]) -> None:
         """Of the layers with an indexer, the share whose alignment target
