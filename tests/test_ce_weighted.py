@@ -1,5 +1,5 @@
 """The chunked cross-entropy against a replicated head
-(``parallel/transformer.py::_ce_weighted``): a ``custom_vjp`` whose forward
+(``parallel/head.py::_ce_weighted``): a ``custom_vjp`` whose forward
 rule makes the gradients of ``x``, ``head`` and the weights in the pass that
 makes the logits, and whose backward rule only scales them.  Held here, on
 the CPU at tiny widths, against ``jax.grad`` of the dense unchunked form;
@@ -15,7 +15,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from znicz_tpu.parallel import transformer as tfm
+from znicz_tpu.parallel import head as tfm_head, transformer as tfm
+from znicz_tpu.parallel.arch import gpt_arch
+from znicz_tpu.parallel.head import _ce_chunked, ce_grad_in_forward
+from znicz_tpu.parallel.params import init_params
 from znicz_tpu.parallel.mesh import make_mesh
 
 VOCAB = 53
@@ -50,7 +53,7 @@ def _old_chunked(x, head, labels, w, n_chunks):
                 * wc).sum()
 
     return lax.map(lambda inp: chunk_nll(*inp),
-                   tfm._ce_chunked(x, labels, w, n_chunks)).sum()
+                   _ce_chunked(x, labels, w, n_chunks)).sum()
 
 
 def _rel(a, b):
@@ -267,16 +270,24 @@ def test_differentiated_step_runs_three_vocabulary_products_a_head_pass(
     dlogits`` once a chunk of every head pass (a GPT-shaped block's one, an
     MTP stack's two, a looped stack's four) and no second logits product;
     the checkpointed chunk this replaced counts four on the same counter."""
-    arch = {"gpt": tfm.gpt_arch(1, 32, 4, 64, VOCAB),
+    arch = {"gpt": gpt_arch(1, 32, 4, 64, VOCAB),
             "glm": tfm.arch_from_config(GLM_TINY),
             "ouro": tfm.arch_from_config(OURO_TINY)}[name]
     jaxpr = _step_jaxpr(arch, loss_chunks=chunks)
     assert _vocab_products(jaxpr) == 3 * passes * (chunks or 1)
     # what the step unit's gauge says of this step: a looped stack's head
     # passes take the rule chunked or not, the others' when chunked
-    assert tfm.ce_grad_in_forward(arch, chunks, False) == \
-        (name == "ouro" or bool(chunks))
-    assert not tfm.ce_grad_in_forward(arch, chunks, True) or name == "ouro"
+    def share(head_sharded):
+        return tfm.step_choices(_mesh1(), arch, 2, 16, chunks, head_sharded)[
+            "ce_grad_in_forward_share"]
+
+    assert share(False) == float(name == "ouro" or bool(chunks))
+    if name == "gpt":
+        assert share(True) == 0.0
+    else:               # as the step itself refuses
+        with pytest.raises(ValueError, match="head_sharded"):
+            share(True)
+    assert ce_grad_in_forward(chunks, True, looped=True)
 
     x, head, labels, w = _operands(32, 16, jnp.float32)
     old = jax.make_jaxpr(jax.grad(_old_chunked, argnums=(0, 1)),
@@ -295,8 +306,8 @@ def test_eval_pass_is_the_old_chunked_loss_bit_for_bit():
     not divide the tokens included."""
     from unittest import mock
 
-    arch = tfm.gpt_arch(1, 32, 4, 64, VOCAB)
-    params = tfm.init_params(np.random.default_rng(2), arch)
+    arch = gpt_arch(1, 32, 4, 64, VOCAB)
+    params = init_params(np.random.default_rng(2), arch)
     rows = jax.random.randint(jax.random.PRNGKey(4), (4, 17), 0, VOCAB)
     mask = jnp.array([True, True, True, False])
 
@@ -312,7 +323,7 @@ def test_eval_pass_is_the_old_chunked_loss_bit_for_bit():
         return _old_chunked(*a), None
 
     new = loss()
-    with mock.patch.object(tfm, "_ce_weighted", old_core):
+    with mock.patch.object(tfm_head, "_ce_weighted", old_core):
         old = loss()
     assert calls == [3] and np.isfinite(new) and new == old
 

@@ -1,4 +1,4 @@
-"""The checkpoint plan (``parallel/transformer.py::checkpoint_plan``) held
+"""The checkpoint plan (``parallel/plan.py::checkpoint_plan``) held
 to the compiler: the two benchmark cells whose layers are checkpointed by
 ``_loop_saves`` (``granite4h_micro_train_pp4_t8192``, whose state-space
 stack keeps what a v5e has room for, and ``ouro_train_pp8_t4096``, whose

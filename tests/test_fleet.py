@@ -38,7 +38,7 @@ def _clean_globals():
 
 @pytest.fixture(scope="module")
 def decoder():
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.serve.kvcache import KVDecoder
 
     params = init_params(np.random.default_rng(3), N_LAYERS, D, HEADS,

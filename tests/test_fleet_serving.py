@@ -47,7 +47,7 @@ def _clean_globals():
 
 @pytest.fixture(scope="module")
 def params():
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
 
     return init_params(np.random.default_rng(3), N_LAYERS, D, HEADS,
                        FF, len(CHARMAP))
@@ -582,7 +582,7 @@ def test_rollout_refuses_overlap():
 # -- the acceptance chaos drill (real processes) -----------------------------
 
 def _build_pkg(tmp_path, seed, name):
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.utils.export import export_lm
 
     p = init_params(np.random.default_rng(seed), N_LAYERS, D, HEADS,

@@ -28,7 +28,7 @@ assert len(CHARMAP) == VOCAB
 
 @pytest.fixture(scope="module")
 def params():
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
 
     return init_params(np.random.default_rng(3), N_LAYERS, D, HEADS, FF,
                        VOCAB)

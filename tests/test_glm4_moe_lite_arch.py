@@ -9,6 +9,7 @@ attention against a direct softmax, the second term's mask and weight, the
 refusals by name, and the step unit's loss terms."""
 
 import contextlib
+import dataclasses
 import json
 import os
 import sys
@@ -30,6 +31,10 @@ from reference import glm4_moe_lite as ref                 # noqa: E402
 from znicz_tpu.core import prng                            # noqa: E402
 from znicz_tpu.ops.pallas import attention as pattn        # noqa: E402
 from znicz_tpu.parallel import moe, transformer as tfm     # noqa: E402
+from znicz_tpu.parallel.arch import mechanisms_of_params   # noqa: E402
+from znicz_tpu.parallel.blocks import (                    # noqa: E402
+    _block_routed, _latent_qkv, _rows_rope)
+from znicz_tpu.parallel.params import init_params          # noqa: E402
 from znicz_tpu.parallel.mesh import make_mesh              # noqa: E402
 
 TINY = {
@@ -213,8 +218,8 @@ def test_the_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
         x3 = v[None]
         one = {**p, "ew1": p["ew1"][:1], "ew3": p["ew3"][:1],
                "ew2": p["ew2"][:1], "ln2_g": jnp.ones_like(p["ln2_g"])}
-        share0, _, _ = tfm._block_routed(
-            x3, one, tfm.dataclasses.replace(arch, experts_held=1,
+        share0, _, _ = _block_routed(
+            x3, one, dataclasses.replace(arch, experts_held=1,
                                              norm="rms", eps=0.0), "t")
     np.testing.assert_allclose(v + shared + sum(parts), uncut, atol=5e-6)
     assert pairs == v.shape[0] * dm["top_k"]          # every pair, once
@@ -252,17 +257,17 @@ def test_queries_rotated_as_whole_rows_are_the_cut_and_concatenated_ones(
 
     def loss(h, p, run):
         return sum((a * ct).sum() for a, ct in zip(
-            tfm._latent_qkv(h, p, arch, run), cts))
+            _latent_qkv(h, p, arch, run), cts))
 
     with mock.patch.object(pattn, "unsupported_reason",
                            lambda t, dh: "refused for the test"), \
             jax.default_matmul_precision("highest"):
-        assert tfm._rows_rope(128, arch, rows)
-        assert not tfm._rows_rope(128, arch, cut)
-        text = str(jax.make_jaxpr(lambda h: tfm._latent_qkv(
+        assert _rows_rope(128, arch, rows)
+        assert not _rows_rope(128, arch, cut)
+        text = str(jax.make_jaxpr(lambda h: _latent_qkv(
             h, p, arch, rows))(h))
-        got = tfm._latent_qkv(h, p, arch, rows)
-        want = tfm._latent_qkv(h, p, arch, cut)
+        got = _latent_qkv(h, p, arch, rows)
+        want = _latent_qkv(h, p, arch, cut)
         g_got = jax.grad(loss, (0, 1))(h, p, rows)
         g_want = jax.grad(loss, (0, 1))(h, p, cut)
     assert "rope_tail" in text
@@ -283,7 +288,7 @@ def test_latent_attention_is_a_direct_softmax_with_one_shared_rotary_key():
     h = jax.random.normal(jax.random.PRNGKey(9), (2, 12, dm["d"]))
     nope, rope, heads = dm["nope"], dm["rope"], dm["heads"]
     with jax.default_matmul_precision("highest"):
-        q, k, v = tfm._latent_qkv(h, p, arch, tfm._Run(heads, heads))
+        q, k, v = _latent_qkv(h, p, arch, tfm._Run(heads, heads))
         assert q.shape == k.shape == v.shape == (2, 12, heads, nope + rope)
         # one rotary key for all heads
         for head in range(1, heads):
@@ -349,10 +354,10 @@ def test_the_mtp_term_is_masked_at_the_last_position_and_weighted():
     loss, main, mtp = terms(arch)
     assert loss == pytest.approx(main + 0.3 * mtp, rel=1e-6)
     assert 0.5 * np.log(53) < mtp < 2 * np.log(53)
-    heavy = tfm.dataclasses.replace(arch, mtp_weight=1.0)
+    heavy = dataclasses.replace(arch, mtp_weight=1.0)
     assert terms(heavy)[0] == pytest.approx(main + mtp, rel=1e-6)
     # without the module the loss is the main term alone
-    plain = tfm.dataclasses.replace(arch, mtp=False)
+    plain = dataclasses.replace(arch, mtp=False)
     bare = {k: v for k, v in params.items() if k != "mtp"}
     step, _ = tfm.make_train_step(mesh, plain, lr=0.0,
                                   compute_dtype=jnp.float32)
@@ -484,8 +489,8 @@ def test_serving_and_export_refuse_the_new_mechanisms_by_name(tmp_path, over,
     from znicz_tpu.serve.kvcache import KVDecoder
     from znicz_tpu.utils.export import export_lm
 
-    params = tfm.init_params(np.random.default_rng(1), _arch(_cfg(**over)))
-    assert word in tfm.mechanisms_of_params(params)
+    params = init_params(np.random.default_rng(1), _arch(_cfg(**over)))
+    assert word in mechanisms_of_params(params)
     with pytest.raises(NotImplementedError, match=word):
         KVDecoder(params, heads=4)
     with pytest.raises(ValueError, match=word):

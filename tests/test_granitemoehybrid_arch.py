@@ -31,6 +31,12 @@ if BENCH not in sys.path:
 from reference import granitemoehybrid as ref              # noqa: E402
 
 from znicz_tpu.parallel import ssm, transformer as tfm     # noqa: E402
+from znicz_tpu.parallel.arch import (                      # noqa: E402
+    gpt_arch, mechanisms_of_params)
+from znicz_tpu.parallel.params import (                    # noqa: E402
+    init_params, ssm_in_width)
+from znicz_tpu.parallel.plan import (                      # noqa: E402
+    PLAN_MARGIN, _KEPT_IF_ROOM, _loop_saves, step_footprint)
 from znicz_tpu.parallel.mesh import make_mesh              # noqa: E402
 
 TINY = {
@@ -315,7 +321,7 @@ def test_the_multipliers_emit_nothing_at_their_defaults():
     """A stack without multipliers lowers to the same text whether or not
     the fields exist: at 1.0 / None no multiply is emitted (what keeps the
     other families' steps as they were)."""
-    arch = tfm.gpt_arch(1, 16, 2, 32, 11)
+    arch = gpt_arch(1, 16, 2, 32, 11)
     assert (arch.embed_mult, arch.residual_mult, arch.attn_mult,
             arch.logits_div) == (1.0, 1.0, None, 1.0)
     cfg = _cfg(embedding_multiplier=1.0, residual_multiplier=1.0,
@@ -383,12 +389,12 @@ def test_the_family_reads_into_the_arch_and_its_leaves():
     assert jax.tree.map(np.shape, ref.init_params(1, cfg)) == \
         jax.tree.map(tuple, shapes, is_leaf=lambda x: isinstance(x, tuple))
     assert "state-space layer (Mamba-2)" in \
-        tfm.mechanisms_of_params(ref.init_params(1, cfg))
+        mechanisms_of_params(ref.init_params(1, cfg))
     # the multipliers are written for these sub-layers alone
     with pytest.raises(ValueError, match="multipliers"):
         dataclasses.replace(arch, mtp=True)
     with pytest.raises(ValueError, match="multipliers"):
-        dataclasses.replace(tfm.gpt_arch(1, 16, 2, 32, 11), residual_mult=0.5)
+        dataclasses.replace(gpt_arch(1, 16, 2, 32, 11), residual_mult=0.5)
     with pytest.raises(ValueError, match="ssm_heads"):
         dataclasses.replace(arch, ssm_heads=0)
 
@@ -398,7 +404,7 @@ def test_init_params_follow_the_shape_table_and_mamba2s_start():
 
     prng.seed_all(5)
     arch = _arch(_cfg())
-    params = tfm.init_params(prng.get(), arch)
+    params = init_params(prng.get(), arch)
     assert jax.tree.map(np.shape, params) == jax.tree.map(
         tuple, tfm.param_shapes(arch), is_leaf=lambda x: isinstance(x, tuple))
     blk = params["blocks"][0]
@@ -419,11 +425,11 @@ def test_the_stack_recomputes_its_wide_arrays_by_its_own_policy():
     """``_block_fn`` picks ``_loop_saves`` for a stack with state-space
     layers from the architecture alone (no keyword): a layer's residuals are
     arrays of ``(tokens, d)`` and the chunk states, none ``(tokens, ff)`` or
-    ``(tokens, in_width)`` wide; a keyword still wins."""
+    ``(tokens, in_width)`` wide."""
     arch = _arch(_cfg())
-    blk = tfm._block_fn(False, None, arch)
+    blk = tfm._block_fn(arch)
     assert blk is not tfm._block
-    run = tfm._run_of(_mesh1(), arch, causal=True)
+    run = tfm._run_of(_mesh1(), arch)
     p = jax.tree.map(jnp.asarray, ref.init_params(1, _cfg())["blocks"][0])
     x = jnp.ones((2, 32, 32), jnp.float32)
     _, vjp = jax.vjp(lambda p_, x_: blk(x_, p_, arch, run, 0)[0], p, x)
@@ -434,19 +440,18 @@ def test_the_stack_recomputes_its_wide_arrays_by_its_own_policy():
     # nothing (tokens, inner) or (tokens, ff) wide (both 64 here: the gate,
     # the gated product, the SwiGLU's three), nothing in_width wide
     assert not [s for s in shapes if len(s) == 3 and
-                s[-1] in (64, ssm.in_width(8, 8, 16))]
-    assert tfm._block_fn(True, "dots", arch) is not blk
+                s[-1] in (64, ssm_in_width(8, 8, 16))]
     # with every optional kind kept the wide arrays are residuals: the
     # input projection, the SwiGLU's two products, the convolution's sum
-    full = tfm._block_fn(False, None, arch, tfm._KEPT_IF_ROOM)
+    full = tfm._block_fn(arch, _KEPT_IF_ROOM)
     _, vjp = jax.vjp(lambda p_, x_: full(x_, p_, arch, run, 0)[0], p, x)
     wide = [tuple(v.shape) for v in jax.tree.leaves(vjp)
             if hasattr(v, "shape") and len(v.shape) == 3]
     assert wide.count((2, 32, 64)) >= 2                     # m w1, m w3
-    assert (2, 32, ssm.in_width(8, 8, 16)) in wide
+    assert (2, 32, ssm_in_width(8, 8, 16)) in wide
     assert (2, 32, 64 + 2 * 16) in wide
-    # a keyword's policy is not the plan's to widen
-    assert tfm._saves(()) is tfm._loop_saves
+    # nothing kept beside its list: the policy is the list's own
+    assert tfm._saves(()) is _loop_saves
     assert tfm._saves(("ssm_in",)) is tfm._saves(("ssm_in",))
 
 
@@ -485,15 +490,15 @@ def test_the_plan_keeps_what_the_counted_bytes_leave_room_for(tokens,
     arch, chunks = _cell_arch()
     limit = None if limit_gib is None else int(limit_gib * GIB)
     plan = tfm.checkpoint_plan(arch, tokens, 2, limit, chunks)
-    assert tuple(plan) == tfm._KEPT_IF_ROOM
+    assert tuple(plan) == _KEPT_IF_ROOM
     assert tuple(k for k, v in plan.items() if v) == kept
     sizes = {"glu_wide": 10 * 2 * tokens * 8192 * 2,
              "ssm_in": 9 * tokens * 8512 * 2,
              "ssm_conv_sum": 9 * tokens * 4352 * 4}
     assert all(plan[k] == sizes[k] for k in kept)
     if limit is not None:
-        room = limit - tfm.step_footprint(arch, tokens, 2, chunks) - \
-            tfm.PLAN_MARGIN
+        room = limit - step_footprint(arch, tokens, 2, chunks) - \
+            PLAN_MARGIN
         assert sum(plan.values()) <= max(room, 0)
         refused = [k for k in plan if k not in kept]
         assert not refused or \
@@ -516,7 +521,7 @@ def _loss_and_grads(cfg, dtype, seed=5):
     from znicz_tpu.parallel.compat import shard_map
 
     arch, mesh = _arch(cfg), _mesh1()
-    run = tfm._run_of(mesh, arch, causal=True)
+    run = tfm._run_of(mesh, arch)
 
     def local(ps, tok, lab):
         return tfm._forward_ce(ps, tok, lab, None, arch, run, dtype,
@@ -546,10 +551,10 @@ def test_a_full_plan_moves_no_loss_and_no_gradient(monkeypatch, dtype, rel):
     got = []
     for limit in (None, 64 * GIB):
         monkeypatch.setattr(tfm, "_memory_limit", lambda mesh: limit)
-        assert tuple(k for k, v in tfm.checkpoint_kept_bytes(
+        assert tuple(k for k, v in tfm.step_choices(
             _mesh1(), _arch(cfg), TRAFFIC["minibatch_size"],
-            TRAFFIC["seq_len"], 2, dtype).items() if v) == \
-            (tfm._KEPT_IF_ROOM if limit else ())
+            TRAFFIC["seq_len"], 2)["checkpoint_kept_bytes"].items()
+            if v) == (_KEPT_IF_ROOM if limit else ())
         loss, grads, run = _loss_and_grads(cfg, dtype)
         assert run.hbm_limit == limit
         got.append((loss, grads))
@@ -638,9 +643,9 @@ def test_the_unit_publishes_the_state_space_counters(tmp_path):
         fam = registry.REGISTRY.get(f"znicz_lm_ssm_{key}")
         assert fam is not None and fam.labels(unit=step.name).get() == value
     # the plan's gauge: a CPU reports no memory limit, every kind refused
-    assert step.checkpoint_kept_bytes == dict.fromkeys(tfm._KEPT_IF_ROOM, 0)
+    assert step.checkpoint_kept_bytes == dict.fromkeys(_KEPT_IF_ROOM, 0)
     fam = registry.REGISTRY.get("znicz_lm_checkpoint_kept_bytes")
-    for name in tfm._KEPT_IF_ROOM:
+    for name in _KEPT_IF_ROOM:
         assert fam.labels(unit=step.name, name=name).get() == 0
     with pytest.raises(ValueError, match=MECHANISM):
         step.export_lm(str(tmp_path / "pkg.npz"))

@@ -9,6 +9,7 @@ does not; the threshold by counting against a sort; the eight shares adding
 up to the uncut routed layer; multi-axis rotary positions on text; the
 refusals by name; the step unit's counters."""
 
+import dataclasses
 import os
 import sys
 
@@ -27,6 +28,8 @@ from reference import keye_vl2 as ref                      # noqa: E402
 
 from znicz_tpu.ops.pallas import attention as pattn        # noqa: E402
 from znicz_tpu.parallel import dsa, moe, transformer as tfm  # noqa: E402
+from znicz_tpu.parallel.arch import mechanisms_of_params   # noqa: E402
+from znicz_tpu.parallel.blocks import _rotate              # noqa: E402
 from znicz_tpu.parallel.mesh import make_mesh              # noqa: E402
 
 TINY = {
@@ -160,8 +163,9 @@ def test_the_step_with_its_kernels_interpreted_follows_the_reference(
     want = ref.first_steps(5, cfg, traffic, 1, steps=1)
     with _pallas_interpret(True):
         arch = _arch(cfg)
-        assert tfm.dsa_kernel_shares(_mesh1(), arch, 256) == {
-            "index": 1.0, "align": float(align_kernel)}
+        chose = tfm.step_choices(_mesh1(), arch, 1, 256)
+        assert (chose["dsa_index_kernel_share"],
+                chose["dsa_align_kernel_share"]) == (1.0, float(align_kernel))
         text = str(jax.make_jaxpr(tfm.make_train_step(
             _mesh1(), arch, compute_dtype=jnp.float32)[0])(
                 ref.init_params(5, cfg), jnp.zeros((1, 256), jnp.int32),
@@ -194,7 +198,7 @@ def test_the_alignment_term_trains_the_indexer_and_nothing_else():
 
     cfg = _cfg()
     arch, mesh = _arch(cfg), _mesh1()
-    run = tfm._run_of(mesh, arch, causal=True)
+    run = tfm._run_of(mesh, arch)
     params = ref.init_params(3, cfg)
     rows = ref.make_tokens(3, cfg, 32, 0, 2)
 
@@ -329,7 +333,7 @@ def test_three_equal_position_streams_are_plain_rotate_half():
     x = jax.random.normal(jax.random.PRNGKey(1), (t, heads, dh))
     text = ref._text_positions(t, 3)
     got = ref.mrope(x, text, 1e7, [16, 24, 24])
-    np.testing.assert_allclose(got, tfm._rotate(x[None], 1e7)[0], atol=2e-6)
+    np.testing.assert_allclose(got, _rotate(x[None], 1e7)[0], atol=2e-6)
     np.testing.assert_allclose(got, ref.mrope(x, text[:1], 1e7), atol=0)
     moved = ref.mrope(x, np.stack([text[0], text[1] + 5, text[2]]), 1e7,
                       [16, 24, 24])
@@ -376,9 +380,9 @@ def test_the_family_reads_into_the_arch_and_its_leaves():
     assert jax.tree.map(np.shape, ref.init_params(1, cfg)) == \
         jax.tree.map(tuple, shapes, is_leaf=lambda x: isinstance(x, tuple))
     assert "learned sparse attention (indexer)" in \
-        tfm.mechanisms_of_params(ref.init_params(1, cfg))
+        mechanisms_of_params(ref.init_params(1, cfg))
     with pytest.raises(ValueError, match="index_top_k"):
-        tfm.dataclasses.replace(arch, mtp=True)
+        dataclasses.replace(arch, mtp=True)
 
 
 def test_the_indexer_refuses_a_sharded_mesh_by_name(cpu_devices):

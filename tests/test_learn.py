@@ -470,7 +470,7 @@ def test_fleet_status_surfaces_package_and_rollout_top_level(tmp_path):
 # ---------------------------------------------------------------------------
 
 def _export_base_package(tmp) -> str:
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.utils.export import export_lm
 
     params = init_params(np.random.default_rng(31), 2, 32, 4, 64,

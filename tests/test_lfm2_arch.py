@@ -28,6 +28,8 @@ from reference import lfm2_moe as ref                      # noqa: E402
 
 from znicz_tpu.core import prng                            # noqa: E402
 from znicz_tpu.parallel import moe, transformer as tfm     # noqa: E402
+from znicz_tpu.parallel.arch import gpt_arch               # noqa: E402
+from znicz_tpu.parallel.params import init_params          # noqa: E402
 from znicz_tpu.parallel.mesh import make_mesh              # noqa: E402
 
 TINY = {
@@ -466,16 +468,16 @@ def test_published_configuration_is_read_as_the_issue_counted_it():
 
 
 def test_gpt_shaped_block_is_one_instance_of_the_same_definition():
-    arch = tfm.gpt_arch(2, 32, 4, 64, 17)
+    arch = gpt_arch(2, 32, 4, 64, 17)
     assert not arch.mechanisms()
     assert tfm.param_shapes(arch) == tfm.param_shapes(2, 32, 64, 17)
     assert tfm.param_specs(arch) == tfm.param_specs(2)
-    moe_arch = tfm.gpt_arch(2, 32, 4, 64, 17, n_experts=4)
+    moe_arch = gpt_arch(2, 32, 4, 64, 17, n_experts=4)
     assert tfm.param_specs(moe_arch) == tfm.param_specs(2, moe=True)
     assert tfm.param_shapes(moe_arch) == \
         tfm.param_shapes(2, 32, 64, 17, n_experts=4)
-    a = tfm.init_params(np.random.default_rng(5), arch)
-    b = tfm.init_params(np.random.default_rng(5), 2, 32, 4, 64, 17)
+    a = init_params(np.random.default_rng(5), arch)
+    b = init_params(np.random.default_rng(5), 2, 32, 4, 64, 17)
     assert all(np.array_equal(x, y) for x, y in zip(jax.tree.leaves(a),
                                                     jax.tree.leaves(b)))
 
@@ -497,8 +499,8 @@ def test_serving_and_export_refuse_the_new_layer_kinds_by_name(tmp_path):
     from znicz_tpu.serve.kvcache import KVDecoder
     from znicz_tpu.utils.export import export_lm
 
-    params = tfm.init_params(np.random.default_rng(1),
-                             _arch(_cfg(["conv", "full_attention"], 1)))
+    params = init_params(np.random.default_rng(1),
+                         _arch(_cfg(["conv", "full_attention"], 1)))
     with pytest.raises(NotImplementedError, match="routed experts"):
         KVDecoder(params, heads=4)
     with pytest.raises(ValueError, match="gated short convolution"):

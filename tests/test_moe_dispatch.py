@@ -212,6 +212,7 @@ def test_router_z_loss_value_and_presence(cpu_devices):
     balance aux masks it."""
     from znicz_tpu.parallel.moe import router_z_loss
     from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.core import prng
 
     rng = np.random.default_rng(3)
@@ -230,8 +231,8 @@ def test_router_z_loss_value_and_presence(cpu_devices):
     losses = {}
     for name, zw in (("off", 0.0), ("on", 0.01)):
         prng.seed_all(21)
-        params = tfm.init_params(prng.get(), 2, 32, 4, 64, 16,
-                                 n_experts=4)
+        params = init_params(prng.get(), 2, 32, 4, 64, 16,
+                             n_experts=4)
         step, _ = tfm.make_train_step(mesh, 2, 32, 4, 64, 16, lr=0.2,
                                       n_experts=4, moe_zloss_weight=zw)
         _, loss = step(params, tokens, labels)

@@ -858,11 +858,12 @@ def test_transformer_step_scopes():
     import numpy as np
 
     from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh({"data": 1, "seq": 1, "model": 1}, jax.devices()[:1])
     step, _ = tfm.make_train_step(mesh, 2, 16, 2, 32, 11, lr=0.1)
-    params = tfm.init_params(np.random.default_rng(0), 2, 16, 2, 32, 11)
+    params = init_params(np.random.default_rng(0), 2, 16, 2, 32, 11)
     tok = np.zeros((2, 8), np.int32)
     text = step.lower(params, tok, tok).as_text(debug_info=True)
     for scope in ("embed", "block0.attn", "block0.mlp", "block1.attn",

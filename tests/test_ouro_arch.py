@@ -29,6 +29,11 @@ from reference import ouro as ref                          # noqa: E402
 
 from znicz_tpu.ops.pallas import attention as pattn        # noqa: E402
 from znicz_tpu.parallel import transformer as tfm          # noqa: E402
+from znicz_tpu.parallel.arch import mechanisms_of_params   # noqa: E402
+from znicz_tpu.parallel.blocks import _glu                 # noqa: E402
+from znicz_tpu.parallel.params import (                    # noqa: E402
+    _layer_shapes, init_params)
+from znicz_tpu.parallel.plan import _loop_saves            # noqa: E402
 from znicz_tpu.parallel.mesh import make_mesh              # noqa: E402
 
 MODEL_KEYS = ["model_type", "hidden_size", "intermediate_size", "hidden_act",
@@ -166,10 +171,9 @@ def test_loop_keeps_the_named_arrays_and_recomputes_the_wide_products():
     cfg = _cfg(num_hidden_layers=1)
     arch = _arch(cfg)
     run = tfm._Run(arch.heads, arch.kv_heads)
-    blk = tfm._block_fn(False, None, arch)
+    blk = tfm._block_fn(arch)
     assert blk is not tfm._block
-    assert tfm._block_fn(False, None, _arch(_cfg(total_ut_steps=1))) \
-        is tfm._block
+    assert tfm._block_fn(_arch(_cfg(total_ut_steps=1))) is tfm._block
     p = ref.init_params(2, cfg)["blocks"][0]
     x = jnp.ones((2, 16, 32), jnp.float32)
     saved = saved_residuals(lambda x, p: _on_mesh(
@@ -179,7 +183,7 @@ def test_loop_keeps_the_named_arrays_and_recomputes_the_wide_products():
     plain = saved_residuals(lambda x, p: _on_mesh(
         lambda x, p: tfm._block(x, p, arch, run, 0)[0], x, p), x, p)
     assert any(aval.shape == (2, 16, 48) for aval, _ in plain)
-    assert tfm._loop_saves(types.SimpleNamespace(name="pallas_call"))
+    assert _loop_saves(types.SimpleNamespace(name="pallas_call"))
 
 
 def _unrolled_loss(copies, top, tokens, labels, arch):
@@ -331,14 +335,14 @@ def test_second_norm_acts_on_the_sublayers_output_not_on_the_stream():
     half = {**p, "ln1o_g": jnp.zeros(32)}
     y = _on_mesh(block, x, half)
     m = tfm._rms_norm(x, p["ln2_g"], arch.eps)
-    want = x + tfm._rms_norm(tfm._glu(m, p["w1"], p["w3"], p["w2"]),
+    want = x + tfm._rms_norm(_glu(m, p["w1"], p["w3"], p["w2"]),
                              p["ln2o_g"], arch.eps)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
     # the added term has the norm's scale whatever the stream's is
     added = np.asarray(y - x)
     assert np.sqrt((added ** 2).mean()) == pytest.approx(1.0, abs=0.15)
-    assert set(tfm._layer_shapes(arch, 0)) >= {"ln1_g", "ln1o_g", "ln2_g",
-                                               "ln2o_g"}
+    assert set(_layer_shapes(arch, 0)) >= {"ln1_g", "ln1o_g", "ln2_g",
+                                           "ln2o_g"}
 
 
 def test_an_unknown_model_type_is_refused_by_name():
@@ -364,19 +368,21 @@ def test_keys_the_stack_cannot_honour_are_refused_by_name(key, value, word):
 def test_a_looped_stack_has_one_recomputation_path_and_no_routed_layer():
     """What is not written is refused: routed experts under the loop
     (their counters are means over layers, and the loop would sum them
-    over loop steps), and ``remat`` / ``remat_policy`` beside the loop's
-    own choice of what a layer application saves."""
+    over loop steps); and what a layer application saves is the loop's
+    own choice: no builder takes a recomputation keyword."""
     import dataclasses
+    import inspect
 
     arch = _arch(_cfg())
     with pytest.raises(ValueError, match="no routed experts"):
         dataclasses.replace(arch, sandwich=False, n_experts=4, moe_ff=8,
                             ffns=("glu", "moe_routed"))
-    for kw in ({"remat": True}, {"remat_policy": "dots"}):
-        with pytest.raises(ValueError, match="_loop_saves"):
-            tfm.make_train_step(_mesh1(), arch, lr=0.05, **kw)[0](
-                ref.init_params(5, _cfg()), jnp.zeros((2, 8), jnp.int32),
-                jnp.zeros((2, 8), jnp.int32))
+    for make in (tfm.make_train_step, tfm.make_eval_loss,
+                 tfm.make_logits_fn):
+        assert not {"remat", "remat_policy", "causal"} & \
+            set(inspect.signature(make).parameters)
+    with pytest.raises(TypeError, match="remat"):
+        tfm.make_train_step(_mesh1(), arch, lr=0.05, remat=True)
 
 
 @pytest.mark.parametrize("limit_gib", [None, 15.75, 64.0, 1024.0])
@@ -399,10 +405,10 @@ def test_a_looped_stack_keeps_nothing_beside_its_list_at_any_limit(
         assert tfm._report_plan(arch, tokens, 2, limit,
                                 opts["loss_chunks"]) == ()
     monkeypatch.setattr(tfm, "_memory_limit", lambda mesh: limit)
-    assert tfm._run_of(_mesh1(), cell, causal=True).hbm_limit == limit
-    assert tfm.checkpoint_kept_bytes(_mesh1(), cell, 2, 4096, 8,
-                                     jnp.bfloat16) == {"glu_wide": 0}
-    assert tfm._saves(()) is tfm._loop_saves
+    assert tfm._run_of(_mesh1(), cell).hbm_limit == limit
+    assert tfm.step_choices(_mesh1(), cell, 2, 4096, 8)[
+        "checkpoint_kept_bytes"] == {"glu_wide": 0}
+    assert tfm._saves(()) is _loop_saves
 
 
 def test_the_new_kinds_refuse_a_sharded_mesh_by_name(cpu_devices):
@@ -423,8 +429,8 @@ def test_serving_and_export_refuse_the_new_mechanisms_by_name(tmp_path,
 
     arch = _arch(_cfg(total_ut_steps=steps))
     assert word in arch.mechanisms()
-    params = tfm.init_params(np.random.default_rng(1), arch)
-    assert word in tfm.mechanisms_of_params(params)
+    params = init_params(np.random.default_rng(1), arch)
+    assert word in mechanisms_of_params(params)
     with pytest.raises(NotImplementedError, match=word):
         KVDecoder(params, heads=4)
     with pytest.raises(ValueError, match=word):
@@ -433,7 +439,7 @@ def test_serving_and_export_refuse_the_new_mechanisms_by_name(tmp_path,
 
 def test_init_params_follow_the_shape_table():
     arch = _arch(_cfg())
-    params = tfm.init_params(np.random.default_rng(3), arch)
+    params = init_params(np.random.default_rng(3), arch)
     shapes = tfm.param_shapes(arch)
     assert jax.tree.map(np.shape, params) == shapes
     assert shapes["exit_w"] == (32, 1) and shapes["exit_b"] == (1,)

@@ -24,7 +24,7 @@ N_LAYERS, D, HEADS, FF, VOCAB = 2, 32, 4, 64, 31
 
 @pytest.fixture(scope="module")
 def params():
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
 
     return init_params(np.random.default_rng(3), N_LAYERS, D, HEADS, FF,
                        VOCAB)

@@ -834,16 +834,17 @@ def test_rope_tail_rotates_each_heads_tail_and_nothing_else(dh, rope, dtype):
 
     from znicz_tpu.ops.pallas import rope as prope
     from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.blocks import _rope_angles, _rotate
 
     b, t, h = 2, 48, 3
     ks = jax.random.split(jax.random.PRNGKey(dh + rope), 2)
     x, ct = (jax.random.normal(k, (b, t, h * dh)).astype(dtype) for k in ks)
-    cos, sin = tfm._rope_angles(t, rope, 10000.0)
+    cos, sin = _rope_angles(t, rope, 10000.0)
     assert prope.unsupported_reason(t, dh, rope) is None
 
     def cut(x):
         x4 = x.reshape(b, t, h, dh)
-        return jnp.concatenate([x4[..., :dh - rope], tfm._rotate(
+        return jnp.concatenate([x4[..., :dh - rope], _rotate(
             x4[..., dh - rope:], 10000.0)], axis=-1).reshape(x.shape)
 
     def rows(x):

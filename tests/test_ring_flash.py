@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 from znicz_tpu.core import prng
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel import transformer as tfm
+from znicz_tpu.parallel.params import init_params
 from znicz_tpu.parallel.ring_attention import (ring_attention,
                                                ring_flash_attention)
 
@@ -165,8 +166,8 @@ def test_transformer_ring_flash_forward_matches_ring(cpu_devices):
                 run = []
                 for seed in (13, 29, 57):
                     prng.seed_all(seed)
-                    params = tfm.init_params(prng.get(), n_layers, d,
-                                             heads, ff, vocab)
+                    params = init_params(prng.get(), n_layers, d,
+                                         heads, ff, vocab)
                     run.append(float(ev(params, tokens, labels)))
                 losses[name] = run
             finally:

@@ -1,5 +1,5 @@
 """Seeded composition fuzz over the transformer step's flag surface:
-every MATH-PRESERVING flag (loss_chunks, head_sharded, remat, donate,
+every MATH-PRESERVING flag (loss_chunks, head_sharded, donate,
 shard_update) must leave the training trajectory unchanged vs the plain
 step in ANY combination on ANY mesh — pairwise parity is pinned
 elsewhere; this catches interaction bugs between the execution-strategy
@@ -20,6 +20,7 @@ import jax
 from znicz_tpu.core import prng
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel import transformer as tfm
+from znicz_tpu.parallel.params import init_params
 
 MESHES = (
     {"data": 2, "seq": 2, "model": 2},
@@ -32,8 +33,8 @@ MESHES = (
 def _run(mesh, masked, tokens, labels, mask, n_steps=3, **kw):
     n_layers, d, heads, ff, vocab = 2, 32, 4, 64, 16
     prng.seed_all(41)
-    params = tfm.init_params(prng.get(), n_layers, d, heads, ff, vocab,
-                             n_experts=kw.get("n_experts"))
+    params = init_params(prng.get(), n_layers, d, heads, ff, vocab,
+                         n_experts=kw.get("n_experts"))
     step, _ = tfm.make_train_step(mesh, n_layers, d, heads, ff, vocab,
                                   lr=0.2, masked=masked, **kw)
     args = (tokens, labels, mask) if masked else (tokens, labels)
@@ -58,13 +59,10 @@ def test_math_preserving_flag_combinations(cpu_devices):
         flags = {
             "loss_chunks": [None, 2, 3, 5][int(rng.integers(4))],
             "head_sharded": bool(rng.integers(2)),
-            "remat": bool(rng.integers(2)),
             "donate": False,   # donation forbids plain-python rebinds
                                # of the SAME host params; covered by
-                               # test_remat_and_donate_match_baseline
+                               # test_donate_matches_baseline
             "shard_update": bool(rng.integers(2)),
-            "remat_policy":
-                [None, "dots", "nothing"][int(rng.integers(3))],
         }
         mesh = make_mesh(mesh_axes)
         key = (tuple(sorted(mesh_axes.items())), masked)

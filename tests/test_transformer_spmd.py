@@ -14,6 +14,9 @@ import numpy as np
 from znicz_tpu.core import prng
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel import transformer as tfm
+from znicz_tpu.parallel.params import init_params
+from znicz_tpu.parallel.pipeline import (
+    init_moe_pipeline_params, make_pipeline_step)
 
 
 def test_dp_sp_tp_train_step_learns(cpu_devices):
@@ -21,7 +24,7 @@ def test_dp_sp_tp_train_step_learns(cpu_devices):
     prng.seed_all(5)
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 2, 32, 4, 64, 17
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    params = init_params(gen, n_layers, d, heads, ff, vocab)
     step, _ = tfm.make_train_step(mesh, n_layers, d, heads, ff, vocab,
                                   lr=0.2)
     rng = np.random.default_rng(0)
@@ -41,7 +44,7 @@ def test_dp_sp_tp_matches_tp1(cpu_devices):
     prng.seed_all(7)
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 1, 16, 2, 32, 11
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    params = init_params(gen, n_layers, d, heads, ff, vocab)
     rng = np.random.default_rng(1)
     tokens = rng.integers(0, vocab, (4, 8)).astype(np.int32)
     labels = ((tokens + 1) % vocab).astype(np.int32)
@@ -69,7 +72,7 @@ def test_bf16_step_tracks_f32(cpu_devices):
     prng.seed_all(11)
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 1, 16, 2, 32, 11
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    params = init_params(gen, n_layers, d, heads, ff, vocab)
     rng = np.random.default_rng(3)
     tokens = rng.integers(0, vocab, (4, 8)).astype(np.int32)
     labels = ((tokens + 1) % vocab).astype(np.int32)
@@ -96,9 +99,9 @@ def test_dp_pp_ep_pipeline_step_learns(cpu_devices):
     prng.seed_all(9)
     gen = prng.get()
     d, ff, n_experts = 16, 32, 4
-    params = tfm.init_moe_pipeline_params(gen, n_stages=2, d=d, ff=ff,
-                                          n_experts=n_experts)
-    step, _ = tfm.make_pipeline_step(mesh, n_experts, lr=0.05)
+    params = init_moe_pipeline_params(gen, n_stages=2, d=d, ff=ff,
+                                      n_experts=n_experts)
+    step, _ = make_pipeline_step(mesh, n_experts, lr=0.05)
     rng = np.random.default_rng(2)
     xs = rng.normal(size=(4, 8, d)).astype(np.float32)
     w_true = rng.normal(0, 0.3, (d, d)).astype(np.float32)
@@ -131,7 +134,7 @@ def test_flash_step_matches_ring_composition(cpu_devices):
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 1, 256, 2, 64, 11
     assert supported(128, d // heads)
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    params = init_params(gen, n_layers, d, heads, ff, vocab)
     rng = np.random.default_rng(7)
     tokens = rng.integers(0, vocab, (4, 128)).astype(np.int32)
     labels = ((tokens + 1) % vocab).astype(np.int32)
@@ -167,7 +170,7 @@ def test_shard_update_transformer_matches_replicated(cpu_devices):
     prng.seed_all(19)
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 2, 32, 4, 64, 17
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    params = init_params(gen, n_layers, d, heads, ff, vocab)
     rng = np.random.default_rng(4)
     tokens = rng.integers(0, vocab, (4, 16)).astype(np.int32)
     labels = ((tokens + 1) % vocab).astype(np.int32)
@@ -197,8 +200,8 @@ def test_bf16_pipeline_step_tracks_f32(cpu_devices):
     prng.seed_all(25)
     gen = prng.get()
     d, ff, n_experts = 16, 32, 4
-    params = tfm.init_moe_pipeline_params(gen, n_stages=2, d=d, ff=ff,
-                                          n_experts=n_experts)
+    params = init_moe_pipeline_params(gen, n_stages=2, d=d, ff=ff,
+                                      n_experts=n_experts)
     mesh = make_mesh({"data": 2, "pipe": 2, "expert": 2})
     rng = np.random.default_rng(6)
     xs = rng.normal(size=(4, 8, d)).astype(np.float32)
@@ -206,8 +209,8 @@ def test_bf16_pipeline_step_tracks_f32(cpu_devices):
 
     losses = {}
     for name, cdt in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
-        step, _ = tfm.make_pipeline_step(mesh, n_experts, lr=0.05,
-                                         compute_dtype=cdt)
+        step, _ = make_pipeline_step(mesh, n_experts, lr=0.05,
+                                     compute_dtype=cdt)
         p = dict(params)
         run = []
         for _ in range(5):
@@ -247,7 +250,7 @@ def test_orbax_checkpoint_roundtrip_across_meshes(tmp_path, cpu_devices):
     prng.seed_all(29)
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 1, 32, 4, 64, 13
-    p = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    p = init_params(gen, n_layers, d, heads, ff, vocab)
     rng = np.random.default_rng(8)
     tokens = rng.integers(0, vocab, (4, 8)).astype(np.int32)
     labels = ((tokens + 1) % vocab).astype(np.int32)
@@ -278,11 +281,10 @@ def test_orbax_checkpoint_roundtrip_across_meshes(tmp_path, cpu_devices):
     np.testing.assert_allclose(float(loss_b), float(loss_ref), rtol=2e-4)
 
 
-def test_remat_and_donate_match_baseline(cpu_devices):
-    """remat=True (per-block jax.checkpoint) and donate=True (params
-    buffers donated to the step) are pure execution-strategy switches:
-    losses and updated params must match the plain step bit-for-bit
-    variant by variant (remat recomputes the same f32/bf16 ops).
+def test_donate_matches_baseline(cpu_devices):
+    """donate=True (params buffers donated to the step) is a pure
+    execution-strategy switch: losses and updated params must match the
+    plain step bit-for-bit.
 
     NOTE: the CPU backend ignores donate_argnums, so the donate leg
     here pins only API/rebind safety; actual donation runs on the chip
@@ -296,15 +298,10 @@ def test_remat_and_donate_match_baseline(cpu_devices):
     labels = ((tokens + 1) % vocab).astype(np.int32)
 
     outs = {}
-    for name, kw in (("plain", {}), ("remat", {"remat": True}),
-                     ("donate", {"donate": True}),
-                     ("remat_dots", {"remat_policy": "dots"}),
-                     ("remat_dnb",
-                      {"remat_policy": "dots_no_batch"}),
-                     ("remat_nothing", {"remat_policy": "nothing"})):
+    for name, kw in (("plain", {}), ("donate", {"donate": True})):
         prng.seed_all(9)
-        params = tfm.init_params(prng.get(), n_layers, d, heads, ff,
-                                 vocab)
+        params = init_params(prng.get(), n_layers, d, heads, ff,
+                             vocab)
         step, _ = tfm.make_train_step(mesh, n_layers, d, heads, ff,
                                       vocab, lr=0.2, **kw)
         for _ in range(3):
@@ -312,10 +309,8 @@ def test_remat_and_donate_match_baseline(cpu_devices):
         outs[name] = (float(loss),
                       np.asarray(jax.device_get(
                           jax.tree.leaves(params)[0])))
-    for name in ("remat", "donate", "remat_dots", "remat_dnb",
-                 "remat_nothing"):
-        assert outs[name][0] == outs["plain"][0], (name, outs[name][0])
-        np.testing.assert_array_equal(outs[name][1], outs["plain"][1])
+    assert outs["donate"][0] == outs["plain"][0], outs["donate"][0]
+    np.testing.assert_array_equal(outs["donate"][1], outs["plain"][1])
 
 
 def test_chunked_ce_matches_dense(cpu_devices):
@@ -338,8 +333,8 @@ def test_chunked_ce_matches_dense(cpu_devices):
         for name, chunks in (("dense", None), ("chunk4", 4),
                              ("chunk3", 3)):   # 3 does not divide 16·2
             prng.seed_all(11)
-            params = tfm.init_params(prng.get(), n_layers, d, heads, ff,
-                                     vocab)
+            params = init_params(prng.get(), n_layers, d, heads, ff,
+                                 vocab)
             step, _ = tfm.make_train_step(
                 mesh, n_layers, d, heads, ff, vocab, lr=0.2,
                 masked=masked, loss_chunks=chunks)
@@ -356,7 +351,7 @@ def test_chunked_ce_matches_dense(cpu_devices):
 
     # eval path shares the implementation
     prng.seed_all(11)
-    params = tfm.init_params(prng.get(), n_layers, d, heads, ff, vocab)
+    params = init_params(prng.get(), n_layers, d, heads, ff, vocab)
     ev_d = tfm.make_eval_loss(mesh, n_layers, d, heads, ff, vocab)
     ev_c = tfm.make_eval_loss(mesh, n_layers, d, heads, ff, vocab,
                               loss_chunks=4)
@@ -387,8 +382,8 @@ def test_head_sharded_matches_replicated(cpu_devices):
                          ("vshard_chunk", {"head_sharded": True,
                                            "loss_chunks": 4})):
             prng.seed_all(21)
-            params = tfm.init_params(prng.get(), n_layers, d, heads, ff,
-                                     vocab)
+            params = init_params(prng.get(), n_layers, d, heads, ff,
+                                 vocab)
             step, _ = tfm.make_train_step(mesh, n_layers, d, heads, ff,
                                           vocab, lr=0.2, masked=masked,
                                           **kw)
@@ -404,7 +399,7 @@ def test_head_sharded_matches_replicated(cpu_devices):
                 np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
 
     prng.seed_all(21)
-    params = tfm.init_params(prng.get(), n_layers, d, heads, ff, vocab)
+    params = init_params(prng.get(), n_layers, d, heads, ff, vocab)
     ev_r = tfm.make_eval_loss(mesh, n_layers, d, heads, ff, vocab)
     ev_v = tfm.make_eval_loss(mesh, n_layers, d, heads, ff, vocab,
                               head_sharded=True)
@@ -432,7 +427,7 @@ def test_orbax_roundtrip_head_sharded_to_replicated(tmp_path,
 
     prng.seed_all(31)
     n_layers, d, heads, ff, vocab = 1, 32, 4, 64, 16
-    p = tfm.init_params(prng.get(), n_layers, d, heads, ff, vocab)
+    p = init_params(prng.get(), n_layers, d, heads, ff, vocab)
     rng = np.random.default_rng(9)
     tokens = rng.integers(0, vocab, (4, 8)).astype(np.int32)
     labels = ((tokens + 1) % vocab).astype(np.int32)
@@ -480,8 +475,8 @@ def test_moe_ffn_transformer_tp_invariant_and_learns(cpu_devices):
         # regularizers ride the tp-invariance pin
         mesh = make_mesh(shape)
         prng.seed_all(33)
-        params = tfm.init_params(prng.get(), n_layers, d, heads, ff,
-                                 vocab, n_experts=n_experts)
+        params = init_params(prng.get(), n_layers, d, heads, ff,
+                             vocab, n_experts=n_experts)
         step, _ = tfm.make_train_step(mesh, n_layers, d, heads, ff,
                                       vocab, lr=0.2,
                                       n_experts=n_experts,

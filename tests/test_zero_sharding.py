@@ -378,12 +378,14 @@ def test_transformer_shard_params_matches_shard_update(cpu_devices):
     and regathers on demand instead of after the update)."""
     import jax
     from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.params import (
+        init_params, shard_params_host, unshard_params_host)
     from znicz_tpu.parallel.mesh import make_mesh
 
     prng.seed_all(19)
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 2, 32, 4, 64, 17
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    params = init_params(gen, n_layers, d, heads, ff, vocab)
     rng = np.random.default_rng(4)
     tokens = rng.integers(0, vocab, (4, 16)).astype(np.int32)
     labels = ((tokens + 1) % vocab).astype(np.int32)
@@ -400,14 +402,14 @@ def test_transformer_shard_params_matches_shard_update(cpu_devices):
         p = {k: (v if not isinstance(v, list) else [dict(b) for b in v])
              for k, v in params.items()}
         if mode == "shard_params":
-            p = tfm.shard_params_host(p, specs, 2)
+            p = shard_params_host(p, specs, 2)
         losses = []
         for _ in range(6):
             p, loss = step(p, tokens, labels)
             losses.append(float(loss))
         host = jax.device_get(p)
         if mode == "shard_params":
-            host = tfm.unshard_params_host(host, specs, shapes)
+            host = unshard_params_host(host, specs, shapes)
         res[mode] = (losses, host)
 
     assert res["shard_params"][0] == res["shard_update"][0]
@@ -420,15 +422,17 @@ def test_transformer_shard_params_host_roundtrip(cpu_devices):
     """shard_params_host -> unshard_params_host is the identity,
     including odd (padded) leaf sizes."""
     from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.params import (
+        init_params, shard_params_host, unshard_params_host)
 
     prng.seed_all(3)
     gen = prng.get()
     n_layers, d, heads, ff, vocab = 1, 16, 2, 32, 11   # 11: pads at n=4
-    params = tfm.init_params(gen, n_layers, d, heads, ff, vocab)
+    params = init_params(gen, n_layers, d, heads, ff, vocab)
     specs = tfm.param_specs(n_layers)
     shapes = tfm.param_shapes(n_layers, d, ff, vocab)
-    flat = tfm.shard_params_host(params, specs, 4)
-    back = tfm.unshard_params_host(flat, specs, shapes)
+    flat = shard_params_host(params, specs, 4)
+    back = unshard_params_host(flat, specs, shapes)
     import jax
     for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -26,6 +26,7 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.plan import PLAN_MARGIN, step_footprint
     from znicz_tpu.parallel.mesh import make_mesh
 
     try:
@@ -79,8 +80,8 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
         "limit": limit,
         "plan": tfm.checkpoint_plan(arch, b * t, 2, limit,
                                     opts["loss_chunks"]),
-        "footprint": tfm.step_footprint(arch, b * t, 2, opts["loss_chunks"]),
-        "margin": tfm.PLAN_MARGIN}
+        "footprint": step_footprint(arch, b * t, 2, opts["loss_chunks"]),
+        "margin": PLAN_MARGIN}
 
 
 if __name__ == "__main__":

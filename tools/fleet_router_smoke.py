@@ -49,7 +49,7 @@ def fail(msg: str) -> "None":
 def build_packages(tmp: str):
     import numpy as np
 
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.utils.export import export_lm
     from znicz_tpu.utils.naming import package_fingerprint
 

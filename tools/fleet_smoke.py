@@ -44,7 +44,7 @@ def fail(msg: str) -> "None":
 def build_package(tmp: str) -> str:
     import numpy as np
 
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.utils.export import export_lm
 
     charmap = list("abcdefghijklmnopqrstuvwxyz .,!?")

@@ -48,7 +48,7 @@ def fail(msg: str) -> "None":
 def build_package(tmp: str, with_draft: bool = False) -> str:
     import numpy as np
 
-    from znicz_tpu.parallel.transformer import init_params
+    from znicz_tpu.parallel.params import init_params
     from znicz_tpu.serve.paged import truncate_draft
     from znicz_tpu.utils.export import export_lm
 

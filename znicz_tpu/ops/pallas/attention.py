@@ -61,7 +61,7 @@ only if the caller's arrays ARE row-major ``(b, t, h*dh)`` on the chip:
 XLA keeps them so when they are made whole rows of heads at a time (a
 product's result, a row kernel's) and lays them out time-minor, with a
 copy before the kernels, when a head is cut and concatenated at a column
-that is no multiple of 128 (``parallel/transformer.py::_latent_qkv`` has
+that is no multiple of 128 (``parallel/blocks.py::_latent_qkv`` has
 the forms that keep it).
 
 A SELECTION (``flash_attention(..., sel=)``: int8 ``(b, t, t)``, one entry a

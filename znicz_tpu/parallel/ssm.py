@@ -44,8 +44,9 @@ operands when the gradients are, and never stored.  The operands themselves
 come from the input projection and the convolution, which the layer's own
 checkpoint (``transformer.py::_block_fn``) makes again unless the device has
 room for them: the projection's result and the convolution's float32 sum are
-named here (``ssm_in``, ``ssm_conv_sum``) for ``transformer.py::
-checkpoint_plan`` to keep or refuse.
+named here (``ssm_in``, ``ssm_conv_sum``) for ``plan.py::checkpoint_plan`` to
+keep or refuse.  The mixer's leaves and their shapes are ``params.py``'s
+(``_ssm_leaf_shapes``).
 """
 
 from __future__ import annotations
@@ -56,27 +57,6 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from znicz_tpu.observe import probe as _probe
-
-#: the words a refusal is made of (``Arch.mechanisms``, ``serve/``, a mesh)
-MECHANISM = "state-space layer (Mamba-2)"
-
-
-def in_width(heads: int, head_dim: int, state: int) -> int:
-    """Columns of ``W_in``: the gate, the convolved ``[x | B | C]``, a step
-    size a head."""
-    return 2 * heads * head_dim + 2 * state + heads
-
-
-def leaf_shapes(d: int, heads: int, head_dim: int, state: int,
-                taps: int) -> dict:
-    """``{leaf: shape}`` of the mixer."""
-    inner = heads * head_dim
-    return {"ssm_in": (d, in_width(heads, head_dim, state)),
-            "ssm_conv_k": (taps, inner + 2 * state),
-            "ssm_conv_b": (inner + 2 * state,), "ssm_dt_b": (heads,),
-            "ssm_a_log": (heads,), "ssm_d": (heads,), "ssm_g": (inner,),
-            "ssm_out": (inner, d)}
-
 
 #: the mixer's leaves that stay in the master dtype in a step's forward:
 #: the step size's bias, the decay rate and the skip enter float32 chains

@@ -13,12 +13,12 @@ triggers **zero** recompiles across mixed request lengths within a
 bucket; ``compile_count`` makes that assertable exactly like
 ``BatchEngine``.
 
-The decode math deliberately mirrors the training transformer
-(``parallel/transformer.py``) op by op — the same ``_layer_norm``, the
+The decode math deliberately mirrors the training stack's layers
+(``parallel/blocks.py``) op by op — the same ``_layer_norm``, the
 same ``masked_scores`` scale/mask constants, the same f32 softmax
 accumulators ``ring_attention`` uses at ring size 1, the same compute-
 dtype cast policy — and the whole path is pinned against the full-pass
-:func:`~znicz_tpu.parallel.transformer.make_logits_fn` oracle: greedy
+``make_logits_fn`` oracle the train step's module builds: greedy
 decode through the cache must reproduce N full forward passes token for
 token (tests/test_generate.py).  Dense FFN blocks only; MoE decode is
 refused loudly (expert routing under a one-token batch is a different
@@ -83,7 +83,7 @@ class TokenSampler:
 class KVDecoder(Logger):
     """Bucketed incremental decoder over a transformer param pytree.
 
-    ``params``: the ``parallel/transformer.py`` pytree (``emb``,
+    ``params``: the ``parallel/params.py`` pytree (``emb``,
     ``head``, ``blocks``) as numpy or jax arrays; placed on device
     once.  ``heads`` cannot be derived from the arrays and must be
     given; everything else (layers, d, ff, vocab) is read off the
@@ -111,7 +111,7 @@ class KVDecoder(Logger):
         super().__init__()
         import jax
 
-        from znicz_tpu.parallel.transformer import mechanisms_of_params
+        from znicz_tpu.parallel.arch import mechanisms_of_params
 
         extra = mechanisms_of_params(params)
         if extra:
@@ -177,7 +177,7 @@ class KVDecoder(Logger):
 
     # -- compiled program builders ------------------------------------------
     def _cast_policy(self):
-        from znicz_tpu.parallel.transformer import _default_compute_dtype
+        from znicz_tpu.parallel.arch import _default_compute_dtype
         return _default_compute_dtype(None)
 
     def _attend(self, jnp, s, v_cache):
@@ -200,7 +200,7 @@ class KVDecoder(Logger):
         import jax.numpy as jnp
 
         from znicz_tpu.ops.attention import masked_scores
-        from znicz_tpu.parallel.transformer import _layer_norm
+        from znicz_tpu.parallel.blocks import _layer_norm
 
         H, Dh = self.heads, self.head_dim
         cdt = self._cast_policy()
@@ -240,7 +240,7 @@ class KVDecoder(Logger):
         import jax
         import jax.numpy as jnp
 
-        from znicz_tpu.parallel.transformer import _layer_norm
+        from znicz_tpu.parallel.blocks import _layer_norm
 
         H, Dh = self.heads, self.head_dim
         cdt = self._cast_policy()

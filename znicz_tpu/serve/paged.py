@@ -277,7 +277,7 @@ class PagedKVDecoder(KVDecoder):
         import jax
         import jax.numpy as jnp
 
-        from znicz_tpu.parallel.transformer import _layer_norm
+        from znicz_tpu.parallel.blocks import _layer_norm
 
         H, Dh, page = self.heads, self.head_dim, self.page
         cdt = self._cast_policy()
@@ -323,7 +323,7 @@ class PagedKVDecoder(KVDecoder):
         import jax
         import jax.numpy as jnp
 
-        from znicz_tpu.parallel.transformer import _layer_norm
+        from znicz_tpu.parallel.blocks import _layer_norm
 
         p_view, q_len = key
         H, Dh, page = self.heads, self.head_dim, self.page

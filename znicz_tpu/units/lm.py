@@ -59,6 +59,55 @@ def _term_gauges() -> dict:
     }
 
 
+def _choice_gauges() -> tuple:
+    """``(key, gauge, label)`` of each constant of a step as it is built
+    (``parallel/transformer.py::step_choices``, by its key, which is the
+    unit's attribute too): a value that is a dict sets one child a name
+    under ``label``.  The next selection is one row here."""
+    from znicz_tpu.observe import registry
+
+    return (
+        ("ce_grad_in_forward_share", registry.gauge(
+            "znicz_lm_ce_grad_in_forward_share",
+            "head passes of the train step that made their gradients in "
+            "the pass that made their logits (chunked cross-entropy "
+            "against a replicated head: three products a pass) over all "
+            "its head passes (an unchunked or a vocab-sharded one leaves "
+            "them to AD)",
+            ("unit",)), None),
+        ("attn_kvb_block_rows", registry.gauge(
+            "znicz_lm_attn_kvb_block_rows",
+            "rows of the (square) tile each pass of the key/value-blocked "
+            "flash kernels runs for the step's attention shape (forward, "
+            "dk/dv, dq), 0 where its attention layers run the whole-row "
+            "form, no flash kernel, or there are none",
+            ("unit", "pass")), "pass"),
+        ("checkpoint_kept_bytes", registry.gauge(
+            "znicz_lm_checkpoint_kept_bytes",
+            "bytes all the step's checkpointed layers keep of an optional "
+            "kind of activation for the backward pass (the SwiGLU's two "
+            "wide products, a state-space layer's input projection, its "
+            "convolution's float32 sum), 0 for a kind the device's memory "
+            "refused",
+            ("unit", "name")), "name"),
+        ("dsa_align_kernel_share", registry.gauge(
+            "znicz_lm_dsa_align_kernel_share",
+            "layers with an indexer whose alignment target (the heads' mean "
+            "attention probabilities over the selection) the Pallas kernel "
+            "dsa_align_target makes over the layers with an indexer (the "
+            "rest: blocked jax.numpy, the heads' scores through HBM)",
+            ("unit",)), None),
+        ("dsa_index_kernel_share", registry.gauge(
+            "znicz_lm_dsa_index_kernel_share",
+            "layers with an indexer whose index scores and their gradients "
+            "to the index queries, keys and weights the Pallas kernels "
+            "dsa_index_scores and dsa_index_grads make over the layers with "
+            "an indexer (the rest: blocked jax.numpy einsums, the index "
+            "heads' scores through HBM)",
+            ("unit",)), None),
+    )
+
+
 def _fold_pass(acc, loss, mask, stats):
     """One minibatch into the class pass's device-side sums: the loss
     weighted by the rows that count (the Decision's own weighting), the
@@ -84,7 +133,7 @@ class TransformerLMStep(AcceleratedUnit):
     ``mamba_n_heads``, ``embedding_multiplier``, ... and
     ``experts_held``, this chip's share:
     ``{"first", "count"}``; see
-    ``parallel.transformer.arch_from_config``); the vocabulary is the
+    ``parallel.arch.arch_from_config``); the vocabulary is the
     loader's.  Without it the unit builds the GPT-shaped block from
     ``n_layers``, ``d``, ``heads``, ``ff`` (and ``n_experts``).
 
@@ -186,29 +235,20 @@ class TransformerLMStep(AcceleratedUnit):
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
         self.attn_direct_layout_share: Optional[float] = None
-        #: ``{pass: rows}`` of the tile each pass of the key/value-blocked
-        #: flash kernels runs for the step's attention shape (``fwd``,
-        #: ``dkv``, ``dq``; ``parallel/transformer.py::
-        #: attn_kvb_block_rows``), 0 where the attention layers run
-        #: another form; empty until the step is built
+        #: the constants of the step as it is built, each under its key of
+        #: ``parallel/transformer.py::step_choices`` (which documents them)
+        #: and gauged by :func:`_choice_gauges`; empty or None until then.
+        #: ``{pass: rows}`` of the blocked flash kernels' tiles
         self.attn_kvb_block_rows: dict = {}
-        #: of the step's layers with an indexer, the share whose alignment
-        #: target, and the share whose index scores and their gradients,
-        #: the Pallas kernels make (each all or none: ``parallel/
-        #: transformer.py::dsa_kernel_shares``); None without an indexer,
-        #: and until the step is built
+        #: of the layers with an indexer, the share whose alignment target
+        #: and whose index scores the Pallas kernels make; None without one
         self.dsa_align_kernel_share: Optional[float] = None
         self.dsa_index_kernel_share: Optional[float] = None
-        #: ``{name: bytes}`` of what the step's checkpointed layers keep
-        #: for the backward pass beside their policy's own list, by the
-        #: memory the device reports (``parallel/transformer.py::
-        #: checkpoint_plan``; 0: refused); empty where no layer is
-        #: checkpointed by that policy, and until the step is built
+        #: ``{name: bytes}`` the checkpointed layers keep beside their
+        #: policy's own list (``parallel/plan.py::checkpoint_plan``)
         self.checkpoint_kept_bytes: dict = {}
-        #: of the train step's head passes, the share that make their
-        #: gradients where they make their logits (all or none:
-        #: ``parallel/transformer.py::ce_grad_in_forward``); None until
-        #: the step is built
+        #: of the head passes, the share that make their gradients where
+        #: they make their logits (``parallel/head.py::ce_grad_in_forward``)
         self.ce_grad_in_forward_share: Optional[float] = None
         self.arch = None
         self._params = None
@@ -228,6 +268,7 @@ class TransformerLMStep(AcceleratedUnit):
 
         from znicz_tpu.parallel import transformer as tfm
         from znicz_tpu.parallel.mesh import make_mesh
+        from znicz_tpu.parallel.params import init_params
 
         if self.loader is None:
             raise ValueError("TransformerLMStep needs loader=")
@@ -244,7 +285,7 @@ class TransformerLMStep(AcceleratedUnit):
         self.arch = self._resolve_arch()
         if self._params is None:
             with probe.setup_phase("init_params"):
-                self._params = tfm.init_params(prng.get(), self.arch)
+                self._params = init_params(prng.get(), self.arch)
         with probe.setup_phase("place"):
             self._params = probe.placed(self._place_params(self._params))
         # masked=True: the loader's padded tail rows (base.py static-shape
@@ -258,16 +299,10 @@ class TransformerLMStep(AcceleratedUnit):
         self._eval = tfm.make_eval_loss(
             self.mesh, self.arch, masked=True, loss_chunks=self.loss_chunks,
             head_sharded=self.head_sharded)
-        self._publish_ce_rule(float(tfm.ce_grad_in_forward(
-            self.arch, self.loss_chunks, self.head_sharded)))
-        seq_len = int(self.loader.minibatch_data.shape[1])
-        self._publish_attn_tiles(tfm.attn_kvb_block_rows(
-            self.mesh, self.arch, seq_len))
-        self._publish_dsa_kernels(tfm.dsa_kernel_shares(
-            self.mesh, self.arch, seq_len))
-        self._publish_checkpoint_plan(tfm.checkpoint_kept_bytes(
+        self._publish_choices(tfm.step_choices(
             self.mesh, self.arch, int(self.loader.max_minibatch_size),
-            seq_len, self.loss_chunks))
+            int(self.loader.minibatch_data.shape[1]), self.loss_chunks,
+            self.head_sharded))
         self._fold = jax.jit(_fold_pass)
         # cold-compile timing, and the shapes probe.scope_map() lowers
         # the programs from again (as FusedTrainStep's programs)
@@ -287,12 +322,12 @@ class TransformerLMStep(AcceleratedUnit):
 
     def _resolve_arch(self):
         """The ``Arch`` this unit runs, at the loader's vocabulary."""
-        from znicz_tpu.parallel import transformer as tfm
+        from znicz_tpu.parallel.arch import arch_from_config, gpt_arch
 
         if self.arch_config is not None:
-            return tfm.arch_from_config(self.arch_config, self.vocab_size)
-        return tfm.gpt_arch(self.n_layers, self.d, self.heads, self.ff,
-                            self.vocab_size, self.n_experts, self.moe_top_k)
+            return arch_from_config(self.arch_config, self.vocab_size)
+        return gpt_arch(self.n_layers, self.d, self.heads, self.ff,
+                        self.vocab_size, self.n_experts, self.moe_top_k)
 
     def _stage_batch(self, tokens, labels, count: int):
         """ONE fused ``device_put``: tokens, labels and the padding mask
@@ -323,9 +358,9 @@ class TransformerLMStep(AcceleratedUnit):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from znicz_tpu.parallel import transformer as tfm
+        from znicz_tpu.parallel.params import param_specs
 
-        specs = tfm.param_specs(self.arch, self.head_sharded)
+        specs = param_specs(self.arch, self.head_sharded)
         return jax.device_put(
             params, jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s), specs,
@@ -397,84 +432,20 @@ class TransformerLMStep(AcceleratedUnit):
             self._publish_attn_layout(float(sums["attn_direct"]) /
                                       float(sums["attn_flash"]))
 
-    def _publish_ce_rule(self, share: float) -> None:
-        """Of the step's head passes, the share that take the chunked
-        cross-entropy's own rule (a constant of the step as it is built):
-        the unit's mirror and the process registry."""
-        from znicz_tpu.observe import registry
-
-        self.ce_grad_in_forward_share = share
-        registry.gauge(
-            "znicz_lm_ce_grad_in_forward_share",
-            "head passes of the train step that made their gradients in "
-            "the pass that made their logits (chunked cross-entropy "
-            "against a replicated head: three products a pass) over all "
-            "its head passes (an unchunked or a vocab-sharded one leaves "
-            "them to AD)",
-            ("unit",)).labels(unit=self.name).set(share)
-
-    def _publish_attn_tiles(self, rows: dict) -> None:
-        """The rows of the tile each pass of the key/value-blocked flash
-        kernels runs for the step's attention shape (a constant of the
-        step as it is built): the unit's mirror and the process registry."""
-        from znicz_tpu.observe import registry
-
-        self.attn_kvb_block_rows = rows
-        gauge = registry.gauge(
-            "znicz_lm_attn_kvb_block_rows",
-            "rows of the (square) tile each pass of the key/value-blocked "
-            "flash kernels runs for the step's attention shape (forward, "
-            "dk/dv, dq), 0 where its attention layers run the whole-row "
-            "form, no flash kernel, or there are none",
-            ("unit", "pass"))
-        for name, value in rows.items():
-            gauge.labels(**{"unit": self.name, "pass": name}).set(value)
-
-    def _publish_checkpoint_plan(self, plan: dict) -> None:
-        """What the step's checkpointed layers keep beside their policy's
-        own list, in bytes a name (a constant of the step as it is built):
-        the unit's mirror and the process registry."""
-        from znicz_tpu.observe import registry
-
-        self.checkpoint_kept_bytes = plan
-        gauge = registry.gauge(
-            "znicz_lm_checkpoint_kept_bytes",
-            "bytes all the step's checkpointed layers keep of an optional "
-            "kind of activation for the backward pass (the SwiGLU's two "
-            "wide products, a state-space layer's input projection, its "
-            "convolution's float32 sum), 0 for a kind the device's memory "
-            "refused",
-            ("unit", "name"))
-        for name, value in plan.items():
-            gauge.labels(unit=self.name, name=name).set(value)
-
-    def _publish_dsa_kernels(self, shares: Optional[dict]) -> None:
-        """Of the layers with an indexer, the share whose alignment target
-        and the share whose index scores the kernels make (constants of the
-        step as it is built; nothing without an indexer): the unit's
-        mirrors and the process registry."""
-        from znicz_tpu.observe import registry
-
-        if shares is None:
-            self.dsa_align_kernel_share = self.dsa_index_kernel_share = None
-            return
-        self.dsa_align_kernel_share = shares["align"]
-        self.dsa_index_kernel_share = shares["index"]
-        registry.gauge(
-            "znicz_lm_dsa_align_kernel_share",
-            "layers with an indexer whose alignment target (the heads' mean "
-            "attention probabilities over the selection) the Pallas kernel "
-            "dsa_align_target makes over the layers with an indexer (the "
-            "rest: blocked jax.numpy, the heads' scores through HBM)",
-            ("unit",)).labels(unit=self.name).set(shares["align"])
-        registry.gauge(
-            "znicz_lm_dsa_index_kernel_share",
-            "layers with an indexer whose index scores and their gradients "
-            "to the index queries, keys and weights the Pallas kernels "
-            "dsa_index_scores and dsa_index_grads make over the layers with "
-            "an indexer (the rest: blocked jax.numpy einsums, the index "
-            "heads' scores through HBM)",
-            ("unit",)).labels(unit=self.name).set(shares["index"])
+    def _publish_choices(self, choices: dict) -> None:
+        """The constants of the step as it is built (``step_choices``):
+        each the unit's mirror under its key and, unless None (a stack
+        without what it describes), the process registry."""
+        for key, gauge, label in _choice_gauges():
+            value = choices[key]
+            setattr(self, key, value)
+            if value is None:
+                continue
+            if label is None:
+                gauge.labels(unit=self.name).set(value)
+                continue
+            for name, one in value.items():
+                gauge.labels(**{"unit": self.name, label: name}).set(one)
 
     def _publish_attn_layout(self, share: float) -> None:
         """Of the attention layers that ran a flash kernel, the share whose
@@ -692,9 +663,10 @@ class TransformerLMStep(AcceleratedUnit):
             # every leaf's shape follows from the architecture
             import jax
 
-            from znicz_tpu.parallel import transformer as tfm
+            from znicz_tpu.parallel.arch import arch_from_config
+            from znicz_tpu.parallel.params import param_shapes
 
-            want = tfm.param_shapes(tfm.arch_from_config(
+            want = param_shapes(arch_from_config(
                 self.arch_config, restored_vocab))
             if jax.tree.map(lambda a: tuple(np.shape(a)), params) != want:
                 raise ValueError(

@@ -99,7 +99,7 @@ def _lm_arch(params, heads: int, prefix: str = ""):
     pytree — shared by the target and draft halves of a package."""
     vocab, d = (int(s) for s in np.shape(params["emb"]))
     blocks = params["blocks"]
-    from znicz_tpu.parallel.transformer import mechanisms_of_params
+    from znicz_tpu.parallel.arch import mechanisms_of_params
 
     extra = mechanisms_of_params(params)
     if extra:
@@ -126,7 +126,7 @@ def _lm_arch(params, heads: int, prefix: str = ""):
 def export_lm(params, path: str, *, heads: int, charmap=None,
               name: str = "lm", draft_params=None,
               draft_heads: int | None = None) -> str:
-    """Package a ``parallel/transformer.py`` param pytree as a
+    """Package a ``parallel/params.py`` param pytree as a
     generative serving artifact (.npz): flat weight arrays plus an
     ``__lm__`` meta block carrying the architecture (layers/d/heads/ff/
     vocab — everything :class:`~znicz_tpu.serve.kvcache.KVDecoder`
