@@ -1,8 +1,9 @@
 """The checkpoint plan (``parallel/plan.py::checkpoint_plan``) held
-to the compiler: the two benchmark cells whose layers are checkpointed by
+to the compiler: the three benchmark cells whose layers are checkpointed by
 ``_loop_saves`` (``granite4h_micro_train_pp4_t8192``, whose state-space
-stack keeps what a v5e has room for, and ``ouro_train_pp8_t4096``, whose
-looped stack keeps its own list) compiled at their real widths for a TPU
+stack keeps what a v5e has room for, ``ouro_train_pp8_t4096``, whose
+looped stack keeps its own list, and ``nemotron3_nano_train_ep8_t8192``,
+a stack of one-sub-layer layers with routed experts among them) compiled at their real widths for a TPU
 v5e that is described and not attached, as ``benchmark/tests/
 test_granite4h_rehearsal.py`` and ``test_ouro_rehearsal.py`` do, with the
 plan a v5e's memory limit gives.
@@ -44,6 +45,12 @@ def _compiled(config: str, traffic: str) -> dict:
       "ssm_conv_sum": 0}, (38.5, 40.0)),
     ("ouro_2_6b", "train_tokens_pp8_t4096", 509_661_185,
      {"glu_wide": 0}, (17.5, 18.7)),
+    # a stack of one-sub-layer layers (M, E, *): the shared experts' wide
+    # products count with the SwiGLUs', an E layer keeps its up-projections
+    # and holds its experts' cast and gradients while its backward runs
+    ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 986_254_848,
+     {"glu_wide": 4 * 16384 * 3712 * 2, "ssm_in": 4 * 16384 * 10304 * 2,
+      "ssm_conv_sum": 0}, (26.0, 28.2)),
 ])
 def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
         config, traffic, params, kept, tflop):

@@ -199,7 +199,8 @@ def test_grow_preserves_generation(params, decoder_cache):
 
 def test_decoder_refuses_moe_and_bad_input(params):
     moe = {"emb": params["emb"], "head": params["head"],
-           "blocks": [{**params["blocks"][0], "ew1": np.zeros((2, D, FF))}]}
+           "blocks": [{**params["blocks"][0], "ew1": np.zeros((2, D, FF)),
+                       "eb1": np.zeros((2, FF))}]}   # dense-masked: biased
     with pytest.raises(NotImplementedError, match="MoE"):
         KVDecoder(moe, heads=HEADS, max_len=8)
     dec = KVDecoder(params, heads=HEADS, max_len=8, batch=1)

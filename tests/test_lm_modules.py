@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from test_lfm2_arch import _pallas_interpret
-from znicz_tpu.ops.pallas import attention as pattn, dsa as pdsa
+from znicz_tpu.ops.pallas import (attention as pattn, dsa as pdsa,
+                                  grouped as pgrouped)
 from znicz_tpu.parallel import plan, transformer as tfm
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel.params import param_shapes
@@ -36,9 +37,10 @@ MAY_IMPORT = {
                "znicz_tpu.parallel.tp", "znicz_tpu.parallel.ring_attention",
                "znicz_tpu.observe.probe", "znicz_tpu.ops.pallas"),
     "head": ("znicz_tpu.parallel.arch",),
-    # ``head`` for the one reading of ``loss_chunks`` (``_n_chunks``)
+    # ``head`` for the one reading of ``loss_chunks`` (``_n_chunks``),
+    # ``moe`` for the rows of a routed layer's compact pairs buffer
     "plan": ("znicz_tpu.parallel.arch", "znicz_tpu.parallel.params",
-             "znicz_tpu.parallel.head"),
+             "znicz_tpu.parallel.head", "znicz_tpu.parallel.moe"),
     "transformer": (
         "znicz_tpu.parallel.arch", "znicz_tpu.parallel.params",
         "znicz_tpu.parallel.blocks", "znicz_tpu.parallel.head",
@@ -129,13 +131,20 @@ def _tiny(family: str, wide: bool):
         "lfm2_moe": "test_lfm2_arch", "glm4_moe_lite":
         "test_glm4_moe_lite_arch", "ouro": "test_ouro_arch",
         "KeyeVL2": "test_keye_vl2_arch",
-        "granitemoehybrid": "test_granitemoehybrid_arch"}[family])
+        "granitemoehybrid": "test_granitemoehybrid_arch",
+        "nemotron_h": "test_nemotron_h_arch"}[family])
     over = {}
     if wide and family == "KeyeVL2":
         over = {"hidden_size": 64, "head_dim": 128, "num_attention_heads": 2,
                 "num_key_value_heads": 1, "num_hidden_layers": 1,
                 "rope_scaling": {"mrope_section": [16, 24, 24],
                                  "rope_type": "default", "type": "default"}}
+    elif wide and family == "nemotron_h":
+        # experts 192 wide: over 128 lanes and not a multiple of them
+        over = {"hidden_size": 128, "num_attention_heads": 2,
+                "num_key_value_heads": 1, "head_dim": 128,
+                "moe_intermediate_size": 192,
+                "moe_shared_expert_intermediate_size": 256}
     elif wide and family in ("ouro", "lfm2_moe"):
         over = {"hidden_size": 256, "num_attention_heads": 2,
                 "num_key_value_heads": 2, "head_dim": 128}
@@ -215,6 +224,13 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
             assert (chose[key] is None) == (not arch.index_top_k)
             for kernel in kernels:
                 assert (kernel in text) == (chose[key] == 1.0), (key, kernel)
+        # the grouped products' form
+        assert (chose["moe_gmm_kernel_share"] is None) == \
+            (not arch.routed_layers())
+        for kernel in (pgrouped.ROWS_KERNEL_NAME,
+                       pgrouped.ROWS_T_KERNEL_NAME,
+                       pgrouped.WEIGHTS_KERNEL_NAME):
+            assert (kernel in text) == (chose["moe_gmm_kernel_share"] == 1.0)
         # what a checkpointed layer keeps
         kept = chose["checkpoint_kept_bytes"]
         if arch.loop_steps > 1 or "mamba" in arch.mixers:
@@ -236,4 +252,6 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
                  "dsa_align_kernel_share"}
     if family == "granitemoehybrid":
         want |= {"checkpoint_kept_bytes"}
+    if family == "nemotron_h":
+        want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share"}
     assert want <= seen, (want, seen)

@@ -1338,6 +1338,75 @@ def test_grouped_product_kernels_match_ragged_dot(kernel, case):
         assert not np.asarray(got)[int(sizes.sum()):].any()   # the tail
 
 
+#: widths 128 lanes do not divide (over one lane tile, multiples of 8): the
+#: block takes the width whole.  (k, n) as the weights lie: the last axis
+#: unaligned (the pipeline brings the weights), the middle one (the kernel
+#: fetches them itself), both
+UNALIGNED_WIDTHS = {"n_192": (128, 192), "k_192": (192, 128),
+                    "both_136_200": (136, 200)}
+
+
+@pytest.mark.parametrize("widths", list(UNALIGNED_WIDTHS))
+@pytest.mark.parametrize("kernel", ["rows", "rows_t", "weights"])
+def test_grouped_product_kernels_take_a_width_128_does_not_divide(kernel,
+                                                                  widths):
+    """The three kernels at a width taken whole (Nemotron 3's experts are
+    1,856 = 14.5 x 128 wide), against ``lax.ragged_dot`` in float32, the
+    tail NaN going in and zeros coming out; no operand is padded."""
+    import jax
+    from jax import lax
+    from znicz_tpu.ops.pallas import grouped
+    from znicz_tpu.parallel.moe import _TO_WEIGHTS
+
+    k, n = UNALIGNED_WIDTHS[widths]
+    assert grouped.unsupported_reason(1280, k, n, 8, jnp.float32) is None
+    sizes, live, a, g, w = _grouped_operands("one_of_3.9_means", k, n)
+    a0, g0 = jnp.where(live, a, 0), jnp.where(live, g, 0)
+    with jax.default_matmul_precision("highest"):
+        if kernel == "rows":
+            got = grouped.gmm_rows(a, w, sizes, interpret=True)
+            want = jnp.where(live, lax.ragged_dot(a0, w, sizes), 0)
+        elif kernel == "rows_t":
+            got = grouped.gmm_rows_t(g, w, sizes, interpret=True)
+            want = jnp.where(live, jax.linear_transpose(
+                lambda x: lax.ragged_dot(x, w, sizes), a0)(g0)[0], 0)
+        else:
+            got = grouped.gmm_weights(a, g, sizes, interpret=True)
+            want = lax.ragged_dot_general(a0, g0, sizes, _TO_WEIGHTS)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=2e-4)
+    if kernel != "weights":
+        assert not np.asarray(got)[int(sizes.sum()):].any()   # the tail
+
+
+def test_grouped_weights_gradient_cuts_k_where_n_cannot_be_cut(monkeypatch):
+    """The weights' gradient takes ``k`` whole and slabs of ``n``; where 128
+    does not divide ``n`` and the whole block is over the budget it takes
+    ``n`` whole and slabs of ``k`` (the Nemotron cell's ``(2,688, 1,856)``
+    float32 block is 19 MiB against 12), to the same values."""
+    import jax
+    from jax import lax
+    from znicz_tpu.ops.pallas import grouped
+    from znicz_tpu.parallel.moe import _TO_WEIGHTS
+
+    assert grouped._weights_slabs(2688, 1856) == (896, 1856)
+    assert grouped._weights_slabs(1856, 2688) == (1856, 896)
+    assert grouped._weights_slabs(2048, 1536) == (2048, 1536)
+    assert grouped.unsupported_reason(18432, 2688, 1856, 16,
+                                      jnp.bfloat16) is None
+    sizes, live, a, g, w = _grouped_operands("one_of_3.9_means", 256, 192)
+    a, g = (jnp.where(live, v, 0).astype(jnp.bfloat16) for v in (a, g))
+    monkeypatch.setattr(grouped, "_BLOCK_BYTES", 256 * 128 * 4)
+    assert grouped._weights_slabs(256, 192) == (128, 192)
+    got = grouped.gmm_weights(a, g, sizes, interpret=True)
+    want = lax.ragged_dot_general(a, g, sizes, _TO_WEIGHTS,
+                                  preferred_element_type=jnp.float32)
+    # the same exact products of bfloat16 operands, summed in another order
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-3)
+
+
 @pytest.mark.parametrize("slabs", [1, 2], ids=["whole_block", "two_slabs"])
 def test_grouped_product_is_differentiable_and_splits_wide_weights(
         slabs, monkeypatch):
@@ -1393,6 +1462,8 @@ def test_grouped_tile_fill_counts_a_shared_tile_twice(sizes, tile, fill):
     ((512, 100, 256, 4), jnp.float32, "128 lanes"),
     ((512, 128, 256, 4), jnp.float16, "bfloat16 or float32"),
     ((512, 128, 32768, 4), jnp.bfloat16, "MiB"),
+    ((512, 260, 128, 4), jnp.float32, "multiples of 8"),
+    ((512, 20000, 200, 4), jnp.bfloat16, "taken whole"),
     ((512, 128, 256, 0), jnp.bfloat16, "no group")])
 def test_grouped_unsupported_reason_names_the_refused_shape(shape, dtype,
                                                             word):

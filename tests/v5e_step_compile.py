@@ -44,6 +44,9 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     # the step asks jax.default_backend(), which is the CPU here, and the
     # described chip reports no memory: a v5e's own answers
     tfm._flash_eligible = lambda mesh, interp: True
+    # ... and the grouped products' kernels wherever their shapes take them
+    from znicz_tpu.parallel import moe
+    moe._kernels_eligible = lambda interpret: True
     limit = int(limit_gib * 2 ** 30)
     tfm._memory_limit = lambda mesh: limit
     opts = cfg["builders"]["lm_train_keys"]

@@ -139,14 +139,50 @@ def tile_fill(sizes, tile: int):
     return jnp.where(slots > 0, ends[-1] / jnp.maximum(slots, 1.0), 1.0)
 
 
-def _rows_kernel(off_ref, grp_ref, til_ref, dst_ref, n_ref, a_ref, w_hbm,
-                 o_ref, w_ref, sem, *, tile: int, contract_last: bool):
+def _rows_kernel(off_ref, grp_ref, til_ref, dst_ref, n_ref, a_ref, w_any,
+                 o_ref, *fetched, tile: int, contract_last: bool):
     j, v = pl.program_id(0), pl.program_id(1)
     at, g = dst_ref[v], grp_ref[v]
-    held, slab = w_hbm.shape[0], o_ref.shape[1]
+    if fetched:
+        _fetch_groups(w_any, *fetched, j, v, g, grp_ref, o_ref.shape[1],
+                      contract_last)
+
+    def weights():
+        # the slot this kernel fetched the group's slab into, or the block
+        # the pipeline brought
+        return fetched[0][g % 2] if fetched else w_any[...]
+
+    # the first step on this output tile: what the block holds is
+    # whatever the memory held, so nothing of it is kept
+    fresh = (v == 0) | (at != dst_ref[jnp.maximum(v - 1, 0)])
+    active = v < n_ref[0]
+
+    @pl.when(active)
+    def _visit():
+        dims = (((1,), (1 if contract_last else 0,)), ((), ()))
+        acc = jax.lax.dot_general(a_ref[...], weights(), dims,
+                                  preferred_element_type=jnp.float32)
+        row = at * tile + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
+        # rows of other groups in this tile: kept as earlier visits wrote
+        # them, zeros on a fresh tile (later visits write theirs over)
+        kept = row < jnp.where(fresh, 0, (at + 1) * tile)
+        prev = jnp.where(kept, o_ref[...].astype(jnp.float32), 0.0)
+        o_ref[...] = jnp.where(mine, acc, prev).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(active) & fresh)
+    def _tail():
+        # every live tile has had its visit: a fresh one is past them
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _fetch_groups(w_hbm, w_ref, sem, j, v, g, grp_ref, slab: int,
+                  contract_last: bool):
+    """The row kernels' own fetch of a group's slab of weights from HBM
+    into its slot of the two in ``w_ref``, a whole group ahead of its use."""
+    held = w_hbm.shape[0]
 
     def fetch(group):
-        """Group ``group``'s slab of weights into its slot of the two."""
         src = w_hbm.at[group, pl.ds(j * slab, slab), :] if contract_last \
             else w_hbm.at[group, :, pl.ds(j * slab, slab)]
         return pltpu.make_async_copy(src, w_ref.at[group % 2],
@@ -167,29 +203,6 @@ def _rows_kernel(off_ref, grp_ref, til_ref, dst_ref, n_ref, a_ref, w_hbm,
         @pl.when(g + 1 < held)
         def _ahead():
             fetch(g + 1).start()
-
-    # the first step on this output tile: what the block holds is
-    # whatever the memory held, so nothing of it is kept
-    fresh = (v == 0) | (at != dst_ref[jnp.maximum(v - 1, 0)])
-    active = v < n_ref[0]
-
-    @pl.when(active)
-    def _visit():
-        dims = (((1,), (1 if contract_last else 0,)), ((), ()))
-        acc = jax.lax.dot_general(a_ref[...], w_ref[g % 2], dims,
-                                  preferred_element_type=jnp.float32)
-        row = at * tile + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
-        mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
-        # rows of other groups in this tile: kept as earlier visits wrote
-        # them, zeros on a fresh tile (later visits write theirs over)
-        kept = row < jnp.where(fresh, 0, (at + 1) * tile)
-        prev = jnp.where(kept, o_ref[...].astype(jnp.float32), 0.0)
-        o_ref[...] = jnp.where(mine, acc, prev).astype(o_ref.dtype)
-
-    @pl.when(jnp.logical_not(active) & fresh)
-    def _tail():
-        # every live tile has had its visit: a fresh one is past them
-        o_ref[...] = jnp.zeros_like(o_ref)
 
 
 def _weights_kernel(off_ref, grp_ref, til_ref, dst_ref, n_ref, a_ref, g_ref,
@@ -229,14 +242,28 @@ def _weights_kernel(off_ref, grp_ref, til_ref, dst_ref, n_ref, a_ref, g_ref,
 
 
 def _slab(other: int, dim: int, itemsize: int) -> int:
-    """The widest slab of ``dim`` (all of it, or a divisor that is a
+    """The widest slab of ``dim`` (all of it, whatever its width: a block as
+    wide as the array needs no lane alignment; or a divisor that is a
     multiple of 128) whose ``other x slab`` block stays within
-    ``_BLOCK_BYTES``; 0 when not even 128 columns do."""
-    for parts in range(1, dim // 128 + 1):
-        if dim % parts == 0 and (dim // parts) % 128 == 0 and \
-                other * (dim // parts) * itemsize <= _BLOCK_BYTES:
+    ``_BLOCK_BYTES``; 0 when none does (not even 128 columns, or a width
+    128 does not divide that is too wide to take whole)."""
+    for parts in range(1, max(dim // 128, 1) + 1):
+        if dim % parts == 0 and (parts == 1 or (dim // parts) % 128 == 0) \
+                and other * (dim // parts) * itemsize <= _BLOCK_BYTES:
             return dim // parts
     return 0
+
+
+def _weights_slabs(k: int, n: int) -> tuple:
+    """``(rows, columns)`` of a block of the weights' float32 gradient:
+    ``k`` whole, so that the cotangent is read once, and as wide a slab of
+    ``n``; where ``n`` cannot be cut (128 does not divide it) and is too
+    wide whole, ``n`` whole and a slab of ``k``.  ``(0, 0)``: neither."""
+    slab = _slab(k, n, 4)
+    if slab:
+        return k, slab
+    slab = _slab(n, k, 4)
+    return (slab, n) if slab else (0, 0)
 
 
 def _params(*semantics):
@@ -250,6 +277,17 @@ def _rows_call(a, w, sizes, contract_last: bool, interpret: bool):
     held = w.shape[0]
     out = w.shape[1] if contract_last else w.shape[2]
     slab = _slab(c, out, w.dtype.itemsize)
+    block = (slab, c) if contract_last else (c, slab)
+    # the kernel fetches the weights itself (they stay in HBM) wherever it
+    # can cut its slab from them: Mosaic slices a memory reference on its
+    # last axis by multiples of 128 lanes only, even for the whole of it
+    # ("Slice shape along dimension 2 must be aligned to tiling (128), but
+    # is 1856"); a last axis 128 does not divide comes by the pipeline, a
+    # whole group's block at a time
+    own_fetch = w.shape[2] % 128 == 0
+    w_spec = pl.BlockSpec(memory_space=pl.ANY) if own_fetch else \
+        pl.BlockSpec((None, *block), lambda j, v, off, grp, *_:
+                     (grp[v], j, 0) if contract_last else (grp[v], 0, j))
     return pl.pallas_call(
         partial(_rows_kernel, tile=tile, contract_last=contract_last),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -260,15 +298,13 @@ def _rows_call(a, w, sizes, contract_last: bool, interpret: bool):
             in_specs=[
                 pl.BlockSpec((tile, c),
                              lambda j, v, off, grp, til, *_: (til[v], 0)),
-                # the weights stay in HBM: the kernel fetches them itself
-                pl.BlockSpec(memory_space=pl.ANY)],
+                w_spec],
             out_specs=pl.BlockSpec(
                 (tile, slab),
                 lambda j, v, off, grp, til, out_tile, n: (out_tile[v], j)),
             scratch_shapes=[
-                pltpu.VMEM((2, slab, c) if contract_last else (2, c, slab),
-                           w.dtype),
-                pltpu.SemaphoreType.DMA((2,))]),
+                pltpu.VMEM((2, *block), w.dtype),
+                pltpu.SemaphoreType.DMA((2,))] if own_fetch else []),
         out_shape=_out_struct((rows, out), a.dtype, a),
         compiler_params=_params("parallel", "arbitrary"),
         name=ROWS_T_KERNEL_NAME if contract_last else ROWS_KERNEL_NAME,
@@ -312,22 +348,23 @@ def gmm_weights(a, g, sizes, *, interpret: bool = False):
     n, held, tile = g.shape[1], sizes.shape[0], ROW_TILE
     _check(rows, k, n, held, a.dtype,
            g.shape[0] == rows and g.dtype == a.dtype)
-    # ``k`` whole, so that ``g`` is read once, and as wide a slab of ``n``
-    slab = _slab(k, n, 4)
+    bk, bn = _weights_slabs(k, n)
+    by_k = bk < k                  # the slabs cut ``k``; else they cut ``n``
     return pl.pallas_call(
         partial(_weights_kernel, tile=tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             # the visits innermost: a group's block of the result stays
             # put, and sums, over its consecutive visits
-            grid=(n // slab, rows // tile + held - 1),
+            grid=(k // bk if by_k else n // bn, rows // tile + held - 1),
             in_specs=[
-                pl.BlockSpec((tile, k),
-                             lambda j, v, off, grp, til, *_: (til[v], 0)),
-                pl.BlockSpec((tile, slab),
-                             lambda j, v, off, grp, til, *_: (til[v], j))],
+                pl.BlockSpec((tile, bk), lambda j, v, off, grp, til, *_:
+                             (til[v], j if by_k else 0)),
+                pl.BlockSpec((tile, bn), lambda j, v, off, grp, til, *_:
+                             (til[v], 0 if by_k else j))],
             out_specs=pl.BlockSpec(
-                (None, k, slab), lambda j, v, off, grp, *_: (grp[v], 0, j))),
+                (None, bk, bn), lambda j, v, off, grp, *_:
+                (grp[v], j, 0) if by_k else (grp[v], 0, j))),
         out_shape=_out_struct((held, k, n), jnp.float32, a),
         compiler_params=_params("parallel", "arbitrary"),
         name=WEIGHTS_KERNEL_NAME,
@@ -356,10 +393,17 @@ def unsupported_reason(rows: int, k: int, n: int, held: int,
         return "no group"
     if rows < ROW_TILE or rows % ROW_TILE:
         return f"rows={rows} is not a multiple of the {ROW_TILE}-row tile"
-    if k % 128 or n % 128:
-        return f"k={k}, n={n}: both must be multiples of 128 lanes"
+    if any(width % 128 and (width < 128 or width % 8) for width in (k, n)):
+        return (f"k={k}, n={n}: both must be multiples of 128 lanes, or over "
+                f"128, multiples of 8 and taken whole (the last lane tile "
+                f"masked)")
     if max(k, n) * 128 * 4 > _BLOCK_BYTES:
         return (f"a float32 block of {max(k, n)} x 128 is over the "
+                f"{_BLOCK_BYTES >> 20} MiB a block may take of VMEM")
+    if not (_slab(k, n, dtype.itemsize) and _slab(n, k, dtype.itemsize) and
+            _weights_slabs(k, n)[0]):
+        return (f"k={k}, n={n}: a width 128 lanes do not divide is taken "
+                f"whole, and no such block of the weights stays within the "
                 f"{_BLOCK_BYTES >> 20} MiB a block may take of VMEM")
     return None
 

@@ -15,8 +15,12 @@ import numpy as np
 
 import jax
 
-#: a state-space layer's name in a refusal (:meth:`Arch.mechanisms`)
+#: names in a refusal (:meth:`Arch.mechanisms`, :func:`mechanisms_of_params`)
 _SSM_MECHANISM = "state-space layer (Mamba-2)"
+_GROUPS_MECHANISM = "state-space groups (B and C a group of heads)"
+_ONE_SUB_LAYER_MECHANISM = "layers of one sub-layer"
+_ROUTED_MECHANISM = "routed experts (moe_routed_ffn)"
+_RELU2_MECHANISM = "squared-ReLU experts (two weights, no gate)"
 
 
 def _default_compute_dtype(compute_dtype=None):
@@ -48,8 +52,14 @@ class Arch:
     computes every token), ``"glu"`` (bias-free SwiGLU) or
     ``"moe_routed"`` (:func:`moe.moe_routed_ffn`: this chip's
     ``experts_held`` of ``n_experts`` from ``experts_first``, token
-    dispatch, no drop; with ``shared_ff`` a SwiGLU of that width that
-    every token passes, beside it).  ``norm`` is ``"layer"`` (gain and
+    dispatch, no drop; with ``shared_ff`` a shared expert of that width
+    that every token passes, beside it; ``expert_form`` says what an
+    expert, routed or shared, is: ``"glu"``, the gated unit ``w2 (silu(x
+    w1) * (x w3))``, or ``"relu2"``, the plain ``w2 relu(x w1)^2`` of two
+    weights).  One of the two may be ``"none"``: the layer is then ONE
+    sub-layer behind ONE norm (a ``"none"`` mixer leaves the ``ln2``
+    norm and the feed-forward part, a ``"none"`` feed-forward the ``ln1``
+    norm and the mixer).  ``norm`` is ``"layer"`` (gain and
     bias) or ``"rms"`` (gain); ``kv_heads < heads`` is grouped-query
     attention; ``qk_norm`` puts an RMSNorm with its own gain on each head
     of q and k; ``rope_theta`` rotates them (rotate-half, over the whole
@@ -81,9 +91,11 @@ class Arch:
     layers and trains the indexer alone: ``parallel/dsa.py``).
     A ``"mamba"`` mixer is a state-space layer (Mamba-2, ``parallel/
     ssm.py``): ``ssm_heads`` heads of ``ssm_head_dim`` with a state of
-    ``ssm_state`` entries a head entry, one group, a depthwise convolution
-    of ``conv_taps`` taps with a bias, scanned in chunks of ``ssm_chunk``
-    positions (a tile: it changes no value).  Four static multipliers (muP's,
+    ``ssm_state`` entries a head entry, ``ssm_groups`` groups of heads that
+    share their ``B`` and ``C`` (and the gated norm's statistic), a
+    depthwise convolution of ``conv_taps`` taps with a bias, scanned in
+    chunks of ``ssm_chunk`` positions (a tile: it changes no value).  Four
+    static multipliers (muP's,
     as the Granite families write them; each emits nothing at its default):
     ``embed_mult`` on the embeddings entering layer 0, ``residual_mult`` on
     every sub-layer's output before the residual sum, ``attn_mult`` the
@@ -137,6 +149,8 @@ class Arch:
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_chunk: int = 256
+    ssm_groups: int = 1
+    expert_form: str = "glu"
     embed_mult: float = 1.0
     residual_mult: float = 1.0
     attn_mult: float | None = None
@@ -158,6 +172,22 @@ class Arch:
                 self.ssm_chunk > 0):
             raise ValueError("a mamba mixer needs ssm_heads, ssm_head_dim, "
                              "ssm_state, conv_taps and ssm_chunk")
+        if self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups:
+            raise ValueError(f"ssm_groups {self.ssm_groups} must divide "
+                             f"ssm_heads {self.ssm_heads}")
+        if self.expert_form not in ("glu", "relu2"):
+            raise ValueError(f"expert_form {self.expert_form!r}: glu (the "
+                             f"gated unit) or relu2 (plain squared ReLU)")
+        if any(pair == ("none", "none")
+               for pair in zip(self.mixers, self.ffns)):
+            raise ValueError("a layer of no sub-layer: one of its mixer and "
+                             "its feed-forward part may be none, not both")
+        if "none" in self.mixers + self.ffns and (
+                self.sandwich or self.mtp or self.loop_steps > 1 or
+                self.norm != "rms"):
+            raise ValueError("a layer of one sub-layer is written for an "
+                             "unlooped RMSNorm stack with no sandwich norm "
+                             "and no MTP module")
         if self.sandwich and not (set(self.mixers) <= {"attention", "latent"}
                                   and set(self.ffns) <= {"glu"}):
             raise ValueError("sandwich: the second norm is written for "
@@ -211,6 +241,10 @@ class Arch:
             out.append("gated short convolution")
         if "mamba" in self.mixers:
             out.append(_SSM_MECHANISM)
+        if "mamba" in self.mixers and self.ssm_groups > 1:
+            out.append(_GROUPS_MECHANISM)
+        if "none" in self.mixers + self.ffns:
+            out.append(_ONE_SUB_LAYER_MECHANISM)
         if "latent" in self.mixers:
             out.append("latent attention")
         if self.kv_heads != self.heads:
@@ -227,7 +261,9 @@ class Arch:
         if "glu" in self.ffns:
             out.append("SwiGLU")
         if "moe_routed" in self.ffns:
-            out.append("routed experts (moe_routed_ffn)")
+            out.append(_ROUTED_MECHANISM)
+        if "moe_routed" in self.ffns and self.expert_form == "relu2":
+            out.append(_RELU2_MECHANISM)
         if self.shared_ff:
             out.append("shared expert")
         if self.mtp:
@@ -499,19 +535,23 @@ def _granitemoehybrid_arch(cfg, vocab: int | None) -> Arch:
     ``shared_intermediate_size`` in every layer (the family's one fused
     ``input_linear`` is ``w1`` and ``w3`` side by side), a final norm, the
     head tied or not.  Refused by name: experts (``num_local_experts`` > 0:
-    the family's routed part beside the shared SwiGLU is not written),
-    ``mamba_n_groups`` other than 1, a ``normalization_function`` other
-    than ``rmsnorm``, a ``position_embedding_type`` other than ``nope``, an
+    the family's routed part beside the shared SwiGLU in ONE layer is not
+    written), ``mamba_n_groups`` other than 1 (the scan takes groups; this
+    family's reference walks one), a ``normalization_function`` other than
+    ``rmsnorm``, a ``position_embedding_type`` other than ``nope``, an
     attention or projection bias, a convolution without its bias, an inner
     width that is not ``mamba_n_heads x mamba_d_head``, an activation other
     than silu.  ``intermediate_size`` (the experts') is not read."""
     if int(cfg.get("num_local_experts") or 0) > 0:
         raise ValueError(f"num_local_experts {cfg['num_local_experts']}: "
-                         f"routed experts beside the shared SwiGLU are not "
-                         f"written for this family (0 is)")
+                         f"routed experts beside the shared SwiGLU in one "
+                         f"layer are not written for this family (0 is)")
     if int(cfg.get("mamba_n_groups", 1)) != 1:
-        raise ValueError(f"mamba_n_groups {cfg['mamba_n_groups']}: one group "
-                         f"(B and C serve all heads) is what is written")
+        raise ValueError(f"mamba_n_groups {cfg['mamba_n_groups']}: this "
+                         f"family is read with one group (the scan takes "
+                         f"groups, Arch.ssm_groups, and the nemotron_h "
+                         f"reader hands them over; no reference of this "
+                         f"family walks them)")
     if cfg.get("normalization_function", "rmsnorm") != "rmsnorm":
         raise ValueError(f"normalization_function "
                          f"{cfg['normalization_function']!r}: rmsnorm")
@@ -560,10 +600,102 @@ def _granitemoehybrid_arch(cfg, vocab: int | None) -> Arch:
         logits_div=float(cfg.get("logits_scaling", 1.0)))
 
 
+#: a character of ``hybrid_override_pattern`` -> that layer's (mixer, ffn)
+_NEMOTRON_LAYERS = {"M": ("mamba", "none"), "*": ("attention", "none"),
+                    "E": ("none", "moe_routed")}
+
+
+def _nemotron_h_arch(cfg, vocab: int | None) -> Arch:
+    """``nemotron_h`` (Nemotron-H / Nemotron 3: ``hybrid_override_pattern``, a
+    character a layer, ``mamba_num_heads``, ``mamba_head_dim``,
+    ``ssm_state_size``, ``n_groups``, ``conv_kernel``, ``chunk_size``,
+    ``n_routed_experts``, ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``moe_shared_expert_intermediate_size``, ``n_shared_experts``, ...): an
+    RMSNorm stack whose layer is ONE sub-layer behind one norm, ``x <- x +
+    f(RMSNorm(x))``: ``M`` a Mamba-2 state-space layer (``parallel/ssm.py``,
+    ``n_groups`` groups, a biased convolution, an inner width of
+    ``mamba_num_heads x mamba_head_dim`` whatever ``expand`` says), ``*``
+    grouped-query attention with NO positional encoding (the family's
+    modelling code applies none: ``rope_theta`` and
+    ``partial_rotary_factor`` are not read), ``E`` sigmoid-routed experts
+    selected by score plus ``e_score_correction_bias`` (one group) beside a
+    shared expert of ``n_shared_experts x
+    moe_shared_expert_intermediate_size``, every expert the plain ``W_down
+    relu(W_up x)^2``; a final norm, the head tied or not.
+    ``n_routed_experts`` is the experts held here where ``router_width``
+    gives the router's published width.  Refused by name: ``-`` (dense MLP)
+    layers and any other character, a pattern whose length is not
+    ``num_hidden_layers``, ``n_group`` / ``topk_group`` other than 1, any of
+    the four biases (``attention_bias``, ``mlp_bias``, ``mamba_proj_bias``,
+    ``use_bias``), a convolution without its bias, an ``mlp_hidden_act``
+    other than ``relu2``, a ``mamba_hidden_act`` other than ``silu``, a
+    sliding window.  ``time_step_*`` and ``rescale_prenorm_residual`` are
+    an initialiser's keys, ``intermediate_size`` the ``-`` layers' width."""
+    pattern = str(cfg["hybrid_override_pattern"])
+    if int(cfg.get("num_hidden_layers", len(pattern))) != len(pattern):
+        raise ValueError(f"num_hidden_layers {cfg['num_hidden_layers']} "
+                         f"against a hybrid_override_pattern of "
+                         f"{len(pattern)} characters")
+    unknown = sorted(set(pattern) - set(_NEMOTRON_LAYERS))
+    if unknown:
+        raise ValueError(
+            f"hybrid_override_pattern characters {unknown}: M (Mamba-2), E "
+            f"(experts) and * (attention) are what is written"
+            f"{' (- is a dense MLP layer)' if '-' in unknown else ''}")
+    if int(cfg.get("n_group", 1)) != 1 or int(cfg.get("topk_group", 1)) != 1:
+        raise ValueError("n_group / topk_group: selection over one group of "
+                         "experts is what is written")
+    biased = [k for k in ("attention_bias", "mlp_bias", "mamba_proj_bias",
+                          "use_bias") if cfg.get(k, False)]
+    if biased:
+        raise ValueError(f"{' / '.join(biased)}: the projections here have "
+                         f"no bias")
+    if not cfg.get("use_conv_bias", True):
+        raise ValueError("use_conv_bias false: the state-space layer's "
+                         "convolution here carries its bias")
+    if cfg.get("mlp_hidden_act", "relu2") != "relu2":
+        raise ValueError(f"mlp_hidden_act {cfg['mlp_hidden_act']!r}: relu2 "
+                         f"(the experts are W_down relu(W_up x)^2)")
+    if cfg.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(f"mamba_hidden_act {cfg['mamba_hidden_act']!r}: "
+                         f"silu")
+    if cfg.get("sliding_window"):
+        raise ValueError("sliding_window: attention here is causal over "
+                         "the whole sequence")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    routed = "E" in pattern
+    n_experts = int(cfg.get("router_width", cfg.get("n_routed_experts", 0)))
+    first, count = _experts_held(cfg, n_experts)
+    mixers, ffns = zip(*(_NEMOTRON_LAYERS[c] for c in pattern))
+    return Arch(
+        d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=int(cfg.get("head_dim") or d // heads),
+        ff=int(cfg.get("intermediate_size", 0)),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=mixers, ffns=ffns, norm="rms",
+        eps=float(cfg.get("layer_norm_epsilon", 1e-5)),
+        conv_taps=int(cfg.get("conv_kernel", 4)),
+        n_experts=n_experts if routed else 0,
+        experts_first=first, experts_held=count if routed else 0,
+        top_k=int(cfg.get("num_experts_per_tok", 1)),
+        moe_ff=int(cfg.get("moe_intermediate_size", 0)), score="sigmoid",
+        expert_bias=True, norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        final_norm=True, tied=bool(cfg.get("tie_word_embeddings", False)),
+        shared_ff=int(cfg.get("n_shared_experts", 0)) * int(
+            cfg.get("moe_shared_expert_intermediate_size", 0)) * routed,
+        ssm_heads=int(cfg["mamba_num_heads"]),
+        ssm_head_dim=int(cfg["mamba_head_dim"]),
+        ssm_state=int(cfg["ssm_state_size"]),
+        ssm_chunk=int(cfg.get("chunk_size", 256)),
+        ssm_groups=int(cfg.get("n_groups", 1)), expert_form="relu2")
+
+
 #: ``model_type`` -> the reader of that family's keys
 _FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch,
              "ouro": _ouro_arch, "KeyeVL2": _keye_vl2_arch,
-             "granitemoehybrid": _granitemoehybrid_arch}
+             "granitemoehybrid": _granitemoehybrid_arch,
+             "nemotron_h": _nemotron_h_arch}
 
 
 def arch_from_config(cfg, vocab: int | None = None) -> Arch:
@@ -571,7 +703,8 @@ def arch_from_config(cfg, vocab: int | None = None) -> Arch:
     (:data:`_FAMILIES`: :func:`_lfm2_moe_arch`, also what a mapping with
     ``layer_types`` and no ``model_type`` is read as,
     :func:`_glm4_moe_lite_arch`, :func:`_ouro_arch`,
-    :func:`_keye_vl2_arch` and :func:`_granitemoehybrid_arch`).
+    :func:`_keye_vl2_arch`, :func:`_granitemoehybrid_arch` and
+    :func:`_nemotron_h_arch`).
     ``experts_held`` (``{"first", "count"}``; all by default) is this chip's
     share of the experts;
     ``vocab`` (the loader's) overrides ``vocab_size``.  Any other
@@ -599,12 +732,39 @@ def as_arch(arch, d=None, heads=None, ff=None, vocab=None,
 #: a leaf only a layer kind beyond the GPT-shaped block has -> its name
 _LEAF_MECHANISMS = {
     "w_in": "gated short convolution", "q_g": "QK-norm",
-    "w3": "SwiGLU", "ew3": "routed experts (moe_routed_ffn)",
-    "wkv_a": "latent attention", "sw1": "shared expert",
+    "w3": "SwiGLU", "wkv_a": "latent attention", "sw1": "shared expert",
     "ln1o_g": "sandwich norm",
     "wiq": "learned sparse attention (indexer)",
     "ssm_a_log": _SSM_MECHANISM,
 }
+
+
+def routed_block(blk) -> bool:
+    """Whether a layer's leaves are a routed expert layer's: unbiased expert
+    stacks (the dense-masked experts carry ``eb1``), gated or plain."""
+    return "ew1" in blk and "eb1" not in blk
+
+
+def _block_mechanisms(blk) -> list:
+    """The names of what one layer's leaves show beyond the GPT-shaped
+    block: the leaves of :data:`_LEAF_MECHANISMS`, and what only the leaves
+    together say: routed experts (:func:`routed_block`), without ``ew3`` the
+    plain squared-ReLU form; one
+    norm's gain alone is a layer of one sub-layer; a gated norm's gain laid
+    out a group (``params._ssm_leaf_shapes``) is state-space groups; fewer
+    key/value than query columns grouped-query attention."""
+    out = [name for leaf, name in _LEAF_MECHANISMS.items() if leaf in blk]
+    if routed_block(blk):
+        out.append(_ROUTED_MECHANISM)
+        if "ew3" not in blk:
+            out.append(_RELU2_MECHANISM)
+    if ("ln1_g" in blk) != ("ln2_g" in blk):
+        out.append(_ONE_SUB_LAYER_MECHANISM)
+    if np.ndim(blk.get("ssm_g")) > 1:
+        out.append(_GROUPS_MECHANISM)
+    if "wk" in blk and np.shape(blk["wk"]) != np.shape(blk["wq"]):
+        out.append("grouped-query attention")
+    return out
 
 
 def mechanisms_of_params(params) -> list:
@@ -613,12 +773,7 @@ def mechanisms_of_params(params) -> list:
     and ``export_lm`` refuse with."""
     out = []
     for blk in params["blocks"]:
-        for leaf, name in _LEAF_MECHANISMS.items():
-            if leaf in blk and name not in out:
-                out.append(name)
-        if "wk" in blk and np.shape(blk["wk"]) != np.shape(blk["wq"]) and \
-                "grouped-query attention" not in out:
-            out.append("grouped-query attention")
+        out += [name for name in _block_mechanisms(blk) if name not in out]
     if "mtp" in params:
         out.append("multi-token prediction")
     if "exit_w" in params:             # only a looped stack carries a gate

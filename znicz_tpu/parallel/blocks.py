@@ -22,7 +22,7 @@ from znicz_tpu.observe import probe as _probe
 from znicz_tpu.parallel import dsa, ssm, tp
 from znicz_tpu.parallel.arch import Arch
 from znicz_tpu.parallel.moe import (load_balance_aux, moe_ffn,
-                                    moe_routed_ffn, router_z_loss)
+                                    moe_routed_ffn, relu2, router_z_loss)
 from znicz_tpu.parallel.ring_attention import (ring_attention,
                                                ring_flash_attention)
 
@@ -164,7 +164,9 @@ def _rotate(x, theta: float, interleaved: bool = False):
 def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     """Layer ``index`` of ``arch`` on local shards (``n_layers``: the MTP
     module's): its mixer, then its feed-forward, each reading a norm of
-    the residual stream and adding to it.  -> ``(x, aux, stats)``: the
+    the residual stream and adding to it; a layer of one sub-layer
+    (``"none"`` for the other) runs that one behind its one norm.  ->
+    ``(x, aux, stats)``: the
     regularizer term (pre-weighted) and the layer's counters (the routed
     layer's, and :func:`_block_attn`'s of a layer that ran a flash
     kernel or has an indexer, whose alignment term ``aux`` carries).
@@ -181,14 +183,16 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
             x = _block_sconv(x, p, arch)
     elif mixer == "mamba":
         x, stats = _block_ssm(x, p, arch, f"block{index}.ssm")
-    else:
+    elif mixer != "none":
         x, stats = _block_attn(x, p, arch, run, f"block{index}.attn")
     if ffn == "moe_routed":
         x, aux, routed = _block_routed(x, p, arch, f"block{index}.moe")
         stats = {**stats, **routed}
-    else:
+    elif ffn != "none":
         with _probe.scope(f"block{index}.mlp"):
             x, aux = _block_mlp(x, p, arch, ffn, run)
+    else:
+        aux = jnp.zeros((), jnp.float32)
     if "loss_index" in stats:
         # an indexer's alignment term joins the loss as a regularizer does
         aux = aux + stats["loss_index"]
@@ -201,7 +205,8 @@ def _block_ssm(x, p, arch: Arch, scope: str):
     with _probe.scope(scope):
         u = _norm(x, p, "ln1", arch)
     y, stats = ssm.mixer(u, p, arch.ssm_heads, arch.ssm_head_dim,
-                         arch.ssm_state, arch.ssm_chunk, arch.eps, scope)
+                         arch.ssm_state, arch.ssm_chunk, arch.eps, scope,
+                         arch.ssm_groups)
     with _probe.scope(scope):
         return x + _sub_out(y, p, "ln1o", arch), stats
 
@@ -504,21 +509,32 @@ def _block_mlp(x, p, arch: Arch, ffn: str, run: _Run):
     return x, jnp.zeros((), jnp.float32)
 
 
+def _relu2_mlp(m, w1, w2):
+    """The plain squared-ReLU unit ``relu(m w1)^2 w2``.  Its wide product
+    is named for the recomputation policy as :func:`_glu`'s two are
+    (``glu_wide``: a feed-forward unit's wide products)."""
+    return relu2(checkpoint_name(m @ w1, "glu_wide")) @ w2
+
+
 def _block_routed(x, p, arch: Arch, scope: str):
     """This chip's share of a routed expert layer
     (:func:`moe.moe_routed_ffn`); the norm and the residual sum lie
     under ``scope``, the layer's two parts under ``scope.route`` and
     ``scope.experts``, and the shared expert, which every chip computes
-    alike for every token, under ``scope.shared``."""
+    alike for every token, under ``scope.shared``.  The experts, routed
+    and shared, are of ``arch.expert_form``."""
+    gated = arch.expert_form == "glu"
     with _probe.scope(scope):
         m = _norm(x, p, "ln2", arch)
     if "sw1" in p:
         with _probe.scope(f"{scope}.shared"):
-            x = x + _glu(m, p["sw1"], p["sw3"], p["sw2"])
+            x = x + (_glu(m, p["sw1"], p["sw3"], p["sw2"]) if gated else
+                     _relu2_mlp(m, p["sw1"], p["sw2"]))
     y, stats = moe_routed_ffn(
         m.reshape(-1, m.shape[-1]), p["gate"], p.get("ebias"), p["ew1"],
-        p["ew3"], p["ew2"], first=arch.experts_first, top_k=arch.top_k,
+        p.get("ew3"), p["ew2"], first=arch.experts_first, top_k=arch.top_k,
         score=arch.score, norm_topk=arch.norm_topk,
-        scale=arch.routed_scale, scope=scope)
+        scale=arch.routed_scale, act=jax.nn.silu if gated else relu2,
+        scope=scope)
     with _probe.scope(scope):
         return x + y.reshape(m.shape), jnp.zeros((), jnp.float32), stats

@@ -16,9 +16,10 @@ Three regimes (docs/TUNING.md "MoE"):
   products over the held experts' groups and sums back into tokens,
   over a buffer sized from the pairs it has counted (the static worst
   case as the fallback).  No capacity and no drop; what absent experts
-  would add is left out (their chips add it).  The score function, the selection bias and the
-  expert's form are arguments.  Its all-to-all form over an expert axis
-  is what would retire the two above (ROADMAP.md).
+  would add is left out (their chips add it).  The score function, the
+  selection bias and the expert's form (a gated unit of three weights, or a
+  plain one of two) are arguments.  Its all-to-all form over an expert
+  axis is what would retire the two above (ROADMAP.md).
 
 :func:`load_balance_aux` is the shared switch load-balance regularizer.
 """
@@ -33,6 +34,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from znicz_tpu.observe import probe as _probe
 from znicz_tpu.ops.pallas import grouped as _gmm
@@ -253,7 +255,12 @@ _COMPACT_SLACK = 1.5
 #: a multiple of the Pallas kernels' (``ops/pallas/grouped.py::ROW_TILE``)
 _ROW_TILE = 512
 #: counters that are averaged, not summed, over layers and shards
-MEAN_STATS = ("load_max_over_mean", "compact", "tile_fill")
+MEAN_STATS = ("load_max_over_mean", "compact", "tile_fill", "act_zero")
+
+
+def relu2(h):
+    """Squared ReLU, the plain expert's activation (``w2 relu(x w1)^2``)."""
+    return jnp.square(jax.nn.relu(h))
 
 
 def compact_rows(n_pairs: int, held: int, n_experts: int) -> int:
@@ -273,6 +280,23 @@ def _report_kernel_refusal(shape: tuple, why: str) -> None:
                  "held=%d: %s; this layer uses lax.ragged_dot", *shape, why)
 
 
+def _kernels_eligible(interpret: bool) -> bool:
+    """Whether the Pallas kernels may run at all: on a TPU, or interpreted."""
+    return interpret or jax.default_backend() == "tpu"
+
+
+def gmm_kernel_refusal(rows: int, k: int, n: int, held: int, dtype,
+                       interpret: bool) -> str | None:
+    """Why a grouped product of ``rows`` rows with ``(held, k, n)`` weights
+    (``k`` and ``n`` in either order) runs on ``lax.ragged_dot`` and not on
+    the Pallas kernels (``ops/pallas/grouped.py``), or None: what
+    ``transformer.step_choices`` asks, by the deciders :func:`_gmm_kernels`
+    asks as the step is traced."""
+    if not _kernels_eligible(interpret):
+        return "no TPU, and engine.pallas_interpret is not set"
+    return _gmm.unsupported_reason(rows, k, n, held, dtype)
+
+
 def _gmm_kernels(rows: int, k: int, n: int, held: int, dtype):
     """THE choice between the two forms of a grouped product of ``rows``
     rows with ``(held, k, n)`` weights (``k`` and ``n`` in either
@@ -284,7 +308,7 @@ def _gmm_kernels(rows: int, k: int, n: int, held: int, dtype):
     ``lax.ragged_dot``, else the kernels' ``interpret`` argument."""
     from znicz_tpu.core.config import root
     interpret = bool(root.common.engine.get("pallas_interpret", False))
-    if not interpret and jax.default_backend() != "tpu":
+    if not _kernels_eligible(interpret):
         return None
     why = _gmm.unsupported_reason(rows, k, n, held, dtype)
     if why:
@@ -346,25 +370,50 @@ def _grouped_to_weights(a, g, sizes, dtype):
     return _gmm.gmm_weights(a, g, sizes, interpret=interpret).astype(dtype)
 
 
-def _pairs_full(x, weight, w1, w3, w2, order, slot_of, sizes, top_k: int,
+def _experts_hidden(hs: tuple, act):
+    """The experts' hidden rows from their up-projections' results: the
+    gated unit's ``act(h1) * h3``, or of the plain form ``act(h1)``."""
+    return act(hs[0]) * hs[1] if len(hs) == 2 else act(hs[0])
+
+
+def _zeroed(hs: tuple, live):
+    """Of the plain form's live hidden entries, how many its activation (a
+    squared ReLU) zeroes, float32; None of the gated unit (no leaf: its
+    program is the one it was)."""
+    if len(hs) == 2:
+        return None
+    return jnp.where(live, hs[0] <= 0, False).sum(dtype=jnp.float32)
+
+
+def _experts(xs, ws: tuple, sizes, live, act):
+    """The grouped products of the pairs' rows ``xs`` through the experts'
+    weights ``ws`` (``(w1, w3, w2)`` of the gated unit: three products;
+    ``(w1, w2)`` of the plain form: two) -> ``(ys, the up-projections'
+    results, :func:`_zeroed`)``."""
+    hs = tuple(_grouped(xs, w, sizes, live) for w in ws[:-1])
+    ys = _grouped(_experts_hidden(hs, act), ws[-1], sizes, live)
+    return ys, hs, _zeroed(hs, live)
+
+
+def _pairs_full(x, weight, ws: tuple, order, slot_of, sizes, top_k: int,
                 act, scope: str):
     """The pairs stage over the static worst case: all ``tokens *
     top_k`` sorted pairs, whatever their number (``sizes.sum()``) that
     held experts receive.  Dispatch and combine are gathers both ways
-    (:func:`_rows_of_pairs`, :func:`_sum_of_pairs`)."""
+    (:func:`_rows_of_pairs`, :func:`_sum_of_pairs`).  -> ``(y,
+    :func:`_zeroed`)``."""
     n_pairs = order.shape[0]
     with _probe.scope(f"{scope}.route"):
         live = (jnp.arange(n_pairs) < sizes.sum())[:, None]
         token_of = order // top_k
         xs = jnp.where(
             live, _rows_of_pairs(x, token_of, slot_of, top_k), 0)
-        ws = weight[order]
+        wp = weight[order]
     with _probe.scope(f"{scope}.experts"):
-        h1, h3 = _grouped(xs, w1, sizes, live), _grouped(xs, w3, sizes, live)
-        ys = _grouped(act(h1) * h3, w2, sizes, live)
+        ys, _, zeroed = _experts(xs, ws, sizes, live, act)
     with _probe.scope(f"{scope}.route"):
-        ys = ys * ws[:, None].astype(ys.dtype)
-        return _sum_of_pairs(ys, token_of, slot_of, top_k)
+        ys = ys * wp[:, None].astype(ys.dtype)
+        return _sum_of_pairs(ys, token_of, slot_of, top_k), zeroed
 
 
 def _compact_index(order, sizes, rows: int, top_k: int):
@@ -393,32 +442,33 @@ def _sum_of_slots(rows_of, slot_of, n_held, top_k: int):
     return acc.astype(rows_of.dtype)
 
 
-def _compact_fwd(x, weight, w1, w3, w2, order, slot_of, sizes, rows: int,
+def _compact_fwd(x, weight, ws: tuple, order, slot_of, sizes, rows: int,
                  top_k: int, act, scope: str):
-    """The pairs stage over the first ``rows`` sorted pairs -> ``(y, (h1,
-    h3))``: the same arithmetic for every live pair as
-    :func:`_pairs_full`, and the two products its backward needs."""
+    """The pairs stage over the first ``rows`` sorted pairs -> ``((y,
+    :func:`_zeroed`), the up-projections' results)``: the same arithmetic
+    for every live pair as :func:`_pairs_full`, and the products its
+    backward needs."""
     with _probe.scope(f"{scope}.route"):
         order_c, token_c, n_held, live = _compact_index(order, sizes, rows,
                                                         top_k)
         xs = jnp.where(live, x[token_c], 0)
-        ws = weight[order_c]
+        wp = weight[order_c]
     with _probe.scope(f"{scope}.experts"):
-        h1, h3 = _grouped(xs, w1, sizes, live), _grouped(xs, w3, sizes, live)
-        ys = _grouped(act(h1) * h3, w2, sizes, live)
+        ys, hs, zeroed = _experts(xs, ws, sizes, live, act)
     with _probe.scope(f"{scope}.route"):
-        ys = ys * ws[:, None].astype(ys.dtype)
-        return _sum_of_slots(ys, slot_of, n_held, top_k), (h1, h3)
+        ys = ys * wp[:, None].astype(ys.dtype)
+        return (_sum_of_slots(ys, slot_of, n_held, top_k), zeroed), hs
 
 
-def _compact_bwd(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g,
+def _compact_bwd(x, weight, ws: tuple, order, slot_of, sizes, hs: tuple, g,
                  rows: int, top_k: int, act, scope: str, dtypes: tuple):
-    """:func:`_compact_fwd`'s gradients to ``(x, weight, w1, w3, w2)``
-    from ``h1``, ``h3`` and a second gather of the rows: six grouped
-    products (each the transpose AD itself would take), none computed
-    twice, and nothing kept at the full buffer's size.  The weights'
-    gradients leave their products in ``dtypes``, their masters' (float32:
-    unrounded)."""
+    """:func:`_compact_fwd`'s gradients to ``(x, weight, ws)`` from the
+    up-projections' results ``hs`` and a second gather of the rows: for
+    each of the experts' weights a grouped product to the rows and one to
+    the weights (each the transpose AD itself would take: six of the gated
+    unit, four of the plain form), none computed twice, and nothing kept at
+    the full buffer's size.  The weights' gradients leave their products
+    in ``dtypes``, their masters' (float32: unrounded)."""
     def to_rows(g_out, w):
         return _grouped_to_rows(g_out, w, sizes, live)
 
@@ -429,79 +479,85 @@ def _compact_bwd(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g,
         order_c, token_c, n_held, live = _compact_index(order, sizes, rows,
                                                         top_k)
         xs = jnp.where(live, x[token_c], 0)
-        ws = weight[order_c][:, None]
+        wp = weight[order_c][:, None]
         gy = g[token_c]
     with _probe.scope_bwd(f"{scope}.experts"):
-        hh, hh_vjp = jax.vjp(lambda a, b: act(a) * b, h1, h3)
-        # ys = (hh w2) * ws: the product's own cotangent gives both the
-        # weights' (its rows against hh's) and hh's (times ws)
-        t = to_rows(gy, w2)
-        d_w2 = to_weights(hh * ws.astype(hh.dtype), gy, dtypes[2])
-        d_h1, d_h3 = hh_vjp(t * ws.astype(t.dtype))
-        d_w1 = to_weights(xs, d_h1, dtypes[0])
-        d_w3 = to_weights(xs, d_h3, dtypes[1])
-        d_xs = to_rows(d_h1, w1) + to_rows(d_h3, w3)
+        hh, hh_vjp = jax.vjp(lambda *h: _experts_hidden(h, act), *hs)
+        # ys = (hh w2) * wp: the product's own cotangent gives both the
+        # weights' (its rows against hh's) and hh's (times wp)
+        t = to_rows(gy, ws[-1])
+        d_down = to_weights(hh * wp.astype(hh.dtype), gy, dtypes[-1])
+        d_hs = hh_vjp(t * wp.astype(t.dtype))
+        d_ups = tuple(to_weights(xs, d_h, dtype)
+                      for d_h, dtype in zip(d_hs, dtypes))
+        d_xs = to_rows(d_hs[0], ws[0])
+        for d_h, w in zip(d_hs[1:], ws[1:-1]):
+            d_xs = d_xs + to_rows(d_h, w)
     with _probe.scope_bwd(f"{scope}.route"):
-        d_ws = (t.astype(jnp.float32) * hh.astype(jnp.float32)).sum(-1)
+        d_wp = (t.astype(jnp.float32) * hh.astype(jnp.float32)).sum(-1)
         d_weight = jnp.zeros(weight.shape, weight.dtype).at[order_c].set(
-            d_ws.astype(weight.dtype), unique_indices=True)
+            d_wp.astype(weight.dtype), unique_indices=True)
         d_x = _sum_of_slots(d_xs, slot_of, n_held, top_k)
-    return d_x, d_weight, d_w1, d_w3, d_w2
+    return d_x, d_weight, (*d_ups, d_down)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
-def _pairs_either(x, weight, w1, w3, w2, order, slot_of, sizes, rows: int,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _pairs_either(x, weight, ws: tuple, order, slot_of, sizes, rows: int,
                   top_k: int, act, scope: str):
     """The pairs stage at the size the layer observes: over ``rows``
     pairs when the held experts' pairs (``sizes.sum()``) fit, over all
-    of them otherwise.  One rule for both passes, so that AD does not
+    of them otherwise -> ``(y, :func:`_zeroed`)``.  One rule for both
+    passes, so that AD does not
     make each branch of the choice write the other's residuals as zeros:
-    the compact branch keeps its two products (:func:`_compact_bwd`),
+    the compact branch keeps its up-projections' results
+    (:func:`_compact_bwd`),
     the full one keeps nothing and takes :func:`_pairs_full` again in
     the backward pass.  The experts' weights are cast to ``x``'s dtype
     once, before the choice, and both passes of either branch read that
     cast: a grouped product and its gradient to the rows take a weight
     in the same layout (ops/pallas/grouped.py), so the backward pass
     makes no second pass over the masters."""
-    return _pairs_either_fwd(x, weight, w1, w3, w2, order, slot_of, sizes,
-                             rows, top_k, act, scope)[0]
+    return _pairs_either_fwd(x, weight, ws, order, slot_of, sizes, rows,
+                             top_k, act, scope)[0]
 
 
-def _pairs_either_fwd(x, weight, w1, w3, w2, order, slot_of, sizes, rows,
-                      top_k, act, scope):
-    masters = (w1, w3, w2)
+def _pairs_either_fwd(x, weight, ws, order, slot_of, sizes, rows, top_k,
+                      act, scope):
     with _probe.scope(f"{scope}.experts"):
-        cast = tuple(w.astype(x.dtype) for w in masters)
-    args = (x, weight, *cast, order, slot_of, sizes)
+        cast = tuple(w.astype(x.dtype) for w in ws)
+    args = (x, weight, cast, order, slot_of, sizes)
 
     def full(*a):
-        blank = jnp.zeros((rows, w1.shape[-1]), x.dtype)
-        return _pairs_full(*a, top_k, act, scope), (blank, blank)
+        blank = jnp.zeros((rows, ws[0].shape[-1]), x.dtype)
+        return _pairs_full(*a, top_k, act, scope), (blank,) * (len(ws) - 1)
 
     with _probe.scope(f"{scope}.route"):
         fits = sizes.sum() <= rows
-    y, kept = lax.cond(
+    out, kept = lax.cond(
         fits, lambda *a: _compact_fwd(*a, rows, top_k, act, scope), full,
         *args)
-    return y, (args, kept, masters)
+    # a kernel's output that a checkpointed layer keeps as it keeps every
+    # other's (``plan._loop_saves``): out of the ``cond`` it needs a name
+    return out, (args, checkpoint_name(kept, "moe_up"), ws)
 
 
 def _pairs_either_bwd(rows, top_k, act, scope, res, g):
     args, kept, masters = res       # the masters: their gradients' dtypes
     dtypes = tuple(w.dtype for w in masters)
 
-    def full(x, weight, w1, w3, w2, order, slot_of, sizes, h1, h3, g):
-        d_x, d_weight, *d_w = jax.vjp(
+    def full(x, weight, ws, order, slot_of, sizes, hs, g):
+        d_x, d_weight, d_ws = jax.vjp(
             lambda *a: _pairs_full(*a, order, slot_of, sizes, top_k, act,
-                                   scope),
-            x, weight, w1, w3, w2)[1](g)
-        return (d_x, d_weight, *(d.astype(t) for d, t in zip(d_w, dtypes)))
+                                   scope)[0],
+            x, weight, ws)[1](g)
+        return d_x, d_weight, tuple(d.astype(t)
+                                    for d, t in zip(d_ws, dtypes))
 
     with _probe.scope_bwd(f"{scope}.route"):
         fits = args[-1].sum() <= rows           # sizes: as the forward chose
     grads = lax.cond(
         fits, lambda *a: _compact_bwd(*a, rows, top_k, act, scope, dtypes),
-        full, *args, *kept, g)
+        full, *args, kept, g[0])                # the count takes no gradient
     return (*grads, None, None, None)
 
 
@@ -519,12 +575,15 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
     discrete choice: the router's product runs at the highest
     precision); ``w1``, ``w3`` ``(held, d, f)`` and ``w2`` ``(held, f,
     d)`` are experts ``first .. first + held``, each a gated unit ``w2
-    (act(x w1) * (x w3))``, in ``x``'s dtype or as float32 masters: the
+    (act(x w1) * (x w3))`` or, with ``w3`` None, the plain ``w2 act(x w1)``
+    (``act`` :func:`relu2`: the squared-ReLU expert), in ``x``'s dtype or
+    as float32 masters: the
     products run in ``x``'s dtype either way, and masters take their
     gradients as the products accumulate them, unrounded.
 
     The ``tokens * top_k`` pairs are sorted by expert, pairs of absent
-    experts last, and the pairs stage (gather, three grouped products,
+    experts last, and the pairs stage (gather, the grouped products: three
+    of the gated unit, two of the plain form,
     the weights, the sum into tokens, and their backward) runs over a
     buffer sized from what the layer observes: the first
     :func:`compact_rows` sorted pairs when the held experts' pairs fit
@@ -541,10 +600,12 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
     Returns ``(y (tokens, d), stats)``; ``stats`` holds float32 scalars
     ``pairs_held`` (pairs routed to held experts), ``load_max_over_mean``
     (the fullest held expert's pairs over the held experts' mean),
-    ``compact`` (1.0 when the compact buffer carried the layer) and
+    ``compact`` (1.0 when the compact buffer carried the layer),
     ``tile_fill`` (held pairs over the row-slots the grouped products
     visit: a row tile that two groups share is visited twice; reckoned
-    with the row tile of the form that ran).  The
+    with the row tile of the form that ran) and, of the plain form alone,
+    ``act_zero`` (over the held pairs, the share of the experts' hidden
+    entries that ``act`` zeroes: ``x w1 <= 0``).  The
     work lies under two scopes of the program, ``<scope>.route``
     (scores, top-k, sort, gather, scatter) and ``<scope>.experts`` (the
     grouped products): siblings by name, since an operation counts for
@@ -565,11 +626,16 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
         slot_of = jnp.argsort(order)                   # its inverse
         sizes = (key[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
         n_held = sizes.sum()
-    stage = (x, weight.reshape(n_pairs), w1, w3, w2, order, slot_of, sizes)
+        # named for a checkpointed layer's policy (``plan._loop_saves``; a
+        # name is no operation): kept, no sort runs twice
+        weight, order, slot_of, sizes = checkpoint_name(
+            (weight.reshape(n_pairs), order, slot_of, sizes), "moe_route")
+    ws = (w1, w2) if w3 is None else (w1, w3, w2)
+    stage = (x, weight, ws, order, slot_of, sizes)
     if rows < n_pairs:
-        y = _pairs_either(*stage, rows, top_k, act, scope)
+        y, zeroed = _pairs_either(*stage, rows, top_k, act, scope)
     else:                                   # one buffer, nothing to choose
-        y = _pairs_full(*stage, top_k, act, scope)
+        y, zeroed = _pairs_full(*stage, top_k, act, scope)
     with _probe.scope(f"{scope}.route"):
         sizes_f = sizes.astype(jnp.float32)
         compact = (n_held <= rows) & (rows < n_pairs)
@@ -580,4 +646,7 @@ def moe_routed_ffn(x, gate_w, bias, w1, w3, w2, first: int, top_k: int,
                      sizes_f.max() / jnp.maximum(sizes_f.mean(), 1e-9),
                  "compact": compact.astype(jnp.float32),
                  "tile_fill": jnp.where(compact, fill_c, fill_f)}
+        if zeroed is not None:
+            stats["act_zero"] = lax.stop_gradient(zeroed) / jnp.maximum(
+                stats["pairs_held"] * w1.shape[2], 1.0)
     return y, stats

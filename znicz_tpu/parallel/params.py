@@ -14,35 +14,41 @@ from jax.sharding import PartitionSpec as P
 from znicz_tpu.parallel.arch import Arch, as_arch, gpt_arch
 
 
-def ssm_in_width(heads: int, head_dim: int, state: int) -> int:
+def ssm_in_width(heads: int, head_dim: int, state: int,
+                 groups: int = 1) -> int:
     """Columns of a state-space layer's ``W_in``: the gate, the convolved
-    ``[x | B | C]``, a step size a head."""
-    return 2 * heads * head_dim + 2 * state + heads
+    ``[x | B | C]`` (``B`` and ``C`` a group), a step size a head."""
+    return 2 * heads * head_dim + 2 * groups * state + heads
 
 
 def _ssm_leaf_shapes(d: int, heads: int, head_dim: int, state: int,
-                     taps: int) -> dict:
-    """``{leaf: shape}`` of a state-space layer (``parallel/ssm.py``)."""
+                     taps: int, groups: int = 1) -> dict:
+    """``{leaf: shape}`` of a state-space layer (``parallel/ssm.py``); the
+    inner width is ``heads x head_dim``.  The gated norm's gain lies a group
+    a row where there are several, as its statistic is taken."""
     inner = heads * head_dim
-    return {"ssm_in": (d, ssm_in_width(heads, head_dim, state)),
-            "ssm_conv_k": (taps, inner + 2 * state),
-            "ssm_conv_b": (inner + 2 * state,), "ssm_dt_b": (heads,),
-            "ssm_a_log": (heads,), "ssm_d": (heads,), "ssm_g": (inner,),
+    conv = inner + 2 * groups * state
+    return {"ssm_in": (d, ssm_in_width(heads, head_dim, state, groups)),
+            "ssm_conv_k": (taps, conv), "ssm_conv_b": (conv,),
+            "ssm_dt_b": (heads,), "ssm_a_log": (heads,), "ssm_d": (heads,),
+            "ssm_g": (inner,) if groups == 1 else (groups, inner // groups),
             "ssm_out": (inner, d)}
 
 
 def _layer_shapes(arch: Arch, i: int) -> dict:
     """``{leaf: shape}`` of layer ``i`` (``n_layers``: the MTP module's):
     the one table the initialiser, the specs and the shapes are read
-    from."""
+    from.  A layer of one sub-layer has that sub-layer's norm alone."""
     d, hd = arch.d, arch.head_dim
     bias = arch.norm == "layer"
     mixer, ffn = arch.kinds(i)
-    out = {"ln1_g": (d,), "ln2_g": (d,)}
+    norms = [n for n, kind in (("ln1", mixer), ("ln2", ffn))
+             if kind != "none"]
+    out = {f"{n}_g": (d,) for n in norms}
     if bias:
-        out.update({"ln1_b": (d,), "ln2_b": (d,)})
+        out.update({f"{n}_b": (d,) for n in norms})
     if arch.sandwich:
-        out.update({"ln1o_g": (d,), "ln2o_g": (d,)})
+        out.update({f"{n}o_g": (d,) for n in norms})
     if mixer == "latent":
         out.update({
             "wq_a": (d, arch.q_lora), "q_a_g": (arch.q_lora,),
@@ -62,8 +68,9 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
                         "ik_g": (di,), "ik_b": (di,)})
     elif mixer == "mamba":
         out.update(_ssm_leaf_shapes(d, arch.ssm_heads, arch.ssm_head_dim,
-                                    arch.ssm_state, arch.conv_taps))
-    else:
+                                    arch.ssm_state, arch.conv_taps,
+                                    arch.ssm_groups))
+    elif mixer == "sconv":
         out.update({"w_in": (d, 3 * d), "conv_k": (arch.conv_taps, d),
                     "w_out": (d, d)})
     if ffn == "mlp":
@@ -77,15 +84,20 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
         out.update({"gate": (d, e), "ew1": (e, d, arch.ff),
                     "eb1": (e, arch.ff), "ew2": (e, arch.ff, d),
                     "eb2": (e, d)})
-    else:
-        e, f = arch.experts_held, arch.moe_ff
-        out.update({"gate": (d, arch.n_experts), "ew1": (e, d, f),
-                    "ew3": (e, d, f), "ew2": (e, f, d)})
+    elif ffn == "moe_routed":
+        # the gated unit's second up-projection is what the plain form lacks
+        e, f, gated = arch.experts_held, arch.moe_ff, arch.expert_form == "glu"
+        out.update({"gate": (d, arch.n_experts), "ew1": (e, d, f)})
+        if gated:
+            out["ew3"] = (e, d, f)
+        out["ew2"] = (e, f, d)
         if arch.expert_bias:
             out["ebias"] = (arch.n_experts,)
         if arch.shared_ff:
-            out.update({"sw1": (d, arch.shared_ff), "sw3": (d, arch.shared_ff),
-                        "sw2": (arch.shared_ff, d)})
+            out["sw1"] = (d, arch.shared_ff)
+            if gated:
+                out["sw3"] = (d, arch.shared_ff)
+            out["sw2"] = (arch.shared_ff, d)
     return out
 
 

@@ -16,6 +16,7 @@ from jax.sharding import Mesh
 
 from znicz_tpu.parallel.arch import Arch
 from znicz_tpu.parallel.head import _n_chunks
+from znicz_tpu.parallel.moe import compact_rows
 from znicz_tpu.parallel.params import (_layer_shapes, _shape_leaves,
                                        param_shapes, ssm_in_width)
 
@@ -23,13 +24,16 @@ _log = logging.getLogger("znicz_tpu.transformer")
 
 
 #: what a checkpointed layer keeps whatever the memory (:func:`_loop_saves`)
-_KEPT_ALWAYS = ("attn_qkv", "sub_out", "ssm_y", "ssm_state")
+_KEPT_ALWAYS = ("attn_qkv", "sub_out", "ssm_y", "ssm_state", "moe_route",
+                "moe_up")
 
 #: what it keeps beside them where the device has room for all the layers'
-#: (:func:`checkpoint_plan`), in the order of time saved a byte kept: the
-#: SwiGLU's two wide products and a state-space layer's input projection
-#: (a product made again costs about 12 ms a GiB of its result on a v5e),
-#: then the convolution's float32 sum (elementwise: about 10 ms a GiB)
+#: (:func:`checkpoint_plan`), in the order of time saved a byte kept: a
+#: feed-forward unit's wide products (a SwiGLU's two; of a shared expert
+#: beside routed ones its form's: two, or a squared-ReLU unit's one) and a
+#: state-space layer's input projection (a product made again costs about
+#: 12 ms a GiB of its result on a v5e), then the convolution's float32 sum
+#: (elementwise: about 10 ms a GiB)
 _KEPT_IF_ROOM = ("glu_wide", "ssm_in", "ssm_conv_sum")
 
 #: bytes :func:`checkpoint_plan` leaves free beside the step's reckoned
@@ -57,7 +61,13 @@ def _loop_saves(prim, *_, **params) -> bool:
     ``parallel/ssm.py``), with the wide input projection and its split, the
     convolution, the gate and the gated norm made again: a layer holds five or
     six arrays of ``(tokens, d)`` and its chunk states where it would hold
-    ``(tokens, 8.5 d)`` of them.  What such a stack keeps beside this list
+    ``(tokens, 8.5 d)`` of them.  Of a routed expert layer in such a stack it
+    keeps the router's choice (``moe_route``: the weights, the sort and its
+    inverse, the group sizes; small, and no sort runs twice) and the experts'
+    up-projections' results (``moe_up``: a kernel's output that leaves the
+    pairs stage's ``cond``, which ``pallas_call`` alone would not name); the
+    norm, the gathers, the experts' cast and the shared expert are made
+    again.  What such a stack keeps beside this list
     follows the memory: :func:`checkpoint_plan`, :func:`_saves`."""
     return prim.name == "pallas_call" or _SAVED_NAMES(prim, *_, **params)
 
@@ -102,12 +112,14 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
       first and a looped stack carries its layers' through the scan in the
       compute dtype; every other leaf's update runs as its gradient lands;
     - what :func:`_loop_saves` keeps of every layer application (the
-      layer's input, ``sub_out`` twice, q, k, v and the kernel's output and
-      rows of an attention layer, ``ssm_y`` and ``ssm_state`` of a
-      state-space layer), and a looped stack's outputs;
+      layer's input, ``sub_out`` of each sub-layer, q, k, v and the kernel's
+      output and rows of an attention layer, ``ssm_y`` and ``ssm_state`` of
+      a state-space layer, ``moe_up`` of a routed one), and a looped stack's
+      outputs;
     - one layer's backward pass at work: six arrays of its widest
       activation in the compute dtype (a SwiGLU's two products, their
-      gated product and the three gradients), and of a looped stack the
+      gated product and the three gradients), of a routed layer the held
+      experts' cast and float32 gradients, and of a looped stack the
       layer's kept arrays once more (cut from the scan's stack as copies);
     - the head pass: one chunk's float32 logits and their gradient in the
       compute dtype (a looped stack's passes are one call of ``loop_steps``
@@ -126,23 +138,36 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
     kept = working = 0
     for i in range(arch.n_layers):
         mixer, ffn = arch.kinds(i)
-        layer = 3 * act * d
+        # the layer's input and each sub-layer's output (``sub_out`` twice
+        # of a layer of two)
+        layer = (1 + (mixer != "none") + (ffn != "none")) * act * d
+        wide = transient = 0
         if mixer == "mamba":
             inner = arch.ssm_heads * arch.ssm_head_dim
             chunks = -(-tokens // arch.ssm_chunk)
             layer += act * inner + chunks * inner * arch.ssm_state * 4
             wide = ssm_in_width(arch.ssm_heads, arch.ssm_head_dim,
-                                arch.ssm_state)
+                                arch.ssm_state, arch.ssm_groups)
         elif mixer in ("attention", "latent"):
             qo, kv = arch.heads * arch.head_dim, arch.kv_heads * arch.head_dim
             layer += act * (2 * qo + 2 * kv) + tokens * arch.heads * 4
             wide = qo
-        else:
+        elif mixer == "sconv":
             wide = 3 * d
-        kept += layer
         if ffn == "glu":
             wide = max(wide, arch.ff)
-        working = max(working, 6 * act * wide +
+        elif ffn == "moe_routed" and _recomputes_by_policy(arch):
+            # ``moe_up`` of the compact buffer's rows; at work, the held
+            # experts' cast and their float32 gradients beside the rows
+            rows = compact_rows(tokens * arch.top_k, arch.experts_held,
+                                arch.n_experts)
+            ups = 2 if arch.expert_form == "glu" else 1
+            layer += rows * arch.moe_ff * ups * itemsize
+            wide = max(wide, arch.shared_ff, -(-rows * arch.moe_ff // tokens))
+            transient = arch.experts_held * (ups + 1) * d * arch.moe_ff * \
+                (4 + itemsize)
+        kept += layer
+        working = max(working, 6 * act * wide + transient +
                       (layer if loops > 1 else 0))
     if loops > 1:
         grads += itemsize * sum(
@@ -158,15 +183,22 @@ def _kind_bytes(arch: Arch, tokens: int, itemsize: int) -> dict:
     """``{name: bytes}`` all the layers of ``arch`` hold of each optional
     kind of :data:`_KEPT_IF_ROOM` when a step of ``tokens`` local tokens
     keeps it: ``tokens x width x itemsize x layers that have it`` (the
-    convolution's sum is float32 whatever the compute dtype)."""
+    convolution's sum is float32 whatever the compute dtype; a shared
+    expert's wide products, two of a gated unit and one of a plain one,
+    count with the SwiGLUs')."""
     glu = sum(f == "glu" for f in arch.ffns)
     mamba = sum(m == "mamba" for m in arch.mixers)
+    shared = arch.shared_ff * arch.ffns.count("moe_routed")
+    gated = arch.expert_form == "glu"
     inner = arch.ssm_heads * arch.ssm_head_dim
+    bc = 2 * arch.ssm_groups * arch.ssm_state
     return {
-        "glu_wide": tokens * 2 * arch.ff * itemsize * glu,
+        "glu_wide": tokens * itemsize * (2 * arch.ff * glu +
+                                         (1 + gated) * shared),
         "ssm_in": tokens * itemsize * mamba * ssm_in_width(
-            arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state),
-        "ssm_conv_sum": tokens * (inner + 2 * arch.ssm_state) * 4 * mamba}
+            arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state,
+            arch.ssm_groups),
+        "ssm_conv_sum": tokens * (inner + bc) * 4 * mamba}
 
 
 def checkpoint_plan(arch: Arch, tokens: int, itemsize: int,

@@ -1,32 +1,38 @@
 """The Mamba-2 mixer (a state-space layer; Dao & Gu, arXiv:2405.21060), as
-the ``granitemoehybrid`` family's ``mamba`` layers have it, with its scan in
-the chunked form.
+the ``granitemoehybrid`` family's ``mamba`` layers (one group) and the
+``nemotron_h`` family's ``M`` layers (eight) have it, with its scan in the
+chunked form.
 
 The layer, on the normed residual stream ``u (b, t, d)``, with ``H`` heads
-of ``P`` entries, a state of ``N`` entries a head entry and ONE group (``B``
-and ``C`` serve all heads):
+of ``P`` entries, a state of ``N`` entries a head entry and ``G`` groups of
+``H / G`` heads (head ``h`` reads the ``B`` and ``C`` of group ``h // (H /
+G)``):
 
-    [z | xBC | dt] = u W_in                      d -> H P + (H P + 2 N) + H
+    [z | xBC | dt] = u W_in                      d -> H P + (H P + 2 G N) + H
     xBC = silu(conv(xBC) + b)                    depthwise, causal, zeros
                                                  before the sequence
-    [x | B | C] = xBC                            x as (H, P)
+    [x | B | C] = xBC                            x as (H, P); B, C as (G, N)
     dt = softplus(dt + dt_bias),  A = -exp(A_log)             a head
     h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,  h_0 = 0      (P, N) a head
     y_t = h_t C_t + D x_t
-    out = RMSNorm(y * silu(z); g) W_out          over all H P, gate first
+    out = RMSNorm(y * silu(z); g) W_out          over each group's H P / G
+                                                 entries, gate first
 
 **The scan** (:func:`ssd`) never walks the positions.  In chunks of ``Q``
 positions, with ``cs`` the running sum of ``dt A`` inside a chunk:
 
-1. inside a chunk the quadratic form: ``scores = C B^T`` once a chunk for
-   all heads, masked by the decays ``L[i, j] = exp(cs_i - cs_j)`` (``j <=
-   i``), times ``dt x``;
+1. inside a chunk the quadratic form: ``scores = C B^T`` once a chunk and
+   a GROUP (not a head), masked by each head's decays ``L[i, j] = exp(cs_i
+   - cs_j)`` (``j <= i``), times ``dt x``;
 2. a chunk's closing state ``S_c = sum_j exp(cs_last - cs_j) dt_j x_j
-   B_j^T``;
+   B_j^T``, each head against its group's ``B``;
 3. the carry from chunk to chunk, ``h_{c+1} = exp(cs_last) h_c + S_c``
    (``t / Q`` steps of a ``lax.scan``; the one part that is sequential);
 4. the carried state's contribution to each position, ``exp(cs_i) h_c
-   C_i``.
+   C_i``, each head against its group's ``C``.
+
+With one group ``B`` and ``C`` carry no group axis and the program is the
+one it was before groups were written (:func:`_heads`).
 
 Products run on the operands' dtype (bfloat16 in a training step) and
 accumulate in float32; the softplus, the log-decays and their running sums,
@@ -74,19 +80,43 @@ def _running(dt, a, q: int):
     return jnp.cumsum(_chunked(dt * a, q).transpose(0, 1, 3, 2), axis=-1)
 
 
+def _heads(v, groups: int, axis: int):
+    """``v`` with its head axis cut into ``(groups, heads a group)`` for a
+    product against a group's ``B`` or ``C``; as it is with one group."""
+    if groups == 1:
+        return v
+    return v.reshape(*v.shape[:axis], groups, v.shape[axis] // groups,
+                     *v.shape[axis + 1:])
+
+
+def _flat_heads(v, groups: int, axis: int):
+    """:func:`_heads`, undone."""
+    if groups == 1:
+        return v
+    return v.reshape(*v.shape[:axis], -1, *v.shape[axis + 2:])
+
+
+def _letters(groups: int) -> tuple:
+    """``(a group's axis, the heads' axes)`` of the einsums below."""
+    return ("", "h") if groups == 1 else ("g", "gr")
+
+
 @jax.checkpoint
 def _chunk_states(x, dt, a, bm):
     """Steps 2 and 3 on chunked operands ``x (b, c, q, H, P)``, ``dt (b, c *
-    q, H)`` float32, ``a (H,)``, ``bm (b, c, q, N)`` -> ``(each chunk's
-    opening state (b, c, H, P, N), the state behind the last position
-    (b, H, P, N))``, float32."""
+    q, H)`` float32, ``a (H,)``, ``bm (b, c, q, N)`` (``(b, c, q, G, N)`` of
+    several groups) -> ``(each chunk's opening state (b, c, H, P, N), the
+    state behind the last position (b, H, P, N))``, float32."""
     q = x.shape[2]
+    groups = bm.shape[3] if bm.ndim == 5 else 1
+    g, h = _letters(groups)
     cs = _running(dt, a, q)
     to_end = jnp.exp(cs[..., -1:] - cs).transpose(0, 1, 3, 2)   # (b, c, q, H)
     xw = (x.astype(jnp.float32) *
           (_chunked(dt, q) * to_end)[..., None]).astype(x.dtype)
-    closing = jnp.einsum("bcqhp,bcqn->cbhpn", xw, bm,
-                         preferred_element_type=jnp.float32)
+    closing = _flat_heads(jnp.einsum(
+        f"bcq{h}p,bcq{g}n->cb{h}pn", _heads(xw, groups, 3), bm,
+        preferred_element_type=jnp.float32), groups, 2)
     whole = jnp.exp(cs[..., -1]).transpose(1, 0, 2)             # (c, b, H)
 
     def carry(h, inp):
@@ -103,8 +133,10 @@ def _chunk_outputs(x, dt, a, bm, cm, skip, opening):
     """Steps 1 and 4 and the skip -> ``y (b, c, q, H, P)`` in ``x``'s
     dtype."""
     q = x.shape[2]
+    groups = bm.shape[3] if bm.ndim == 5 else 1
+    g, h = _letters(groups)
     cs = _running(dt, a, q)
-    scores = jnp.einsum("bcin,bcjn->bcij", cm, bm,
+    scores = jnp.einsum(f"bci{g}n,bcj{g}n->bc{g}ij", cm, bm,
                         preferred_element_type=jnp.float32)
     seen = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
     # masked before the exp: above the diagonal the difference is positive
@@ -112,21 +144,27 @@ def _chunk_outputs(x, dt, a, bm, cm, skip, opening):
                               -jnp.inf))                     # (b, c, H, i, j)
     xf = x.astype(jnp.float32)
     xdt = (xf * _chunked(dt, q)[..., None]).astype(x.dtype)
-    y = jnp.einsum("bchij,bcjhp->bcihp",
-                   (scores[:, :, None] * decay).astype(x.dtype), xdt,
+    # a group's scores serve each of its heads, under the head's own decays
+    y = jnp.einsum(f"bc{h}ij,bcj{h}p->bci{h}p",
+                   (scores[..., None, :, :] *
+                    _heads(decay, groups, 2)).astype(x.dtype),
+                   _heads(xdt, groups, 3),
                    preferred_element_type=jnp.float32)
-    carried = jnp.einsum("bcin,bchpn->bcihp", cm, opening.astype(x.dtype),
+    carried = jnp.einsum(f"bci{g}n,bc{h}pn->bci{h}p", cm,
+                         _heads(opening.astype(x.dtype), groups, 2),
                          preferred_element_type=jnp.float32)
-    y = y + carried * jnp.exp(cs).transpose(0, 1, 3, 2)[..., None]
+    y = _flat_heads(y, groups, 3) + _flat_heads(carried, groups, 3) * \
+        jnp.exp(cs).transpose(0, 1, 3, 2)[..., None]
     return (y + skip[:, None] * xf).astype(x.dtype)
 
 
 def ssd(x, dt, a, bm, cm, skip, chunk: int):
     """The scan: ``x (b, t, H, P)``, ``dt (b, t, H)`` float32 (after the
-    softplus), ``a (H,)`` float32 (negative), ``bm``, ``cm`` ``(b, t, N)``,
-    ``skip (H,)`` float32 -> ``(y (b, t, H, P) in x's dtype, the state
-    behind the last position (b, H, P, N) float32)``, in chunks of ``chunk``
-    positions (the whole row where it is shorter)."""
+    softplus), ``a (H,)`` float32 (negative), ``bm``, ``cm`` ``(b, t, N)``
+    of one group or ``(b, t, G, N)`` of ``G`` (``H / G`` heads each, in
+    order), ``skip (H,)`` float32 -> ``(y (b, t, H, P) in x's dtype, the
+    state behind the last position (b, H, P, N) float32)``, in chunks of
+    ``chunk`` positions (the whole row where it is shorter)."""
     b, t = x.shape[:2]
     q = min(int(chunk), t)
     fill = -t % q
@@ -152,7 +190,7 @@ def _conv(v, taps, bias):
 
 
 def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
-          eps: float, scope: str):
+          eps: float, scope: str, groups: int = 1):
     """The layer on the normed stream ``u (b, t, d)`` -> ``(out (b, t, d),
     stats)``.  Scopes: ``scope`` (the two projections, the split, the gate
     and the gated norm), ``scope.conv`` and ``scope.scan`` (softplus,
@@ -163,11 +201,11 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
     ``ssm_layers``, 1.  Neither
     depends on ``chunk``."""
     b, t, _ = u.shape
-    inner = heads * head_dim
+    inner, bc = heads * head_dim, groups * state
     with _probe.scope(scope):
         proj = checkpoint_name(u @ p["ssm_in"], "ssm_in")
-        z, xbc = proj[..., :inner], proj[..., inner:2 * inner + 2 * state]
-        dt = proj[..., 2 * inner + 2 * state:]
+        z, xbc = proj[..., :inner], proj[..., inner:2 * inner + 2 * bc]
+        dt = proj[..., 2 * inner + 2 * bc:]
     with _probe.scope(f"{scope}.conv"):
         xbc = jax.nn.silu(checkpoint_name(
             _conv(xbc, p["ssm_conv_k"], p["ssm_conv_b"]), "ssm_conv_sum")
@@ -176,10 +214,12 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
         dt = jax.nn.softplus(dt.astype(jnp.float32) +
                              p["ssm_dt_b"].astype(jnp.float32))
         a = -jnp.exp(p["ssm_a_log"].astype(jnp.float32))
-        y, last = ssd(xbc[..., :inner].reshape(b, t, heads, head_dim), dt, a,
-                      xbc[..., inner:inner + state],
-                      xbc[..., inner + state:],
-                      p["ssm_d"].astype(jnp.float32), chunk)
+        x = xbc[..., :inner].reshape(b, t, heads, head_dim)
+        bm, cm = xbc[..., inner:inner + bc], xbc[..., inner + bc:]
+        if groups > 1:
+            bm, cm = (v.reshape(b, t, groups, state) for v in (bm, cm))
+        y, last = ssd(x, dt, a, bm, cm, p["ssm_d"].astype(jnp.float32),
+                      chunk)
         y = checkpoint_name(y, "ssm_y")
         last = lax.stop_gradient(last)
         stats = {"ssm_decay": lax.stop_gradient(jnp.exp(dt * a)).mean(),
@@ -189,6 +229,10 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
     with _probe.scope(scope):
         gated = y.reshape(b, t, inner).astype(jnp.float32) * \
             jax.nn.silu(z.astype(jnp.float32))
+        # the statistic over each group's entries (the gain lies a group a
+        # row, ``params._ssm_leaf_shapes``); over all of them with one group
+        gated = _heads(gated, groups, 2)
         gated = gated * lax.rsqrt((gated * gated).mean(-1, keepdims=True)
                                   + eps)
-        return (gated.astype(u.dtype) * p["ssm_g"]) @ p["ssm_out"], stats
+        return _flat_heads(gated.astype(u.dtype) * p["ssm_g"], groups,
+                           2) @ p["ssm_out"], stats

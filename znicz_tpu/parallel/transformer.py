@@ -22,14 +22,15 @@ from znicz_tpu.parallel import dsa, qcomm, ssm, zero
 # take ``make_train_step`` from
 from znicz_tpu.parallel.arch import (Arch, _FAMILIES,  # noqa: F401
                                      _default_compute_dtype,
-                                     arch_from_config, as_arch)
+                                     arch_from_config, as_arch, routed_block)
 from znicz_tpu.parallel.blocks import (_Run, _block, _rms_norm,
                                        flash_refusal)
 from znicz_tpu.parallel.compat import quantized_psum, shard_map
 from znicz_tpu.parallel.head import (_ce_from_hidden, _ce_weighted, _head_of,
                                      _n_chunks, _normalised,
                                      ce_grad_in_forward)
-from znicz_tpu.parallel.moe import MEAN_STATS
+from znicz_tpu.parallel.moe import (MEAN_STATS, compact_rows,
+                                    gmm_kernel_refusal)
 from znicz_tpu.parallel.params import (_shape_leaves, _spec_leaves,
                                        param_shapes, param_specs,
                                        shard_params_specs)
@@ -125,7 +126,7 @@ def _cast_params(ps, arch: Arch, cdt):
     if arch.mtp:
         layers.append((ps["mtp"]["block"], out["mtp"]["block"]))
     for master, cast in layers:
-        if "ew3" in master:                # a routed layer's, no other's
+        if routed_block(master):
             for k in ("gate", "ebias", "ew1", "ew3", "ew2"):
                 if k in master:
                     cast[k] = master[k]
@@ -247,7 +248,9 @@ def _forward_hidden(ps, tokens, arch: Arch, run: _Run, cdt,
 
 def _mean_stats(stats: dict, arch: Arch) -> dict:
     """The routed layers' summed counters with ``moe.MEAN_STATS`` turned
-    into their mean over the layers."""
+    into their mean over the routed layers (a state-space layer's readings
+    stay sums beside their own count, ``ssm_layers``: each kind's mean is
+    over its own layers, whatever else the stack holds)."""
     return {k: v / arch.routed_layers() if k in MEAN_STATS else v
             for k, v in stats.items()}
 
@@ -455,7 +458,12 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
       with an indexer, the share whose index scores with their gradients,
       and whose alignment target, the kernels of ``ops/pallas/dsa.py`` make
       (all or none: ``dsa.index_kernel_refusal``,
-      ``dsa.align_kernel_refusal``); None without an indexer."""
+      ``dsa.align_kernel_refusal``); None without an indexer;
+    - ``moe_gmm_kernel_share``: of the routed expert layers, the share whose
+      grouped products the kernels of ``ops/pallas/grouped.py`` make (all or
+      none, in the compact and the full pairs buffer alike:
+      ``moe.gmm_kernel_refusal``; the rest ``lax.ragged_dot``); None
+      without a routed layer."""
     from znicz_tpu.ops.pallas import attention as pattn
     run = _run_of(mesh, arch, arch.vocab if head_sharded else None)
     b_loc = batch // mesh.shape.get("data", 1)
@@ -471,13 +479,22 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         align = float(dsa.align_kernel_refusal(
             t_loc, run.heads_local, run.kv_heads_local, arch.head_dim,
             run.interpret) is None)
+    gmm = None
+    if arch.routed_layers():
+        pairs = b_loc * t_loc * arch.top_k
+        gmm = float(not any(gmm_kernel_refusal(
+            r, arch.d, arch.moe_ff, arch.experts_held,
+            _default_compute_dtype(), run.interpret)
+            for r in {pairs, compact_rows(pairs, arch.experts_held,
+                                          arch.n_experts)}))
     return {
         "ce_grad_in_forward_share": float(ce_grad_in_forward(
             loss_chunks, head_sharded, arch.loop_steps > 1)),
         "attn_kvb_block_rows": rows,
         "checkpoint_kept_bytes": checkpoint_plan(*_plan_of(
             arch, run, b_loc * t_loc, _default_compute_dtype(), loss_chunks)),
-        "dsa_index_kernel_share": index, "dsa_align_kernel_share": align}
+        "dsa_index_kernel_share": index, "dsa_align_kernel_share": align,
+        "moe_gmm_kernel_share": gmm}
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

@@ -105,6 +105,13 @@ def _choice_gauges() -> tuple:
             "an indexer (the rest: blocked jax.numpy einsums, the index "
             "heads' scores through HBM)",
             ("unit",)), None),
+        ("moe_gmm_kernel_share", registry.gauge(
+            "znicz_lm_moe_gmm_kernel_share",
+            "routed expert layers whose grouped products the Pallas kernels "
+            "moe_gmm_rows, moe_gmm_rows_t and moe_gmm_weights make over the "
+            "routed expert layers (the rest: lax.ragged_dot, which the "
+            "shape or the platform left them to)",
+            ("unit",)), None),
     )
 
 
@@ -205,8 +212,10 @@ class TransformerLMStep(AcceleratedUnit):
         #: routed layers' counters; None between passes
         self._acc = None
         #: the last finished pass's routed-expert counters (host floats):
-        #: pairs routed to held experts a step, and the fullest held
-        #: expert's load over the mean
+        #: pairs routed to held experts a step, the fullest held
+        #: expert's load over the mean, the compact buffer's and the row
+        #: tiles' shares and, over the held pairs, the share of the experts'
+        #: hidden entries a squared ReLU zeroed (0 for gated experts)
         self.moe_counters: dict = {}
         #: the last finished training pass's mean loss terms of a stack
         #: with an MTP module (``last_loss`` and the Decision's metric are
@@ -244,6 +253,9 @@ class TransformerLMStep(AcceleratedUnit):
         #: and whose index scores the Pallas kernels make; None without one
         self.dsa_align_kernel_share: Optional[float] = None
         self.dsa_index_kernel_share: Optional[float] = None
+        #: of the routed expert layers, the share whose grouped products
+        #: the Pallas kernels make; None without one
+        self.moe_gmm_kernel_share: Optional[float] = None
         #: ``{name: bytes}`` the checkpointed layers keep beside their
         #: policy's own list (``parallel/plan.py::checkpoint_plan``)
         self.checkpoint_kept_bytes: dict = {}
@@ -422,7 +434,8 @@ class TransformerLMStep(AcceleratedUnit):
                               float(sums["load_max_over_mean"]) / steps,
                               float(sums["compact"]) / steps,
                               float(sums["tile_fill"]) / steps,
-                              float(sums["pairs_held"]))
+                              float(sums["pairs_held"]),
+                              float(sums.get("act_zero", 0.0)) / steps)
         self._publish_terms(sums, steps)
         if "dsa_pairs" in sums:
             self._publish_dsa(sums, steps, self.minibatch_mse)
@@ -549,7 +562,7 @@ class TransformerLMStep(AcceleratedUnit):
 
     def _publish_moe(self, pairs_a_step: float, load_ratio: float,
                      compact_share: float, tile_fill: float,
-                     pairs: float) -> None:
+                     pairs: float, act_zero_share: float) -> None:
         """A finished pass's routed-expert counters: the unit's mirror
         and the process registry (docs/OBSERVABILITY.md)."""
         from znicz_tpu.observe import registry
@@ -557,7 +570,8 @@ class TransformerLMStep(AcceleratedUnit):
         self.moe_counters = {"pairs_held_per_step": pairs_a_step,
                              "load_max_over_mean": load_ratio,
                              "compact_share": compact_share,
-                             "tile_fill": tile_fill}
+                             "tile_fill": tile_fill,
+                             "act_zero_share": act_zero_share}
         registry.counter(
             "znicz_lm_moe_pairs_held_total",
             "(token, choice) pairs routed to experts this chip holds",
@@ -579,6 +593,13 @@ class TransformerLMStep(AcceleratedUnit):
             "products visited (a row tile two experts share is visited "
             "twice), averaged over the layers and the last class pass",
             ("unit",)).labels(unit=self.name).set(tile_fill)
+        registry.gauge(
+            "znicz_lm_moe_act_zero_share",
+            "over the pairs routed to held experts, the share of the "
+            "experts' hidden entries that a squared ReLU zeroed (0 for "
+            "gated experts), averaged over the routed layers and the last "
+            "class pass",
+            ("unit",)).labels(unit=self.name).set(act_zero_share)
 
     # -- serving handoff (ISSUE 10) -----------------------------------------
     def export_lm(self, path: str,
