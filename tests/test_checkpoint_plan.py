@@ -13,6 +13,7 @@ a worker that had loaded the TPU's library would disturb the profiler's
 tests that run in it afterwards.  Skipped where no topology can be
 described."""
 
+import functools
 import json
 import os
 import subprocess
@@ -25,6 +26,7 @@ GIB = 2 ** 30
 HBM_USABLE = 15.75 * GIB          # what the runtime leaves of 16 GiB
 
 
+@functools.lru_cache(maxsize=None)
 def _compiled(config: str, traffic: str) -> dict:
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "TPU_LOG_DIR": "disabled"}
     env.pop("XLA_FLAGS", None)     # one CPU device is enough there
@@ -33,7 +35,12 @@ def _compiled(config: str, traffic: str) -> dict:
          traffic, "15.75"], env=env, capture_output=True, text=True,
         timeout=900)
     assert done.returncode == 0, done.stderr[-3000:]
-    out = json.loads(done.stdout.strip().splitlines()[-1])
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+def _compiled_or_skip(config: str, traffic: str) -> dict:
+    """One compile a cell, whichever test asks first."""
+    out = _compiled(config, traffic)
     if "skip" in out:
         pytest.skip(out["skip"])
     return out
@@ -42,7 +49,7 @@ def _compiled(config: str, traffic: str) -> dict:
 @pytest.mark.parametrize("config,traffic,params,kept,tflop", [
     ("granite_4_0_h_micro", "train_tokens_pp4_t8192", 951_991_232,
      {"glu_wide": 10 * 2 * 8192 * 8192 * 2, "ssm_in": 9 * 8192 * 8512 * 2,
-      "ssm_conv_sum": 0}, (38.5, 40.0)),
+      "ssm_conv_sum": 0}, (37.5, 38.6)),
     ("ouro_2_6b", "train_tokens_pp8_t4096", 509_661_185,
      {"glu_wide": 0}, (17.5, 18.7)),
     # a stack of one-sub-layer layers (M, E, *): the shared experts' wide
@@ -60,9 +67,11 @@ def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
     (nor over it by more: a formula that counts double would refuse what
     fits), the whole stays under the rehearsals' line of 0.9 x 15.75 GiB,
     and the compiler's operation count says the kept products are not made
-    again (47.3 TFLOP with today's list in the granite cell; a looped
-    stack's count, loop bodies counted once, is the parent's)."""
-    out = _compiled(config, traffic)
+    again (47.3 TFLOP with today's list in the granite cell, 39.2 with the
+    plan's and the scan in ``jax.numpy``, 38.1 with the scan in its kernels,
+    whose operations the compiler does not count; a looped stack's count,
+    loop bodies counted once, is the parent's)."""
+    out = _compiled_or_skip(config, traffic)
     assert out["params"] == params and out["limit"] == int(HBM_USABLE)
     assert out["plan"] == kept
     live = out["argument_bytes"] + out["temp_bytes"]
@@ -75,3 +84,28 @@ def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
     assert abs(live - reckoned) <= out["margin"], (live, reckoned)
     assert reckoned + out["margin"] <= HBM_USABLE
     assert tflop[0] < out["flops"] / 1e12 < tflop[1]
+
+
+@pytest.mark.parametrize("config,traffic,layers", [
+    ("granite_4_0_h_micro", "train_tokens_pp4_t8192", 9),
+    ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 4),
+])
+def test_both_state_space_cells_hold_the_scan_kernels_and_no_chunk_square(
+        config, traffic, layers):
+    """The same compiled steps (what a v5e would answer: the scan's kernels
+    wherever their shapes take them, ``ops/pallas/ssd.py``): every
+    state-space layer holds the forward kernel once (a checkpointed layer
+    does not run it again) and the backward kernel once, no float32 array
+    with two chunk-length axes is left among the step's arrays, and
+    arguments plus temporaries are not above the 12.68 GiB the parent's step
+    counted with the scan in ``jax.numpy`` (``PERF.md`` section 5, PRs 43
+    and 45)."""
+    from znicz_tpu.ops.pallas import ssd
+
+    out = _compiled_or_skip(config, traffic)
+    assert out["state_space_layers"] == layers
+    assert out["scan_kernels"] == {ssd.FWD_KERNEL_NAME: layers,
+                                   ssd.BWD_KERNEL_NAME: layers}
+    assert out["chunk_squares"] == []
+    live = out["argument_bytes"] + out["temp_bytes"]
+    assert live <= 12.685 * GIB, live / GIB

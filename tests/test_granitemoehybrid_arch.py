@@ -188,18 +188,33 @@ def test_a_faulty_carry_fails_the_same_tolerance(monkeypatch, fault):
     assert err > 5e-4, err
 
 
-def test_the_scan_keeps_chunk_states_and_no_decay_matrix_for_its_gradients():
-    """What the backward pass of ``ssd`` holds: the operands and each
-    chunk's opening state; no array with two chunk-length axes (the ``(H,
-    Q, Q)`` decay and score matrices are made again)."""
-    ops = _scan_operands(5, 64, n=12)
-    q, chunks = 16, 4
-    _, vjp = jax.vjp(lambda *o: ssm.ssd(*o, q)[0], *ops)
+@pytest.mark.parametrize("form,t,q,heads,pd,n", [
+    ("jax.numpy", 64, 16, 4, 8, 12),
+    # the kernels' shape, interpreted: a chunk and a state of 128, eight
+    # heads of 32 (so that no kept array's other axes read 128 twice)
+    ("kernels", 384, 128, 8, 32, 128),
+])
+def test_the_scan_keeps_chunk_states_and_no_decay_matrix_for_its_gradients(
+        form, t, q, heads, pd, n):
+    """What the backward pass of ``ssd`` holds, in either form: the operands
+    and each chunk's opening state; no array with two chunk-length axes (the
+    ``(H, Q, Q)`` decay and score matrices are made again), and none larger
+    than ``x``, ``B`` and ``C`` together (the kernels take the three side by
+    side, as the layer has them)."""
+    from test_lfm2_arch import _pallas_interpret
+
+    ops = _scan_operands(5, t, heads=heads, pd=pd, n=n)
+    chunks = t // q
+    with _pallas_interpret(form == "kernels"):
+        _, vjp = jax.vjp(lambda *o: ssm.ssd(*o, q)[0], *ops)
     shapes = [tuple(v.shape) for v in jax.tree.leaves(vjp)
               if hasattr(v, "shape")]
-    assert (2, chunks, 4, 8, 12) in shapes          # the opening states
+    states = (2, chunks, heads * pd, n) if form == "kernels" else \
+        (2, chunks, heads, pd, n)
+    assert states in shapes                         # the opening states
     assert not [s for s in shapes if s.count(q) >= 2], shapes
-    assert max(int(np.prod(s)) for s in shapes) <= 2 * 64 * 4 * 8
+    assert max(int(np.prod(s)) for s in shapes) <= \
+        2 * t * (heads * pd + 2 * n)
 
 
 # -- (b) the whole step against the reference -------------------------------
@@ -435,12 +450,13 @@ def test_the_stack_recomputes_its_wide_arrays_by_its_own_policy():
     _, vjp = jax.vjp(lambda p_, x_: blk(x_, p_, arch, run, 0)[0], p, x)
     shapes = [tuple(v.shape) for v in jax.tree.leaves(vjp)
               if hasattr(v, "shape")]
-    assert (2, 32, 8, 8) in shapes              # ssm_y, a head at a time
     assert (2, 4, 8, 8, 16) in shapes           # ssm_state
-    # nothing (tokens, inner) or (tokens, ff) wide (both 64 here: the gate,
-    # the gated product, the SwiGLU's three), nothing in_width wide
-    assert not [s for s in shapes if len(s) == 3 and
-                s[-1] in (64, ssm_in_width(8, 8, 16))]
+    # ssm_y, (tokens, inner) wide as the gate reads it, and nothing else
+    # that wide or (tokens, ff) wide (both 64 here: the gate, the gated
+    # product, the SwiGLU's three), nothing in_width wide
+    assert [s for s in shapes if len(s) == 3 and
+            s[-1] in (64, ssm_in_width(8, 8, 16))] == [(2, 32, 64)]
+    assert (2, 32, 8, 8) not in shapes
     # with every optional kind kept the wide arrays are residuals: the
     # input projection, the SwiGLU's two products, the convolution's sum
     full = tfm._block_fn(arch, _KEPT_IF_ROOM)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from test_lfm2_arch import _pallas_interpret
 from znicz_tpu.ops.pallas import (attention as pattn, dsa as pdsa,
-                                  grouped as pgrouped)
+                                  grouped as pgrouped, ssd as pssd)
 from znicz_tpu.parallel import plan, transformer as tfm
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel.params import param_shapes
@@ -140,11 +140,18 @@ def _tiny(family: str, wide: bool):
                 "rope_scaling": {"mrope_section": [16, 24, 24],
                                  "rope_type": "default", "type": "default"}}
     elif wide and family == "nemotron_h":
-        # experts 192 wide: over 128 lanes and not a multiple of them
+        # experts 192 wide: over 128 lanes and not a multiple of them; two
+        # groups of eight heads, a state and a chunk of 128: the scan's
+        # kernels' shape
         over = {"hidden_size": 128, "num_attention_heads": 2,
                 "num_key_value_heads": 1, "head_dim": 128,
                 "moe_intermediate_size": 192,
-                "moe_shared_expert_intermediate_size": 256}
+                "moe_shared_expert_intermediate_size": 256,
+                "mamba_num_heads": 16, "mamba_head_dim": 16,
+                "ssm_state_size": 128, "n_groups": 2, "chunk_size": 128}
+    elif wide and family == "granitemoehybrid":
+        over = {"mamba_n_heads": 8, "mamba_d_head": 16, "mamba_expand": 4,
+                "mamba_d_state": 128, "mamba_chunk_size": 128}
     elif wide and family in ("ouro", "lfm2_moe"):
         over = {"hidden_size": 256, "num_attention_heads": 2,
                 "num_key_value_heads": 2, "head_dim": 128}
@@ -231,6 +238,11 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
                        pgrouped.ROWS_T_KERNEL_NAME,
                        pgrouped.WEIGHTS_KERNEL_NAME):
             assert (kernel in text) == (chose["moe_gmm_kernel_share"] == 1.0)
+        # the scan's form
+        assert (chose["ssm_scan_kernel_share"] is None) == \
+            ("mamba" not in arch.mixers)
+        for kernel in (pssd.FWD_KERNEL_NAME, pssd.BWD_KERNEL_NAME):
+            assert (kernel in text) == (chose["ssm_scan_kernel_share"] == 1.0)
         # what a checkpointed layer keeps
         kept = chose["checkpoint_kept_bytes"]
         if arch.loop_steps > 1 or "mamba" in arch.mixers:
@@ -251,7 +263,8 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
         want |= {"attn_kvb_block_rows", "dsa_index_kernel_share",
                  "dsa_align_kernel_share"}
     if family == "granitemoehybrid":
-        want |= {"checkpoint_kept_bytes"}
+        want |= {"checkpoint_kept_bytes", "ssm_scan_kernel_share"}
     if family == "nemotron_h":
-        want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share"}
+        want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share",
+                 "ssm_scan_kernel_share"}
     assert want <= seen, (want, seen)

@@ -2,8 +2,10 @@
 and not attached, in a process of its own (a process that has loaded the
 TPU's library disturbs the profiler's tests that run after it), and print
 one JSON line: the compiled step's memory, the compiler's operation count,
-the checkpoint plan a v5e's memory limit gives and the footprint the plan
-reckoned with.  ``tests/test_checkpoint_plan.py`` runs it.
+the checkpoint plan a v5e's memory limit gives, the footprint the plan
+reckoned with and, of a stack with state-space layers, how often the scan's
+two kernels stand in the compiled step and which float32 arrays with two
+chunk-length axes do.  ``tests/test_checkpoint_plan.py`` runs it.
 
     python tests/v5e_step_compile.py CONFIG TRAFFIC [LIMIT_GIB]
 """
@@ -11,6 +13,7 @@ reckoned with.  ``tests/test_checkpoint_plan.py`` runs it.
 import json
 import math
 import os
+import re
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -45,8 +48,10 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     # described chip reports no memory: a v5e's own answers
     tfm._flash_eligible = lambda mesh, interp: True
     # ... and the grouped products' kernels wherever their shapes take them
-    from znicz_tpu.parallel import moe
+    from znicz_tpu.parallel import moe, ssm
     moe._kernels_eligible = lambda interpret: True
+    # ... and the scan's
+    ssm._kernels_eligible = lambda interpret: True
     limit = int(limit_gib * 2 ** 30)
     tfm._memory_limit = lambda mesh: limit
     opts = cfg["builders"]["lm_train_keys"]
@@ -69,11 +74,20 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     m = compiled.memory_analysis()
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    text = compiled.as_text()
     out = os.environ.get("V5E_STEP_TEXT")
     if out:
         with open(out, "w") as f:
-            f.write(compiled.as_text())
+            f.write(text)
+    from znicz_tpu.ops.pallas import ssd
+    q = arch.ssm_chunk
     return {
+        "scan_kernels": {name: len(re.findall(
+            rf'custom_call_target="tpu_custom_call"[^\n]*{name}', text))
+            for name in (ssd.FWD_KERNEL_NAME, ssd.BWD_KERNEL_NAME)},
+        "chunk_squares": sorted(set(re.findall(
+            rf"f32\[(?:\d+,)*{q},{q}\]", text))) if q else [],
+        "state_space_layers": arch.mixers.count("mamba"),
         "params": sum(math.prod(s.shape) for s in jax.tree.leaves(params)),
         "tokens": b * t,
         "argument_bytes": m.argument_size_in_bytes,

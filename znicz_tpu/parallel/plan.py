@@ -56,9 +56,10 @@ def _loop_saves(prim, *_, **params) -> bool:
     ``(tokens, ff)``, 12 % of a layer's operations).  It is also the least a
     layer of a stack with state-space layers keeps (``transformer._block_fn``):
     of such a layer the scan's output and each chunk's opening state too
-    (``ssm_y``, ``ssm_state``: the scan's forward pass is not run a second
-    time; its backward pass makes a chunk's decay and score matrices again,
-    ``parallel/ssm.py``), with the wide input projection and its split, the
+    (``ssm_y``, ``ssm_state``: the scan's forward pass, a Pallas kernel
+    where its shape allows, is not run a second time; its backward pass makes
+    a chunk's decay and score matrices again, ``parallel/ssm.py``), with the
+    wide input projection and its split, the
     convolution, the gate and the gated norm made again: a layer holds five or
     six arrays of ``(tokens, d)`` and its chunk states where it would hold
     ``(tokens, 8.5 d)`` of them.  Of a routed expert layer in such a stack it
@@ -145,7 +146,13 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
         if mixer == "mamba":
             inner = arch.ssm_heads * arch.ssm_head_dim
             chunks = -(-tokens // arch.ssm_chunk)
-            layer += act * inner + chunks * inner * arch.ssm_state * 4
+            # the scan's output and the chunks' opening states, both in the
+            # compute dtype: the scan's kernels write the states as their
+            # products read them (``ops/pallas/ssd.py``), and of the
+            # ``jax.numpy`` form's float32 ones the compiled step keeps the
+            # cast alone
+            layer += act * inner + \
+                chunks * inner * arch.ssm_state * itemsize
             wide = ssm_in_width(arch.ssm_heads, arch.ssm_head_dim,
                                 arch.ssm_state, arch.ssm_groups)
         elif mixer in ("attention", "latent"):

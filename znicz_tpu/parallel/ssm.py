@@ -41,12 +41,29 @@ is an ``exp`` of a sum that is at most 0, so none overflows.  ``Q`` changes
 no value: a row that ``Q`` does not divide is filled with positions of ``dt
 = 0`` (the state passes them unchanged).
 
-**What the backward pass keeps**: the operands and each chunk's opening
-state (``(b, t / Q, H, P, N)`` float32, named ``ssm_state``).  The two
-halves, :func:`_chunk_states` (2, 3) and :func:`_chunk_outputs` (1, 4), are
-each a ``jax.checkpoint``: the ``(H, Q, Q)`` decay and score matrices of
-every chunk (0.5 GB a layer at 8,192 positions) are made again from the
-operands when the gradients are, and never stored.  The operands themselves
+**Two forms of one algorithm** (:func:`ssd` asks one question,
+:func:`scan_kernel_refusal`, of what it can observe; no switch chooses).
+Where the step's kernels run (a TPU, or ``engine.pallas_interpret``) and the
+shape fits (``Q`` a multiple of 128, a group of whole blocks of 8 heads, 8
+heads and ``N`` whole lane tiles, a visit inside the kernels' VMEM), steps
+1 to 4 are two Pallas kernels behind a ``jax.custom_vjp``
+(``ops/pallas/ssd.py``: ``ssd_scan_fwd``, ``ssd_scan_bwd``): a visit holds
+one chunk of one block of heads, the carry runs inside the kernel over the
+chunk axis, and nothing with two chunk-length axes is written to HBM in any
+pass; only the running sums ``cs`` (and, under differentiation, their
+reverse sums) stay ``jax.numpy``.  Everywhere else, and as the tests'
+second opinion, the ``jax.numpy`` form below: :func:`_chunk_states` (2, 3)
+and :func:`_chunk_outputs` (1, 4), each a ``jax.checkpoint``.  A refusal is
+logged once a shape with its reason.
+
+**What the backward pass keeps**, in either form: the operands and each
+chunk's opening state (named ``ssm_state``: ``(b, t / Q, H, P, N)``
+float32 here, ``(b, t / Q, H x P, N)`` in the operands' dtype from the
+kernel, which writes it as its products read it).  The ``(H, Q, Q)`` decay
+and score matrices of every chunk
+(0.5 GB a layer at 8,192 positions) are made again from the operands when
+the gradients are, and never stored: by the backward kernel in VMEM, by the
+two ``jax.checkpoint`` halves through HBM.  The operands themselves
 come from the input projection and the convolution, which the layer's own
 checkpoint (``transformer.py::_block_fn``) makes again unless the device has
 room for them: the projection's result and the convolution's float32 sum are
@@ -57,12 +74,18 @@ keep or refuse.  The mixer's leaves and their shapes are ``params.py``'s
 
 from __future__ import annotations
 
+import functools
+import logging
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from znicz_tpu.observe import probe as _probe
+from znicz_tpu.ops.pallas import ssd as _pssd
+
+_log = logging.getLogger("znicz_tpu.transformer")
 
 #: the mixer's leaves that stay in the master dtype in a step's forward:
 #: the step size's bias, the decay rate and the skip enter float32 chains
@@ -158,6 +181,53 @@ def _chunk_outputs(x, dt, a, bm, cm, skip, opening):
     return (y + skip[:, None] * xf).astype(x.dtype)
 
 
+def _kernels_eligible(interpret: bool) -> bool:
+    """Whether the Pallas kernels may run at all: on a TPU, or interpreted."""
+    return interpret or jax.default_backend() == "tpu"
+
+
+def scan_kernel_refusal(t: int, heads: int, head_dim: int, state: int,
+                        groups: int, chunk: int, itemsize: int,
+                        interpret: bool) -> str | None:
+    """Why the scan of rows of ``t`` positions in chunks of ``chunk`` (the
+    whole row where it is shorter) runs in its ``jax.numpy`` form, or
+    ``None`` where the kernels of ``ops/pallas/ssd.py`` run it: where the
+    step's kernels run at all (a TPU, or ``interpret``: interpreted) and
+    the shape is one they take (``ssd.unsupported_reason``: the kernel's own
+    reasons).  What :func:`ssd` asks as the step is traced and
+    ``transformer.step_choices`` before."""
+    if not _kernels_eligible(interpret):
+        return (f"the backend is {jax.default_backend()} and the step's "
+                f"kernels are not interpreted")
+    return _pssd.unsupported_reason(min(int(chunk), t), heads, groups,
+                                    head_dim, state, itemsize)
+
+
+@functools.lru_cache(maxsize=None)
+def _report_refusal(shape: tuple, why: str, level: int) -> None:
+    """Say once a shape and a process that the scan left its kernels."""
+    _log.log(level, "state-space scan kernels refused t=%d heads=%d "
+             "head_dim=%d state=%d groups=%d chunk=%d: %s; this layer "
+             "scans in jax.numpy", *shape, why)
+
+
+def _scan_kernels(*shape, itemsize: int):
+    """THE choice between the two forms of the scan, by what can be
+    observed (:func:`scan_kernel_refusal`, of ``shape``: its arguments up
+    to the chunk) -> ``None`` for ``jax.numpy``, else the kernels'
+    ``interpret`` argument."""
+    from znicz_tpu.core.config import root
+    interpret = bool(root.common.engine.get("pallas_interpret", False))
+    why = scan_kernel_refusal(*shape, itemsize, interpret)
+    if why:
+        # a shape the kernels turn down where they could run is news; a
+        # backend without them is not
+        _report_refusal(shape, why, logging.WARNING
+                        if _kernels_eligible(interpret) else logging.INFO)
+        return None
+    return interpret
+
+
 def ssd(x, dt, a, bm, cm, skip, chunk: int):
     """The scan: ``x (b, t, H, P)``, ``dt (b, t, H)`` float32 (after the
     softplus), ``a (H,)`` float32 (negative), ``bm``, ``cm`` ``(b, t, N)``
@@ -165,13 +235,27 @@ def ssd(x, dt, a, bm, cm, skip, chunk: int):
     order), ``skip (H,)`` float32 -> ``(y (b, t, H, P) in x's dtype, the
     state behind the last position (b, H, P, N) float32)``, in chunks of
     ``chunk`` positions (the whole row where it is shorter)."""
-    b, t = x.shape[:2]
+    b, t, heads, p = x.shape
+    groups = bm.shape[2] if bm.ndim == 4 else 1
     q = min(int(chunk), t)
     fill = -t % q
     if fill:
         x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, fill)) +
                                  ((0, 0),) * (v.ndim - 2))
                          for v in (x, dt, bm, cm))
+    interpret = _scan_kernels(t, heads, p, bm.shape[-1], groups, int(chunk),
+                              itemsize=x.dtype.itemsize)
+    if interpret is not None:
+        cs = jnp.cumsum(_chunked(dt * a, q), axis=2).reshape(dt.shape)
+        # x | B | C side by side, as the mixer cut them from the
+        # convolution's result: XLA folds the cuts and this back into that
+        # array, and the kernels cut their blocks from its lanes
+        xbc = jnp.concatenate([v.reshape(b, t + fill, -1)
+                               for v in (x, bm, cm)], axis=-1)
+        y, last = _pssd.scan(xbc, dt, cs, skip, heads * p, groups, q,
+                             interpret)
+        return (y.reshape(x.shape)[:, :t],
+                last.reshape(b, heads, p, bm.shape[-1]))
     x, bm, cm = (_chunked(v, q) for v in (x, bm, cm))
     opening, last = _chunk_states(x, dt, a, bm)
     opening = checkpoint_name(opening, "ssm_state")
@@ -220,19 +304,24 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
             bm, cm = (v.reshape(b, t, groups, state) for v in (bm, cm))
         y, last = ssd(x, dt, a, bm, cm, p["ssm_d"].astype(jnp.float32),
                       chunk)
-        y = checkpoint_name(y, "ssm_y")
+        # named (b, t, inner) wide, as the gate reads it: a saved array
+        # whose last axis is one head is laid out time-minor, and then
+        # copied on either side of the scan's kernels
+        y = checkpoint_name(y.reshape(b, t, inner), "ssm_y")
         last = lax.stop_gradient(last)
         stats = {"ssm_decay": lax.stop_gradient(jnp.exp(dt * a)).mean(),
                  "ssm_state_rms":
                      jnp.sqrt((last * last).mean((1, 2, 3))).mean(),
                  "ssm_layers": jnp.ones((), jnp.float32)}
     with _probe.scope(scope):
-        gated = y.reshape(b, t, inner).astype(jnp.float32) * \
-            jax.nn.silu(z.astype(jnp.float32))
+        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
         # the statistic over each group's entries (the gain lies a group a
-        # row, ``params._ssm_leaf_shapes``); over all of them with one group
-        gated = _heads(gated, groups, 2)
+        # row, ``params._ssm_leaf_shapes``); over all of them with one
+        # group.  A row a (token, group): a statistic ``(b, t, groups)``
+        # wide is laid out time-minor and takes the gated product with it
+        gated = gated.reshape(b * t * groups, inner // groups)
         gated = gated * lax.rsqrt((gated * gated).mean(-1, keepdims=True)
                                   + eps)
-        return _flat_heads(gated.astype(u.dtype) * p["ssm_g"], groups,
-                           2) @ p["ssm_out"], stats
+        gated = gated.astype(u.dtype).reshape(b, t, groups, -1) * \
+            p["ssm_g"].reshape(groups, -1)
+        return gated.reshape(b, t, inner) @ p["ssm_out"], stats

@@ -463,7 +463,11 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
       grouped products the kernels of ``ops/pallas/grouped.py`` make (all or
       none, in the compact and the full pairs buffer alike:
       ``moe.gmm_kernel_refusal``; the rest ``lax.ragged_dot``); None
-      without a routed layer."""
+      without a routed layer;
+    - ``ssm_scan_kernel_share``: of the state-space layers, the share whose
+      scan the kernels of ``ops/pallas/ssd.py`` run (all or none:
+      ``ssm.scan_kernel_refusal``; the rest ``ssm.py``'s ``jax.numpy``
+      form); None without a state-space layer."""
     from znicz_tpu.ops.pallas import attention as pattn
     run = _run_of(mesh, arch, arch.vocab if head_sharded else None)
     b_loc = batch // mesh.shape.get("data", 1)
@@ -487,6 +491,13 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
             _default_compute_dtype(), run.interpret)
             for r in {pairs, compact_rows(pairs, arch.experts_held,
                                           arch.n_experts)}))
+    scan = None
+    if "mamba" in arch.mixers:
+        scan = float(ssm.scan_kernel_refusal(
+            t_loc, arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state,
+            arch.ssm_groups, arch.ssm_chunk,
+            jnp.dtype(_default_compute_dtype()).itemsize,
+            run.interpret) is None)
     return {
         "ce_grad_in_forward_share": float(ce_grad_in_forward(
             loss_chunks, head_sharded, arch.loop_steps > 1)),
@@ -494,7 +505,7 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         "checkpoint_kept_bytes": checkpoint_plan(*_plan_of(
             arch, run, b_loc * t_loc, _default_compute_dtype(), loss_chunks)),
         "dsa_index_kernel_share": index, "dsa_align_kernel_share": align,
-        "moe_gmm_kernel_share": gmm}
+        "moe_gmm_kernel_share": gmm, "ssm_scan_kernel_share": scan}
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

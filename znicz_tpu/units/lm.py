@@ -112,6 +112,13 @@ def _choice_gauges() -> tuple:
             "routed expert layers (the rest: lax.ragged_dot, which the "
             "shape or the platform left them to)",
             ("unit",)), None),
+        ("ssm_scan_kernel_share", registry.gauge(
+            "znicz_lm_ssm_scan_kernel_share",
+            "state-space layers whose chunked scan the Pallas kernels "
+            "ssd_scan_fwd and ssd_scan_bwd run, a chunk's decay and score "
+            "matrices in VMEM, over the state-space layers (the rest: the "
+            "jax.numpy form, which the shape or the platform left them to)",
+            ("unit",)), None),
     )
 
 
@@ -256,6 +263,9 @@ class TransformerLMStep(AcceleratedUnit):
         #: of the routed expert layers, the share whose grouped products
         #: the Pallas kernels make; None without one
         self.moe_gmm_kernel_share: Optional[float] = None
+        #: of the state-space layers, the share whose scan the Pallas
+        #: kernels run; None without one
+        self.ssm_scan_kernel_share: Optional[float] = None
         #: ``{name: bytes}`` the checkpointed layers keep beside their
         #: policy's own list (``parallel/plan.py::checkpoint_plan``)
         self.checkpoint_kept_bytes: dict = {}
