@@ -48,16 +48,16 @@ def _compiled_or_skip(config: str, traffic: str) -> dict:
 
 @pytest.mark.parametrize("config,traffic,params,kept,tflop", [
     ("granite_4_0_h_micro", "train_tokens_pp4_t8192", 951_991_232,
-     {"glu_wide": 10 * 2 * 8192 * 8192 * 2, "ssm_in": 9 * 8192 * 8512 * 2,
-      "ssm_conv_sum": 0}, (37.5, 38.6)),
+     {"glu_wide": 10 * 2 * 8192 * 8192 * 2, "ssm_in": 9 * 8192 * 8512 * 2},
+     (37.5, 38.6)),
     ("ouro_2_6b", "train_tokens_pp8_t4096", 509_661_185,
      {"glu_wide": 0}, (17.5, 18.7)),
     # a stack of one-sub-layer layers (M, E, *): the shared experts' wide
     # products count with the SwiGLUs', an E layer keeps its up-projections
     # and holds its experts' cast and gradients while its backward runs
     ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 986_254_848,
-     {"glu_wide": 4 * 16384 * 3712 * 2, "ssm_in": 4 * 16384 * 10304 * 2,
-      "ssm_conv_sum": 0}, (26.0, 28.2)),
+     {"glu_wide": 4 * 16384 * 3712 * 2, "ssm_in": 4 * 16384 * 10304 * 2},
+     (26.0, 28.2)),
 ])
 def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
         config, traffic, params, kept, tflop):
@@ -95,11 +95,8 @@ def test_both_state_space_cells_hold_the_scan_kernels_and_no_chunk_square(
     """The same compiled steps (what a v5e would answer: the scan's kernels
     wherever their shapes take them, ``ops/pallas/ssd.py``): every
     state-space layer holds the forward kernel once (a checkpointed layer
-    does not run it again) and the backward kernel once, no float32 array
-    with two chunk-length axes is left among the step's arrays, and
-    arguments plus temporaries are not above the 12.68 GiB the parent's step
-    counted with the scan in ``jax.numpy`` (``PERF.md`` section 5, PRs 43
-    and 45)."""
+    does not run it again) and the backward kernel once, and no float32
+    array with two chunk-length axes is left among the step's arrays."""
     from znicz_tpu.ops.pallas import ssd
 
     out = _compiled_or_skip(config, traffic)
@@ -107,5 +104,29 @@ def test_both_state_space_cells_hold_the_scan_kernels_and_no_chunk_square(
     assert out["scan_kernels"] == {ssd.FWD_KERNEL_NAME: layers,
                                    ssd.BWD_KERNEL_NAME: layers}
     assert out["chunk_squares"] == []
+
+
+@pytest.mark.parametrize("config,traffic,layers,most_gib", [
+    ("granite_4_0_h_micro", "train_tokens_pp4_t8192", 9, 12.95),
+    ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 4, 12.45),
+])
+def test_both_state_space_cells_hold_the_convolution_kernels_and_no_wide_sum(
+        config, traffic, layers, most_gib):
+    """The same compiled steps hold the convolution's kernels
+    (``ops/pallas/ssm_conv.py``) as they hold the scan's: the forward
+    kernel once a state-space layer (its result is kept: a checkpointed
+    layer does not run it again) and the backward kernel once; no
+    instruction of the step writes a float32 array as long as a row and as
+    wide as the convolution's channels (the ``jax.numpy`` form's sum, its
+    padded operand, its shifted cotangents); and arguments plus temporaries
+    stand over the 12.55 / 11.64 GiB the steps counted before the kernels
+    (``PERF.md`` section 5, PR 46) by no more than the kept results, 0.598
+    and 0.750 GiB."""
+    from znicz_tpu.ops.pallas import ssm_conv
+
+    out = _compiled_or_skip(config, traffic)
+    assert out["conv_kernels"] == {ssm_conv.FWD_KERNEL_NAME: layers,
+                                   ssm_conv.BWD_KERNEL_NAME: layers}
+    assert out["conv_wide_f32"] == []
     live = out["argument_bytes"] + out["temp_bytes"]
-    assert live <= 12.685 * GIB, live / GIB
+    assert live <= most_gib * GIB, live / GIB

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from test_lfm2_arch import _pallas_interpret
 from znicz_tpu.ops.pallas import (attention as pattn, dsa as pdsa,
-                                  grouped as pgrouped, ssd as pssd)
+                                  grouped as pgrouped, ssd as pssd,
+                                  ssm_conv as pconv)
 from znicz_tpu.parallel import plan, transformer as tfm
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel.params import param_shapes
@@ -243,6 +244,11 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
             ("mamba" not in arch.mixers)
         for kernel in (pssd.FWD_KERNEL_NAME, pssd.BWD_KERNEL_NAME):
             assert (kernel in text) == (chose["ssm_scan_kernel_share"] == 1.0)
+        # the convolution's form
+        assert (chose["ssm_conv_kernel_share"] is None) == \
+            ("mamba" not in arch.mixers)
+        for kernel in (pconv.FWD_KERNEL_NAME, pconv.BWD_KERNEL_NAME):
+            assert (kernel in text) == (chose["ssm_conv_kernel_share"] == 1.0)
         # what a checkpointed layer keeps
         kept = chose["checkpoint_kept_bytes"]
         if arch.loop_steps > 1 or "mamba" in arch.mixers:
@@ -263,8 +269,9 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
         want |= {"attn_kvb_block_rows", "dsa_index_kernel_share",
                  "dsa_align_kernel_share"}
     if family == "granitemoehybrid":
-        want |= {"checkpoint_kept_bytes", "ssm_scan_kernel_share"}
+        want |= {"checkpoint_kept_bytes", "ssm_scan_kernel_share",
+                 "ssm_conv_kernel_share"}
     if family == "nemotron_h":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share",
-                 "ssm_scan_kernel_share"}
+                 "ssm_scan_kernel_share", "ssm_conv_kernel_share"}
     assert want <= seen, (want, seen)

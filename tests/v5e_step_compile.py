@@ -4,8 +4,9 @@ TPU's library disturbs the profiler's tests that run after it), and print
 one JSON line: the compiled step's memory, the compiler's operation count,
 the checkpoint plan a v5e's memory limit gives, the footprint the plan
 reckoned with and, of a stack with state-space layers, how often the scan's
-two kernels stand in the compiled step and which float32 arrays with two
-chunk-length axes do.  ``tests/test_checkpoint_plan.py`` runs it.
+and the convolution's two kernels each stand in the compiled step, which
+float32 arrays with two chunk-length axes do and which float32 arrays as
+long as the tokens and as wide as the convolution's channels.  ``tests/test_checkpoint_plan.py`` runs it.
 
     python tests/v5e_step_compile.py CONFIG TRAFFIC [LIMIT_GIB]
 """
@@ -20,6 +21,21 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+
+
+def _written(text: str, shape: str) -> list:
+    """The arrays of ``shape`` (a pattern) that instructions outside fused
+    computations write: what crosses HBM (the convolution's float32 sum,
+    its padded operand, a shifted cotangent)."""
+    found, fused = set(), False
+    for line in text.splitlines():
+        if line.endswith("{") and " -> " in line:
+            fused = "fused_computation" in line.split("(", 1)[0]
+        elif not fused:
+            m = re.match(rf"\s*(?:ROOT )?%?[\w.\-]+ = \(?({shape})", line)
+            if m:
+                found.add(m.group(1))
+    return sorted(found)
 
 
 def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
@@ -79,14 +95,26 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     if out:
         with open(out, "w") as f:
             f.write(text)
-    from znicz_tpu.ops.pallas import ssd
+    from znicz_tpu.ops.pallas import ssd, ssm_conv
     q = arch.ssm_chunk
-    return {
-        "scan_kernels": {name: len(re.findall(
+    channels = arch.ssm_heads * arch.ssm_head_dim + \
+        2 * arch.ssm_groups * arch.ssm_state
+
+    def stands(*names):
+        return {name: len(re.findall(
             rf'custom_call_target="tpu_custom_call"[^\n]*{name}', text))
-            for name in (ssd.FWD_KERNEL_NAME, ssd.BWD_KERNEL_NAME)},
+            for name in names}
+
+    return {
+        "scan_kernels": stands(ssd.FWD_KERNEL_NAME, ssd.BWD_KERNEL_NAME),
+        "conv_kernels": stands(ssm_conv.FWD_KERNEL_NAME,
+                               ssm_conv.BWD_KERNEL_NAME),
         "chunk_squares": sorted(set(re.findall(
             rf"f32\[(?:\d+,)*{q},{q}\]", text))) if q else [],
+        # ... as long as a row (its digits, so the padded ones too)
+        "conv_wide_f32": _written(
+            text, rf"f32\[\d+,\d{{{len(str(t))},}},{channels}\]")
+        if channels else [],
         "state_space_layers": arch.mixers.count("mamba"),
         "params": sum(math.prod(s.shape) for s in jax.tree.leaves(params)),
         "tokens": b * t,

@@ -24,17 +24,16 @@ _log = logging.getLogger("znicz_tpu.transformer")
 
 
 #: what a checkpointed layer keeps whatever the memory (:func:`_loop_saves`)
-_KEPT_ALWAYS = ("attn_qkv", "sub_out", "ssm_y", "ssm_state", "moe_route",
-                "moe_up")
+_KEPT_ALWAYS = ("attn_qkv", "sub_out", "ssm_y", "ssm_state", "ssm_conv",
+                "moe_route", "moe_up")
 
 #: what it keeps beside them where the device has room for all the layers'
 #: (:func:`checkpoint_plan`), in the order of time saved a byte kept: a
 #: feed-forward unit's wide products (a SwiGLU's two; of a shared expert
 #: beside routed ones its form's: two, or a squared-ReLU unit's one) and a
 #: state-space layer's input projection (a product made again costs about
-#: 12 ms a GiB of its result on a v5e), then the convolution's float32 sum
-#: (elementwise: about 10 ms a GiB)
-_KEPT_IF_ROOM = ("glu_wide", "ssm_in", "ssm_conv_sum")
+#: 12 ms a GiB of its result on a v5e)
+_KEPT_IF_ROOM = ("glu_wide", "ssm_in")
 
 #: bytes :func:`checkpoint_plan` leaves free beside the step's reckoned
 #: footprint and what it keeps: what :func:`step_footprint` may stand under
@@ -58,12 +57,13 @@ def _loop_saves(prim, *_, **params) -> bool:
     of such a layer the scan's output and each chunk's opening state too
     (``ssm_y``, ``ssm_state``: the scan's forward pass, a Pallas kernel
     where its shape allows, is not run a second time; its backward pass makes
-    a chunk's decay and score matrices again, ``parallel/ssm.py``), with the
-    wide input projection and its split, the
-    convolution, the gate and the gated norm made again: a layer holds five or
-    six arrays of ``(tokens, d)`` and its chunk states where it would hold
-    ``(tokens, 8.5 d)`` of them.  Of a routed expert layer in such a stack it
-    keeps the router's choice (``moe_route``: the weights, the sort and its
+    a chunk's decay and score matrices again, ``parallel/ssm.py``) and, where
+    the convolution's kernels run, their result (``ssm_conv``, the scan's
+    ``x | B | C``), with the wide input projection and its split, the
+    ``jax.numpy`` convolution, the gate and the gated norm made again: a
+    layer holds five or six arrays of ``(tokens, d)``, the scan's operand
+    and its chunk states where it would hold ``(tokens, 8.5 d)`` of them.
+    Of a routed expert layer in such a stack it keeps the router's choice (``moe_route``: the weights, the sort and its
     inverse, the group sizes; small, and no sort runs twice) and the experts'
     up-projections' results (``moe_up``: a kernel's output that leaves the
     pairs stage's ``cond``, which ``pallas_call`` alone would not name); the
@@ -114,9 +114,9 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
       compute dtype; every other leaf's update runs as its gradient lands;
     - what :func:`_loop_saves` keeps of every layer application (the
       layer's input, ``sub_out`` of each sub-layer, q, k, v and the kernel's
-      output and rows of an attention layer, ``ssm_y`` and ``ssm_state`` of
-      a state-space layer, ``moe_up`` of a routed one), and a looped stack's
-      outputs;
+      output and rows of an attention layer, ``ssm_y``, ``ssm_state`` and
+      ``ssm_conv`` of a state-space layer, ``moe_up`` of a routed one), and
+      a looped stack's outputs;
     - one layer's backward pass at work: six arrays of its widest
       activation in the compute dtype (a SwiGLU's two products, their
       gated product and the three gradients), of a routed layer the held
@@ -150,9 +150,12 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
             # compute dtype: the scan's kernels write the states as their
             # products read them (``ops/pallas/ssd.py``), and of the
             # ``jax.numpy`` form's float32 ones the compiled step keeps the
-            # cast alone
+            # cast alone; and the convolution's result, the scan's ``x | B |
+            # C``, which its kernel writes (``ops/pallas/ssm_conv.py``; the
+            # ``jax.numpy`` form's is made again, and then this counts high)
             layer += act * inner + \
-                chunks * inner * arch.ssm_state * itemsize
+                chunks * inner * arch.ssm_state * itemsize + \
+                act * (inner + 2 * arch.ssm_groups * arch.ssm_state)
             wide = ssm_in_width(arch.ssm_heads, arch.ssm_head_dim,
                                 arch.ssm_state, arch.ssm_groups)
         elif mixer in ("attention", "latent"):
@@ -189,23 +192,19 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
 def _kind_bytes(arch: Arch, tokens: int, itemsize: int) -> dict:
     """``{name: bytes}`` all the layers of ``arch`` hold of each optional
     kind of :data:`_KEPT_IF_ROOM` when a step of ``tokens`` local tokens
-    keeps it: ``tokens x width x itemsize x layers that have it`` (the
-    convolution's sum is float32 whatever the compute dtype; a shared
+    keeps it: ``tokens x width x itemsize x layers that have it`` (a shared
     expert's wide products, two of a gated unit and one of a plain one,
     count with the SwiGLUs')."""
     glu = sum(f == "glu" for f in arch.ffns)
     mamba = sum(m == "mamba" for m in arch.mixers)
     shared = arch.shared_ff * arch.ffns.count("moe_routed")
     gated = arch.expert_form == "glu"
-    inner = arch.ssm_heads * arch.ssm_head_dim
-    bc = 2 * arch.ssm_groups * arch.ssm_state
     return {
         "glu_wide": tokens * itemsize * (2 * arch.ff * glu +
                                          (1 + gated) * shared),
         "ssm_in": tokens * itemsize * mamba * ssm_in_width(
             arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state,
-            arch.ssm_groups),
-        "ssm_conv_sum": tokens * (inner + bc) * 4 * mamba}
+            arch.ssm_groups)}
 
 
 def checkpoint_plan(arch: Arch, tokens: int, itemsize: int,

@@ -56,6 +56,20 @@ second opinion, the ``jax.numpy`` form below: :func:`_chunk_states` (2, 3)
 and :func:`_chunk_outputs` (1, 4), each a ``jax.checkpoint``.  A refusal is
 logged once a shape with its reason.
 
+**The convolution** in front of the scan has two forms too, chosen the
+same way (:func:`conv_kernel_refusal`): where the kernels run and the cut
+of the projection's lanes is whole lane tiles, ``t`` whole row tiles and the
+taps within the rows fetched in front of a tile, two Pallas kernels behind a
+``jax.custom_vjp`` (``ops/pallas/ssm_conv.py``: ``ssm_conv_fwd``,
+``ssm_conv_bwd``) read the ``xBC`` lanes of the projection itself, do the
+float32 sum, the bias, the ``silu`` and the cast in VMEM and write the ``x |
+B | C`` array the scan's kernels cut their blocks from; the backward kernel
+makes the sum and the sigmoid again and sums the taps' and the bias's
+gradients in VMEM.  Where they run, ``[z | xBC]`` and ``dt`` come from two
+products, so that the array the kernels cut is whole lane tiles wide (the
+64 lanes of ``dt`` behind it make every reader of it slow on a TPU).
+Everywhere else :func:`_conv`, the ``jax.numpy`` form, on one product.
+
 **What the backward pass keeps**, in either form: the operands and each
 chunk's opening state (named ``ssm_state``: ``(b, t / Q, H, P, N)``
 float32 here, ``(b, t / Q, H x P, N)`` in the operands' dtype from the
@@ -64,11 +78,14 @@ and score matrices of every chunk
 (0.5 GB a layer at 8,192 positions) are made again from the operands when
 the gradients are, and never stored: by the backward kernel in VMEM, by the
 two ``jax.checkpoint`` halves through HBM.  The operands themselves
-come from the input projection and the convolution, which the layer's own
-checkpoint (``transformer.py::_block_fn``) makes again unless the device has
-room for them: the projection's result and the convolution's float32 sum are
-named here (``ssm_in``, ``ssm_conv_sum``) for ``plan.py::checkpoint_plan`` to
-keep or refuse.  The mixer's leaves and their shapes are ``params.py``'s
+come from the input projection and the convolution.  The convolution's
+kernel names its result (``ssm_conv``, the scan's ``x | B | C``, in the
+compute dtype) and the layer's own checkpoint (``transformer.py::
+_block_fn``) keeps it as it keeps every kernel's, so that kernel runs once;
+the ``jax.numpy`` form is made again.  The projection's result is named
+here (``ssm_in``) for ``plan.py::checkpoint_plan`` to keep or refuse; where
+it is refused the product is made again and both convolution kernels read
+that.  The mixer's leaves and their shapes are ``params.py``'s
 (``_ssm_leaf_shapes``).
 """
 
@@ -83,7 +100,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from znicz_tpu.observe import probe as _probe
-from znicz_tpu.ops.pallas import ssd as _pssd
+from znicz_tpu.ops.pallas import ssd as _pssd, ssm_conv as _pconv
 
 _log = logging.getLogger("znicz_tpu.transformer")
 
@@ -203,26 +220,42 @@ def scan_kernel_refusal(t: int, heads: int, head_dim: int, state: int,
                                     head_dim, state, itemsize)
 
 
+def conv_kernel_refusal(t: int, start: int, width: int, taps: int,
+                        interpret: bool) -> str | None:
+    """Why the convolution of the lanes ``[start, start + width)`` of the
+    projection over rows of ``t`` positions with ``taps`` taps runs in its
+    ``jax.numpy`` form (:func:`_conv`), or ``None`` where the kernels of
+    ``ops/pallas/ssm_conv.py`` run it: where the step's kernels run at all
+    and the shape is one they take (``ssm_conv.unsupported_reason``).  What
+    :func:`mixer` asks as the step is traced and ``transformer.
+    step_choices`` before."""
+    if not _kernels_eligible(interpret):
+        return (f"the backend is {jax.default_backend()} and the step's "
+                f"kernels are not interpreted")
+    return _pconv.unsupported_reason(t, start, width, taps)
+
+
 @functools.lru_cache(maxsize=None)
-def _report_refusal(shape: tuple, why: str, level: int) -> None:
-    """Say once a shape and a process that the scan left its kernels."""
-    _log.log(level, "state-space scan kernels refused t=%d heads=%d "
-             "head_dim=%d state=%d groups=%d chunk=%d: %s; this layer "
-             "scans in jax.numpy", *shape, why)
+def _report_refusal(what: str, shape: str, why: str, level: int) -> None:
+    """Say once a shape and a process that the layer's ``what`` left its
+    kernels."""
+    _log.log(level, "state-space %s kernels refused %s: %s; this layer's "
+             "%s runs in jax.numpy", what, shape, why, what)
 
 
-def _scan_kernels(*shape, itemsize: int):
-    """THE choice between the two forms of the scan, by what can be
-    observed (:func:`scan_kernel_refusal`, of ``shape``: its arguments up
-    to the chunk) -> ``None`` for ``jax.numpy``, else the kernels'
+def _kernels_or_none(what: str, refusal, names: str, shape: tuple, **more):
+    """THE choice between the two forms of the layer's ``what``, by what
+    can be observed (``refusal`` of ``shape``, whose entries ``names``
+    names, and ``more``) -> ``None`` for ``jax.numpy``, else the kernels'
     ``interpret`` argument."""
     from znicz_tpu.core.config import root
     interpret = bool(root.common.engine.get("pallas_interpret", False))
-    why = scan_kernel_refusal(*shape, itemsize, interpret)
+    why = refusal(*shape, **more, interpret=interpret)
     if why:
         # a shape the kernels turn down where they could run is news; a
         # backend without them is not
-        _report_refusal(shape, why, logging.WARNING
+        said = " ".join(f"{k}={v}" for k, v in zip(names.split(), shape))
+        _report_refusal(what, said, why, logging.WARNING
                         if _kernels_eligible(interpret) else logging.INFO)
         return None
     return interpret
@@ -243,8 +276,10 @@ def ssd(x, dt, a, bm, cm, skip, chunk: int):
         x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, fill)) +
                                  ((0, 0),) * (v.ndim - 2))
                          for v in (x, dt, bm, cm))
-    interpret = _scan_kernels(t, heads, p, bm.shape[-1], groups, int(chunk),
-                              itemsize=x.dtype.itemsize)
+    interpret = _kernels_or_none(
+        "scan", scan_kernel_refusal, "t heads head_dim state groups chunk",
+        (t, heads, p, bm.shape[-1], groups, int(chunk)),
+        itemsize=x.dtype.itemsize)
     if interpret is not None:
         cs = jnp.cumsum(_chunked(dt * a, q), axis=2).reshape(dt.shape)
         # x | B | C side by side, as the mixer cut them from the
@@ -273,6 +308,20 @@ def _conv(v, taps, bias):
         bias.astype(jnp.float32)
 
 
+def _conv_silu(proj, taps, bias, start: int, interpret):
+    """``silu(conv(proj[..., start:start + channels]) + bias)`` in ``proj``'s
+    dtype, ``channels`` the taps' ``(taps, channels)``: the scan's ``x | B |
+    C``, by the kernels of ``ops/pallas/ssm_conv.py`` (``interpret``: what
+    :func:`conv_kernel_refusal` left of them), by :func:`_conv` where that
+    is None."""
+    if interpret is None:
+        cut = proj[..., start:start + taps.shape[1]]
+        return jax.nn.silu(_conv(cut, taps, bias)).astype(proj.dtype)
+    coef = jnp.concatenate([taps.astype(jnp.float32),
+                            bias.astype(jnp.float32)[None]], axis=0)
+    return _pconv.conv(proj, coef, start, interpret)
+
+
 def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
           eps: float, scope: str, groups: int = 1):
     """The layer on the normed stream ``u (b, t, d)`` -> ``(out (b, t, d),
@@ -286,14 +335,29 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
     depends on ``chunk``."""
     b, t, _ = u.shape
     inner, bc = heads * head_dim, groups * state
+    conv_kernels = _kernels_or_none(
+        "convolution", conv_kernel_refusal, "t start width taps",
+        (t, inner, inner + 2 * bc, p["ssm_conv_k"].shape[0]))
     with _probe.scope(scope):
-        proj = checkpoint_name(u @ p["ssm_in"], "ssm_in")
-        z, xbc = proj[..., :inner], proj[..., inner:2 * inner + 2 * bc]
-        dt = proj[..., 2 * inner + 2 * bc:]
+        w_in, wide = p["ssm_in"], 2 * inner + 2 * bc
+        if conv_kernels is None:
+            proj = checkpoint_name(u @ w_in, "ssm_in")
+            dt = proj[..., wide:]
+        else:
+            # z | xBC, whole lane tiles wide, and dt by a product of its
+            # own: the convolution's kernels cut their blocks from the
+            # projection's own lanes, and an array whose last axis is no
+            # multiple of 128 (8,512 and 10,304 in the two cells: 66.5 and
+            # 80.5 tiles) is read at 0.4 of the rate on a v5e, by a
+            # kernel's block fetch and by XLA's own slices alike (0.70
+            # against 0.29 ms for 4,352 lanes of 8,192 rows; my chip run,
+            # PR 47)
+            proj = checkpoint_name(u @ w_in[:, :wide], "ssm_in")
+            dt = checkpoint_name(u @ w_in[:, wide:], "ssm_in")
+        z = proj[..., :inner]
     with _probe.scope(f"{scope}.conv"):
-        xbc = jax.nn.silu(checkpoint_name(
-            _conv(xbc, p["ssm_conv_k"], p["ssm_conv_b"]), "ssm_conv_sum")
-                          ).astype(u.dtype)
+        xbc = _conv_silu(proj, p["ssm_conv_k"], p["ssm_conv_b"], inner,
+                         conv_kernels)
     with _probe.scope(f"{scope}.scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) +
                              p["ssm_dt_b"].astype(jnp.float32))

@@ -467,7 +467,11 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
     - ``ssm_scan_kernel_share``: of the state-space layers, the share whose
       scan the kernels of ``ops/pallas/ssd.py`` run (all or none:
       ``ssm.scan_kernel_refusal``; the rest ``ssm.py``'s ``jax.numpy``
-      form); None without a state-space layer."""
+      form); None without a state-space layer;
+    - ``ssm_conv_kernel_share``: of the state-space layers, the share whose
+      convolution, bias and ``silu`` the kernels of ``ops/pallas/
+      ssm_conv.py`` run (all or none: ``ssm.conv_kernel_refusal``; the rest
+      ``ssm._conv``); None without a state-space layer."""
     from znicz_tpu.ops.pallas import attention as pattn
     run = _run_of(mesh, arch, arch.vocab if head_sharded else None)
     b_loc = batch // mesh.shape.get("data", 1)
@@ -491,8 +495,12 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
             _default_compute_dtype(), run.interpret)
             for r in {pairs, compact_rows(pairs, arch.experts_held,
                                           arch.n_experts)}))
-    scan = None
+    scan = conv = None
     if "mamba" in arch.mixers:
+        inner = arch.ssm_heads * arch.ssm_head_dim
+        conv = float(ssm.conv_kernel_refusal(
+            t_loc, inner, inner + 2 * arch.ssm_groups * arch.ssm_state,
+            arch.conv_taps, run.interpret) is None)
         scan = float(ssm.scan_kernel_refusal(
             t_loc, arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state,
             arch.ssm_groups, arch.ssm_chunk,
@@ -505,7 +513,8 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         "checkpoint_kept_bytes": checkpoint_plan(*_plan_of(
             arch, run, b_loc * t_loc, _default_compute_dtype(), loss_chunks)),
         "dsa_index_kernel_share": index, "dsa_align_kernel_share": align,
-        "moe_gmm_kernel_share": gmm, "ssm_scan_kernel_share": scan}
+        "moe_gmm_kernel_share": gmm, "ssm_scan_kernel_share": scan,
+        "ssm_conv_kernel_share": conv}
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

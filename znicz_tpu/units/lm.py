@@ -86,9 +86,8 @@ def _choice_gauges() -> tuple:
             "znicz_lm_checkpoint_kept_bytes",
             "bytes all the step's checkpointed layers keep of an optional "
             "kind of activation for the backward pass (the SwiGLU's two "
-            "wide products, a state-space layer's input projection, its "
-            "convolution's float32 sum), 0 for a kind the device's memory "
-            "refused",
+            "wide products, a state-space layer's input projection), 0 for "
+            "a kind the device's memory refused",
             ("unit", "name")), "name"),
         ("dsa_align_kernel_share", registry.gauge(
             "znicz_lm_dsa_align_kernel_share",
@@ -118,6 +117,14 @@ def _choice_gauges() -> tuple:
             "ssd_scan_fwd and ssd_scan_bwd run, a chunk's decay and score "
             "matrices in VMEM, over the state-space layers (the rest: the "
             "jax.numpy form, which the shape or the platform left them to)",
+            ("unit",)), None),
+        ("ssm_conv_kernel_share", registry.gauge(
+            "znicz_lm_ssm_conv_kernel_share",
+            "state-space layers whose causal convolution, bias and silu the "
+            "Pallas kernels ssm_conv_fwd and ssm_conv_bwd run on the input "
+            "projection's own lanes, the float32 sum in VMEM, over the "
+            "state-space layers (the rest: the jax.numpy form, which the "
+            "shape or the platform left them to)",
             ("unit",)), None),
     )
 
@@ -263,9 +270,10 @@ class TransformerLMStep(AcceleratedUnit):
         #: of the routed expert layers, the share whose grouped products
         #: the Pallas kernels make; None without one
         self.moe_gmm_kernel_share: Optional[float] = None
-        #: of the state-space layers, the share whose scan the Pallas
-        #: kernels run; None without one
+        #: of the state-space layers, the share whose scan, and whose
+        #: convolution, the Pallas kernels run; None without one
         self.ssm_scan_kernel_share: Optional[float] = None
+        self.ssm_conv_kernel_share: Optional[float] = None
         #: ``{name: bytes}`` the checkpointed layers keep beside their
         #: policy's own list (``parallel/plan.py::checkpoint_plan``)
         self.checkpoint_kept_bytes: dict = {}
