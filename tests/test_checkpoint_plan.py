@@ -1,9 +1,11 @@
 """The checkpoint plan (``parallel/plan.py::checkpoint_plan``) held
-to the compiler: the three benchmark cells whose layers are checkpointed by
+to the compiler: the four benchmark cells whose layers are checkpointed by
 ``_loop_saves`` (``granite4h_micro_train_pp4_t8192``, whose state-space
 stack keeps what a v5e has room for, ``ouro_train_pp8_t4096``, whose
 looped stack keeps its own list, and ``nemotron3_nano_train_ep8_t8192``,
-a stack of one-sub-layer layers with routed experts among them) compiled at their real widths for a TPU
+a stack of one-sub-layer layers with routed experts among them, and
+``trinity_large_train_ep32_t8192``, window and full attention layers mixed)
+compiled at their real widths for a TPU
 v5e that is described and not attached, as ``benchmark/tests/
 test_granite4h_rehearsal.py`` and ``test_ouro_rehearsal.py`` do, with the
 plan a v5e's memory limit gives.
@@ -58,6 +60,12 @@ def _compiled_or_skip(config: str, traffic: str) -> dict:
     ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 986_254_848,
      {"glu_wide": 4 * 16384 * 3712 * 2, "ssm_in": 4 * 16384 * 10304 * 2},
      (26.0, 28.2)),
+    # window and full attention layers mixed, a gated output, four norms a
+    # layer, routed experts behind the fourth: checkpointed by policy (a
+    # stack with window layers is one for long rows); the wide products (1
+    # GiB) find no room beside the margin: 12.81 GiB compiled, 13.24 reckoned
+    ("trinity_large_preview", "train_tokens_ep32_t8192", 1_604_388_096,
+     {"glu_wide": 0}, (32.5, 33.9)),
 ])
 def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
         config, traffic, params, kept, tflop):
@@ -84,6 +92,26 @@ def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
     assert abs(live - reckoned) <= out["margin"], (live, reckoned)
     assert reckoned + out["margin"] <= HBM_USABLE
     assert tflop[0] < out["flops"] / 1e12 < tflop[1]
+
+
+def test_the_window_cell_holds_the_windowed_kernels_once_a_layer_a_pass():
+    """The Trinity cell's compiled step (what a v5e would answer): each of
+    the three windowed blocked kernels once a window layer (four: a
+    checkpointed layer does not run its forward kernel again) and each of
+    the three plain blocked kernels once, for the one full layer; 12.81 GiB
+    of arguments and temporaries, under the 13-14 ISSUE 48 reckoned."""
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    out = _compiled_or_skip("trinity_large_preview",
+                            "train_tokens_ep32_t8192")
+    assert out["window_layers"] == 4
+    want = {rf"{name}\b": 4 for name in pattn.KVB_SWA_KERNEL_NAMES.values()}
+    want.update({rf"{name}\b": 1 for name in (
+        pattn.KVB_FWD_KERNEL_NAME, pattn.KVB_DKV_KERNEL_NAME,
+        pattn.KVB_DQ_KERNEL_NAME)})
+    assert out["attn_kernels"] == want
+    live = out["argument_bytes"] + out["temp_bytes"]
+    assert 12.5 * GIB < live < 13.1 * GIB, live / GIB
 
 
 @pytest.mark.parametrize("config,traffic,layers", [

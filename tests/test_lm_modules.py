@@ -133,7 +133,8 @@ def _tiny(family: str, wide: bool):
         "test_glm4_moe_lite_arch", "ouro": "test_ouro_arch",
         "KeyeVL2": "test_keye_vl2_arch",
         "granitemoehybrid": "test_granitemoehybrid_arch",
-        "nemotron_h": "test_nemotron_h_arch"}[family])
+        "nemotron_h": "test_nemotron_h_arch",
+        "afmoe": "test_afmoe_arch"}[family])
     over = {}
     if wide and family == "KeyeVL2":
         over = {"hidden_size": 64, "head_dim": 128, "num_attention_heads": 2,
@@ -153,6 +154,12 @@ def _tiny(family: str, wide: bool):
     elif wide and family == "granitemoehybrid":
         over = {"mamba_n_heads": 8, "mamba_d_head": 16, "mamba_expand": 4,
                 "mamba_d_state": 128, "mamba_chunk_size": 128}
+    elif wide and family == "afmoe":
+        # a window of 160 under rows of 256: the window layers run the
+        # windowed blocked kernels, the full layer the whole-row form
+        over = {"hidden_size": 256, "num_attention_heads": 2,
+                "num_key_value_heads": 1, "head_dim": 128,
+                "sliding_window": 160, "moe_intermediate_size": 128}
     elif wide and family in ("ouro", "lfm2_moe"):
         over = {"hidden_size": 256, "num_attention_heads": 2,
                 "num_key_value_heads": 2, "head_dim": 128}
@@ -251,7 +258,7 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
             assert (kernel in text) == (chose["ssm_conv_kernel_share"] == 1.0)
         # what a checkpointed layer keeps
         kept = chose["checkpoint_kept_bytes"]
-        if arch.loop_steps > 1 or "mamba" in arch.mixers:
+        if plan._recomputes_by_policy(arch):
             assert policies == {plan._saves(tuple(
                 name for name, size in kept.items() if size))}
         else:
@@ -274,4 +281,11 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
     if family == "nemotron_h":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share",
                  "ssm_scan_kernel_share", "ssm_conv_kernel_share"}
+    if family == "afmoe":
+        want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share"}
+        # the windowed kernels stand in the interpreted step by their own
+        # names, beside the full layer's whole-row pair
+        for kernel in pattn.KVB_SWA_KERNEL_NAMES.values():
+            assert re.search(rf"name={kernel}\b", text)
+        assert pattn.FWD_KERNEL_NAME in text
     assert want <= seen, (want, seen)

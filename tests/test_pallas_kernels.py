@@ -1499,3 +1499,173 @@ def test_grouped_kernel_names_are_found_by_the_benchmarks_pattern(kernel):
     (call,) = mod.calls_per_step({}, {})
     name = getattr(grouped, kernel + "_KERNEL_NAME")
     assert call["pattern"] in name and name.startswith("moe_gmm_")
+
+
+# -- the blocked flash kernels under a window ---------------------------------
+
+def _dense_windowed(q, k, v, window):
+    from znicz_tpu.ops import attention as att
+
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    return att.attention(jnp, q, k, v, causal=True, window=window)
+
+
+@pytest.mark.parametrize("dh,h,kv,window,t,block", [
+    (64, 6, 1, 256, 512, 128), (128, 6, 1, 100, 256, 128),
+    (128, 2, 2, 300, 512, 128), (64, 2, 2, 129, 384, 128),
+    (128, 2, 1, 384, 512, 256), (64, 2, 2, 200, 512, None)],
+    ids=["dh64_group6_window_2_blocks", "dh128_group6_window_under_a_block",
+         "dh128_group1_window_off_the_block", "dh64_group1_window_129",
+         "dh128_group2_blocks_of_256", "dh64_one_tile_both_cuts"])
+def test_windowed_blocked_kernels_match_the_dense_masked_form(
+        dh, h, kv, window, t, block):
+    """``flash_attention(..., causal=True, window=W)`` (interpreted),
+    forward and the three gradients, against dense attention with the band
+    as a mask: heads of 64 (folded operands) and 128 (the layer's own
+    layout), a group of 1, 2 and 6 query heads a key/value head, windows
+    that are and are not a multiple of the block, one shorter than a block
+    (the diagonal's tile then takes both cuts), and the chooser's own tile
+    (one tile a row: both cuts in it)."""
+    import contextlib
+    import jax
+    from unittest import mock
+
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    ks = jax.random.split(jax.random.PRNGKey(t + dh + window), 4)
+    q, ct = (jax.random.normal(kk, (1, t, h, dh)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (1, t, kv, dh)) for kk in ks[2:])
+
+    def blocked(q, k, v):
+        return pattn.flash_attention(q, k, v, causal=True, interpret=True,
+                                     window=window)
+
+    patched = mock.patch.object(
+        pattn, "_kvb_block", lambda t, dh, pass_, sel=False: block) \
+        if block else contextlib.nullcontext()
+    with patched, jax.default_matmul_precision("highest"):
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: blocked(*a).sum(), (0, 1, 2)))(q, k, v))
+        for name in pattn.KVB_SWA_KERNEL_NAMES.values():
+            assert name in text
+        assert pattn.KVB_FWD_KERNEL_NAME + " " not in text
+        np.testing.assert_allclose(blocked(q, k, v),
+                                   _dense_windowed(q, k, v, window),
+                                   atol=2e-5)
+        got = jax.grad(lambda *a: (blocked(*a) * ct).sum(), (0, 1, 2))(
+            q, k, v)
+        want = jax.grad(lambda *a: (_dense_windowed(*a, window) * ct).sum(),
+                        (0, 1, 2))(q, k, v)
+    if block:                   # no later test meets the patched programs
+        pattn._kvb_call_fwd.clear_cache()
+        pattn._kvb_call_bwd.clear_cache()
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+def _parents_visits(t, block, causal, by_kv):
+    """``_visits`` as the parent commit (PR 47) wrote it, flags 1, 2, 4."""
+    n = t // block
+    pairs = [(i, j) for i in range(n) for j in range(n)
+             if not causal or j <= i]
+    outer = 1 if by_kv else 0
+    pairs.sort(key=lambda ij: (ij[outer], ij[1 - outer]))
+    flags = []
+    for v, (i, j) in enumerate(pairs):
+        first = v == 0 or pairs[v - 1][outer] != pairs[v][outer]
+        last = v + 1 == len(pairs) or pairs[v + 1][outer] != pairs[v][outer]
+        flags.append(1 * first + 2 * last + 4 * (causal and i == j))
+    return (np.asarray([i for i, _ in pairs], np.int32),
+            np.asarray([j for _, j in pairs], np.int32),
+            np.asarray(flags, np.int32))
+
+
+@pytest.mark.parametrize("t,block", [(8192, 1024), (4096, 1024),
+                                     (16384, 1024), (4096, 512), (640, 128)])
+def test_visit_tables_without_a_window_are_the_parents(t, block):
+    """``_visits(..., window=None)`` (and with the argument left out) equals
+    the parent's tables array for array, dtype for dtype, in both orders,
+    causal and full: a call without a window walks the grid it walked."""
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    for causal in (True, False):
+        for by_kv in (False, True):
+            want = _parents_visits(t, block, causal, by_kv)
+            for got in (pattn._visits(t, block, causal, by_kv),
+                        pattn._visits(t, block, causal, by_kv, None)):
+                for a, b in zip(got, want):
+                    assert a.dtype == b.dtype
+                    np.testing.assert_array_equal(a, b)
+    assert pattn._LOW not in (pattn._FIRST, pattn._LAST, pattn._CUT)
+
+
+@pytest.mark.parametrize("t,block,window", [
+    (8192, 1024, 4096), (8192, 1024, 4095), (8192, 1024, 4097),
+    (2048, 256, 300), (1024, 128, 100), (1024, 128, 1), (1024, 256, 5000)])
+def test_band_tables_list_every_tile_with_a_live_entry_and_no_other(
+        t, block, window):
+    """Under a window the tables list exactly the tiles in which some query
+    sees some key (``0 <= i - j < window``), in both orders, and flag
+    ``_LOW`` exactly those in which some pair lies ``window`` or more apart
+    (the second mask is computed there alone) and ``_CUT`` the diagonal's;
+    at the cell's shape 30 of the triangle's 36 tiles, four of them cut by
+    the band's edge."""
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    pos = np.arange(t)
+    apart = pos[:, None] - pos[None, :]
+    live = (apart >= 0) & (apart < window)
+    n = t // block
+    tiles = lambda m: m.reshape(n, block, n, block).any(axis=(1, 3))  # noqa
+    want_live, want_low = tiles(live), tiles(apart >= window)
+    for by_kv in (False, True):
+        qi, ki, flags = pattn._visits(t, block, True, by_kv, window)
+        listed = np.zeros((n, n), bool)
+        listed[qi, ki] = True
+        np.testing.assert_array_equal(listed, want_live)
+        assert len(qi) == want_live.sum()            # none listed twice
+        np.testing.assert_array_equal((flags & pattn._LOW) != 0,
+                                      want_low[qi, ki])
+        np.testing.assert_array_equal((flags & pattn._CUT) != 0, qi == ki)
+        outer = ki if by_kv else qi
+        assert all(np.diff(outer) >= 0)
+        first = np.r_[True, np.diff(outer) != 0]
+        last = np.r_[np.diff(outer) != 0, True]
+        np.testing.assert_array_equal((flags & pattn._FIRST) != 0, first)
+        np.testing.assert_array_equal((flags & pattn._LAST) != 0, last)
+    if (t, block, window) == (8192, 1024, 4096):
+        assert len(qi) == 30 and len(pattn._visits(t, block, True, False)[0]) \
+            == 36
+        assert int(((flags & pattn._LOW) != 0).sum()) == 4
+    if window >= t:                    # a window longer than the row: causal
+        for a, b in zip(pattn._visits(t, block, True, False, window),
+                        pattn._visits(t, block, True, False)):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_a_window_needs_the_blocked_form_a_causal_call_and_no_selection():
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    q = jnp.zeros((1, 256, 2, 64))
+    with pytest.raises(ValueError, match="causal"):
+        pattn.flash_attention(q, q, q, interpret=True, window=64)
+    with pytest.raises(ValueError, match="selection"):
+        pattn.flash_attention(q, q, q, causal=True, interpret=True, window=64,
+                              sel=jnp.ones((1, 256, 256), jnp.int8))
+    with pytest.raises(ValueError, match="at least one"):
+        pattn.flash_attention(q, q, q, causal=True, interpret=True, window=0)
+    with pytest.raises(ValueError, match="multiple of the 128"):
+        pattn.flash_attention(q[:, :200], q[:, :200], q[:, :200],
+                              causal=True, interpret=True, window=64)
+    # a shape the whole-row form would take runs blocked under a window,
+    # in the layer's layout at a head of 128
+    assert pattn.form_of(256, 128)[0] == "rows"
+    assert not pattn.direct_layout(256, 128)
+    assert pattn.direct_layout(256, 128, True)
+    assert not pattn.direct_layout(256, 64, True)
+    assert pattn.kvb_block_rows(256, 64) == dict.fromkeys(
+        ("fwd", "dkv", "dq"), 0)
+    assert pattn.kvb_block_rows(256, 64, window=64) == dict.fromkeys(
+        ("fwd", "dkv", "dq"), 256)

@@ -3,7 +3,9 @@ and not attached, in a process of its own (a process that has loaded the
 TPU's library disturbs the profiler's tests that run after it), and print
 one JSON line: the compiled step's memory, the compiler's operation count,
 the checkpoint plan a v5e's memory limit gives, the footprint the plan
-reckoned with and, of a stack with state-space layers, how often the scan's
+reckoned with, how often each of the key/value-blocked flash kernels stands
+in the compiled step (under a window and without one) and, of a stack with
+state-space layers, how often the scan's
 and the convolution's two kernels each stand in the compiled step, which
 float32 arrays with two chunk-length axes do and which float32 arrays as
 long as the tokens and as wide as the convolution's channels.  ``tests/test_checkpoint_plan.py`` runs it.
@@ -95,7 +97,7 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     if out:
         with open(out, "w") as f:
             f.write(text)
-    from znicz_tpu.ops.pallas import ssd, ssm_conv
+    from znicz_tpu.ops.pallas import attention as pattn, ssd, ssm_conv
     q = arch.ssm_chunk
     channels = arch.ssm_heads * arch.ssm_head_dim + \
         2 * arch.ssm_groups * arch.ssm_state
@@ -109,6 +111,13 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
         "scan_kernels": stands(ssd.FWD_KERNEL_NAME, ssd.BWD_KERNEL_NAME),
         "conv_kernels": stands(ssm_conv.FWD_KERNEL_NAME,
                                ssm_conv.BWD_KERNEL_NAME),
+        # the blocked flash kernels, under a window and without one
+        # (``\b``: the plain names are no prefix of the windowed ones, but
+        # of nothing else either)
+        "attn_kernels": stands(*(rf"{name}\b" for name in (
+            *pattn.KVB_SWA_KERNEL_NAMES.values(), pattn.KVB_FWD_KERNEL_NAME,
+            pattn.KVB_DKV_KERNEL_NAME, pattn.KVB_DQ_KERNEL_NAME))),
+        "window_layers": arch.window_layers(),
         "chunk_squares": sorted(set(re.findall(
             rf"f32\[(?:\d+,)*{q},{q}\]", text))) if q else [],
         # ... as long as a row (its digits, so the padded ones too)

@@ -31,11 +31,14 @@ def softmax(xp, x, axis=-1):
     return e / e.sum(axis=axis, keepdims=True)
 
 
-def masked_scores(xp, q, k, causal: bool, q_offset=0, k_offset=0):
+def masked_scores(xp, q, k, causal: bool, q_offset=0, k_offset=0,
+                  window: int | None = None):
     """Scaled q·kᵀ scores ``(b, h, tq, tk)`` with optional causal masking;
     ``*_offset`` give global positions when q/k are sequence blocks — the
     ONE definition of the mask convention, shared by dense attention and
-    the ring variant (znicz_tpu.parallel.ring_attention).
+    the ring variant (znicz_tpu.parallel.ring_attention).  ``window`` (with
+    ``causal``): query ``i`` sees key ``j`` iff ``0 <= i - j < window``, the
+    band as a second mask on the same scores.
 
     The product accumulates in f32 even for bf16 inputs (matmul inputs
     stay bf16 on the MXU; only the accumulator widens — the same rule as
@@ -53,13 +56,18 @@ def masked_scores(xp, q, k, causal: bool, q_offset=0, k_offset=0):
         kpos = xp.arange(tk)[None, :] + k_offset
         s = xp.where((kpos > qpos)[None, None, :, :],
                      xp.asarray(-1e30, dtype=s.dtype), s)
+        if window is not None:
+            s = xp.where((qpos - kpos >= window)[None, None, :, :],
+                         xp.asarray(-1e30, dtype=s.dtype), s)
+    elif window is not None:
+        raise ValueError("a window on the scores needs causal=True")
     return s
 
 
-def attention(xp, q, k, v, causal: bool = False):
+def attention(xp, q, k, v, causal: bool = False, window: int | None = None):
     """Scaled-dot-product attention over per-head tensors
     ``(b, t, h, dh)``."""
-    p = softmax(xp, masked_scores(xp, q, k, causal))
+    p = softmax(xp, masked_scores(xp, q, k, causal, window=window))
     # probabilities ride the MXU at the value dtype (flash-kernel rule)
     return xp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 

@@ -78,6 +78,20 @@ static switch: a call without a selection traces the kernels it always
 traced, under the names they always had; with one the three kernels are
 named ``flash_attention_kvb_sel_*``.  Nothing here holds a ``(heads, t,
 t)`` array.
+
+A WINDOW (``flash_attention(..., causal=True, window=W)``: query ``i`` sees
+key ``j`` iff ``0 <= i - j < W``) reaches the blocked kernels through their
+visit tables, with no operand: :func:`_visits` lists beside the causal rule
+only the tiles with an entry inside the band, so a tile below the band costs
+no step and no fetch, as one above the diagonal never did, and flags the
+tiles the band's lower edge crosses (:data:`_LOW`), in which alone the
+second mask is computed (:func:`_band_scores`, from the visit's own two
+table entries).  ``W`` need be no multiple of a tile, and may be shorter
+than one (the diagonal's tile then takes both cuts).  A static switch as the
+selection is: ``window=None`` traces the kernels it always traced over the
+tables it always walked; with one the three kernels are named
+``flash_attention_kvb_swa_*``.  The whole-row form takes no window: a
+windowed call is always blocked.
 """
 
 from __future__ import annotations
@@ -321,6 +335,12 @@ KVB_SEL_KERNEL_NAMES = {"fwd": "flash_attention_kvb_sel_fwd",
                         "dkv": "flash_attention_kvb_sel_dkv",
                         "dq": "flash_attention_kvb_sel_dq"}
 
+#: and under a window (a band of the causal triangle), whose tables list
+#: fewer tiles and whose bodies mask a second kind of cut tile
+KVB_SWA_KERNEL_NAMES = {"fwd": "flash_attention_kvb_swa_fwd",
+                        "dkv": "flash_attention_kvb_swa_dkv",
+                        "dq": "flash_attention_kvb_swa_dq"}
+
 #: rows of a q block and of a key/value block a pass may take, largest
 #: first (:func:`_kvb_block` chooses)
 _KVB_BLOCKS = (1024, 512, 256, 128)
@@ -347,8 +367,10 @@ _KVB_HOLDS = {"fwd": (4, 1, 2, 4), "dkv": (6, 2, 4, 0), "dq": (5, 1, 4, 4)}
 _KVB_PASSES = tuple(_KVB_HOLDS)
 #: a visit's flags: the first and the last of its run (the visits that
 #: share the block the kernel accumulates for), and whether the diagonal
-#: cuts the tile (only then is the mask computed)
-_FIRST, _LAST, _CUT = 1, 2, 4
+#: cuts the tile (only then is the mask computed); under a window, whether
+#: the band's lower edge does (some pair of the tile lies ``window`` or more
+#: positions apart: the second mask)
+_FIRST, _LAST, _CUT, _LOW = 1, 2, 4, 8
 _MASKED = -1e30
 
 
@@ -380,27 +402,57 @@ def _kvb_block(t: int, dh: int, pass_: str, sel: bool = False) -> int:
                  _kvb_vmem(pass_, b, dh, sel) <= _KVB_VMEM_LIMIT), 0)
 
 
-def kvb_block_rows(t: int, dh: int, sel: bool = False) -> dict[str, int]:
+def kvb_block_rows(t: int, dh: int, sel: bool = False,
+                   window: int | None = None) -> dict[str, int]:
     """``{pass: rows of its tile}`` for the shape, 0 in every pass where
-    the shape's form (:func:`form_of`; with a selection always the
-    blocked one, :func:`flash_attention`) is not the key/value-blocked
-    one."""
-    blocked = sel or form_of(t, dh)[0] == "blocked"
+    the shape's form (:func:`form_of`; with a selection or under a window
+    always the blocked one, :func:`flash_attention`) is not the
+    key/value-blocked one."""
+    blocked = sel or window is not None or form_of(t, dh)[0] == "blocked"
     return {p: _kvb_block(t, dh, p, sel) if blocked else 0
             for p in _KVB_PASSES}
 
 
+def _tile_in_band(i: int, j: int, block: int, window: int) -> bool:
+    """Whether the tile (q block ``i``, key/value block ``j <= i``) holds a
+    pair fewer than ``window`` positions apart: its nearest pair is its
+    first query and its last key (the diagonal's own tile: distance 0)."""
+    return i == j or (i - j - 1) * block + 1 < window
+
+
+def _tile_crosses_band(i: int, j: int, block: int, window: int) -> bool:
+    """Whether the tile holds a pair ``window`` or more positions apart (its
+    last query and its first key are its farthest): the band's lower edge
+    cuts it."""
+    return (i - j + 1) * block - 1 >= window
+
+
+def kvb_window_tiles(t: int, dh: int, window: int) -> dict[str, tuple]:
+    """``{pass: (tiles its table lists under the window, tiles the causal
+    triangle's table lists)}`` at each pass's own tile
+    (:func:`kvb_block_rows`): what a window spares the blocked kernels."""
+    return {p: (len(_visits(t, rows, True, p == "dkv", window)[0]),
+                len(_visits(t, rows, True, p == "dkv")[0]))
+            for p, rows in kvb_block_rows(t, dh, window=window).items()}
+
+
 @lru_cache(maxsize=None)
-def _visits(t: int, block: int, causal: bool, by_kv: bool):
+def _visits(t: int, block: int, causal: bool, by_kv: bool,
+            window: int | None = None):
     """The (q block, key/value block) tiles a pass visits, as three static
     int32 tables ``(q block, kv block, flags)``: under ``causal`` only the
-    tiles with an unmasked entry.  Ordered by q block (the forward pass
+    tiles with an unmasked entry, and under a ``window`` (query ``i`` sees
+    key ``j`` iff ``0 <= i - j < window``) of those only the tiles with an
+    entry inside the band, the ones its lower edge crosses flagged
+    :data:`_LOW`.  Ordered by q block (the forward pass
     and dq, which accumulate over key/value blocks) or ``by_kv`` (dk and
     dv, which accumulate over q blocks); the kernels' grids walk the
     tables, so a tile that is not listed costs no step and no fetch."""
     n = t // block
     pairs = [(i, j) for i in range(n) for j in range(n)
              if not causal or j <= i]
+    if window is not None:
+        pairs = [ij for ij in pairs if _tile_in_band(*ij, block, window)]
     outer = 1 if by_kv else 0
     pairs.sort(key=lambda ij: (ij[outer], ij[1 - outer]))
     flags = []
@@ -408,7 +460,9 @@ def _visits(t: int, block: int, causal: bool, by_kv: bool):
         first = v == 0 or pairs[v - 1][outer] != pairs[v][outer]
         last = v + 1 == len(pairs) or pairs[v + 1][outer] != pairs[v][outer]
         flags.append(_FIRST * first + _LAST * last +
-                     _CUT * (causal and i == j))
+                     _CUT * (causal and i == j) +
+                     _LOW * (window is not None and
+                             _tile_crosses_band(i, j, block, window)))
     return (np.asarray([i for i, _ in pairs], np.int32),
             np.asarray([j for _, j in pairs], np.int32),
             np.asarray(flags, np.int32))
@@ -436,6 +490,26 @@ def _cut_scores(s, rows_are_q: bool):
     return jnp.where(kpos > qpos, jnp.float32(_MASKED), s)
 
 
+def _tile_apart(qi_ref, ki_ref, block: int, window: int | None):
+    """Positions by which this visit's first query lies behind its first
+    key, ``(q block - key/value block) * rows``, from the visit's two table
+    entries (read at the kernel's top level: a grid index is not to be had
+    inside a ``pl.when``); None without a window, which alone asks."""
+    if window is None:
+        return None
+    v = pl.program_id(1)
+    return (qi_ref[v] - ki_ref[v]) * block
+
+
+def _band_scores(s, rows_are_q: bool, apart, window: int):
+    """The second cut, of a tile the band's lower edge crosses: ``-1e30``
+    where query and key lie ``window`` or more positions apart
+    (:func:`_tile_apart`)."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0 if rows_are_q else 1)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 if rows_are_q else 0)
+    return jnp.where(qpos - kpos + apart >= window, jnp.float32(_MASKED), s)
+
+
 def _selected_scores(s, sel_ref):
     """The tile's scores where the selection tile is nonzero, ``-1e30``
     elsewhere (the causal cut is the selection's own)."""
@@ -443,20 +517,35 @@ def _selected_scores(s, sel_ref):
                      jnp.float32(_MASKED))
 
 
-def _tile_of(flags, tile, sel_ref):
+def _band_tile(flags, tile):
+    """Run ``tile(cut, low)`` once, each of the two masks only where its
+    edge cuts the tile (both in one tile only under a window shorter than
+    the tile)."""
+    edges = flags & (_CUT | _LOW)
+    for cut in (False, True):
+        for low in (False, True):
+            pl.when(edges == _CUT * cut + _LOW * low)(partial(tile, cut, low))
+
+
+def _tile_of(flags, tile, sel_ref, window=None):
     """Run ``tile`` once: with a selection as it stands (the tile masks by
-    its selection operand), else by :func:`_either_tile`."""
-    if sel_ref is None:
-        _either_tile(flags, tile)
-    else:
+    its selection operand), under a window by :func:`_band_tile`, else by
+    :func:`_either_tile`."""
+    if sel_ref is not None:
         tile(False)
+    elif window is not None:
+        _band_tile(flags, tile)
+    else:
+        _either_tile(flags, tile)
 
 
 def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
-                    sm_scale: float, sel: bool = False):
+                    sm_scale: float, sel: bool = False,
+                    window: int | None = None):
     sel_ref = rest[0] if sel else None
     o_ref, lse_ref, m_sc, l_sc, acc_sc = rest[sel:]
     flags = fl_ref[pl.program_id(1)]
+    apart = _tile_apart(qi_ref, ki_ref, q_ref.shape[1], window)
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
@@ -464,11 +553,13 @@ def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def tile(cut: bool):
+    def tile(cut: bool, low: bool = False):
         v = v_ref[0]
         s = _nt(q_ref[0], k_ref[0]) * sm_scale             # (bq, bk)
         if cut:
             s = _cut_scores(s, True)
+        if low:
+            s = _band_scores(s, True, apart, window)
         if sel:
             s = _selected_scores(s, sel_ref)
         m_prev = m_sc[...]
@@ -485,7 +576,7 @@ def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
-    _tile_of(flags, tile, sel_ref)
+    _tile_of(flags, tile, sel_ref, window)
 
     @pl.when((flags & _LAST) != 0)
     def _store():
@@ -496,7 +587,8 @@ def _kvb_fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
 
 def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, *rest, sm_scale: float,
-                    sel: bool = False):
+                    sel: bool = False,
+                    window: int | None = None):
     """dk and dv of one key/value block, summed over the q blocks that see
     it.  Everything is held TRANSPOSED (key/value rows down, queries
     across; ``lse`` and ``delta`` arrive as rows, a selection as the tile
@@ -505,17 +597,20 @@ def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     sel_ref = rest[0] if sel else None
     dk_ref, dv_ref, dk_sc, dv_sc = rest[sel:]
     flags = fl_ref[pl.program_id(1)]
+    apart = _tile_apart(qi_ref, ki_ref, q_ref.shape[1], window)
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    def tile(cut: bool):
+    def tile(cut: bool, low: bool = False):
         q, do = q_ref[0], do_ref[0]
         s = _nt(k_ref[0], q) * sm_scale                    # (bk, bq)
         if cut:
             s = _cut_scores(s, False)
+        if low:
+            s = _band_scores(s, False, apart, window)
         if sel:
             s = _selected_scores(s, sel_ref)
         p = jnp.exp(s - lse_ref[0])
@@ -525,7 +620,7 @@ def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         dk_sc[...] += jnp.dot(ds.astype(q.dtype), q,
                               preferred_element_type=jnp.float32)
 
-    _tile_of(flags, tile, sel_ref)
+    _tile_of(flags, tile, sel_ref, window)
 
     @pl.when((flags & _LAST) != 0)
     def _store():
@@ -535,20 +630,24 @@ def _kvb_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _kvb_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, *rest, sm_scale: float,
-                   sel: bool = False):
+                   sel: bool = False,
+                   window: int | None = None):
     sel_ref = rest[0] if sel else None
     dq_ref, dq_sc = rest[sel:]
     flags = fl_ref[pl.program_id(1)]
+    apart = _tile_apart(qi_ref, ki_ref, q_ref.shape[1], window)
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    def tile(cut: bool):
+    def tile(cut: bool, low: bool = False):
         k = k_ref[0]
         s = _nt(q_ref[0], k) * sm_scale                    # (bq, bk)
         if cut:
             s = _cut_scores(s, True)
+        if low:
+            s = _band_scores(s, True, apart, window)
         if sel:
             s = _selected_scores(s, sel_ref)
         p = jnp.exp(s - lse_ref[0])
@@ -556,7 +655,7 @@ def _kvb_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         dq_sc[...] += jnp.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32)
 
-    _tile_of(flags, tile, sel_ref)
+    _tile_of(flags, tile, sel_ref, window)
 
     @pl.when((flags & _LAST) != 0)
     def _store():
@@ -637,20 +736,34 @@ def _kvb_params():
 
 # Jitted, so that a program's layers share one trace and one lowering of
 # each kernel (as ops/pallas/grouped.py's)
-@partial(jax.jit, static_argnames=("causal", "interpret"))
-def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool, sel=None):
+def _kvb_name(pass_: str, masked: bool, window: int | None) -> str:
+    """A pass's kernel name: its own, with a selection's tile among its
+    operands or under a window (each another program, found by name in a
+    device trace)."""
+    if masked:
+        return KVB_SEL_KERNEL_NAMES[pass_]
+    if window is not None:
+        return KVB_SWA_KERNEL_NAMES[pass_]
+    return {"fwd": KVB_FWD_KERNEL_NAME, "dkv": KVB_DKV_KERNEL_NAME,
+            "dq": KVB_DQ_KERNEL_NAME}[pass_]
+
+
+@partial(jax.jit, static_argnames=("causal", "interpret", "window"))
+def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool, sel=None,
+                  window: int | None = None):
     """-> ``(o, lse)``: ``o`` in the operands' layout (:func:`_kvb_dims`),
     ``lse`` float32 ``(b * h, t, 1)``.  ``sel``: the selection ``(b, t,
-    t)`` int8, or None."""
+    t)`` int8, or None.  ``window``: query ``i`` sees key ``j`` iff ``0 <=
+    i - j < window`` (:func:`_visits`), or None."""
     bh, t, dh, heads = _kvb_dims(q)
     masked = sel is not None
     block = _kvb_block(t, dh, "fwd", masked)
-    tables = _visits(t, block, causal, False)
+    tables = _visits(t, block, causal, False, window)
     spec = _kvb_specs(block, dh, heads, bh // sel.shape[0] if masked else 0)
     q3 = _as_rows(q)
     o, lse = pl.pallas_call(
         partial(_kvb_fwd_kernel, sm_scale=1.0 / float(np.sqrt(dh)),
-                sel=masked),
+                sel=masked, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(tables[0])),
             in_specs=[spec["q"], spec["kv"], spec["kv"]] +
@@ -662,15 +775,15 @@ def _kvb_call_fwd(q, k, v, causal: bool, interpret: bool, sel=None):
         out_shape=[_out_struct(q3.shape, q.dtype, q),
                    _out_struct((bh, t, 1), jnp.float32, q)],
         compiler_params=_kvb_params(),
-        name=KVB_SEL_KERNEL_NAMES["fwd"] if masked else KVB_FWD_KERNEL_NAME,
+        name=_kvb_name("fwd", masked, window),
         interpret=interpret,
     )(*tables, q3, _as_rows(k), _as_rows(v), *([sel] if masked else []))
     return o.reshape(q.shape), lse
 
 
-@partial(jax.jit, static_argnames=("causal", "interpret"))
+@partial(jax.jit, static_argnames=("causal", "interpret", "window"))
 def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
-                  sel=None):
+                  sel=None, window: int | None = None):
     bh, t, dh, heads = _kvb_dims(q)
     sm_scale = 1.0 / float(np.sqrt(dh))
     masked = sel is not None
@@ -680,9 +793,10 @@ def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
     # each pass on the tile that is its own (:func:`_kvb_block`)
     block = _kvb_block(t, dh, "dkv", masked)
     spec = _kvb_specs(block, dh, heads, sel_heads)
-    by_kv = _visits(t, block, causal, True)
+    by_kv = _visits(t, block, causal, True, window)
     dk, dv = pl.pallas_call(
-        partial(_kvb_dkv_kernel, sm_scale=sm_scale, sel=masked),
+        partial(_kvb_dkv_kernel, sm_scale=sm_scale, sel=masked,
+                window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(by_kv[0])),
             in_specs=[spec["q"], spec["kv"], spec["kv"], spec["q"],
@@ -694,7 +808,7 @@ def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
         out_shape=[_out_struct(k3.shape, k.dtype, q),
                    _out_struct(v3.shape, v.dtype, q)],
         compiler_params=_kvb_params(),
-        name=KVB_SEL_KERNEL_NAMES["dkv"] if masked else KVB_DKV_KERNEL_NAME,
+        name=_kvb_name("dkv", masked, window),
         interpret=interpret,
     )(*by_kv, q3, k3, v3, do3, lse.reshape(bh, 1, t),
       delta.reshape(bh, 1, t),
@@ -703,9 +817,10 @@ def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
       *([sel.transpose(0, 2, 1)] if masked else []))
     block = _kvb_block(t, dh, "dq", masked)
     spec = _kvb_specs(block, dh, heads, sel_heads)
-    by_q = _visits(t, block, causal, False)
+    by_q = _visits(t, block, causal, False, window)
     dq = pl.pallas_call(
-        partial(_kvb_dq_kernel, sm_scale=sm_scale, sel=masked),
+        partial(_kvb_dq_kernel, sm_scale=sm_scale, sel=masked,
+                window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bh, len(by_q[0])),
             in_specs=[spec["q"], spec["kv"], spec["kv"], spec["q"],
@@ -715,27 +830,28 @@ def _kvb_call_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
             scratch_shapes=[pltpu.VMEM((block, dh), jnp.float32)]),
         out_shape=_out_struct(q3.shape, q.dtype, q),
         compiler_params=_kvb_params(),
-        name=KVB_SEL_KERNEL_NAMES["dq"] if masked else KVB_DQ_KERNEL_NAME,
+        name=_kvb_name("dq", masked, window),
         interpret=interpret,
     )(*by_q, q3, k3, v3, do3, lse, delta.reshape(bh, t, 1),
       *([sel] if masked else []))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_kvb(q, k, v, causal: bool, interpret: bool):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_kvb(q, k, v, causal: bool, interpret: bool,
+               window: int | None = None):
     """The blocked kernels over operands in either layout
     (:func:`_kvb_dims`); ``o`` and the three gradients come in theirs."""
-    return _kvb_call_fwd(q, k, v, causal, interpret)[0]
+    return _kvb_call_fwd(q, k, v, causal, interpret, window=window)[0]
 
 
-def _flash_kvb_fwd(q, k, v, causal, interpret):
-    o, lse = _kvb_call_fwd(q, k, v, causal, interpret)
+def _flash_kvb_fwd(q, k, v, causal, interpret, window):
+    o, lse = _kvb_call_fwd(q, k, v, causal, interpret, window=window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_kvb_bwd(causal, interpret, res, do):
-    return _kvb_call_bwd(*res, do, causal, interpret)
+def _flash_kvb_bwd(causal, interpret, window, res, do):
+    return _kvb_call_bwd(*res, do, causal, interpret, window=window)
 
 
 _flash_kvb.defvjp(_flash_kvb_fwd, _flash_kvb_bwd)
@@ -845,19 +961,33 @@ def supported(t: int, dh: int) -> bool:
     return unsupported_reason(t, dh) is None
 
 
-def direct_layout(t: int, dh: int) -> bool:
+def direct_layout(t: int, dh: int, windowed: bool = False) -> bool:
     """Whether the shape's kernels cut their blocks from the layer's own
     ``(b, t, heads * dh)`` and write ``o`` and the gradients there, by the
-    shape alone: the blocked form (:func:`form_of`) with a head that is a
-    multiple of 128.  A ``(1, block, dh)`` block at index ``(batch, block,
+    shape alone: the blocked form (:func:`form_of`; under a window,
+    ``windowed``, wherever the blocked form takes the shape) with a head
+    that is a multiple of 128.  A ``(1, block, dh)`` block at index ``(batch, block,
     head)`` is then a legal TPU block (its last axis a multiple of the 128
     lanes); at 64, 192, 320, 448 it is neither that nor the whole axis,
     and the operands are folded head-major as the whole-row form's are."""
-    return form_of(t, dh)[0] == "blocked" and dh % 128 == 0
+    blocked = blocked_unsupported_reason(t, dh) is None if windowed else \
+        form_of(t, dh)[0] == "blocked"
+    return blocked and dh % 128 == 0
+
+
+def window_unsupported_reason(t: int, dh: int, window: int) -> str | None:
+    """Why no kernel here takes the shape under a window, or ``None``: the
+    blocked form alone does (:func:`blocked_unsupported_reason`; its tables
+    skip what lies outside the band and its bodies mask the two kinds of cut
+    tile, so any window of at least one position will do)."""
+    if window < 1:
+        return f"window={window}: at least one position (a query sees itself)"
+    return blocked_unsupported_reason(t, dh)
 
 
 def flash_attention(q, k, v, causal: bool = False, *,
-                    interpret: bool = False, sel=None):
+                    interpret: bool = False, sel=None,
+                    window: int | None = None):
     """Fused attention over per-head tensors ``(b, t, h, dh)`` — same
     contract as ops.attention.attention (``softmax(q·kᵀ/√dh)·v``),
     differentiable via the flash backward kernels, in the form
@@ -871,9 +1001,21 @@ def flash_attention(q, k, v, causal: bool = False, *,
     query's softmax to its selected keys, for all heads alike: always in
     the blocked form (the whole-row kernels take no selection, so
     :func:`blocked_unsupported_reason` alone decides), ``causal``
-    required."""
+    required.  ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window``; always in the blocked form too
+    (:func:`window_unsupported_reason`), ``causal`` required, and refused
+    beside a selection (which holds whatever cut it likes itself)."""
     b, t, h, dh = q.shape
-    if sel is not None:
+    if window is not None:
+        why = window_unsupported_reason(t, dh, window)
+        if not causal:
+            why = why or "causal=True is required"
+        if sel is not None:
+            why = why or "a selection beside it (which holds its own cut)"
+        if why:
+            raise ValueError(f"flash_attention with a window: {why}")
+        form = "blocked"
+    elif sel is not None:
         why = blocked_unsupported_reason(t, dh)
         if why or not causal:
             raise ValueError(f"flash_attention with a selection: "
@@ -900,7 +1042,8 @@ def flash_attention(q, k, v, causal: bool = False, *,
         if sel is not None:
             o = _flash_kvb_sel(fold(q), fold(k), fold(v), sel, interpret)
         elif direct:
-            return _flash_kvb(q, k, v, causal, interpret)
+            return _flash_kvb(q, k, v, causal, interpret, window)
         else:
-            o = _flash_kvb(fold(q), fold(k), fold(v), causal, interpret)
+            o = _flash_kvb(fold(q), fold(k), fold(v), causal, interpret,
+                           window)
     return o.reshape(b, h, t, dh).transpose(0, 2, 1, 3)

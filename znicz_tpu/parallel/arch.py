@@ -21,6 +21,9 @@ _GROUPS_MECHANISM = "state-space groups (B and C a group of heads)"
 _ONE_SUB_LAYER_MECHANISM = "layers of one sub-layer"
 _ROUTED_MECHANISM = "routed experts (moe_routed_ffn)"
 _RELU2_MECHANISM = "squared-ReLU experts (two weights, no gate)"
+_WINDOW_MECHANISM = "window on the attention scores"
+_SOME_ROTATED_MECHANISM = "rotary embedding on some layers only"
+_ATTN_GATE_MECHANISM = "gated attention output"
 
 
 def _default_compute_dtype(compute_dtype=None):
@@ -103,6 +106,15 @@ class Arch:
     kernels keep their own scale; q takes ``attn_mult * sqrt(head_dim)``),
     ``logits_div`` dividing the logits (the hidden state in front of the
     head pass takes ``1 / logits_div``).
+    ``window`` and ``windowed`` (a flag a layer; empty: no layer) put a
+    window on an attention layer's scores: query ``i`` sees key ``j`` iff
+    ``0 <= i - j < window`` (:meth:`window_of`).  ``rotated`` (a flag a
+    layer; empty: every layer, where ``rope_theta`` is set) says which
+    attention layers rotate q and k (:meth:`rotates`): a stack may rotate
+    its window layers and leave its full ones position-free.  ``attn_gate``
+    gates the attention's output, ``o = W_o (sigmoid(W_g u) * heads'
+    output)``, ``u`` the layer's normed input and ``W_g`` as wide as the
+    heads' output.
 
     Built by :func:`gpt_arch` (the block this module always had: the
     four integers) or :func:`arch_from_config` (a model's own keys)."""
@@ -155,17 +167,46 @@ class Arch:
     residual_mult: float = 1.0
     attn_mult: float | None = None
     logits_div: float = 1.0
+    window: int = 0
+    windowed: tuple = ()
+    rotated: tuple = ()
+    attn_gate: bool = False
 
     def __post_init__(self):
         if self._scaled() and (
                 self.mtp or self.loop_steps > 1 or self.index_top_k or
                 not set(self.mixers) <= {"attention", "mamba"} or
-                not set(self.ffns) <= {"glu"}):
+                not set(self.ffns) <= {"glu", "moe_routed"} or
+                self.expert_form != "glu"):
             raise ValueError("embed_mult / residual_mult / attn_mult / "
                              "logits_div: the multipliers are written for "
                              "an unlooped stack of plain or grouped-query "
-                             "attention, state-space and SwiGLU sub-layers "
-                             "with no indexer and no MTP module")
+                             "attention, state-space, SwiGLU and gated "
+                             "routed-expert sub-layers with no indexer and "
+                             "no MTP module")
+        for name, flags in (("windowed", self.windowed),
+                            ("rotated", self.rotated)):
+            if flags and len(flags) != len(self.mixers):
+                raise ValueError(f"{name}: {len(flags)} flags for "
+                                 f"{len(self.mixers)} layers")
+            if any(flag and mixer != "attention"
+                   for flag, mixer in zip(flags, self.mixers)):
+                raise ValueError(f"{name}: written for plain or "
+                                 f"grouped-query attention layers")
+        if any(self.windowed) != bool(self.window) or self.window < 0:
+            raise ValueError(f"window {self.window} with windowed "
+                             f"{self.windowed}: a window needs its layers "
+                             f"and they a window of at least 1")
+        if any(self.rotated) and self.rope_theta is None:
+            raise ValueError("rotated layers need rope_theta")
+        if (any(self.windowed) or self.attn_gate or self.rotated) and (
+                self.index_top_k or self.mtp or self.loop_steps > 1):
+            raise ValueError("window / rotated / attn_gate: written for an "
+                             "unlooped stack with no indexer (a selection "
+                             "holds its own cut) and no MTP module")
+        if self.attn_gate and "latent" in self.mixers:
+            raise ValueError("attn_gate: written for plain or grouped-query "
+                             "attention layers")
         if "mamba" in self.mixers and not (
                 self.ssm_heads > 0 and self.ssm_head_dim > 0 and
                 self.ssm_state > 0 and self.conv_taps > 0 and
@@ -188,10 +229,14 @@ class Arch:
             raise ValueError("a layer of one sub-layer is written for an "
                              "unlooped RMSNorm stack with no sandwich norm "
                              "and no MTP module")
-        if self.sandwich and not (set(self.mixers) <= {"attention", "latent"}
-                                  and set(self.ffns) <= {"glu"}):
+        if self.sandwich and not (
+                set(self.mixers) <= {"attention", "latent"} and
+                set(self.ffns) <= {"glu", "moe_routed"} and
+                self.expert_form == "glu"):
             raise ValueError("sandwich: the second norm is written for "
-                             "attention and SwiGLU sub-layers")
+                             "attention, SwiGLU and gated routed-expert "
+                             "sub-layers (the routed and the shared experts' "
+                             "sum behind one norm)")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps {self.loop_steps}: at least 1")
         if self.index_top_k and (set(self.mixers) != {"attention"} or
@@ -228,6 +273,23 @@ class Arch:
         i = min(i, self.n_layers - 1)
         return self.mixers[i], self.ffns[i]
 
+    def window_of(self, i: int) -> int | None:
+        """The window on layer ``i``'s attention scores (query ``i`` sees
+        key ``j`` iff ``0 <= i - j < window``), None where it has none."""
+        return self.window if self.windowed and \
+            self.windowed[min(i, self.n_layers - 1)] else None
+
+    def rotates(self, i: int) -> bool:
+        """Whether layer ``i``'s attention rotates q and k."""
+        if self.rope_theta is None:
+            return False
+        return not self.rotated or bool(
+            self.rotated[min(i, self.n_layers - 1)])
+
+    def window_layers(self) -> int:
+        """Attention layers with a window on their scores."""
+        return sum(bool(w) for w in self.windowed)
+
     def routed_layers(self) -> int:
         """Routed expert layers a step runs, the MTP module's among them."""
         n = self.ffns.count("moe_routed")
@@ -253,6 +315,14 @@ class Arch:
             out.append("QK-norm")
         if self.rope_theta is not None:
             out.append("rotary embedding")
+        if self.rotated and not all(
+                r for r, m in zip(self.rotated, self.mixers)
+                if m == "attention"):
+            out.append(_SOME_ROTATED_MECHANISM)
+        if self.window_layers():
+            out.append(_WINDOW_MECHANISM)
+        if self.attn_gate:
+            out.append(_ATTN_GATE_MECHANISM)
         if self._scaled():
             out.append("static multipliers (embedding, residual, scores, "
                        "logits)")
@@ -691,11 +761,105 @@ def _nemotron_h_arch(cfg, vocab: int | None) -> Arch:
         ssm_groups=int(cfg.get("n_groups", 1)), expert_form="relu2")
 
 
+#: ``layer_types`` of the ``afmoe`` family -> whether the layer has a window
+_AFMOE_LAYERS = {"sliding_attention": True, "full_attention": False}
+
+
+def _afmoe_arch(cfg, vocab: int | None) -> Arch:
+    """``afmoe`` (Arcee's Trinity family: ``layer_types`` of
+    ``sliding_attention`` and ``full_attention``, ``sliding_window``,
+    ``num_dense_layers``, ``num_experts``, ``num_experts_per_tok``,
+    ``num_shared_experts``, ``moe_intermediate_size``, ``score_func``,
+    ``route_norm``, ``route_scale``, ``mup_enabled``, ...): an RMSNorm stack
+    of four norms a layer (each sub-layer's input and its output, ``h = x +
+    N2(Attn(N1 x))``, ``x' = h + N4(F(N3 h))``), grouped-query attention
+    with QK-norm whose output is gated by a sigmoid of the layer's normed
+    input (``attn_gate``); a ``sliding_attention`` layer rotates q and k
+    (rotate-half over the whole head, ``rope_theta``) and sees
+    ``sliding_window`` positions back, a ``full_attention`` layer rotates
+    nothing and is causal alone; a bias-free SwiGLU of ``intermediate_size``
+    in the first ``num_dense_layers`` layers, after them sigmoid-routed
+    SwiGLU experts of ``moe_intermediate_size`` selected by score plus a
+    selection bias (one group), their weights normalised over the selected
+    (``route_norm``) times ``route_scale``, beside one shared SwiGLU of
+    ``num_shared_experts x moe_intermediate_size`` (the family sums the
+    width), both behind the one fourth norm; the embeddings times
+    ``sqrt(hidden_size)`` where ``mup_enabled``; a final norm, the head tied
+    or not.  ``num_experts`` is the experts held here where ``router_width``
+    gives the router's published width.  Refused by name: a ``rope_scaling``
+    that is not null, ``n_group`` / ``topk_group`` / ``num_expert_groups``
+    / ``num_limited_groups`` other than 1, a ``score_func`` other than
+    sigmoid, any other ``layer_types`` entry, a ``hidden_act`` other than
+    silu, an attention bias, window layers without a ``sliding_window`` of at
+    least 1 (the kernels take any such window, a multiple of their block or
+    not).  ``load_balance_coeff`` is read by nothing: the family's modelling
+    code returns no auxiliary loss and the step adds none;
+    ``global_attn_every_n_layers`` is what ``layer_types`` spells out."""
+    if cfg.get("rope_scaling"):
+        raise ValueError(f"rope_scaling {cfg['rope_scaling']}: the rotary "
+                         f"embedding is unscaled")
+    grouped = [k for k in ("n_group", "topk_group", "num_expert_groups",
+                           "num_limited_groups") if int(cfg.get(k, 1)) != 1]
+    if grouped:
+        raise ValueError(f"{' / '.join(grouped)}: selection over one group "
+                         f"of experts is what is written")
+    if cfg.get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"score_func {cfg['score_func']!r}: sigmoid scores "
+                         f"with normalised weights are what is written for "
+                         f"these keys")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {cfg['hidden_act']!r}: SwiGLU")
+    if cfg.get("attention_bias", False):
+        raise ValueError("attention_bias: the projections here have none")
+    types = list(cfg["layer_types"])
+    if int(cfg.get("num_hidden_layers", len(types))) != len(types):
+        raise ValueError(f"num_hidden_layers {cfg['num_hidden_layers']} "
+                         f"against {len(types)} layer_types")
+    unknown = sorted(set(types) - set(_AFMOE_LAYERS))
+    if unknown:
+        raise ValueError(f"layer_types {unknown}: sliding_attention or "
+                         f"full_attention")
+    windowed = tuple(_AFMOE_LAYERS[t] for t in types)
+    window = int(cfg.get("sliding_window") or 0) if any(windowed) else 0
+    if any(windowed) and window < 1:
+        raise ValueError(f"sliding_window {cfg.get('sliding_window')!r} with "
+                         f"sliding_attention layers: at least 1")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    n_dense = int(cfg.get("num_dense_layers", 0))
+    n_experts = int(cfg.get("router_width", cfg.get("num_experts", 0)))
+    first, count = _experts_held(cfg, n_experts)
+    moe_ff = int(cfg.get("moe_intermediate_size", 0))
+    ffns = tuple("glu" if i < n_dense or not n_experts else "moe_routed"
+                 for i in range(len(types)))
+    routed = "moe_routed" in ffns
+    return Arch(
+        d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=int(cfg.get("head_dim") or d // heads),
+        ff=int(cfg["intermediate_size"]),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=("attention",) * len(types), ffns=ffns, norm="rms",
+        eps=float(cfg.get("rms_norm_eps", 1e-5)), qk_norm=True,
+        rope_theta=float(cfg.get("rope_theta", 1e4)) if any(windowed)
+        else None,
+        n_experts=n_experts if routed else 0, experts_first=first,
+        experts_held=count if routed else 0,
+        top_k=int(cfg.get("num_experts_per_tok", 1)), moe_ff=moe_ff,
+        score="sigmoid", expert_bias=True,
+        norm_topk=bool(cfg.get("route_norm", True)),
+        routed_scale=float(cfg.get("route_scale", 1.0)), final_norm=True,
+        tied=bool(cfg.get("tie_word_embeddings", False)),
+        shared_ff=int(cfg.get("num_shared_experts", 0)) * moe_ff * routed,
+        sandwich=True,
+        embed_mult=float(np.sqrt(d)) if cfg.get("mup_enabled", False)
+        else 1.0,
+        window=window, windowed=windowed, rotated=windowed, attn_gate=True)
+
+
 #: ``model_type`` -> the reader of that family's keys
 _FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch,
              "ouro": _ouro_arch, "KeyeVL2": _keye_vl2_arch,
              "granitemoehybrid": _granitemoehybrid_arch,
-             "nemotron_h": _nemotron_h_arch}
+             "nemotron_h": _nemotron_h_arch, "afmoe": _afmoe_arch}
 
 
 def arch_from_config(cfg, vocab: int | None = None) -> Arch:
@@ -703,8 +867,8 @@ def arch_from_config(cfg, vocab: int | None = None) -> Arch:
     (:data:`_FAMILIES`: :func:`_lfm2_moe_arch`, also what a mapping with
     ``layer_types`` and no ``model_type`` is read as,
     :func:`_glm4_moe_lite_arch`, :func:`_ouro_arch`,
-    :func:`_keye_vl2_arch`, :func:`_granitemoehybrid_arch` and
-    :func:`_nemotron_h_arch`).
+    :func:`_keye_vl2_arch`, :func:`_granitemoehybrid_arch`,
+    :func:`_nemotron_h_arch` and :func:`_afmoe_arch`).
     ``experts_held`` (``{"first", "count"}``; all by default) is this chip's
     share of the experts;
     ``vocab`` (the loader's) overrides ``vocab_size``.  Any other
@@ -733,7 +897,7 @@ def as_arch(arch, d=None, heads=None, ff=None, vocab=None,
 _LEAF_MECHANISMS = {
     "w_in": "gated short convolution", "q_g": "QK-norm",
     "w3": "SwiGLU", "wkv_a": "latent attention", "sw1": "shared expert",
-    "ln1o_g": "sandwich norm",
+    "ln1o_g": "sandwich norm", "wg": _ATTN_GATE_MECHANISM,
     "wiq": "learned sparse attention (indexer)",
     "ssm_a_log": _SSM_MECHANISM,
 }

@@ -184,7 +184,7 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     elif mixer == "mamba":
         x, stats = _block_ssm(x, p, arch, f"block{index}.ssm")
     elif mixer != "none":
-        x, stats = _block_attn(x, p, arch, run, f"block{index}.attn")
+        x, stats = _block_attn(x, p, arch, run, f"block{index}.attn", index)
     if ffn == "moe_routed":
         x, aux, routed = _block_routed(x, p, arch, f"block{index}.moe")
         stats = {**stats, **routed}
@@ -211,12 +211,14 @@ def _block_ssm(x, p, arch: Arch, scope: str):
         return x + _sub_out(y, p, "ln1o", arch), stats
 
 
-def _plain_qkv(h, p, arch: Arch, run: _Run):
+def _plain_qkv(h, p, arch: Arch, run: _Run, rotates: bool,
+               windowed: bool = False):
     """Queries, keys and values ``(b, t, heads, head_dim)`` of plain or
     grouped-query attention: three projections, the score scale where it is
     not the kernels' own (``arch.attn_mult``: q takes ``attn_mult *
     sqrt(head_dim)``, exact where that is a power of two), the optional
-    QK-norm, the optional rotary embedding over the whole head: rotate-half, by
+    QK-norm, and where the layer ``rotates`` (``arch.rotates``) the rotary
+    embedding over the whole head: rotate-half, by
     the in-place row kernel where :func:`_rows_rope` says so (a head of 128:
     the whole head is the kernel's tail), else :func:`_rotate`'s f32 chain of
     array operations.  What no kernel wrote is named ``attn_qkv`` for the
@@ -236,18 +238,19 @@ def _plain_qkv(h, p, arch: Arch, run: _Run):
     if arch.qk_norm:
         q = _rms_norm(q, p["q_g"], arch.eps)
         k = _rms_norm(k, p["k_g"], arch.eps)
-    if arch.rope_theta is not None and _rows_rope(t_loc, arch, run):
+    if rotates and _rows_rope(t_loc, arch, run, windowed):
         from znicz_tpu.ops.pallas import rope as prope
         cos, sin = _rope_angles(t_loc, arch.head_dim, arch.rope_theta)
         return tuple(prope.rope_tail(
             a.reshape(b, t_loc, -1), cos, sin, a.shape[2],
             run.interpret).reshape(a.shape) for a in (q, k)) + (v,)
-    if arch.rope_theta is not None:
+    if rotates:
         q, k = _rotate(q, arch.rope_theta), _rotate(k, arch.rope_theta)
     return checkpoint_name(q, "attn_qkv"), checkpoint_name(k, "attn_qkv"), v
 
 
-def _rows_rope(t: int, arch: Arch, run: _Run) -> bool:
+def _rows_rope(t: int, arch: Arch, run: _Run,
+               windowed: bool = False) -> bool:
     """Whether the rotated columns of every head (latent attention's
     ``rope_dim`` tail; of plain attention the whole head) are rotated as
     whole rows of heads by the in-place kernel (``ops/pallas/rope.py``):
@@ -257,7 +260,7 @@ def _rows_rope(t: int, arch: Arch, run: _Run) -> bool:
     or copy it anyway."""
     from znicz_tpu.ops.pallas import attention as pattn, rope as prope
     dh = arch.head_dim
-    return run.use_flash and pattn.direct_layout(t, dh) and \
+    return run.use_flash and pattn.direct_layout(t, dh, windowed) and \
         prope.unsupported_reason(t, dh, arch.rope_dim or dh) is None
 
 
@@ -324,12 +327,17 @@ def _latent_qkv(h, p, arch: Arch, run: _Run):
     return q, k.reshape(b, t, heads, -1), v.reshape(b, t, heads, -1)
 
 
-def flash_refusal(t: int, dh: int, run: _Run, selected: bool) -> str | None:
+def flash_refusal(t: int, dh: int, run: _Run, selected: bool,
+                  window: int | None = None) -> str | None:
     """Why a layer of ``t`` local positions and a head of ``dh`` gets no
     flash kernel where ``run`` lets it try one, or None: asked by the trace
     (:func:`_block_attn`) and by ``transformer.step_choices``.  ``selected``:
-    an indexer's selection goes with it, which the blocked form alone takes."""
+    an indexer's selection goes with it, which the blocked form alone takes;
+    so it is with a ``window`` on the scores (a sharded ``seq`` axis never
+    meets one: ``transformer._check_tp`` refuses the mesh by mechanism)."""
     from znicz_tpu.ops.pallas import attention as pattn
+    if run.use_flash and window is not None:
+        return pattn.window_unsupported_reason(t, dh, window)
     if run.use_flash and selected:
         return pattn.blocked_unsupported_reason(t, dh)
     if run.use_flash:
@@ -339,7 +347,28 @@ def flash_refusal(t: int, dh: int, run: _Run, selected: bool) -> str | None:
     return None
 
 
-def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
+@functools.lru_cache(maxsize=None)
+def _report_window_choice(t: int, dh: int, window: int, why: str | None,
+                          blocked: bool) -> None:
+    """What runs a window layer's attention and why, said once a shape a
+    process: the blocked kernels with the tiles each pass's table lists of
+    the causal triangle's, or dense attention with the band as a mask."""
+    from znicz_tpu.ops.pallas import attention as pattn
+    if blocked:
+        tiles = pattn.kvb_window_tiles(t, dh, window)
+        rows = pattn.kvb_block_rows(t, dh, window=window)
+        form = ("the key/value-blocked kernels under the window (" +
+                ", ".join(f"{p} {listed} of {whole} tiles of {rows[p]} rows"
+                          for p, (listed, whole) in tiles.items()) +
+                "; a tile outside the band costs no step and no fetch)")
+    else:
+        form = ("dense attention with the band as a mask on the (t, t) "
+                "scores (" + (why or "no flash kernel on this platform or "
+                              "mesh") + ")")
+    _log.info("attention t=%d head_dim=%d window=%d: %s", t, dh, window, form)
+
+
+def _block_attn(x, p, arch: Arch, run: _Run, scope: str, index: int = 0):
     """Attention with tp-sharded heads: ring attention over the seq axis;
     with the seq axis unsharded, ``run.use_flash`` swaps the core for a
     Pallas flash kernel (ops/pallas/attention.py) — same math, no (t, t)
@@ -357,8 +386,18 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
     (``arch.index_top_k``) hands its kernels the selection
     (:func:`_select_keys`, the three scopes ``scope.index``, ``.select``,
     ``.align``) and adds ``loss_index`` (the alignment term, a local mean
-    as a regularizer's is) and the selection's counts."""
+    as a regularizer's is) and the selection's counts.  Layer ``index``
+    rotates q and k or not (``arch.rotates``) and may have a window on its
+    scores (``arch.window_of``): the blocked kernels then run under
+    ``scope.swa`` (the dense core masks the band where no kernel takes the
+    shape), and the layer counts ``attn_window`` 1 and
+    ``attn_window_tiles`` / ``attn_causal_tiles``, the tiles its three
+    passes' tables list and those the causal triangle alone would (equal
+    where the window ran as a mask).  A gated output (``arch.attn_gate``):
+    ``o * sigmoid(h wg)`` in front of the output product, under
+    ``scope.gate``."""
     from znicz_tpu.ops.pallas import attention as pattn
+    window = arch.window_of(index)
     with _probe.scope(scope):
         h = _norm(x, p, "ln1", arch)
     b, t_loc, _ = h.shape
@@ -367,25 +406,30 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
             q, k, v = _latent_qkv(h, p, arch, run)
     else:
         with _probe.scope(scope):
-            q, k, v = _plain_qkv(h, p, arch, run)
+            q, k, v = _plain_qkv(h, p, arch, run, arch.rotates(index),
+                                 window is not None)
     sel, picked = None, {}
     if arch.index_top_k:
         sel, picked = _select_keys(h, q, k, p, arch, run, scope)
-    with _probe.scope(scope):
-        dh = q.shape[-1]
-        why = flash_refusal(t_loc, dh, run, sel is not None)
-        eligible = run.use_flash or run.use_ring_flash
-        flash = eligible and not why
-        direct = bool(flash and run.use_flash and
-                      pattn.direct_layout(t_loc, dh))
-        if eligible:
-            _report_flash_choice(
-                t_loc, dh, why, direct, None if sel is None else
-                _dsa_choice(t_loc, q.shape[2], k.shape[2], dh,
-                            arch.index_heads, arch.index_dim, run.interpret))
+    dh = q.shape[-1]
+    why = flash_refusal(t_loc, dh, run, sel is not None, window)
+    eligible = run.use_flash or run.use_ring_flash
+    flash = eligible and not why
+    direct = bool(flash and run.use_flash and pattn.direct_layout(
+        t_loc, dh, window is not None))
+    if eligible:
+        _report_flash_choice(
+            t_loc, dh, why, direct, None if sel is None else
+            _dsa_choice(t_loc, q.shape[2], k.shape[2], dh,
+                        arch.index_heads, arch.index_dim, run.interpret))
+    if window is not None:
+        _report_window_choice(t_loc, dh, window, why,
+                              bool(run.use_flash and not why))
+    with _probe.scope(scope if window is None else f"{scope}.swa"):
         if run.use_flash and not why:
             o = pattn.flash_attention(q, k, v, causal=True,
-                                      interpret=run.interpret, sel=sel)
+                                      interpret=run.interpret, sel=sel,
+                                      window=window)
         elif sel is not None:
             o = _selected_attention_dense(q, k, v, sel)
         else:
@@ -396,15 +440,37 @@ def _block_attn(x, p, arch: Arch, run: _Run, scope: str):
                 o = ring_flash_attention(q, k, v, "seq", causal=True,
                                          interpret=run.interpret)
             else:
-                o = ring_attention(q, k, v, "seq", causal=True)
+                o = ring_attention(q, k, v, "seq", causal=True,
+                                   window=window)
         o = o.reshape(b, t_loc, -1)                  # (b, t_loc, d_local)
+    if arch.attn_gate:
+        with _probe.scope(f"{scope}.gate"):
+            o = o * jax.nn.sigmoid(h @ p["wg"])
+    with _probe.scope(scope):
         # a layer that ran a flash kernel counts itself, and once more if
         # its kernels read the layer's layout: known as the step is traced
         stats = {"attn_flash": jnp.ones((), jnp.float32),
                  "attn_direct": jnp.full((), float(direct), jnp.float32)} \
             if flash else {}
+        if window is not None:
+            stats.update(_window_counts(t_loc, dh, window, bool(
+                run.use_flash and not why)))
         y = tp.row_parallel(o, p["wo"], None, "model")
         return x + _sub_out(y, p, "ln1o", arch), {**stats, **picked}
+
+
+def _window_counts(t: int, dh: int, window: int, blocked: bool) -> dict:
+    """A window layer's constants of the traced step: itself, the tiles the
+    three passes' visit tables list under the window and those the causal
+    triangle's tables would (the same number where the window ran as a mask
+    on dense scores: one tile, the whole square)."""
+    from znicz_tpu.ops.pallas import attention as pattn
+    tiles = pattn.kvb_window_tiles(t, dh, window).values() if blocked \
+        else [(1, 1)]
+    listed, whole = (float(sum(n)) for n in zip(*tiles))
+    return {"attn_window": jnp.ones((), jnp.float32),
+            "attn_window_tiles": jnp.full((), listed, jnp.float32),
+            "attn_causal_tiles": jnp.full((), whole, jnp.float32)}
 
 
 def _select_keys(h, q, k, p, arch: Arch, run: _Run, scope: str):
@@ -522,14 +588,23 @@ def _block_routed(x, p, arch: Arch, scope: str):
     under ``scope``, the layer's two parts under ``scope.route`` and
     ``scope.experts``, and the shared expert, which every chip computes
     alike for every token, under ``scope.shared``.  The experts, routed
-    and shared, are of ``arch.expert_form``."""
+    and shared, are of ``arch.expert_form``.  In a sandwich-normed stack
+    the two parts' sum passes the layer's fourth norm (``ln2o``) before
+    the residual sum."""
     gated = arch.expert_form == "glu"
+    # behind a second norm (or a residual multiplier) the shared and the
+    # routed experts' sum is ONE sub-layer's output; without either the
+    # shared expert joins the stream first, as it always did
+    joined = arch.sandwich or arch.residual_mult != 1.0
+    shared = None
     with _probe.scope(scope):
         m = _norm(x, p, "ln2", arch)
     if "sw1" in p:
         with _probe.scope(f"{scope}.shared"):
-            x = x + (_glu(m, p["sw1"], p["sw3"], p["sw2"]) if gated else
-                     _relu2_mlp(m, p["sw1"], p["sw2"]))
+            shared = _glu(m, p["sw1"], p["sw3"], p["sw2"]) if gated else \
+                _relu2_mlp(m, p["sw1"], p["sw2"])
+            if not joined:
+                x = x + shared
     y, stats = moe_routed_ffn(
         m.reshape(-1, m.shape[-1]), p["gate"], p.get("ebias"), p["ew1"],
         p.get("ew3"), p["ew2"], first=arch.experts_first, top_k=arch.top_k,
@@ -537,4 +612,8 @@ def _block_routed(x, p, arch: Arch, scope: str):
         scale=arch.routed_scale, act=jax.nn.silu if gated else relu2,
         scope=scope)
     with _probe.scope(scope):
-        return x + y.reshape(m.shape), jnp.zeros((), jnp.float32), stats
+        y = y.reshape(m.shape)
+        if joined:
+            y = _sub_out(y if shared is None else y + shared, p, "ln2o",
+                         arch)
+        return x + y, jnp.zeros((), jnp.float32), stats

@@ -62,6 +62,8 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
                     "wv": (d, arch.kv_heads * hd), "wo": (arch.heads * hd, d)})
         if arch.qk_norm:
             out.update({"q_g": (hd,), "k_g": (hd,)})
+        if arch.attn_gate:
+            out["wg"] = (d, arch.heads * hd)
         if arch.index_top_k:
             hi, di = arch.index_heads, arch.index_dim
             out.update({"wiq": (d, hi * di), "wik": (d, di), "wiw": (d, hi),

@@ -90,8 +90,13 @@ def _saves(kept: tuple):
 def _recomputes_by_policy(arch: Arch) -> bool:
     """Whether the stack's layers are checkpointed by :func:`_loop_saves`
     with no keyword asking: a looped stack, a stack with state-space
-    layers (``transformer._block_fn``)."""
-    return arch.loop_steps > 1 or "mamba" in arch.mixers
+    layers, a stack with window layers (``transformer._block_fn``).  A
+    window on the scores does nothing under its own length, so such a stack
+    is one for long rows: it keeps what :func:`_loop_saves` lists and, of
+    the rest, what :func:`checkpoint_plan` finds room for in the device's
+    memory."""
+    return arch.loop_steps > 1 or "mamba" in arch.mixers or \
+        arch.window_layers() > 0
 
 
 def _n_params(arch: Arch) -> int:
@@ -114,7 +119,9 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
       compute dtype; every other leaf's update runs as its gradient lands;
     - what :func:`_loop_saves` keeps of every layer application (the
       layer's input, ``sub_out`` of each sub-layer, q, k, v and the kernel's
-      output and rows of an attention layer, ``ssm_y``, ``ssm_state`` and
+      output and rows of an attention layer (a gated output's gate is made
+      again from the layer's input and its weight counts with the masters),
+      ``ssm_y``, ``ssm_state`` and
       ``ssm_conv`` of a state-space layer, ``moe_up`` of a routed one), and
       a looped stack's outputs;
     - one layer's backward pass at work: six arrays of its widest

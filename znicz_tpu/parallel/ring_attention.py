@@ -21,9 +21,14 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def ring_attention(q, k, v, axis_name: str, causal: bool = False):
+def ring_attention(q, k, v, axis_name: str, causal: bool = False,
+                   window: int | None = None):
     """Sequence-sharded exact attention; call inside shard_map with the
-    time dimension sharded over ``axis_name``."""
+    time dimension sharded over ``axis_name``.  ``window``: the band as a
+    mask on each block's scores (``ops.attention.masked_scores``: every
+    block is still visited, so it is what a window layer falls back to on
+    an unsharded axis where no kernel takes its shape, not a way to run
+    one over a ring)."""
     axis_size = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)
     b, t_loc, h, dh = q.shape
@@ -37,7 +42,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False):
         # stays bf16-sized
         return masked_scores(jnp, q, k_blk, causal,
                              q_offset=my_idx * t_loc,
-                             k_offset=blk_idx * t_loc)
+                             k_offset=blk_idx * t_loc, window=window)
 
     def step(carry, _):
         o, m, l, k_blk, v_blk, blk_idx = carry

@@ -545,8 +545,9 @@ def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,
     - ``masked``: ``step(params, tokens, labels, mask)``; rows whose mask
       is false (the loader's padded tail) train nothing.
     - ``stats``: ``-> (params, loss, stats)``, float32 scalars: the routed
-      layers' counters, ``attn_flash`` / ``attn_direct``, the loss's named
-      terms and a looped stack's ``loop_*`` (:func:`_forward_loop_ce`).
+      layers' counters, ``attn_flash`` / ``attn_direct``, a window layer's
+      ``attn_window`` / ``attn_window_tiles`` / ``attn_causal_tiles``, the
+      loss's named terms and a looped stack's ``loop_*`` (:func:`_forward_loop_ce`).
     - ``donate``: the params' buffers are the step's to overwrite.
     - ``loss_chunks=k``: the head pass ``k`` chunks of tokens at a time
       (``head._ce_weighted``): the ``(tokens, vocab)`` logits never exist

@@ -258,6 +258,13 @@ class TransformerLMStep(AcceleratedUnit):
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
         self.attn_direct_layout_share: Optional[float] = None
+        #: the last finished pass's readings of a stack whose attention
+        #: layers have a window on their scores: ``window_layers`` (such
+        #: layers a step) and ``window_tile_share`` (tiles the blocked flash
+        #: kernels' visit tables list under the window over those the causal
+        #: triangle's list, all three passes; 1.0: the window ran as a mask
+        #: alone); empty without one
+        self.attn_counters: dict = {}
         #: the constants of the step as it is built, each under its key of
         #: ``parallel/transformer.py::step_choices`` (which documents them)
         #: and gauged by :func:`_choice_gauges`; empty or None until then.
@@ -462,6 +469,11 @@ class TransformerLMStep(AcceleratedUnit):
         if "attn_flash" in sums:
             self._publish_attn_layout(float(sums["attn_direct"]) /
                                       float(sums["attn_flash"]))
+        if "attn_window" in sums:
+            self._publish_attn_window(
+                float(sums["attn_window"]) / steps,
+                float(sums["attn_window_tiles"]) /
+                float(sums["attn_causal_tiles"]))
 
     def _publish_choices(self, choices: dict) -> None:
         """The constants of the step as it is built (``step_choices``):
@@ -491,6 +503,27 @@ class TransformerLMStep(AcceleratedUnit):
             "t, heads x head_dim) layout over the attention layers that ran "
             "a flash kernel (the rest fold their operands head-major)",
             ("unit",)).labels(unit=self.name).set(share)
+
+    def _publish_attn_window(self, layers: float, tile_share: float) -> None:
+        """A finished pass's readings of the window layers (constants of
+        the traced step): the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        self.attn_counters = {"window_layers": layers,
+                              "window_tile_share": tile_share}
+        registry.gauge(
+            "znicz_lm_attn_window_layers",
+            "attention layers of a step whose scores have a window (query i "
+            "sees key j iff 0 <= i - j < window), last class pass",
+            ("unit",)).labels(unit=self.name).set(layers)
+        registry.gauge(
+            "znicz_lm_attn_window_tile_share",
+            "tiles the key/value-blocked flash kernels' visit tables list "
+            "under the window over the tiles the causal triangle's tables "
+            "list, the three passes of every window layer of the last class "
+            "pass (1.0: the window ran as a mask alone, on dense scores or "
+            "in tiles that are all visited)",
+            ("unit",)).labels(unit=self.name).set(tile_share)
 
     def _publish_dsa(self, sums: dict, steps: float, loss: float) -> None:
         """A finished pass's readings of the indexers' selections and of
