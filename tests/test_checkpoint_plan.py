@@ -136,7 +136,8 @@ def test_both_state_space_cells_hold_the_scan_kernels_and_no_chunk_square(
 
 @pytest.mark.parametrize("config,traffic,layers,most_gib", [
     ("granite_4_0_h_micro", "train_tokens_pp4_t8192", 9, 12.95),
-    ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 4, 12.45),
+    # 12.38 GiB before the gate's kernels (PR 49), 12.68 with them
+    ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 4, 12.75),
 ])
 def test_both_state_space_cells_hold_the_convolution_kernels_and_no_wide_sum(
         config, traffic, layers, most_gib):
@@ -158,3 +159,58 @@ def test_both_state_space_cells_hold_the_convolution_kernels_and_no_wide_sum(
     assert out["conv_wide_f32"] == []
     live = out["argument_bytes"] + out["temp_bytes"]
     assert live <= most_gib * GIB, live / GIB
+
+
+@pytest.mark.parametrize("config,traffic,kernels", [
+    # one group: refused by shape, the parent's program
+    ("granite_4_0_h_micro", "train_tokens_pp4_t8192", 0),
+    ("nemotron_3_nano_30b_a3b", "train_tokens_ep8_t8192", 4),
+])
+def test_the_gate_kernels_stand_where_groups_are_several_and_keep_nothing(
+        config, traffic, kernels):
+    """The same compiled steps and the gate's kernels (``ops/pallas/
+    ssm_gate.py``): in the Nemotron cell (eight groups) the forward kernel
+    stands once a state-space layer and the backward kernel once, though
+    NOTHING of the forward kernel's is kept (the output product stands
+    inside their ``custom_vjp``, so no recomputation wants the gated rows:
+    ``tests/test_ssm_gate_kernel.py`` counts the residuals), and no
+    instruction of the step writes an array a (token, group) a row, the
+    ``jax.numpy`` form's view; in the Granite cell (one group) neither
+    kernel stands (the test above holds the steps' GiB).  The plan is the
+    parent's in both (what the first test pins: ``glu_wide`` and ``ssm_in``
+    kept with the bytes PR 47 counted), since its footprint counts nothing
+    new."""
+    from znicz_tpu.ops.pallas import ssm_gate
+
+    out = _compiled_or_skip(config, traffic)
+    assert out["gate_kernels"] == {ssm_gate.FWD_KERNEL_NAME: kernels,
+                                   ssm_gate.BWD_KERNEL_NAME: kernels}
+    assert out["group_rows"] == []
+
+
+@pytest.mark.parametrize("config,tokens,footprint,kept", [
+    ("granite_4_0_h_micro", 8192, 10_727_286_400,
+     {"glu_wide": 2_684_354_560, "ssm_in": 1_255_145_472}),
+    ("nemotron_3_nano_30b_a3b", 16384, 12_610_589_696,
+     {"glu_wide": 486_539_264, "ssm_in": 1_350_565_888}),
+])
+def test_the_plan_counts_what_it_counted_before_the_gates_kernels(
+        config, tokens, footprint, kept):
+    """The arithmetic alone (no compile): the gate's kernels leave nothing
+    kept, so ``step_footprint`` and the plan at a v5e's limit are PR 47's to
+    the byte in both state-space cells; at the Granite cell's shape
+    ``glu_wide`` and ``ssm_in`` stay on the kept list (0.09 GiB to spare: a
+    kept gate result of 0.56 GiB would have cost one of them)."""
+    from znicz_tpu.parallel import transformer as tfm
+    from znicz_tpu.parallel.plan import (PLAN_MARGIN, checkpoint_plan,
+                                         step_footprint)
+
+    with open(os.path.join(os.path.dirname(HERE), "benchmark", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    opts = cfg["builders"]["lm_train_keys"]
+    arch = tfm.arch_from_config({k: cfg[k] for k in opts["model_keys"]})
+    assert step_footprint(arch, tokens, 2, opts["loss_chunks"]) == footprint
+    assert checkpoint_plan(arch, tokens, 2, int(HBM_USABLE),
+                           opts["loss_chunks"]) == kept
+    assert footprint + sum(kept.values()) + PLAN_MARGIN <= HBM_USABLE

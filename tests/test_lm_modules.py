@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from test_lfm2_arch import _pallas_interpret
 from znicz_tpu.ops.pallas import (attention as pattn, dsa as pdsa,
                                   grouped as pgrouped, ssd as pssd,
-                                  ssm_conv as pconv)
+                                  ssm_conv as pconv, ssm_gate as pgate)
 from znicz_tpu.parallel import plan, transformer as tfm
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel.params import param_shapes
@@ -256,6 +256,11 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
             ("mamba" not in arch.mixers)
         for kernel in (pconv.FWD_KERNEL_NAME, pconv.BWD_KERNEL_NAME):
             assert (kernel in text) == (chose["ssm_conv_kernel_share"] == 1.0)
+        # the gate's and the gated norm's form
+        assert (chose["ssm_gate_kernel_share"] is None) == \
+            ("mamba" not in arch.mixers)
+        for kernel in (pgate.FWD_KERNEL_NAME, pgate.BWD_KERNEL_NAME):
+            assert (kernel in text) == (chose["ssm_gate_kernel_share"] == 1.0)
         # what a checkpointed layer keeps
         kept = chose["checkpoint_kept_bytes"]
         if plan._recomputes_by_policy(arch):
@@ -280,7 +285,8 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
                  "ssm_conv_kernel_share"}
     if family == "nemotron_h":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share",
-                 "ssm_scan_kernel_share", "ssm_conv_kernel_share"}
+                 "ssm_scan_kernel_share", "ssm_conv_kernel_share",
+                 "ssm_gate_kernel_share"}
     if family == "afmoe":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share"}
         # the windowed kernels stand in the interpreted step by their own

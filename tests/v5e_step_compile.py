@@ -5,10 +5,11 @@ one JSON line: the compiled step's memory, the compiler's operation count,
 the checkpoint plan a v5e's memory limit gives, the footprint the plan
 reckoned with, how often each of the key/value-blocked flash kernels stands
 in the compiled step (under a window and without one) and, of a stack with
-state-space layers, how often the scan's
-and the convolution's two kernels each stand in the compiled step, which
-float32 arrays with two chunk-length axes do and which float32 arrays as
-long as the tokens and as wide as the convolution's channels.  ``tests/test_checkpoint_plan.py`` runs it.
+state-space layers, how often the scan's,
+the convolution's and the gate's two kernels each stand in the compiled
+step, which float32 arrays with two chunk-length axes do, which float32
+arrays as long as the tokens and as wide as the convolution's channels, and
+which arrays a (token, group) a row.  ``tests/test_checkpoint_plan.py`` runs it.
 
     python tests/v5e_step_compile.py CONFIG TRAFFIC [LIMIT_GIB]
 """
@@ -97,7 +98,8 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     if out:
         with open(out, "w") as f:
             f.write(text)
-    from znicz_tpu.ops.pallas import attention as pattn, ssd, ssm_conv
+    from znicz_tpu.ops.pallas import (attention as pattn, ssd, ssm_conv,
+                                      ssm_gate)
     q = arch.ssm_chunk
     channels = arch.ssm_heads * arch.ssm_head_dim + \
         2 * arch.ssm_groups * arch.ssm_state
@@ -111,6 +113,12 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
         "scan_kernels": stands(ssd.FWD_KERNEL_NAME, ssd.BWD_KERNEL_NAME),
         "conv_kernels": stands(ssm_conv.FWD_KERNEL_NAME,
                                ssm_conv.BWD_KERNEL_NAME),
+        "gate_kernels": stands(ssm_gate.FWD_KERNEL_NAME,
+                               ssm_gate.BWD_KERNEL_NAME),
+        # the gated norm's group-wise view, a (token, group) a row
+        "group_rows": _written(
+            text, rf"\w+\[{b * t * arch.ssm_groups},\d+\]")
+        if arch.ssm_groups > 1 else [],
         # the blocked flash kernels, under a window and without one
         # (``\b``: the plain names are no prefix of the windowed ones, but
         # of nothing else either)

@@ -60,7 +60,9 @@ def _loop_saves(prim, *_, **params) -> bool:
     a chunk's decay and score matrices again, ``parallel/ssm.py``) and, where
     the convolution's kernels run, their result (``ssm_conv``, the scan's
     ``x | B | C``), with the wide input projection and its split, the
-    ``jax.numpy`` convolution, the gate and the gated norm made again: a
+    ``jax.numpy`` convolution, the gate and the gated norm made again (where
+    the gate's kernels run, ``ops/pallas/ssm_gate.py``, neither made again
+    nor kept: their backward kernel writes the gated rows once more): a
     layer holds five or six arrays of ``(tokens, d)``, the scan's operand
     and its chunk states where it would hold ``(tokens, 8.5 d)`` of them.
     Of a routed expert layer in such a stack it keeps the router's choice (``moe_route``: the weights, the sort and its

@@ -70,6 +70,23 @@ products, so that the array the kernels cut is whole lane tiles wide (the
 64 lanes of ``dt`` behind it make every reader of it slow on a TPU).
 Everywhere else :func:`_conv`, the ``jax.numpy`` form, on one product.
 
+**The gate and the gated norm** behind the scan have two forms too, chosen
+the same way (:func:`gate_kernel_refusal`): where the kernels run, there
+are SEVERAL groups of whole lane tiles each and ``t`` is whole row tiles, two
+Pallas kernels behind a ``jax.custom_vjp`` (``ops/pallas/ssm_gate.py``:
+``ssm_gate_fwd``, ``ssm_gate_bwd``) read the scan's result as its kernel
+wrote it and ``z`` from the projection's own lanes, a tile of rows of one
+group's lanes a visit, take the statistic over the lanes the visit holds and
+write the operand of the output product; the backward kernel makes the
+product, the statistic and the sigmoid again and sums the gain's gradient in
+VMEM.  The group-wise view ``(tokens x groups, H P / G)`` of the ``jax.numpy``
+form below, which is no bitcast on a TPU's tiles and moves three or four
+``(b, t, H P)`` arrays a layer and a pass, does not exist there.  With ONE
+group that form has no such view and stands at its traffic's least as XLA
+compiles it, so one group is refused with that sentence (the kernels
+themselves take any group of whole lane tiles; ``PERF.md`` section 6, PR 49,
+has both forms' times alone at both cells' shapes).
+
 **What the backward pass keeps**, in either form: the operands and each
 chunk's opening state (named ``ssm_state``: ``(b, t / Q, H, P, N)``
 float32 here, ``(b, t / Q, H x P, N)`` in the operands' dtype from the
@@ -85,8 +102,14 @@ _block_fn``) keeps it as it keeps every kernel's, so that kernel runs once;
 the ``jax.numpy`` form is made again.  The projection's result is named
 here (``ssm_in``) for ``plan.py::checkpoint_plan`` to keep or refuse; where
 it is refused the product is made again and both convolution kernels read
-that.  The mixer's leaves and their shapes are ``params.py``'s
-(``_ssm_leaf_shapes``).
+that.  Of the gate's kernels NOTHING is kept: the output product stands
+inside their ``custom_vjp``, whose backward rule reads the operands alone
+(``ssm_y``, the projection, the gain, the weight) and takes the gated rows
+for the weight's gradient from the backward kernel, which writes them again
+beside ``dy`` and ``dz`` (one more write of ``(b, t, H P)`` in 16 bits
+against 0.125 GiB a layer kept; the layer's ``sub_out`` is kept, so no
+recomputation wants the forward kernel either).  The mixer's leaves and
+their shapes are ``params.py``'s (``_ssm_leaf_shapes``).
 """
 
 from __future__ import annotations
@@ -100,7 +123,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from znicz_tpu.observe import probe as _probe
-from znicz_tpu.ops.pallas import ssd as _pssd, ssm_conv as _pconv
+from znicz_tpu.ops.pallas import (ssd as _pssd, ssm_conv as _pconv,
+                                  ssm_gate as _pgate)
 
 _log = logging.getLogger("znicz_tpu.transformer")
 
@@ -203,6 +227,15 @@ def _kernels_eligible(interpret: bool) -> bool:
     return interpret or jax.default_backend() == "tpu"
 
 
+def _backend_refusal(interpret: bool) -> str | None:
+    """Why none of the step's kernels runs here, or ``None`` where they
+    may."""
+    if _kernels_eligible(interpret):
+        return None
+    return (f"the backend is {jax.default_backend()} and the step's "
+            f"kernels are not interpreted")
+
+
 def scan_kernel_refusal(t: int, heads: int, head_dim: int, state: int,
                         groups: int, chunk: int, itemsize: int,
                         interpret: bool) -> str | None:
@@ -213,11 +246,8 @@ def scan_kernel_refusal(t: int, heads: int, head_dim: int, state: int,
     the shape is one they take (``ssd.unsupported_reason``: the kernel's own
     reasons).  What :func:`ssd` asks as the step is traced and
     ``transformer.step_choices`` before."""
-    if not _kernels_eligible(interpret):
-        return (f"the backend is {jax.default_backend()} and the step's "
-                f"kernels are not interpreted")
-    return _pssd.unsupported_reason(min(int(chunk), t), heads, groups,
-                                    head_dim, state, itemsize)
+    return _backend_refusal(interpret) or _pssd.unsupported_reason(
+        min(int(chunk), t), heads, groups, head_dim, state, itemsize)
 
 
 def conv_kernel_refusal(t: int, start: int, width: int, taps: int,
@@ -229,10 +259,29 @@ def conv_kernel_refusal(t: int, start: int, width: int, taps: int,
     and the shape is one they take (``ssm_conv.unsupported_reason``).  What
     :func:`mixer` asks as the step is traced and ``transformer.
     step_choices`` before."""
-    if not _kernels_eligible(interpret):
-        return (f"the backend is {jax.default_backend()} and the step's "
-                f"kernels are not interpreted")
-    return _pconv.unsupported_reason(t, start, width, taps)
+    return _backend_refusal(interpret) or \
+        _pconv.unsupported_reason(t, start, width, taps)
+
+
+def gate_kernel_refusal(t: int, inner: int, groups: int, start: int,
+                        itemsize: int, interpret: bool) -> str | None:
+    """Why the gate and the gated norm of ``inner`` entries of ``itemsize``
+    bytes in ``groups`` groups, ``z`` the lanes ``[start, start + inner)``
+    of the projection, over rows of ``t`` positions run in their
+    ``jax.numpy`` form (the closing lines of :func:`mixer`), or ``None``
+    where the kernels of ``ops/pallas/ssm_gate.py`` run them: where the
+    step's kernels run at all, the shape is one they take
+    (``ssm_gate.unsupported_reason``) and there are several groups.  What
+    :func:`mixer` asks as the step is traced and ``transformer.
+    step_choices`` before."""
+    if groups == 1 and _kernels_eligible(interpret):
+        # measured, PERF.md section 6 (PR 49): the kernels take a group of
+        # any whole lane tiles, all 4,096 of one too
+        return ("one group: the statistic over a whole row has no "
+                "group-wise view, and the compiled jax.numpy form already "
+                "stands at its traffic's least there")
+    return _backend_refusal(interpret) or \
+        _pgate.unsupported_reason(t, inner, groups, start, itemsize)
 
 
 @functools.lru_cache(maxsize=None)
@@ -338,6 +387,9 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
     conv_kernels = _kernels_or_none(
         "convolution", conv_kernel_refusal, "t start width taps",
         (t, inner, inner + 2 * bc, p["ssm_conv_k"].shape[0]))
+    gate_kernels = _kernels_or_none(
+        "gate", gate_kernel_refusal, "t inner groups start",
+        (t, inner, groups, 0), itemsize=u.dtype.itemsize)
     with _probe.scope(scope):
         w_in, wide = p["ssm_in"], 2 * inner + 2 * bc
         if conv_kernels is None:
@@ -354,6 +406,9 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
             # PR 47)
             proj = checkpoint_name(u @ w_in[:, :wide], "ssm_in")
             dt = checkpoint_name(u @ w_in[:, wide:], "ssm_in")
+        # cut here, in front of the convolution's, as the projection's
+        # cotangents are summed in the order of its uses (the gate's
+        # kernels cut their own and leave this one dead)
         z = proj[..., :inner]
     with _probe.scope(f"{scope}.conv"):
         xbc = _conv_silu(proj, p["ssm_conv_k"], p["ssm_conv_b"], inner,
@@ -378,6 +433,11 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
                      jnp.sqrt((last * last).mean((1, 2, 3))).mean(),
                  "ssm_layers": jnp.ones((), jnp.float32)}
     with _probe.scope(scope):
+        if gate_kernels is not None:
+            # z by a block spec on the projection's first lanes
+            return _pgate.gate_out(
+                y, proj, p["ssm_g"].reshape(1, inner).astype(u.dtype),
+                p["ssm_out"], 0, groups, float(eps), gate_kernels), stats
         gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
         # the statistic over each group's entries (the gain lies a group a
         # row, ``params._ssm_leaf_shapes``); over all of them with one

@@ -471,7 +471,12 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
     - ``ssm_conv_kernel_share``: of the state-space layers, the share whose
       convolution, bias and ``silu`` the kernels of ``ops/pallas/
       ssm_conv.py`` run (all or none: ``ssm.conv_kernel_refusal``; the rest
-      ``ssm._conv``); None without a state-space layer."""
+      ``ssm._conv``); None without a state-space layer;
+    - ``ssm_gate_kernel_share``: of the state-space layers, the share whose
+      gate, gated group norm and output product the kernels of ``ops/pallas/
+      ssm_gate.py`` run (all or none: ``ssm.gate_kernel_refusal``, which
+      also refuses ONE group; the rest the closing lines of ``ssm.mixer``);
+      None without a state-space layer."""
     from znicz_tpu.ops.pallas import attention as pattn
     run = _run_of(mesh, arch, arch.vocab if head_sharded else None)
     b_loc = batch // mesh.shape.get("data", 1)
@@ -495,16 +500,19 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
             _default_compute_dtype(), run.interpret)
             for r in {pairs, compact_rows(pairs, arch.experts_held,
                                           arch.n_experts)}))
-    scan = conv = None
+    scan = conv = gate = None
     if "mamba" in arch.mixers:
         inner = arch.ssm_heads * arch.ssm_head_dim
+        itemsize = jnp.dtype(_default_compute_dtype()).itemsize
         conv = float(ssm.conv_kernel_refusal(
             t_loc, inner, inner + 2 * arch.ssm_groups * arch.ssm_state,
             arch.conv_taps, run.interpret) is None)
         scan = float(ssm.scan_kernel_refusal(
             t_loc, arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state,
-            arch.ssm_groups, arch.ssm_chunk,
-            jnp.dtype(_default_compute_dtype()).itemsize,
+            arch.ssm_groups, arch.ssm_chunk, itemsize,
+            run.interpret) is None)
+        gate = float(ssm.gate_kernel_refusal(
+            t_loc, inner, arch.ssm_groups, 0, itemsize,
             run.interpret) is None)
     return {
         "ce_grad_in_forward_share": float(ce_grad_in_forward(
@@ -514,7 +522,7 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
             arch, run, b_loc * t_loc, _default_compute_dtype(), loss_chunks)),
         "dsa_index_kernel_share": index, "dsa_align_kernel_share": align,
         "moe_gmm_kernel_share": gmm, "ssm_scan_kernel_share": scan,
-        "ssm_conv_kernel_share": conv}
+        "ssm_conv_kernel_share": conv, "ssm_gate_kernel_share": gate}
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

@@ -126,6 +126,14 @@ def _choice_gauges() -> tuple:
             "state-space layers (the rest: the jax.numpy form, which the "
             "shape or the platform left them to)",
             ("unit",)), None),
+        ("ssm_gate_kernel_share", registry.gauge(
+            "znicz_lm_ssm_gate_kernel_share",
+            "state-space layers whose gate, gated group norm and output "
+            "product the Pallas kernels ssm_gate_fwd and ssm_gate_bwd run, "
+            "a group's statistic taken in VMEM, over the state-space layers "
+            "(the rest: the jax.numpy form, which one group, the shape or "
+            "the platform left them to)",
+            ("unit",)), None),
     )
 
 
@@ -277,10 +285,12 @@ class TransformerLMStep(AcceleratedUnit):
         #: of the routed expert layers, the share whose grouped products
         #: the Pallas kernels make; None without one
         self.moe_gmm_kernel_share: Optional[float] = None
-        #: of the state-space layers, the share whose scan, and whose
-        #: convolution, the Pallas kernels run; None without one
+        #: of the state-space layers, the share whose scan, whose
+        #: convolution, and whose gate and gated norm, the Pallas kernels
+        #: run; None without one
         self.ssm_scan_kernel_share: Optional[float] = None
         self.ssm_conv_kernel_share: Optional[float] = None
+        self.ssm_gate_kernel_share: Optional[float] = None
         #: ``{name: bytes}`` the checkpointed layers keep beside their
         #: policy's own list (``parallel/plan.py::checkpoint_plan``)
         self.checkpoint_kept_bytes: dict = {}
