@@ -12,8 +12,6 @@ hook                  call site
                       ``znicz_unit_runs_total`` / ``znicz_unit_run_
                       seconds_total`` (labels: workflow, unit); the
                       registry children ARE what ``timing_table()`` reads
-``step_histogram``    ``core/workflow.py`` run loop — per signal-delivery
-                      wall time into ``znicz_workflow_step_seconds``
 ``watch_compiles`` /  ``parallel/step.py`` registers its jitted
 ``check_recompiles``  functions; the workflow loop polls their
                       ``_cache_size()`` sum — a positive delta increments
@@ -53,7 +51,7 @@ import functools
 import re
 import time
 import weakref
-from typing import Optional
+from typing import NamedTuple, Optional
 
 from znicz_tpu.observe import registry as _reg
 from znicz_tpu.observe import trace as _trace
@@ -127,10 +125,6 @@ def unit_timing_rows(workflow_name: str, unit_names) -> list:
                                     unit=name).get()
         rows.append((secs, int(runs), name))
     return rows
-
-
-def step_histogram():
-    return _STEP_SECONDS
 
 
 def signal_dispatched(dt_s: float) -> None:
@@ -344,15 +338,18 @@ class _CompileTimed:
     ``_cache_size`` delegates so :func:`watch_compiles` keeps polling the
     real compile cache through the wrapper.  The first call's argument
     shapes, dtypes and shardings are remembered (no buffer is kept), so
-    :func:`scope_map` can lower the same program again when asked."""
+    :func:`scope_table` can lower the same program again when asked."""
 
-    __slots__ = ("_fn", "_label", "_cold", "_abstract", "__weakref__")
+    __slots__ = ("_fn", "_label", "_cold", "_abstract", "_table",
+                 "__weakref__")
 
     def __init__(self, fn, label: str) -> None:
         self._fn = fn
         self._label = label
         self._cold = True
         self._abstract = None
+        #: (the signature parsed, module, rows), by :func:`scope_table`
+        self._table = None
         _timed_programs.add(self)
         _recent_programs.append(self)
 
@@ -398,6 +395,8 @@ _OPERAND = re.compile(r"%([\w.\-]+)")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$")
 #: a compiler option at its default: changes nothing but jit's memo
 _FRESH_COMPILE = {"xla_embed_ir_in_executable": False}
+#: what marks a ``custom-call`` as a Mosaic (Pallas) kernel
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 #: instructions that are no work of their own
 TRIVIAL_OPCODES = frozenset((
     "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
@@ -448,39 +447,157 @@ def _abstract_call(args, kw):
     return jax.tree.map(leaf, (args, kw))
 
 
+def _bare(component: str) -> str:
+    """``transpose(jvp(conv.00_c))`` -> ``conv.00_c``."""
+    return component.rstrip(")").rsplit("(", 1)[-1]
+
+
 def scope_of(op_name: str, names=None) -> str:
     """The program's scope in one ``op_name`` path: its outermost
     component that is a name opened through :func:`scope`, kept as it
     stands there -- ``conv.00_Conv`` in an eval pass, ``jvp(conv.00_
     Conv)`` in a differentiated forward, ``transpose(jvp(conv.00_
-    Conv))`` in the backward pass -- or ``""`` under none."""
+    Conv))`` in the backward pass -- or ``""`` under none.  This is all
+    the projection (:func:`scope_map`) keeps of a path: a scope opened
+    inside another, and a ``transpose(`` or ``rematted_computation``
+    that stands on an ancestor (a scan's, a checkpoint's), are the
+    table's (:func:`path_of`, :func:`way_of`)."""
     names = _scope_names if names is None else names
     for part in op_name.split("/"):
-        if part.rstrip(")").rsplit("(", 1)[-1] in names:
+        if _bare(part) in names:
             return part
     return ""
 
 
-def parse_scopes(hlo_text: str, names=None) -> tuple:
+def path_of(op_name: str, names=None) -> tuple:
+    """Every scope of one ``op_name`` path that was opened through
+    :func:`scope` / :func:`scope_bwd`, outermost to innermost, as bare
+    names: ``("block3.ssm", "block3.ssm.gate")``."""
+    names = _scope_names if names is None else names
+    return tuple(b for b in map(_bare, op_name.split("/")) if b in names)
+
+
+def way_of(op_name: str) -> str:
+    """The pass one ``op_name`` path belongs to: ``remat`` where a
+    component is ``rematted_computation`` (a checkpoint's forward made
+    again; it stands under a ``transpose(`` ancestor), ``bwd`` where ANY
+    other path has a component that starts with ``transpose(`` -- the
+    scope's own (``transpose(jvp(conv.00_c))``, :func:`scope_bwd`'s
+    literal label) or an ancestor's (a scan's ``transpose(jvp())/while/
+    body``, a checkpoint's ``transpose(jvp(jvp()))/checkpoint``) -- else
+    ``fwd``."""
+    parts = op_name.split("/")
+    if "rematted_computation" in parts:
+        return "remat"
+    if any(p.startswith("transpose(") for p in parts):
+        return "bwd"
+    return "fwd"
+
+
+class ScopeRow(NamedTuple):
+    """What :func:`parse_scopes` knows of one instruction."""
+
+    #: the projection: :func:`scope_of` of the ``op_name`` the scope came
+    #: from (its own, its fusion's root's, a neighbour's), ``""`` under none
+    scope: str
+    #: :func:`path_of` of that same ``op_name``
+    path: tuple
+    #: ``fwd`` | ``remat`` | ``bwd``: :func:`way_of` of that ``op_name``,
+    #: or, of an instruction whose scope is a neighbour's or nobody's, of
+    #: its own ``op_name`` where it has one
+    way: str
+    #: where the scope came from: ``own`` (it stands in the instruction's
+    #: ``op_name``), ``root`` / ``inside`` (a fusion without one: the root
+    #: of the computation it calls, else the first scoped instruction in
+    #: it), ``lent`` (a neighbour's), ``none``
+    how: str
+    #: a fusion whose fused instructions carry more than one
+    #: ``(outermost scope, way)``
+    mixed: bool
+    #: ``kernel`` (a Mosaic ``custom-call``), ``product`` (it, or the
+    #: computation it calls, holds a ``convolution`` / ``dot``), ``stack``
+    #: (a ``dynamic-update-slice`` / ``dynamic-slice`` under ``while/body``
+    #: that stands under no scope, or a scopeless fusion around one),
+    #: ``copy``, ``pad``, ``other``
+    holds: str
+    #: ``(outermost scope, way)`` by what the instruction does: of the
+    #: products a fusion holds where they are all one scope's, else of
+    #: ``path`` and ``way``; of a ``stack`` instruction the scope of the
+    #: value it writes (the update's producer) or reads (the slice's
+    #: consumers), which no order of the text moves
+    by_work: tuple
+
+    @property
+    def moved(self) -> bool:
+        """``by_work`` names another scope than the projection does."""
+        return self.by_work[0] != (self.path[0] if self.path else "")
+
+
+_NO_OP = ("", (), "fwd")
+_PRODUCTS = ("convolution", "dot")
+_STACKS = ("dynamic-update-slice", "dynamic-slice")
+
+
+def _operand_text(line: str, start: int) -> str:
+    """The operand list that opens at ``line[start]``, to its ``)``."""
+    depth = 1
+    for i in range(start, len(line)):
+        if line[i] == "(":
+            depth += 1
+        elif line[i] == ")":
+            depth -= 1
+            if not depth:
+                return line[start:i]
+    return line[start:]
+
+
+def _commonest(scopes) -> str:
+    """The scope most of ``scopes`` name; the first by name on a tie."""
+    counts = collections.Counter(s for s in scopes if s)
+    return min(counts, key=lambda s: (-counts[s], s)) if counts else ""
+
+
+def parse_scopes(hlo_text: str, names=None, rows: bool = False) -> tuple:
     """``(module name, {instruction name: scope})`` from one optimised
-    HLO module's text.  An instruction takes, in this order: the scope
-    in its own ``op_name``; that of the root of the computation it calls
-    (a fusion), else of any instruction in it; and, where the compiler
-    gave it no metadata (a layout ``copy``, an async slice or copy, a
-    scalar it moved), the scope of the first instruction that consumes
-    it, else of an operand -- whose layout the copy is -- else of the
-    instruction that calls its computation.  Trivial instructions
+    HLO module's text, or, with ``rows``, ``{instruction name:``
+    :class:`ScopeRow` ``}`` from the same one parse: the first is the
+    second's ``scope`` column.  An instruction takes, in this order: the
+    scope in its own ``op_name``; that of the root of the computation it
+    calls (a fusion), else of any instruction in it; and, where the
+    compiler gave it no metadata (a layout ``copy``, an async slice or
+    copy, a scalar it moved), the scope of the first instruction that
+    consumes it, else of an operand -- whose layout the copy is -- else of
+    the instruction that calls its computation.  Trivial instructions
     (:data:`TRIVIAL_OPCODES`) pass scopes on but are left out; ``""`` is
-    what remains under no scope."""
+    what remains under no scope.
+
+    The projection keeps the outermost scope's component as it stands
+    (:func:`scope_of`) and says nothing of the rule that gave it.  A row
+    adds every scope of the path, the pass read off ANY component of it,
+    which rule gave the scope (the first-neighbour rule follows the order
+    of the text, so ``lent`` time is what a group may gain or lose with no
+    work moved), what the instruction holds, and ``by_work``, an
+    attribution that looks at the work and not at the text's order."""
     module, comp = "", None
     scope: dict = {}          # instruction -> scope
+    given: dict = {}          # instruction -> (scope, path, way) it took
+    how: dict = {}            # instruction -> own | root | inside | lent
+    own_way: dict = {}        # instruction with an op_name -> its way
     operands: dict = {}       # instruction -> [operand names]
+    n_args: dict = {}         # instruction -> how many of them are operands
+    arg_text: dict = {}       # parameter -> its number, as text
     opcode_of: dict = {}
     comp_of: dict = {}        # instruction -> its computation
+    members: dict = {}        # computation -> [its instructions]
+    root_of: dict = {}        # computation -> its root
     fused: dict = {}          # instruction -> the computation it calls=
+    fusion_of: dict = {}      # computation -> the fusion that calls= it
     caller: dict = {}         # computation -> an instruction that calls it
     roots: dict = {}          # computation -> scope of its root
     inside: dict = {}         # computation -> first scope found in it
+    inside_of: dict = {}      # computation -> the instruction that had it
+    kernels: set = set()      # Mosaic custom-calls
+    bare_stacks: set = set()  # stack instructions under no scope
     for line in hlo_text.splitlines():
         if line.startswith("HloModule "):
             module = line.split()[1].rstrip(",")
@@ -494,19 +611,39 @@ def parse_scopes(hlo_text: str, names=None) -> tuple:
         is_root, name, opcode = m.groups()
         op = _OP_NAME.search(line)
         sc = scope_of(op.group(1), names) if op else ""
+        if op:
+            own_way[name] = way_of(op.group(1))
         if is_root:
-            roots[comp] = sc
+            roots[comp], root_of[comp] = sc, name
         if sc:
-            inside.setdefault(comp, sc)
+            given[name] = (sc, path_of(op.group(1), names), own_way[name])
+            how[name] = "own"
+            if comp not in inside:
+                inside[comp], inside_of[comp] = sc, name
+        elif op and opcode in _STACKS and \
+                "/while/body/" in op.group(1) + "/":
+            bare_stacks.add(name)
         for key, callee in _CALLED.findall(line):
             caller.setdefault(callee, name)
             if key == "calls":
                 fused[name] = callee
+                fusion_of[callee] = name
         scope[name], opcode_of[name], comp_of[name] = sc, opcode, comp
+        members.setdefault(comp, []).append(name)
         operands[name] = _OPERAND.findall(line[m.end():])
+        args = _operand_text(line, m.end())
+        n_args[name] = len(_OPERAND.findall(args))
+        if opcode == "parameter":
+            arg_text[name] = args
+        elif opcode == "custom-call" and _MOSAIC_CALL in line:
+            kernels.add(name)
     for name, called in fused.items():
         if not scope[name]:
             scope[name] = roots.get(called) or inside.get(called, "")
+            if scope[name]:
+                rule = "root" if roots.get(called) else "inside"
+                src = (root_of if rule == "root" else inside_of)[called]
+                given[name], how[name] = given[src], rule
     users: dict = {}
     for name, ops in operands.items():
         for o in ops:
@@ -519,8 +656,8 @@ def parse_scopes(hlo_text: str, names=None) -> tuple:
         for n in (*users.get(name, ()), *operands.get(name, ()),
                   caller.get(comp_of[name])):
             if scope.get(n):
-                return scope[n]
-        return ""
+                return n
+        return None
 
     # hand scopes down chains of bare instructions (copy-start ->
     # copy-done -> bitcast -> the fusion that reads it)
@@ -529,19 +666,123 @@ def parse_scopes(hlo_text: str, names=None) -> tuple:
         for name, sc in scope.items():
             if not sc:
                 got = neighbour(name)
-                if got:
-                    scope[name], moved = got, True
+                if got is not None:
+                    scope[name], moved = scope[got], True
+                    given[name], how[name] = given[got], "lent"
         if not moved:
             break
-    return module, {n: sc for n, sc in scope.items()
-                    if opcode_of[n] not in TRIVIAL_OPCODES}
+    kept = {n: sc for n, sc in scope.items()
+            if opcode_of[n] not in TRIVIAL_OPCODES}
+    if not rows:
+        return module, kept
+
+    def settled(name) -> str:
+        """The outermost scope an instruction has by its own metadata or
+        its fused computation's: nothing lent."""
+        if how.get(name) in ("own", "root", "inside"):
+            return given[name][1][0]
+        return ""
+
+    def args_of(name) -> list:
+        return operands[name][:n_args[name]]
+
+    def producer_scope(name) -> str:
+        """Up from a value to the first instruction that has a scope."""
+        for _ in range(16):
+            if name not in opcode_of:
+                return ""
+            if settled(name):
+                return settled(name)
+            fusion = fusion_of.get(comp_of[name])
+            if opcode_of[name] == "parameter" and fusion is not None:
+                k = int(arg_text[name])
+                if k >= n_args[fusion]:
+                    return ""
+                name = operands[fusion][k]
+            elif n_args[name]:
+                name = operands[name][0]
+            else:
+                return ""
+        return ""
+
+    def consumer_scope(name) -> str:
+        """Down from a value to the instructions that read it: the scope
+        most of them have."""
+        found, front, seen = [], [name], {name}
+        for _ in range(6):
+            nxt = []
+            for v in front:
+                # what a fused computation's root holds, the fusion's
+                # readers read
+                fusion = fusion_of.get(comp_of[v])
+                step = [fusion] if fusion is not None and \
+                    v == root_of[comp_of[v]] else []
+                for u in users.get(v, ()):
+                    if v not in args_of(u):
+                        continue
+                    if settled(u):
+                        found.append(settled(u))
+                    else:
+                        step.append(u)
+                nxt += [u for u in step if u not in seen]
+                seen.update(step)
+            if found or not nxt:
+                break
+            front = nxt
+        return _commonest(found)
+
+    def stack_value(name) -> str:
+        if opcode_of[name] == "dynamic-update-slice":
+            return producer_scope(args_of(name)[1]) \
+                if n_args[name] > 1 else ""
+        return consumer_scope(name)
+
+    table: dict = {}
+    for name, sc in kept.items():
+        opcode, called = opcode_of[name], fused.get(name)
+        inner = members.get(called, ()) if called else ()
+        _, path, way = given.get(name, _NO_OP)
+        rule = how.get(name, "none")
+        if rule in ("lent", "none") and name in own_way:
+            way = own_way[name]
+        held, made = set(), set()     # (scope, way) inside; of products
+        for i in inner:
+            if how.get(i) == "own":
+                held.add((given[i][1][0], given[i][2]))
+                if opcode_of[i] in _PRODUCTS:
+                    made.add((given[i][1][0], given[i][2]))
+        stacks = [i for i in (name, *inner) if i in bare_stacks] \
+            if rule != "own" else []
+        first = re.split(r"[._]", name)[0]
+        if name in kernels:
+            holds = "kernel"
+        elif opcode in _PRODUCTS or \
+                any(opcode_of[i] in _PRODUCTS for i in inner):
+            holds = "product"
+        elif stacks:
+            holds = "stack"
+        else:
+            holds = next((k for k in ("copy", "pad") if opcode == k or
+                          opcode.startswith(k + "-") or first == k), "other")
+        by_work = (path[0] if path else "", way)
+        if len(made) == 1:
+            by_work = next(iter(made))
+        elif holds == "stack":
+            value = _commonest(stack_value(i) for i in stacks)
+            if value:
+                by_work = (value, way)
+        table[name] = ScopeRow(sc, path, way, rule, len(held) > 1, holds,
+                               by_work)
+    return module, table
 
 
-def scope_map() -> dict:
-    """``{module name: {instruction name: scope}}`` for every program
-    wrapped by :func:`time_compiles` that has run: each is lowered again
-    from the remembered shapes of its first call, compiled, and its
-    optimised HLO parsed by :func:`parse_scopes`.
+def scope_table() -> dict:
+    """``{module name: {instruction name:`` :class:`ScopeRow` ``}}`` for
+    every program wrapped by :func:`time_compiles` that has run: each is
+    lowered again from the remembered shapes of its first call, compiled,
+    and its optimised HLO parsed by :func:`parse_scopes`; the rows are
+    remembered with the program, so this and :func:`scope_map` cost one
+    compile a program between them however often either is asked.
 
     The compile has to be a fresh one.  The executable that runs may
     have come out of the persistent cache, whose key leaves metadata
@@ -562,15 +803,26 @@ def scope_map() -> dict:
         for prog in list(_timed_programs):
             if prog._abstract is None:
                 continue
-            args, kw = prog._abstract
-            text = prog.lower(*args, **kw).compile(
-                compiler_options=_FRESH_COMPILE).as_text()
-            module, scopes = parse_scopes(text)
+            if prog._table is None or prog._table[0] is not prog._abstract:
+                args, kw = prog._abstract
+                text = prog.lower(*args, **kw).compile(
+                    compiler_options=_FRESH_COMPILE).as_text()
+                prog._table = (prog._abstract,
+                               *parse_scopes(text, rows=True))
+            _, module, table = prog._table
             known = out.setdefault(module, {})
-            for name, sc in scopes.items():
-                if sc or name not in known:     # two live steps, one name
-                    known[name] = sc
+            for name, row in table.items():
+                if row.scope or name not in known:  # two live steps, one name
+                    known[name] = row
     return out
+
+
+def scope_map() -> dict:
+    """``{module name: {instruction name: scope}}``: the ``scope`` column
+    of :func:`scope_table`, which is what every ``*_device_ms_per_step``
+    reads."""
+    return {module: {name: row.scope for name, row in table.items()}
+            for module, table in scope_table().items()}
 
 
 # -- persistent compilation cache (ISSUE 7) ----------------------------------

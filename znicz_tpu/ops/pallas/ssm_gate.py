@@ -46,6 +46,7 @@ accumulates in float32.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 
@@ -55,6 +56,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from znicz_tpu.observe import probe as _probe
 from znicz_tpu.ops.pallas._elementwise import out_struct as _out_struct
 from znicz_tpu.ops.pallas.ssm_conv import _folded
 
@@ -269,9 +271,9 @@ def gate_bwd(y, proj, g, do, *, start: int, groups: int, eps: float,
     return dy, dz, out, sums.sum(axis=(0, 1))[None]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def gate_out(y, proj, g, w_out, start: int, groups: int, eps: float,
-             interpret: bool):
+             interpret: bool, scopes: tuple | None = None):
     """The layer's closing lines by the two kernels, differentiable in the
     scan's result ``y (b, t, inner)``, the projection ``proj (b, t, any
     width)`` whose lanes ``[start, start + inner)`` are ``z``, the gain ``g
@@ -279,27 +281,43 @@ def gate_out(y, proj, g, w_out, start: int, groups: int, eps: float,
     ``RMSNorm(y silu(z); g) @ w_out``, ``(b, t, d)``.  The product stands
     inside so that the backward pass keeps NOTHING of the forward kernel's:
     its rule reads the operands alone, and the weight's gradient takes the
-    gated rows the backward kernel writes again."""
-    return gate_fwd(y, proj, g, start=start, groups=groups, eps=eps,
-                    interpret=interpret) @ w_out
+    gated rows the backward kernel writes again.  ``scopes``: the names of
+    the scopes (``observe.probe.scope``) the gate and the product open,
+    in both passes; metadata only."""
+    with _scope(scopes, 0):
+        gated = gate_fwd(y, proj, g, start=start, groups=groups, eps=eps,
+                         interpret=interpret)
+    with _scope(scopes, 1):
+        return gated @ w_out
 
 
-def _gate_out_fwd(y, proj, g, w_out, start, groups, eps, interpret):
-    return (gate_out(y, proj, g, w_out, start, groups, eps, interpret),
-            (y, proj, g, w_out))
+def _scope(scopes, part: int, bwd: bool = False):
+    if scopes is None:
+        return contextlib.nullcontext()
+    return (_probe.scope_bwd if bwd else _probe.scope)(scopes[part])
 
 
-def _gate_out_bwd(start, groups, eps, interpret, kept, d_out):
+def _gate_out_fwd(y, proj, g, w_out, start, groups, eps, interpret, scopes):
+    return (gate_out(y, proj, g, w_out, start, groups, eps, interpret,
+                     scopes), (y, proj, g, w_out))
+
+
+def _gate_out_bwd(start, groups, eps, interpret, scopes, kept, d_out):
     y, proj, g, w_out = kept
     # the product's two transposes, as autodiff writes them
-    do = jnp.einsum("btd,id->bti", d_out, w_out).astype(y.dtype)
-    dy, dz, gated, dg = gate_bwd(y, proj, g, do, start=start, groups=groups,
-                                 eps=eps, interpret=interpret)
-    dw = jnp.einsum("bti,btd->id", gated, d_out)
+    with _scope(scopes, 1, bwd=True):
+        do = jnp.einsum("btd,id->bti", d_out, w_out).astype(y.dtype)
+    with _scope(scopes, 0, bwd=True):
+        dy, dz, gated, dg = gate_bwd(y, proj, g, do, start=start,
+                                     groups=groups, eps=eps,
+                                     interpret=interpret)
+    with _scope(scopes, 1, bwd=True):
+        dw = jnp.einsum("bti,btd->id", gated, d_out)
     # the cotangent's way back beside those of the projection's other lanes
-    behind = proj.shape[2] - start - dz.shape[2]
-    return (dy, jnp.pad(dz, ((0, 0), (0, 0), (start, behind))),
-            dg.astype(g.dtype), dw)
+    with _scope(scopes, 0, bwd=True):
+        behind = proj.shape[2] - start - dz.shape[2]
+        return (dy, jnp.pad(dz, ((0, 0), (0, 0), (start, behind))),
+                dg.astype(g.dtype), dw)
 
 
 gate_out.defvjp(_gate_out_fwd, _gate_out_bwd)

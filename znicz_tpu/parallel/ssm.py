@@ -374,9 +374,11 @@ def _conv_silu(proj, taps, bias, start: int, interpret):
 def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
           eps: float, scope: str, groups: int = 1):
     """The layer on the normed stream ``u (b, t, d)`` -> ``(out (b, t, d),
-    stats)``.  Scopes: ``scope`` (the two projections, the split, the gate
-    and the gated norm), ``scope.conv`` and ``scope.scan`` (softplus,
-    decays, the four steps, the skip), siblings by name.  ``stats`` (of
+    stats)``.  Scopes: ``scope`` and, opened inside it, ``scope.in`` (the
+    input products and the cut of ``z``), ``scope.gate`` (the gate and the
+    gated norm) and ``scope.out`` (the output product); ``scope.conv`` and
+    ``scope.scan`` (softplus, decays, the four steps, the skip), siblings
+    by name.  ``stats`` (of
     this layer; a step sums them over its layers): ``ssm_decay``, the mean
     over positions and heads of ``exp(dt A)``; ``ssm_state_rms``, the RMS
     of the state behind the last position (a row's, mean over the rows);
@@ -390,7 +392,7 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
     gate_kernels = _kernels_or_none(
         "gate", gate_kernel_refusal, "t inner groups start",
         (t, inner, groups, 0), itemsize=u.dtype.itemsize)
-    with _probe.scope(scope):
+    with _probe.scope(scope), _probe.scope(f"{scope}.in"):
         w_in, wide = p["ssm_in"], 2 * inner + 2 * bc
         if conv_kernels is None:
             proj = checkpoint_name(u @ w_in, "ssm_in")
@@ -432,20 +434,26 @@ def mixer(u, p, heads: int, head_dim: int, state: int, chunk: int,
                  "ssm_state_rms":
                      jnp.sqrt((last * last).mean((1, 2, 3))).mean(),
                  "ssm_layers": jnp.ones((), jnp.float32)}
+    parts = (f"{scope}.gate", f"{scope}.out")
     with _probe.scope(scope):
         if gate_kernels is not None:
             # z by a block spec on the projection's first lanes
             return _pgate.gate_out(
                 y, proj, p["ssm_g"].reshape(1, inner).astype(u.dtype),
-                p["ssm_out"], 0, groups, float(eps), gate_kernels), stats
-        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        # the statistic over each group's entries (the gain lies a group a
-        # row, ``params._ssm_leaf_shapes``); over all of them with one
-        # group.  A row a (token, group): a statistic ``(b, t, groups)``
-        # wide is laid out time-minor and takes the gated product with it
-        gated = gated.reshape(b * t * groups, inner // groups)
-        gated = gated * lax.rsqrt((gated * gated).mean(-1, keepdims=True)
-                                  + eps)
-        gated = gated.astype(u.dtype).reshape(b, t, groups, -1) * \
-            p["ssm_g"].reshape(groups, -1)
-        return gated.reshape(b, t, inner) @ p["ssm_out"], stats
+                p["ssm_out"], 0, groups, float(eps), gate_kernels,
+                parts), stats
+        with _probe.scope(parts[0]):
+            gated = y.astype(jnp.float32) * \
+                jax.nn.silu(z.astype(jnp.float32))
+            # the statistic over each group's entries (the gain lies a
+            # group a row, ``params._ssm_leaf_shapes``); over all of them
+            # with one group.  A row a (token, group): a statistic ``(b, t,
+            # groups)`` wide is laid out time-minor and takes the gated
+            # product with it
+            gated = gated.reshape(b * t * groups, inner // groups)
+            gated = gated * lax.rsqrt(
+                (gated * gated).mean(-1, keepdims=True) + eps)
+            gated = gated.astype(u.dtype).reshape(b, t, groups, -1) * \
+                p["ssm_g"].reshape(groups, -1)
+        with _probe.scope(parts[1]):
+            return gated.reshape(b, t, inner) @ p["ssm_out"], stats
