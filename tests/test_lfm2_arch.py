@@ -294,6 +294,34 @@ def interpreted_kernels():
         yield
 
 
+@pytest.mark.parametrize("n_dense", [1, 0],
+                         ids=["sconv+swiglu", "sconv+experts"])
+def test_the_sconv_kernels_give_every_leaf_the_numpy_stacks_gradients(
+        n_dense):
+    """A one-layer stack of the gated short convolution at a width of whole
+    lane tiles, with the step's kernels interpreted (``sconv_gate_fwd`` /
+    ``sconv_gate_bwd`` between the layer's two products) against the same
+    stack in ``jax.numpy``: the first loss and every leaf's first gradient,
+    to the tolerance this file holds a stack to against the reference."""
+    cfg = _cfg(["conv"], n_dense, hidden_size=128)
+    arch = _arch(cfg)
+    b, t = TRAFFIC["minibatch_size"], TRAFFIC["seq_len"]
+    assert tfm.step_choices(_mesh1(), arch, b, t)["sconv_kernel_share"] == 0
+    want_loss, want, _ = _program_first_steps(cfg, 7, steps=1)
+    with _pallas_interpret(True):
+        assert tfm.step_choices(_mesh1(), arch, b, t)[
+            "sconv_kernel_share"] == 1.0
+        loss, grads, _ = _program_first_steps(cfg, 7, steps=1)
+    assert loss[0] == pytest.approx(want_loss[0], rel=2e-5)
+    grads, want = _named(grads), _named(want)
+    assert set(grads) == set(want)
+    for name, g in want.items():
+        scale = max(np.linalg.norm(g), 1e-6)
+        assert np.linalg.norm(grads[name]) == pytest.approx(
+            np.linalg.norm(g), rel=2e-3, abs=2e-6), name
+        assert np.linalg.norm(grads[name] - g) / scale < 5e-3, name
+
+
 def _grouped_forms(p, v):
     """How often each form of the grouped product stands in the traced
     layer and its gradients: ``(kernel calls, lax.ragged_dot calls)``."""

@@ -19,8 +19,9 @@ import jax.numpy as jnp
 
 from test_lfm2_arch import _pallas_interpret
 from znicz_tpu.ops.pallas import (attention as pattn, dsa as pdsa,
-                                  grouped as pgrouped, ssd as pssd,
-                                  ssm_conv as pconv, ssm_gate as pgate)
+                                  grouped as pgrouped, sconv as psconv,
+                                  ssd as pssd, ssm_conv as pconv,
+                                  ssm_gate as pgate)
 from znicz_tpu.parallel import plan, transformer as tfm
 from znicz_tpu.parallel.mesh import make_mesh
 from znicz_tpu.parallel.params import param_shapes
@@ -261,6 +262,11 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
             ("mamba" not in arch.mixers)
         for kernel in (pgate.FWD_KERNEL_NAME, pgate.BWD_KERNEL_NAME):
             assert (kernel in text) == (chose["ssm_gate_kernel_share"] == 1.0)
+        # the gated short convolution's form
+        assert (chose["sconv_kernel_share"] is None) == \
+            ("sconv" not in arch.mixers)
+        for kernel in (psconv.FWD_KERNEL_NAME, psconv.BWD_KERNEL_NAME):
+            assert (kernel in text) == (chose["sconv_kernel_share"] == 1.0)
         # what a checkpointed layer keeps
         kept = chose["checkpoint_kept_bytes"]
         if plan._recomputes_by_policy(arch):
@@ -287,6 +293,8 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share",
                  "ssm_scan_kernel_share", "ssm_conv_kernel_share",
                  "ssm_gate_kernel_share"}
+    if family == "lfm2_moe":
+        want |= {"sconv_kernel_share"}
     if family == "afmoe":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share"}
         # the windowed kernels stand in the interpreted step by their own

@@ -180,7 +180,7 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     stats: dict = {}
     if mixer == "sconv":
         with _probe.scope(f"block{index}.sconv"):
-            x = _block_sconv(x, p, arch)
+            x = _block_sconv(x, p, arch, run)
     elif mixer == "mamba":
         x, stats = _block_ssm(x, p, arch, f"block{index}.ssm")
     elif mixer != "none":
@@ -522,20 +522,83 @@ def _selected_attention_dense(q, k, v, sel):
     return jnp.einsum("bhqk,bkhd->bqhd", a.astype(v.dtype), v)
 
 
-def _block_sconv(x, p, arch: Arch):
+def sconv_kernel_refusal(t: int, d: int, taps: int, bias: bool,
+                         interpret: bool) -> str | None:
+    """Why the gates and taps of a gated short convolution ``d`` wide over
+    rows of ``t`` positions run in their ``jax.numpy`` form
+    (:func:`_sconv_gate`), or ``None`` where the kernels of ``ops/pallas/
+    sconv.py`` run them: where the step's kernels run at all (a TPU, or
+    ``interpret``: interpreted) and the shape is one they take
+    (``sconv.unsupported_reason``: cuts of whole lane tiles, rows of whole
+    halo tiles, taps within the halo, no convolution bias).  What
+    :func:`_block_sconv` asks as the step is traced and ``transformer.
+    step_choices`` before."""
+    from znicz_tpu.ops.pallas import sconv as psconv
+    return ssm._backend_refusal(interpret) or \
+        psconv.unsupported_reason(t, d, taps, bias)
+
+
+@functools.lru_cache(maxsize=None)
+def _report_sconv_refusal(shape: tuple, why: str, level: int) -> None:
+    """Say once a shape and a process that the layer left its kernels."""
+    _log.log(level, "gated short convolution kernels refused t=%d d=%d "
+             "taps=%d bias=%s: %s; this layer's gates and taps run in "
+             "jax.numpy", *shape, why)
+
+
+def _sconv_gate(proj, taps, bias=None):
+    """The ``jax.numpy`` form of ``C * conv(B * X)`` on ``[B, C, X] =
+    split3(proj)``: ``z = B * X`` cast to float32, the taps in float32
+    (``c_t = sum_j k_j z_{t-taps+1+j}``, zeros before the sequence), the
+    bias where the layer has one, one cast back."""
+    gate_b, gate_c, xin = jnp.split(proj, 3, axis=-1)
+    z = (gate_b * xin).astype(jnp.float32)
+    n, t = taps.shape[0], proj.shape[1]
+    zp = jnp.pad(z, ((0, 0), (n - 1, 0), (0, 0)))
+    kf = taps.astype(jnp.float32)
+    c = sum(kf[j] * zp[:, j:j + t] for j in range(n))
+    if bias is not None:
+        c = c + bias.astype(jnp.float32)
+    return gate_c * c.astype(proj.dtype)
+
+
+def _block_sconv(x, p, arch: Arch, run: _Run):
     """Gated short convolution: ``[B, C, X] = split3(u W_in)``; ``z = B *
     X``; a depthwise causal convolution over time, ``conv_taps`` taps a
     channel, zeros before the sequence starts (``c_t = sum_j k_j
-    z_{t-taps+1+j}``, accumulated in f32); ``out = (C * c) W_out``."""
+    z_{t-taps+1+j}``, accumulated in f32); ``out = (C * c) W_out``.
+
+    The gates and the taps between the two products run by the two Pallas
+    kernels of ``ops/pallas/sconv.py`` (``sconv_gate_fwd`` /
+    ``sconv_gate_bwd`` behind one ``custom_vjp``) wherever
+    :func:`sconv_kernel_refusal` says None, picked by what can be observed
+    and by no switch: they read the three cuts from the projection itself,
+    keep ``u W_in`` and the taps for the backward pass and nothing else, and
+    write its cotangent whole.  Elsewhere (off the TPU and not interpreted,
+    a width that is no whole lane tiles, rows that are no multiple of 16, a
+    convolution bias) :func:`_sconv_gate`, the ``jax.numpy`` form, which
+    keeps the three cuts and the sum; a refusal is logged once a shape.
+    Either way operands in the compute dtype, ``z``, the taps and the sum
+    float32; the kernels keep everything between their operands and their
+    results float32 (one rounding each of ``y``, ``dB``, ``dC``, ``dX``),
+    where that form as written rounds ``z``, the sum and two cotangents on
+    the way.  The two products, the norm and the residual sum are XLA's in
+    both."""
     u = _norm(x, p, "ln1", arch)
-    t = u.shape[1]
-    gate_b, gate_c, xin = jnp.split(u @ p["w_in"], 3, axis=-1)
-    z = (gate_b * xin).astype(jnp.float32)
-    taps = arch.conv_taps
-    zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
-    kf = p["conv_k"].astype(jnp.float32)
-    c = sum(kf[j] * zp[:, j:j + t] for j in range(taps))
-    return x + (gate_c * c.astype(x.dtype)) @ p["w_out"]
+    proj, taps, bias = u @ p["w_in"], p["conv_k"], p.get("conv_b")
+    shape = (u.shape[1], u.shape[2], taps.shape[0], bias is not None)
+    why = sconv_kernel_refusal(*shape, run.interpret)
+    if why:
+        # a shape the kernels turn down where they could run is news; a
+        # backend without them is not
+        _report_sconv_refusal(shape, why, logging.WARNING if
+                              ssm._kernels_eligible(run.interpret) else
+                              logging.INFO)
+        y = _sconv_gate(proj, taps, bias)
+    else:
+        from znicz_tpu.ops.pallas import sconv as psconv
+        y = psconv.gate(proj, taps.astype(jnp.float32), run.interpret)
+    return x + y @ p["w_out"]
 
 
 def _glu(m, w1, w3, w2):

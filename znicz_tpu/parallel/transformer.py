@@ -24,7 +24,7 @@ from znicz_tpu.parallel.arch import (Arch, _FAMILIES,  # noqa: F401
                                      _default_compute_dtype,
                                      arch_from_config, as_arch, routed_block)
 from znicz_tpu.parallel.blocks import (_Run, _block, _rms_norm,
-                                       flash_refusal)
+                                       flash_refusal, sconv_kernel_refusal)
 from znicz_tpu.parallel.compat import quantized_psum, shard_map
 from znicz_tpu.parallel.head import (_ce_from_hidden, _ce_weighted, _head_of,
                                      _n_chunks, _normalised,
@@ -476,7 +476,14 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
       gate, gated group norm and output product the kernels of ``ops/pallas/
       ssm_gate.py`` run (all or none: ``ssm.gate_kernel_refusal``, which
       also refuses ONE group; the rest the closing lines of ``ssm.mixer``);
-      None without a state-space layer."""
+      None without a state-space layer;
+    - ``sconv_kernel_share``: of the gated short convolutions, the share
+      whose gates and taps (``C * conv(B * X)`` between the layer's two
+      products, and backward the projection's whole cotangent) the kernels
+      of ``ops/pallas/sconv.py`` run, keeping the projection and the taps
+      alone (all or none: ``blocks.sconv_kernel_refusal``; the rest
+      ``blocks._sconv_gate``, the ``jax.numpy`` form, which keeps the three
+      cuts and the sum); None without such a layer."""
     from znicz_tpu.ops.pallas import attention as pattn
     run = _run_of(mesh, arch, arch.vocab if head_sharded else None)
     b_loc = batch // mesh.shape.get("data", 1)
@@ -514,6 +521,10 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         gate = float(ssm.gate_kernel_refusal(
             t_loc, inner, arch.ssm_groups, 0, itemsize,
             run.interpret) is None)
+    sconv = None
+    if "sconv" in arch.mixers:
+        sconv = float(sconv_kernel_refusal(
+            t_loc, arch.d, arch.conv_taps, False, run.interpret) is None)
     return {
         "ce_grad_in_forward_share": float(ce_grad_in_forward(
             loss_chunks, head_sharded, arch.loop_steps > 1)),
@@ -522,7 +533,8 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
             arch, run, b_loc * t_loc, _default_compute_dtype(), loss_chunks)),
         "dsa_index_kernel_share": index, "dsa_align_kernel_share": align,
         "moe_gmm_kernel_share": gmm, "ssm_scan_kernel_share": scan,
-        "ssm_conv_kernel_share": conv, "ssm_gate_kernel_share": gate}
+        "ssm_conv_kernel_share": conv, "ssm_gate_kernel_share": gate,
+        "sconv_kernel_share": sconv}
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

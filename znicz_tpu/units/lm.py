@@ -134,6 +134,15 @@ def _choice_gauges() -> tuple:
             "(the rest: the jax.numpy form, which one group, the shape or "
             "the platform left them to)",
             ("unit",)), None),
+        ("sconv_kernel_share", registry.gauge(
+            "znicz_lm_sconv_kernel_share",
+            "gated short convolutions whose gates and taps the Pallas "
+            "kernels sconv_gate_fwd and sconv_gate_bwd run on the input "
+            "projection's own lanes, the float32 sum in VMEM and the "
+            "projection's cotangent written whole, over the gated short "
+            "convolutions (the rest: the jax.numpy form, which the shape "
+            "or the platform left them to)",
+            ("unit",)), None),
     )
 
 
@@ -291,6 +300,9 @@ class TransformerLMStep(AcceleratedUnit):
         self.ssm_scan_kernel_share: Optional[float] = None
         self.ssm_conv_kernel_share: Optional[float] = None
         self.ssm_gate_kernel_share: Optional[float] = None
+        #: of the gated short convolutions, the share whose gates and taps
+        #: the Pallas kernels run; None without one
+        self.sconv_kernel_share: Optional[float] = None
         #: ``{name: bytes}`` the checkpointed layers keep beside their
         #: policy's own list (``parallel/plan.py::checkpoint_plan``)
         self.checkpoint_kept_bytes: dict = {}
