@@ -94,6 +94,40 @@ def test_the_planned_step_fits_a_v5e_and_the_footprint_holds(
     assert tflop[0] < out["flops"] / 1e12 < tflop[1]
 
 
+def test_the_delta_rule_cell_compiles_for_a_v5e_and_holds_its_kernels():
+    """The Solar-Open2 cell's step (three delta-rule linear-attention layers
+    and one gated grouped-query layer, routed experts in each, 1 x 8,192
+    tokens) compiled for the described chip, which refuses what does not
+    fit its 15.75 GiB: 1,420,941,120 parameters; the convolution's two
+    kernels once a linear layer, the plain blocked flash kernels once for
+    the one grouped-query layer; no float32 array with two chunk-length axes
+    beside a head's 128 channels; 15.71 GiB of arguments and temporaries by
+    ``memory_analysis()`` (5.29 + 10.41; the compiler's own report of the
+    bytes in use at the fullest says 15.07), which the plan's footprint
+    (15.11) stands within its margin of, so the plan keeps nothing beside
+    the policy's list; 36.7 TFLOP outside the kernels."""
+    from znicz_tpu.ops.pallas import attention as pattn
+
+    out = _compiled_or_skip("solar_open2_250b",
+                            "train_tokens_ep32_kda_t8192")
+    assert out["params"] == 1_420_941_120 and out["tokens"] == 8192
+    assert out["delta_rule_layers"] == 3 and out["window_layers"] == 0
+    assert out["conv_kernels"] == {"ssm_conv_fwd": 3, "ssm_conv_bwd": 3}
+    assert out["attn_kernels"] == {
+        **{rf"{name}\b": 0 for name in pattn.KVB_SWA_KERNEL_NAMES.values()},
+        **{rf"{name}\b": 1 for name in (
+            pattn.KVB_FWD_KERNEL_NAME, pattn.KVB_DKV_KERNEL_NAME,
+            pattn.KVB_DQ_KERNEL_NAME)}}
+    assert out["chunk_channel_squares"] == []
+    assert out["plan"] == {"glu_wide": 0, "kda_in": 0}
+    live = out["argument_bytes"] + out["temp_bytes"]
+    print(f"solar_open2_250b: compiled {live / GIB:.3f} GiB, footprint "
+          f"{out['footprint'] / GIB:.3f}, {out['flops'] / 1e12:.3f} TFLOP")
+    assert live <= HBM_USABLE, f"{live / GIB:.2f} GiB"
+    assert abs(live - out["footprint"]) <= out["margin"]
+    assert 35.5 < out["flops"] / 1e12 < 38.0
+
+
 def test_the_window_cell_holds_the_windowed_kernels_once_a_layer_a_pass():
     """The Trinity cell's compiled step (what a v5e would answer): each of
     the three windowed blocked kernels once a window layer (four: a

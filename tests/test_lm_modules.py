@@ -35,10 +35,14 @@ MAY_IMPORT = {
     "arch": ("znicz_tpu.core",),
     "params": ("znicz_tpu.parallel.arch",),
     "blocks": ("znicz_tpu.parallel.arch", "znicz_tpu.parallel.dsa",
-               "znicz_tpu.parallel.ssm", "znicz_tpu.parallel.moe",
+               "znicz_tpu.parallel.ssm", "znicz_tpu.parallel.kda",
+               "znicz_tpu.parallel.moe",
                "znicz_tpu.parallel.tp", "znicz_tpu.parallel.ring_attention",
                "znicz_tpu.observe.probe", "znicz_tpu.ops.pallas"),
     "head": ("znicz_tpu.parallel.arch",),
+    # the delta-rule layer borrows the state-space layer's convolution and
+    # its choice between the two forms
+    "kda": ("znicz_tpu.parallel.ssm", "znicz_tpu.observe.probe"),
     # ``head`` for the one reading of ``loss_chunks`` (``_n_chunks``),
     # ``moe`` for the rows of a routed layer's compact pairs buffer
     "plan": ("znicz_tpu.parallel.arch", "znicz_tpu.parallel.params",
@@ -47,7 +51,8 @@ MAY_IMPORT = {
         "znicz_tpu.parallel.arch", "znicz_tpu.parallel.params",
         "znicz_tpu.parallel.blocks", "znicz_tpu.parallel.head",
         "znicz_tpu.parallel.plan", "znicz_tpu.parallel.dsa",
-        "znicz_tpu.parallel.ssm", "znicz_tpu.parallel.moe",
+        "znicz_tpu.parallel.ssm", "znicz_tpu.parallel.kda",
+        "znicz_tpu.parallel.moe",
         "znicz_tpu.parallel.compat", "znicz_tpu.parallel.qcomm",
         "znicz_tpu.parallel.zero", "znicz_tpu.observe.probe",
         "znicz_tpu.ops.pallas", "znicz_tpu.core.config"),
@@ -135,7 +140,8 @@ def _tiny(family: str, wide: bool):
         "KeyeVL2": "test_keye_vl2_arch",
         "granitemoehybrid": "test_granitemoehybrid_arch",
         "nemotron_h": "test_nemotron_h_arch",
-        "afmoe": "test_afmoe_arch"}[family])
+        "afmoe": "test_afmoe_arch",
+        "solar_open2": "test_solar_open2_arch"}[family])
     over = {}
     if wide and family == "KeyeVL2":
         over = {"hidden_size": 64, "head_dim": 128, "num_attention_heads": 2,
@@ -161,6 +167,15 @@ def _tiny(family: str, wide: bool):
         over = {"hidden_size": 256, "num_attention_heads": 2,
                 "num_key_value_heads": 1, "head_dim": 128,
                 "sliding_window": 160, "moe_intermediate_size": 128}
+    elif wide and family == "solar_open2":
+        # linear heads whose q | k | v is whole lane tiles wide (3 x 2 x 64):
+        # the convolution's kernels' shape
+        over = {"hidden_size": 128, "num_attention_heads": 2,
+                "num_key_value_heads": 1, "head_dim": 128,
+                "moe_intermediate_size": 128,
+                "linear_attn_config": {"short_conv_kernel_size": 4,
+                                       "head_dim": 64, "num_heads": 2,
+                                       "num_kv_heads": None}}
     elif wide and family in ("ouro", "lfm2_moe"):
         over = {"hidden_size": 256, "num_attention_heads": 2,
                 "num_key_value_heads": 2, "head_dim": 128}
@@ -252,11 +267,16 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
             ("mamba" not in arch.mixers)
         for kernel in (pssd.FWD_KERNEL_NAME, pssd.BWD_KERNEL_NAME):
             assert (kernel in text) == (chose["ssm_scan_kernel_share"] == 1.0)
-        # the convolution's form
+        # the convolution's form, a state-space layer's or a delta-rule
+        # layer's (the same kernels)
         assert (chose["ssm_conv_kernel_share"] is None) == \
             ("mamba" not in arch.mixers)
+        assert (chose["kda_conv_kernel_share"] is None) == \
+            ("kda" not in arch.mixers)
         for kernel in (pconv.FWD_KERNEL_NAME, pconv.BWD_KERNEL_NAME):
-            assert (kernel in text) == (chose["ssm_conv_kernel_share"] == 1.0)
+            assert (kernel in text) == (1.0 in (
+                chose["ssm_conv_kernel_share"],
+                chose["kda_conv_kernel_share"]))
         # the gate's and the gated norm's form
         assert (chose["ssm_gate_kernel_share"] is None) == \
             ("mamba" not in arch.mixers)
@@ -295,6 +315,9 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
                  "ssm_gate_kernel_share"}
     if family == "lfm2_moe":
         want |= {"sconv_kernel_share"}
+    if family == "solar_open2":
+        want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share",
+                 "kda_conv_kernel_share"}
     if family == "afmoe":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share"}
         # the windowed kernels stand in the interpreted step by their own

@@ -348,11 +348,11 @@ def test_second_norm_acts_on_the_sublayers_output_not_on_the_stream():
 def test_an_unknown_model_type_is_refused_by_name():
     assert set(tfm._FAMILIES) == {"lfm2_moe", "glm4_moe_lite", "ouro",
                                   "KeyeVL2", "granitemoehybrid",
-                                  "nemotron_h", "afmoe"}
+                                  "nemotron_h", "afmoe", "solar_open2"}
     with pytest.raises(ValueError, match="model_type 'mamba2'.*lfm2_moe, "
                                          "glm4_moe_lite, ouro, KeyeVL2, "
                                          "granitemoehybrid, nemotron_h, "
-                                         "afmoe"):
+                                         "afmoe, solar_open2"):
         tfm.arch_from_config({**TINY, "model_type": "mamba2"})
 
 

@@ -182,6 +182,61 @@ def test_the_kernels_inside_the_mixer_give_its_gradients_to_every_leaf():
         assert _rel(got[1][name], want[1][name]) < 5e-5, name
 
 
+def test_a_delta_rule_layers_bias_free_cut_from_lane_zero_takes_the_kernels():
+    """The delta-rule layer (``parallel/kda.py``) hands the kernels its whole
+    ``q | k | v`` projection, cut from lane 0, with a bias row of zeros: the
+    cell's shape (8,192 positions, 24,576 lanes, 4 taps) passes the one
+    question with tiles of 1,024 x 512, and at a small shape the kernels
+    (interpreted) give ``silu(conv(proj))`` and the gradients to the
+    projection and the taps as ``ssm._conv`` does; through ``kda.mixer``,
+    the layer's output and the gradients to the stream and to every leaf."""
+    from znicz_tpu.parallel import kda
+    from znicz_tpu.parallel.params import _kda_leaf_shapes
+
+    assert ssm.conv_kernel_refusal(8192, 0, 24576, 4, True) is None
+    assert pconv.tiles(8192, 0, 24576) == (1024, 512)
+    r = np.random.default_rng(11)
+    proj = jnp.asarray(r.normal(size=(2, 48, 384)), jnp.float32)
+    k = jnp.asarray(r.normal(size=(4, 384)) / 2.0, jnp.float32)
+    w = jnp.asarray(r.normal(size=proj.shape), jnp.float32)
+    zero = jnp.zeros((384,), jnp.float32)
+
+    def loss(interpret):
+        return lambda a, b: (ssm._conv_silu(a, b, zero, 0, interpret) *
+                             w).sum()
+
+    got = jax.grad(loss(True), argnums=(0, 1))(proj, k)
+    want = jax.grad(loss(None), argnums=(0, 1))(proj, k)
+    np.testing.assert_allclose(ssm._conv_silu(proj, k, zero, 0, True),
+                               ssm._conv_silu(proj, k, zero, 0, None),
+                               rtol=2e-6, atol=2e-6)
+    for g, g_want in zip(got, want):
+        assert _rel(g, g_want) < 2e-6
+    # the layer: 2 heads of 64 (q | k | v three whole lane tiles), rows of 64
+    d, heads, width = 32, 2, 64
+    p = {name: jnp.asarray(r.normal(size=shape) / np.sqrt(shape[0]),
+                           jnp.float32)
+         for name, shape in _kda_leaf_shapes(d, heads, width, width,
+                                             4).items()}
+    p["kda_dt_b"] = jnp.full((heads * width,), -3.0)
+    u = jnp.asarray(r.normal(size=(2, 64, d)), jnp.float32)
+    wo = jnp.asarray(r.normal(size=u.shape), jnp.float32)
+
+    def layer(u_, p_):
+        out, _ = kda.mixer(u_, p_, heads, width, 16, True, 1e-5, "blk.kda")
+        return (out * wo).sum()
+
+    with jax.default_matmul_precision("highest"):
+        with _pallas_interpret(True):
+            text = str(jax.make_jaxpr(layer)(u, p))
+            got = jax.grad(layer, argnums=(0, 1))(u, p)
+        want = jax.grad(layer, argnums=(0, 1))(u, p)
+    assert pconv.FWD_KERNEL_NAME in text
+    assert _rel(got[0], want[0]) < 5e-5
+    for name in want[1]:
+        assert _rel(got[1][name], want[1][name]) < 5e-5, name
+
+
 # -- (b) the one question -----------------------------------------------------
 
 SHAPE = dict(t=8192, start=4096, width=4352, taps=4, interpret=True)

@@ -5,7 +5,9 @@ one JSON line: the compiled step's memory, the compiler's operation count,
 the checkpoint plan a v5e's memory limit gives, the footprint the plan
 reckoned with, how often each of the key/value-blocked flash kernels stands
 in the compiled step (under a window and without one) and, of a stack with
-state-space layers, how often the scan's,
+delta-rule layers which float32 arrays with two chunk-length axes beside a
+head's channels stand in it, of a stack with state-space layers how often the
+scan's,
 the convolution's and the gate's two kernels each stand in the compiled
 step, which float32 arrays with two chunk-length axes do, which float32
 arrays as long as the tokens and as wide as the convolution's channels, and
@@ -133,6 +135,14 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
             text, rf"f32\[\d+,\d{{{len(str(t))},}},{channels}\]")
         if channels else [],
         "state_space_layers": arch.mixers.count("mamba"),
+        # of a stack with delta-rule layers: float32 arrays with two
+        # chunk-length axes beside a head's channels, every chunk's (a
+        # chunk's decay matrix a channel: ``(.., t / C, C, C, K)``)
+        "delta_rule_layers": arch.mixers.count("kda"),
+        "chunk_channel_squares": sorted(set(re.findall(
+            rf"f32\[(?:\d+,)*{t // arch.kda_chunk},{arch.kda_chunk},"
+            rf"{arch.kda_chunk},{arch.kda_head_dim}\]", text)))
+        if arch.mixers.count("kda") else [],
         "params": sum(math.prod(s.shape) for s in jax.tree.leaves(params)),
         "tokens": b * t,
         "argument_bytes": m.argument_size_in_bytes,

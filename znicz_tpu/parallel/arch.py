@@ -17,6 +17,7 @@ import jax
 
 #: names in a refusal (:meth:`Arch.mechanisms`, :func:`mechanisms_of_params`)
 _SSM_MECHANISM = "state-space layer (Mamba-2)"
+_KDA_MECHANISM = "delta-rule linear attention (a decay a key channel)"
 _GROUPS_MECHANISM = "state-space groups (B and C a group of heads)"
 _ONE_SUB_LAYER_MECHANISM = "layers of one sub-layer"
 _ROUTED_MECHANISM = "routed experts (moe_routed_ffn)"
@@ -115,6 +116,14 @@ class Arch:
     gates the attention's output, ``o = W_o (sigmoid(W_g u) * heads'
     output)``, ``u`` the layer's normed input and ``W_g`` as wide as the
     heads' output.
+    A ``"kda"`` mixer is a linear-attention layer whose state follows the
+    delta rule under a decay a key channel (Kimi Delta Attention,
+    ``parallel/kda.py``): ``kda_heads`` heads of ``kda_head_dim`` (keys and
+    values alike), a depthwise bias-free convolution of ``conv_taps`` taps on
+    each of q, k and v, the log-decay and the output gate through low-rank
+    pairs of ``kda_rank``, ``beta`` in (0, 2) where ``kda_neg_eigval`` (in
+    (0, 1) without), the rule run in chunks of ``kda_chunk`` positions (a
+    tile, a power of two: it changes no value).
 
     Built by :func:`gpt_arch` (the block this module always had: the
     four integers) or :func:`arch_from_config` (a model's own keys)."""
@@ -171,6 +180,11 @@ class Arch:
     windowed: tuple = ()
     rotated: tuple = ()
     attn_gate: bool = False
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_rank: int = 0
+    kda_neg_eigval: bool = False
+    kda_chunk: int = 64
 
     def __post_init__(self):
         if self._scaled() and (
@@ -213,6 +227,18 @@ class Arch:
                 self.ssm_chunk > 0):
             raise ValueError("a mamba mixer needs ssm_heads, ssm_head_dim, "
                              "ssm_state, conv_taps and ssm_chunk")
+        if "kda" in self.mixers and not (
+                self.kda_heads > 0 and self.kda_head_dim > 0 and
+                self.kda_rank > 0 and self.conv_taps > 0 and
+                self.kda_chunk > 0 and
+                self.kda_chunk & (self.kda_chunk - 1) == 0):
+            raise ValueError("a kda mixer needs kda_heads, kda_head_dim, "
+                             "kda_rank, conv_taps and a kda_chunk that is a "
+                             "power of two")
+        if "kda" in self.mixers and (self.mtp or self.loop_steps > 1 or
+                                     self.norm != "rms"):
+            raise ValueError("a kda mixer is written for an unlooped "
+                             "RMSNorm stack with no MTP module")
         if self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups:
             raise ValueError(f"ssm_groups {self.ssm_groups} must divide "
                              f"ssm_heads {self.ssm_heads}")
@@ -305,6 +331,8 @@ class Arch:
             out.append(_SSM_MECHANISM)
         if "mamba" in self.mixers and self.ssm_groups > 1:
             out.append(_GROUPS_MECHANISM)
+        if "kda" in self.mixers:
+            out.append(_KDA_MECHANISM)
         if "none" in self.mixers + self.ffns:
             out.append(_ONE_SUB_LAYER_MECHANISM)
         if "latent" in self.mixers:
@@ -855,11 +883,113 @@ def _afmoe_arch(cfg, vocab: int | None) -> Arch:
         window=window, windowed=windowed, rotated=windowed, attn_gate=True)
 
 
+def _solar_open2_arch(cfg, vocab: int | None) -> Arch:
+    """``solar_open2`` (Upstage's Solar Open 2: ``gqa_layers`` /
+    ``gqa_interval``, ``linear_attn_config`` (``num_heads``, ``head_dim``,
+    ``short_conv_kernel_size``, ``num_kv_heads``), ``kda_use_full_proj``,
+    ``kda_allow_neg_eigval``, ``use_rope``, ``use_gqa_gate``,
+    ``first_k_dense_replace``, ``n_routed_experts``, ``n_shared_experts``,
+    ``num_experts_per_tok``, ``norm_topk_prob``, ``routed_scaling_factor``,
+    ...): an RMSNorm stack of two norms a layer whose mixer is, on the layers
+    ``gqa_layers`` lists, grouped-query attention with NO positional
+    encoding and a sigmoid gate on the heads' output (``attn_gate``; no
+    QK-norm, no bias), and on every other layer Kimi Delta Attention
+    (``parallel/kda.py``: the log-decay and the gate through low-rank pairs of
+    ``linear_attn_config.head_dim``, ``beta`` in (0, 2) where
+    ``kda_allow_neg_eigval``); in every layer a shared SwiGLU expert of
+    ``n_shared_experts x moe_intermediate_size`` (the DeepSeek family sums
+    the width) beside sigmoid-routed SwiGLU experts selected by score plus a
+    selection bias (the DeepSeek-V3 keys this config carries, read as
+    ``glm4_moe_lite`` reads them; one group), their weights normalised over
+    the selected where ``norm_topk_prob``, times ``routed_scaling_factor``; a
+    final norm, the head tied or not.  ``gqa_layers`` defaults to every
+    ``gqa_interval + 1``-th layer from 0.  ``n_routed_experts`` is the
+    experts held here where ``router_width`` gives the router's published
+    width.  Refused by name: ``use_rope`` true (and with it any
+    ``partial_rotary_factor`` other than 1), ``kda_use_full_proj`` true (the
+    low-rank pair is what is written), ``linear_attn_config.num_kv_heads``
+    other than null or ``num_heads``, a ``short_conv_kernel_size`` under 1,
+    ``first_k_dense_replace`` other than 0 (``intermediate_size``, the dense
+    width, is read by nothing), ``use_gqa_gate`` false, ``n_group`` /
+    ``topk_group`` other than 1, a ``scoring_func`` other than sigmoid, a
+    ``hidden_act`` other than silu, an attention bias, a ``gqa_layers``
+    entry outside the stack."""
+    if cfg.get("use_rope", False):
+        raise ValueError(
+            "use_rope true: the grouped-query layers here carry no rotary "
+            "embedding (the linear layers carry the order)" + (
+                "; partial_rotary_factor "
+                f"{cfg['partial_rotary_factor']} would rotate part of a head"
+                if float(cfg.get("partial_rotary_factor", 1)) != 1 else ""))
+    if cfg.get("kda_use_full_proj", False):
+        raise ValueError("kda_use_full_proj true: the log-decay and the gate "
+                         "through their low-rank pairs are what is written")
+    if not cfg.get("use_gqa_gate", True):
+        raise ValueError("use_gqa_gate false: the grouped-query layers' "
+                         "gated output is what is written")
+    if int(cfg.get("first_k_dense_replace", 0)) != 0:
+        raise ValueError(f"first_k_dense_replace "
+                         f"{cfg['first_k_dense_replace']}: every layer "
+                         f"routed is what is written (intermediate_size is "
+                         f"not read)")
+    if int(cfg.get("n_group", 1)) != 1 or int(cfg.get("topk_group", 1)) != 1:
+        raise ValueError("n_group / topk_group: selection over one group of "
+                         "experts is what is written")
+    if cfg.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"scoring_func {cfg['scoring_func']!r}: sigmoid "
+                         f"scores are what is written for these keys")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {cfg['hidden_act']!r}: SwiGLU")
+    if cfg.get("attention_bias", False):
+        raise ValueError("attention_bias: the projections here have none")
+    lin = cfg["linear_attn_config"]
+    k_heads, k_dim = int(lin["num_heads"]), int(lin["head_dim"])
+    if lin.get("num_kv_heads") not in (None, k_heads):
+        raise ValueError(f"linear_attn_config.num_kv_heads "
+                         f"{lin['num_kv_heads']}: as many key/value as query "
+                         f"heads ({k_heads}, or null) is what is written")
+    taps = int(lin.get("short_conv_kernel_size", 4))
+    if taps < 1:
+        raise ValueError(f"linear_attn_config.short_conv_kernel_size {taps}: "
+                         f"at least one tap")
+    layers = int(cfg["num_hidden_layers"])
+    full = cfg.get("gqa_layers")
+    if full is None:
+        full = range(0, layers, int(cfg.get("gqa_interval", 3)) + 1)
+    full = sorted(int(i) for i in full)
+    if full and not 0 <= full[0] <= full[-1] < layers:
+        raise ValueError(f"gqa_layers {full} outside the {layers} layers")
+    d, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    n_experts = int(cfg.get("router_width", cfg.get("n_routed_experts", 0)))
+    first, count = _experts_held(cfg, n_experts)
+    moe_ff = int(cfg.get("moe_intermediate_size", 0))
+    return Arch(
+        d=d, heads=heads, kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=int(cfg.get("head_dim") or d // heads),
+        ff=int(cfg.get("intermediate_size", 0)),
+        vocab=int(vocab if vocab is not None else cfg["vocab_size"]),
+        mixers=tuple("attention" if i in full else "kda"
+                     for i in range(layers)),
+        ffns=("moe_routed",) * layers, norm="rms",
+        eps=float(cfg.get("rms_norm_eps", 1e-5)), conv_taps=taps,
+        n_experts=n_experts, experts_first=first, experts_held=count,
+        top_k=int(cfg.get("num_experts_per_tok", 1)), moe_ff=moe_ff,
+        score="sigmoid", expert_bias=True,
+        norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        final_norm=True, tied=bool(cfg.get("tie_word_embeddings", False)),
+        shared_ff=int(cfg.get("n_shared_experts", 0)) * moe_ff,
+        attn_gate=bool(full), kda_heads=k_heads, kda_head_dim=k_dim,
+        kda_rank=k_dim, kda_neg_eigval=bool(cfg.get("kda_allow_neg_eigval",
+                                                    False)))
+
+
 #: ``model_type`` -> the reader of that family's keys
 _FAMILIES = {"lfm2_moe": _lfm2_moe_arch, "glm4_moe_lite": _glm4_moe_lite_arch,
              "ouro": _ouro_arch, "KeyeVL2": _keye_vl2_arch,
              "granitemoehybrid": _granitemoehybrid_arch,
-             "nemotron_h": _nemotron_h_arch, "afmoe": _afmoe_arch}
+             "nemotron_h": _nemotron_h_arch, "afmoe": _afmoe_arch,
+             "solar_open2": _solar_open2_arch}
 
 
 def arch_from_config(cfg, vocab: int | None = None) -> Arch:
@@ -868,7 +998,8 @@ def arch_from_config(cfg, vocab: int | None = None) -> Arch:
     ``layer_types`` and no ``model_type`` is read as,
     :func:`_glm4_moe_lite_arch`, :func:`_ouro_arch`,
     :func:`_keye_vl2_arch`, :func:`_granitemoehybrid_arch`,
-    :func:`_nemotron_h_arch` and :func:`_afmoe_arch`).
+    :func:`_nemotron_h_arch`, :func:`_afmoe_arch` and
+    :func:`_solar_open2_arch`).
     ``experts_held`` (``{"first", "count"}``; all by default) is this chip's
     share of the experts;
     ``vocab`` (the loader's) overrides ``vocab_size``.  Any other
@@ -899,7 +1030,7 @@ _LEAF_MECHANISMS = {
     "w3": "SwiGLU", "wkv_a": "latent attention", "sw1": "shared expert",
     "ln1o_g": "sandwich norm", "wg": _ATTN_GATE_MECHANISM,
     "wiq": "learned sparse attention (indexer)",
-    "ssm_a_log": _SSM_MECHANISM,
+    "ssm_a_log": _SSM_MECHANISM, "kda_a_log": _KDA_MECHANISM,
 }
 
 

@@ -19,7 +19,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from znicz_tpu.observe import probe as _probe
-from znicz_tpu.parallel import dsa, ssm, tp
+from znicz_tpu.parallel import dsa, kda, ssm, tp
 from znicz_tpu.parallel.arch import Arch
 from znicz_tpu.parallel.moe import (load_balance_aux, moe_ffn,
                                     moe_routed_ffn, relu2, router_z_loss)
@@ -173,7 +173,8 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
     Scopes: ``block<index>.attn`` (with ``.attn.latent`` beside it for
     what latent attention does before the kernel, ``.attn.index``,
     ``.attn.select`` and ``.attn.align`` for an indexer), ``.sconv`` or
-    ``.ssm`` (with ``.ssm.conv`` and ``.ssm.scan`` beside it), then
+    ``.ssm`` (with ``.ssm.conv`` and ``.ssm.scan`` beside it) or ``.kda``
+    (with ``.kda.conv`` and ``.kda.delta`` beside it), then
     ``block<index>.mlp`` or ``.moe`` (with ``.moe.route``,
     ``.moe.experts`` and ``.moe.shared`` beside it)."""
     mixer, ffn = arch.kinds(index)
@@ -183,6 +184,8 @@ def _block(x, p, arch: Arch, run: _Run, index: int = 0):
             x = _block_sconv(x, p, arch, run)
     elif mixer == "mamba":
         x, stats = _block_ssm(x, p, arch, f"block{index}.ssm")
+    elif mixer == "kda":
+        x, stats = _block_kda(x, p, arch, f"block{index}.kda")
     elif mixer != "none":
         x, stats = _block_attn(x, p, arch, run, f"block{index}.attn", index)
     if ffn == "moe_routed":
@@ -207,6 +210,18 @@ def _block_ssm(x, p, arch: Arch, scope: str):
     y, stats = ssm.mixer(u, p, arch.ssm_heads, arch.ssm_head_dim,
                          arch.ssm_state, arch.ssm_chunk, arch.eps, scope,
                          arch.ssm_groups)
+    with _probe.scope(scope):
+        return x + _sub_out(y, p, "ln1o", arch), stats
+
+
+def _block_kda(x, p, arch: Arch, scope: str):
+    """A delta-rule linear-attention layer (``kda.mixer``) on the normed
+    stream; the norm and the residual sum lie under ``scope``.  -> ``(x,
+    stats)``."""
+    with _probe.scope(scope):
+        u = _norm(x, p, "ln1", arch)
+    y, stats = kda.mixer(u, p, arch.kda_heads, arch.kda_head_dim,
+                         arch.kda_chunk, arch.kda_neg_eigval, arch.eps, scope)
     with _probe.scope(scope):
         return x + _sub_out(y, p, "ln1o", arch), stats
 

@@ -35,6 +35,21 @@ def _ssm_leaf_shapes(d: int, heads: int, head_dim: int, state: int,
             "ssm_out": (inner, d)}
 
 
+def _kda_leaf_shapes(d: int, heads: int, head_dim: int, rank: int,
+                     taps: int) -> dict:
+    """``{leaf: shape}`` of a delta-rule linear-attention layer
+    (``parallel/kda.py``): ``q | k | v`` from one product with a tap row a
+    channel, the log-decay's and the gate's low-rank pairs with their
+    biases, a decay rate and a ``beta`` a head, the head norm's gain."""
+    inner = heads * head_dim
+    return {"kda_in": (d, 3 * inner), "kda_conv_k": (taps, 3 * inner),
+            "kda_f1": (d, rank), "kda_f2": (rank, inner),
+            "kda_dt_b": (inner,), "kda_a_log": (heads,),
+            "kda_b": (d, heads), "kda_g1": (d, rank),
+            "kda_g2": (rank, inner), "kda_g_b": (inner,),
+            "kda_norm_g": (head_dim,), "kda_out": (inner, d)}
+
+
 def _layer_shapes(arch: Arch, i: int) -> dict:
     """``{leaf: shape}`` of layer ``i`` (``n_layers``: the MTP module's):
     the one table the initialiser, the specs and the shapes are read
@@ -72,6 +87,9 @@ def _layer_shapes(arch: Arch, i: int) -> dict:
         out.update(_ssm_leaf_shapes(d, arch.ssm_heads, arch.ssm_head_dim,
                                     arch.ssm_state, arch.conv_taps,
                                     arch.ssm_groups))
+    elif mixer == "kda":
+        out.update(_kda_leaf_shapes(d, arch.kda_heads, arch.kda_head_dim,
+                                    arch.kda_rank, arch.conv_taps))
     elif mixer == "sconv":
         out.update({"w_in": (d, 3 * d), "conv_k": (arch.conv_taps, d),
                     "w_out": (d, d)})
@@ -126,9 +144,10 @@ def _mtp_shapes(arch: Arch) -> dict:
 
 #: leaves that start at one (gains), and those that start at zero
 _ONES = ("ln1_g", "ln2_g", "ln1o_g", "ln2o_g", "q_g", "k_g", "norm_g",
-         "q_a_g", "kv_a_g", "enorm_g", "hnorm_g", "ik_g", "ssm_g", "ssm_d")
+         "q_a_g", "kv_a_g", "enorm_g", "hnorm_g", "ik_g", "ssm_g", "ssm_d",
+         "kda_norm_g")
 _ZEROS = ("ln1_b", "ln2_b", "b1", "b2", "eb1", "eb2", "ebias", "exit_b",
-          "ik_b", "ssm_conv_b")
+          "ik_b", "ssm_conv_b", "kda_g_b")
 #: how each leaf of the GPT-shaped block lies over the ``model`` axis
 _TP_SPECS = {
     "wq": P(None, "model"), "wk": P(None, "model"), "wv": P(None, "model"),
@@ -151,7 +170,8 @@ def init_params(gen, arch, d=None, heads=None, ff=None, vocab=None,
     zero; a convolution's taps are normal ``1/sqrt(taps)``; a state-space
     layer's decay rates uniform 1 .. 16 (``ssm_a_log`` their log), its step
     sizes log-uniform 0.001 .. 0.1 (``ssm_dt_b`` their inverse softplus),
-    its skip one, as Mamba-2 starts them."""
+    its skip one, as Mamba-2 starts them; a delta-rule layer's ``kda_a_log``
+    and ``kda_dt_b`` likewise, as Kimi Linear starts them."""
     arch = as_arch(arch, d, heads, ff, vocab, n_experts)
 
     def w(shape, scale=None):
@@ -164,11 +184,11 @@ def init_params(gen, arch, d=None, heads=None, ff=None, vocab=None,
             return np.ones(shape, np.float32)
         if name in _ZEROS:
             return np.zeros(shape, np.float32)
-        if name in ("conv_k", "ssm_conv_k"):
+        if name in ("conv_k", "ssm_conv_k", "kda_conv_k"):
             return w(shape, 1.0 / np.sqrt(shape[0]))
-        if name == "ssm_a_log":
+        if name in ("ssm_a_log", "kda_a_log"):
             return np.log(gen.uniform(1.0, 16.0, shape)).astype(np.float32)
-        if name == "ssm_dt_b":
+        if name in ("ssm_dt_b", "kda_dt_b"):
             dt = np.exp(gen.uniform(np.log(1e-3), np.log(1e-1), shape))
             return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
         return w(shape)

@@ -25,15 +25,15 @@ _log = logging.getLogger("znicz_tpu.transformer")
 
 #: what a checkpointed layer keeps whatever the memory (:func:`_loop_saves`)
 _KEPT_ALWAYS = ("attn_qkv", "sub_out", "ssm_y", "ssm_state", "ssm_conv",
-                "moe_route", "moe_up")
+                "moe_route", "moe_up", "kda_y", "kda_state")
 
 #: what it keeps beside them where the device has room for all the layers'
 #: (:func:`checkpoint_plan`), in the order of time saved a byte kept: a
 #: feed-forward unit's wide products (a SwiGLU's two; of a shared expert
-#: beside routed ones its form's: two, or a squared-ReLU unit's one) and a
-#: state-space layer's input projection (a product made again costs about
-#: 12 ms a GiB of its result on a v5e)
-_KEPT_IF_ROOM = ("glu_wide", "ssm_in")
+#: beside routed ones its form's: two, or a squared-ReLU unit's one), a
+#: state-space layer's input projection and a delta-rule layer's (a product
+#: made again costs about 12 ms a GiB of its result on a v5e)
+_KEPT_IF_ROOM = ("glu_wide", "ssm_in", "kda_in")
 
 #: bytes :func:`checkpoint_plan` leaves free beside the step's reckoned
 #: footprint and what it keeps: what :func:`step_footprint` may stand under
@@ -65,6 +65,12 @@ def _loop_saves(prim, *_, **params) -> bool:
     nor kept: their backward kernel writes the gated rows once more): a
     layer holds five or six arrays of ``(tokens, d)``, the scan's operand
     and its chunk states where it would hold ``(tokens, 8.5 d)`` of them.
+    Of a delta-rule linear-attention layer (``parallel/kda.py``) likewise the
+    heads' output and each chunk's opening state in the compute dtype
+    (``kda_y``, ``kda_state``) and, where the convolution's kernels run,
+    their result (``q | k | v``); the L2 norms, the log-decays, ``beta``, the
+    gate and the head norm are made again, and so is every step of the rule
+    inside a chunk when the gradients are.
     Of a routed expert layer in such a stack it keeps the router's choice (``moe_route``: the weights, the sort and its
     inverse, the group sizes; small, and no sort runs twice) and the experts'
     up-projections' results (``moe_up``: a kernel's output that leaves the
@@ -92,13 +98,14 @@ def _saves(kept: tuple):
 def _recomputes_by_policy(arch: Arch) -> bool:
     """Whether the stack's layers are checkpointed by :func:`_loop_saves`
     with no keyword asking: a looped stack, a stack with state-space
-    layers, a stack with window layers (``transformer._block_fn``).  A
+    or delta-rule layers (a carried state), a stack with window layers
+    (``transformer._block_fn``).  A
     window on the scores does nothing under its own length, so such a stack
     is one for long rows: it keeps what :func:`_loop_saves` lists and, of
     the rest, what :func:`checkpoint_plan` finds room for in the device's
     memory."""
-    return arch.loop_steps > 1 or "mamba" in arch.mixers or \
-        arch.window_layers() > 0
+    return arch.loop_steps > 1 or bool({"mamba", "kda"} & set(arch.mixers)) \
+        or arch.window_layers() > 0
 
 
 def _n_params(arch: Arch) -> int:
@@ -124,8 +131,9 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
       output and rows of an attention layer (a gated output's gate is made
       again from the layer's input and its weight counts with the masters),
       ``ssm_y``, ``ssm_state`` and
-      ``ssm_conv`` of a state-space layer, ``moe_up`` of a routed one), and
-      a looped stack's outputs;
+      ``ssm_conv`` of a state-space layer, ``kda_y``, ``kda_state`` and the
+      convolution's ``q | k | v`` of a delta-rule layer, ``moe_up`` of a
+      routed one), and a looped stack's outputs;
     - one layer's backward pass at work: six arrays of its widest
       activation in the compute dtype (a SwiGLU's two products, their
       gated product and the three gradients), of a routed layer the held
@@ -167,6 +175,17 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
                 act * (inner + 2 * arch.ssm_groups * arch.ssm_state)
             wide = ssm_in_width(arch.ssm_heads, arch.ssm_head_dim,
                                 arch.ssm_state, arch.ssm_groups)
+        elif mixer == "kda":
+            inner = arch.kda_heads * arch.kda_head_dim
+            chunks = -(-tokens // arch.kda_chunk)
+            # the heads' output, the chunks' opening states as the outputs'
+            # products read them, and the convolution's result (its kernel
+            # writes it; the ``jax.numpy`` form's is made again, and then
+            # this counts high)
+            layer += act * inner + \
+                chunks * inner * arch.kda_head_dim * itemsize + \
+                act * 3 * inner
+            wide = 3 * inner
         elif mixer in ("attention", "latent"):
             qo, kv = arch.heads * arch.head_dim, arch.kv_heads * arch.head_dim
             layer += act * (2 * qo + 2 * kv) + tokens * arch.heads * 4
@@ -206,6 +225,7 @@ def _kind_bytes(arch: Arch, tokens: int, itemsize: int) -> dict:
     count with the SwiGLUs')."""
     glu = sum(f == "glu" for f in arch.ffns)
     mamba = sum(m == "mamba" for m in arch.mixers)
+    kda = sum(m == "kda" for m in arch.mixers)
     shared = arch.shared_ff * arch.ffns.count("moe_routed")
     gated = arch.expert_form == "glu"
     return {
@@ -213,7 +233,9 @@ def _kind_bytes(arch: Arch, tokens: int, itemsize: int) -> dict:
                                          (1 + gated) * shared),
         "ssm_in": tokens * itemsize * mamba * ssm_in_width(
             arch.ssm_heads, arch.ssm_head_dim, arch.ssm_state,
-            arch.ssm_groups)}
+            arch.ssm_groups),
+        "kda_in": tokens * itemsize * kda * 3 * arch.kda_heads *
+        arch.kda_head_dim}
 
 
 def checkpoint_plan(arch: Arch, tokens: int, itemsize: int,

@@ -16,7 +16,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from znicz_tpu.observe import probe as _probe
-from znicz_tpu.parallel import dsa, qcomm, ssm, zero
+from znicz_tpu.parallel import dsa, kda, qcomm, ssm, zero
 # ``arch_from_config`` and ``_FAMILIES`` are not called below: the
 # benchmark's builders take the description's reader from the module they
 # take ``make_train_step`` from
@@ -119,7 +119,8 @@ def _cast_params(ps, arch: Arch, cdt):
     choice: 12 ms of the step, my chip run, PR 29).  The exit gate of a
     looped stack stays in the master dtype too, and a state-space layer's
     step-size bias, decay rates and skip (``ssm.F32_LEAVES``: they enter
-    float32 chains).  A looped stack reads this one cast in every loop
+    float32 chains), and a delta-rule layer's decay bias and rates
+    (``kda.F32_LEAVES``).  A looped stack reads this one cast in every loop
     step."""
     out = jax.tree.map(lambda w: w.astype(cdt), ps)
     layers = list(zip(ps["blocks"], out["blocks"]))
@@ -132,6 +133,8 @@ def _cast_params(ps, arch: Arch, cdt):
                     cast[k] = master[k]
         if "ssm_a_log" in master:
             cast.update({k: master[k] for k in ssm.F32_LEAVES})
+        if "kda_a_log" in master:
+            cast.update({k: master[k] for k in kda.F32_LEAVES})
     if arch.exit_gate:                     # its product is taken in f32
         out.update({k: ps[k] for k in ("exit_w", "exit_b")})
     return out
@@ -477,6 +480,11 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
       ssm_gate.py`` run (all or none: ``ssm.gate_kernel_refusal``, which
       also refuses ONE group; the rest the closing lines of ``ssm.mixer``);
       None without a state-space layer;
+    - ``kda_conv_kernel_share``: of the delta-rule linear-attention layers,
+      the share whose three convolutions and ``silu`` those same kernels run
+      on the ``q | k | v`` projection's lanes (all or none:
+      ``ssm.conv_kernel_refusal``; the rest ``ssm._conv``); None without
+      such a layer;
     - ``sconv_kernel_share``: of the gated short convolutions, the share
       whose gates and taps (``C * conv(B * X)`` between the layer's two
       products, and backward the projection's whole cotangent) the kernels
@@ -521,6 +529,11 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         gate = float(ssm.gate_kernel_refusal(
             t_loc, inner, arch.ssm_groups, 0, itemsize,
             run.interpret) is None)
+    kda_conv = None
+    if "kda" in arch.mixers:
+        kda_conv = float(ssm.conv_kernel_refusal(
+            t_loc, 0, 3 * arch.kda_heads * arch.kda_head_dim,
+            arch.conv_taps, run.interpret) is None)
     sconv = None
     if "sconv" in arch.mixers:
         sconv = float(sconv_kernel_refusal(
@@ -534,7 +547,7 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         "dsa_index_kernel_share": index, "dsa_align_kernel_share": align,
         "moe_gmm_kernel_share": gmm, "ssm_scan_kernel_share": scan,
         "ssm_conv_kernel_share": conv, "ssm_gate_kernel_share": gate,
-        "sconv_kernel_share": sconv}
+        "kda_conv_kernel_share": kda_conv, "sconv_kernel_share": sconv}
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

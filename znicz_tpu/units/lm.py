@@ -134,6 +134,14 @@ def _choice_gauges() -> tuple:
             "(the rest: the jax.numpy form, which one group, the shape or "
             "the platform left them to)",
             ("unit",)), None),
+        ("kda_conv_kernel_share", registry.gauge(
+            "znicz_lm_kda_conv_kernel_share",
+            "delta-rule linear-attention layers whose three causal "
+            "convolutions and silu the Pallas kernels ssm_conv_fwd and "
+            "ssm_conv_bwd run on the q | k | v projection's own lanes over "
+            "the delta-rule layers (the rest: the jax.numpy form, which the "
+            "shape or the platform left them to)",
+            ("unit",)), None),
         ("sconv_kernel_share", registry.gauge(
             "znicz_lm_sconv_kernel_share",
             "gated short convolutions whose gates and taps the Pallas "
@@ -271,6 +279,14 @@ class TransformerLMStep(AcceleratedUnit):
         #: heads) and ``final_state_rms`` (RMS of the state behind a row's
         #: last position); neither depends on the chunk the scan runs in
         self.ssm_counters: dict = {}
+        #: the last finished training pass's readings of a stack with
+        #: delta-rule linear-attention layers (``parallel/kda.py``), means
+        #: over its steps and layers: ``decay_mean`` (of ``exp(g)`` over
+        #: positions, heads and key channels), ``beta_mean``,
+        #: ``final_state_rms`` (RMS of the state behind a row's last
+        #: position) and ``layers`` (such layers a step); none depends on
+        #: the chunk the rule runs in
+        self.kda_counters: dict = {}
         #: of the last finished pass's attention layers that ran a flash
         #: kernel, the share whose kernels read the layer's layout
         #: (``ops/pallas/attention.py::direct_layout``); None without one
@@ -300,6 +316,9 @@ class TransformerLMStep(AcceleratedUnit):
         self.ssm_scan_kernel_share: Optional[float] = None
         self.ssm_conv_kernel_share: Optional[float] = None
         self.ssm_gate_kernel_share: Optional[float] = None
+        #: of the delta-rule layers, the share whose convolutions the
+        #: state-space layer's Pallas kernels run; None without one
+        self.kda_conv_kernel_share: Optional[float] = None
         #: of the gated short convolutions, the share whose gates and taps
         #: the Pallas kernels run; None without one
         self.sconv_kernel_share: Optional[float] = None
@@ -488,6 +507,8 @@ class TransformerLMStep(AcceleratedUnit):
             self._publish_dsa(sums, steps, self.minibatch_mse)
         if sums.get("ssm_layers"):
             self._publish_ssm(sums)
+        if sums.get("kda_layers"):
+            self._publish_kda(sums, steps)
         if "attn_flash" in sums:
             self._publish_attn_layout(float(sums["attn_direct"]) /
                                       float(sums["attn_flash"]))
@@ -610,6 +631,46 @@ class TransformerLMStep(AcceleratedUnit):
             "pass's steps (a carry that is dropped or shortened moves it)",
             ("unit",)).labels(unit=self.name).set(
                 self.ssm_counters["final_state_rms"])
+
+    def _publish_kda(self, sums: dict, steps: float) -> None:
+        """A finished training pass's readings of the delta-rule layers
+        (each summed over the pass's steps and layers, as their count is):
+        the unit's mirror and the process registry."""
+        from znicz_tpu.observe import registry
+
+        layers = float(sums["kda_layers"])
+        self.kda_counters = {
+            "decay_mean": float(sums["kda_decay"]) / layers,
+            "beta_mean": float(sums["kda_beta"]) / layers,
+            "final_state_rms": float(sums["kda_state_rms"]) / layers,
+            "layers": layers / steps}
+        # each family by its literal name: tools/check_metric_catalogue.py
+        # reads declarations off the source
+        gauges = {
+            "decay_mean": registry.gauge(
+                "znicz_lm_kda_decay_mean",
+                "mean over positions, heads, key channels, delta-rule "
+                "layers and the last training pass's steps of the state's "
+                "decay a position, exp(g) (1: a channel is kept whole; 0: "
+                "dropped)", ("unit",)),
+            "beta_mean": registry.gauge(
+                "znicz_lm_kda_beta_mean",
+                "mean over positions, heads, delta-rule layers and the last "
+                "training pass's steps of the delta rule's step beta (in "
+                "(0, 2) with negative eigenvalues allowed, else (0, 1))",
+                ("unit",)),
+            "final_state_rms": registry.gauge(
+                "znicz_lm_kda_final_state_rms",
+                "RMS of a delta-rule layer's state behind a row's last "
+                "position, mean over rows, layers and the last training "
+                "pass's steps (a carry that is dropped or shortened moves "
+                "it)", ("unit",)),
+            "layers": registry.gauge(
+                "znicz_lm_kda_layers",
+                "delta-rule linear-attention layers a step runs, last "
+                "training pass", ("unit",))}
+        for key, value in self.kda_counters.items():
+            gauges[key].labels(unit=self.name).set(value)
 
     def _publish_terms(self, sums: dict, steps: float) -> None:
         """A finished training pass's named terms, each the mean over its
