@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from test_lfm2_arch import _pallas_interpret
 from znicz_tpu.ops.pallas import (attention as pattn, dsa as pdsa,
-                                  grouped as pgrouped, sconv as psconv,
+                                  grouped as pgrouped, kda_delta as pdelta,
+                                  sconv as psconv,
                                   ssd as pssd, ssm_conv as pconv,
                                   ssm_gate as pgate)
 from znicz_tpu.parallel import plan, transformer as tfm
@@ -41,8 +42,9 @@ MAY_IMPORT = {
                "znicz_tpu.observe.probe", "znicz_tpu.ops.pallas"),
     "head": ("znicz_tpu.parallel.arch",),
     # the delta-rule layer borrows the state-space layer's convolution and
-    # its choice between the two forms
-    "kda": ("znicz_tpu.parallel.ssm", "znicz_tpu.observe.probe"),
+    # its choice between the two forms; its rule's kernels are its own
+    "kda": ("znicz_tpu.parallel.ssm", "znicz_tpu.observe.probe",
+            "znicz_tpu.ops.pallas"),
     # ``head`` for the one reading of ``loss_chunks`` (``_n_chunks``),
     # ``moe`` for the rows of a routed layer's compact pairs buffer
     "plan": ("znicz_tpu.parallel.arch", "znicz_tpu.parallel.params",
@@ -168,19 +170,23 @@ def _tiny(family: str, wide: bool):
                 "num_key_value_heads": 1, "head_dim": 128,
                 "sliding_window": 160, "moe_intermediate_size": 128}
     elif wide and family == "solar_open2":
-        # linear heads whose q | k | v is whole lane tiles wide (3 x 2 x 64):
-        # the convolution's kernels' shape
+        # two linear heads of one lane tile each: the convolution's
+        # kernels' shape (q | k | v whole lane tiles wide) and the delta
+        # rule's (a head 128 lanes)
         over = {"hidden_size": 128, "num_attention_heads": 2,
                 "num_key_value_heads": 1, "head_dim": 128,
                 "moe_intermediate_size": 128,
                 "linear_attn_config": {"short_conv_kernel_size": 4,
-                                       "head_dim": 64, "num_heads": 2,
+                                       "head_dim": 128, "num_heads": 2,
                                        "num_kv_heads": None}}
     elif wide and family in ("ouro", "lfm2_moe"):
         over = {"hidden_size": 256, "num_attention_heads": 2,
                 "num_key_value_heads": 2, "head_dim": 128}
     if family == "lfm2_moe":
         return module._arch(module._cfg(["conv", "full_attention"], 1, **over))
+    if wide and family == "solar_open2":
+        # ... in chunks of 128: a head a stack, the two heads one visit
+        return module._arch(module._cfg(**over), chunk=128)
     return module._arch(module._cfg(**over))
 
 
@@ -277,6 +283,12 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
             assert (kernel in text) == (1.0 in (
                 chose["ssm_conv_kernel_share"],
                 chose["kda_conv_kernel_share"]))
+        # the delta rule's form
+        assert (chose["kda_delta_kernel_share"] is None) == \
+            ("kda" not in arch.mixers)
+        for kernel in (pdelta.FWD_KERNEL_NAME, pdelta.BWD_KERNEL_NAME):
+            assert (kernel in text) == \
+                (chose["kda_delta_kernel_share"] == 1.0)
         # the gate's and the gated norm's form
         assert (chose["ssm_gate_kernel_share"] is None) == \
             ("mamba" not in arch.mixers)
@@ -317,7 +329,7 @@ def test_step_choices_says_what_the_traced_step_does(family, monkeypatch):
         want |= {"sconv_kernel_share"}
     if family == "solar_open2":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share",
-                 "kda_conv_kernel_share"}
+                 "kda_conv_kernel_share", "kda_delta_kernel_share"}
     if family == "afmoe":
         want |= {"checkpoint_kept_bytes", "moe_gmm_kernel_share"}
         # the windowed kernels stand in the interpreted step by their own

@@ -36,7 +36,12 @@ def _spans(ring, name: str) -> list:
 
 @pytest.fixture()
 def listener():
+    """The listener, and both rings emptied: a worker that has compiled
+    much before this file fills them (8,192 events), and a full ring drops
+    its oldest span for the new one, so a count of spans stands still."""
     compilecache._register_listener()
+    TRACER.clear()
+    probe.SETUP_RING.clear()
 
 
 @pytest.mark.parametrize("phase", sorted(EVENTS))

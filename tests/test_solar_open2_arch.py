@@ -275,6 +275,42 @@ def test_first_three_steps_follow_the_reference_in_float32():
         assert 0 < got["pairs_held"] <= 3 * 88 * 3
 
 
+def test_the_delta_rules_kernels_train_to_the_same_losses():
+    """Two linear heads of 128 (one lane tile each) over rows of 100
+    positions in chunks of 64 (one whole chunk and a filled one), in float32:
+    with the step's kernels interpreted the rule runs as
+    ``kda_delta_fwd`` / ``kda_delta_bwd`` (``kda_delta_kernel_share`` 1.0) and
+    two steps' losses, every leaf's first gradient and every leaf's change
+    are the ``jax.numpy`` form's to the tolerances this file holds that form
+    to the reference with."""
+    from test_lfm2_arch import _pallas_interpret
+
+    cfg = _cfg(linear_attn_config={
+        "short_conv_kernel_size": 4, "head_dim": 128, "num_heads": 2,
+        "num_kv_heads": None})
+    traffic = {"minibatch_size": 2, "seq_len": 100}
+    arch = _arch(cfg, 64)
+    runs = {}
+    for interpret in (False, True):
+        jax.clear_caches()
+        with _pallas_interpret(interpret):
+            assert tfm.step_choices(_mesh1(), arch, 2, 100, 2)[
+                "kda_delta_kernel_share"] == float(interpret)
+            runs[interpret] = _program_first_steps(
+                cfg, 14, jnp.float32, steps=2, arch=arch, traffic=traffic)
+    jax.clear_caches()
+    (losses, grads, deltas, counters), plain = runs[True], runs[False]
+    np.testing.assert_allclose(losses, plain[0], rtol=2e-6)
+    for name, g in plain[1].items():
+        scale = max(np.linalg.norm(g), 1e-7)
+        assert np.linalg.norm(grads[name] - g) / scale < 5e-4, name
+        assert deltas[name] == pytest.approx(plain[2][name], rel=2e-4,
+                                             abs=1e-8), name
+    for got, want in zip(counters, plain[3]):
+        for key in ("kda_decay", "kda_beta", "kda_state_rms", "kda_layers"):
+            assert got[key] == pytest.approx(want[key], rel=1e-5), key
+
+
 @pytest.mark.parametrize("chunk,seq_len", [(64, 44), (8, 48)])
 def test_the_chunk_is_a_tile(chunk, seq_len):
     """One chunk wider than the row, and six chunks a row: the first step's
@@ -479,6 +515,10 @@ def test_the_unit_publishes_the_delta_rule_counters(tmp_path, caplog):
         fam = registry.REGISTRY.get(f"znicz_lm_kda_{key}")
         assert fam is not None and fam.labels(unit=step.name).get() == value
     assert step.kda_conv_kernel_share == 0.0 and step.ssm_counters == {}
+    # ... nor the rule's: the unit's mirror and the process registry
+    assert step.kda_delta_kernel_share == 0.0
+    fam = registry.REGISTRY.get("znicz_lm_kda_delta_kernel_share")
+    assert fam is not None and fam.labels(unit=step.name).get() == 0.0
     said = [r.getMessage() for r in caplog.records
             if "convolution kernels refused" in r.getMessage()]
     assert len(said) == 1 and "width=96" in said[0] and \

@@ -5,8 +5,9 @@ one JSON line: the compiled step's memory, the compiler's operation count,
 the checkpoint plan a v5e's memory limit gives, the footprint the plan
 reckoned with, how often each of the key/value-blocked flash kernels stands
 in the compiled step (under a window and without one) and, of a stack with
-delta-rule layers which float32 arrays with two chunk-length axes beside a
-head's channels stand in it, of a stack with state-space layers how often the
+delta-rule layers how often the rule's two kernels each stand in it and
+which float32 arrays with two chunk-length axes do (beside a head's
+channels, and at all), of a stack with state-space layers how often the
 scan's,
 the convolution's and the gate's two kernels each stand in the compiled
 step, which float32 arrays with two chunk-length axes do, which float32
@@ -100,8 +101,8 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
     if out:
         with open(out, "w") as f:
             f.write(text)
-    from znicz_tpu.ops.pallas import (attention as pattn, ssd, ssm_conv,
-                                      ssm_gate)
+    from znicz_tpu.ops.pallas import (attention as pattn, kda_delta, ssd,
+                                      ssm_conv, ssm_gate)
     q = arch.ssm_chunk
     channels = arch.ssm_heads * arch.ssm_head_dim + \
         2 * arch.ssm_groups * arch.ssm_state
@@ -139,6 +140,13 @@ def main(config: str, traffic: str, limit_gib: float = 15.75) -> dict:
         # chunk-length axes beside a head's channels, every chunk's (a
         # chunk's decay matrix a channel: ``(.., t / C, C, C, K)``)
         "delta_rule_layers": arch.mixers.count("kda"),
+        "delta_kernels": stands(kda_delta.FWD_KERNEL_NAME,
+                                kda_delta.BWD_KERNEL_NAME),
+        # ... and with two chunk-length axes at all (a chunk's scores, the
+        # unit-triangular inverse)
+        "delta_chunk_squares": sorted(set(re.findall(
+            rf"f32\[(?:\d+,)*{arch.kda_chunk},{arch.kda_chunk}\]", text)))
+        if arch.mixers.count("kda") else [],
         "chunk_channel_squares": sorted(set(re.findall(
             rf"f32\[(?:\d+,)*{t // arch.kda_chunk},{arch.kda_chunk},"
             rf"{arch.kda_chunk},{arch.kda_head_dim}\]", text)))

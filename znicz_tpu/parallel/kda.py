@@ -56,15 +56,44 @@ reads them.  ``C`` is a tile, a power of two: it changes no value beyond
 rounding, and a row it does not divide is filled with positions of ``g = 0``,
 ``beta = 0`` (the state passes them unchanged).
 
-**What the backward pass keeps**: the operands and each chunk's opening
-state (named ``kda_state``, cast as the outputs' products read it).
-:func:`_prepared` (1, 2), :func:`_carried` (3) and :func:`_outputs` (1 for
-the queries, 4) are each a ``jax.checkpoint``, so the scores, the inverse,
-``W`` and ``U`` are made again from the operands when the gradients are, and
-never stored.  The gradients are those of the chunked
-form as written (no hand-written rule).  The input projection is named
-``kda_in`` for ``plan.py::checkpoint_plan`` to keep or refuse and the heads'
-output ``kda_y``.
+**Two forms of one algorithm** (:func:`delta` asks one question,
+:func:`delta_kernel_refusal`, of what it can observe; no switch chooses).
+Where the step's kernels run (a TPU, or ``engine.pallas_interpret``) and the
+shape fits (a head of exactly 128 entries, keys and values alike: one lane
+tile; ``C`` a power of two from 16 to 128; the heads whole stacks of ``128 /
+C``; a visit inside the kernels' VMEM), steps 1 to 4 are two Pallas kernels
+behind a ``jax.custom_vjp`` (``ops/pallas/kda_delta.py``: ``kda_delta_fwd``,
+``kda_delta_bwd``): a visit holds one chunk of a block of heads, ``q``, ``k``
+and ``v`` cut by lanes from the convolution's own ``(b, t, 3 H K)`` result
+(the L2 norms of a head's ``q`` and ``k`` taken in the kernels: :func:`mixer`
+hands the array over whole, :func:`_packed`) and ``g`` from its ``(b, t, H
+K)``, the float32 state of a row's heads stays in VMEM over the row's chunks, and the
+scores, the inverse, ``W``, ``U``, ``K e^(G_last - G)`` and every head-major
+copy never reach HBM in any pass.  There the halves go all the way down (no
+direct sub-blocks: a level is one 128-row product), the inverse's float32
+products run as three 16-bit passes on two-term operands, the running sums are
+made inside the levels from ``g`` itself, and ``U - W S`` is ``T (V - (K
+e^G) S)``, the same number.  Everywhere else, and as the tests' second
+opinion, the ``jax.numpy`` form below.  A refusal is logged once a shape
+with its reason.
+
+**What the backward pass keeps**, in either form: the operands and each
+chunk's opening state (named ``kda_state``, cast as the outputs' products
+read it: ``(b, H, t / C, K, V)`` here, ``(b, t / C, H V, K)`` transposed from
+the kernel, which writes it as its products read it).  The scores, the
+inverse, ``W`` and ``U`` are made again from the operands when the gradients
+are, and never stored: by the backward kernel in VMEM (``dN = -M^T dM M^T``;
+the decays' cotangent a channel-wise product of each level's operand with
+its cotangent, walked back through the running sums), by the three
+``jax.checkpoint`` parts below through HBM (:func:`_prepared` (1, 2),
+:func:`_carried` (3) and :func:`_outputs` (1 for the queries, 4); the
+gradients are those of the chunked form as written, no hand-written rule).
+The operands themselves come from the kept convolution result by
+elementwise work and two small products.  The kernel names its output
+``kda_y`` and the layer's own checkpoint (``transformer.py::_block_fn``)
+keeps it as it keeps every kernel's, so the forward kernel runs once a
+step.  The input projection is named ``kda_in`` for ``plan.py::
+checkpoint_plan`` to keep or refuse and the heads' output ``kda_y``.
 
 **The convolution** in front is the state-space layer's without a bias over
 the projection's ``3 H K`` lanes: where ``ssm.conv_kernel_refusal`` says None
@@ -260,18 +289,47 @@ def _outputs(q, k, g, w, u, opening):
     return o.astype(k.dtype)
 
 
+def delta_kernel_refusal(t: int, heads: int, head_dim: int, chunk: int,
+                         itemsize: int, interpret: bool) -> str | None:
+    """Why the rule over rows of ``t`` positions of ``heads`` heads of
+    ``head_dim`` entries (keys and values alike) of ``itemsize`` bytes in
+    chunks of ``chunk`` runs in its ``jax.numpy`` form, or ``None`` where the
+    kernels of ``ops/pallas/kda_delta.py`` run it: where the step's kernels
+    run at all (a TPU, or ``interpret``: interpreted) and the shape is one
+    they take (``kda_delta.unsupported_reason``: the kernel's own reasons;
+    ``t`` is filled to whole chunks in either form).  What :func:`delta`
+    asks as the step is traced and ``transformer.step_choices`` before."""
+    from znicz_tpu.ops.pallas import kda_delta as _pdelta
+    return ssm._backend_refusal(interpret) or _pdelta.unsupported_reason(
+        int(chunk), heads, head_dim, itemsize)
+
+
 def delta(q, k, v, g, beta, chunk: int):
     """The rule: ``q``, ``k`` ``(b, t, H, K)``, ``v (b, t, H, V)`` in one
     dtype, ``g (b, t, H, K)`` float32 (the log-decays, at most 0), ``beta (b,
     t, H)`` float32 -> ``(o (b, t, H, V) in that dtype, the state behind the
     last position (b, H, K, V) float32)``, in chunks of ``chunk`` positions
-    (a power of two)."""
+    (a power of two): by the two kernels where :func:`delta_kernel_refusal`
+    says None, else as written below."""
     b, t, heads, _ = q.shape
     fill = -t % chunk
     if fill:
         q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, fill)) +
                                     ((0, 0),) * (a.ndim - 2))
                             for a in (q, k, v, g, beta))
+    interpret = None
+    if v.shape == q.shape:
+        interpret = ssm._kernels_or_none(
+            "delta rule", delta_kernel_refusal, "t heads head_dim chunk",
+            (t, heads, q.shape[-1], int(chunk)), itemsize=q.dtype.itemsize)
+    if interpret is not None:
+        from znicz_tpu.ops.pallas import kda_delta as _pdelta
+        # the operands as the layer has them, a head a lane tile: the
+        # kernels cut their blocks from the lanes
+        o, last = _pdelta.delta(
+            *(a.reshape(b, t + fill, -1) for a in (q, k, v, g)), beta,
+            int(chunk), interpret)
+        return o.reshape(q.shape)[:, :t], last
 
     def chunked(a):                      # head-major: (b, H, c, C, ...)
         a = jnp.moveaxis(a, 2, 1)
@@ -282,6 +340,22 @@ def delta(q, k, v, g, beta, chunk: int):
     opening = checkpoint_name(opening, "kda_state")
     o = _outputs(q, k, g, w, u, opening)
     o = jnp.moveaxis(o.reshape(b, heads, t + fill, -1), 1, 2)
+    return o[:, :t], last
+
+
+def _packed(qkv, g, beta, chunk: int, interpret: bool):
+    """The rule by the kernels on the layer's own ``qkv (b, t, 3 H K)``,
+    ``g (b, t, H K)`` float32 and ``beta (b, t, H)`` float32, the L2 norms
+    of q and k (:func:`l2_normed`'s, the queries times ``K^-1/2``) taken in
+    the kernels -> ``(o (b, t, H V), the state behind the last position)``;
+    a row the chunk does not divide is filled as :func:`delta` fills it."""
+    from znicz_tpu.ops.pallas import kda_delta as _pdelta
+    t = qkv.shape[1]
+    fill = -t % chunk
+    if fill:
+        qkv, g, beta = (jnp.pad(a, ((0, 0), (0, fill), (0, 0)))
+                        for a in (qkv, g, beta))
+    o, last = _pdelta.delta_packed(qkv, g, beta, chunk, 1e-6, interpret)
     return o[:, :t], last
 
 
@@ -310,21 +384,31 @@ def mixer(u, p, heads: int, head_dim: int, chunk: int, neg_eigval: bool,
         qkv = ssm._conv_silu(proj, taps, jnp.zeros((3 * inner,), _F32), 0,
                              conv_kernels)
     with _probe.scope(f"{scope}.delta"):
-        q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(
-            b, t, heads, head_dim) for i in range(3))
-        q, k = l2_normed(q) * (head_dim ** -0.5), l2_normed(k)
-        # the log-decays: float32 from the second product on
+        # the log-decays: float32 from the second product on, a head's rate
+        # on each of its channels
         pre = jnp.einsum("btr,rn->btn", u @ p["kda_f1"], p["kda_f2"],
                          preferred_element_type=_F32) + \
             p["kda_dt_b"].astype(_F32)
-        g = -jnp.exp(p["kda_a_log"].astype(_F32))[:, None] * \
-            jax.nn.softplus(pre).reshape(b, t, heads, head_dim)
+        g = -jnp.repeat(jnp.exp(p["kda_a_log"].astype(_F32)), head_dim) * \
+            jax.nn.softplus(pre)
         beta = jax.nn.sigmoid(jnp.einsum(
             "btd,dh->bth", u, p["kda_b"], preferred_element_type=_F32))
         if neg_eigval:
             # I - beta k k^T then has its eigenvalue along k in (-1, 1)
             beta = beta * 2.0
-        o, last = delta(q, k, v, g, beta, chunk)
+        kernels = ssm._kernels_or_none(
+            "delta rule", delta_kernel_refusal, "t heads head_dim chunk",
+            (t, heads, head_dim, int(chunk)), itemsize=qkv.dtype.itemsize)
+        if kernels is not None:
+            # q | k | v as the convolution left them: the kernels cut the
+            # three from the lanes and take the L2 norms a head themselves
+            o, last = _packed(qkv, g, beta, int(chunk), kernels)
+        else:
+            q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(
+                b, t, heads, head_dim) for i in range(3))
+            q, k = l2_normed(q) * (head_dim ** -0.5), l2_normed(k)
+            o, last = delta(q, k, v, g.reshape(b, t, heads, head_dim), beta,
+                            chunk)
         # named (b, t, inner) wide, as the state-space layer's ``ssm_y`` is
         o = checkpoint_name(o.reshape(b, t, inner), "kda_y")
         last = lax.stop_gradient(last)

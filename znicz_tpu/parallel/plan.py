@@ -137,8 +137,11 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
     - one layer's backward pass at work: six arrays of its widest
       activation in the compute dtype (a SwiGLU's two products, their
       gated product and the three gradients), of a routed layer the held
-      experts' cast and float32 gradients, and of a looped stack the
-      layer's kept arrays once more (cut from the scan's stack as copies);
+      experts' cast and float32 gradients, of a delta-rule layer the
+      operands and cotangents of the rule's kernels (twelve arrays of the
+      heads' width, at another time than the layer's feed-forward), and of
+      a looped stack the layer's kept arrays once more (cut from the scan's
+      stack as copies);
     - the head pass: one chunk's float32 logits and their gradient in the
       compute dtype (a looped stack's passes are one call of ``loop_steps``
       times the chunks), and the gradient to the stack's output it leaves
@@ -159,7 +162,7 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
         # the layer's input and each sub-layer's output (``sub_out`` twice
         # of a layer of two)
         layer = (1 + (mixer != "none") + (ffn != "none")) * act * d
-        wide = transient = 0
+        wide = transient = alone = 0
         if mixer == "mamba":
             inner = arch.ssm_heads * arch.ssm_head_dim
             chunks = -(-tokens // arch.ssm_chunk)
@@ -185,7 +188,16 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
             layer += act * inner + \
                 chunks * inner * arch.kda_head_dim * itemsize + \
                 act * 3 * inner
-            wide = 3 * inner
+            # at work, apart from the feed-forward's time at work: what the
+            # rule's kernels read and write beside the kept arrays (the
+            # float32 log-decays and their cotangent, dq, dk and dv: seven
+            # arrays of the heads' width in the compute dtype), the three
+            # joined as the cotangent of ``q | k | v`` (three) and one more
+            # pair at the seams; ``q``, ``k`` and ``v`` are cut from the
+            # kept convolution result and the rule's scores, inverse and
+            # carry live in the kernels' VMEM.  Where the ``jax.numpy`` form
+            # runs, its temporaries stand 2 GiB over this at the cell's shape
+            alone = 12 * act * inner
         elif mixer in ("attention", "latent"):
             qo, kv = arch.heads * arch.head_dim, arch.kv_heads * arch.head_dim
             layer += act * (2 * qo + 2 * kv) + tokens * arch.heads * 4
@@ -205,7 +217,7 @@ def step_footprint(arch: Arch, tokens: int, itemsize: int,
             transient = arch.experts_held * (ups + 1) * d * arch.moe_ff * \
                 (4 + itemsize)
         kept += layer
-        working = max(working, 6 * act * wide + transient +
+        working = max(working, alone, 6 * act * wide + transient +
                       (layer if loops > 1 else 0))
     if loops > 1:
         grads += itemsize * sum(

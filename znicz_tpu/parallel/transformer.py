@@ -485,6 +485,11 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
       on the ``q | k | v`` projection's lanes (all or none:
       ``ssm.conv_kernel_refusal``; the rest ``ssm._conv``); None without
       such a layer;
+    - ``kda_delta_kernel_share``: of the delta-rule layers, the share whose
+      rule (scores, unit-triangular inverse, carry and outputs, forward and
+      backward) the kernels of ``ops/pallas/kda_delta.py`` run (all or none:
+      ``kda.delta_kernel_refusal``; the rest ``kda.py``'s ``jax.numpy``
+      form); None without such a layer;
     - ``sconv_kernel_share``: of the gated short convolutions, the share
       whose gates and taps (``C * conv(B * X)`` between the layer's two
       products, and backward the projection's whole cotangent) the kernels
@@ -529,11 +534,15 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         gate = float(ssm.gate_kernel_refusal(
             t_loc, inner, arch.ssm_groups, 0, itemsize,
             run.interpret) is None)
-    kda_conv = None
+    kda_conv = kda_delta = None
     if "kda" in arch.mixers:
         kda_conv = float(ssm.conv_kernel_refusal(
             t_loc, 0, 3 * arch.kda_heads * arch.kda_head_dim,
             arch.conv_taps, run.interpret) is None)
+        kda_delta = float(kda.delta_kernel_refusal(
+            t_loc, arch.kda_heads, arch.kda_head_dim, arch.kda_chunk,
+            jnp.dtype(_default_compute_dtype()).itemsize,
+            run.interpret) is None)
     sconv = None
     if "sconv" in arch.mixers:
         sconv = float(sconv_kernel_refusal(
@@ -547,7 +556,8 @@ def step_choices(mesh: Mesh, arch: Arch, batch: int, t: int,
         "dsa_index_kernel_share": index, "dsa_align_kernel_share": align,
         "moe_gmm_kernel_share": gmm, "ssm_scan_kernel_share": scan,
         "ssm_conv_kernel_share": conv, "ssm_gate_kernel_share": gate,
-        "kda_conv_kernel_share": kda_conv, "sconv_kernel_share": sconv}
+        "kda_conv_kernel_share": kda_conv,
+        "kda_delta_kernel_share": kda_delta, "sconv_kernel_share": sconv}
 
 
 def make_train_step(mesh: Mesh, arch, d=None, heads=None, ff=None,

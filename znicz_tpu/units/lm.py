@@ -142,6 +142,15 @@ def _choice_gauges() -> tuple:
             "the delta-rule layers (the rest: the jax.numpy form, which the "
             "shape or the platform left them to)",
             ("unit",)), None),
+        ("kda_delta_kernel_share", registry.gauge(
+            "znicz_lm_kda_delta_kernel_share",
+            "delta-rule linear-attention layers whose chunked rule (scores "
+            "under the decays, unit-triangular inverse, carry and outputs) "
+            "the Pallas kernels kda_delta_fwd and kda_delta_bwd run, a chunk "
+            "of a block of heads in VMEM, over the delta-rule layers (the "
+            "rest: the jax.numpy form, which the shape or the platform left "
+            "them to)",
+            ("unit",)), None),
         ("sconv_kernel_share", registry.gauge(
             "znicz_lm_sconv_kernel_share",
             "gated short convolutions whose gates and taps the Pallas "
@@ -317,8 +326,10 @@ class TransformerLMStep(AcceleratedUnit):
         self.ssm_conv_kernel_share: Optional[float] = None
         self.ssm_gate_kernel_share: Optional[float] = None
         #: of the delta-rule layers, the share whose convolutions the
-        #: state-space layer's Pallas kernels run; None without one
+        #: state-space layer's Pallas kernels run, and whose rule its own;
+        #: None without one
         self.kda_conv_kernel_share: Optional[float] = None
+        self.kda_delta_kernel_share: Optional[float] = None
         #: of the gated short convolutions, the share whose gates and taps
         #: the Pallas kernels run; None without one
         self.sconv_kernel_share: Optional[float] = None
